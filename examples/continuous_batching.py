@@ -12,7 +12,8 @@ The interesting number is ``dispatches``: N requests of budget B cost
 ~max-chain dispatches instead of N*B — the continuous-batching win that
 static batch serving (and the reference) cannot express.
 
-Run: ``python examples/continuous_batching.py`` (CPU-safe).
+Run: ``python examples/continuous_batching.py`` (runs on the device
+JAX finds; CPU works).
 """
 
 from __future__ import annotations
@@ -22,8 +23,6 @@ import json
 
 def main() -> dict:
     import jax
-
-    jax.config.update("jax_platforms", "cpu")  # control-plane example
     import jax.numpy as jnp
     import numpy as np
 

@@ -1,7 +1,7 @@
 """lock-discipline: annotated shared state touched without its lock.
 
 The host-side subsystems (loader worker pools, serving driver threads,
-the relay lock breaker) guard shared attributes with plain
+circuit breakers) guard shared attributes with plain
 ``threading`` locks — nothing makes a new code path remember. This
 rule turns the convention into a checked contract: a trailing
 

@@ -4,7 +4,7 @@ A ``while``/``for`` that catches an exception and ``time.sleep``\\ s a
 *constant* before trying again is a retry storm waiting to happen: when
 the dependency actually goes down, every worker in the fleet re-dogpiles
 it in lockstep at exactly the same cadence (the AWS full-jitter result;
-this is why ``runtime/resilience.py`` exists). The PR-3 relay-lock
+this is why ``runtime/resilience.py`` exists). A PR-3 lock-file
 incident was this exact shape — a ``FileExistsError`` busy-spin.
 
 Flagged: a loop whose body contains a ``try``/``except`` (the retry
@@ -13,8 +13,7 @@ anywhere inside the loop. Not flagged: poll/wait loops with no
 exception handling (sleeping a constant while *watching* for a state
 change is fine — nothing failed), computed sleeps (a
 ``RetryPolicy.delay(...)`` result is a Name, not a Constant), and the
-sanctioned backoff homes ``runtime/resilience.py`` and
-``runtime/relaylock.py``.
+sanctioned backoff home ``runtime/resilience.py``.
 
 The fix is almost always ``resilience.RetryPolicy(...).call(fn)`` —
 bounded attempts, exponential backoff, full jitter, telemetry.
@@ -28,11 +27,8 @@ from hops_tpu.analysis.engine import Context, Rule, dotted_name, register
 from hops_tpu.analysis.model import Finding, ParsedFile
 
 #: Modules allowed to hand-roll sleeps in retry shapes: the policy
-#: engine itself, and the relay lock's carefully-reviewed wait loop.
-SANCTIONED = (
-    "hops_tpu/runtime/resilience.py",
-    "hops_tpu/runtime/relaylock.py",
-)
+#: engine itself.
+SANCTIONED = ("hops_tpu/runtime/resilience.py",)
 
 
 def _is_sleep(node: ast.AST) -> bool:

@@ -34,22 +34,6 @@ from hops_tpu.runtime.logging import get_logger
 log = get_logger(__name__)
 
 _procs: dict[str, subprocess.Popen] = {}
-
-# Execution bootstrap: runs the app file as __main__ with its argv, but
-# first re-applies JAX_PLATFORMS if a sitecustomize pre-imported jax
-# (which snapshots the env var before the job's intent can take effect).
-# Without this, a cpu-destined job still initializes the accelerator
-# backend — and hangs outright if the accelerator is unreachable. The
-# platform-forcing trick matches tests/conftest.py and launch.py.
-_BOOTSTRAP = """\
-import os, sys, runpy
-_p = os.environ.get("JAX_PLATFORMS")
-if _p and "jax" in sys.modules:
-    sys.modules["jax"].config.update("jax_platforms", _p)
-sys.argv = sys.argv[1:]
-sys.path.insert(0, os.path.dirname(os.path.abspath(sys.argv[0])))
-runpy.run_path(sys.argv[0], run_name="__main__")
-"""
 _procs_lock = threading.Lock()
 
 
@@ -59,8 +43,10 @@ class JobConfig:
 
     ``app_file`` is the Python entry file (the reference's
     ``{APP_FILE}`` placeholder); ``dependencies`` are extra files/dirs
-    staged next to it; ``chips`` requests a sub-slice (0 = whole slice,
-    mapped to device-visibility env for the child process).
+    staged next to it; ``chips`` records the sub-slice the job asks for
+    (0 = whole slice). It is NOT yet mapped to the child's device
+    visibility: a job child takes every chip JAX finds, so one host
+    runs at most one chip-using child at a time.
     """
 
     app_file: str = ""
@@ -212,7 +198,7 @@ def start_job(name: str, args: list[str] | None = None) -> Execution:
     logfile = open(ex.log_path, "w")
     try:
         proc = subprocess.Popen(
-            [sys.executable, "-c", _BOOTSTRAP, job.config.app_file, *ex.args],
+            [sys.executable, job.config.app_file, *ex.args],
             stdout=logfile,
             stderr=subprocess.STDOUT,
             env=env,

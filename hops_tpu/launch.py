@@ -42,13 +42,6 @@ def main(argv: list[str] | None = None) -> None:
         type=int,
         default=int(os.environ["JAX_PROCESS_ID"]) if "JAX_PROCESS_ID" in os.environ else None,
     )
-    parser.add_argument(
-        "--platform",
-        default=os.environ.get("HOPS_TPU_PLATFORM"),
-        help="force the JAX platform (e.g. cpu) — applied via jax.config "
-        "before backend init, so it wins even when a sitecustomize has "
-        "already imported jax and snapshotted JAX_PLATFORMS",
-    )
     parser.add_argument("-m", "--module", help="run a module instead of a script file")
     parser.add_argument("script", nargs="?", help="Python file to run on this host")
     parser.add_argument("script_args", nargs=argparse.REMAINDER)
@@ -57,13 +50,12 @@ def main(argv: list[str] | None = None) -> None:
     if not args.module and not args.script:
         parser.error("provide a script file or -m module")
 
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
-
-    # Join the slice BEFORE the user code can touch the XLA backend.
+    # Both before the user code can touch the XLA backend: place the
+    # persistent compile cache, then join the slice.
     from hops_tpu.parallel import multihost
+    from hops_tpu.runtime import compile_cache
+
+    compile_cache.enable()
 
     multihost.initialize(
         coordinator_address=args.coordinator,

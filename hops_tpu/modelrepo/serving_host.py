@@ -56,6 +56,11 @@ def main(argv: list[str] | None = None) -> None:
         parser.error("provide a serving name, --restore, or --fleet-worker")
 
     from hops_tpu.modelrepo import serving
+    from hops_tpu.runtime import compile_cache
+
+    # Before the predictor's first use of the backend: a restarted host
+    # loads its compiled programs instead of compiling them again.
+    compile_cache.enable()
 
     # Block the termination signals BEFORE any server thread exists:
     # spawned threads inherit the mask, so the kernel can only deliver

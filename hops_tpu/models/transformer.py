@@ -21,6 +21,7 @@ by ``parallel.sharding.infer_param_spec``; activations shard
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -36,6 +37,7 @@ from hops_tpu.ops.attention import (
     quantize_kv,
     repeat_kv,
 )
+from hops_tpu.parallel.mesh import per_shard
 
 
 def rotary_embedding(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -169,7 +171,11 @@ class Attention(nn.Module):
             k, v = repeat_kv(q, k, v)
 
         if self.attention_impl == "flash":
-            o = flash_attention(q, k, v, causal=True, window=self.window)
+            o = per_shard(
+                functools.partial(
+                    flash_attention, causal=True, window=self.window
+                )
+            )(q, k, v)
         elif self.attention_impl == "reference":
             o = attention_reference(q, k, v, causal=True, window=self.window)
         elif self.attention_impl == "ring_local":

@@ -36,23 +36,23 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float("-inf")
 _LANES = 128  # VPU lane width: per-row stats are broadcast across lanes
 
-# JAX renamed pltpu.TPUCompilerParams -> CompilerParams; resolve
-# whichever the installed version carries so the module imports on both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if _CompilerParams is None:  # pragma: no cover — future rename
-    raise ImportError(
-        "jax.experimental.pallas.tpu has neither CompilerParams nor "
-        "TPUCompilerParams; update the compat shim in ops/attention.py"
-    )
-
 # The (batch·heads) grid dim is embarrassingly parallel; the q/k block
 # dims carry scratch state between steps and must stay "arbitrary".
-_COMPILER_PARAMS = _CompilerParams(
-    dimension_semantics=("parallel", "arbitrary", "arbitrary")
-)
+_GRID_SEMANTICS = ("parallel", "arbitrary", "arbitrary")
 
+# Flash kernels: above 4k keys the tiles are 1024x2048, and the backward
+# kernels hold four fp32 (block_q, block_k) intermediates (s, p, dp, ds
+# — 8 MiB each before Mosaic reuses them). Mosaic's default 16 MiB
+# scoped-VMEM limit refuses that by 72 KB at seq 8192 and by 1.6 MB at
+# 32k (libtpu 0.0.34); 32 MiB fits every tile `flash_attention` picks
+# (compiled and checked against the reference at seq 8192 on a v5e,
+# compiled ahead of time at 32k).
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=_GRID_SEMANTICS, vmem_limit_bytes=32 * 1024 * 1024
+)
+_DECODE_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=_GRID_SEMANTICS
+)
 
 
 def repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -343,7 +343,7 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, int
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)
 
@@ -391,7 +391,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, 
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(qf, kf, vf, gf, lse, delta)
 
@@ -422,7 +422,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, 
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(qf, kf, vf, gf, lse, delta)
 
@@ -850,9 +850,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, q_rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
-        ),
+        compiler_params=_DECODE_COMPILER_PARAMS,
         interpret=interpret,
     )(vl, *args)
     return out[:, :rows].reshape(b, hkv, g, s, d).reshape(b, h, s, d)
@@ -861,6 +859,16 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 # Paged KV cache: block-pool storage addressed through per-row page tables
 # ---------------------------------------------------------------------------
+
+
+# Rows of the HBM tile XLA gives a (hkv, nblocks, page, d) pool on TPU:
+# T(8,128) for fp32, bf16 AND int8 (packing narrows the tile's words,
+# not its row count — libtpu 0.0.34). Pages of 8, 16, 32 and 64 rows
+# compile under Mosaic and match the reference on a v5e for bf16 and
+# int8 (PERF.md, PR 21). Any other page makes XLA pick a different pool
+# layout (T(4,128), or a permuted dim order that costs a relayout copy
+# of the whole pool per call) that no chip run has checked.
+_PAGE_TILE_ROWS = 8
 
 
 def paged_gather_kv(pool: jax.Array, pages: jax.Array) -> jax.Array:
@@ -898,7 +906,7 @@ def paged_decode_attention_reference(
 ) -> jax.Array:
     """XLA ground truth for :func:`paged_decode_attention`: gather the
     dense view, then :func:`decode_attention_reference`. Kept for (a)
-    numeric tests, (b) page sizes the kernel's tiling can't take. With
+    numeric tests, (b) backends without Mosaic. With
     ``k_scale``/``v_scale`` pools the gathered int8 view dequantizes
     before the reference math (the kernel folds the same scales into
     its dots instead)."""
@@ -1049,9 +1057,10 @@ def paged_decode_attention(
     whose page-table entries are 0 by convention points at a reserved
     scratch block; masking makes its contents unreachable.
 
-    Pool rows the page table never references are never read. Page
-    sizes that don't tile (``page % 8 != 0``) fall back to the gathered
-    reference formulation.
+    Pool rows the page table never references are never read. A page
+    that is not whole 8-row tiles (:data:`_PAGE_TILE_ROWS`) raises on
+    the compiled path — it neither reaches Mosaic nor slides to the
+    reference.
 
     With ``k_scale``/``v_scale`` (both or neither; fp32 ``(hkv,
     nblocks, page)`` per-position scale pools living beside the page
@@ -1087,13 +1096,6 @@ def paged_decode_attention(
     valid_len = _normalize_valid_len(valid_len, b)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    if page % 8:
-        # Sub-sublane pages can't be a Mosaic block; the gathered
-        # reference is the shape fallback (tests use it as ground truth).
-        return paged_decode_attention_reference(
-            q, k, v, valid_len, pages, sm_scale, window,
-            k_scale=k_scale, v_scale=v_scale,
-        ).astype(q.dtype)
     if interpret is None:
         if jax.default_backend() != "tpu":
             # Non-TPU backends take the XLA reference twin: the paged
@@ -1107,6 +1109,11 @@ def paged_decode_attention(
                 k_scale=k_scale, v_scale=v_scale,
             ).astype(q.dtype)
         interpret = False
+    if not interpret and page % _PAGE_TILE_ROWS:
+        raise ValueError(
+            f"kv page size {page} does not tile the pool on TPU: pages "
+            f"must be a multiple of {_PAGE_TILE_ROWS} rows"
+        )
 
     g = h // hkv
     rows = g * s
@@ -1175,9 +1182,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, q_rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
-        ),
+        compiler_params=_DECODE_COMPILER_PARAMS,
         interpret=interpret,
     )(vl, pages32, *args)
     return out[:, :rows].reshape(b, hkv, g, s, d).reshape(b, h, s, d)

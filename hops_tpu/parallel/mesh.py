@@ -157,6 +157,51 @@ def global_mesh(axis_names: Sequence[str] = ("data",)) -> Mesh:
     return make_mesh(axis_names=axis_names)
 
 
+# GSPMD data-parallel region: Strategy.step's default path announces
+# (mesh, data axis) on this thread while it TRACES the step, so an op
+# XLA cannot partition can split itself over the batch (see per_shard).
+_gspmd = threading.local()
+
+
+@contextlib.contextmanager
+def gspmd_data_parallel(mesh: Mesh, axis: str | tuple[str, ...]):
+    """Mark the enclosed trace as a GSPMD step whose batch is sharded on
+    ``axis`` of ``mesh``."""
+    prev = getattr(_gspmd, "region", None)
+    _gspmd.region = (mesh, axis)
+    try:
+        yield
+    finally:
+        _gspmd.region = prev
+
+
+def per_shard(fn: Any) -> Any:
+    """``fn`` over batch-leading arrays, run per device shard when traced
+    inside a multi-device :func:`gspmd_data_parallel` region.
+
+    A Mosaic ``pallas_call`` is a custom call GSPMD cannot partition
+    ("Mosaic kernels cannot be automatically partitioned" at lowering),
+    so a batch-parallel kernel inside a plain sharded ``jax.jit`` goes
+    under ``shard_map`` over the data axis — every array argument and
+    the result shard on their leading (batch) dim, other mesh axes
+    replicate. Outside such a region (one device, or a step already
+    inside ``shard_map``) this is ``fn`` itself.
+    """
+    region = getattr(_gspmd, "region", None)
+    if region is None or region[0].size == 1:
+        return fn
+    mesh, axis = region
+    spec = P(axis)
+
+    def sharded(*arrays):
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=(spec,) * len(arrays), out_specs=spec,
+            check_vma=False,
+        )(*arrays)
+
+    return sharded
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
@@ -168,22 +213,11 @@ def pvary(x: Any, axes: Sequence[str | None]) -> Any:
     ``pcast`` rejects mixed invarying/varying requests (e.g. zeros_like
     of a seq-sharded activation is already seq-varying and only needs
     the stage axis added)."""
-    axes = tuple(a for a in axes if a is not None)
-    try:
-        current = jax.typeof(x).vma
-    except (AttributeError, TypeError):
-        current = frozenset()
-    axes = tuple(a for a in axes if a not in current)
+    current = jax.typeof(x).vma
+    axes = tuple(a for a in axes if a is not None and a not in current)
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):  # current API; pvary is its deprecated alias
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    # Neither exists: this JAX predates varying-manual-axes typing
-    # (<= 0.4.x), where shard_map carries broadcast constants without
-    # any vma marking — nothing to do.
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def batch_sharding(mesh: Mesh, axis: str | tuple[str, ...] = "data") -> NamedSharding:

@@ -34,7 +34,7 @@ def initialize(
     function never touches ``jax.process_count()`` etc. until after the
     distributed client is up.
     """
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return  # already joined
     want_multi = (
         coordinator_address is not None
@@ -55,22 +55,6 @@ def initialize(
         jax.process_count(),
         jax.device_count(),
     )
-
-
-def _distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for JAX
-    versions that predate it (0.4.x): the distributed client lives in
-    ``jax._src.distributed.global_state``. Must not touch the local XLA
-    backend (see :func:`initialize`)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:  # pragma: no cover — private-API drift
-        return False
 
 
 def _sync_session_id(max_len: int = 64) -> None:

@@ -23,7 +23,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hops_tpu.parallel.mesh import pvary as _pvary
@@ -619,7 +619,7 @@ def _scheduled_lm_loss_and_grads(
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P()),
         out_specs=(P(), P(axis), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss_and_grads(params, inputs, targets):

@@ -30,7 +30,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hops_tpu.ops.attention import NEG_INF, flash_attention, repeat_kv

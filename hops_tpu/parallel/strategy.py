@@ -153,14 +153,12 @@ class Strategy:
                     lambda st: gc.zero12_state_specs(st, self.data_axis),
                 )
             else:
-                from jax.experimental.shard_map import shard_map
-
-                inner = shard_map(
+                inner = jax.shard_map(
                     fn,
                     mesh=self.mesh,
                     in_specs=(P(), P(self.data_axis)),
                     out_specs=(P(), P()),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 inner_jit = jax.jit(inner, donate_argnums=donate)
             stepped = gc.instrument_step(
@@ -177,8 +175,16 @@ class Strategy:
         else:
             rep = mesh_lib.replicated(self.mesh)
             data = NamedSharding(self.mesh, P(self.data_axis))
+
+            def partitioned(state, batch):
+                # Traced under the region marker so ops GSPMD cannot
+                # partition (the Pallas attention kernels) split
+                # themselves over the batch — mesh_lib.per_shard.
+                with mesh_lib.gspmd_data_parallel(self.mesh, self.data_axis):
+                    return fn(state, batch)
+
             stepped = jax.jit(
-                fn,
+                partitioned,
                 in_shardings=(rep, data),
                 out_shardings=(rep, rep),
                 donate_argnums=donate,
@@ -197,8 +203,6 @@ class Strategy:
         ZeRO-1/2 persistent MomentShards buffers): the specs come from
         ``spec_fn`` on the actual state at first call and re-derive per
         state structure/shape signature."""
-        from jax.experimental.shard_map import shard_map
-
         compiled: dict[Any, Callable[..., Any]] = {}
 
         def run(state, batch):
@@ -209,12 +213,12 @@ class Strategy:
             exe = compiled.get(key)
             if exe is None:
                 specs = spec_fn(state)
-                inner = shard_map(
+                inner = jax.shard_map(
                     fn,
                     mesh=self.mesh,
                     in_specs=(specs, P(self.data_axis)),
                     out_specs=(specs, P()),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 exe = compiled[key] = jax.jit(inner, donate_argnums=donate)
             return exe(state, batch)

@@ -26,7 +26,7 @@ import functools
 from typing import Any
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -201,7 +201,7 @@ def _compiled(model, mesh, tp_axis, batch_axis, kw_items, draft_model=None):
             return shard_map(
                 run, mesh=mesh,
                 in_specs=(tp_param_specs(params, tp_axis), data_spec, P()),
-                out_specs=data_spec, check_rep=False,
+                out_specs=data_spec, check_vma=False,
             )(params, prompt, rng)
 
     else:
@@ -221,7 +221,7 @@ def _compiled(model, mesh, tp_axis, batch_axis, kw_items, draft_model=None):
                     data_spec,
                     P(),
                 ),
-                out_specs=data_spec, check_rep=False,
+                out_specs=data_spec, check_vma=False,
             )(params, draft_params, prompt, rng)
 
     return jax.jit(mapped)

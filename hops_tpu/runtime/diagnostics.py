@@ -184,29 +184,36 @@ def deterministic_mode(seed: int = 0) -> Iterator[jax.Array]:
 # -- roofline analysis over profiler traces ----------------------------------
 
 #: Peak specs per TPU generation for roofline bounds (bf16 matmul
-#: TFLOP/s, HBM GB/s). v5e figures are the published 197/819; other
-#: rows are fallbacks so the report still renders off-TPU.
+#: FLOP/s, HBM bytes/s), keyed by a substring of ``device_kind``. v5e
+#: (a v5e chip reports ``TPU v5 lite``) is the published 197 TFLOP/s /
+#: 819 GB/s (Google Cloud documentation, "TPU v5e"). A device that is
+#: not in the table is an error, not a default.
 _PEAKS = {
     "v5 lite": (197e12, 819e9),
     "v5e": (197e12, 819e9),
     "v5p": (459e12, 2765e9),
     "v4": (275e12, 1228e9),
-    "cpu": (1e12, 100e9),
 }
 
 
-def device_peaks(kind: str | None = None) -> tuple[float, float] | None:
+def device_peaks(kind: str | None = None) -> tuple[float, float]:
     """(bf16 matmul FLOP/s, HBM bytes/s) peaks for a device kind.
 
-    ``kind`` defaults to the local backend's ``device_kind``; returns
-    None when the generation isn't tabulated — callers must not guess
-    a roof (an MFU% against the wrong generation's peak overstates the
-    headline). Single source for every peak lookup (roofline_report,
-    bench.py --lm).
+    ``kind`` defaults to the local backend's ``device_kind``; raises
+    ``KeyError`` when the generation isn't tabulated — an MFU% or a
+    roofline share against a guessed roof is not a number. Single
+    source for every peak lookup (roofline_report, bench.py --lm).
     """
     if kind is None:
         kind = jax.devices()[0].device_kind
-    return next((v for k, v in _PEAKS.items() if k in kind.lower()), None)
+    for key, peaks in _PEAKS.items():
+        if key in kind.lower():
+            return peaks
+    raise KeyError(
+        f"no peak FLOP/s / HBM bandwidth tabulated for device kind {kind!r} "
+        f"(known: {sorted(_PEAKS)}); add its published peaks to "
+        "diagnostics._PEAKS"
+    )
 
 
 def _find_trace_file(trace_dir: str) -> str:
@@ -287,17 +294,10 @@ def roofline_report(
 
     if peak_flops is None or peak_bw is None:
         # The chrome trace doesn't record the device *kind*, only
-        # "/device:TPU:0" — so peaks come from the local backend. When
-        # analyzing a trace on a different machine (or an unknown chip),
-        # pass peak_flops/peak_bw explicitly.
+        # "/device:TPU:0" — so peaks come from the local backend (and an
+        # unknown kind raises). When analyzing a trace on a different
+        # machine, pass peak_flops/peak_bw explicitly.
         match = device_peaks()
-        if match is None:
-            log.warning(
-                "roofline_report: unknown device kind %r — using conservative "
-                "cpu peaks; pass peak_flops/peak_bw for a meaningful roofline",
-                jax.devices()[0].device_kind,
-            )
-            match = _PEAKS["cpu"]
         peak_flops, peak_bw = peak_flops or match[0], peak_bw or match[1]
 
     by_cat = collections.defaultdict(lambda: [0.0, 0.0, 0.0])

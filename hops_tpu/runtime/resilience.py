@@ -1,7 +1,7 @@
 """Self-healing primitives: retry policies, deadlines, circuit breakers.
 
-The platform's failure story so far was *avoidance* — relay locks that
-never SIGKILL, preemption guards that exit cleanly. This module is the
+The platform's failure story so far was *avoidance* — preemption
+guards that exit cleanly. This module is the
 *recovery* half the TF paper treats as table stakes for a platform
 (user-level checkpointing + automatic re-execution on transient
 failure) and the preemptible-pod reality of TPU slices assumes: I/O and

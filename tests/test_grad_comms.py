@@ -10,7 +10,7 @@ import numpy as np
 import optax
 import pytest
 from flax import linen as nn
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from hops_tpu.models import common
@@ -31,7 +31,7 @@ def _collective(fn, per_device, out_spec=P("data")):
     has one leading row per device."""
     mesh = mesh_lib.make_mesh({"data": N_DEV})
     g = shard_map(fn, mesh=mesh, in_specs=P("data"), out_specs=out_spec,
-                  check_rep=False)
+                  check_vma=False)
     return np.asarray(jax.jit(g)(jnp.asarray(per_device)))
 
 
@@ -311,7 +311,7 @@ def test_zero1_preserves_param_dtype_with_lower_precision_grads():
     mesh = mesh_lib.make_mesh({"data": N_DEV})
     step = shard_map(
         lambda s, g: gc.sharded_apply_gradients(s, g, axis_name="data"),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
     out = jax.jit(step)(state, grads_bf16)
     assert out.params["w"].dtype == jnp.float32  # not the grads dtype
     # And the value matches the replicated update on the same grads, up

@@ -1,9 +1,9 @@
 """Version-drift guard: every ``hops_tpu`` module must import cleanly.
 
 API drift in a pinned dependency used to surface as opaque pytest
-collection errors spanning nine test modules (``pltpu.CompilerParams``
-vs ``TPUCompilerParams``, ``jax.distributed.is_initialized`` absent in
-older JAX). Importing every module directly — one parametrized case
+collection errors spanning nine test modules (a renamed Pallas
+compiler-params class, ``jax.distributed.is_initialized`` absent in
+older JAX; the floor is now ``jax>=0.9``). Importing every module directly — one parametrized case
 per module, under the CPU backend — turns the next drift into one
 NAMED failure per module instead.
 

@@ -1517,7 +1517,7 @@ def test_engine_paged_int8_pool_capacity_at_equal_memory():
 def test_bench_lm_serving_smoke_e2e():
     """`bench.py --lm-serving --smoke` runs the Poisson-load serving
     tier end-to-end on the CPU tier and its JSON line carries the full
-    metric set the driver relays: tokens/s/chip, TTFT p50/p99, slot
+    metric set the driver reads: tokens/s/chip, TTFT p50/p99, slot
     occupancy, block-pool utilization, prefill-chunk and
     preempted-prefill counts, plus the dense same-memory baseline."""
     import os

@@ -440,99 +440,6 @@ def test_bench_input_pipeline_threaded_e2e():
     assert line["speedup_vs_sync"] >= 1.5
 
 
-def test_bench_probe_never_hangs_past_deadline_budget(monkeypatch):
-    """The BENCH_r04/r05 wedge, pinned at test timescale: a probe that
-    HANGS (the wedged-relay signature) must bounce off the per-attempt
-    deadline and return (None, 'probe_timeout', ...) within the retry
-    policy's budget — never block the driver open-endedly. The budgets
-    are probe_with_retry parameters precisely so this contract is
-    testable without a 6-minute test."""
-    import importlib.util
-    import time as _time
-
-    root = Path(__file__).resolve().parents[1]
-    spec = importlib.util.spec_from_file_location("_bench_probe", root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    def hung_probe(timeout_s=120):
-        _time.sleep(10)  # far past every budget below
-        return {"ok": True}
-
-    monkeypatch.setattr(bench, "probe_tpu", hung_probe)
-    t0 = _time.monotonic()
-    health, kind, err = bench.probe_with_retry(
-        attempt_deadline_s=0.3, probe_timeout_s=0.2,
-        total_timeout_s=1.0, base_delay_s=0.05,
-    )
-    elapsed = _time.monotonic() - t0
-    assert health is None
-    assert kind == "probe_timeout"
-    assert elapsed < 5.0, f"probe hung {elapsed:.1f}s past its budget"
-
-
-def test_bench_stale_fallback_never_chains_stale_lines(tmp_path, monkeypatch, capsys):
-    """Regression (emit_stale_or_fail): a logged line already flagged
-    ``"stale": true`` is a fallback re-emission, not a measurement —
-    scanning must skip it so provenance points at the last GENUINE
-    green result even when a stale re-emission was logged after it."""
-    import importlib.util
-
-    root = Path(__file__).resolve().parents[1]
-    spec = importlib.util.spec_from_file_location("_bench_mod", root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    metric = "resnet50_samples_per_sec_per_chip"
-    green = {"step": "resnet50_bench", "rc": 0, "ts": "t1",
-             "stdout": json.dumps({"metric": metric, "value": 10.0})}
-    chained = {"step": "resnet50_bench", "rc": 0, "ts": "t2",
-               "stdout": json.dumps({
-                   "metric": metric, "value": 9.0, "stale": True,
-                   "stale_reason": "older outage",
-                   "stale_artifact": "HW_MEASURE.jsonl step=resnet50_bench ts=t0"})}
-    log = tmp_path / "HW_MEASURE.jsonl"
-    log.write_text("\n".join(json.dumps(e) for e in (green, chained)) + "\n")
-    monkeypatch.setattr(bench, "HW_LOG", log)
-    with pytest.raises(SystemExit) as e:
-        bench.emit_stale_or_fail(metric, "relay wedged")
-    assert e.value.code == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] == 10.0  # the green measurement, not the re-emission
-    assert out["stale"] is True
-    assert out["stale_reason"] == "relay wedged"
-    assert "ts=t1" in out["stale_artifact"]
-
-
-def test_bench_stale_fallback_demotes_vs_baseline(tmp_path, monkeypatch, capsys):
-    """Regression (emit_stale_or_fail): the re-emitted line used to
-    carry the ORIGINAL run's ``vs_baseline`` under the live key, so a
-    consumer reading the round artifact saw an hours-old comparison
-    (e.g. 1.40x) as this round's number. The fallback must move it to
-    ``vs_baseline_stale``."""
-    import importlib.util
-
-    root = Path(__file__).resolve().parents[1]
-    spec = importlib.util.spec_from_file_location("_bench_mod2", root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    metric = "resnet50_samples_per_sec_per_chip"
-    green = {"step": "resnet50_bench", "rc": 0, "ts": "t1",
-             "stdout": json.dumps(
-                 {"metric": metric, "value": 10.0, "vs_baseline": 1.4})}
-    log = tmp_path / "HW_MEASURE.jsonl"
-    log.write_text(json.dumps(green) + "\n")
-    monkeypatch.setattr(bench, "HW_LOG", log)
-    with pytest.raises(SystemExit) as e:
-        bench.emit_stale_or_fail(metric, "relay wedged")
-    assert e.value.code == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "vs_baseline" not in out
-    assert out["vs_baseline_stale"] == 1.4
-    assert out["stale"] is True
-
-
 class TestCheckpointIntegration:
     def test_data_state_sidecar_roundtrip(self, tmp_path):
         from hops_tpu.runtime import checkpoint

@@ -92,7 +92,6 @@ def test_multi_process_collective_all_reduce(tmp_path, n_proc):
         subprocess.Popen(
             [
                 sys.executable, "-m", "hops_tpu.launch",
-                "--platform", "cpu",
                 "--coordinator", f"127.0.0.1:{port}",
                 "--num-processes", str(n_proc),
                 "--process-id", str(i),
@@ -139,7 +138,6 @@ def test_two_process_multihost_bench(tmp_path):
         subprocess.Popen(
             [
                 sys.executable, "-m", "hops_tpu.launch",
-                "--platform", "cpu",
                 "--coordinator", f"127.0.0.1:{port}",
                 "--num-processes", "2",
                 "--process-id", str(i),
@@ -249,7 +247,6 @@ def test_two_process_feeder_process_sharded(tmp_path):
         procs.append(subprocess.Popen(
             [
                 sys.executable, "-m", "hops_tpu.launch",
-                "--platform", "cpu",
                 "--coordinator", f"127.0.0.1:{port}",
                 "--num-processes", "2",
                 "--process-id", str(i),
@@ -353,7 +350,6 @@ def test_two_process_preemption_stops_both_at_same_step(tmp_path):
         subprocess.Popen(
             [
                 sys.executable, "-m", "hops_tpu.launch",
-                "--platform", "cpu",
                 "--coordinator", f"127.0.0.1:{port}",
                 "--num-processes", "2",
                 "--process-id", str(i),

@@ -308,9 +308,10 @@ def test_paged_decode_attention_zero_row_and_scratch_block():
     np.testing.assert_array_equal(np.asarray(clean)[1:], np.asarray(dirty)[1:])
 
 
-def test_paged_decode_attention_sub_sublane_page_falls_back():
-    """page % 8 != 0 can't tile on Mosaic: routes to the gathered XLA
-    reference (same contract as the dense kernel's odd-capacity path)."""
+def test_paged_decode_attention_off_tpu_takes_reference_twin():
+    """Off-TPU (interpret=None) every page size — even one no dtype
+    tiles — takes the gathered XLA reference; the compiled path raises
+    on it instead (tests/test_chip_path.py)."""
     from hops_tpu.ops.attention import (
         paged_decode_attention,
         paged_decode_attention_reference,
@@ -397,9 +398,9 @@ def test_paged_decode_q8_zero_row_and_scratch_block():
     np.testing.assert_array_equal(np.asarray(clean)[1:], np.asarray(dirty)[1:])
 
 
-def test_paged_decode_q8_sub_sublane_page_falls_back():
-    """page % 8 != 0 routes the quantized pool to the gathered
-    reference, same contract as fp."""
+def test_paged_decode_q8_off_tpu_takes_reference_twin():
+    """The quantized pool takes the gathered reference off-TPU for any
+    page size, same contract as fp."""
     from hops_tpu.ops.attention import (
         paged_decode_attention,
         paged_decode_attention_reference,
