@@ -2,10 +2,9 @@
 
 BENCHMARKS.md records 3.0 ms/token-step for the 45M-param LM at batch 8
 — far above the ~0.15 ms weight-streaming floor. This example measures
-it properly: times `generate()` end-to-end, then traces the run and
-prints the roofline category table plus the heaviest individual ops
-(`runtime.diagnostics.roofline_report` / `top_ops`), so the bound
-(HBM, small-op overhead, cache copies) is named, not guessed.
+it properly: times `generate()` end-to-end with the profiler off. For
+where the time goes, trace a benchmark cell (`benchmark/run.py --trace
+1`): its reduction reads the profiler's own `*.xplane.pb`.
 
 Usage: python examples/decode_bench.py [--batch 8] [--tokens 64]
 """
@@ -13,7 +12,6 @@ Usage: python examples/decode_bench.py [--batch 8] [--tokens 64]
 from __future__ import annotations
 
 import argparse
-import tempfile
 import time
 
 
@@ -81,7 +79,6 @@ def _dispatch(args, parser) -> None:
 
     from hops_tpu.models.generation import generate
     from hops_tpu.models.transformer import TransformerLM
-    from hops_tpu.runtime import diagnostics
 
     if args.offline and (args.spec_k or args.horizon > 1):
         # run_offline falls back to the ONLINE scheduler for
@@ -145,19 +142,6 @@ def _dispatch(args, parser) -> None:
         f"cache={args.kv_dtype}, kv_heads={args.kv_heads or 8}, "
         f"window={args.window})"
     )
-
-    trace_dir = tempfile.mkdtemp(prefix="decode_trace_")
-    with diagnostics.trace(trace_dir):
-        run()
-    # The trace covers prefill + all token steps; normalize per token.
-    report = diagnostics.roofline_report(trace_dir, steps=args.tokens)
-    diagnostics.print_roofline(report)
-    print("\nheaviest ops (per token-step):")
-    for r in diagnostics.top_ops(trace_dir, steps=args.tokens, n=12):
-        print(
-            f"{r['ms']:7.3f} ms  {r['tflops_per_s']:6.2f} TF/s {r['gb']:7.3f} GB  "
-            f"x{r['count']:4d} {r['category'][:18]:18s} {r['source'].split('/')[-1][:40]}"
-        )
 
 
 def _valid_sweep(args) -> None:

@@ -36,6 +36,7 @@ from hops_tpu.parallel.strategy import (
 )
 from hops_tpu.runtime import rundir
 from hops_tpu.runtime.logging import attach_run_log, detach_run_log, get_logger, scalarize
+from hops_tpu.telemetry import tracing
 from hops_tpu.telemetry.metrics import REGISTRY
 
 log = get_logger(__name__)
@@ -84,9 +85,16 @@ def _run_wrapper(
     """Shared launcher mechanics for all experiment kinds."""
     run = rundir.new_run(name=name, local_logdir=local_logdir)
     chief = multihost.is_chief()
+    # One trace per run, as a served request has one: the wrapper's
+    # Strategy spans (input placement, step dispatch) are its children,
+    # and the id in the registry record finds them at
+    # GET /debug/traces/<id>. A no-op span (id None) with tracing off.
+    root = tracing.start_trace("experiment.run", kind=kind, name=name)
+    trace_id = root.trace_id or None
     if chief:
         registry.register(
-            {"run_id": run.run_id, "name": name, "kind": kind, "status": "RUNNING"}
+            {"run_id": run.run_id, "name": name, "kind": kind,
+             "status": "RUNNING", "trace_id": trace_id}
         )
     start = time.time()
     out_path = Path(run.logdir) / "output.log"
@@ -98,7 +106,7 @@ def _run_wrapper(
         try:
             with contextlib.redirect_stdout(tee_out):
                 ctx = strategy.scope() if strategy is not None else contextlib.nullcontext()
-                with ctx:
+                with ctx, root:
                     result = fn(**kwargs) if kwargs else fn()
             metrics = _normalize_metrics(result, metric_key)
         except Exception as e:  # noqa: BLE001 — failures must land in the registry
@@ -137,6 +145,7 @@ def _run_wrapper(
                 "duration_s": time.time() - start,
                 "path": final_path,
                 "num_replicas": strategy.num_replicas_in_sync if strategy else 1,
+                "trace_id": trace_id,
             }
         )
     if err is not None:

@@ -17,6 +17,8 @@ import optax
 from flax import linen as nn
 from flax.training import train_state
 
+from hops_tpu.telemetry.spans import SCOPE_OPTIMIZER
+
 
 class TrainState(train_state.TrainState):
     """flax TrainState + dropout RNG folded per step."""
@@ -134,10 +136,11 @@ def make_train_step(
             return new_state, metrics
         # Replicated-params + sharded-batch shardings make XLA reduce
         # `grads` across the data axis here (AllReduce over ICI).
-        if has_bn:
-            new_state = state.apply_gradients(grads=grads, batch_stats=updates["batch_stats"])
-        else:
-            new_state = state.apply_gradients(grads=grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            if has_bn:
+                new_state = state.apply_gradients(grads=grads, batch_stats=updates["batch_stats"])
+            else:
+                new_state = state.apply_gradients(grads=grads)
         return new_state, {"loss": loss, "accuracy": accuracy(logits, batch["label"])}
 
     # Marker read by Strategy.step: a step that syncs its own gradients

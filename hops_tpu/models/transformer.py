@@ -638,6 +638,7 @@ def make_lm_train_step(
     import optax
 
     from hops_tpu.models.moe import sum_sown_losses
+    from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
 
     def train_step(state, batch):
         tokens = batch["tokens"]
@@ -660,14 +661,16 @@ def make_lm_train_step(
                     out, params["unembed"]["kernel"], targets, chunk=loss_chunk
                 )
             else:
-                loss = optax.softmax_cross_entropy_with_integer_labels(
-                    out, targets
-                ).mean()
+                with jax.named_scope(SCOPE_LM_HEAD_LOSS):
+                    loss = optax.softmax_cross_entropy_with_integer_labels(
+                        out, targets
+                    ).mean()
             aux = sum_sown_losses(mods)
             return loss + aux_loss_weight * aux, loss
 
         (_, loss), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)
-        state = state.apply_gradients(grads=grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            state = state.apply_gradients(grads=grads)
         return state, {"loss": loss, "perplexity": jnp.exp(loss)}
 
     return train_step

@@ -345,6 +345,7 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, int
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -393,6 +394,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, 
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -424,6 +426,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, 
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     return dq.reshape(shape), dk.reshape(k.shape), dv.reshape(v.shape)
@@ -852,6 +855,7 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((bh, q_rows, d), q.dtype),
         compiler_params=_DECODE_COMPILER_PARAMS,
         interpret=interpret,
+        name="dense_decode",
     )(vl, *args)
     return out[:, :rows].reshape(b, hkv, g, s, d).reshape(b, h, s, d)
 
@@ -1184,6 +1188,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((bh, q_rows, d), q.dtype),
         compiler_params=_DECODE_COMPILER_PARAMS,
         interpret=interpret,
+        name="paged_decode",
     )(vl, pages32, *args)
     return out[:, :rows].reshape(b, hkv, g, s, d).reshape(b, h, s, d)
 

@@ -27,7 +27,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS
 
+
+@jax.named_scope(SCOPE_LM_HEAD_LOSS)
 def chunked_softmax_xent(
     hidden: jax.Array,
     unembed: jax.Array,
@@ -45,6 +48,9 @@ def chunked_softmax_xent(
     (padded up to a multiple); each step's logits block, and therefore
     peak LM-head memory, is ``chunk x vocab`` fp32 — the full vocab
     axis is present per chunk, never sliced.
+
+    Traced under the ``lm_head_loss`` scope, so every device op of the
+    loss and of its backward carries that name in the profiler trace.
     """
     b, s, d = hidden.shape
     n = b * s

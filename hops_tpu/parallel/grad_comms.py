@@ -73,9 +73,11 @@ Telemetry (see docs/operations.md): counters
 ``hops_tpu_grad_comms_bytes_pre_total`` /
 ``hops_tpu_grad_comms_bytes_post_total`` (wire bytes per step before /
 after compression, labelled ``mode``), gauge
-``hops_tpu_grad_comms_compression_ratio``, and a
-``span("grad_comms.all_reduce")`` timing each step dispatch into
-``grad_comms_all_reduce_seconds``.
+``hops_tpu_grad_comms_compression_ratio``; the step's dispatch is timed
+by ``Strategy.step``'s ``hops_tpu_train_dispatch`` span (label
+``mode``). On the device every explicit collective here is traced under
+the ``grad_exchange`` scope and the update under ``optimizer``
+(``telemetry/spans.py:TRAIN_SCOPES``).
 """
 
 from __future__ import annotations
@@ -89,6 +91,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from hops_tpu.telemetry.spans import SCOPE_GRAD_EXCHANGE, SCOPE_OPTIMIZER
 
 #: Default bucket target: 4 MiB of gradient bytes per collective — big
 #: enough to amortize launch overhead, small enough to overlap.
@@ -313,6 +317,7 @@ def hier_groups(
     return intra, inter
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def hier_reduce_scatter(
     flat: jax.Array, axis_name: Any, hosts: int
 ) -> jax.Array:
@@ -350,6 +355,7 @@ def hier_reduce_scatter(
     return acc
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def hier_all_gather(
     shard: jax.Array, axis_name: Any, hosts: int
 ) -> jax.Array:
@@ -369,6 +375,7 @@ def hier_all_gather(
     )
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def psum_hierarchical(
     x: jax.Array,
     axis_name: Any,
@@ -398,6 +405,7 @@ def psum_hierarchical(
     return out
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def psum_quantized(
     x: jax.Array,
     axis_name: Any,
@@ -517,6 +525,7 @@ def unflatten_buckets(buffers: list[jax.Array], layout: BucketLayout) -> Any:
 # -- tree-level collectives ---------------------------------------------------
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def all_reduce_grads(
     grads: Any,
     axis_name: Any = "data",
@@ -610,15 +619,16 @@ def sharded_apply_gradients(
     #    every replica ends up with the mean-gradient slice it owns.
     gbufs, _ = flatten_buckets(grads, cfg.bucket_bytes, pad_multiple=n)
     gshards = []
-    for buf in gbufs:
-        if cfg.quantize and jnp.issubdtype(buf.dtype, jnp.floating):
-            buf = _wire(buf, cfg.block_size, cfg.qdtype)
-        if cfg.hierarchy:
-            shard = hier_reduce_scatter(buf, axis_name, cfg.hierarchy)
-        else:
-            shard = lax.psum_scatter(
-                buf, axis_name, scatter_dimension=0, tiled=True)
-        gshards.append(shard / n)
+    with jax.named_scope(SCOPE_GRAD_EXCHANGE):
+        for buf in gbufs:
+            if cfg.quantize and jnp.issubdtype(buf.dtype, jnp.floating):
+                buf = _wire(buf, cfg.block_size, cfg.qdtype)
+            if cfg.hierarchy:
+                shard = hier_reduce_scatter(buf, axis_name, cfg.hierarchy)
+            else:
+                shard = lax.psum_scatter(
+                    buf, axis_name, scatter_dimension=0, tiled=True)
+            gshards.append(shard / n)
 
     # 2-4. Sharded optimizer tail on the same per-dtype bucket layout.
     #    The params layout drives the unflatten: grads may arrive in a
@@ -659,6 +669,7 @@ def _overlap_psum_hook(axis_name: Any, cfg: GradCommsConfig) -> Callable[[Any], 
     def fwd(x):
         return x, None
 
+    @jax.named_scope(SCOPE_GRAD_EXCHANGE)
     def bwd(_, g):
         n = lax.psum(1, axis_name)
         if n == 1:
@@ -696,6 +707,7 @@ def _scatter_shard_hook(axis_name: Any, cfg: GradCommsConfig) -> Callable[[Any],
     def fwd(x):
         return x, None
 
+    @jax.named_scope(SCOPE_GRAD_EXCHANGE)
     def bwd(_, g):
         n = lax.psum(1, axis_name)
         if n == 1:
@@ -831,9 +843,10 @@ def _sharded_state_update(
     updates, new_opt_shard = state.tx.update(gshards, opt_state_shard, pshards)
     new_pshards = jax.tree.map(lambda p, u: p + u.astype(p.dtype), pshards, updates)
 
-    new_params = unflatten_buckets(
-        [lax.all_gather(s, axis_name, tiled=True) for s in new_pshards], playout
-    )
+    with jax.named_scope(SCOPE_GRAD_EXCHANGE):
+        gathered_params = [
+            lax.all_gather(s, axis_name, tiled=True) for s in new_pshards]
+    new_params = unflatten_buckets(gathered_params, playout)
     new_opt_vals = []
     for kind, vlayout, new_val in zip(
         opt_kind, opt_layouts, opt_def.flatten_up_to(new_opt_shard)
@@ -841,7 +854,9 @@ def _sharded_state_update(
         if kind == "persistent":
             new_opt_vals.append(MomentShards(new_val))
         elif kind == "replicated":
-            gathered = [lax.all_gather(s, axis_name, tiled=True) for s in new_val]
+            with jax.named_scope(SCOPE_GRAD_EXCHANGE):
+                gathered = [
+                    lax.all_gather(s, axis_name, tiled=True) for s in new_val]
             new_opt_vals.append(unflatten_buckets(gathered, vlayout))
         else:
             new_opt_vals.append(new_val)
@@ -1154,6 +1169,7 @@ def _make_zero3_state_cls():
     return _ZERO3_CLS
 
 
+@jax.named_scope(SCOPE_GRAD_EXCHANGE)
 def zero3_gather_params(shard_params: Any, meta: tuple, axis_name: Any) -> Any:
     """All-gather the flat shards back into dense param leaves — the
     on-demand materialization before forward/backward. Runs inside
@@ -1272,6 +1288,7 @@ def prepare_params(params: Any, config: GradCommsConfig, axis_name: Any,
     return params
 
 
+@jax.named_scope(SCOPE_OPTIMIZER)
 def apply_gradients(
     state: Any,
     grads: Any,
@@ -1336,13 +1353,12 @@ def instrument_step(
     steps_per_call: int = 1,
 ) -> Callable[..., Any]:
     """Wrap a compiled grad-comms step with telemetry: per-call pre/post
-    byte counters, the compression-ratio gauge, and a
-    ``span("grad_comms.all_reduce")`` around the dispatch (async
-    dispatch time, not device time — device time is the bench's job).
+    byte counters and the compression-ratio gauge (the dispatch itself
+    is timed by ``Strategy.step``'s ``hops_tpu_train_dispatch`` span).
     ``steps_per_call`` scales the byte counters for steps that fuse
     several optimizer updates per dispatch (``lax.scan`` loops — the
     ``grad_comms_steps`` attribute Strategy.step reads off the fn)."""
-    from hops_tpu.telemetry import REGISTRY, span
+    from hops_tpu.telemetry import REGISTRY
 
     mode = config.mode
     pre_c = REGISTRY.counter(
@@ -1368,7 +1384,7 @@ def instrument_step(
         pre_c.inc(pre * steps_per_call, mode=mode)
         post_c.inc(post * steps_per_call, mode=mode)
         ratio_g.set(pre / post if post else 1.0, mode=mode)
-        with span("grad_comms.all_reduce", mode=mode):
-            return step_fn(state, *args, **kwargs)
+        return step_fn(state, *args, **kwargs)
 
+    wrapped.lower = step_fn.lower  # Strategy.step's callables answer .lower
     return wrapped

@@ -30,8 +30,51 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hops_tpu.parallel import mesh as mesh_lib
+from hops_tpu.telemetry import tracing
+from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import (
+    SPAN_TRAIN_DISPATCH,
+    SPAN_TRAIN_INPUT_PUT,
+    span,
+)
 
 _current: list["Strategy"] = []
+
+#: ``mode`` label of the dispatch span when XLA inserts the gradient
+#: collectives itself (no ``grad_comms`` config, whose ``mode`` it is
+#: otherwise).
+_IMPLICIT_MODE = "implicit"
+
+_m_input_bytes = REGISTRY.counter(
+    "hops_tpu_train_input_bytes_total",
+    "Bytes of batch data Strategy.distribute_batch placed on the mesh",
+)
+
+
+class _TracedStep:
+    """What :meth:`Strategy.step` returns: the compiled step, each call
+    under ``span("hops_tpu_train_dispatch")`` (async dispatch time, not
+    device time) whose tracing span carries ``step`` = the index of the
+    call on this callable. Adds no device sync and returns what the
+    compiled step returns; every other attribute (``.lower(state,
+    batch)`` first of all) is the compiled step's own."""
+
+    def __init__(self, compiled: Callable[..., Any], mode: str):
+        self._compiled = compiled
+        self._mode = mode
+        self._calls = 0
+
+    def __call__(self, *args: Any, **kwargs: Any) -> Any:
+        index = self._calls
+        self._calls += 1
+        with span(SPAN_TRAIN_DISPATCH, mode=self._mode):
+            tracing.annotate(step=index)
+            return self._compiled(*args, **kwargs)
+
+    def __getattr__(self, name: str) -> Any:
+        if name == "_compiled":  # not set yet (copy/pickle probe a bare instance)
+            raise AttributeError(name)
+        return getattr(self._compiled, name)
 
 
 class Strategy:
@@ -77,7 +120,13 @@ class Strategy:
         return mesh_lib.replicate(self.mesh, tree)
 
     def distribute_batch(self, batch: Any) -> Any:
-        return mesh_lib.shard_batch(self.mesh, batch, self.data_axis)
+        """Place a host batch on the mesh, sharded on the data axis,
+        under ``span("hops_tpu_train_input_put")``; the bytes placed
+        count into ``hops_tpu_train_input_bytes_total``."""
+        with span(SPAN_TRAIN_INPUT_PUT):
+            placed = mesh_lib.shard_batch(self.mesh, batch, self.data_axis)
+        _m_input_bytes.inc(sum(x.nbytes for x in jax.tree.leaves(placed)))
+        return placed
 
     # -- execution ------------------------------------------------------------
 
@@ -161,11 +210,12 @@ class Strategy:
                     check_vma=False,
                 )
                 inner_jit = jax.jit(inner, donate_argnums=donate)
-            stepped = gc.instrument_step(
+            compiled = gc.instrument_step(
                 inner_jit,
                 cfg,
                 steps_per_call=getattr(fn, "grad_comms_steps", 1),
             )
+            mode = cfg.mode
         elif marker is not None:
             raise ValueError(
                 "fn was built with an explicit grad_comms config "
@@ -183,13 +233,14 @@ class Strategy:
                 with mesh_lib.gspmd_data_parallel(self.mesh, self.data_axis):
                     return fn(state, batch)
 
-            stepped = jax.jit(
+            compiled = jax.jit(
                 partitioned,
                 in_shardings=(rep, data),
                 out_shardings=(rep, rep),
                 donate_argnums=donate,
             )
-        self._step_cache[key] = stepped
+            mode = _IMPLICIT_MODE
+        stepped = self._step_cache[key] = _TracedStep(compiled, mode)
         return stepped
 
     def _lazy_spec_step(
@@ -205,7 +256,7 @@ class Strategy:
         state structure/shape signature."""
         compiled: dict[Any, Callable[..., Any]] = {}
 
-        def run(state, batch):
+        def exe_for(state):
             key = (
                 jax.tree.structure(state),
                 tuple(jax.numpy.shape(l) for l in jax.tree.leaves(state)),
@@ -221,8 +272,12 @@ class Strategy:
                     check_vma=False,
                 )
                 exe = compiled[key] = jax.jit(inner, donate_argnums=donate)
-            return exe(state, batch)
+            return exe
 
+        def run(state, batch):
+            return exe_for(state)(state, batch)
+
+        run.lower = lambda state, batch: exe_for(state).lower(state, batch)
         return run
 
     def run(self, fn: Callable[..., Any], state: Any, batch: Any) -> Any:
@@ -353,8 +408,9 @@ class ShardedStrategy(Strategy):
         key = (fn, donate_state, None)
         cached = self._step_cache.get(key)
         if cached is None:
-            cached = self._step_cache[key] = jax.jit(
-                fn, donate_argnums=(0,) if donate_state else ()
+            cached = self._step_cache[key] = _TracedStep(
+                jax.jit(fn, donate_argnums=(0,) if donate_state else ()),
+                _IMPLICIT_MODE,
             )
         return cached
 

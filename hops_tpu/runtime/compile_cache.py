@@ -64,6 +64,14 @@ def enable() -> str | None:
     # The engine's programs are many and individually quick to compile;
     # the default 1 s floor would leave most of them out of the cache.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # By default the cache key strips an instruction's location, which is
+    # where its op_name (the jax.named_scope path) lives: a program whose
+    # scopes changed would load an executable with the old names, and the
+    # profiler would show a training step without ``optimizer`` or
+    # ``lm_head_loss`` (telemetry/spans.py:TRAIN_SCOPES). With the
+    # metadata in the key, an edit that moves traced lines costs one
+    # compile; the names in a trace are always the running source's.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     with _lock:
         if not _listening:
             jax.monitoring.register_event_listener(_on_event)

@@ -202,7 +202,7 @@ def device_peaks(kind: str | None = None) -> tuple[float, float]:
     ``kind`` defaults to the local backend's ``device_kind``; raises
     ``KeyError`` when the generation isn't tabulated — an MFU% or a
     roofline share against a guessed roof is not a number. Single
-    source for every peak lookup (roofline_report, bench.py --lm).
+    source for the program's peak lookups (bench.py --lm).
     """
     if kind is None:
         kind = jax.devices()[0].device_kind
@@ -214,169 +214,3 @@ def device_peaks(kind: str | None = None) -> tuple[float, float]:
         f"(known: {sorted(_PEAKS)}); add its published peaks to "
         "diagnostics._PEAKS"
     )
-
-
-def _find_trace_file(trace_dir: str) -> str:
-    import glob
-
-    files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"), recursive=True))
-    if not files:
-        raise FileNotFoundError(f"no *.trace.json.gz under {trace_dir}")
-    return files[-1]
-
-
-def _device_op_rows(trace_dir: str) -> tuple[str, list[dict]]:
-    """Parse a :func:`trace` capture into per-op rows for ONE device pid.
-
-    Shared by :func:`roofline_report` and :func:`top_ops` so the
-    load-bearing filters live in one place: one device pid only (in
-    SPMD every chip runs the same program — summing all pids would
-    multiply time and bytes by the chip count), program envelopes
-    (``jit_fn(...)``, bare step numbers) skipped, and the ``*-start``
-    halves of async pairs skipped (bytes live on the ``-done`` event).
-    """
-    import gzip
-    import json
-    import re
-
-    with gzip.open(_find_trace_file(trace_dir)) as f:
-        events = json.load(f)["traceEvents"]
-    pid_names = {
-        e["pid"]: e["args"].get("name", "")
-        for e in events
-        if e.get("ph") == "M" and e.get("name") == "process_name"
-    }
-    device_pids = set(sorted(p for p, n in pid_names.items() if "TPU" in n or "GPU" in n)[:1])
-    device_name = next((pid_names[p] for p in device_pids), "")
-
-    per_op: dict[str, dict] = {}
-    for e in events:
-        args = e.get("args") or {}
-        if e.get("ph") != "X" or e["pid"] not in device_pids or "device_duration_ps" not in args:
-            continue
-        if re.match(r"^(jit_|\d+$)", e["name"]) or e["name"].split(".")[0].endswith("-start"):
-            continue
-        row = per_op.setdefault(
-            e["name"],
-            {"name": e["name"], "category": args.get("hlo_category", e["name"]),
-             "s": 0.0, "flops": 0.0, "bytes": 0.0,
-             "source": args.get("source", "?"), "count": 0},
-        )
-        row["s"] += int(args["device_duration_ps"]) / 1e12
-        row["flops"] += float(args.get("model_flops", 0) or 0)
-        row["bytes"] += float(args.get("raw_bytes_accessed", 0) or 0)
-        row["count"] += 1
-    return device_name, list(per_op.values())
-
-
-def roofline_report(
-    trace_dir: str,
-    peak_flops: float | None = None,
-    peak_bw: float | None = None,
-    steps: int = 1,
-) -> dict:
-    """Aggregate a :func:`trace` capture into a per-HLO-category roofline.
-
-    Reads the Chrome-trace export ``jax.profiler`` writes, sums device
-    op time / model FLOPs / bytes accessed by ``hlo_category``, and for
-    each category reports achieved FLOP/s and bytes/s against the
-    chip's compute and HBM roofs — the analysis the reference's
-    TensorBoard profiler window left to the reader (SURVEY.md §5).
-
-    Returns ``{"total_ms", "device": str, "categories": [{name, ms,
-    tflops_per_s, gb_per_s, gb, bound, roofline_ms}, ...]}`` where
-    ``bound`` is which roof the category sits under and ``roofline_ms``
-    is the best-case time at 100% of that roof.
-    """
-    import collections
-
-    device_name, rows = _device_op_rows(trace_dir)
-
-    if peak_flops is None or peak_bw is None:
-        # The chrome trace doesn't record the device *kind*, only
-        # "/device:TPU:0" — so peaks come from the local backend (and an
-        # unknown kind raises). When analyzing a trace on a different
-        # machine, pass peak_flops/peak_bw explicitly.
-        match = device_peaks()
-        peak_flops, peak_bw = peak_flops or match[0], peak_bw or match[1]
-
-    by_cat = collections.defaultdict(lambda: [0.0, 0.0, 0.0])
-    for r in rows:
-        agg = by_cat[r["category"]]
-        agg[0] += r["s"]
-        agg[1] += r["flops"]
-        agg[2] += r["bytes"]
-
-    categories = []
-    for cat, (dur, fl, by) in sorted(by_cat.items(), key=lambda kv: -kv[1][0]):
-        if dur <= 0:
-            continue
-        flop_bound, byte_bound = fl / peak_flops, by / peak_bw
-        categories.append(
-            {
-                "name": cat,
-                "ms": dur * 1e3,
-                "tflops_per_s": fl / dur / 1e12,
-                "gb_per_s": by / dur / 1e9,
-                "gb": by / 1e9,
-                "bound": "compute" if flop_bound >= byte_bound else "memory",
-                "roofline_ms": max(flop_bound, byte_bound) * 1e3,
-            }
-        )
-    for c in categories:
-        for k in ("ms", "gb", "roofline_ms"):
-            c[k] /= steps
-    total = sum(c["ms"] for c in categories)
-    ideal = sum(c["roofline_ms"] for c in categories)
-    return {
-        "steps": steps,
-        "total_ms": total,
-        "roofline_ms": ideal,
-        "roofline_fraction": ideal / total if total else 0.0,
-        "device": device_name,
-        "peak_tflops": peak_flops / 1e12,
-        "peak_gbps": peak_bw / 1e9,
-        "categories": categories,
-    }
-
-
-def print_roofline(report: dict) -> None:
-    """Render :func:`roofline_report` as the table BENCHMARKS.md carries."""
-    print(
-        f"device {report['device']}  roofs: {report['peak_tflops']:.0f} TFLOP/s, "
-        f"{report['peak_gbps']:.0f} GB/s"
-    )
-    print(f"{'category':26s}{'ms':>9s}{'TFLOP/s':>9s}{'GB/s':>7s}{'GB':>7s}  bound  best-case ms")
-    for c in report["categories"]:
-        print(
-            f"{c['name']:26s}{c['ms']:9.2f}{c['tflops_per_s']:9.1f}{c['gb_per_s']:7.0f}"
-            f"{c['gb']:7.2f}  {c['bound']:6s}{c['roofline_ms']:10.2f}"
-        )
-    print(
-        f"total {report['total_ms']:.1f} ms vs roofline best-case {report['roofline_ms']:.1f} ms "
-        f"-> running at {report['roofline_fraction'] * 100:.0f}% of the roofline bound"
-    )
-
-
-def top_ops(trace_dir: str, steps: int = 1, n: int = 15) -> list[dict]:
-    """Per-op (not per-category) view of a :func:`trace` capture: the n
-    heaviest device ops with duration, FLOP/s, bytes and source line —
-    for pinpointing which op a bound category's time lives in.
-    Durations/bytes are divided by ``steps``."""
-    _, rows = _device_op_rows(trace_dir)
-    out = sorted(rows, key=lambda r: -r["s"])[:n]
-    result = []
-    for r in out:
-        ms = r["s"] * 1e3 / steps
-        result.append(
-            {
-                "name": r["name"],
-                "category": r["category"],
-                "source": r["source"],
-                "count": r["count"],
-                "ms": ms,
-                "gb": r["bytes"] / 1e9 / steps,
-                "tflops_per_s": (r["flops"] / steps) / max(ms / 1e3, 1e-12) / 1e12,
-            }
-        )
-    return result

@@ -36,6 +36,24 @@ _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
 HEARTBEAT_GAUGE = "hops_tpu_heartbeat_time"
 HEARTBEAT_MONO_GAUGE = "hops_tpu_heartbeat_monotonic"
 
+#: The training step's device vocabulary: ``jax.named_scope`` names that
+#: reach the device trace in every op's ``tf_op`` (forward and backward).
+#: The first four are Flax module names the models already enter; the
+#: last three are entered inside ``ops/xent.py``, the step factories and
+#: ``parallel/grad_comms.py``. Readers outside the program (the
+#: benchmark's ``harness/trace_scopes.py``) repeat these strings.
+TRAIN_SCOPES = (
+    "attn", "mlp", "embed", "final_norm",
+    "lm_head_loss", "optimizer", "grad_exchange",
+)
+SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER, SCOPE_GRAD_EXCHANGE = TRAIN_SCOPES[4:]
+
+#: The training step's host vocabulary: :func:`span` names entered by
+#: ``Strategy.distribute_batch`` and by every ``Strategy.step`` callable,
+#: children of the launcher's ``experiment.run`` root span.
+SPAN_TRAIN_INPUT_PUT = "hops_tpu_train_input_put"
+SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
+
 
 def _sanitize(name: str) -> str:
     return _NAME_RE.sub("_", name)

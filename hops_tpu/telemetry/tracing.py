@@ -17,7 +17,9 @@ Design constraints, in order:
 - **Stdlib-only.** Spans are recorded from processes that must never
   touch JAX (serving hosts, the fleet router).
 - **Bounded memory.** Finished spans land in a ring
-  (:class:`Tracer`, default 512 spans); old traces fall off the back.
+  (:class:`Tracer`, default 4096 spans: a training run records two per
+  step, so 512 held 13 s of a 50 ms step); old traces fall
+  off the back.
   ``GET /debug/traces`` (telemetry/export.py) serves the ring.
 
 Context is carried on a :mod:`contextvars` ContextVar, so every handler
@@ -229,6 +231,9 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: Default capacity of the span ring.
+DEFAULT_RING_SIZE = 4096
+
 #: The active span of the current (thread/task) context. Handler
 #: threads each see their own request; worker threads see None unless
 #: they adopted a context via :func:`use_context`.
@@ -242,7 +247,7 @@ class Tracer:
     spans. One process-global :data:`TRACER` serves the stack; tests
     may build private ones."""
 
-    def __init__(self, ring_size: int = 512, sample_rate: float = 1.0,
+    def __init__(self, ring_size: int = DEFAULT_RING_SIZE, sample_rate: float = 1.0,
                  seed: int | None = None):
         if not 0.0 <= sample_rate <= 1.0:
             raise ValueError(f"sample_rate must be in [0,1], got {sample_rate}")
@@ -327,7 +332,7 @@ _ENABLED = os.environ.get("HOPS_TPU_TRACING", "1") not in ("0", "false", "")
 
 #: The process-global tracer (ring + sampling decision).
 TRACER = Tracer(
-    ring_size=int(_env_float("HOPS_TPU_TRACE_RING", 512)),
+    ring_size=int(_env_float("HOPS_TPU_TRACE_RING", DEFAULT_RING_SIZE)),
     sample_rate=_env_float("HOPS_TPU_TRACE_SAMPLE", 1.0),
 )
 
@@ -388,6 +393,7 @@ def current_trace_id() -> str | None:
 
 def start_trace(
     name: str,
+    /,
     headers: Any = None,
     parent: TraceContext | None = None,
     force_sample: bool = False,

@@ -664,8 +664,8 @@ def test_wire_bytes_and_telemetry_compression_ratio():
     assert REGISTRY.counter(
         "hops_tpu_grad_comms_bytes_pre_total", labels=("mode",)
     ).value(mode="quantized") > pre_c
-    hist = REGISTRY.histogram("grad_comms_all_reduce_seconds", labels=("mode",))
-    assert any(v > 0 for _, _, v in hist.samples())
+    hist = REGISTRY.histogram("hops_tpu_train_dispatch_seconds", labels=("mode",))
+    assert any(v > 0 for _, labels, v in hist.samples() if labels.get("mode") == "quantized")
 
 
 # -- hierarchy-aware collectives ----------------------------------------------

@@ -216,60 +216,3 @@ class TestMetricLogger:
         ml.close()
         events = htlog.read_metrics(tmp_path / "m.jsonl")
         assert [e["value"] for e in events] == [1.5, 0.5]
-
-
-class TestRoofline:
-    def test_roofline_report_parses_synthetic_trace(self, tmp_path):
-        import gzip, json
-        from hops_tpu.runtime.diagnostics import roofline_report, print_roofline
-
-        d = tmp_path / "plugins" / "profile" / "2026_01_01"
-        d.mkdir(parents=True)
-        events = [
-            {"ph": "M", "pid": 3, "name": "process_name", "args": {"name": "/device:TPU:0"}},
-            # program envelope + step number must be excluded
-            {"ph": "X", "pid": 3, "name": "jit_step(123)", "dur": 99,
-             "args": {"device_duration_ps": int(99e9)}},
-            {"ph": "X", "pid": 3, "name": "0", "dur": 99,
-             "args": {"device_duration_ps": int(99e9)}},
-            # 10 ms per occurrence (ps), one occurrence per step
-            {"ph": "X", "pid": 3, "name": "fusion.1", "dur": 10,
-             "args": {"device_duration_ps": int(1e10), "hlo_category": "convolution fusion",
-                      "model_flops": 2e9, "raw_bytes_accessed": 8e6}},
-            {"ph": "X", "pid": 3, "name": "fusion.1", "dur": 10,
-             "args": {"device_duration_ps": int(1e10), "hlo_category": "convolution fusion",
-                      "model_flops": 2e9, "raw_bytes_accessed": 8e6}},
-            {"ph": "X", "pid": 3, "name": "copy-start.2", "dur": 1,
-             "args": {"device_duration_ps": int(1e9), "hlo_category": "copy-start",
-                      "raw_bytes_accessed": 4e6}},
-        ]
-        with gzip.open(d / "host.trace.json.gz", "wt") as f:
-            json.dump({"traceEvents": events}, f)
-
-        r = roofline_report(str(tmp_path), peak_flops=200e12, peak_bw=800e9, steps=2)
-        assert [c["name"] for c in r["categories"]] == ["convolution fusion"]
-        conv = r["categories"][0]
-        assert conv["ms"] == pytest.approx(10.0)  # 10 ms per step
-        assert conv["tflops_per_s"] == pytest.approx(4e9 / 0.02 / 1e12)  # total fl / total dur
-        assert conv["bound"] == "compute"
-        print_roofline(r)  # must not raise
-
-    def test_top_ops_lists_heaviest_with_source(self, tmp_path):
-        import gzip, json
-        from hops_tpu.runtime.diagnostics import top_ops
-
-        d = tmp_path / "plugins" / "profile" / "x"
-        d.mkdir(parents=True)
-        events = [
-            {"ph": "M", "pid": 3, "name": "process_name", "args": {"name": "/device:TPU:0"}},
-            {"ph": "X", "pid": 3, "name": "fusion.9", "dur": 1,
-             "args": {"device_duration_ps": int(2e10), "hlo_category": "loop fusion",
-                      "model_flops": 1e9, "raw_bytes_accessed": 4e9, "source": "a.py:7"}},
-            {"ph": "X", "pid": 3, "name": "copy.1", "dur": 1,
-             "args": {"device_duration_ps": int(1e9), "hlo_category": "copy"}},
-        ]
-        with gzip.open(d / "h.trace.json.gz", "wt") as f:
-            json.dump({"traceEvents": events}, f)
-        rows = top_ops(str(tmp_path), steps=2, n=5)
-        assert rows[0]["name"] == "fusion.9" and rows[0]["ms"] == pytest.approx(10.0)
-        assert rows[0]["gb"] == pytest.approx(2.0) and rows[0]["source"] == "a.py:7"
