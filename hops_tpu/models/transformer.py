@@ -174,7 +174,8 @@ class Attention(nn.Module):
             o = per_shard(
                 functools.partial(
                     flash_attention, causal=True, window=self.window
-                )
+                ),
+                op="flash",
             )(q, k, v)
         elif self.attention_impl == "reference":
             o = attention_reference(q, k, v, causal=True, window=self.window)

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hops_tpu.runtime import devices as rt_devices
+from hops_tpu.telemetry.metrics import REGISTRY
 
 # Sub-slice scoping: the trial driver partitions the slice into disjoint
 # device groups (1 chip, 2 chips, 2x2, ...) and enters a device_scope
@@ -162,6 +163,12 @@ def global_mesh(axis_names: Sequence[str] = ("data",)) -> Mesh:
 # XLA cannot partition can split itself over the batch (see per_shard).
 _gspmd = threading.local()
 
+_m_per_shard_traces = REGISTRY.counter(
+    "hops_tpu_train_per_shard_traces_total",
+    "Ops per_shard put under shard_map while a GSPMD step was traced",
+    labels=("op",),
+)
+
 
 @contextlib.contextmanager
 def gspmd_data_parallel(mesh: Mesh, axis: str | tuple[str, ...]):
@@ -175,27 +182,41 @@ def gspmd_data_parallel(mesh: Mesh, axis: str | tuple[str, ...]):
         _gspmd.region = prev
 
 
-def per_shard(fn: Any) -> Any:
+def per_shard(fn: Any, *, op: str = "unnamed", replicated: Sequence[int] = ()) -> Any:
     """``fn`` over batch-leading arrays, run per device shard when traced
     inside a multi-device :func:`gspmd_data_parallel` region.
 
-    A Mosaic ``pallas_call`` is a custom call GSPMD cannot partition
-    ("Mosaic kernels cannot be automatically partitioned" at lowering),
-    so a batch-parallel kernel inside a plain sharded ``jax.jit`` goes
-    under ``shard_map`` over the data axis — every array argument and
-    the result shard on their leading (batch) dim, other mesh axes
-    replicate. Outside such a region (one device, or a step already
-    inside ``shard_map``) this is ``fn`` itself.
+    Two kinds of op need it inside a plain sharded ``jax.jit``. A Mosaic
+    ``pallas_call`` is a custom call GSPMD cannot partition ("Mosaic
+    kernels cannot be automatically partitioned" at lowering). A
+    ``lax.scan`` over an axis made from the sharded batch (the chunk
+    loop of ``ops/xent.py``) is partitioned, but badly: the body's
+    dynamic slice cannot stay sharded, so GSPMD all-gathers the scanned
+    array inside the loop and every chip computes the whole batch. Under
+    ``shard_map`` over the data axis every array argument and the result
+    shard on their leading (batch) dim, except the arguments at the
+    positions in ``replicated`` (weights), which enter whole; a
+    replicated argument's cotangent is summed across the axis once, at
+    the ``shard_map`` boundary. Other mesh axes replicate. A reduction
+    over the batch returns its per-shard partial with a leading dim of 1
+    and the caller adds the ``n_shards`` values. Outside such a region
+    (one device, or a step already inside ``shard_map``) this is ``fn``
+    itself.
+
+    Each wrap counts one in ``hops_tpu_train_per_shard_traces_total{op}``:
+    trace-time proof that the per-shard path engaged.
     """
     region = getattr(_gspmd, "region", None)
     if region is None or region[0].size == 1:
         return fn
     mesh, axis = region
-    spec = P(axis)
 
     def sharded(*arrays):
+        _m_per_shard_traces.inc(op=op)
+        in_specs = tuple(
+            P() if i in replicated else P(axis) for i in range(len(arrays)))
         return jax.shard_map(
-            fn, mesh=mesh, in_specs=(spec,) * len(arrays), out_specs=spec,
+            fn, mesh=mesh, in_specs=in_specs, out_specs=P(axis),
             check_vma=False,
         )(*arrays)
 
