@@ -1,54 +1,177 @@
-"""Mixture-of-Experts layer with expert parallelism over an ``expert`` axis.
+"""Mixture-of-Experts layer: dropless top-k routing as sorted grouped matmuls.
 
 Beyond-reference capability (the reference shards nothing, SURVEY.md
 §2.9 row 5) rounding out the parallelism families: dp (data), tp
-(model), sp (seq — ring attention), fsdp, and here **ep**. Design is
-the TPU-standard dense-dispatch MoE:
+(model), sp (seq — ring attention), fsdp, and here **ep**. One routing
+implementation, the one large open MoEs need (OLMoE-1B-7B: 64 experts,
+top-8, no capacity):
 
-- router: softmax top-k over expert logits, tokens weighted by router
-  probability;
-- dispatch/combine as einsums against a one-hot dispatch mask — dense
-  compute, static shapes, no sorting/gather, exactly what the MXU and
-  XLA's GSPMD partitioner want;
-- capacity factor bounds per-expert work; overflow tokens drop (their
-  residual path still carries them);
-- with a mesh, expert weights shard ``P("expert")`` on the leading
-  (num_experts) dim and the per-expert matmuls partition across the
-  axis — XLA inserts the all-to-alls.
+- router: float32 logits, softmax, the ``top_k`` largest probabilities
+  per token; the weights are the probabilities themselves, renormalised
+  over the chosen experts only when ``norm_topk_prob`` says so;
+- dispatch: the ``tokens x top_k`` (token, slot) rows are ordered by
+  expert with a stable sort and gathered — no capacity, no dropped
+  token, no ``(tokens, experts, capacity)`` mask; shapes are static
+  (always ``tokens x top_k`` rows), so one compile whatever the routing;
+- experts: SwiGLU, three grouped matmuls over the ragged groups
+  (``ops/grouped_matmul.py``: a Pallas kernel named ``moe_gmm`` on the
+  TPU, ``jax.lax.ragged_dot`` elsewhere; only the routed work is done);
+- combine: rows go back to token order and each token's ``top_k`` rows
+  are summed. Dispatch and combine are each other's transposes and both
+  run as gathers (by the sort order and by its inverse), forward and
+  backward: no scatter-add anywhere.
+
+Inside ``Strategy.step`` on a data mesh the routing runs per device
+shard (``parallel.mesh.per_shard``): each chip sorts its own tokens
+through all experts, the expert weights enter whole. With a mesh
+carrying an ``expert`` axis, expert weights placed ``P("expert")`` (see
+:func:`expert_specs`) are partitioned by GSPMD.
 
 ``MoEBlock`` slots into ``TransformerLM`` as a drop-in MLP replacement.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from hops_tpu.ops.grouped_matmul import grouped_matmul, implementation
+from hops_tpu.parallel.mesh import per_shard
+from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP
+
+SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_COMBINE = MOE_SCOPES
+#: names of the expert-stacked weights, leading dim ``num_experts``
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+_m_moe_traces = REGISTRY.counter(
+    "hops_tpu_train_moe_traces_total",
+    "Routed feed-forward layers traced, by the grouped matmul they hold",
+    labels=("impl",),
+)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_sorted(x, order, inverse, k):
+    """Row ``r`` of the result is row ``order[r] // k`` of ``x``: every
+    token's row once per slot, in sorted order. Its transpose is
+    :func:`_from_sorted`, so the backward pass is a gather too."""
+    return x[order // k]
+
+
+def _to_sorted_fwd(x, order, inverse, k):
+    return _to_sorted(x, order, inverse, k), (order, inverse)
+
+
+def _to_sorted_bwd(k, res, g):
+    order, inverse = res
+    return _from_sorted(g, order, inverse, k), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _from_sorted(rows, order, inverse, k):
+    """Sorted rows back in (token, slot) order, the ``k`` slots of each
+    token summed in float32."""
+    back = rows[inverse].reshape(-1, k, *rows.shape[1:])
+    return back.astype(jnp.float32).sum(1).astype(rows.dtype)
+
+
+def _from_sorted_fwd(rows, order, inverse, k):
+    return _from_sorted(rows, order, inverse, k), (order, inverse)
+
+
+def _from_sorted_bwd(k, res, g):
+    order, inverse = res
+    return _to_sorted(g, order, inverse, k), None, None
+
+
+_to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
+_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+
+
+def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, first=0):
+    """The dropless expert pass over one shard's tokens.
+
+    ``x`` (b, s, d); ``top_p``/``top_ids`` (b, s, k) the chosen experts'
+    weights and ids; ``w_*`` the stacks of the ``len(w_gate)`` experts
+    from id ``first`` on (all of them unless the caller holds a slice).
+    Returns ``(out (b, s, d), rows per expert (1, num_experts))``; the
+    leading 1 is the batch-leading partial ``per_shard`` stacks.
+    """
+    b, s, d = x.shape
+    k = top_ids.shape[-1]
+    n_rows = b * s * k
+    n_local = w_gate.shape[0]
+    flat_ids = top_ids.reshape(n_rows)
+    with jax.named_scope(SCOPE_DISPATCH):
+        # held experts first, in id order: their rows are the leading
+        # sum(local sizes) rows whatever slice of the experts is held
+        order = jnp.argsort((flat_ids - first) % num_experts, stable=True)
+        inverse = jnp.argsort(order)
+        sizes = jnp.sum(flat_ids[:, None] == jnp.arange(num_experts)[None, :], axis=0, dtype=jnp.int32)
+        local_sizes = jax.lax.dynamic_slice_in_dim(sizes, first, n_local)
+
+        here = None if n_local == num_experts else jnp.arange(n_rows) < jnp.sum(local_sizes)
+
+        def held(rows):
+            """Rows of experts held elsewhere: the grouped matmul leaves
+            them unspecified, so they enter and leave every call as 0."""
+            if here is None:
+                return rows
+            return jnp.where(here.reshape(-1, *[1] * (rows.ndim - 1)), rows, 0)
+
+        rows = held(_to_sorted(x.reshape(b * s, d), order, inverse, k))
+        weight = held(_to_sorted(top_p.reshape(n_rows), order, inverse, 1))
+    with jax.named_scope(SCOPE_EXPERTS):
+        gate = held(grouped_matmul(rows, w_gate, local_sizes)).astype(jnp.float32)
+        up = held(grouped_matmul(rows, w_up, local_sizes)).astype(jnp.float32)
+        # p_e * W_down(h) == W_down(p_e * h): the weighting rides the
+        # activation's fusion at the experts' width, not the model's;
+        # float32 inside the fusion, one rounding on the way out
+        act = (nn.silu(gate) * up * weight[:, None]).astype(rows.dtype)
+        out_rows = held(grouped_matmul(act, w_down, local_sizes))
+    with jax.named_scope(SCOPE_COMBINE):
+        out = _from_sorted(out_rows, order, inverse, k)
+    return out.reshape(b, s, d), sizes[None]
+
 
 class MoEMLP(nn.Module):
-    """Top-k routed expert FFN over ``(batch, seq, d_model)``.
+    """Top-k routed SwiGLU expert FFN over ``(batch, seq, d_model)``.
+
+    ``expert_hidden`` is one expert's width (default: ``d_model x
+    hidden_mult`` rounded down to a multiple of 128); ``norm_topk_prob``
+    renormalises the chosen probabilities to sum to 1 (OLMoE publishes
+    False: the weights are the softmax probabilities themselves).
+
+    Sown for the train step: ``losses/moe_aux`` (Switch load balancing,
+    ``E * sum_e f_e * P_e``), ``losses/moe_router_z`` (mean squared
+    log-sum-exp of the router logits) and, when the caller makes
+    ``moe_stats`` mutable, ``moe_stats/rows_per_expert`` (E,) and
+    ``moe_stats/expert_ids`` (batch, seq, top_k).
 
     Two expert-parallel modes:
 
     - GSPMD (default): params are full ``(num_experts, ...)`` arrays and
       ep comes from placing them ``P("expert", ...)`` (see
-      :func:`expert_specs`) — XLA partitions the einsums.
+      :func:`expert_specs`) — XLA partitions the grouped matmuls.
     - Explicit (``expert_axis`` set): for use under an ENCLOSING
       ``shard_map`` that carries an ``expert``-named mesh axis (ep
       inside pipeline stages). Params hold only the local
       ``num_experts // expert_shards`` experts; routing still spans all
       ``num_experts`` (the router is replicated), each device computes
-      its local experts' contribution and a ``psum`` over
-      ``expert_axis`` combines — exact same math as the dense dispatch.
+      the rows of its local experts — a contiguous slice of the sorted
+      groups — and a ``psum`` over ``expert_axis`` combines.
     """
 
     num_experts: int = 8
     top_k: int = 2
     hidden_mult: int = 4
-    capacity_factor: float = 1.25
+    expert_hidden: int | None = None
+    norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     expert_axis: str | None = None
     expert_shards: int = 1
@@ -56,83 +179,71 @@ class MoEMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         b, s, dm = x.shape
-        hidden = max(128, (dm * self.hidden_mult // 128) * 128)
-        n_tok = b * s
-        capacity = max(1, int(self.capacity_factor * n_tok * self.top_k / self.num_experts))
-
-        tokens = x.reshape(n_tok, dm)
-        router_logits = nn.Dense(
-            self.num_experts, dtype=jnp.float32, use_bias=False, name="router"
-        )(tokens.astype(jnp.float32))
-        probs = jax.nn.softmax(router_logits, axis=-1)  # (T, E)
-
-        # Top-k gating: zero all but the k largest per token, renormalize.
-        top_vals, _ = jax.lax.top_k(probs, self.top_k)
-        kth = top_vals[:, -1:]
-        gates = jnp.where(probs >= kth, probs, 0.0)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-        # Position of each token in each expert's buffer; tokens past
-        # capacity drop (residual connection still carries them).
-        assigned = gates > 0.0  # (T, E)
-        position = jnp.cumsum(assigned, axis=0) - 1
-        keep = assigned & (position < capacity)
-        # dispatch: (T, E, C) one-hot over buffer slots.
-        dispatch = keep[..., None] & (
-            position[..., None] == jnp.arange(capacity)[None, None, :]
-        )
-        dispatch = dispatch.astype(self.dtype)
-        combine = dispatch * gates[..., None].astype(self.dtype)
-
+        hidden = self.expert_hidden or max(128, (dm * self.hidden_mult // 128) * 128)
         if self.num_experts % self.expert_shards:
             raise ValueError(
                 f"{self.num_experts} experts not divisible by "
                 f"expert_shards={self.expert_shards}"
             )
         e_local = self.num_experts // self.expert_shards
+
+        with jax.named_scope(SCOPE_ROUTER):
+            # float32 end to end: the top-k is discontinuous in the logits
+            router_logits = nn.Dense(
+                self.num_experts, dtype=jnp.float32, use_bias=False,
+                precision=jax.lax.Precision.HIGHEST, name="router",
+            )(x.astype(jnp.float32))
+            probs = jax.nn.softmax(router_logits, axis=-1)  # (b, s, E)
+            top_p, top_ids = jax.lax.top_k(probs, self.top_k)
+            if self.norm_topk_prob:
+                top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+
         # Plain (unboxed) params; under expert_axis they hold only this
         # shard's experts, otherwise parallelism comes from placing the
         # full stack P("expert", None, None) — see expert_specs() below.
-        w_in = self.param(
-            "w_in", nn.initializers.lecun_normal(), (e_local, dm, hidden)
-        ).astype(self.dtype)
-        w_out = self.param(
-            "w_out", nn.initializers.lecun_normal(), (e_local, hidden, dm)
-        ).astype(self.dtype)
-
+        init = nn.initializers.lecun_normal()
+        w_gate, w_up, w_down = (
+            self.param(name, init, shape).astype(self.dtype)
+            for name, shape in zip(
+                EXPERT_WEIGHTS,
+                ((e_local, dm, hidden), (e_local, dm, hidden), (e_local, hidden, dm)),
+            )
+        )
+        _m_moe_traces.inc(impl=implementation(
+            jax.ShapeDtypeStruct((b * s * self.top_k, dm), self.dtype), w_gate))
+        first = 0
         if self.expert_axis is not None:
-            start = jax.lax.axis_index(self.expert_axis) * e_local
-            dispatch = jax.lax.dynamic_slice_in_dim(dispatch, start, e_local, axis=1)
-            combine = jax.lax.dynamic_slice_in_dim(combine, start, e_local, axis=1)
-
-        # Expert buffers: (E_local, C, dm).
-        expert_in = jnp.einsum("td,tec->ecd", tokens.astype(self.dtype), dispatch)
-        h = jnp.einsum("ecd,edh->ech", expert_in, w_in)
-        h = nn.gelu(h)
-        expert_out = jnp.einsum("ech,ehd->ecd", h, w_out)
-
-        out = jnp.einsum("ecd,tec->td", expert_out, combine)
+            first = jax.lax.axis_index(self.expert_axis) * e_local
+        out, rows_per_expert = per_shard(
+            functools.partial(_routed_experts, num_experts=self.num_experts, first=first),
+            op="moe", replicated=(3, 4, 5),
+        )(x.astype(self.dtype), top_p, top_ids, w_gate, w_up, w_down)
+        rows_per_expert = rows_per_expert.sum(0)
         if self.expert_axis is not None:
-            # Each shard contributed its local experts' weighted outputs;
-            # the top-k combine is a linear sum over experts, so psum
-            # over the expert axis reproduces the dense dispatch exactly.
+            # Each shard contributed its local experts' weighted rows;
+            # the combine is a linear sum over experts, so psum over the
+            # expert axis gives the whole layer.
             out = jax.lax.psum(out, self.expert_axis)
 
-        # Load-balancing auxiliary loss (Switch-style): mean gate prob ×
-        # fraction of tokens routed, per expert. Stored for the train
-        # step via sow.
-        density = assigned.astype(jnp.float32).mean(0)
-        mean_prob = probs.mean(0)
-        aux = self.num_experts * jnp.sum(density * mean_prob)
-        self.sow("losses", "moe_aux", aux)
+        with jax.named_scope(SCOPE_ROUTER):
+            # Load balancing (Switch): fraction of tokens that chose each
+            # expert times its mean probability. Router z-loss (ST-MoE,
+            # OLMoE): mean squared log-sum-exp of the logits.
+            density = rows_per_expert.astype(jnp.float32) / (b * s)
+            mean_prob = probs.reshape(-1, self.num_experts).mean(0)
+            self.sow("losses", "moe_aux", self.num_experts * jnp.sum(density * mean_prob))
+            self.sow("losses", "moe_router_z",
+                     jnp.mean(jnp.square(jax.nn.logsumexp(router_logits, axis=-1))))
+        self.sow("moe_stats", "rows_per_expert", rows_per_expert)
+        self.sow("moe_stats", "expert_ids", top_ids)
+        return out
 
-        return out.reshape(b, s, dm)
 
-
-
-def sum_sown_losses(variables: Any) -> jax.Array | float:
+def sum_sown_losses(variables: Any, name: str | None = None) -> jax.Array | float:
     """Reduce the ``"losses"`` collection of a ``mutable=["losses"]``
-    apply's variables to one scalar (0.0 when nothing was sown).
+    apply's variables to one scalar (0.0 when nothing was sown): every
+    sown loss, or only those sown as ``name`` (``"moe_aux"``,
+    ``"moe_router_z"``), summed over layers.
 
     Flax ``sow`` accumulates each loss as a tuple of arrays; this is
     the single definition of "total sown aux" shared by the dense
@@ -140,24 +251,42 @@ def sum_sown_losses(variables: Any) -> jax.Array | float:
     (``pipeline.pipelined_lm_apply``) so the two can never diverge.
     Takes the whole variables mapping, not the collection itself.
     """
-    leaves = jax.tree.leaves(
-        variables.get("losses", {}), is_leaf=lambda x: isinstance(x, tuple)
-    )
+    leaves = _sown(variables, "losses", name)
     if not leaves:
         return 0.0
     return sum(jnp.sum(jnp.stack(v)) for v in leaves)
 
+
+def _sown(variables: Any, collection: str, name: str | None) -> list[tuple]:
+    """The tuples flax ``sow`` accumulated under ``name`` (any name when
+    None) anywhere in ``collection``, one per module that sowed."""
+    found = jax.tree_util.tree_leaves_with_path(
+        variables.get(collection, {}), is_leaf=lambda x: isinstance(x, tuple)
+    )
+    return [v for path, v in found if name is None or path[-1].key == name]
+
+
+def max_load_over_mean(variables: Any) -> jax.Array | float:
+    """Busiest expert's rows over the mean rows per expert, the largest
+    over the MoE layers of a ``mutable=["moe_stats"]`` apply (1.0 is a
+    perfectly even routing; 0.0 when no layer routed)."""
+    rows = [r for v in _sown(variables, "moe_stats", "rows_per_expert") for r in v]
+    if not rows:
+        return 0.0
+    return jnp.max(jnp.stack([jnp.max(r) / jnp.mean(r.astype(jnp.float32)) for r in rows]))
+
+
 def expert_specs(params: Any, axis: str = "expert") -> Any:
     """PartitionSpec tree sharding every expert-stacked weight (leading
-    dim == num_experts, named ``w_in``/``w_out``) on ``axis``; the rest
-    replicated. Feed to ``jax.device_put`` with a mesh carrying an
-    ``expert`` axis for expert parallelism."""
+    dim == num_experts, named ``w_gate``/``w_up``/``w_down``) on
+    ``axis``; the rest replicated. Feed to ``jax.device_put`` with a
+    mesh carrying an ``expert`` axis for expert parallelism."""
     from jax.sharding import PartitionSpec as P
 
     def walk(tree, name=""):
         if isinstance(tree, dict):
             return {k: walk(v, k) for k, v in tree.items()}
-        if name in ("w_in", "w_out"):
+        if name in EXPERT_WEIGHTS:
             return P(axis, None, None)
         return P()
 
@@ -170,6 +299,8 @@ class MoEBlock(nn.Module):
     num_heads: int
     num_experts: int = 8
     top_k: int = 2
+    expert_hidden: int | None = None
+    norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     attention_impl: str = "flash"
     mesh: Any = None
@@ -183,6 +314,9 @@ class MoEBlock(nn.Module):
     num_kv_heads: int | None = None
     window: int | None = None
     ragged_decode: bool = False
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False):
@@ -200,19 +334,29 @@ class MoEBlock(nn.Module):
             num_kv_heads=self.num_kv_heads,
             window=self.window,
             ragged_decode=self.ragged_decode,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
+            rope_base=self.rope_base,
             name="attn",
-        )(RMSNorm(dtype=self.dtype)(x), decode=decode)
+        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x), decode=decode)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = x + h
-        h = MoEMLP(
-            num_experts=self.num_experts,
-            top_k=self.top_k,
-            dtype=self.dtype,
-            expert_axis=self.expert_axis,
-            expert_shards=self.expert_shards,
-            name="moe",
-        )(RMSNorm(dtype=self.dtype)(x))
+        h = RMSNorm(self.norm_eps, dtype=self.dtype)(x)
+        # the routed experts ARE this block's feed-forward: the module
+        # keeps its name in the parameter tree ("moe"), its device ops
+        # carry the vocabulary's ``mlp`` scope like a dense block's
+        with jax.named_scope(SCOPE_MLP):
+            h = MoEMLP(
+                num_experts=self.num_experts,
+                top_k=self.top_k,
+                expert_hidden=self.expert_hidden,
+                norm_topk_prob=self.norm_topk_prob,
+                dtype=self.dtype,
+                expert_axis=self.expert_axis,
+                expert_shards=self.expert_shards,
+                name="moe",
+            )(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return x + h

@@ -115,6 +115,12 @@ class Attention(nn.Module):
     paged_decode: bool = False
     kv_page_size: int = 64
     kv_pool_blocks: int | None = None
+    # QK-norm (OLMoE): RMSNorm over the WHOLE q and k projections
+    # (num_heads x head_dim wide), before the split into heads and
+    # before RoPE; ``norm_eps`` is its epsilon.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
 
     @nn.compact
     def __call__(self, x, decode: bool = False):
@@ -152,6 +158,14 @@ class Attention(nn.Module):
             )(x)
             k, v = [jnp.moveaxis(kv[:, :, i], 2, 1) for i in range(2)]
 
+        if self.qk_norm:
+            if self.tp_shards > 1:
+                raise NotImplementedError(
+                    "qk_norm spans every head's channels; under tp_shards "
+                    "a device holds only its own heads"
+                )
+            q, k = self._whole_norm(q, "q_norm"), self._whole_norm(k, "k_norm")
+
         if decode:
             return self._decode_attend(q, k, v, b, s, dm, head_dim)
 
@@ -160,7 +174,8 @@ class Attention(nn.Module):
             # Inside a seq-sharded shard_map x is the LOCAL chunk:
             # absolute positions start at this shard's offset.
             pos = pos + jax.lax.axis_index(self.seq_axis) * s
-        q, k = rotary_embedding(q, pos), rotary_embedding(k, pos)
+        q = rotary_embedding(q, pos, self.rope_base)
+        k = rotary_embedding(k, pos, self.rope_base)
         # Single-chip training/full-forward is FLOPs-bound:
         # broadcasting GQA kv heads here costs memory only at the
         # (short-lived) activation. The sequence-parallel impls below
@@ -208,6 +223,13 @@ class Attention(nn.Module):
             raise ValueError(f"unknown attention_impl {self.attention_impl!r}")
 
         return self._project_out(o, b, s, dm)
+
+    def _whole_norm(self, t, name):
+        """RMSNorm over all heads' channels of ``t`` (b, h, s, d) at once."""
+        b, h, s, d = t.shape
+        flat = jnp.moveaxis(t, 1, 2).reshape(b, s, h * d)
+        flat = RMSNorm(self.norm_eps, dtype=self.dtype, name=name)(flat)
+        return jnp.moveaxis(flat.reshape(b, s, h, d), 2, 1)
 
     def _project_out(self, o, b, s, dm):
         """(b, h_local, s, d) -> out projection; under tp the local
@@ -283,8 +305,8 @@ class Attention(nn.Module):
             def put2(cache, update, starts):
                 return jax.lax.dynamic_update_slice(cache, update, (0, 0, starts))
 
-        q = rotary_embedding(q, pos)
-        k = rotary_embedding(k, pos)
+        q = rotary_embedding(q, pos, self.rope_base)
+        k = rotary_embedding(k, pos, self.rope_base)
         if int8_cache:
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
@@ -385,8 +407,8 @@ class Attention(nn.Module):
         offset = idx.value
 
         pos = offset[:, None] + jnp.arange(s)[None, :]  # (b, s) absolute
-        q = rotary_embedding(q, pos)
-        k = rotary_embedding(k, pos)
+        q = rotary_embedding(q, pos, self.rope_base)
+        k = rotary_embedding(k, pos, self.rope_base)
         # Clamp pad positions into the table's domain; rows whose pad
         # runs past their allocation hit entry 0 = the scratch block.
         posc = jnp.minimum(pos, self.max_decode_len - 1)
@@ -471,6 +493,9 @@ class Block(nn.Module):
     paged_decode: bool = False
     kv_page_size: int = 64
     kv_pool_blocks: int | None = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False):
@@ -491,8 +516,11 @@ class Block(nn.Module):
             paged_decode=self.paged_decode,
             kv_page_size=self.kv_page_size,
             kv_pool_blocks=self.kv_pool_blocks,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
+            rope_base=self.rope_base,
             name="attn",
-        )(RMSNorm(dtype=self.dtype)(x), decode=decode)
+        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x), decode=decode)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = x + h
@@ -501,7 +529,7 @@ class Block(nn.Module):
             tp_axis=self.tp_axis,
             tp_shards=self.tp_shards,
             name="mlp",
-        )(RMSNorm(dtype=self.dtype)(x))
+        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return x + h
@@ -524,6 +552,16 @@ class TransformerLM(nn.Module):
     moe_every: int = 0  # >0: every k-th block routes through experts
     num_experts: int = 8
     moe_top_k: int = 2
+    # One expert's SwiGLU width (None: d_model x 4, MoEMLP's default)
+    # and whether the chosen experts' probabilities are renormalised
+    # (OLMoE publishes 1024 and False).
+    moe_expert_hidden: int | None = None
+    moe_norm_topk_prob: bool = True
+    # Layer options that differ between published models: QK-norm over
+    # the whole q/k projections, every RMSNorm's epsilon, the rotary base.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
     max_decode_len: int = 2048
     kv_cache_dtype: str | None = None  # "int8": quantized decode cache
     num_kv_heads: int | None = None  # GQA: shrink the decode cache
@@ -567,12 +605,17 @@ class TransformerLM(nn.Module):
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")(tokens)
         block_cls = nn.remat(Block, static_argnums=(2, 3)) if self.remat else Block
         moe_cls = nn.remat(MoEBlock, static_argnums=(2, 3)) if self.remat else MoEBlock
+        layer_options = dict(
+            qk_norm=self.qk_norm, norm_eps=self.norm_eps, rope_base=self.rope_base
+        )
         for i in range(self.num_layers):
             if self.moe_every and (i + 1) % self.moe_every == 0:
                 x = moe_cls(
                     self.num_heads,
                     num_experts=self.num_experts,
                     top_k=self.moe_top_k,
+                    expert_hidden=self.moe_expert_hidden,
+                    norm_topk_prob=self.moe_norm_topk_prob,
                     dtype=self.dtype,
                     attention_impl=self.attention_impl,
                     mesh=self.mesh,
@@ -584,6 +627,7 @@ class TransformerLM(nn.Module):
                     num_kv_heads=self.num_kv_heads,
                     window=self.window,
                     ragged_decode=self.ragged_decode,
+                    **layer_options,
                     name=f"block_{i}",
                 )(x, train, decode)
                 continue
@@ -605,9 +649,10 @@ class TransformerLM(nn.Module):
                 paged_decode=self.paged_decode,
                 kv_page_size=self.kv_page_size,
                 kv_pool_blocks=self.kv_pool_blocks,
+                **layer_options,
                 name=f"block_{i}",
             )(x, train, decode)
-        x = RMSNorm(dtype=self.dtype, name="final_norm")(x)
+        x = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")(x)
         if return_hidden:
             # The chunked-vocab loss (ops/xent.py) computes the loss
             # straight from hidden states + the unembed kernel without
@@ -618,14 +663,21 @@ class TransformerLM(nn.Module):
 
 
 def make_lm_train_step(
-    aux_loss_weight: float = 0.01, loss_chunk: int | None = None
+    aux_loss_weight: float = 0.01,
+    loss_chunk: int | None = None,
+    router_z_loss_weight: float = 0.0,
 ):
     """Next-token-prediction step: ``(state, {"tokens"}) -> (state, metrics)``.
 
     Same ``step(state, batch)`` contract as ``common.make_train_step``
     so every launcher (launch/mirrored/collective_all_reduce) accepts it
-    unchanged. MoE blocks' sown load-balancing losses are folded in at
-    ``aux_loss_weight``.
+    unchanged. MoE blocks' sown losses are folded in: load balancing at
+    ``aux_loss_weight``, the router z-loss at ``router_z_loss_weight``
+    (OLMoE trains with 0.01 and 0.001). A model with MoE blocks also
+    reports ``moe_aux_loss``, ``moe_router_z_loss`` (unweighted, summed
+    over layers) and ``moe_load_max_over_mean`` (busiest expert's rows
+    over the mean, the largest over layers) — device scalars like
+    ``loss``, no host sync.
 
     ``loss_chunk``: compute the loss via the memory-efficient
     token-chunked LM-head path (``ops/xent.py``) — ``loss_chunk``
@@ -638,7 +690,7 @@ def make_lm_train_step(
     """
     import optax
 
-    from hops_tpu.models.moe import sum_sown_losses
+    from hops_tpu.models.moe import max_load_over_mean, sum_sown_losses
     from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
 
     def train_step(state, batch):
@@ -653,7 +705,7 @@ def make_lm_train_step(
                 train=True,
                 return_hidden=bool(loss_chunk),
                 rngs={"dropout": step_rng},
-                mutable=["losses"],
+                mutable=["losses", "moe_stats"],
             )
             if loss_chunk:
                 from hops_tpu.ops.xent import chunked_softmax_xent
@@ -666,12 +718,21 @@ def make_lm_train_step(
                     loss = optax.softmax_cross_entropy_with_integer_labels(
                         out, targets
                     ).mean()
-            aux = sum_sown_losses(mods)
-            return loss + aux_loss_weight * aux, loss
+            metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
+            total = loss
+            if "losses" in mods:  # the model has MoE blocks
+                aux = sum_sown_losses(mods, "moe_aux")
+                router_z = sum_sown_losses(mods, "moe_router_z")
+                total = loss + aux_loss_weight * aux + router_z_loss_weight * router_z
+                metrics.update(
+                    moe_aux_loss=aux, moe_router_z_loss=router_z,
+                    moe_load_max_over_mean=max_load_over_mean(mods),
+                )
+            return total, metrics
 
-        (_, loss), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)
+        (_, metrics), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)
         with jax.named_scope(SCOPE_OPTIMIZER):
             state = state.apply_gradients(grads=grads)
-        return state, {"loss": loss, "perplexity": jnp.exp(loss)}
+        return state, metrics
 
     return train_step

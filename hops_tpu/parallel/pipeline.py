@@ -232,10 +232,11 @@ def pipelined_lm_apply(
     Logits match ``model.apply`` exactly (tests/test_pipeline.py).
 
     MoE models (``moe_every > 0``) pipeline too: layers chunk into
-    uniform (moe_every-1 dense + 1 MoE) groups. Semantic notes: MoE
-    routing (expert capacity, token drops) is computed per microbatch —
-    the batch a stage sees IS the microbatch, as in any GPipe x MoE
-    system — so whole-batch parity is exact only for drop-free routing.
+    uniform (moe_every-1 dense + 1 MoE) groups. Routing is dropless and
+    per token, so a microbatch's outputs are the whole batch's; the
+    load-balancing loss is the mean over microbatches of a statistic of
+    each microbatch (equal to the whole batch's only when every token
+    picks every expert).
 
     Inner parallelism composes (round 3):
 
@@ -243,13 +244,12 @@ def pipelined_lm_apply(
       tokens/logits shard ``P(None, seq_axis)`` and attention runs the
       ring-attention body over that axis (``ring_attention_local``),
       so pp bounds layer memory while sp bounds activation memory for
-      long sequences. Dense models only (MoE routing under a sharded
-      sequence would change drop semantics — use ``expert_axis``).
+      long sequences. Dense models only (use ``expert_axis`` for MoE).
     - ``expert_axis``: expert parallelism INSIDE each pipeline stage —
-      ``w_in``/``w_out`` stacks shard over the axis, each device runs
-      its local experts and a per-layer ``psum`` combines
-      (``MoEMLP(expert_axis=...)``); routing/capacity math is
-      unchanged, so logits still match the dense apply exactly.
+      the ``w_gate``/``w_up``/``w_down`` stacks shard over the axis,
+      each device runs its local experts' slice of the sorted rows and
+      a per-layer ``psum`` combines (``MoEMLP(expert_axis=...)``); the
+      routing is unchanged, so logits still match the dense apply.
     - ``tp_axis``: Megatron tensor parallelism INSIDE each pipeline
       stage — qkv/gate/up kernels column-shard (local heads / local
       hidden columns), out/down kernels row-shard, and one psum per
@@ -266,15 +266,14 @@ def pipelined_lm_apply(
     microbatches, summed over layers/stages) — feed it into the train
     loss exactly like ``make_lm_train_step`` does for the dense path.
     """
-    from hops_tpu.models.moe import MoEBlock, sum_sown_losses
+    from hops_tpu.models.moe import EXPERT_WEIGHTS, MoEBlock, sum_sown_losses
     from hops_tpu.models.transformer import Block, RMSNorm
     from flax import linen as nn
 
     if seq_axis and model.moe_every:
         raise NotImplementedError(
             "seq_axis inside pp is supported for dense LMs; MoE models "
-            "compose pp with expert_axis instead (per-microbatch routing "
-            "over a sharded sequence would change drop semantics)"
+            "compose pp with expert_axis instead"
         )
     if expert_axis and not model.moe_every:
         raise ValueError("expert_axis requires a MoE model (moe_every > 0)")
@@ -285,6 +284,9 @@ def pipelined_lm_apply(
         )
 
     n_stages = mesh.shape[axis]
+    layer_options = dict(
+        qk_norm=model.qk_norm, norm_eps=model.norm_eps, rope_base=model.rope_base
+    )
     block = Block(
         model.num_heads,
         dtype=model.dtype,
@@ -298,9 +300,10 @@ def pipelined_lm_apply(
         num_kv_heads=model.num_kv_heads,
         kv_cache_dtype=model.kv_cache_dtype,
         window=model.window,
+        **layer_options,
     )
     embed = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    norm = RMSNorm(dtype=model.dtype)
+    norm = RMSNorm(model.norm_eps, dtype=model.dtype)
     unembed = nn.Dense(model.vocab_size, dtype=model.dtype, use_bias=False)
 
     if model.moe_every:
@@ -319,6 +322,8 @@ def pipelined_lm_apply(
             model.num_heads,
             num_experts=model.num_experts,
             top_k=model.moe_top_k,
+            expert_hidden=model.moe_expert_hidden,
+            norm_topk_prob=model.moe_norm_topk_prob,
             dtype=model.dtype,
             attention_impl=model.attention_impl,
             mesh=None,
@@ -328,6 +333,7 @@ def pipelined_lm_apply(
             num_kv_heads=model.num_kv_heads,
             kv_cache_dtype=model.kv_cache_dtype,
             window=model.window,
+            **layer_options,
         )
         groups = []
         for start in range(0, model.num_layers, g):
@@ -351,7 +357,7 @@ def pipelined_lm_apply(
                 h, mods = moe_block.apply(
                     {"params": gp["moe"]}, h, mutable=["losses"]
                 )
-                aux = aux + sum_sown_losses(mods)
+                aux = aux + sum_sown_losses(mods, "moe_aux")
                 return (h, aux), None
 
             # Under dp the sown aux derives from data-sharded
@@ -407,7 +413,7 @@ def pipelined_lm_apply(
         # the expert axis).
         def leaf_spec(path, _):
             name = str(path[-1].key) if hasattr(path[-1], "key") else ""
-            if name in ("w_in", "w_out"):
+            if name in EXPERT_WEIGHTS:
                 return P(axis, None, expert_axis)
             return P(axis)
 
@@ -474,9 +480,10 @@ def _scheduled_lm_loss_and_grads(
         attention_impl=model.attention_impl, dropout_rate=0.0,
         num_kv_heads=model.num_kv_heads,
         kv_cache_dtype=model.kv_cache_dtype, window=model.window,
+        qk_norm=model.qk_norm, norm_eps=model.norm_eps, rope_base=model.rope_base,
     )
     embed = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    norm = RMSNorm(dtype=model.dtype)
+    norm = RMSNorm(model.norm_eps, dtype=model.dtype)
     unembed = nn.Dense(model.vocab_size, dtype=model.dtype, use_bias=False)
 
     def stage_fn(stage_params, h):

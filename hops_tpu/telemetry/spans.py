@@ -48,6 +48,14 @@ TRAIN_SCOPES = (
 )
 SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER, SCOPE_GRAD_EXCHANGE = TRAIN_SCOPES[4:]
 
+#: Inside ``mlp``, the parts of a routed feed-forward (``models/moe.py``):
+#: the router (matmul, softmax, top-k, the two auxiliary losses), the
+#: dispatch (sort by expert, group sizes, gather), the experts (the three
+#: grouped matmuls, forward and backward) and the combine (back to token
+#: order, summed over a token's slots). A dense block enters none of them.
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+SCOPE_MLP = TRAIN_SCOPES[1]
+
 #: The training step's host vocabulary: :func:`span` names entered by
 #: ``Strategy.distribute_batch`` and by every ``Strategy.step`` callable,
 #: children of the launcher's ``experiment.run`` root span.

@@ -1,0 +1,123 @@
+"""``ops/grouped_matmul.py``: the ``moe_gmm`` kernels through the Pallas
+interpreter against their twin ``jax.lax.ragged_dot`` (values and both
+gradients, float32, so 1e-5 of the largest value is rounding alone), the
+schedule's invariants, the fallback to the twin, and a compile of the
+three kernels for a described v5e at OLMoE's widths."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hops_tpu.ops import grouped_matmul as gm
+
+M, K, N, G = 256, 128, 256, 5
+TILING = (64, 128, 128)  # 4 row tiles, so boundaries fall inside tiles and on them
+
+
+def _operands(dtype=jnp.float32):
+    rs = np.random.RandomState(0)
+    return jnp.asarray(rs.randn(M, K), dtype), jnp.asarray(rs.randn(G, K, N), dtype)
+
+
+@pytest.mark.parametrize("sizes", [
+    [100, 0, 60, 96, 0], [256, 0, 0, 0, 0], [0, 0, 0, 0, 256], [64, 64, 64, 64, 0], [1, 2, 3, 4, 246],
+    [10, 20, 30, 40, 50], [0, 0, 0, 0, 0],
+], ids=["ragged_with_empty", "all_in_first", "all_in_last", "on_tile_edges", "tiny_groups",
+        "rows_left_over", "no_rows"])
+def test_kernels_follow_ragged_dot(sizes):
+    lhs, rhs = _operands()
+    sizes = jnp.asarray(sizes, jnp.int32)
+    used = (jnp.arange(M) < sizes.sum())[:, None]  # rows past the groups are unspecified
+
+    def loss(l, r, interpret):
+        out = gm.grouped_matmul(l, r, sizes, tiling=TILING, interpret=interpret)
+        return jnp.sum(jnp.where(used, out, 0.0) ** 2), jnp.where(used, out, 0.0)
+
+    (want, want_out), (want_dl, want_dr) = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs, None)
+    (got, got_out), (got_dl, got_dr) = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs, True)
+    for a, b in ((got_out, want_out), (jnp.where(used, got_dl, 0.0), want_dl), (got_dr, want_dr)):
+        np.testing.assert_allclose(a, b, atol=1e-5 * max(float(jnp.max(jnp.abs(b))), 1.0), rtol=0)
+    empty = np.asarray(sizes) == 0
+    assert not np.asarray(got_dr)[empty].any()  # an expert nobody chose gets a zero gradient, not garbage
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+
+
+def test_bf16_rows_accumulate_in_float32():
+    lhs, rhs = _operands(jnp.bfloat16)
+    sizes = jnp.asarray([100, 0, 60, 96, 0], jnp.int32)
+    got = gm.grouped_matmul(lhs, rhs, sizes, tiling=TILING, interpret=True)
+    want = jax.lax.ragged_dot(lhs.astype(jnp.float32), rhs.astype(jnp.float32), sizes)
+    assert got.dtype == jnp.bfloat16
+    # one bf16 rounding of the result (2^-9), nothing lost in the sum over 128 terms
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2 ** -8 * float(jnp.max(jnp.abs(want))), rtol=0)
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 60, 96, 0], [0, 0, 0, 0, 0], [256, 0, 0, 0, 0], [3, 61, 64, 1, 127]])
+def test_schedule_visits_every_group_and_never_goes_back(sizes):
+    tm = 64
+    group_ids, tile_ids, offsets, n_items = (np.asarray(a) for a in gm._schedule(jnp.asarray(sizes, jnp.int32), M, tm))
+    n = int(n_items[0])
+    assert len(group_ids) == len(tile_ids) == M // tm + G - 1 and 1 <= n <= len(group_ids)
+    assert offsets.tolist() == [0, *np.cumsum(sizes)]
+    assert (np.diff(tile_ids[:n]) >= 0).all() and (np.diff(group_ids[:n]) >= 0).all()  # revisits are consecutive
+    assert set(group_ids[:n]) == set(range(G))  # empty groups too: their dW is zeroed there
+    for g, size in enumerate(sizes):  # a group's items cover exactly the tiles its rows touch
+        tiles = tile_ids[:n][group_ids[:n] == g]
+        want = range(offsets[g] // tm, (offsets[g + 1] - 1) // tm + 1) if size else [min(offsets[g] // tm, M // tm - 1)]
+        assert tiles.tolist() == list(want)
+    assert (group_ids[n:] == group_ids[n - 1]).all() and (tile_ids[n:] == tile_ids[n - 1]).all()  # padding repeats
+
+
+def test_shapes_that_are_not_whole_tiles_take_the_twin():
+    assert gm.fit_tiling(65536, 2048, 1024) == (256, 2048, 1024)  # a dimension below its tile is one tile
+    assert gm.fit_tiling(256, 128, 256, TILING) == TILING
+    assert gm.fit_tiling(256, 64, 48) is None and gm.fit_tiling(250, 128, 256, TILING) is None
+    lhs, rhs = jnp.ones((48, 64)), jnp.ones((3, 64, 48))
+    assert gm.implementation(lhs, rhs, interpret=True) == "ragged_dot"
+    assert gm.implementation(*_operands(), tiling=TILING, interpret=True) == "gmm_kernel"
+    assert gm.implementation(*_operands(), tiling=TILING) == "ragged_dot"  # off the TPU, unless forced
+    out = gm.grouped_matmul(lhs, rhs, jnp.asarray([16, 16, 16], jnp.int32), interpret=True)
+    np.testing.assert_allclose(out, 64.0)
+
+
+# -- the real widths, compiled for a chip that is described and not attached ---
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("k, n", [(2048, 1024), (1024, 2048)], ids=["gate_up", "down"])
+def test_kernels_compile_for_v5e_at_olmoe_widths(one_chip, k, n):
+    """65,536 routed rows through 64 experts, forward and both gradients:
+    Mosaic accepts the block shapes and the VMEM the default tiling asks
+    for (block-shape rules and VMEM limits are what interpret mode cannot
+    show). Nothing runs."""
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        lhs = jax.ShapeDtypeStruct((65536, k), jnp.bfloat16, sharding=one_chip)
+        rhs = jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip)
+        sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+
+        def grads(l, r, s):
+            return jax.grad(lambda l, r: gm.grouped_matmul(l, r, s, interpret=False).astype(jnp.float32).sum(),
+                            argnums=(0, 1))(l, r)
+
+        text = jax.jit(grads).lower(lhs, rhs, sizes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2 and all(gm.KERNEL_NAME in line.split(" = ")[0] for line in calls)  # dX and dW

@@ -156,11 +156,8 @@ def test_pipelined_moe_lm_matches_dense(stage_mesh):
     """VERDICT r2 weak #5: pp composes with ep — an LM with MoE blocks
     (moe_every=2) through the GPipe ring, logits vs the dense model.
 
-    Parity tests route drop-free (top_k == num_experts): capacity-based
-    token dropping is computed per batch, and under pp the batch a stage
-    sees IS the microbatch — a semantic, documented difference
-    (pipeline.py), not an implementation error. A dropping config is
-    exercised separately for finiteness/shape."""
+    Routing is dropless and per token, so parity holds for any top_k;
+    top_k < num_experts is exercised separately."""
     from hops_tpu.models.transformer import TransformerLM
     from hops_tpu.parallel.pipeline import pipelined_lm_apply
 
@@ -193,10 +190,9 @@ def test_pipelined_all_moe_lm_matches_dense(stage_mesh):
     np.testing.assert_allclose(pp, dense, atol=1e-4, rtol=1e-4)
 
 
-def test_pipelined_moe_lm_with_token_dropping_runs(stage_mesh):
-    """top_k < num_experts (real routing with capacity drops): outputs
-    are finite and shaped — exact whole-batch parity is impossible by
-    design since routing is microbatch-local under pp."""
+def test_pipelined_moe_lm_top1_matches_dense(stage_mesh):
+    """top_k < num_experts (real routing): dropless routing is per
+    token, so a microbatch's logits are the whole batch's."""
     from hops_tpu.models.transformer import TransformerLM
     from hops_tpu.parallel.pipeline import pipelined_lm_apply
 
@@ -208,8 +204,8 @@ def test_pipelined_moe_lm_with_token_dropping_runs(stage_mesh):
     tokens = jax.random.randint(jax.random.PRNGKey(10), (8, 16), 0, 64)
     params = model.init(jax.random.PRNGKey(11), tokens)["params"]
     pp = pipelined_lm_apply(model, params, tokens, stage_mesh)
-    assert pp.shape == (8, 16, 64)
-    assert bool(jnp.all(jnp.isfinite(pp)))
+    dense = model.apply({"params": params}, tokens)
+    np.testing.assert_allclose(pp, dense, atol=1e-4, rtol=1e-4)
 
 
 def test_pipelined_moe_lm_grads_match_dense(stage_mesh):
@@ -255,7 +251,7 @@ def test_pipelined_moe_aux_loss_matches_dense(stage_mesh):
     params = model.init(jax.random.PRNGKey(13), tokens)["params"]
 
     _, mods = model.apply({"params": params}, tokens, mutable=["losses"])
-    dense_aux = sum_sown_losses(mods)
+    dense_aux = sum_sown_losses(mods, "moe_aux")
     logits, pp_aux = pipelined_lm_apply(
         model, params, tokens, stage_mesh, return_aux=True)
     assert logits.shape == (8, 16, 64)
@@ -314,7 +310,7 @@ def test_pp_with_ep_inside_stages_matches_dense():
     )(params, tokens)
     dense, mods = model.apply({"params": params}, tokens, mutable=["losses"])
     np.testing.assert_allclose(logits, dense, atol=1e-4, rtol=1e-4)
-    np.testing.assert_allclose(float(pp_aux), float(sum_sown_losses(mods)), rtol=1e-5)
+    np.testing.assert_allclose(float(pp_aux), float(sum_sown_losses(mods, "moe_aux")), rtol=1e-5)
 
 
 def test_pp_sp_moe_raises():
