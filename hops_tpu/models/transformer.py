@@ -682,7 +682,9 @@ def make_lm_train_step(
     ``loss_chunk``: compute the loss via the memory-efficient
     token-chunked LM-head path (``ops/xent.py``) — ``loss_chunk``
     tokens' logits at a time, so the (batch, seq, vocab) fp32 logits
-    are never materialized (peak ``loss_chunk x vocab``). For fp32
+    are never materialized (peak ``loss_chunk x vocab`` fp32 +
+    ``max(loss_chunk, 2048) x vocab`` in the model dtype: the loss and
+    both its gradients come from one pass, no recompute). For fp32
     models the loss and gradients are identical to the dense path
     (tests/test_ops.py parity); for bf16 models they differ slightly —
     in the chunked path's favor, since its logits are fp32-accumulated

@@ -10,23 +10,37 @@ stack does.
 
 :func:`chunked_softmax_xent` computes the identical loss directly from
 the final hidden states and the unembed matrix, one token chunk at a
-time under ``jax.checkpoint``: the forward keeps only the per-chunk
-scalar losses, and the backward recomputes each chunk's logits on the
-fly — peak logits memory per chip drops from ``tokens x vocab`` to
-``chunk x vocab`` (16x at those cells' chunk of 512). The matmuls stay
-MXU-shaped (chunk x d @ d x vocab, bf16 inputs, fp32 accumulation), so
-this trades a second pass of LM-head FLOPs for O(tokens/chunk) less HBM
-— the right trade on a bandwidth-bound chip.
+time, and under differentiation makes both gradients in the SAME pass:
+a chunk holds the whole vocabulary, so its softmax is complete inside
+the chunk and its ``dlogits = softmax - onehot(target)`` are made while
+its logits are live. Nothing is kept per chunk and nothing is
+recomputed: three logits-sized matmuls a step (logits, dH, dW), not the
+four of a checkpointed loop. The dlogits of a *group* of chunks
+(``_GROUP_ROWS`` = 2,048 rows) are staged in the hidden dtype, then one
+matmul makes the group's dH and one adds the group's dW into the fp32
+``[d, vocab]`` accumulator, so that matrix is read and written once per
+2,048 rows: at 512 rows the accumulation is bound by HBM traffic by a
+factor of two, from 1,024 rows on it is not (the derivation is at the
+constant). Peak LM-head memory per chip is ``chunk x vocab`` fp32 (one
+chunk's logits) + ``max(chunk, 2048) x vocab`` in the hidden dtype (the
+staged dlogits) + the fp32 ``[d, vocab]`` gradient every path holds,
+against ``tokens x vocab`` fp32 for full logits. The matmuls stay
+MXU-shaped (bf16 inputs, fp32 accumulation). The loss is the last thing
+the forward does, so its gradients are born where the backward would
+start; the backward rule only scales them by the incoming cotangent.
+An undifferentiated call (evaluation) runs the plain forward loop and
+makes no gradient. ``hops_tpu_train_loss_traces_total{pass}`` counts at
+trace time which of the two a compiled program holds (PERF.md §6, PR 26).
 
 The chunk loop is a ``lax.scan`` over an axis made from the batch. In a
 GSPMD step whose batch is sharded over devices the partitioner cannot
 keep a scanned axis sharded: it all-gathers the hidden states inside
-the forward and the backward loop and every device computes the whole
-global batch (439 ms of a 684 ms step on four v5e chips against 48 ms
-on one: PERF.md §6, PR 24). So inside ``Strategy.step``'s default path
-the loop runs per device shard (``parallel.mesh.per_shard``): each
-device scans its own tokens, the scalar sums are added, and the fp32
-``[d, vocab]`` weight gradient is all-reduced once, after the loop.
+the loop and every device computes the whole global batch (439 ms of a
+684 ms step on four v5e chips against 48 ms on one: PERF.md §6, PR 24).
+So inside ``Strategy.step``'s default path the loop runs per device
+shard (``parallel.mesh.per_shard``): each device scans its own tokens,
+the scalar sums are added, and the fp32 ``[d, vocab]`` weight gradient
+is all-reduced once, after the loop.
 
 Exactness: same log-sum-exp formulation as
 ``optax.softmax_cross_entropy_with_integer_labels`` in fp32 —
@@ -42,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from hops_tpu.parallel.mesh import per_shard
+from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS
 
 
@@ -60,9 +75,12 @@ def chunked_softmax_xent(
     Returns the scalar mean loss, identical (fp32 inputs) to computing
     full logits and feeding optax. ``chunk`` is a TOKEN count — the
     flattened ``batch*seq`` tokens are processed ``chunk`` at a time
-    (padded up to a multiple); each step's logits block, and therefore
-    peak LM-head memory PER CHIP, is ``chunk x vocab`` fp32 — the full
-    vocab axis is present per chunk, never sliced.
+    (padded up to whole groups of chunks); each visit's fp32 logits
+    block is ``chunk x vocab`` — the full vocab axis is present per
+    chunk, never sliced. Under ``jax.grad`` the same pass makes dH and
+    dW (module docstring) and additionally holds ``max(chunk, 2048) x
+    vocab`` staged dlogits in ``hidden``'s dtype: peak LM-head memory
+    PER CHIP is the two together.
 
     Inside ``Strategy.step``'s default path on more than one device
     (``mesh.per_shard``) each device flattens, pads and scans its OWN
@@ -84,41 +102,136 @@ def chunked_softmax_xent(
     return jnp.sum(sums) / targets.size
 
 
-def _loss_sum(
-    hidden: jax.Array, unembed: jax.Array, targets: jax.Array, *, chunk: int
-) -> jax.Array:
-    """Sum of the token losses of ``hidden``'s rows, shape ``(1,)`` (the
-    batch-leading partial that ``per_shard`` stacks across shards)."""
+#: Rows of hidden states whose weight gradient is added into the fp32
+#: ``[d_model, vocab]`` accumulator at once. One accumulation does
+#: ``2 * rows * d * vocab`` operations over ``8 * d * vocab`` bytes (the
+#: fp32 matrix read and written): ``rows / 4`` FLOP per byte. A v5e chip
+#: turns at 197e12 / 819e9 = 240 FLOP per byte, i.e. 962 rows; twice
+#: that keeps the matmul compute-bound with room for the operands'
+#: own traffic. Read on the chip at 1,024 / 2,048 / 4,096: PERF.md §6,
+#: PR 26.
+_GROUP_ROWS = 2048
+
+_m_loss_traces = REGISTRY.counter(
+    "hops_tpu_train_loss_traces_total",
+    "Chunked LM-head losses traced, by whether the pass also makes the gradients",
+    labels=("pass",),
+)
+
+
+def _grouped(hidden: jax.Array, targets: jax.Array, chunk: int):
+    """Flatten to tokens, pad to whole groups, and shape for the two
+    loops: ``h`` ``(groups, per_group, chunk, d)``, ``t`` and the fp32
+    ``valid`` mask ``(groups, per_group, chunk)``. A group is as many
+    chunks as fill ``_GROUP_ROWS`` rows, spread evenly over the groups
+    and never more than the tokens have."""
     b, s, d = hidden.shape
     n = b * s
+    n_chunks = -(-n // chunk)
+    groups = -(-n_chunks // -(-_GROUP_ROWS // chunk))
+    per_group = -(-n_chunks // groups)
     h = hidden.reshape(n, d)
     t = targets.reshape(n)
-    pad = (-n) % chunk
+    pad = groups * per_group * chunk - n
     if pad:
         h = jnp.concatenate([h, jnp.zeros((pad, d), h.dtype)])
         t = jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
-    valid = (jnp.arange(n + pad) < n).reshape(-1, chunk)
-    h = h.reshape(-1, chunk, d)
-    t = t.reshape(-1, chunk)
+    valid = (jnp.arange(n + pad) < n).astype(jnp.float32)
+    shape = (groups, per_group, chunk)
+    return h.reshape(*shape, d), t.reshape(shape), valid.reshape(shape)
 
-    @jax.checkpoint
-    def chunk_loss(hc, tc, vc):
-        # (chunk, vocab) exists only inside this (rematerialized) body.
-        # bf16 inputs on the MXU, fp32 accumulation — the logits are
-        # BORN fp32 here (the full-logits path rounds them through the
-        # model dtype first, so bf16 models get slightly better loss
-        # numerics on this path, exactness for fp32 models).
-        logits = jax.lax.dot_general(
-            hc, unembed.astype(hc.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return jnp.sum((lse - tgt) * vc)
+
+def _chunk_logits(hc: jax.Array, w: jax.Array) -> jax.Array:
+    # (chunk, vocab) fp32 exists only inside one visit of a loop body.
+    # bf16 inputs on the MXU, fp32 accumulation — the logits are BORN
+    # fp32 here (the full-logits path rounds them through the model
+    # dtype first, so bf16 models get slightly better loss numerics on
+    # this path, exactness for fp32 models).
+    return jax.lax.dot_general(
+        hc, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _chunk_loss(logits: jax.Array, tc: jax.Array, vc: jax.Array):
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+    return jnp.sum((lse - tgt) * vc), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _loss_sum(
+    hidden: jax.Array, unembed: jax.Array, targets: jax.Array, chunk: int
+) -> jax.Array:
+    """Sum of the token losses of ``hidden``'s rows, shape ``(1,)`` (the
+    batch-leading partial that ``per_shard`` stacks across shards).
+
+    This body is the undifferentiated call (evaluation): the forward
+    loop alone, no gradient made. Under differentiation JAX runs
+    :func:`_loss_sum_fwd` in its place."""
+    _m_loss_traces.inc(**{"pass": "forward_only"})
+    h, t, valid = _grouped(hidden, targets, chunk)
+    w = unembed.astype(h.dtype)
 
     def body(acc, args):
         hc, tc, vc = args
-        return acc + chunk_loss(hc, tc, vc), None
+        return acc + _chunk_loss(_chunk_logits(hc, w), tc, vc)[0], None
 
-    total, _ = jax.lax.scan(body, jnp.float32(0), (h, t, valid.astype(jnp.float32)))
+    total, _ = jax.lax.scan(
+        body, jnp.float32(0),
+        jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), (h, t, valid)))
     return total[None]
+
+
+def _loss_sum_fwd(hidden, unembed, targets, chunk):
+    """The differentiated forward: one pass over the chunks that makes
+    the loss sum AND d(loss sum)/d(hidden), d(loss sum)/d(unembed).
+
+    A chunk's softmax is complete inside the chunk (the whole vocabulary
+    is there), so its dlogits ``softmax - onehot(target)`` are made in
+    the visit that makes its loss, rounded to ``hidden``'s dtype (what
+    the MXU takes) into a ``(group rows, vocab)`` staging buffer. After
+    a group's chunks, two matmuls over the whole group: dH, and dW added
+    into the fp32 ``(d, vocab)`` carry — once per ``_GROUP_ROWS`` rows.
+    """
+    _m_loss_traces.inc(**{"pass": "one_pass"})
+    h, t, valid = _grouped(hidden, targets, chunk)
+    _, per_group, _, d = h.shape
+    vocab = unembed.shape[1]
+    w = unembed.astype(h.dtype)
+
+    def visit(total, args):
+        hc, tc, vc = args
+        logits = _chunk_logits(hc, w)
+        loss, lse = _chunk_loss(logits, tc, vc)
+        p = jnp.exp(logits - lse[:, None])
+        hit = jax.lax.broadcasted_iota(tc.dtype, p.shape, 1) == tc[:, None]
+        dlogits = jnp.where(hit, p - 1.0, p) * vc[:, None]
+        return total + loss, dlogits.astype(hc.dtype)
+
+    def group(carry, args):
+        total, dw = carry
+        hg = args[0].reshape(per_group * chunk, d)
+        total, dlogits = jax.lax.scan(visit, total, args)
+        dlogits = dlogits.reshape(per_group * chunk, vocab)
+        dh = jax.lax.dot_general(
+            dlogits, w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(hg.dtype)
+        dw = dw + jax.lax.dot_general(
+            hg, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return (total, dw), dh
+
+    (total, dw), dh = jax.lax.scan(
+        group, (jnp.float32(0), jnp.zeros((d, vocab), jnp.float32)),
+        (h, t, valid))
+    n = hidden.shape[0] * hidden.shape[1]
+    dh = dh.reshape(-1, d)[:n].reshape(hidden.shape)
+    return total[None], (dh, dw.astype(unembed.dtype))
+
+
+def _loss_sum_bwd(chunk, grads, g):
+    # Scaled in fp32 and rounded once more: the cotangent (1 / tokens
+    # under the mean) is not rounded to the hidden dtype first.
+    return tuple((x * g[0]).astype(x.dtype) for x in grads) + (None,)
+
+
+_loss_sum.defvjp(_loss_sum_fwd, _loss_sum_bwd)

@@ -1,5 +1,7 @@
 """Flash-attention kernel vs the XLA reference (interpreter on fake mesh)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -783,24 +785,146 @@ def test_chunked_xent_matches_optax_value_and_grad():
         np.testing.assert_allclose(a, b_, atol=1e-5, rtol=1e-5)
 
 
-def test_chunked_xent_never_materializes_full_logits():
-    """The compiled forward+backward must not allocate a (tokens, vocab)
-    fp32 buffer — that is the entire point of the chunked path."""
+def _xent_case(n, d, v, dtype, seed=0):
+    rs = np.random.RandomState(seed)
+    h = jnp.asarray(rs.randn(1, n, d), dtype)
+    w = jnp.asarray(rs.randn(d, v) * 0.3, jnp.float32)
+    t = jnp.asarray(rs.randint(0, v, (1, n)))
+    return h, w, t
+
+
+def _optax_xent(h, w, t):
+    import optax
+
+    logits = h.astype(jnp.float32) @ w
+    return optax.softmax_cross_entropy_with_integer_labels(logits, t).mean()
+
+
+# ``tol`` is relative to the reference's largest entry: fp32 is exact up
+# to summation order; bf16's is what the checkpointed autodiff loop this
+# pass replaced met on the same inputs (one bf16 step of the largest dH).
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("n,chunk", [
+    (4096, 512),   # chunk divides the tokens: two whole groups of four
+    (5000, 512),   # ten chunks in three groups of four: pads inside a group
+    (24, 8),       # fewer tokens than one group: three chunks, one group
+    (5000, 2048),  # chunk >= the group's rows: one chunk a group
+], ids=["divides", "pads_in_group", "under_one_group", "chunk_is_group"])
+def test_chunked_xent_one_pass_matches_optax(n, chunk, dtype, tol):
     from hops_tpu.ops.xent import chunked_softmax_xent
 
-    rs = np.random.RandomState(1)
-    b, s, d, v = 2, 256, 32, 512
-    h = jnp.asarray(rs.randn(b, s, d), jnp.float32)
-    w = jnp.asarray(rs.randn(d, v) * 0.1, jnp.float32)
-    t = jnp.asarray(rs.randint(0, v, (b, s)))
+    h, w, t = _xent_case(n, 16, 67, dtype)
+    value, grads = jax.jit(jax.value_and_grad(
+        lambda h, w: chunked_softmax_xent(h, w, t, chunk=chunk), argnums=(0, 1)))(h, w)
+    ref_value, ref_grads = jax.value_and_grad(_optax_xent, argnums=(0, 1))(h, w, t)
+    np.testing.assert_allclose(value, ref_value, rtol=max(tol * 0.1, 1e-6))
+    # the undifferentiated call (the plain forward loop) gives the same value
+    np.testing.assert_allclose(
+        jax.jit(lambda h, w: chunked_softmax_xent(h, w, t, chunk=chunk))(h, w), value, rtol=1e-6)
+    for got, ref in zip(grads, ref_grads):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        np.testing.assert_allclose(got, ref, atol=tol * np.abs(ref).max(), rtol=0)
+
+
+def test_chunked_xent_scales_by_the_incoming_cotangent():
+    """The one pass makes d(loss sum); the ``bwd`` rule owes the rest of
+    the chain: here 3 / tokens, beside a second use of both inputs."""
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    h, w, t = _xent_case(5000, 16, 67, jnp.float32, seed=1)
+
+    def loss(xent, h, w):
+        return 3.0 * xent(h, w) + 0.01 * jnp.sum(h * h) + 0.1 * jnp.sum(w)
+
+    got = jax.grad(functools.partial(
+        loss, lambda h, w: chunked_softmax_xent(h, w, t, chunk=512)), argnums=(0, 1))(h, w)
+    ref = jax.grad(functools.partial(
+        loss, lambda h, w: _optax_xent(h, w, t)), argnums=(0, 1))(h, w)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
+
+
+def test_chunked_xent_counts_which_pass_was_traced():
+    from hops_tpu.ops.xent import chunked_softmax_xent
+    from hops_tpu.telemetry.export import render_prometheus
+    from hops_tpu.telemetry.metrics import REGISTRY
+
+    counter = REGISTRY.counter("hops_tpu_train_loss_traces_total", labels=("pass",))
+
+    def counts():
+        return tuple(counter.value(**{"pass": p}) for p in ("forward_only", "one_pass"))
+
+    h, w, t = _xent_case(64, 16, 67, jnp.float32)
 
     def loss(h, w):
-        return chunked_softmax_xent(h, w, t, chunk=64)
+        return chunked_softmax_xent(h, w, t, chunk=16)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(h, w).as_text()
-    full, chunked = f"{b * s}x{v}", f"64x{v}"
-    assert chunked in text       # per-chunk logits exist
-    assert full not in text      # full logits never do
+    forward_only, one_pass = counts()
+    jax.jit(loss)(h, w)  # evaluation: the forward loop alone
+    assert counts() == (forward_only + 1, one_pass)
+    jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(h, w)  # a training step's call
+    assert counts() == (forward_only + 1, one_pass + 1)
+    assert any(line.startswith("hops_tpu_train_loss_traces_total{") and 'pass="one_pass"' in line
+               for line in render_prometheus().splitlines())  # what /metrics serves
+
+
+def _vocab_tensors(text, vocab):
+    """``(rows, dtype)`` of every tensor type in lowered StableHLO that
+    has a dim of ``vocab``: ``rows`` is the product of its other dims."""
+    import re
+
+    out = set()
+    for dims, dtype in re.findall(r"tensor<((?:\d+x)+)(\w+)>", text):
+        dims = [int(x) for x in dims.split("x") if x]
+        if vocab in dims:
+            out.add((int(np.prod(dims)) // vocab, dtype))
+    return out
+
+
+def _lowered_xent_grad(tokens, d, vocab, chunk):
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    h = jax.ShapeDtypeStruct((2, tokens // 2, d), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((d, vocab), jnp.float32)
+    t = jax.ShapeDtypeStruct((2, tokens // 2), jnp.int32)
+
+    def loss(h, w, t):
+        return chunked_softmax_xent(h, w, t, chunk=chunk)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(h, w, t).as_text()
+
+
+@pytest.mark.parametrize("chunk", [512, 4096])
+def test_chunked_xent_never_materializes_full_logits(chunk):
+    """The memory contract of the one-pass loss, read off the lowered
+    forward + backward of 8,192 tokens: no ``tokens x vocab`` tensor of
+    any dtype, no fp32 tensor wider than ``chunk x vocab`` (a chunk's
+    logits; the ``d x vocab`` weight gradient is shorter still), and no
+    staging tensor taller than ``max(chunk, 2048)`` rows."""
+    tokens, d, vocab = 8192, 16, 67
+    seen = _vocab_tensors(_lowered_xent_grad(tokens, d, vocab, chunk), vocab)
+    assert (chunk, "f32") in seen                    # per-chunk logits exist
+    assert (max(chunk, 2048), "bf16") in seen        # and a group's staged dlogits
+    assert max(rows for rows, _ in seen) <= max(chunk, 2048) < tokens
+    assert max(rows for rows, dtype in seen if dtype == "f32") <= chunk
+
+
+def test_chunked_xent_backward_does_not_recompute_the_logits():
+    """One logits-shaped ``dot_general`` in the whole lowered forward +
+    backward: the chunk visit's. (Autodiff of the checkpointed loop held
+    two: the forward's and the backward's recompute.) And the two
+    gradient matmuls run once a group, over the group's rows."""
+    import re
+
+    chunk, d, vocab = 512, 16, 67
+    text = _lowered_xent_grad(8192, d, vocab, chunk)
+    results = re.findall(r"stablehlo\.dot_general.*-> tensor<([\dx]+)x\w+>", text)
+    assert results.count(f"{chunk}x{vocab}") == 1    # logits
+    assert results.count(f"2048x{d}") == 1           # dH of a group
+    assert results.count(f"{d}x{vocab}") == 1        # dW of a group
+    assert len(results) == 3
 
 
 @pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)

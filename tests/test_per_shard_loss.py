@@ -98,7 +98,9 @@ def test_four_device_step_gathers_nothing_and_loops_hold_no_collective():
     assert "all-gather" not in hlo
     comps = _computations(hlo)
     bodies = set(re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", hlo))
-    assert bodies <= set(comps) and len(bodies) >= 2, bodies  # the loss loop, forward and backward
+    # the loss loop: one pass since PR 26 (a shard this small is one group,
+    # so its chunk loop is the only `while` left; before: forward and backward)
+    assert bodies <= set(comps) and len(bodies) >= 1, bodies
     for name in _reachable(comps, bodies):
         assert not [c for c in _COLLECTIVES if re.search(rf"\b{c}(-start)?\(", comps[name])], name
 
