@@ -1,25 +1,18 @@
-"""Benchmark harness — the TPU port of the reference's benchmark notebook.
+"""Host-plane benchmark tiers: none of them touches an accelerator.
 
-Reference: notebooks/ml/Benchmarks/benchmark.ipynb — ResNet-50 on
-synthetic 224x224x3 batches under MirroredStrategy, bs=8/GPU (SURVEY.md
-§6). Here: ResNet-50 fwd+bwd+SGD on synthetic data, bf16 on the MXU,
-per-chip batch sized for TPU (128 by default), data-parallel over all
-visible chips.
+Each tier is one flag and prints ONE JSON line on stdout (progress goes
+to stderr): ``--input-pipeline``, ``--online-store``, ``--serving-fleet``,
+``--multi-host``, ``--partition``, ``--tail``, ``--continuous-loop``,
+``--hot-path``, ``--fault-overhead``, ``--tracing-overhead``,
+``--capture-overhead``, ``--replay`` / ``--replay-scenario``. They time
+host code (loader, online store, router, placement, tracing plumbing)
+on the host's CPU; ``docs/operations.md`` has the operator's command
+for each. There is no default tier: without a tier flag the usage is
+printed and the exit code is 2.
 
-Prints ONE JSON line:
-  {"metric": "resnet50_samples_per_sec_per_chip", "value": N,
-   "unit": "samples/s/chip", "vs_baseline": N | null, "platform": ...,
-   "device_kind": ..., "n_chips": N}
-
-The chip-facing tiers (default ResNet-50, ``--lm``, ``--lm-serving``)
-measure the accelerator: without ``--smoke`` they exit non-zero with
-one line when JAX's default backend is not ``tpu``. ``--smoke`` is the
-tiny CPU-safe plumbing run (its line carries ``"smoke": true``). The
-host-only tiers never touch an accelerator.
-
-The reference publishes no numbers (BASELINE.md); ``vs_baseline``
-compares against the self-measured entry in BASELINE_SELF.json when
-one exists for the platform (read-only — a run never writes it).
+The chip's yardstick is ``benchmark/run.py`` (cells in
+``BENCHMARK.json``, readings in ``PERF.md``); ``chip_smoke.py`` is the
+go/no-go that the system starts on a chip.
 """
 
 from __future__ import annotations
@@ -34,9 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-BASELINE_FILE = Path(__file__).parent / "BASELINE_SELF.json"
-
-
 def _note(msg: str) -> None:
     """Progress line on stderr (stdout carries only the JSON line), so a
     slow compile and a hung run can be told apart from the outside."""
@@ -44,341 +34,6 @@ def _note(msg: str) -> None:
 
 
 _T0 = time.perf_counter()
-
-
-def _require_tpu() -> None:
-    """A measurement path that finds no chip fails; it never falls back
-    to the CPU (or to the Pallas interpreter the kernels would silently
-    pick there)."""
-    backend = jax.default_backend()
-    if backend != "tpu":
-        raise SystemExit(
-            f"bench.py: no TPU (jax.default_backend() == {backend!r}); only "
-            "--smoke and the host-only tiers run without a chip"
-        )
-
-
-def _timed_loop(step_fn, state, batch, *, steps, warmup, scan_chunk):
-    """Shared timing harness for every bench: compile+warmup, then
-    whole-dispatch timing ended by ``block_until_ready``. Returns
-    ``(elapsed_s, total_steps)``."""
-    _note(f"compiling + warmup ({max(1, warmup // scan_chunk)} dispatches of {scan_chunk} steps)")
-    for _ in range(max(1, warmup // scan_chunk)):
-        state, loss = step_fn(state, batch)
-    jax.block_until_ready(loss)
-    _note("warmup done, timing")
-
-    n_dispatch = max(1, steps // scan_chunk)  # whole dispatches only, never overshoot
-    t0 = time.perf_counter()
-    for _ in range(n_dispatch):
-        state, loss = step_fn(state, batch)
-    jax.block_until_ready(loss)
-    return time.perf_counter() - t0, n_dispatch * scan_chunk
-
-
-def run_bench(
-    per_chip_batch: int = 128,  # measured sweet spot on v5e (96/192/256 all slower, BENCHMARKS.md)
-    image_size: int = 224,
-    steps: int = 32,
-    warmup: int = 16,
-    smoke: bool = False,
-    scan_chunk: int = 16,
-    multihost: bool = False,
-    remat: bool = False,
-    grad_comms: str = "none",
-) -> dict:
-    """Time the ResNet-50 train step with a device-side training loop.
-
-    ``lax.scan`` runs ``scan_chunk`` optimizer steps per dispatch — the
-    idiomatic TPU training loop (host only dispatches and reads
-    metrics). Pass ``scan_chunk=1`` for the per-dispatch variant.
-
-    ``grad_comms`` picks the gradient-communication schedule
-    (``none`` = XLA's implicit fp32 AllReduce; ``quantized`` /
-    ``zero1`` / ``quantized+zero1`` / ``overlap`` /
-    ``quantized+overlap`` / ``zero2`` / ``zero3`` route through
-    ``hops_tpu.parallel.grad_comms``) so the trajectory can attribute
-    comms wins; the chosen mode and its compression ratio travel in
-    the result. Overlap-scheduled modes (``overlap``/``zero2``/
-    ``zero3``) additionally re-time the step against the matching
-    compute-then-communicate schedule and a no-reduction reference to
-    report ``overlap_fraction`` — the share of comms time hidden under
-    backward — plus per-chip optimizer-state bytes (the ZeRO ladder's
-    memory story).
-    """
-    import dataclasses as _dc
-
-    from hops_tpu.models import common
-    from hops_tpu.models.resnet import ResNet18ish, ResNet50
-    from hops_tpu.parallel import grad_comms as gc_lib
-    from hops_tpu.parallel.strategy import CollectiveAllReduceStrategy, Strategy
-
-    gc_cfg = gc_lib.GradCommsConfig.parse(grad_comms)
-
-    if smoke:
-        model = ResNet18ish(dtype=jnp.float32, remat=remat)
-        per_chip_batch, image_size, steps, warmup, scan_chunk = 8, 32, 4, 2, 2
-    else:
-        model = ResNet50(num_classes=1000, remat=remat)
-
-    scan_chunk = min(scan_chunk, steps)  # --steps 8 means 8 steps, not 16
-    # --multihost: the whole-slice mesh (XLA AllReduce over ICI/DCN),
-    # launched one process per host via ``python -m hops_tpu.launch``
-    # (RUNBOOK_v5e64.md). Default: all chips of this host.
-    strategy = CollectiveAllReduceStrategy() if multihost else Strategy()
-    n_chips = strategy.num_replicas_in_sync
-    global_batch = per_chip_batch * n_chips
-    local_batch = per_chip_batch * (jax.local_device_count() if multihost else n_chips)
-    _note(f"backend up: {n_chips} chip(s), platform={jax.devices()[0].platform}")
-
-    # Init under ONE jit at a tiny batch: params and BN stats are
-    # batch-independent, and an eager init would dispatch (and compile)
-    # every conv as its own program.
-    import functools
-
-    init_fn = functools.partial(
-        common.create_bn_train_state,
-        model,
-        input_shape=(8, image_size, image_size, 3),
-    )
-    # One jit wrapper, hoisted: make_state_for runs once per timed
-    # config and a fresh ``jax.jit(init_fn)`` each time would recompile.
-    jit_init = jax.jit(init_fn)
-
-    def make_state_for(cfg):
-        st = strategy.replicate(jit_init(jax.random.PRNGKey(0)))
-        if cfg is not None and cfg.update_sharding == "zero3":
-            # ZeRO-3 trains on the flat-shard state carrier: params and
-            # moments live 1/N-sharded across the data axis at rest.
-            st = gc_lib.zero3_init(st, strategy.mesh, strategy.data_axis)
-        elif cfg is not None and cfg.update_sharding in (
-            "cross_replica", "zero2",
-        ):
-            # ZeRO-1/2 persistent-sharded moments: optimizer state
-            # lives 1/N-sharded between steps (params stay dense) —
-            # opt_state_bytes_per_chip on the JSON line shows the ~1/N.
-            st = gc_lib.zero12_init(st, strategy.mesh, cfg,
-                                    strategy.data_axis)
-        return st
-
-    def build_step(cfg):
-        ts = common.make_bn_train_step(grad_comms=cfg)
-
-        def multi_step(state, batch):
-            def body(st, _):
-                st, metrics = ts(st, batch)
-                return st, metrics["loss"]
-
-            state, losses = jax.lax.scan(body, state, None, length=scan_chunk)
-            return state, losses[-1]
-
-        # Propagate the inner step's grad-comms marker (and the scan
-        # factor, so the wire-byte counters account every fused
-        # optimizer step).
-        multi_step.grad_comms = cfg
-        multi_step.grad_comms_steps = scan_chunk
-        return strategy.step(multi_step, grad_comms=cfg)
-
-    state = make_state_for(gc_cfg)
-    _note("params initialized")
-    step_fn = build_step(gc_cfg)
-    gc_pre, gc_post = (
-        gc_lib.wire_bytes(state.params, gc_cfg) if gc_cfg is not None else (0, 0)
-    )
-    # Read off the live initial state BEFORE the timed loop donates it
-    # — re-initializing a whole state later just to count bytes would
-    # double the init cost and peak memory.
-    gc_opt_bytes = _opt_state_bytes(state) if gc_cfg is not None else (0, 0)
-
-    # Each process contributes its own local shard of the global batch.
-    rs = np.random.RandomState(jax.process_index())
-    batch = strategy.distribute_batch(
-        {
-            "image": rs.randn(local_batch, image_size, image_size, 3).astype(np.float32),
-            "label": rs.randint(0, 10, (local_batch,)),
-        }
-    )
-
-    elapsed, total_steps = _timed_loop(
-        step_fn, state, batch, steps=steps, warmup=warmup,
-        scan_chunk=scan_chunk,
-    )
-    samples_per_sec = global_batch * total_steps / elapsed
-    result = {
-        "samples_per_sec": samples_per_sec,
-        "samples_per_sec_per_chip": samples_per_sec / n_chips,
-        "step_time_ms": elapsed / total_steps * 1e3,
-        "n_chips": n_chips,
-        "global_batch": global_batch,
-        "platform": jax.devices()[0].platform,
-    }
-    if gc_cfg is not None:
-        result["grad_comms"] = gc_cfg.mode
-        result["grad_comms_compression"] = round(gc_pre / gc_post, 2) if gc_post else 1.0
-        result["opt_state_bytes"] = gc_opt_bytes[0]
-        result["opt_state_bytes_per_chip"] = gc_opt_bytes[1]
-        overlapish = gc_cfg.overlap or gc_cfg.update_sharding in ("zero2", "zero3")
-        if overlapish:
-            # Re-time against (a) the matching compute-then-communicate
-            # schedule and (b) a no-reduction reference: the comms time
-            # is (a) - (b), the hidden share is ((a) - overlap) / comms.
-            seq_cfg = (
-                _dc.replace(gc_cfg, overlap=False)
-                if gc_cfg.overlap
-                else _dc.replace(gc_cfg, update_sharding="cross_replica")
-            )
-            local_cfg = gc_lib.GradCommsConfig(local_only=True)
-            t_overlap = elapsed / total_steps
-            ref = {}
-            for name, cfg in (("sequential", seq_cfg), ("local", local_cfg)):
-                _note(f"overlap attribution: timing the {name} reference "
-                      f"({cfg.mode})")
-                el, n = _timed_loop(
-                    build_step(cfg), make_state_for(cfg), batch,
-                    steps=steps, warmup=warmup, scan_chunk=scan_chunk,
-                )
-                ref[name] = el / n
-            comms_s = max(ref["sequential"] - ref["local"], 0.0)
-            hidden_s = max(ref["sequential"] - t_overlap, 0.0)
-            frac = min(1.0, hidden_s / comms_s) if comms_s > 0 else 0.0
-            result["overlap_fraction"] = round(frac, 4)
-            result["seq_step_time_ms"] = round(ref["sequential"] * 1e3, 3)
-            result["nocomms_step_time_ms"] = round(ref["local"] * 1e3, 3)
-            from hops_tpu.telemetry import REGISTRY
-
-            REGISTRY.gauge(
-                "hops_tpu_grad_comms_overlap_fraction",
-                "Share of gradient-comms time hidden under backward "
-                "compute (bench-measured)",
-                labels=("mode",),
-            ).set(frac, mode=gc_cfg.mode)
-    return result
-
-
-def _opt_state_bytes(state) -> tuple[int, int]:
-    """(total, per-chip) optimizer-state bytes: per-chip counts each
-    leaf's addressable shard, so ZeRO-3's sharded-at-rest moments show
-    their 1/N footprint while replicated-contract modes show the full
-    one."""
-    total = per_chip = 0
-    for leaf in jax.tree.leaves(state.opt_state):
-        itemsize = jnp.dtype(leaf.dtype).itemsize
-        nbytes = leaf.size * itemsize
-        total += nbytes
-        shards = getattr(leaf, "addressable_shards", None)
-        per_chip += shards[0].data.size * itemsize if shards else nbytes
-    return int(total), int(per_chip)
-
-
-def run_lm_bench(
-    per_chip_batch: int = 8,
-    seq_len: int = 1024,
-    steps: int = 16,
-    warmup: int = 8,
-    smoke: bool = False,
-    scan_chunk: int = 8,
-    remat: bool = False,
-    loss_chunk: int = 512,
-) -> dict:
-    """Driver-grade LM training headline: tokens/s/chip and MFU%.
-
-    The LM stack is half the framework (flash kernels, ring/Ulysses,
-    chunked xent, the serving engine) but through round 4 only ResNet
-    had a driver-style number (round-4 review item #4). This times the
-    full next-token training step — GPT-2-medium-class TransformerLM
-    (~180M params: d_model 1024, d_head 128 per the round-4 decode
-    finding, 12 layers), flash attention, token-chunked LM-head loss,
-    bf16 matmuls — with the same device-side `lax.scan` loop and sync
-    discipline as the ResNet bench.
-
-    MFU uses the standard model-FLOPs accounting: 6*N_matmul per token
-    for fwd+bwd over every matmul parameter (embedding lookups are
-    gathers, not matmuls) plus the causal-attention term
-    6 * d_model * seq * layers; remat recompute is deliberately NOT
-    credited, so --remat reports honest (lower) MFU.
-    """
-    import functools
-
-    from hops_tpu.models import common
-    from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
-    from hops_tpu.parallel.strategy import Strategy
-
-    if smoke:
-        d_model, num_layers, vocab = 64, 2, 256
-        per_chip_batch, seq_len, steps, warmup, scan_chunk, loss_chunk = 2, 64, 4, 2, 2, 32
-    else:
-        d_model, num_layers, vocab = 1024, 12, 32000
-
-    model = TransformerLM(
-        vocab_size=vocab,
-        d_model=d_model,
-        num_heads=8,
-        num_layers=num_layers,
-        dtype=jnp.bfloat16,
-        attention_impl="flash",
-        remat=remat,
-    )
-    strategy = Strategy()
-    n_chips = strategy.num_replicas_in_sync
-    global_batch = per_chip_batch * n_chips
-    _note(f"backend up: {n_chips} chip(s), platform={jax.devices()[0].platform}")
-
-    init_fn = functools.partial(
-        common.create_train_state, model, input_shape=(1, 8), input_dtype=jnp.int32
-    )
-    state = strategy.replicate(jax.jit(init_fn)(jax.random.PRNGKey(0)))
-    n_params = sum(x.size for x in jax.tree.leaves(state.params))
-    n_embed = state.params["embed"]["embedding"].size
-    _note(f"params initialized: {n_params / 1e6:.1f}M ({(n_params - n_embed) / 1e6:.1f}M matmul)")
-
-    train_step = make_lm_train_step(loss_chunk=loss_chunk)
-    scan_chunk = min(scan_chunk, steps)
-
-    def multi_step(state, batch):
-        def body(st, _):
-            st, metrics = train_step(st, batch)
-            return st, metrics["loss"]
-
-        state, losses = jax.lax.scan(body, state, None, length=scan_chunk)
-        return state, losses[-1]
-
-    step_fn = strategy.step(multi_step)
-    rs = np.random.RandomState(jax.process_index())
-    # seq_len + 1 ids per row: the step slices inputs[:-1] / targets[1:],
-    # so the model itself runs at exactly seq_len.
-    batch = strategy.distribute_batch(
-        {"tokens": rs.randint(0, vocab, (global_batch, seq_len + 1)).astype(np.int32)}
-    )
-
-    elapsed, total_steps = _timed_loop(
-        step_fn, state, batch, steps=steps, warmup=warmup,
-        scan_chunk=scan_chunk,
-    )
-    tokens_per_sec = global_batch * seq_len * total_steps / elapsed
-    # Model FLOPs per trained token: 2 MACs/param fwd, 2x that bwd,
-    # plus causal attention (QK^T + AV, s/2 average span): fwd
-    # 2 * 2 * d * s/2 * 2 = 2*d*s per layer-token, x3 for training.
-    fwd_flops_per_token = 2 * (n_params - n_embed) + 2 * d_model * seq_len * num_layers
-    train_flops_per_token = 3 * fwd_flops_per_token
-    achieved = tokens_per_sec / n_chips * train_flops_per_token
-    # The peak comes from the roofline's own table, keyed by the chip's
-    # device_kind; a kind it does not know raises (MFU against a guessed
-    # roof is not a number). --smoke is the CPU plumbing run: no MFU.
-    from hops_tpu.runtime.diagnostics import device_peaks
-
-    peak = None if smoke else device_peaks()[0]
-    return {
-        "tokens_per_sec": tokens_per_sec,
-        "tokens_per_sec_per_chip": tokens_per_sec / n_chips,
-        "step_time_ms": elapsed / total_steps * 1e3,
-        "mfu_pct": round(100 * achieved / peak, 2) if peak else None,
-        "model_tflops_per_sec_per_chip": round(achieved / 1e12, 2),
-        "n_params_m": round(n_params / 1e6, 1),
-        "n_chips": n_chips,
-        "global_batch": global_batch,
-        "seq_len": seq_len,
-        "platform": jax.devices()[0].platform,
-    }
 
 
 def run_input_pipeline_bench(
@@ -1586,12 +1241,6 @@ def run_tail_bench(
                         min_samples=6, factor=3.0, floor_ms=float(work_ms) * 2,
                         probe_interval_s=0.2, readmit_probes=3),
                 )
-            hedges0 = {
-                o: REGISTRY.counter(
-                    "hops_tpu_fleet_hedges_total", labels=("model", "outcome")
-                ).value(model="tailbench", outcome=o)
-                for o in ("won", "lost", "denied")
-            }
             ejections0 = REGISTRY.counter(
                 "hops_tpu_fleet_ejections_total", labels=("model",)
             ).value(model="tailbench")
@@ -1605,6 +1254,17 @@ def run_tail_bench(
                 with load.lock:
                     load.lat_ms.clear()
                     warm_errors = load.errors
+                # Hedges are counted over the same window as the
+                # requests they are a share of: the warm-up's requests
+                # are not in `requests`, so its hedges are not in
+                # `hedges_fired` (the budget's bucket carries at most
+                # `budget_burst` tokens across).
+                hedges0 = {
+                    o: REGISTRY.counter(
+                        "hops_tpu_fleet_hedges_total", labels=("model", "outcome")
+                    ).value(model="tailbench", outcome=o)
+                    for o in ("won", "lost", "denied")
+                }
                 # The gray replica appears NOW, mid-traffic: slow, not
                 # dead — every response still a 200.
                 slow_port = f.manager.ready()[-1].port
@@ -2692,270 +2352,10 @@ def run_workload_replay_bench(
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def _lm_serving_workload(requests: int, seed: int, rate_rps: float, *,
-                         short, long, long_frac, budget):
-    """Seeded Poisson arrival process with a mixed prompt-length
-    distribution: the open-loop load model serving actually sees
-    (bursts + a heavy tail of long prompts), not a closed batch."""
-    rs = np.random.RandomState(seed)
-    arrivals = np.cumsum(rs.exponential(1.0 / rate_rps, requests))
-    prompts, budgets = [], []
-    for _ in range(requests):
-        lo, hi = long if rs.rand() < long_frac else short
-        prompts.append(rs.randint(0, 256, rs.randint(lo, hi + 1)).astype(np.int32))
-        budgets.append(int(rs.randint(budget[0], budget[1] + 1)))
-    return arrivals, prompts, budgets
-
-
-def _drive_lm_serving(engine, arrivals, prompts, budgets) -> dict:
-    """Open-loop driver: submit each request at its arrival time (wall
-    clock), step the engine whenever it has work, and collect per-ticket
-    TTFT + tokens. Late arrivals queue — exactly the backpressure the
-    paged/chunked scheduler is supposed to absorb."""
-    n = len(prompts)
-    stats0 = engine.stats()
-    t0 = time.perf_counter()
-    done: dict[int, list[int]] = {}
-    order: list[int] = []
-    i = 0
-    while len(done) < n:
-        now = time.perf_counter() - t0
-        while i < n and arrivals[i] <= now:
-            order.append(engine.submit(prompts[i], max_new_tokens=budgets[i]))
-            i += 1
-        if engine.has_work:
-            for t in engine.step():
-                done[t] = engine.result(t)
-        elif i < n:
-            time.sleep(min(0.002, max(0.0, arrivals[i] - now)))
-    wall = time.perf_counter() - t0
-    stats1 = engine.stats()
-    ttfts = np.asarray([engine.ttft_s[t] for t in order])
-    tokens = sum(len(v) for v in done.values())
-    d_disp = stats1["dispatches"] - stats0["dispatches"]
-    occ = (
-        stats1["mean_occupancy"] * stats1["dispatches"]
-        - stats0["mean_occupancy"] * stats0["dispatches"]
-    ) / max(d_disp, 1)
-    out = {
-        "wall_s": wall,
-        "tokens": tokens,
-        "tokens_per_sec": tokens / wall,
-        "ttft_p50_ms": float(np.percentile(ttfts, 50) * 1e3),
-        "ttft_p99_ms": float(np.percentile(ttfts, 99) * 1e3),
-        "slot_occupancy": round(occ, 4),
-    }
-    if stats1.get("cache_layout") == "paged":
-        out.update(
-            block_pool_peak_util=round(
-                stats1["blocks_peak_used"] / stats1["blocks_total"], 4
-            ),
-            prefill_chunks=stats1["prefill_chunks"] - stats0["prefill_chunks"],
-            preempted_prefills=stats1["preemptions"] - stats0["preemptions"],
-        )
-    return out
-
-
-def run_lm_serving_bench(
-    requests: int = 40,
-    seed: int = 0,
-    rate_rps: float | None = None,
-    smoke: bool = False,
-    tp: bool = False,
-) -> dict:
-    """The ``--lm-serving`` tier: the continuous-batching LM engine
-    under seeded Poisson load — paged KV cache + chunked prefill vs the
-    dense full-prefill baseline AT EQUAL CACHE MEMORY.
-
-    Both engines get the same token budget of persistent KV memory;
-    the dense layout spends it on ``budget / max_decode_len`` max-length
-    slot reservations, while the paged layout spends it on a block pool
-    shared by 2x the slots (slot count bounded by LIVE tokens). Under
-    the same arrival process the paged engine keeps more requests
-    decoding concurrently and never freezes the batch behind a long
-    prompt's prefill — which is what tokens/s and TTFT p99 measure.
-    Token streams are bit-identical between the two (the equivalence
-    tests pin this), so the comparison is pure scheduling/memory.
-
-    ``tp=True`` runs both engines tensor-parallel over every visible
-    device (``parallel/tp_inference`` Megatron sharding, paged pools
-    head-sharded) — the multichip variant; tokens/s/chip divides by the
-    mesh size.
-    """
-    import jax  # noqa: F811 — resolved at call time under forced-cpu smoke
-    import jax.numpy as jnp
-
-    from hops_tpu.models.transformer import TransformerLM
-    from hops_tpu.modelrepo.lm_engine import LMEngine
-
-    if smoke:
-        cap, d_model, layers = 96, 32, 2
-        page, chunk = 8, 16
-        short, long_, long_frac, budget = (4, 12), (32, 64), 0.3, (4, 8)
-        requests = min(requests, 10)
-        dense_slots = 2
-        rate = rate_rps or 6.0
-    else:
-        cap, d_model, layers = 192, 64, 2
-        page, chunk = 16, 32
-        short, long_, long_frac, budget = (8, 24), (96, 160), 0.3, (8, 24)
-        dense_slots = 4
-        # CPU-tier tuned load point: deep enough queueing that the
-        # dense engine's 4 slots saturate and its multi-request
-        # admission waves pad to the 192 bucket (monolithic prefill
-        # stalling decode), while the paged engine's 2x slots + fused
-        # prefill chunks keep absorbing arrivals — measured 3-4x
-        # tokens/s and ~40x lower TTFT p99 across reps on the CPU
-        # tier. TPU runs should pass --lm-serving-rate sized to the
-        # chip.
-        rate = rate_rps or 40.0
-    mesh = None
-    n_chips = 1
-    if tp:
-        from jax.sharding import Mesh
-
-        devs = np.array(jax.devices())
-        if devs.size > 1:
-            mesh = Mesh(devs, ("model",))
-            n_chips = devs.size
-    budget_tokens = dense_slots * cap
-    paged_slots = dense_slots * 2
-    pool_blocks = 1 + budget_tokens // page
-    # int8 pool at the SAME byte budget: 1-byte values + one fp32 scale
-    # per position for each of k/v, vs 4-byte fp32 values — the block
-    # count scales by the per-token byte ratio (~3.2x at head_dim 16).
-    head_dim = d_model // 4
-    fp_tok_bytes = head_dim * 4 * 2
-    q8_tok_bytes = (head_dim + 4) * 2
-    pool_blocks_int8 = 1 + (budget_tokens * fp_tok_bytes) // (
-        q8_tok_bytes * page)
-    live_tokens_ratio = (pool_blocks_int8 - 1) / max(pool_blocks - 1, 1)
-
-    model = TransformerLM(
-        vocab_size=256, d_model=d_model, num_heads=4, num_layers=layers,
-        dtype=jnp.float32, attention_impl="reference", max_decode_len=cap,
-        ragged_decode=True,
-    )
-    model_int8 = model.clone(kv_cache_dtype="int8")
-    params = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-    _note(
-        f"lm-serving bench: budget {budget_tokens} KV tokens -> dense "
-        f"{dense_slots} slots vs paged {paged_slots} slots "
-        f"({pool_blocks} blocks of {page}; int8 {pool_blocks_int8} blocks "
-        f"= {live_tokens_ratio:.2f}x live tokens), {requests} req @ {rate}/s"
-    )
-
-    results = {}
-    for layout in ("dense", "paged", "paged_int8"):
-        if layout == "dense":
-            engine = LMEngine(
-                model, params, slots=dense_slots,
-                prefill_buckets=(max(32, chunk), cap), mesh=mesh,
-            )
-        elif layout == "paged_int8":
-            engine = LMEngine(
-                model_int8, params, slots=paged_slots, kv_page_size=page,
-                kv_pool_blocks=int(pool_blocks_int8), prefill_chunk=chunk,
-                mesh=mesh,
-            )
-        else:
-            engine = LMEngine(
-                model, params, slots=paged_slots, kv_page_size=page,
-                kv_pool_blocks=pool_blocks, prefill_chunk=chunk, mesh=mesh,
-            )
-        # Warm the compiles OUTSIDE the timed window: one short and one
-        # long request touch every program shape the workload uses.
-        rs = np.random.RandomState(999)
-        engine.submit(rs.randint(0, 256, short[1]), max_new_tokens=2)
-        engine.submit(rs.randint(0, 256, long_[1]), max_new_tokens=2)
-        engine.run()
-        _note(f"{layout}: warm, driving Poisson load")
-        arrivals, prompts, budgets = _lm_serving_workload(
-            requests, seed, rate, short=short, long=long_,
-            long_frac=long_frac, budget=budget,
-        )
-        results[layout] = _drive_lm_serving(engine, arrivals, prompts, budgets)
-        _note(
-            f"{layout}: {results[layout]['tokens_per_sec']:.1f} tok/s, "
-            f"ttft p99 {results[layout]['ttft_p99_ms']:.0f} ms"
-        )
-    paged, dense = results["paged"], results["dense"]
-    q8 = results["paged_int8"]
-    return {
-        "tokens_per_sec_per_chip": paged["tokens_per_sec"] / n_chips,
-        "ttft_p50_ms": round(paged["ttft_p50_ms"], 1),
-        "ttft_p99_ms": round(paged["ttft_p99_ms"], 1),
-        "slot_occupancy": paged["slot_occupancy"],
-        "block_pool_peak_util": paged["block_pool_peak_util"],
-        "prefill_chunks": paged["prefill_chunks"],
-        "preempted_prefills": paged["preempted_prefills"],
-        "dense_tokens_per_sec_per_chip": round(
-            dense["tokens_per_sec"] / n_chips, 2
-        ),
-        "dense_ttft_p99_ms": round(dense["ttft_p99_ms"], 1),
-        "speedup_vs_dense": round(
-            paged["tokens_per_sec"] / dense["tokens_per_sec"], 3
-        ),
-        # int8 pool at the SAME byte budget: the capacity headline is
-        # live tokens per pool (blocks scale by the per-token byte
-        # ratio); greedy streams stay bit-identical (test-pinned), so
-        # tokens/s differences are scheduling, not output.
-        "int8_tokens_per_sec_per_chip": round(
-            q8["tokens_per_sec"] / n_chips, 2
-        ),
-        "int8_ttft_p99_ms": round(q8["ttft_p99_ms"], 1),
-        "int8_pool_blocks": int(pool_blocks_int8),
-        "fp_pool_blocks": int(pool_blocks),
-        "int8_live_tokens_ratio": round(live_tokens_ratio, 2),
-        "int8_block_pool_peak_util": q8["block_pool_peak_util"],
-        "requests": requests,
-        "rate_rps": rate,
-        "n_chips": n_chips,
-        "platform": jax.devices()[0].platform,
-    }
-
-
 def main() -> None:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--smoke", action="store_true", help="tiny CPU-safe run")
-    parser.add_argument(
-        "--batch", type=int, default=None,
-        help="per-chip batch size (default: 128 ResNet, 8 LM)",
-    )
-    parser.add_argument("--steps", type=int, default=None,
-                        help="timed steps (default: 32 ResNet, 16 LM)")
-    parser.add_argument(
-        "--scan-chunk", type=int, default=None,
-        help="train steps per dispatch, 1 = python loop "
-        "(default: 16 ResNet, 8 LM)",
-    )
-    parser.add_argument(
-        "--multihost", action="store_true",
-        help="whole-slice data parallelism; launch per host via hops_tpu.launch "
-        "(see RUNBOOK_v5e64.md)",
-    )
-    parser.add_argument(
-        "--grad-comms",
-        choices=["none", "quantized", "zero1", "quantized+zero1",
-                 "overlap", "quantized+overlap", "zero2",
-                 "quantized+zero2", "zero3", "quantized+zero3",
-                 "hier", "quantized+hier"],
-        default="none",
-        help="gradient-communication schedule for the ResNet bench: "
-        "block-scaled int8 quantized all-reduce, ZeRO-1/2/3 sharded "
-        "updates, overlap-scheduled (bucket-as-ready, launched "
-        "under backward) variants, and hierarchy-aware (intra-host "
-        "reduce, one inter-host exchange per byte) schedules "
-        "(hops_tpu.parallel.grad_comms); overlap/zero2/zero3 lines "
-        "carry overlap_fraction and per-chip optimizer-state bytes",
-    )
-    parser.add_argument(
-        "--remat", action="store_true",
-        help="per-block rematerialization: trade recompute FLOPs for "
-        "activation HBM bytes (A/B lever on the bandwidth-bound step)",
-    )
+    parser.add_argument("--smoke", action="store_true",
+                        help="the tier at a tiny size (plumbing check)")
     parser.add_argument(
         "--input-pipeline", choices=["sync", "threaded"], default=None,
         help="host input-pipeline bench (featurestore/loader.py): "
@@ -3068,38 +2468,6 @@ def main() -> None:
         "--replay-seed", type=int, default=0,
         help="seed for deterministic re-materialization of capped "
         "payloads (same artifact + seed = identical issued stream)",
-    )
-    parser.add_argument(
-        "--lm", action="store_true",
-        help="LM training headline instead of ResNet-50: ~180M-param "
-        "TransformerLM (d_head 128, flash attention, chunked LM-head "
-        "loss, bf16), reporting tokens/s/chip and MFU%%",
-    )
-    parser.add_argument(
-        "--seq-len", type=int, default=1024, help="--lm sequence length"
-    )
-    parser.add_argument(
-        "--lm-serving", action="store_true",
-        help="LM serving-engine tier: paged KV cache + chunked prefill "
-        "vs the dense full-prefill baseline at equal cache memory, "
-        "under a seeded Poisson arrival load; reports tokens/s/chip, "
-        "TTFT p50/p99, slot occupancy, block-pool utilization, and "
-        "preempted-prefill counts",
-    )
-    parser.add_argument(
-        "--lm-serving-requests", type=int, default=48,
-        help="--lm-serving: requests in the Poisson workload",
-    )
-    parser.add_argument(
-        "--lm-serving-rate", type=float, default=None,
-        help="--lm-serving: Poisson arrival rate (req/s; default "
-        "platform-tuned)",
-    )
-    parser.add_argument(
-        "--lm-serving-tp", action="store_true",
-        help="--lm-serving: run both engines tensor-parallel over all "
-        "visible devices (parallel/tp_inference; paged pools "
-        "head-sharded)",
     )
     args = parser.parse_args()
 
@@ -3265,157 +2633,7 @@ def main() -> None:
         print(json.dumps(line))
         return
 
-    if args.lm_serving:
-        if args.multihost:
-            parser.error(
-                "--lm-serving --multihost is not supported: use "
-                "--lm-serving-tp for the tensor-parallel variant on one "
-                "host's devices"
-            )
-        metric, unit, value_key = (
-            "lm_serving_tokens_per_sec_per_chip", "tokens/s/chip",
-            "tokens_per_sec_per_chip",
-        )
-
-        def do_run(**overrides):
-            overrides.pop("multihost", None)
-            return run_lm_serving_bench(
-                requests=args.lm_serving_requests,
-                rate_rps=args.lm_serving_rate,
-                tp=args.lm_serving_tp,
-                **overrides,
-            )
-    elif args.lm:
-        if args.multihost:
-            parser.error(
-                "--lm --multihost is not supported yet: the multihost LM "
-                "path is exercised by dryrun_multichip and the multihost "
-                "integration tests; the LM headline is single-chip"
-            )
-        if args.grad_comms != "none":
-            parser.error(
-                "--grad-comms applies to the ResNet data-parallel bench; "
-                "the LM headline is single-chip (no gradient collective "
-                "to optimize)"
-            )
-        metric, unit, value_key = "lm_tokens_per_sec_per_chip", "tokens/s/chip", "tokens_per_sec_per_chip"
-        batch = args.batch if args.batch is not None else 8
-        steps = args.steps if args.steps is not None else 16
-        scan_chunk = args.scan_chunk if args.scan_chunk is not None else 8
-
-        def do_run(**overrides):
-            return run_lm_bench(
-                per_chip_batch=batch, seq_len=args.seq_len, steps=steps,
-                scan_chunk=scan_chunk, remat=args.remat, **overrides,
-            )
-    else:
-        metric, unit, value_key = (
-            "resnet50_samples_per_sec_per_chip", "samples/s/chip", "samples_per_sec_per_chip"
-        )
-        batch = args.batch if args.batch is not None else 128
-        steps = args.steps if args.steps is not None else 32
-        scan_chunk = args.scan_chunk if args.scan_chunk is not None else 16
-
-        def do_run(**overrides):
-            return run_bench(
-                per_chip_batch=batch, steps=steps,
-                scan_chunk=scan_chunk, remat=args.remat,
-                grad_comms=args.grad_comms, **overrides,
-            )
-
-    multihost = {"multihost": True} if args.multihost else {}
-    if args.smoke:
-        # --smoke is the CPU plumbing run, also on a machine that has a
-        # chip: pin the platform before the backend comes up.
-        # (--smoke --multihost is the two-OS-process integration test's
-        # harness, launched via hops_tpu.launch on the fake mesh.)
-        jax.config.update("jax_platforms", "cpu")
-        result = do_run(smoke=True, **multihost)
-    else:
-        from hops_tpu.runtime import compile_cache
-
-        cache_dir = compile_cache.enable()  # before first use of the backend
-        _require_tpu()
-        _note(f"compile cache: {cache_dir}")
-        result = do_run(**multihost)
-    value = result[value_key]
-    if args.multihost and jax.process_index() != 0:
-        return  # one JSON line total: the chief's
-
-    # Read-only: a run never records itself as the baseline (the file
-    # is tracked, and a checkout must stay clean after a run).
-    baseline = None
-    if not args.smoke and BASELINE_FILE.exists():
-        baseline_key = result["platform"] + (
-            "_lmserv" if args.lm_serving else ("_lm" if args.lm else "")
-        )
-        baseline = json.loads(BASELINE_FILE.read_text()).get(
-            baseline_key, {}
-        ).get(value_key)
-
-    dev = jax.devices()[0]
-    line = {
-        "metric": metric,
-        "value": round(value, 2),
-        "unit": unit,
-        "vs_baseline": round(value / baseline, 4) if baseline else None,
-        "platform": dev.platform,
-        "device_kind": dev.device_kind,
-        "n_chips": result["n_chips"],
-    }
-    if args.smoke:
-        line["smoke"] = True
-    if result.get("grad_comms", "none") != "none":
-        # Attribution: which comms schedule produced this number, and
-        # how many wire bytes it saved (telemetry gauge's value).
-        line.update(
-            grad_comms=result["grad_comms"],
-            grad_comms_compression=result["grad_comms_compression"],
-        )
-        if "opt_state_bytes_per_chip" in result:
-            line["opt_state_bytes_per_chip"] = result["opt_state_bytes_per_chip"]
-        if "overlap_fraction" in result:
-            # The headline of the overlap-scheduled modes: comms time
-            # hidden under backward / total comms time, with the raw
-            # reference step times for the trajectory.
-            line.update(
-                overlap_fraction=result["overlap_fraction"],
-                seq_step_time_ms=result["seq_step_time_ms"],
-                nocomms_step_time_ms=result["nocomms_step_time_ms"],
-            )
-    if args.lm:
-        # The roofline context travels with the number (review item #4:
-        # "tokens/s/chip AND MFU% with the same roofline treatment").
-        line.update(
-            mfu_pct=result["mfu_pct"],
-            model_tflops_per_sec_per_chip=result["model_tflops_per_sec_per_chip"],
-            n_params_m=result["n_params_m"],
-            seq_len=result["seq_len"],
-        )
-    if args.lm_serving:
-        # The paged engine's headline plus the dense same-memory
-        # baseline it beat — the comparison IS the measurement.
-        line.update(
-            engine="paged",
-            ttft_p50_ms=result["ttft_p50_ms"],
-            ttft_p99_ms=result["ttft_p99_ms"],
-            slot_occupancy=result["slot_occupancy"],
-            block_pool_peak_util=result["block_pool_peak_util"],
-            prefill_chunks=result["prefill_chunks"],
-            preempted_prefills=result["preempted_prefills"],
-            dense_tokens_per_sec_per_chip=result["dense_tokens_per_sec_per_chip"],
-            dense_ttft_p99_ms=result["dense_ttft_p99_ms"],
-            speedup_vs_dense=result["speedup_vs_dense"],
-            # int8 paged leg at the same byte budget: the capacity
-            # headline (live tokens per pool) plus its throughput.
-            int8_tokens_per_sec_per_chip=result["int8_tokens_per_sec_per_chip"],
-            int8_ttft_p99_ms=result["int8_ttft_p99_ms"],
-            int8_pool_blocks=result["int8_pool_blocks"],
-            fp_pool_blocks=result["fp_pool_blocks"],
-            int8_live_tokens_ratio=result["int8_live_tokens_ratio"],
-            int8_block_pool_peak_util=result["int8_block_pool_peak_util"],
-        )
-    print(json.dumps(line))
+    parser.error("name a tier: bench.py has no default tier")
 
 
 if __name__ == "__main__":

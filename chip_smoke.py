@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-One process, one pass over the main path at the full width of the
-``bench.py --lm`` dense LM (d_model 1024, 8 heads so d_head 128, vocab
-32,000, bf16; depth cut from 12 layers to 4, weights random from a
-seed):
+One process, one pass over the main path with a small dense LM
+(d_model 1024, 8 heads so d_head 128, vocab 32,000, bf16, 4 layers,
+weights random from a seed). It is go/no-go, not a yardstick: the
+benchmark's cells (``benchmark/run.py``) are what is measured.
 
 1. *kernels*: every ``pallas_call`` family in ``ops/attention.py`` is
    compiled by Mosaic (``interpret=False``) at the main path's shapes
@@ -40,7 +40,7 @@ import time
 import traceback
 
 SEED = 0
-# The bench.py --lm model, depth cut.
+# A small dense LM at d_head 128.
 VOCAB, D_MODEL, NUM_HEADS, NUM_LAYERS = 32000, 1024, 8, 4
 D_HEAD = D_MODEL // NUM_HEADS
 # Train: seq 2048 so the Pallas flash kernels run, not the sub-1536 XLA route.

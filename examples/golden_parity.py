@@ -15,7 +15,7 @@ parity run uses the bundled **real** handwritten-digits dataset
 UCI repository), deterministically split, nearest-neighbor-upscaled to
 the models' 28x28 input. Same model families, same launchers, real
 handwritten-digit pixels; the bar is the reference's golden number for
-each launcher. Results land in BENCHMARKS.md's parity table.
+each launcher. The run prints its results; no file records them.
 """
 
 from __future__ import annotations

@@ -241,8 +241,8 @@ class LMEngine:
     ``run()`` drains everything and returns ``{ticket: tokens}``.
 
     ``decode_horizon`` scans that many decode steps on-device per
-    dispatch, amortizing host-dispatch latency (the measured serving
-    bottleneck — BENCHMARKS.md round-4 hardware notes) at the cost of
+    dispatch, amortizing host-dispatch latency (whether that pays is
+    not measured on this stack: PERF §7, ROADMAP S5) at the cost of
     admitting new requests only at horizon boundaries and of wasted
     steps for rows that retire mid-horizon. Output tokens are
     IDENTICAL for any horizon (an in-graph live mask retires rows at
@@ -398,12 +398,11 @@ class LMEngine:
                 raise ValueError(f"spec_k must be >= 2, got {spec_k}")
             if not getattr(draft_model, "ragged_decode", False):
                 raise ValueError("draft_model needs ragged_decode=True too")
-            # Speculation composes with BOTH other levers (round-4
-            # review item #3): decode_horizon runs the whole
-            # draft/score/accept loop ``horizon`` times inside one
-            # dispatch (the high-RTT configuration the dispatch-floor
-            # analysis asks for), and mesh= runs every spec program
-            # tensor-parallel like the non-spec engine.
+            # Speculation composes with BOTH other levers:
+            # decode_horizon runs the whole draft/score/accept loop
+            # ``horizon`` times inside one dispatch, and mesh= runs
+            # every spec program tensor-parallel like the non-spec
+            # engine.
         # Tensor parallelism: every engine program runs inside a
         # shard_map over ``tp_axis`` — params and KV caches shard on
         # their head axes (parallel/tp_inference.py layout), scalars
@@ -1155,10 +1154,10 @@ class LMEngine:
                         temps, topks, topps, seeds, ns)
 
         # Speculation x horizon: the whole draft/score/accept loop runs
-        # ``horizon`` times inside ONE dispatch — the configuration the
-        # dispatch-floor analysis asks for on high-RTT hosts (round-4
-        # review item #3: one ~84 ms dispatch then buys up to
-        # horizon * spec_k tokens). In-graph retirement mirrors
+        # ``horizon`` times inside ONE dispatch, which then buys up to
+        # horizon * spec_k tokens (whether speculation x horizon pays is
+        # not measured on this stack: ROADMAP S5). In-graph
+        # retirement mirrors
         # account() exactly: a row emits its accepted prefix plus the
         # bonus, truncated by its budget and its first eos, then goes
         # dead (cache index clamps to 0 — the free-slot convention).

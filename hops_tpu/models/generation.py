@@ -113,9 +113,9 @@ def generate(
     order on the temperature-scaled logits. Returns
     ``(b, L + max_new_tokens)`` token ids. ``model.max_decode_len`` must
     cover the full final length — size it to the final length, not
-    "big enough": decode cost scales with cache capacity (BENCHMARKS.md
-    "KV-cached decoding"). With ``eos_id`` set, rows that have emitted
-    it produce ``pad_id`` from the next step on (shapes stay static —
+    "big enough": the cache is allocated, and the dense decode kernel's
+    grid laid out, at that capacity. With ``eos_id`` set, rows that have
+    emitted it produce ``pad_id`` from the next step on (shapes stay static —
     the scan still runs ``max_new_tokens`` steps, the TPU-idiomatic
     trade for per-row early exit). ``row_offset`` is the global id of
     row 0 — sampling keys fold in global row ids, so a dp-sharded call

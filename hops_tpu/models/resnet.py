@@ -3,8 +3,8 @@
 The reference benchmarked ResNet-50 on synthetic 224x224x3 batches
 (notebooks/ml/Benchmarks/benchmark.ipynb cell 2, SURVEY.md §6). This is
 a fresh flax ResNet-v1.5 (stride-2 in the 3x3 of bottlenecks, as the
-benchmark model family) tuned for TPU HBM bandwidth, the measured
-bottleneck (BENCHMARKS.md roofline):
+benchmark model family) built to move fewer HBM bytes a step (the
+cell `resnet50.train-bs128` runs it; its per-op breakdown is PERF §5):
 
 - bfloat16 conv compute so the FLOPs land on the MXU;
 - bfloat16 norm *output* (``norm_dtype``) so the residual stream and
@@ -19,10 +19,9 @@ bottleneck (BENCHMARKS.md roofline):
   time);
 - optional per-block rematerialization (``remat``): save only the
   residual stream at block boundaries and recompute the 3-4 intra-block
-  conv/BN/relu activations during backward — on an HBM-bound step the
-  saved activation bytes buy more than the recompute FLOPs cost, since
-  the MXU has headroom (gradients are numerically identical; A/B via
-  ``bench.py --remat``).
+  conv/BN/relu activations during backward (gradients are numerically
+  identical; no cell sets it, so what it costs or saves on the chip is
+  not measured: PERF §7).
 """
 
 from __future__ import annotations

@@ -249,11 +249,11 @@ class Attention(nn.Module):
         causal self-attention over the chunk and runs through the flash
         kernel — O(s·d) memory instead of materializing
         ``(s, max_decode_len)`` masked scores against the whole cache
-        (3.1× end-to-end on an 8k prompt, BENCHMARKS.md
-        "generation-path prefill"). Single-token steps — and multi-token
+        (the saving is in memory; the time is not measured on this
+        stack: PERF §7). Single-token steps — and multi-token
         appends to a warm cache (chunked prefill), whose offset is a
         traced value — stream the static-shape cache through the
-        ``decode_attention`` kernel (one near-bandwidth HBM pass with
+        ``decode_attention`` kernel (one HBM pass with
         the validity mask applied as a bias), so jit sees one shape
         for every decode step.
         """
@@ -340,11 +340,9 @@ class Attention(nn.Module):
             ).astype(q.dtype)
         else:
             # Token steps (and warm-cache chunk appends) stream the
-            # cache through the Pallas decode kernel — one
-            # near-bandwidth HBM pass instead of the ~90 GB/s masked
-            # matvec fusion XLA makes of the einsum formulation, which
-            # was 85% of decode step time (BENCHMARKS.md "KV-cached
-            # decoding").
+            # cache through the Pallas decode kernel in one HBM pass.
+            # Kernel against XLA's einsum formulation: chosen on a
+            # removed stack; not measured on this one (PERF §7).
             o = decode_attention(
                 q, ck.value, cv.value, idx.value, window=self.window
             )

@@ -12,7 +12,8 @@ Layout: ``(batch, heads, seq, head_dim)``. Grid is
 ``(batch·heads, seq_q/block_q, seq_k/block_k)`` — Pallas streams each
 K/V block from HBM per grid step (double-buffered by the pipeline), so
 VMEM holds only one q/k/v tile plus the accumulators and sequence
-length is unbounded (tested to 32k on one v5e chip; BENCHMARKS.md).
+length is unbounded (the cells train at 4,096; longer sequences are not
+measured on this stack: PERF §7).
 Causal runs skip fully-masked K blocks. The backward pass is two more
 kernels (dq and dk/dv) using the saved logsumexp, the standard
 flash-attention-2 split.
@@ -443,12 +444,11 @@ def _fit_block(seq: int, preferred: int) -> int | None:
     return None
 
 
-# Below this key length the whole score matrix fits comfortably in VMEM
-# and XLA's fused attention beats the Pallas kernel's scratch bookkeeping
-# (measured on v5e, causal bf16 b4/h8/d128: flash 0.84-0.98x at
-# seq<=1024, 1.16x at 1536, 1.28-3.8x beyond — BENCHMARKS.md
-# "attention routing" table). Routed by measurement, not hope; pass
-# block sizes explicitly to force the kernel below this.
+# Below this key length attention goes to XLA's fused form, at or above
+# it to the Pallas kernel. The crossover was chosen on a removed stack;
+# not measured on this one (PERF §7: every cell runs at 4,096 keys, the
+# cell below the line is ROADMAP S2 (c)). Pass block sizes explicitly to
+# force the kernel below this.
 _XLA_FASTER_BELOW = 1536
 
 
@@ -492,11 +492,14 @@ def flash_attention(
     if q_offset is None:
         q_offset = seq_k - seq_q if causal else 0
     forced = block_q is not None or block_k is not None
-    # Measured v5e sweet spots per sequence length (BENCHMARKS.md):
-    # short sequences want fine tiles, long ones coarse tiles (fewer
-    # K/V refetches across q blocks). A preferred size that doesn't
-    # divide the sequence shrinks to the largest 128-multiple divisor
-    # rather than silently punting to the O(seq²) reference.
+    # Default tiles by key length: fine for short sequences, coarse for
+    # long ones (fewer K/V refetches across q blocks). The table was
+    # chosen on a removed stack; the one row a cell runs is 1024 x 1024
+    # at 4,096 keys: `flash_roofline` 31.2 % at d_head 96 with a window
+    # (ledger, PR 26), about 54 % at d_head 128 without (PERF §6 PR 25).
+    # A preferred size that doesn't divide the sequence shrinks to the
+    # largest 128-multiple divisor rather than silently punting to the
+    # O(seq²) reference.
     if seq_k <= 1024:
         default_q, default_k = 128, 128
     elif seq_k <= 2048:
@@ -551,10 +554,10 @@ def decode_attention_reference(
     of the ``(b, h, capacity, d)`` caches. Exactly causal attention
     with the query chunk placed at offset ``valid_len - s``, so it
     delegates to :func:`attention_reference` (whose masking is pure
-    traced arithmetic, hence a traced ``valid_len`` works). XLA lowers
-    this to a badly-tiled matvec fusion at s=1 (~90 GB/s measured;
-    BENCHMARKS.md "KV-cached decoding") — kept only as ground truth
-    and shape fallback. Fewer kv heads than q heads (GQA) broadcast.
+    traced arithmetic, hence a traced ``valid_len`` works). Kept as
+    ground truth and shape fallback; its speed against the kernel is
+    not measured on this stack (PERF §7). Fewer kv heads than q heads
+    (GQA) broadcast.
     ``valid_len`` may be a scalar or a (b,) vector (ragged decode).
     """
     vl = _normalize_valid_len(valid_len, q.shape[0])
@@ -630,8 +633,8 @@ def _group_block_range(vl_ref, bi, *, block_bh, block_k, s, window):
     of the per-row `_decode_block_range`s. The DMA clamp coarsens to
     this union — per-row visibility still comes from `_decode_mask`, so
     grouping trades some over-fetch on ragged batches for ``block_bh``×
-    fewer grid steps (the per-step fixed cost was the measured
-    bottleneck: ~2.3 us/step vs 0.2 us of DMA at block_k=512)."""
+    fewer grid steps (what a grid step costs beside its DMA is not
+    measured on this stack: PERF §7)."""
     firsts, lasts = [], []
     for g in range(block_bh):
         f, l = _decode_block_range(
@@ -719,10 +722,9 @@ def decode_attention(
     grid row masks and clamps its DMA by its own length (a ``vl == 0``
     row attends nothing and outputs zeros).
 
-    The XLA formulation (:func:`decode_attention_reference`) lowers the
-    s=1 matvec + mask + softmax chain to a fusion that sustains only
-    ~90 GB/s on v5e (BENCHMARKS.md "KV-cached decoding" — 85% of decode
-    step time). Here K/V stream through the MXU in ``block_k`` tiles
+    :func:`decode_attention_reference` is the XLA formulation of the
+    same math (the two are not compared on this stack: PERF §7). Here
+    K/V stream through the MXU in ``block_k`` tiles
     with fp32 online-softmax scratch. ``valid_len`` rides scalar
     prefetch: the mask is computed in-kernel, and k blocks past the
     valid prefix (or, with ``window``, before the window) are skipped
@@ -780,13 +782,10 @@ def decode_attention(
 
     bh = b * hkv
     if block_bh is None:
-        # Default 1: grouping rows per grid step was hypothesized to
-        # amortize per-step cost, but hardware says otherwise — at
-        # (b8, h8, d128, cap 16k) block_bh=8 measured 5.9 ms vs 4.9 ms
-        # for block_bh=1, and the marginal streaming rate at block_bh=1
-        # is already ~1 ms/GB (the HBM roofline; the fixed ~1 ms floor
-        # is per-dispatch latency, not kernel time). The knob stays for
-        # experimentation on other topologies.
+        # Default 1: one (batch, kv-head) row per grid step. Chosen on
+        # a removed stack; grouping rows is not measured on this one
+        # (PERF §7; ROADMAP D3: measure it in a serving cell or remove
+        # the knob).
         block_bh = 1
     elif bh % block_bh:
         raise ValueError(f"block_bh {block_bh} must divide b*kv_heads {bh}")
@@ -1202,10 +1201,10 @@ def quantize_kv(x: jax.Array, eps: float = 1e-8) -> tuple[jax.Array, jax.Array]:
     """Per-position symmetric int8 quantization over the head dim.
 
     ``x`` (..., seq, d) -> (int8 values, fp32 scales (..., seq)) with
-    ``x ≈ values * scales[..., None]``. Decode is HBM-bound on the KV
-    cache (BENCHMARKS.md "KV-cached decoding"), so storing it int8
-    halves the bytes the decode kernel streams; the scale adds 4
-    bytes per d-vector (<4% at d=64).
+    ``x ≈ values * scales[..., None]``. Storing the cache int8 halves
+    the bytes the decode kernel streams; the scale adds 4 bytes per
+    d-vector (<4% at d=64). int8 against bf16 decode speed is not
+    measured on this stack (PERF §7).
     """
     scale = jnp.max(jnp.abs(x).astype(jnp.float32), axis=-1) / 127.0
     scale = jnp.maximum(scale, eps)

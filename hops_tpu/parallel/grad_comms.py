@@ -37,9 +37,11 @@ control of that traffic with three composable optimizations:
 4. **Overlap scheduling + ZeRO-2/3** (arXiv:1909.09756's
    comms-under-backward recipe): per-leaf ``custom_vjp`` hooks
    (:func:`tag_backward_comms`) launch each gradient's collective the
-   moment backward produces it — ``overlap`` all-reduces (bit-identical
-   to the sequential path), ``zero2`` reduce-scatters so gradients stay
-   sharded from birth and the optimizer runs on shards
+   moment backward produces it — ``overlap`` all-reduces (equal to
+   the sequential path to the last ulp: the all-reduce is exact; XLA
+   fuses the update differently in the two programs), ``zero2``
+   reduce-scatters so gradients stay sharded from birth and the
+   optimizer runs on shards
    (:func:`zero2_apply_gradients`), and ``zero3``
    (:func:`zero3_init` / :func:`zero3_unshard`) keeps parameters and
    moments 1/N-sharded at rest with on-demand per-leaf all-gather whose
@@ -128,10 +130,6 @@ class GradCommsConfig:
     launched the moment backward produces it and XLA's latency-hiding
     scheduler can run it under the remaining backward compute.
     ``zero2``/``zero3`` overlap by construction.
-
-    ``local_only=True`` is the bench's timing reference: the step runs
-    the explicit-path machinery but skips every cross-replica
-    reduction (training diverges per device — measurement only).
     """
 
     quantize: bool = False
@@ -140,7 +138,6 @@ class GradCommsConfig:
     block_size: int = 256
     bucket_bytes: int = DEFAULT_BUCKET_BYTES
     overlap: bool = False
-    local_only: bool = False  # bench-only: no reduction (compute-time probe)
     #: Host count for hierarchy-aware collectives: 0 = flat (single
     #: fabric), >= 2 = intra-host reduce then one inter-host exchange
     #: per byte. Bit-identical to flat; requires replica count % hosts == 0.
@@ -161,10 +158,6 @@ class GradCommsConfig:
                 "zero2/zero3 overlap by construction and zero1 "
                 "(cross_replica) reduce-scatters at update time"
             )
-        if self.local_only and (self.overlap or self.hierarchy
-                                or self.update_sharding != "replicated"):
-            raise ValueError("local_only is a bench timing reference; "
-                             "combine it with nothing")
         if self.hierarchy:
             if self.hierarchy < 2:
                 raise ValueError(
@@ -188,8 +181,6 @@ class GradCommsConfig:
     @property
     def mode(self) -> str:
         """Human/flag name, e.g. allreduce | quantized+overlap | zero3."""
-        if self.local_only:
-            return "local"
         parts = []
         if self.quantize:
             parts.append("quantized")
@@ -203,7 +194,7 @@ class GradCommsConfig:
 
     @classmethod
     def parse(cls, mode: str | None) -> "GradCommsConfig | None":
-        """Parse the ``--grad-comms`` flag: ``none`` (or None) means the
+        """Parse a mode name (:attr:`mode`): ``none`` (or None) means the
         default XLA-implicit path and returns None; the other modes
         return a config for the explicit path."""
         if mode is None or mode == "none":
@@ -652,9 +643,11 @@ def sharded_apply_gradients(
 # the bucket-ready schedule IS the gradient production order (reverse
 # forward order) — XLA's latency-hiding scheduler interleaves the
 # collectives with the remaining backward compute instead of running
-# them all after it. Values are bit-identical to the post-backward
-# reduction: psum is elementwise, so per-leaf vs per-dtype-bucket
-# grouping cannot change a single bit.
+# them all after it. The reduced gradients are those of the
+# post-backward reduction: psum is elementwise, so per-leaf vs
+# per-dtype-bucket grouping cannot change a single bit of them. The
+# step as a whole is equal to the sequential one to the last ulp (XLA
+# fuses the update differently in the two programs).
 
 
 def _overlap_psum_hook(axis_name: Any, cfg: GradCommsConfig) -> Callable[[Any], Any]:
@@ -765,8 +758,6 @@ def tag_backward_comms(params: Any, axis_name: Any, cfg: GradCommsConfig) -> Any
     backward (``overlap`` → all-reduce hooks, ``zero2`` →
     reduce-scatter hooks). Call INSIDE the differentiated function on
     the argument being differentiated."""
-    if cfg.local_only:
-        return params
     hook = (
         _scatter_shard_hook(axis_name, cfg)
         if cfg.update_sharding in ("zero2", "zero3")
@@ -1273,8 +1264,6 @@ def prepare_params(params: Any, config: GradCommsConfig, axis_name: Any,
     time). ``overlap``/``zero2``: backward hooks. ``zero3``: ``params``
     are the flat shards; gather them (and install the quantized-wire
     cotangent hook when asked)."""
-    if config.local_only:
-        return params
     if config.update_sharding == "zero3":
         if meta is None:
             raise ValueError("zero3 needs the state's layout meta "
@@ -1302,8 +1291,6 @@ def apply_gradients(
     is mode-dependent (raw per-replica for stage 0/1, reduced for
     overlap, scattered for zero2, shard-shaped for zero3)."""
     extra = extra_updates or {}
-    if config.local_only:
-        return state.apply_gradients(grads=grads, **extra)
     if config.update_sharding == "zero3":
         n = lax.psum(1, axis_name)
         shard_grads = jax.tree.map(
@@ -1350,14 +1337,10 @@ def wire_bytes(tree: Any, config: GradCommsConfig) -> tuple[int, int]:
 def instrument_step(
     step_fn: Callable[..., Any],
     config: GradCommsConfig,
-    steps_per_call: int = 1,
 ) -> Callable[..., Any]:
     """Wrap a compiled grad-comms step with telemetry: per-call pre/post
     byte counters and the compression-ratio gauge (the dispatch itself
-    is timed by ``Strategy.step``'s ``hops_tpu_train_dispatch`` span).
-    ``steps_per_call`` scales the byte counters for steps that fuse
-    several optimizer updates per dispatch (``lax.scan`` loops — the
-    ``grad_comms_steps`` attribute Strategy.step reads off the fn)."""
+    is timed by ``Strategy.step``'s ``hops_tpu_train_dispatch`` span)."""
     from hops_tpu.telemetry import REGISTRY
 
     mode = config.mode
@@ -1381,8 +1364,8 @@ def instrument_step(
     def wrapped(state, *args, **kwargs):
         params = getattr(state, "params", state)
         pre, post = wire_bytes(params, config)
-        pre_c.inc(pre * steps_per_call, mode=mode)
-        post_c.inc(post * steps_per_call, mode=mode)
+        pre_c.inc(pre, mode=mode)
+        post_c.inc(post, mode=mode)
         ratio_g.set(pre / post if post else 1.0, mode=mode)
         return step_fn(state, *args, **kwargs)
 

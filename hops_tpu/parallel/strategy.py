@@ -210,11 +210,7 @@ class Strategy:
                     check_vma=False,
                 )
                 inner_jit = jax.jit(inner, donate_argnums=donate)
-            compiled = gc.instrument_step(
-                inner_jit,
-                cfg,
-                steps_per_call=getattr(fn, "grad_comms_steps", 1),
-            )
+            compiled = gc.instrument_step(inner_jit, cfg)
             mode = cfg.mode
         elif marker is not None:
             raise ValueError(

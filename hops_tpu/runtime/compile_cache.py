@@ -1,7 +1,7 @@
 """Persistent XLA compile cache that can be placed from outside.
 
-Every chip-facing entry point (``chip_smoke.py``, ``bench.py``,
-``hops_tpu.launch``, ``serving_host``, ``examples/decode_bench.py``)
+Every chip-facing entry point (``chip_smoke.py``, ``hops_tpu.launch``,
+``serving_host``; ``benchmark/run.py`` through its drivers)
 calls :func:`enable` before its first use of the backend, so a second
 start in the same place loads executables instead of compiling them.
 

@@ -179,38 +179,3 @@ def deterministic_mode(seed: int = 0) -> Iterator[jax.Array]:
         yield jax.random.PRNGKey(seed)
     finally:
         jax.config.update("jax_default_prng_impl", prev)
-
-
-# -- roofline analysis over profiler traces ----------------------------------
-
-#: Peak specs per TPU generation for roofline bounds (bf16 matmul
-#: FLOP/s, HBM bytes/s), keyed by a substring of ``device_kind``. v5e
-#: (a v5e chip reports ``TPU v5 lite``) is the published 197 TFLOP/s /
-#: 819 GB/s (Google Cloud documentation, "TPU v5e"). A device that is
-#: not in the table is an error, not a default.
-_PEAKS = {
-    "v5 lite": (197e12, 819e9),
-    "v5e": (197e12, 819e9),
-    "v5p": (459e12, 2765e9),
-    "v4": (275e12, 1228e9),
-}
-
-
-def device_peaks(kind: str | None = None) -> tuple[float, float]:
-    """(bf16 matmul FLOP/s, HBM bytes/s) peaks for a device kind.
-
-    ``kind`` defaults to the local backend's ``device_kind``; raises
-    ``KeyError`` when the generation isn't tabulated — an MFU% or a
-    roofline share against a guessed roof is not a number. Single
-    source for the program's peak lookups (bench.py --lm).
-    """
-    if kind is None:
-        kind = jax.devices()[0].device_kind
-    for key, peaks in _PEAKS.items():
-        if key in kind.lower():
-            return peaks
-    raise KeyError(
-        f"no peak FLOP/s / HBM bandwidth tabulated for device kind {kind!r} "
-        f"(known: {sorted(_PEAKS)}); add its published peaks to "
-        "diagnostics._PEAKS"
-    )

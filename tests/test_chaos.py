@@ -431,7 +431,6 @@ class TestPubsubChaos:
         assert [r["value"]["seq"] for r in consumer.poll()] == [3]
 
 
-@pytest.mark.slow  # compiles the tiny LM engine programs (jit) — slow tier
 class TestLMEngineDispatchFaults:
     """The ``lm_engine.dispatch`` fault point: an injected transient
     dispatch error must fail ONLY the affected requests — their slots

@@ -6,7 +6,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from hops_tpu.models import common
@@ -21,7 +20,6 @@ def _state():
     )
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_save_restore_roundtrip(tmp_path):
     state = _state()
     with checkpoint.CheckpointManager(tmp_path / "ckpt", async_save=False) as mgr:
@@ -140,7 +138,6 @@ def test_preemption_guard_chains_previous_handler():
         signal.signal(signal.SIGTERM, prev)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_run_preemptible_checkpoints_and_resumes(tmp_path):
     """Preemption mid-run saves at the step boundary and exits; a second
     incarnation resumes from there and finishes the epoch."""
@@ -261,7 +258,6 @@ def test_restore_onto_smaller_mesh(tmp_path):
     jax.tree.map(np.testing.assert_allclose, restored.params, state.params)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_run_preemptible_callable_batches_fast_forward(tmp_path):
     """batches may be callable(start_step) -> iterable: the resumed
     incarnation's stream starts AT the restored step (no draw-and-

@@ -2,10 +2,10 @@
 
 What only a chip can show (Mosaic compiles, parity, times) lives in
 ``chip_smoke.py``. What a CPU can pin is everything that must NOT
-happen without one: a measurement path that finds no TPU fails, an
-unknown device has no peaks, the compile cache goes where it is told,
-a page that does not tile raises, and the apparatus that used
-to paper over a missing chip is gone.
+happen without one: a measurement path that finds no TPU fails, the
+compile cache goes where it is told, a page that does not tile raises,
+``bench.py`` has no chip tier and no default tier, and the apparatus
+that used to paper over a missing chip is gone.
 """
 
 from __future__ import annotations
@@ -101,24 +101,6 @@ def test_one_call_site_sets_the_cache_directory():
     assert hits == ["hops_tpu/runtime/compile_cache.py"]
 
 
-# -- peaks: an unknown device is an error, not a default ---------------------
-
-
-def test_device_peaks_knows_the_v5e_as_jax_reports_it():
-    from hops_tpu.runtime.diagnostics import device_peaks
-
-    assert device_peaks("TPU v5 lite") == (197e12, 819e9)
-
-
-def test_device_peaks_raises_on_unknown_kind():
-    from hops_tpu.runtime.diagnostics import device_peaks
-
-    with pytest.raises(KeyError, match="weird chip"):
-        device_peaks("weird chip")
-    with pytest.raises(KeyError):
-        device_peaks()  # this backend: "cpu" has no row any more
-
-
 # -- no chip, no number -------------------------------------------------------
 
 
@@ -130,12 +112,17 @@ def _run(args, cwd=REPO, **env_overrides):
     )
 
 
-@pytest.mark.parametrize("flags", [[], ["--lm"], ["--lm-serving"]])
-def test_bench_chip_tiers_exit_nonzero_without_a_tpu(flags):
+@pytest.mark.parametrize(
+    "flags", [[], ["--lm"], ["--lm-serving"], ["--multihost"]])
+def test_bench_has_no_default_tier_and_no_chip_tier(flags):
+    """bench.py holds host tiers only: with no tier flag, and with each
+    flag of a retired chip tier, it prints its usage and exits 2. (The
+    chip's yardstick refusing to run off the chip is
+    benchmark/tests/test_drivers_cpu.py's to hold.)"""
     proc = _run(["bench.py", *flags])
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""  # no result line, stale or otherwise
-    assert "no TPU" in proc.stderr.strip().splitlines()[-1]
+    assert proc.returncode == 2
+    assert proc.stdout.strip() == ""  # no JSON line
+    assert "usage: bench.py" in proc.stderr
 
 
 def test_chip_smoke_exits_nonzero_without_a_tpu():
@@ -180,9 +167,9 @@ def test_paged_decode_raises_on_a_page_that_does_not_tile(dtype, page=12):
 
 
 def test_flash_attention_lowers_to_pallas_at_2048_but_not_at_1024():
-    """The bench.py --lm default (seq 1024) routes to the XLA reference;
-    the kernels only run from 1536 keys up — which is why chip_smoke.py
-    trains at 2048."""
+    """Sequences of 1024 route to the XLA reference; the kernels only
+    run from 1536 keys up (``_XLA_FASTER_BELOW``) — which is why
+    chip_smoke.py trains at 2048 and the LM cells at 4096."""
     from hops_tpu.ops.attention import flash_attention
 
     def jaxpr(seq):
@@ -265,5 +252,6 @@ def test_remote_chip_apparatus_is_gone():
         assert not (REPO / name).exists(), name
     bench = (REPO / "bench.py").read_text()
     for gone in ("emit_stale_or_fail", "probe_tpu", "probe_with_retry",
-                 '"stale"', "--no-probe", "--lock-wait"):
+                 '"stale"', "--no-probe", "--lock-wait",
+                 "_require_tpu", "vs_baseline"):
         assert gone not in bench, gone

@@ -114,7 +114,7 @@ def test_maggy_lagom_cell():
     assert result["best_metric"] > 0
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
+@pytest.mark.slow
 def test_jobs_and_dataset_cells(tmp_path):
     """jobs_spark_client.py:44-54 flow via shims."""
     src = tmp_path / "ws"

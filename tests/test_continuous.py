@@ -649,8 +649,8 @@ def _topic_records(topic: str) -> list[dict]:
     return out
 
 
-@pytest.mark.slow  # subprocess interpreters + multi-second chaos run
 class TestContinuousChaosE2E:
+    @pytest.mark.slow
     def test_trainer_sigkilled_mid_span_exactly_once(
             self, workspace, tmp_path):
         """The headline kill test: broker faults + a corrupt record on
@@ -857,8 +857,8 @@ class TestContinuousChaosE2E:
         assert v["records"] == 54 and v["contiguous"] and v["disjoint"]
 
 
-@pytest.mark.slow  # full bench subprocess: fleet + rollouts + chaos (~30s)
 class TestContinuousBenchTier:
+    @pytest.mark.slow
     def test_bench_continuous_loop_smoke_end_to_end(self, tmp_path):
         repo = Path(__file__).resolve().parents[1]
         env = dict(os.environ)

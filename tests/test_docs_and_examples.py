@@ -14,7 +14,6 @@ sys.path.insert(0, str(Path(__file__).parent.parent))
 from hops_tpu import jobs
 from hops_tpu.jobs import api, dataset
 
-pytestmark = pytest.mark.slow  # heavy compiles / subprocess e2e (fast tier: -m 'not slow')
 
 
 def test_make_builds_site(tmp_path):
@@ -36,6 +35,7 @@ def test_featurestore_tour_inprocess():
     assert result["td_splits"]["train"] > 0
 
 
+@pytest.mark.slow
 def test_featurestore_tour_as_job():
     app = str(Path(__file__).parent.parent / "examples" / "featurestore_tour.py")
     jobs.create_job("fs_tour", api.JobConfig(app_file=app, default_args=["--td-version", "2"]))
@@ -103,6 +103,7 @@ def test_td_format_aliases():
     assert len(td.read()) == 3
 
 
+@pytest.mark.slow
 def test_pi_job_with_staged_workspace(tmp_path):
     """jobs-client workflow: zip workspace -> stage -> extract -> run as job."""
     src = Path(__file__).parent.parent / "examples"
@@ -137,6 +138,7 @@ def test_lm_generation_serving():
     assert result["ragged"]["long"][:4] == [cyc[(6 + i) % 8] for i in range(4)]
 
 
+@pytest.mark.slow
 def test_continuous_batching_example():
     """Six ragged requests through 3 slots: bit-exact vs per-request
     generate(), in fewer dispatches than sequential decoding."""
@@ -186,6 +188,7 @@ def test_batch_inference_example():
     assert df["probability"].between(0.0, 1.0).all()
 
 
+@pytest.mark.slow
 def test_torch_example_through_launch_and_de():
     """The launcher contract is framework-agnostic: a full torch program
     runs through experiment.launch and differential_evolution unchanged

@@ -819,7 +819,6 @@ class TestScalaBuilderErgonomics:
         assert got.statistics_config.histograms
         assert len(got.read()) == 2
 
-    @pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
     def test_training_dataset_builder_saves_query(self, fs):
         from hops_tpu.featurestore.builders import DataFormat
 

@@ -21,7 +21,6 @@ def _model_and_params(seed=0):
     return model, variables["params"]
 
 
-@pytest.mark.slow
 def test_decode_logits_match_full_forward():
     """Cache path must reproduce the dense causal forward exactly."""
     model, params = _model_and_params()
@@ -43,7 +42,6 @@ def test_decode_logits_match_full_forward():
         np.testing.assert_allclose(logits[:, 0], full[:, t], atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_greedy_generation_is_deterministic_and_in_range():
     model, params = _model_and_params()
     prompt = jax.random.randint(jax.random.PRNGKey(2), (2, 6), 0, 64)
@@ -55,7 +53,6 @@ def test_greedy_generation_is_deterministic_and_in_range():
     assert int(out1.max()) < 64 and int(out1.min()) >= 0
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_sampled_generation_respects_top_k():
     model, params = _model_and_params()
     prompt = jnp.zeros((1, 4), jnp.int32)
@@ -66,7 +63,6 @@ def test_sampled_generation_respects_top_k():
     assert out.shape == (1, 12)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_generate_rejects_overflow():
     model, params = _model_and_params()
     prompt = jnp.zeros((1, 60), jnp.int32)
@@ -85,7 +81,6 @@ def test_generate_rejects_zero_new_tokens():
         generate(model, params, prompt, jax.random.PRNGKey(0), max_new_tokens=0)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_moe_blocks_inherit_max_decode_len():
     """MoE layers' KV caches must size to the model's max_decode_len, not
     the MoEBlock default — otherwise decode past 2048 silently clamps."""
@@ -99,7 +94,6 @@ def test_moe_blocks_inherit_max_decode_len():
     assert key_lens == {TINY["max_decode_len"]}, key_lens
 
 
-@pytest.mark.slow
 def test_long_prefill_kernel_path_matches_full_forward():
     """Prefill with s>1 rides the flash kernel (round 3); at a kernel-eligible
     length it must still reproduce the dense causal forward."""
@@ -124,7 +118,6 @@ def test_long_prefill_kernel_path_matches_full_forward():
     assert bool(jnp.all(jnp.isfinite(step_logits)))
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_eos_masks_following_tokens_to_pad():
     """Once a row emits eos_id, every later position is pad_id; rows
     that never emit it are untouched (static shapes throughout)."""
@@ -157,7 +150,6 @@ def test_eos_masks_following_tokens_to_pad():
     np.testing.assert_array_equal(out[0, :4 + first_hit + 1], base[0, :4 + first_hit + 1])
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_eos_none_keeps_previous_behavior():
     model, params = _model_and_params()
     prompt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
@@ -168,7 +160,6 @@ def test_eos_none_keeps_previous_behavior():
     np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.slow
 def test_speculative_matches_greedy():
     """Speculative decoding is lossless: with any draft model the
     output equals the target's own greedy decoding, token for token."""
@@ -194,7 +185,6 @@ def test_speculative_matches_greedy():
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.slow
 def test_speculative_with_perfect_draft():
     """Draft == target: every round accepts the cap (k-1 drafts +
     bonus) and the output still matches greedy exactly."""
@@ -224,7 +214,6 @@ def test_speculative_rejects_bad_args():
                              max_new_tokens=8, k=1)
 
 
-@pytest.mark.slow
 def test_int8_cache_decode_close_to_fp_cache():
     """kv_cache_dtype='int8': decode logits track the fp-cache decode
     within quantization tolerance, and greedy generation still emits
@@ -264,7 +253,6 @@ def test_int8_cache_decode_close_to_fp_cache():
     assert bool(((out >= 0) & (out < 64)).all())
 
 
-@pytest.mark.slow
 def test_speculative_matches_greedy_with_int8_cache():
     """Losslessness survives cache quantization: with kv_cache_dtype
     ='int8' on both models, speculative output still equals that
@@ -345,7 +333,6 @@ def test_gqa_decode_matches_full_forward():
     assert float(jnp.max(jnp.abs(q8_step - fp_step))) > 0.0  # really quantized
 
 
-@pytest.mark.slow
 def test_sliding_window_decode_matches_full_forward():
     """window=4: decode-path logits equal the full windowed forward at
     every step (the cache keeps all positions; masking enforces the
@@ -371,7 +358,6 @@ def test_sliding_window_decode_matches_full_forward():
         tok = jnp.argmax(step_logits[:, -1:], axis=-1)
 
 
-@pytest.mark.slow
 def test_all_decode_knobs_compose():
     """The modern-LM preset: GQA + int8 cache + sliding window, decoded
     speculatively — the full knob stack in one model, output identical
@@ -394,11 +380,15 @@ def test_all_decode_knobs_compose():
                                max_new_tokens=11, k=3)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    # And the decode path still equals the full (windowed) forward.
+    # And the decode path still tracks the full (windowed) forward:
+    # within the int8 envelope of test_int8_cache_decode_close_to_fp_cache,
+    # since an int8 prefill reads back through the quantized cache
+    # (measured 0.027 where the largest logit is 3.29; with an fp cache
+    # the same comparison reads 0.0).
     full = model.apply({"params": params}, prompt)
     logits, _ = model.apply(
         {"params": params}, prompt, decode=True, mutable=["cache"])
-    np.testing.assert_allclose(logits, full, atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(logits, full, atol=0.15, rtol=0.05)
 
 
 @pytest.mark.slow
@@ -439,7 +429,6 @@ def test_windowed_moe_decode_matches_full_forward():
         tok = jnp.argmax(step_logits[:, -1:], axis=-1)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_speculative_sampled_is_lossless():
     """Rejection-sampling speculation must emit tokens distributed as
     the TARGET's filtered distribution regardless of the draft: with a
@@ -497,7 +486,6 @@ def test_speculative_sampled_is_lossless():
         )
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_speculative_sampled_perfect_draft_accepts_everything():
     """draft == target: u < min(1, p/q) = 1 always accepts, so every
     round advances k tokens — the while_loop runs ceil(new/k) rounds
@@ -524,7 +512,6 @@ def test_speculative_sampled_perfect_draft_accepts_everything():
 @pytest.mark.parametrize(
     "knobs", [{}, {"num_kv_heads": 2, "kv_cache_dtype": "int8"}]
 )
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_beam_search_k1_is_greedy(knobs):
     """beam_size=1 equals greedy generate — including through the GQA +
     int8-cache decode path (beam search rides the same cache)."""
@@ -542,7 +529,6 @@ def test_beam_search_k1_is_greedy(knobs):
     assert scores.shape == (2,) and np.all(np.asarray(scores) <= 0)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_beam_search_finds_optimal_sequence():
     """With beam_size >= V^depth the search is exhaustive: its winner
     must equal the brute-force most-likely continuation."""
@@ -576,7 +562,6 @@ def test_beam_search_finds_optimal_sequence():
     assert abs(float(score[0]) - best_lp) < 1e-4
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_beam_search_eos_freezes_beam():
     """A beam that emits eos pads thereafter at frozen score. With
     beam_size=1 the beam IS the greedy path, so setting eos to the

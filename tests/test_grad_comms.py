@@ -368,11 +368,18 @@ def test_quantized_step_trains_close_to_fp32():
 # -- overlap-scheduled comms + ZeRO-2/3 ---------------------------------------
 
 
-def test_overlap_step_bit_identical_to_sequential():
+def test_overlap_step_matches_sequential_to_the_ulp():
     """Bucket-as-ready VJP hooks launch each leaf's all-reduce inside
-    backward; psum is elementwise, so the trained params AND optimizer
-    moments must match the compute-then-communicate explicit step
-    bit-for-bit — overlap changes scheduling, never a single bit."""
+    backward; psum is elementwise and exact, so the loss is equal and
+    the trained params match the compute-then-communicate explicit step
+    to within 4 ulp, the optimizer moments to within 4 ulp of their
+    leaf's largest entry. They are two different programs and XLA fuses
+    the arithmetic round the all-reduce differently in each. Measured on
+    JAX 0.9.0's CPU backend after three steps: 7 of 806 kernel
+    parameters differ, by 1 ulp (largest relative difference 1.1e-7);
+    241 first-moment entries differ by at most 3.7e-9 where the leaf's
+    largest is 0.065 (under 1 ulp at that scale; 27 ulp of an entry
+    near zero, where 0.9 mu + 0.1 g cancels)."""
     strategy = Strategy(mesh_lib.make_mesh({"data": N_DEV}))
     batch = strategy.distribute_batch(_batch())
     results = {}
@@ -392,9 +399,14 @@ def test_overlap_step_bit_identical_to_sequential():
     s_ov, m_ov = results["overlap"]
     assert float(m_seq["loss"]) == float(m_ov["loss"])
     for a, b in zip(jax.tree.leaves(s_seq.params), jax.tree.leaves(s_ov.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b), maxulp=4)
     for a, b in zip(jax.tree.leaves(s_seq.opt_state), jax.tree.leaves(s_ov.opt_state)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        a, b = np.asarray(a), np.asarray(b)
+        if np.issubdtype(a.dtype, np.floating):
+            atol = 4 * np.finfo(a.dtype).eps * np.abs(a).max()
+            np.testing.assert_allclose(a, b, rtol=0, atol=atol)
+        else:
+            np.testing.assert_array_equal(a, b)  # Adam's step count
 
 
 @pytest.mark.parametrize(
@@ -564,11 +576,8 @@ def test_new_mode_parse_and_validation():
     assert gc.GradCommsConfig.parse("zero2").zero_stage == 2
     assert gc.GradCommsConfig.parse("zero3").zero_stage == 3
     assert gc.GradCommsConfig.parse("quantized+zero3").mode == "quantized+zero3"
-    assert gc.GradCommsConfig(local_only=True).mode == "local"
     with pytest.raises(ValueError, match="replicated update only"):
         gc.GradCommsConfig(overlap=True, update_sharding="cross_replica")
-    with pytest.raises(ValueError, match="bench timing"):
-        gc.GradCommsConfig(local_only=True, overlap=True)
 
 
 # -- strategy wiring, memoization, telemetry ---------------------------------
@@ -797,5 +806,3 @@ def test_hier_parse_and_validation():
         gc.GradCommsConfig(hierarchy=1)
     with pytest.raises(ValueError, match="zero3"):
         gc.GradCommsConfig(hierarchy=2, update_sharding="zero3")
-    with pytest.raises(ValueError, match="bench timing"):
-        gc.GradCommsConfig(local_only=True, hierarchy=2)

@@ -124,7 +124,6 @@ class TestShardingRules:
         assert sharded["w"].sharding.spec == jax.sharding.PartitionSpec("model", None)
 
 
-@pytest.mark.slow
 def test_bn_train_step():
     from hops_tpu.models import common
     from hops_tpu.models.resnet import ResNet18ish

@@ -11,7 +11,6 @@ from hops_tpu.jobs import api, dag, dataset, streaming
 from hops_tpu.messaging import pubsub
 from hops_tpu.runtime import fs
 
-pytestmark = pytest.mark.slow  # heavy compiles / subprocess e2e (fast tier: -m 'not slow')
 
 
 def _write_app(tmp_path, body: str, name="app.py") -> str:
@@ -20,6 +19,7 @@ def _write_app(tmp_path, body: str, name="app.py") -> str:
     return str(p)
 
 
+@pytest.mark.slow
 def test_create_start_and_finish(tmp_path):
     app = _write_app(tmp_path, "import sys; print('hello', sys.argv[1:])")
     jobs.create_job("hello", api.JobConfig(app_file=app, default_args=["a", "b"]))
@@ -30,6 +30,7 @@ def test_create_start_and_finish(tmp_path):
     assert "hello ['a', 'b']" in done.stdout()
 
 
+@pytest.mark.slow
 def test_job_sibling_import_and_main_semantics(tmp_path):
     """The bootstrap must preserve `python app.py` semantics: the app
     dir on sys.path (sibling imports) and __name__ == "__main__"."""
@@ -47,6 +48,7 @@ def test_job_sibling_import_and_main_semantics(tmp_path):
     assert "got 42" in done.stdout()
 
 
+@pytest.mark.slow
 def test_failing_job_marked_failed(tmp_path):
     app = _write_app(tmp_path, "raise SystemExit(3)")
     jobs.create_job("boom", api.JobConfig(app_file=app))
@@ -55,6 +57,7 @@ def test_failing_job_marked_failed(tmp_path):
     assert done.state == "FAILED" and done.exit_code == 3
 
 
+@pytest.mark.slow
 def test_stop_job_kills_running_execution(tmp_path):
     app = _write_app(tmp_path, "import time; time.sleep(60)")
     jobs.create_job("sleeper", api.JobConfig(app_file=app))
@@ -65,6 +68,7 @@ def test_stop_job_kills_running_execution(tmp_path):
     assert done.state == "KILLED"
 
 
+@pytest.mark.slow
 def test_executions_newest_first(tmp_path):
     app = _write_app(tmp_path, "print('ok')")
     jobs.create_job("multi", api.JobConfig(app_file=app))
@@ -77,6 +81,7 @@ def test_executions_newest_first(tmp_path):
     assert [e.execution_id for e in exs] == [e2.execution_id, e1.execution_id]
 
 
+@pytest.mark.slow
 def test_dag_fan_out_fan_in(tmp_path):
     """The launch_jobs.py shape: task0 >> [task1, task2] >> sensor >> task3."""
     app = _write_app(tmp_path, "print('ok')")
@@ -96,6 +101,7 @@ def test_dag_fan_out_fan_in(tmp_path):
     assert "t3" in ctx
 
 
+@pytest.mark.slow
 def test_dag_failure_skips_downstream(tmp_path):
     ok = _write_app(tmp_path, "print('ok')", "ok.py")
     bad = _write_app(tmp_path, "raise SystemExit(1)", "bad.py")

@@ -415,7 +415,7 @@ class TestFeederAndTdBridges:
         assert batch["tokens"].shape == (2, 5)
 
 
-@pytest.mark.slow  # ~10 s subprocess: full bench e2e (the driver acceptance path)
+@pytest.mark.slow
 def test_bench_input_pipeline_threaded_e2e():
     """`bench.py --input-pipeline threaded` completes on CPU and its
     JSON line carries pipeline samples/s, the starved-step fraction,

@@ -334,7 +334,6 @@ class TestBatchInference:
         # The second run's tail pad reused the first run's buffer.
         assert hit_counter.value(site="batch", result="hit") >= hits0 + 1
 
-    @pytest.mark.slow  # TransformerLM compiles (round-5 re-tiering)
     def test_lm_generate_with_model_offline(self):
         """LM batch inference from the registry rides the offline drain
         and matches per-request generate() (ragged per-prompt budgets,
@@ -479,7 +478,7 @@ class TestStandaloneServing:
         assert serving.get_status("detached") == "Stopped"
         assert not serving._pid_alive(pid)  # host terminated by stop()
 
-    @pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
+    @pytest.mark.slow
     def test_supervisor_restores_and_serves(self, tmp_path, workspace):
         import os
         import signal as sig
@@ -516,7 +515,7 @@ class TestStandaloneServing:
             reg["phoenix2"].pop("port", None)
             serving._save_registry(reg)
 
-    @pytest.mark.slow  # two subprocess interpreters (host + supervisor)
+    @pytest.mark.slow
     def test_watch_revives_dead_server_and_honors_deliberate_stop(
             self, tmp_path, workspace):
         """The --watch revive path, end to end: a hosted serving's

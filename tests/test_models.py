@@ -10,7 +10,6 @@ from hops_tpu.models.mnist import CNN, FFN
 from hops_tpu.models.resnet import ResNet18ish, ResNet50
 from hops_tpu.models.widedeep import WideAndDeep, make_taxi_batch
 
-pytestmark = pytest.mark.slow  # heavy compiles / subprocess e2e (fast tier: -m 'not slow')
 
 
 class TestMnistModels:
@@ -108,7 +107,7 @@ class TestWideDeep:
 
 
 class TestResNetTPUForm:
-    """The HBM-roofline optimizations (BENCHMARKS.md) must not change math."""
+    """The bf16-norm and space-to-depth forms must not change math."""
 
     def test_s2d_stem_matches_dense_stem(self):
         # Same parameter tree (canonical 7x7 kernel) drives both paths;
@@ -137,6 +136,7 @@ class TestResNetTPUForm:
         for leaf in jax.tree.leaves(variables["batch_stats"]):
             assert leaf.dtype == jnp.float32
 
+    @pytest.mark.slow
     def test_remat_blocks_identical_values_and_grads(self):
         """remat=True saves only block boundaries; values, grads, and
         batch_stats updates must be numerically identical."""

@@ -3,7 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from hops_tpu.models.moe import MoEBlock, MoEMLP, expert_specs
@@ -16,7 +15,6 @@ def _x(b=2, s=16, d=32, seed=0):
     return jax.random.normal(jax.random.PRNGKey(seed), (b, s, d), jnp.float32)
 
 
-@pytest.mark.slow
 def test_forward_shape_and_aux_loss():
     x = _x()
     moe = MoEMLP(**TINY)
@@ -28,7 +26,6 @@ def test_forward_shape_and_aux_loss():
     assert float(aux) >= 0.99
 
 
-@pytest.mark.slow
 def test_top1_matches_manual_expert():
     """With top_k=1 each token's output equals its routed expert's
     SwiGLU FFN applied to it, scaled by the (renormalized=1) gate."""
@@ -49,7 +46,6 @@ def test_top1_matches_manual_expert():
     )
 
 
-@pytest.mark.slow
 def test_expert_parallel_placement_and_step():
     mesh = mesh_lib.make_mesh({"data": 2, "expert": 4})
     x = _x(b=4, s=8, d=32)
@@ -75,7 +71,6 @@ def test_expert_parallel_placement_and_step():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow
 def test_moe_block_in_transformer_shape():
     x = _x(b=2, s=32, d=32)
     block = MoEBlock(num_heads=4, num_experts=4, dtype=jnp.float32, attention_impl="reference")
@@ -84,7 +79,6 @@ def test_moe_block_in_transformer_shape():
     assert out.shape == x.shape
 
 
-@pytest.mark.slow
 def test_moe_transformer_lm_trains():
     from hops_tpu.models import common
     from hops_tpu.models.transformer import TransformerLM, make_lm_train_step

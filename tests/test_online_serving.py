@@ -876,8 +876,8 @@ class TestServingIntegration:
             store.close()
 
 
-@pytest.mark.slow
 class TestBenchTier:
+    @pytest.mark.slow
     def test_online_store_bench_smoke_end_to_end(self, workspace):
         env = {"JAX_PLATFORMS": "cpu"}
         import os
@@ -899,7 +899,6 @@ class TestBenchTier:
         assert line["join_p99_ms"] >= line["join_p50_ms"]
 
 
-@pytest.mark.slow
 class TestExample:
     def test_feature_serving_example_inprocess(self, workspace):
         from examples import feature_serving

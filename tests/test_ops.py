@@ -24,7 +24,6 @@ def test_flash_matches_reference(causal):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_grads_match_reference(causal):
     q, k, v = _inputs(batch=1, heads=2, seq=128, d=32)
@@ -107,7 +106,6 @@ def test_causal_cross_length_in_kernel(monkeypatch, seq_q, seq_k):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 def test_causal_cross_length_grads():
     q, _, _ = _inputs(batch=1, heads=2, seq=128, d=32)
     _, k, v = _inputs(batch=1, heads=2, seq=256, d=32, seed=1)
@@ -166,7 +164,6 @@ def _cache_inputs(batch=2, heads=4, cap=512, d=64, dtype=jnp.float32):
 @pytest.mark.parametrize(
     "s,valid", [(1, 1), (1, 7), (1, 128), (1, 300), (4, 132), (16, 512), (5, 5)]
 )
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_matches_reference(s, valid, block_bh):
     """block_bh > 1 groups (batch, kv-head) rows per grid step — the
     per-group scratch views and union DMA clamp are separate indexing
@@ -181,7 +178,6 @@ def test_decode_attention_matches_reference(s, valid, block_bh):
     np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_traced_valid_len_under_scan():
     """One compiled program serves every step: valid_len is a traced
     scalar riding the scan carry, the shapes never change."""
@@ -454,7 +450,6 @@ def test_quantize_kv_roundtrip_error_bound():
 
 @pytest.mark.parametrize("block_bh", [1, 2])
 @pytest.mark.parametrize("s,valid", [(1, 1), (1, 129), (4, 260), (1, 512)])
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_q8_close_to_fp(s, valid, block_bh):
     from hops_tpu.ops.attention import (
         decode_attention_q8,
@@ -512,7 +507,6 @@ def test_sliding_window_flash_matches_reference(window):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.slow
 def test_sliding_window_grads_match_reference(window=96):
     q, k, v = _inputs(batch=1, heads=2, seq=256, d=32)
 
@@ -535,7 +529,6 @@ def test_sliding_window_requires_causal():
         flash_attention(q, k, v, causal=False, window=64)
 
 
-@pytest.mark.slow
 def test_sliding_window_decode_matches_reference():
     from hops_tpu.ops.attention import decode_attention, decode_attention_reference
 
@@ -552,7 +545,6 @@ def test_sliding_window_decode_matches_reference():
 # -- decode kernel: large warm-cache appends + valid-proportional DMA --------
 
 
-@pytest.mark.slow
 def test_decode_large_warm_append_stays_on_kernel(monkeypatch):
     """VERDICT r3 item 8: chunk appends past 64 rows used to silently
     fall back to the O(s*capacity) XLA reference; the q-row-blocked
@@ -578,7 +570,6 @@ def test_decode_large_warm_append_stays_on_kernel(monkeypatch):
         np.testing.assert_allclose(out, ref, atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.slow
 def test_decode_large_warm_append_gqa_and_q8(monkeypatch):
     """rows = g*s > 64 with GQA folding and the int8 cache: both land on
     the blocked kernel (fallback poisoned) and match the reference."""
@@ -606,7 +597,6 @@ def test_decode_large_warm_append_gqa_and_q8(monkeypatch):
     np.testing.assert_allclose(out8, ref, atol=0.05, rtol=0.05)
 
 
-@pytest.mark.slow
 def test_decode_large_warm_append_windowed(monkeypatch):
     """Sliding window composes with the q-row-blocked append path
     (fallback poisoned, as above)."""
@@ -643,7 +633,6 @@ def test_decode_block_range_clamps_dma_to_valid_prefix():
 
 
 @pytest.mark.parametrize("block_bh", [1, 2])
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_ragged_matches_per_row(block_bh):
     """A (b,) valid_len equals running each row alone with its scalar
     length — the continuous-batching contract, on both the kernel and
@@ -666,7 +655,6 @@ def test_decode_attention_ragged_matches_per_row(block_bh):
         np.testing.assert_allclose(ref[i : i + 1], row, atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_ragged_zero_rows_output_zero():
     """vl == 0 marks a free slot: it attends nothing and outputs exact
     zeros (no NaN from the empty softmax), while live rows are
@@ -683,7 +671,6 @@ def test_decode_attention_ragged_zero_rows_output_zero():
     np.testing.assert_allclose(out[:1], alone, atol=2e-6, rtol=2e-6)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_decode_attention_ragged_gqa_q8_window():
     """The ragged vector composes with every decode knob: GQA row
     folding, int8 cache, sliding window — against the per-row scalar
@@ -759,7 +746,6 @@ def test_decode_attention_ragged_traced_under_scan():
 # -- chunked-vocab cross-entropy (ops/xent.py) -------------------------------
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_chunked_xent_matches_optax_value_and_grad():
     import optax
 
@@ -927,8 +913,9 @@ def test_chunked_xent_backward_does_not_recompute_the_logits():
     assert len(results) == 3
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_lm_train_step_loss_chunk_matches_dense_path():
+    import optax
+
     from hops_tpu.models import common
     from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
 
@@ -938,14 +925,19 @@ def test_lm_train_step_loss_chunk_matches_dense_path():
     )
     tokens = {"tokens": jnp.asarray(
         np.random.RandomState(0).randint(0, 64, (4, 17)))}
+    # Loss and gradients, the gradients read through one SGD step at
+    # learning rate 1 (new = old - grad). Not through Adam: its first
+    # step is lr * g / (|g| + 1e-8), which turns a rounding-sized
+    # difference in a gradient near 1e-8 into one the size of the
+    # learning rate (after PR 26's one-pass loss: 1 of 4,096 entries of
+    # one kernel, 4.3e-5 at lr 1e-3, the two losses bit-equal).
     s0 = common.create_train_state(
-        model, jax.random.PRNGKey(0), (4, 16), input_dtype=jnp.int32)
+        model, jax.random.PRNGKey(0), (4, 16), input_dtype=jnp.int32,
+        optimizer=optax.sgd(1.0))
     s1, m1 = jax.jit(make_lm_train_step())(s0, tokens)
-    s0b = common.create_train_state(
-        model, jax.random.PRNGKey(0), (4, 16), input_dtype=jnp.int32)
-    s2, m2 = jax.jit(make_lm_train_step(loss_chunk=32))(s0b, tokens)
+    s2, m2 = jax.jit(make_lm_train_step(loss_chunk=32))(s0, tokens)
     np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]), rtol=1e-6)
     jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5),
+        lambda a, b: np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5),
         s1.params, s2.params,
     )

@@ -163,7 +163,7 @@ class TestHybridMesh:
                 slice_id=lambda d: d.id // 4)
 
     def test_strategy_over_hybrid_mesh(self):
-        """The RUNBOOK multi-slice recipe: Strategy(hybrid_mesh, tuple
+        """The multi-slice recipe: Strategy(hybrid_mesh, tuple
         data axes) — dp over DCN x ICI, tp inside the slice."""
         from hops_tpu.parallel.strategy import Strategy
 
@@ -185,7 +185,6 @@ class TestHybridMesh:
         assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.slow  # whole-generate-loop shard_map compiles (round-5 re-tiering)
 class TestTPInference:
     """Tensor-parallel decoding: tp_generate == single-device generate,
     token for token, on a dense checkpoint sliced in place."""
@@ -256,7 +255,6 @@ class TestTPInference:
             lm.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
-@pytest.mark.slow  # whole-generate-loop shard_map compiles (round-5 re-tiering)
 class TestTPSpeculative:
     """Tensor-parallel speculative decoding: tp_generate_speculative
     matches single-device generate_speculative token for token."""

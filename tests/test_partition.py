@@ -281,7 +281,6 @@ class TestInvariantAudit:
 # -- bench tier ---------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_bench_partition_smoke():
     """`bench.py --partition --smoke` runs the headline chaos drill —
     asymmetric cut, lease fence, re-place, heal, zombie rejection —

@@ -9,7 +9,6 @@ from hops_tpu.models.moe import sum_sown_losses
 from hops_tpu.parallel import mesh as mesh_lib
 from hops_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
 
-pytestmark = pytest.mark.slow  # heavy compiles / subprocess e2e (fast tier: -m 'not slow')
 
 STAGES = 4
 DIM = 16
@@ -127,6 +126,7 @@ def test_pipelined_transformer_lm_matches_dense(stage_mesh):
     np.testing.assert_allclose(pp, dense, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_pipelined_lm_grads_match_dense(stage_mesh):
     from hops_tpu.models.transformer import TransformerLM
     from hops_tpu.parallel.pipeline import pipelined_lm_apply
@@ -208,6 +208,7 @@ def test_pipelined_moe_lm_top1_matches_dense(stage_mesh):
     np.testing.assert_allclose(pp, dense, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_pipelined_moe_lm_grads_match_dense(stage_mesh):
     from hops_tpu.models.transformer import TransformerLM
     from hops_tpu.parallel.pipeline import pipelined_lm_apply
@@ -234,6 +235,7 @@ def test_pipelined_moe_lm_grads_match_dense(stage_mesh):
     )
 
 
+@pytest.mark.slow
 def test_pipelined_moe_aux_loss_matches_dense(stage_mesh):
     """The sown load-balancing loss rides the ring (round 3):
     mean-over-microbatches equals the dense whole-batch aux exactly in
@@ -327,6 +329,7 @@ def test_pp_sp_moe_raises():
         pipelined_lm_apply(model, {}, tokens, mesh, seq_axis="seq")
 
 
+@pytest.mark.slow
 def test_pp_train_step_matches_dense_train_step(stage_mesh):
     """One optimizer step through the ring equals one dense step: same
     loss, same updated params (logit parity extends to grads)."""
@@ -384,6 +387,7 @@ def test_pp_train_step_with_inner_sp():
     assert losses[-1] < losses[0]
 
 
+@pytest.mark.slow
 def test_dp_outside_pp_matches_dense():
     """mesh {data: 2, stage: 2}: every data coordinate runs its own
     microbatch ring over its batch shard; logits match dense and one
@@ -447,6 +451,7 @@ def test_dp_pp_sp_three_axis_composition():
     np.testing.assert_allclose(logits, dense, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_pp_with_tp_inside_stages_matches_dense():
     """mesh {stage: 2, model: 2}: Megatron split inside each stage —
     qkv/gate/up column-sharded, out/down row-sharded with psum — and
@@ -643,14 +648,17 @@ def _sched_lm_and_state():
     return model, state, tokens
 
 
+@pytest.mark.slow
 def test_all_schedules_bit_identical_losses_and_grads():
-    """The tentpole equivalence matrix: 1F1B and interleaved produce
-    bit-identical losses to the sequential (gpipe) schedule; gradients
-    (observed through the SGD update) are bit-identical at matched
-    parameter chunking — gpipe-vs-1f1b at v=1, gpipe-vs-interleaved at
-    v=2 (re-blocking layers into different scan chunks legitimately
-    perturbs single ULPs, so the sequential reference uses the same
-    chunking; losses are forward-only and match across v too)."""
+    """The tentpole equivalence matrix: at matched parameter chunking
+    1F1B and interleaved produce bit-identical losses and gradients
+    (observed through the SGD update) to the sequential (gpipe)
+    schedule — gpipe-vs-1f1b at v=1, gpipe-vs-interleaved at v=2.
+    Re-blocking layers into different scan chunks is another program to
+    XLA and perturbs single ULPs, in the forward pass too: across
+    chunkings the loss is held to 4 ulp (measured 1 on JAX 0.9.0's CPU
+    backend: 3.9314706 at v=1, 3.9314709 at v=2) and the update to
+    float tolerance."""
     from hops_tpu.parallel.pipeline import make_pp_lm_train_step
 
     model, state, tokens = _sched_lm_and_state()
@@ -664,19 +672,21 @@ def test_all_schedules_bit_identical_losses_and_grads():
             model, mesh, schedule=kind, num_microbatches=4, virtual_stages=v))
         st, metrics = step(state, tokens)
         out[name] = (st, float(metrics["loss"]))
-    # Losses: bit-identical across ALL schedules and chunkings.
-    assert len({loss for _, loss in out.values()}) == 1
-    # Gradients: bit-identical at matched chunking.
+    # Losses and gradients: bit-identical at matched chunking.
     for a, b in [("gpipe", "1f1b"), ("gpipe_v2", "interleaved")]:
+        assert out[a][1] == out[b][1]
         for x, y in zip(jax.tree.leaves(out[a][0].params),
                         jax.tree.leaves(out[b][0].params)):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    # And across chunkings the update still agrees to float tolerance.
+    # Across chunkings: the loss to the ulp, the update to float tolerance.
+    np.testing.assert_array_max_ulp(
+        np.float32(out["gpipe"][1]), np.float32(out["interleaved"][1]), maxulp=4)
     for x, y in zip(jax.tree.leaves(out["gpipe"][0].params),
                     jax.tree.leaves(out["interleaved"][0].params)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
 
 
+@pytest.mark.slow
 def test_scheduled_gpipe_matches_autodiff_ring_and_dense():
     """The explicit tick program is a different derivation of the same
     math: its loss/update agree with the legacy autodiff fill-drain

@@ -1,8 +1,8 @@
-"""Pipeline schedule tables + the explicit tick-program engine (fast
-tier): builder invariants, bubble/bookkeeping stats, 1F1B-vs-sequential
+"""Pipeline schedule tables + the explicit tick-program engine:
+builder invariants, bubble/bookkeeping stats, 1F1B-vs-sequential
 bit-identity on a tiny LM, and the schedule telemetry. The full
 cross-schedule matrix (interleaved, dense parity, bigger meshes) lives
-in tests/test_pipeline.py's slow tier."""
+in tests/test_pipeline.py."""
 
 import jax
 import jax.numpy as jnp

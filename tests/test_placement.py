@@ -492,7 +492,6 @@ class TestPlacementChaos:
 # -- bench tier ---------------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_bench_multi_host_smoke(workspace):
     """`bench.py --multi-host --smoke` runs the whole tier — local vs
     placed fleet, local vs placed shard fan-out, warm-start identity —

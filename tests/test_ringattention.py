@@ -51,7 +51,6 @@ def test_ulysses_rejects_indivisible_heads(seq_mesh):
         ulysses_attention(q, k, v, seq_mesh)
 
 
-@pytest.mark.slow
 def test_ring_attention_grads_flow(seq_mesh):
     q, k, v = _inputs(seq=128)
 
@@ -67,7 +66,6 @@ def test_ring_attention_grads_flow(seq_mesh):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
 
 
-@pytest.mark.slow
 def test_ring_and_ulysses_with_sliding_window():
     """window composes with both sp schemes: outputs match the XLA
     windowed reference on the fake mesh."""
@@ -108,7 +106,6 @@ def test_ring_attention_gqa_matches_repeated(seq_mesh, causal):
     np.testing.assert_allclose(out, ref, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_ring_attention_gqa_windowed(seq_mesh):
     from hops_tpu.ops.attention import repeat_kv
 
@@ -136,7 +133,6 @@ def test_ulysses_gqa_matches_repeated(seq_mesh, kv_heads):
     np.testing.assert_allclose(out, ref, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.slow
 def test_gqa_lm_ring_matches_reference_impl():
     """Model-level: a GQA TransformerLM under ring attention produces
     the same logits as the single-chip reference impl."""
@@ -155,7 +151,6 @@ def test_gqa_lm_ring_matches_reference_impl():
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_gqa_windowed_lm_ring_matches_reference_impl():
     """Model-level GQA + window + ring attention: full knob stack on the
     sp training path equals the single-chip reference."""

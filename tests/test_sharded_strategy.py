@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-import pytest
 
 from hops_tpu.models import common
 from hops_tpu.models.mnist import CNN
@@ -12,7 +11,6 @@ from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
 from hops_tpu.parallel import ShardedStrategy, Strategy
 from hops_tpu.parallel import mesh as mesh_lib
 
-pytestmark = pytest.mark.slow  # heavy compiles / subprocess e2e (fast tier: -m 'not slow')
 
 
 def _cnn_state():

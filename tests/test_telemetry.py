@@ -409,7 +409,6 @@ class TestBatchPredictMetrics:
         assert rows_after - rows_before == 5
 
 
-@pytest.mark.slow  # TransformerLM compiles (same tier as test_lm_engine)
 def test_lm_engine_updates_token_and_prefix_metrics():
     """Acceptance: an lm_engine generate call observably updates the
     token counter (tokens/sec at scrape time) and prefix-cache

@@ -695,7 +695,6 @@ class TestFleetTraceE2E:
         assert req["parent_id"] == forwards[1]["span_id"]
 
 
-@pytest.mark.slow  # compiles the tiny LM's engine programs (jit)
 class TestLMTraceE2E:
     def test_lm_variant_records_dispatch_span(self, workspace):
         import jax.numpy as jnp

@@ -3,7 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from hops_tpu.models import common
 from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
@@ -16,7 +15,6 @@ def _tokens(batch=2, seq=64, seed=0):
     return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, TINY["vocab_size"])
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_forward_shape_and_dtype():
     model = TransformerLM(**TINY, attention_impl="reference")
     tokens = _tokens()
@@ -26,7 +24,6 @@ def test_forward_shape_and_dtype():
     assert logits.dtype == jnp.float32
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_train_step_reduces_loss():
     model = TransformerLM(**TINY, attention_impl="reference")
     state = common.create_train_state(
@@ -40,7 +37,6 @@ def test_train_step_reduces_loss():
     assert float(metrics["loss"]) < float(first["loss"])
 
 
-@pytest.mark.slow  # heavy jit compile (fast-tier budget: round-5 re-tiering)
 def test_flash_and_reference_impls_agree():
     tokens = _tokens(seq=128)
     ref = TransformerLM(**TINY, attention_impl="reference")
@@ -51,7 +47,6 @@ def test_flash_and_reference_impls_agree():
     )
 
 
-@pytest.mark.slow
 def test_ring_impl_matches_reference_on_mesh():
     mesh = mesh_lib.make_mesh({"seq": 4}, devices=jax.devices()[:4])
     tokens = _tokens(batch=1, seq=128)
@@ -63,7 +58,6 @@ def test_ring_impl_matches_reference_on_mesh():
     )
 
 
-@pytest.mark.slow
 def test_remat_matches_plain():
     tokens = _tokens(seq=32)
     plain = TransformerLM(**TINY, attention_impl="reference")

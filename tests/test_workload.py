@@ -572,7 +572,6 @@ class TestOverhead:
 # -- the bench replay tier, end to end ----------------------------------------
 
 
-@pytest.mark.slow  # in-process fleet + full artifact replay (~15 s)
 class TestReplayBenchE2E:
     def test_capture_from_live_fleet_replays_through_bench(
         self, tmp_path, workspace
@@ -627,6 +626,7 @@ class TestReplayBenchE2E:
         assert report["recorded"]["status_mix"].keys() == {"200"}
         assert report["arrival"]["p50_error_frac"] < 0.10
 
+    @pytest.mark.slow
     def test_bench_replay_smoke_cli_end_to_end(self, tmp_path):
         """`bench.py --replay-scenario herd --smoke` runs the whole
         tier — synthesize, stand up an in-process fleet, replay — and
