@@ -8,15 +8,18 @@ tile at a time, with the online-softmax statistics (running max ``m``,
 running sum ``l``) carried in fp32 VMEM scratch, so HBM traffic is
 O(seq·d) instead of O(seq²).
 
-Layout: ``(batch, heads, seq, head_dim)``. Grid is
-``(batch·heads, seq_q/block_q, seq_k/block_k)`` — Pallas streams each
-K/V block from HBM per grid step (double-buffered by the pipeline), so
-VMEM holds only one q/k/v tile plus the accumulators and sequence
-length is unbounded (the cells train at 4,096; longer sequences are not
-measured on this stack: PERF §7).
-Causal runs skip fully-masked K blocks. The backward pass is two more
-kernels (dq and dk/dv) using the saved logsumexp, the standard
-flash-attention-2 split.
+Layout: ``(batch, heads, seq, head_dim)``. Grid is ``(batch·heads,
+seq_q/block_q, key steps)`` — Pallas streams each K/V block from HBM per
+grid step (double-buffered by the pipeline), so VMEM holds only one
+q/k/v tile plus the accumulators and sequence length is unbounded (the
+cells train at 4,096; longer sequences are not measured on this stack:
+PERF §7). Causal and sliding-window calls work on the band only
+(`_Band`): the key axis of the grid is as long as the most key tiles a
+query tile can meet and starts at the tile's first one, and inside a
+grid tile the kernels walk sub-tiles of `_SUBTILE`, skip those outside
+the band and mask only those the diagonal or the window's edge crosses.
+The backward pass is two more kernels (dq and dk/dv) using the saved
+logsumexp, the standard flash-attention-2 split.
 
 For cross-device sequence parallelism see
 ``hops_tpu.parallel.ringattention`` which rotates K/V chunks over the
@@ -25,6 +28,7 @@ ICI ring and feeds each local chunk through this kernel's math.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any
@@ -34,6 +38,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_SUBTILES
+
 NEG_INF = float("-inf")
 _LANES = 128  # VPU lane width: per-row stats are broadcast across lanes
 
@@ -41,13 +48,14 @@ _LANES = 128  # VPU lane width: per-row stats are broadcast across lanes
 # dims carry scratch state between steps and must stay "arbitrary".
 _GRID_SEMANTICS = ("parallel", "arbitrary", "arbitrary")
 
-# Flash kernels: above 4k keys the tiles are 1024x2048, and the backward
-# kernels hold four fp32 (block_q, block_k) intermediates (s, p, dp, ds
-# — 8 MiB each before Mosaic reuses them). Mosaic's default 16 MiB
-# scoped-VMEM limit refuses that by 72 KB at seq 8192 and by 1.6 MB at
-# 32k (libtpu 0.0.34); 32 MiB fits every tile `flash_attention` picks
-# (compiled and checked against the reference at seq 8192 on a v5e,
-# compiled ahead of time at 32k).
+# Flash kernels: the fp32 intermediates (s, p, dp, ds) are sub-tile
+# sized since PR 28, 1 MiB each at 512 x 512, and every tile
+# `flash_attention` picks compiles for a described v5e within Mosaic's
+# default 16 MiB of scoped VMEM, 32k keys included (compile, PR 28;
+# libtpu 0.0.34). The 32 MiB stay for a caller's own block that
+# `_SUBTILE` does not divide, which is one sub-tile whatever its size
+# (PR 21 needed them for whole-tile intermediates of 1024 x 2048, 8 MiB
+# each); the cells were measured with it set.
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=_GRID_SEMANTICS, vmem_limit_bytes=32 * 1024 * 1024
 )
@@ -111,35 +119,200 @@ def attention_reference(
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-def _causal_mask(s, qi, kj, block_q, block_k, q_offset, window=None):
-    q_pos = qi * block_q + q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    visible = q_pos >= k_pos
+# Edge, in queries and in keys, of the sub-tile at which the three
+# training kernels decide "skip / compute unmasked / compute masked". A
+# grid tile (the block Pallas fetches per step) is walked in sub-tiles
+# of this edge inside the kernel body; a side of the grid tile that this
+# does not divide (every 128-tile of tier-1, a 384 divisor) is one
+# sub-tile. Visited pairs over visible pairs at 4,096 keys: 1.250 with a
+# window of 2,047 and 1.125 without, against 1.500 / 1.250 for the 1,024
+# x 1,024 grid tile alone; 256 would give 1.125 / 1.062 and is slower:
+# every visit of a row of queries pays its row statistics and its
+# accumulator's read-modify-write whatever the number of keys, and below
+# 512 keys that cost outweighs the pairs saved. The three kernels at the
+# Phi-3 cell's shape (64 batch-heads, 4,096 keys, d_head 96, window
+# 2,047), ms a call, sub-tile 1,024 / 512 / 256 inside a 1,024 grid
+# tile: forward 3.38 / 3.08 / 4.80, dQ 3.74 / 3.60 / 4.69, dK/dV 4.80 /
+# 4.46 / 5.00 (my chip runs, PR 28; PERF §6).
+_SUBTILE = 512
+
+_m_subtiles = REGISTRY.counter(
+    COUNTER_TRAIN_FLASH_SUBTILES,
+    "Sub-tiles of one batch-head in each traced flash kernel, by what the kernel does with them",
+    labels=("kernel", "kind"),
+)
+
+
+def _tile_span(i, outer, inner, n_inner, lo_off, hi_off):
+    """First and last of the ``n_inner`` tiles of edge ``inner`` that hold
+    a position in ``[i*outer + lo_off, (i+1)*outer - 1 + hi_off]`` (an
+    offset of None: unbounded on that side); ``last < first`` where none
+    does. ``i`` is a Python int (the grid's size, the tests) or a traced
+    scalar (index maps and kernel bodies), so the two can never disagree."""
+    at_least, at_most = (max, min) if isinstance(i, int) else (jnp.maximum, jnp.minimum)
+    first = 0 if lo_off is None else at_least(i * outer + lo_off, 0) // inner
+    if hi_off is None:
+        return first, n_inner - 1
+    # (p + inner) // inner - 1 is p // inner for p >= 0 and -1 below, with
+    # no floor division of a negative number.
+    last = at_least((i + 1) * outer - 1 + hi_off + inner, 0) // inner - 1
+    return first, at_most(last, n_inner - 1)
+
+
+def _widest(span, n_outer) -> int:
+    return max(1, max(last - first + 1 for first, last in map(span, range(n_outer))))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Band:
+    """Where the visible (query, key) pairs of one flash call lie and how
+    the call is tiled: query row ``i`` sits at absolute position ``i +
+    q_offset`` and sees keys ``[pos - window + 1, pos]`` (``causal``), or
+    every key. All fields are Python values at trace time, so the same
+    predicates classify a sub-tile from Python ints (the counter, the
+    tests) and from ``program_id``s (the kernels)."""
+
+    seq_q: int
+    seq_k: int
+    block_q: int
+    block_k: int
+    sub_q: int
+    sub_k: int
+    q_offset: int
+    causal: bool
+    window: int | None
+
+    def intersects(self, q_lo, q_len, k_lo, k_len):
+        """Whether any key of ``[k_lo, k_lo + k_len)`` is visible to any
+        query at positions ``[q_lo, q_lo + q_len)``: blocks past the
+        diagonal and blocks wholly below the window hold no work, which
+        makes windowed attention O(seq * window)."""
+        if not self.causal:
+            return True
+        hit = k_lo <= q_lo + q_len - 1
+        if self.window is not None:
+            # the block's newest key against the oldest position its
+            # oldest query still sees
+            hit &= k_lo + k_len - 1 >= q_lo - (self.window - 1)
+        return hit
+
+    def contains(self, q_lo, q_len, k_lo, k_len):
+        """Whether every key of the block is visible to every query of it:
+        such a block needs no mask."""
+        if not self.causal:
+            return True
+        inside = k_lo + k_len - 1 <= q_lo
+        if self.window is not None:
+            inside &= q_lo + q_len - 1 - k_lo < self.window
+        return inside
+
+    def key_tiles(self, qi):
+        """(first, last) grid tile of keys that query tile ``qi`` can meet."""
+        if not self.causal:
+            return 0, self.seq_k // self.block_k - 1
+        lo = None if self.window is None else self.q_offset - (self.window - 1)
+        return _tile_span(qi, self.block_q, self.block_k, self.seq_k // self.block_k, lo, self.q_offset)
+
+    def query_tiles(self, kj):
+        """(first, last) grid tile of queries that key tile ``kj`` can meet."""
+        if not self.causal:
+            return 0, self.seq_q // self.block_q - 1
+        hi = None if self.window is None else self.window - 1 - self.q_offset
+        return _tile_span(kj, self.block_k, self.block_q, self.seq_q // self.block_q, -self.q_offset, hi)
+
+    def key_steps(self) -> int:
+        """Grid steps on the key axis: the most key tiles any query tile meets."""
+        return _widest(self.key_tiles, self.seq_q // self.block_q)
+
+    def query_steps(self) -> int:
+        return _widest(self.query_tiles, self.seq_k // self.block_k)
+
+    def subtile_kinds(self) -> dict[str, int]:
+        """Sub-tiles of one batch-head by what every kernel does with
+        them: ``interior`` (computed with no mask), ``edge`` (computed
+        masked), ``skipped`` (no work)."""
+        kinds = {"interior": 0, "edge": 0, "skipped": 0}
+        for q_lo in range(self.q_offset, self.q_offset + self.seq_q, self.sub_q):
+            for k_lo in range(0, self.seq_k, self.sub_k):
+                block = (q_lo, self.sub_q, k_lo, self.sub_k)
+                kinds["interior" if self.contains(*block) else "edge" if self.intersects(*block) else "skipped"] += 1
+        return kinds
+
+
+def _count_subtiles(kernel: str, band: _Band) -> None:
+    for kind, n in band.subtile_kinds().items():
+        _m_subtiles.inc(n, kernel=kernel, kind=kind)
+
+
+def _causal_mask(s, shift, window, keys_first=False):
+    """``s`` with -inf at the pairs of an edge sub-tile that are not
+    visible; ``shift`` is the sub-tile's first key position less its
+    first query position, so ``row - col - shift`` is how far a key lies
+    behind its query."""
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    behind = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+              - jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis))
+    visible = behind >= shift
     if window is not None:
-        visible &= q_pos - k_pos < window
+        visible &= behind < window + shift
     return jnp.where(visible, s, NEG_INF)
 
 
-def _block_runs(qi, kj, block_q, block_k, q_offset, causal, window):
-    """Whether a (qi, kj) tile intersects the (windowed-)causal band —
-    tiles past the diagonal AND tiles fully below the sliding window
-    are skipped entirely, making long-sequence windowed attention
-    O(seq * window) compute."""
-    if not causal:
-        return True
-    runs = kj * block_k < (qi + 1) * block_q + q_offset
-    if window is not None:
-        # Tile's newest key vs the oldest position the tile's oldest
-        # query still sees.
-        runs = jnp.logical_and(
-            runs, (kj + 1) * block_k - 1 >= qi * block_q + q_offset - (window - 1)
-        )
-    return runs
+def _walk_subtiles(band, qi, kj, live, cell):
+    """The one loop of the three kernels: ``cell(a, b, shift)`` for every
+    sub-tile ``(a, b)`` of grid tile ``(qi, kj)`` that the band touches.
+    ``shift`` is None on an interior sub-tile (no iota, compare or select
+    is traced for it) and `_causal_mask`'s argument on an edge one; a
+    sub-tile outside the band, or a grid step past the tile's span
+    (``live`` false), runs nothing. More than one sub-tile is a
+    `fori_loop` with ``a`` and ``b`` traced, so a kernel's body is traced
+    and lowered twice (interior, edge) whatever the number of sub-tiles:
+    unrolled, the four sub-tiles of a 1,024 tile took a warm start of
+    the cells 2 to 5 s longer and the kernels 1 to 3 % less (my chip
+    runs, PR 28; PERF §6). ``cell``
+    loads what it needs from its refs itself: operands loaded once per
+    row of sub-tiles and carried into the branches cost dQ 0.7 ms a call
+    at the Phi-3 cell's shape."""
+    n_a, n_b = band.block_q // band.sub_q, band.block_k // band.sub_k
+
+    def visit(a, b):
+        if not band.causal:  # every sub-tile is interior
+            return cell(a, b, None)
+        q_lo = qi * band.block_q + a * band.sub_q + band.q_offset
+        k_lo = kj * band.block_k + b * band.sub_k
+        inside = band.contains(q_lo, band.sub_q, k_lo, band.sub_k)
+        hit = band.intersects(q_lo, band.sub_q, k_lo, band.sub_k)
+        pl.when(live & inside)(functools.partial(cell, a, b, None))
+        pl.when(live & hit & jnp.logical_not(inside))(functools.partial(cell, a, b, k_lo - q_lo))
+
+    if n_a * n_b == 1:
+        return visit(0, 0)
+
+    @functools.partial(jax.lax.fori_loop, 0, n_a * n_b, init_val=None)
+    def _(i, carry):
+        visit(jax.lax.div(i, n_b), jax.lax.rem(i, n_b))
+        return carry
+
+
+def _sub(i, size):
+    """Rows ``[i*size, (i+1)*size)`` of a tile; ``i`` may be traced."""
+    return pl.ds(pl.multiple_of(i * size, size), size)
 
 
 # ---------------------------------------------------------------------------
-# Forward kernel: grid (bh, nq, nk), K/V streamed per grid step
+# Forward kernel: grid (bh, nq, key steps), K/V streamed per grid step
 # ---------------------------------------------------------------------------
+
+
+def _across_lanes(x, n):
+    """``(rows, n)`` from a ``(rows, 128)`` array whose lanes all hold the
+    row's value: a lane slice or whole-vreg repeats where ``n`` allows
+    (no cross-lane broadcast), else a broadcast of the first lane."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return pltpu.repeat(x, n // _LANES, axis=1)
+    return x[:, :1]
 
 
 def _online_softmax_update(sc, vb, m_scr, l_scr, acc_scr, p_scale=None):
@@ -150,55 +323,57 @@ def _online_softmax_update(sc, vb, m_scr, l_scr, acc_scr, p_scale=None):
     training forward kernel and both decode kernels — this rescaling
     is the subtlest numerics in the file and must exist exactly once.
 
+    ``m_scr`` and ``l_scr`` hold a row's value in every one of their 128
+    lanes and the arithmetic on them stays lane-replicated; only the two
+    row reductions cross lanes.
+
     ``p_scale`` (1, block_k) folds a per-key scale into the prob@value
     dot ONLY (the int8 path's v_scale — ``vb`` then holds raw int8
     values cast to its dtype); the softmax denominator ``l`` always
     sums the UNSCALED probs."""
-    m = m_scr[:, :1]  # (rows, 1), broadcast across lanes
-    l = l_scr[:, :1]
+    m = m_scr[...]  # (rows, 128)
     m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
     m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-    p = jnp.exp(sc - m_safe)
+    p = jnp.exp(sc - _across_lanes(m_safe, sc.shape[-1]))
     alpha = jnp.exp(jnp.where(m == NEG_INF, NEG_INF, m - m_safe))
-    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
         (p if p_scale is None else p * p_scale).astype(vb.dtype), vb,
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     )
-    acc_scr[...] = acc_scr[...] * alpha + pv
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    acc_scr[...] = acc_scr[...] * _across_lanes(alpha, acc_scr.shape[-1]) + pv
+    m_scr[...] = m_new
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, causal, block_q, block_k, q_offset, window,
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale, band,
 ):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
+    first, last = band.key_tiles(qi)
+    kj = first + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: skip K blocks above the diagonal or below the window.
-    run = _block_runs(qi, kj, block_q, block_k, q_offset, causal, window)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0]
-        kb = k_ref[0]
+    def cell(a, b, shift):
+        rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
         s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0, rows, :], k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         s = s * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, q_offset, window)
-        _online_softmax_update(s, v_ref[0], m_scr, l_scr, acc_scr)
+        if shift is not None:
+            s = _causal_mask(s, shift, band.window)
+        _online_softmax_update(
+            s, v_ref[0, cols, :], m_scr.at[rows], l_scr.at[rows], acc_scr.at[rows]
+        )
 
-    @pl.when(kj == nk - 1)
+    _walk_subtiles(band, qi, kj, kj <= last, cell)
+
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -208,7 +383,7 @@ def _fwd_kernel(
         # lse rides as a full (1, 1, seq_q) row per (batch·head) — TPU
         # block shapes must tile (8, 128) or span their dims, so each
         # q-block program dynamic-stores its slice of the shared row.
-        lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = lse[:, 0]
+        lse_ref[0, 0, pl.ds(qi * band.block_q, band.block_q)] = lse[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -216,90 +391,105 @@ def _fwd_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, causal, block_q, block_k, q_offset, window,
-):
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
-    @pl.when(kj == 0)
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _bwd_p_ds(q, kb, do, vb, lse, delta, shift, sm_scale, window, keys_first):
+    """The probabilities of one sub-tile, made again from the saved
+    logsumexp, and the gradient of its scores: what dQ and dK/dV share.
+    Queries on the rows with ``lse`` and ``delta`` as columns (dQ), or
+    ``keys_first``: keys on the rows with the two statistics as the
+    lane-major rows they are stored as. dK/dV takes the second form: its
+    two products with ``p`` and ``ds`` are then plain matmuls and no
+    score-shaped matrix is transposed (5.30 -> 4.80 ms a call at the
+    Phi-3 cell's shape; the same turn makes dQ slower, 3.57 -> 3.72: my
+    chip runs, PR 28). Operands are float32 as the callers cast them:
+    feeding the inputs' dtype gave under 0.1 ms a call (PERF §6 PR 28)."""
+    s = jax.lax.dot_general(
+        *((kb, q) if keys_first else (q, kb)), _NT, preferred_element_type=jnp.float32
+    )
+    s = s * sm_scale
+    if shift is None:
+        # every pair visible: the query has keys, so its lse is finite
+        p = jnp.exp(s - lse)
+    else:
+        s = _causal_mask(s, shift, window, keys_first)
+        lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
+        p = jnp.where(lse == NEG_INF, 0.0, jnp.exp(s - lse_safe))
+    dp = jax.lax.dot_general(
+        *((vb, do) if keys_first else (do, vb)), _NT, preferred_element_type=jnp.float32
+    )
+    return p, p * (dp - delta) * sm_scale
+
+
+def _bwd_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, sm_scale, band,
+):
+    qi, step = pl.program_id(1), pl.program_id(2)
+    first, last = band.key_tiles(qi)
+    kj = first + step
+
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = _block_runs(qi, kj, block_q, block_k, q_offset, causal, window)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    def cell(a, b, shift):
+        rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
+        at = pl.ds(qi * band.block_q + a * band.sub_q, band.sub_q)
+        kb = _f32(k_ref[0, cols, :])
+        _, ds = _bwd_p_ds(
+            _f32(q_ref[0, rows, :]), kb, _f32(do_ref[0, rows, :]), _f32(v_ref[0, cols, :]),
+            lse_ref[0, 0, at][:, None], delta_ref[0, 0, at][:, None],
+            shift, sm_scale, band.window, keys_first=False,
         )
-        s = s * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, q_offset, window)
-        lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
-        p = jnp.where(lse == NEG_INF, 0.0, jnp.exp(s - lse_safe))
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        dq_scr[rows, :] += jax.lax.dot_general(
+            ds, kb, _NN, preferred_element_type=jnp.float32
         )
 
-    @pl.when(kj == nk - 1)
+    _walk_subtiles(band, qi, kj, kj <= last, cell)
+
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, sm_scale, causal, block_q, block_k, q_offset, window,
+    dk_scr, dv_scr, *, sm_scale, band,
 ):
-    kj, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    kj, step = pl.program_id(1), pl.program_id(2)
+    first, last = band.query_tiles(kj)
+    qi = first + step
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = _block_runs(qi, kj, block_q, block_k, q_offset, causal, window)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    def cell(a, b, shift):
+        rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
+        at = pl.ds(qi * band.block_q + a * band.sub_q, band.sub_q)
+        q, do = _f32(q_ref[0, rows, :]), _f32(do_ref[0, rows, :])
+        p, ds = _bwd_p_ds(
+            q, _f32(k_ref[0, cols, :]), do, _f32(v_ref[0, cols, :]),
+            lse_ref[0, :, at], delta_ref[0, :, at],
+            shift, sm_scale, band.window, keys_first=True,
         )
-        s = s * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, q_offset, window)
-        lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
-        p = jnp.where(lse == NEG_INF, 0.0, jnp.exp(s - lse_safe))
-        dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        dv_scr[cols, :] += jax.lax.dot_general(
+            p, do, _NN, preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        dk_scr[cols, :] += jax.lax.dot_general(
+            ds, q, _NN, preferred_element_type=jnp.float32
         )
 
-    @pl.when(qi == nq - 1)
+    _walk_subtiles(band, qi, kj, qi <= last, cell)
+
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -315,34 +505,65 @@ def _flat(x):
     return x.reshape(b * h, s, d)
 
 
-def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, interpret):
+def _band_specs(band: _Band, d: int):
+    """BlockSpecs of the two grid orders. Under ``(bh, query tile, key
+    step)`` (forward, dQ) the K/V block of step ``j`` is the ``j``-th key
+    tile of the query tile's span; under ``(bh, key tile, query step)``
+    (dK/dV) the Q/dO block is the ``j``-th query tile of the key tile's
+    span. A step past the span's end maps to the span's last tile again
+    (Mosaic issues no copy for a block it already holds) and the kernel
+    body runs nothing for it, so no grid step and no fetch is spent on a
+    tile the band never touches."""
+    def stepped(span, n):
+        def index(b, i, j):
+            first, last = span(i)
+            return b, jnp.clip(jnp.minimum(first + j, last), 0, n - 1), 0
+        return index
+
+    k_of_q = stepped(band.key_tiles, band.seq_k // band.block_k)
+    q_of_k = stepped(band.query_tiles, band.seq_q // band.block_q)
+    own = lambda b, i, j: (b, i, 0)
+    stats = pl.BlockSpec((1, 1, band.seq_q), lambda b, i, j: (b, 0, 0))
+    q_major = {
+        "q": pl.BlockSpec((1, band.block_q, d), own),
+        "kv": pl.BlockSpec((1, band.block_k, d), k_of_q),
+        "stats": stats,
+    }
+    k_major = {
+        "q": pl.BlockSpec((1, band.block_q, d), q_of_k),
+        "kv": pl.BlockSpec((1, band.block_k, d), own),
+        "stats": stats,
+    }
+    return q_major, k_major
+
+
+# The two call builders are jitted so that a model's layers share one
+# trace of each kernel body (traced per layer, the bodies added 2 to 10 s
+# to a warm start of the cells: my chip runs, PR 28), and inlined so
+# that the enclosing program still holds one `pallas_call` per layer and
+# kernel under that layer's own scope.
+_per_geometry = functools.partial(
+    jax.jit, static_argnames=("band", "sm_scale", "interpret"), inline=True
+)
+
+
+@_per_geometry
+def _fwd_call(q, k, v, band, sm_scale, interpret):
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
-    grid = (bh, seq_q // block_q, seq_k // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, window=window,
-    )
+    spec, _ = _band_specs(band, d)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, seq_q), lambda b, i, j: (b, 0, 0)),
-        ],
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, band=band),
+        grid=(bh, seq_q // band.block_q, band.key_steps()),
+        in_specs=[spec["q"], spec["kv"], spec["kv"]],
+        out_specs=[spec["q"], spec["stats"]],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((band.block_q, _LANES), jnp.float32),
+            pltpu.VMEM((band.block_q, _LANES), jnp.float32),
+            pltpu.VMEM((band.block_q, d), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -350,80 +571,61 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, int
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, interpret):
-    o, _ = _fwd_call(
-        _flat(q), _flat(k), _flat(v), causal, sm_scale, block_q, block_k,
-        q_offset, window, interpret,
-    )
-    return o.reshape(q.shape)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, band, sm_scale, interpret):
+    return _flash_fwd(q, k, v, band, sm_scale, interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, interpret):
-    o, lse = _fwd_call(
-        _flat(q), _flat(k), _flat(v), causal, sm_scale, block_q, block_k,
-        q_offset, window, interpret,
-    )
+def _flash_fwd(q, k, v, band, sm_scale, interpret):
+    _count_subtiles("fwd", band)
+    o, lse = _fwd_call(_flat(q), _flat(k), _flat(v), band, sm_scale, interpret)
     return o.reshape(q.shape), (q, k, v, o.reshape(q.shape), lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, res, g):
-    q, k, v, o, lse = res
+def _flash_bwd(band, sm_scale, interpret, res, g):
+    _count_subtiles("dq", band)
+    _count_subtiles("dkv", band)
+    return _bwd_calls(*res, g, band, sm_scale, interpret)
+
+
+@_per_geometry
+def _bwd_calls(q, k, v, o, lse, g, band, sm_scale, interpret):
     shape = q.shape
     qf, kf, vf, of, gf = _flat(q), _flat(k), _flat(v), _flat(o), _flat(g)
     bh, seq_q, d = qf.shape
-    seq_k = kf.shape[1]
     delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), axis=-1)[:, None, :]
+    q_major, k_major = _band_specs(band, d)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, window=window,
-    )
     dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, seq_q // block_q, seq_k // block_k),
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, band=band),
+        grid=(bh, seq_q // band.block_q, band.key_steps()),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, seq_q), lambda b, i, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq_q), lambda b, i, j: (b, 0, 0)),
+            q_major["q"], q_major["kv"], q_major["kv"], q_major["q"],
+            q_major["stats"], q_major["stats"],
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=q_major["q"],
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((band.block_q, d), jnp.float32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, window=window,
-    )
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, seq_k // block_k, seq_q // block_q),
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, band=band),
+        grid=(bh, band.seq_k // band.block_k, band.query_steps()),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0)),
+            k_major["q"], k_major["kv"], k_major["kv"], k_major["q"],
+            k_major["stats"], k_major["stats"],
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+        out_specs=[k_major["kv"], k_major["kv"]],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, band.seq_k, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, band.seq_k, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((band.block_k, d), jnp.float32),
+            pltpu.VMEM((band.block_k, d), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -434,6 +636,12 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, window, interpret, 
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _sub_block(block: int) -> int:
+    """Sub-tile edge of a grid tile's side: `_SUBTILE` where it divides
+    the side, else the whole side."""
+    return _SUBTILE if block % _SUBTILE == 0 else block
 
 
 def _fit_block(seq: int, preferred: int) -> int | None:
@@ -469,7 +677,8 @@ def flash_attention(
 
     ``window`` (causal only): query p attends keys in
     ``[p - window + 1, p]`` — Mistral-style sliding-window attention.
-    Tiles fully below the window are skipped in all three kernels, so
+    Sub-tiles wholly below the window or past the diagonal are skipped
+    in all three kernels and their grid tiles are never fetched, so
     long-sequence compute is O(seq * window).
 
     Cross-length causal calls (chunked prefill: ``seq_q < seq_k``) run
@@ -492,14 +701,19 @@ def flash_attention(
     if q_offset is None:
         q_offset = seq_k - seq_q if causal else 0
     forced = block_q is not None or block_k is not None
-    # Default tiles by key length: fine for short sequences, coarse for
-    # long ones (fewer K/V refetches across q blocks). The table was
-    # chosen on a removed stack; the one row a cell runs is 1024 x 1024
-    # at 4,096 keys: `flash_roofline` 31.2 % at d_head 96 with a window
-    # (ledger, PR 26), about 54 % at d_head 128 without (PERF §6 PR 25).
-    # A preferred size that doesn't divide the sequence shrinks to the
-    # largest 128-multiple divisor rather than silently punting to the
-    # O(seq²) reference.
+    # Default grid tiles by key length: fine for short sequences, coarse
+    # for long ones (fewer K/V refetches across q blocks, fewer grid
+    # steps); what is skipped and masked is decided per `_SUBTILE`
+    # inside the tile, not by the tile. The one row a cell runs is 1024
+    # x 1024 at 4,096 keys, read again on the chip in PR 28 against 512
+    # x 512 with the same 512 sub-tiles: forward 2.99 / 3.23 ms a call,
+    # dQ 3.57 / 3.81, dK/dV 4.43 / 4.87 at d_head 96 with a window of
+    # 2,047, dQ 1.71 / 1.96 and dK/dV 2.21 / 2.51 at d_head 128 without
+    # (my chip runs, PR 28); forward and dQ at 1024 x 2048 are within
+    # 0.1 ms of 1024 x 1024. The other rows were chosen on a removed stack and are not
+    # measured on this one. A preferred size that doesn't divide the
+    # sequence shrinks to the largest 128-multiple divisor rather than
+    # silently punting to the O(seq²) reference.
     if seq_k <= 1024:
         default_q, default_k = 128, 128
     elif seq_k <= 2048:
@@ -529,9 +743,11 @@ def flash_attention(
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _flash(
-        q, k, v, causal, sm_scale, block_q, block_k, q_offset, window, interpret
+    band = _Band(
+        seq_q, seq_k, block_q, block_k, _sub_block(block_q), _sub_block(block_k),
+        q_offset, causal, window,
     )
+    return _flash(q, k, v, band, sm_scale, interpret)
 
 
 # ---------------------------------------------------------------------------

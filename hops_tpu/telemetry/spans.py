@@ -62,6 +62,17 @@ SCOPE_MLP = TRAIN_SCOPES[1]
 SPAN_TRAIN_INPUT_PUT = "hops_tpu_train_input_put"
 SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
 
+#: Trace-time counters of the training vocabulary: each says which form of
+#: an op a compiled step holds, and is added to while the step is traced.
+#: ``hops_tpu_train_per_shard_traces_total{op}`` (``parallel/mesh.py``),
+#: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``) and
+#: ``hops_tpu_train_moe_traces_total{impl}`` (``models/moe.py``) are named
+#: where they are counted; the flash kernels' sub-tiles
+#: (``ops/attention.py``: ``kernel`` = ``fwd`` | ``dq`` | ``dkv``, ``kind`` =
+#: ``interior`` | ``edge`` | ``skipped``, the counts of one batch-head per
+#: traced call) are named here.
+COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"
+
 
 def _sanitize(name: str) -> str:
     return _NAME_RE.sub("_", name)

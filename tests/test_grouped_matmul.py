@@ -121,3 +121,29 @@ def test_kernels_compile_for_v5e_at_olmoe_widths(one_chip, k, n):
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 2 and all(gm.KERNEL_NAME in line.split(" = ")[0] for line in calls)  # dX and dW
+
+
+@pytest.mark.parametrize("heads, d_head, window", [(32, 96, 2047), (16, 128, None)], ids=["phi3", "olmoe"])
+def test_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, heads, d_head, window):
+    """The three flash kernels at 2 x 4,096 tokens a chip with the default
+    tiles and sub-tiles (this file holds the one fixture that may load the
+    TPU compiler): Mosaic accepts the sub-tile slices, the band-sized grid
+    axis with its clamped index maps, and the VMEM they ask for. Nothing
+    runs."""
+    from hops_tpu.ops.attention import flash_attention
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        x = jax.ShapeDtypeStruct((2, heads, 4096, d_head), jnp.bfloat16, sharding=one_chip)
+
+        def grads(q, k, v):
+            return jax.grad(
+                lambda q, k, v: flash_attention(q, k, v, causal=True, window=window, interpret=False)
+                .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+        text = jax.jit(grads).lower(x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 3 and all(any(name in call for call in calls) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))

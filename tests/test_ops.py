@@ -152,6 +152,171 @@ def test_short_seq_routes_to_xla(monkeypatch):
     assert not calls  # explicit blocks force the kernel
 
 
+# -- the band: sub-tiles inside the grid tile, the band-sized grid axis ----------
+
+
+def _band(seq_q, seq_k, block_q, block_k, sub, q_offset, causal, window):
+    from hops_tpu.ops.attention import _Band
+
+    sub_q = sub if block_q % sub == 0 else block_q
+    sub_k = sub if block_k % sub == 0 else block_k
+    if q_offset is None:
+        q_offset = seq_k - seq_q if causal else 0
+    return _Band(seq_q, seq_k, block_q, block_k, sub_q, sub_k, q_offset, causal, window)
+
+
+def _visible(band):
+    """Brute force over every (query, key) pair: what the reference's mask says."""
+    q_pos = np.arange(band.seq_q)[:, None] + band.q_offset
+    k_pos = np.arange(band.seq_k)[None, :]
+    if not band.causal:
+        return np.ones((band.seq_q, band.seq_k), bool)
+    visible = q_pos >= k_pos
+    if band.window is not None:
+        visible &= q_pos - k_pos < band.window
+    return visible
+
+
+_GEOMETRIES = {
+    # the cells: 4,096 keys, 1,024 grid tiles, with Phi-3's window and without (OLMoE)
+    "phi3_512": (4096, 4096, 1024, 1024, 512, None, True, 2047),
+    "olmoe_512": (4096, 4096, 1024, 1024, 512, None, True, None),
+    "phi3_256": (4096, 4096, 1024, 1024, 256, None, True, 2047),
+    "olmoe_256": (4096, 4096, 1024, 1024, 256, None, True, None),
+    "phi3_128": (4096, 4096, 1024, 1024, 128, None, True, 2047),
+    "phi3_whole_tile": (4096, 4096, 1024, 1024, 1024, None, True, 2047),
+    "olmoe_whole_tile": (4096, 4096, 1024, 1024, 1024, None, True, None),
+    # rectangular K/V: the chunk at the end of the keys, at their start, and in the middle
+    "chunk_at_end": (512, 2048, 256, 512, 128, None, True, None),
+    "chunk_at_start": (512, 2048, 256, 512, 128, 0, True, None),
+    "chunk_windowed": (512, 2048, 256, 512, 128, 700, True, 300),
+    "rows_before_every_key": (512, 1024, 256, 256, 128, -300, True, 200),
+    "non_causal": (512, 1024, 256, 512, 128, None, False, None),
+    # a grid tile smaller than the sub-tile, a side the sub-tile does not divide
+    "tile_under_subtile": (512, 512, 128, 128, 512, None, True, 200),
+    "side_384": (768, 768, 384, 384, 256, None, True, 500),
+    "window_1": (512, 512, 256, 256, 128, None, True, 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GEOMETRIES))
+def test_subtile_kinds_match_brute_force(name):
+    """interior = every pair visible, skipped = none, edge = the rest; the
+    counts the counter reports are those; and the spans the grid and the
+    index maps are built from hold every tile with a visible pair."""
+    band = _band(*_GEOMETRIES[name])
+    visible = _visible(band)
+    counts = {"interior": 0, "edge": 0, "skipped": 0}
+    for i in range(0, band.seq_q, band.sub_q):
+        for j in range(0, band.seq_k, band.sub_k):
+            block = visible[i:i + band.sub_q, j:j + band.sub_k]
+            kind = "interior" if block.all() else "edge" if block.any() else "skipped"
+            counts[kind] += 1
+            args = (i + band.q_offset, band.sub_q, j, band.sub_k)
+            assert bool(band.contains(*args)) == (kind == "interior"), (name, i, j)
+            assert bool(band.intersects(*args)) == (kind != "skipped"), (name, i, j)
+    assert band.subtile_kinds() == counts
+    nq, nk = band.seq_q // band.block_q, band.seq_k // band.block_k
+    for qi in range(nq):
+        for kj in range(nk):
+            if visible[qi * band.block_q:(qi + 1) * band.block_q, kj * band.block_k:(kj + 1) * band.block_k].any():
+                first, last = band.key_tiles(qi)
+                assert first <= kj <= last and last - first < band.key_steps(), (name, qi, kj)
+                first, last = band.query_tiles(kj)
+                assert first <= qi <= last and last - first < band.query_steps(), (name, qi, kj)
+    assert 1 <= band.key_steps() <= nk and 1 <= band.query_steps() <= nq
+
+
+@pytest.mark.parametrize("name, ratio, kinds, steps", [
+    ("phi3_whole_tile", 1.500, None, 3),
+    ("olmoe_whole_tile", 1.250, None, 4),
+    ("phi3_512", 1.250, {"interior": 13, "edge": 17, "skipped": 34}, 3),
+    ("olmoe_512", 1.125, {"interior": 28, "edge": 8, "skipped": 28}, 4),
+    ("phi3_256", 1.125, {"interior": 75, "edge": 33, "skipped": 148}, 3),
+    ("olmoe_256", 1.062, {"interior": 120, "edge": 16, "skipped": 120}, 4),
+    ("phi3_128", 1.063, None, 3),
+])
+def test_visited_over_visible_at_the_cells_shapes(name, ratio, kinds, steps):
+    """ISSUE 28's table: pairs the kernels compute over pairs the mask
+    shows, at 4,096 keys; and the key axis of the grid with the window is
+    three 1,024-tiles long where the sequence has four."""
+    band = _band(*_GEOMETRIES[name])
+    got = band.subtile_kinds()
+    visited = (got["interior"] + got["edge"]) * band.sub_q * band.sub_k
+    assert visited / _visible(band).sum() == pytest.approx(ratio, abs=6e-4)
+    assert kinds is None or got == kinds
+    assert band.key_steps() == band.query_steps() == steps
+
+
+def test_default_subtile_is_the_measured_one():
+    from hops_tpu.ops import attention as A
+
+    assert A._SUBTILE == 512
+    assert [A._sub_block(b) for b in (128, 384, 512, 1024, 2048)] == [128, 384, 512, 512, 512]
+
+
+def _flash_vs_reference(monkeypatch, q, k, v, *, sub, **kw):
+    """Forward and the three gradients against the reference at the
+    file's tolerances, with ``_SUBTILE`` forced to ``sub``."""
+    from hops_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_SUBTILE", sub)
+    blocks = {"block_q": kw.pop("block_q", 256), "block_k": kw.pop("block_k", 256)}
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)  # a cotangent that is not all ones
+
+    out = A.flash_attention(q, k, v, **kw, **blocks)
+    ref = A.attention_reference(q, k, v, **kw)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    g_flash = jax.grad(lambda q, k, v: (A.flash_attention(q, k, v, **kw, **blocks) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda q, k, v: (A.attention_reference(q, k, v, **kw) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("window", [127, 128, 129, 255, 256, 257, None])
+def test_subtiled_flash_matches_reference_around_subtile_boundaries(monkeypatch, window):
+    """Sub-tile 128 inside 256-tiles at 512 keys: the window's lower edge
+    on, one before and one after a sub-tile boundary; from 256 up one
+    call holds interior, edge and skipped sub-tiles."""
+    kinds = _band(512, 512, 256, 256, 128, None, True, window).subtile_kinds()
+    assert kinds["edge"] and kinds["skipped"] and bool(kinds["interior"]) == (window is None or window >= 256)
+    q, k, v = _inputs(batch=1, heads=2, seq=512, d=32)
+    _flash_vs_reference(monkeypatch, q, k, v, sub=128, causal=True, window=window)
+
+
+@pytest.mark.parametrize("q_offset, window, causal", [
+    (None, None, True), (None, 200, True), (0, None, True), (130, 257, True), (None, None, False),
+], ids=["at_end", "at_end_windowed", "at_start", "inside_windowed", "non_causal"])
+def test_subtiled_flash_rectangular_kv(monkeypatch, q_offset, window, causal):
+    """seq_q < seq_k with rectangular grid tiles (256 x 512) and 128
+    sub-tiles: forward, dQ, and dK/dV with its query axis band-sized."""
+    q, _, _ = _inputs(batch=1, heads=2, seq=256, d=32)
+    _, k, v = _inputs(batch=1, heads=2, seq=1024, d=32, seed=1)
+    _flash_vs_reference(
+        monkeypatch, q, k, v, sub=128, block_q=256, block_k=512,
+        causal=causal, window=window, q_offset=q_offset,
+    )
+
+
+@pytest.mark.parametrize("sub", [64, 128, 256])
+def test_fully_masked_rows_return_zeros(monkeypatch, sub):
+    """A negative offset puts the first queries before every key: those
+    rows (whole sub-tiles of them at 64, part of one at 128 and 256) come
+    back as zeros with zero gradients, not NaN."""
+    from hops_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_SUBTILE", sub)
+    q, k, v = _inputs(batch=1, heads=1, seq=256, d=32)
+    call = functools.partial(A.flash_attention, causal=True, q_offset=-100, window=90, block_q=256, block_k=256)
+    out = call(q, k, v)
+    assert not np.isnan(out).any() and np.all(np.asarray(out[:, :, :100]) == 0.0)
+    ref = A.attention_reference(q[:, :, 100:], k, v, causal=True, q_offset=0, window=90)
+    np.testing.assert_allclose(out[:, :, 100:], ref, atol=2e-5, rtol=2e-5)
+    dq, dk, dv = jax.grad(lambda q, k, v: call(q, k, v).sum(), argnums=(0, 1, 2))(q, k, v)
+    assert np.all(np.asarray(dq[:, :, :100]) == 0.0)
+    assert not any(np.isnan(g).any() for g in (dq, dk, dv))
+
+
 # -- decode attention (KV-cache token steps) ---------------------------------
 
 

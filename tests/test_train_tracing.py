@@ -19,8 +19,9 @@ from hops_tpu.parallel import grad_comms as gc
 from hops_tpu.parallel import get_strategy
 from hops_tpu.parallel import mesh as mesh_lib
 from hops_tpu.parallel.strategy import ShardedStrategy, Strategy
-from hops_tpu.telemetry import REGISTRY, tracing
+from hops_tpu.telemetry import REGISTRY, render_prometheus, tracing
 from hops_tpu.telemetry.spans import (
+    COUNTER_TRAIN_FLASH_SUBTILES,
     SPAN_TRAIN_DISPATCH,
     SPAN_TRAIN_INPUT_PUT,
     TRAIN_SCOPES,
@@ -134,6 +135,35 @@ def _pallas_names(jaxpr) -> list:
 ], ids=["flash_fwd", "flash_fwd_bwd", "dense_decode", "dense_decode_q8", "paged_decode"])
 def test_every_pallas_call_has_a_stable_name(entry, args, names):
     assert sorted(_pallas_names(jax.make_jaxpr(entry)(*args).jaxpr)) == sorted(names)
+
+
+def test_lm_step_counts_the_flash_subtiles_it_compiles(monkeypatch):
+    """Tracing an LM step through the kernels adds, per traced call and
+    kernel, the sub-tiles of one batch-head by kind; the counts are the
+    classifier's own (``tests/test_ops.py`` checks it against brute force)."""
+    from hops_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_XLA_FASTER_BELOW", 0)  # 256 keys would go to XLA
+    counter = REGISTRY.counter(COUNTER_TRAIN_FLASH_SUBTILES, labels=("kernel", "kind"))
+    kinds = A._Band(256, 256, 128, 128, 128, 128, 0, True, 200).subtile_kinds()
+    assert kinds == {"interior": 0, "edge": 3, "skipped": 1}
+
+    def read():
+        return {(kernel, kind): counter.value(kernel=kernel, kind=kind)
+                for kernel in ("fwd", "dq", "dkv") for kind in kinds}
+
+    lm = TransformerLM(vocab_size=64, d_model=32, num_heads=2, num_layers=2, window=200, dtype=jnp.float32)
+    state = common.create_train_state(lm, jax.random.PRNGKey(0), (1, 8), input_dtype=jnp.int32)
+    before = read()
+    jax.jit(make_lm_train_step(loss_chunk=64)).lower(state, {"tokens": jnp.zeros((2, 257), jnp.int32)})
+    added = {key: value - before[key] for key, value in read().items()}
+    for kernel in ("fwd", "dq", "dkv"):
+        calls = added[kernel, "edge"] / kinds["edge"]
+        assert calls >= 2 and calls == int(calls), added  # each of the two layers, whole calls
+        assert all(added[kernel, kind] == calls * n for kind, n in kinds.items()), added
+    assert added["dq", "edge"] == added["dkv", "edge"] == 2 * kinds["edge"]
+    assert any(line.startswith(COUNTER_TRAIN_FLASH_SUBTILES + "{") and 'kernel="dkv"' in line
+               for line in render_prometheus().splitlines())
 
 
 def test_explicit_gradient_exchange_is_scoped_inside_the_optimizer():
