@@ -10,6 +10,7 @@ on the MXU and shared train-step factories.
 from hops_tpu.models import (  # noqa: F401
     common,
     generation,
+    linear_attention,
     mnist,
     moe,
     resnet,

@@ -38,6 +38,16 @@ from hops_tpu.ops.attention import (
     repeat_kv,
 )
 from hops_tpu.parallel.mesh import per_shard
+from hops_tpu.telemetry.metrics import REGISTRY
+
+_m_layer_kinds = REGISTRY.counter(
+    "hops_tpu_train_layer_kinds_total",
+    "Layers of a TransformerLM traced, by the kind of their token mixer",
+    labels=("kind",),
+)
+
+#: the kinds of layer a ``TransformerLM`` builds (``layer_types``)
+LAYER_TYPES = ("full_attention", "linear_attention")
 
 
 def rotary_embedding(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -120,7 +130,9 @@ class Attention(nn.Module):
     # before RoPE; ``norm_eps`` is its epsilon.
     qk_norm: bool = False
     norm_eps: float = 1e-6
-    rope_base: float = 10000.0
+    # None: no rotary at all (a hybrid's full-attention layers, whose
+    # linear-attention neighbours carry position).
+    rope_base: float | None = 10000.0
 
     @nn.compact
     def __call__(self, x, decode: bool = False):
@@ -174,8 +186,7 @@ class Attention(nn.Module):
             # Inside a seq-sharded shard_map x is the LOCAL chunk:
             # absolute positions start at this shard's offset.
             pos = pos + jax.lax.axis_index(self.seq_axis) * s
-        q = rotary_embedding(q, pos, self.rope_base)
-        k = rotary_embedding(k, pos, self.rope_base)
+        q, k = self._rotate(q, pos), self._rotate(k, pos)
         # Single-chip training/full-forward is FLOPs-bound:
         # broadcasting GQA kv heads here costs memory only at the
         # (short-lived) activation. The sequence-parallel impls below
@@ -223,6 +234,9 @@ class Attention(nn.Module):
             raise ValueError(f"unknown attention_impl {self.attention_impl!r}")
 
         return self._project_out(o, b, s, dm)
+
+    def _rotate(self, t, pos):
+        return t if self.rope_base is None else rotary_embedding(t, pos, self.rope_base)
 
     def _whole_norm(self, t, name):
         """RMSNorm over all heads' channels of ``t`` (b, h, s, d) at once."""
@@ -305,8 +319,7 @@ class Attention(nn.Module):
             def put2(cache, update, starts):
                 return jax.lax.dynamic_update_slice(cache, update, (0, 0, starts))
 
-        q = rotary_embedding(q, pos, self.rope_base)
-        k = rotary_embedding(k, pos, self.rope_base)
+        q, k = self._rotate(q, pos), self._rotate(k, pos)
         if int8_cache:
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
@@ -405,8 +418,7 @@ class Attention(nn.Module):
         offset = idx.value
 
         pos = offset[:, None] + jnp.arange(s)[None, :]  # (b, s) absolute
-        q = rotary_embedding(q, pos, self.rope_base)
-        k = rotary_embedding(k, pos, self.rope_base)
+        q, k = self._rotate(q, pos), self._rotate(k, pos)
         # Clamp pad positions into the table's domain; rows whose pad
         # runs past their allocation hit entry 0 = the scratch block.
         posc = jnp.minimum(pos, self.max_decode_len - 1)
@@ -452,12 +464,16 @@ class MLP(nn.Module):
     dtype: Any = jnp.bfloat16
     tp_axis: str | None = None
     tp_shards: int = 1
+    # The published width where it is not d_model x hidden_mult x 2/3.
+    hidden: int | None = None
 
     @nn.compact
     def __call__(self, x):
         dm = x.shape[-1]
-        hidden = int(dm * self.hidden_mult * 2 / 3)
-        hidden = max(128, (hidden // 128) * 128)  # MXU-aligned
+        hidden = self.hidden
+        if hidden is None:
+            hidden = int(dm * self.hidden_mult * 2 / 3)
+            hidden = max(128, (hidden // 128) * 128)  # MXU-aligned
         if hidden % self.tp_shards:
             raise ValueError(
                 f"hidden {hidden} not divisible by tp_shards={self.tp_shards}"
@@ -493,11 +509,68 @@ class Block(nn.Module):
     kv_pool_blocks: int | None = None
     qk_norm: bool = False
     norm_eps: float = 1e-6
-    rope_base: float = 10000.0
+    rope_base: float | None = 10000.0
+    # What a hybrid's layers differ in (TransformerLM.layer_types): the
+    # token mixer (softmax attention, or a Gated-DeltaNet layer whose
+    # ``linear_*`` sizes follow the published keys), where the norms sit
+    # ("pre": on each sublayer's input; "post_sublayer": on its output,
+    # the Olmo 2 placement) and the feed-forward's width.
+    layer_type: str = "full_attention"
+    norm_placement: str = "pre"
+    mlp_hidden: int | None = None
+    linear_num_heads: int | None = None
+    linear_key_dim: int | None = None
+    linear_value_dim: int | None = None
+    linear_conv_size: int = 4
+    linear_allow_neg_eigval: bool = True
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False):
-        h = Attention(
+        if self.layer_type not in LAYER_TYPES:
+            raise ValueError(f"unknown layer_type {self.layer_type!r} (one of {LAYER_TYPES})")
+        if self.norm_placement not in ("pre", "post_sublayer"):
+            raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        pre = self.norm_placement == "pre"
+
+        def norm(t):
+            return RMSNorm(self.norm_eps, dtype=self.dtype)(t)
+
+        if self.layer_type == "linear_attention":
+            from hops_tpu.models.linear_attention import GatedDeltaNet
+
+            mixer = GatedDeltaNet(
+                self.linear_num_heads or self.num_heads,
+                key_dim=self.linear_key_dim,
+                value_dim=self.linear_value_dim,
+                conv_size=self.linear_conv_size,
+                allow_neg_eigval=self.linear_allow_neg_eigval,
+                norm_eps=self.norm_eps,
+                dtype=self.dtype,
+                name="attn",
+            )
+        else:
+            mixer = self._attention()
+        h = mixer(norm(x) if pre else x, decode=decode)
+        if not pre:
+            h = norm(h)
+        if self.dropout_rate:
+            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
+        x = x + h
+        h = MLP(
+            dtype=self.dtype,
+            tp_axis=self.tp_axis,
+            tp_shards=self.tp_shards,
+            hidden=self.mlp_hidden,
+            name="mlp",
+        )(norm(x) if pre else x)
+        if not pre:
+            h = norm(h)
+        if self.dropout_rate:
+            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
+        return x + h
+
+    def _attention(self):
+        return Attention(
             self.num_heads,
             dtype=self.dtype,
             attention_impl=self.attention_impl,
@@ -518,19 +591,7 @@ class Block(nn.Module):
             norm_eps=self.norm_eps,
             rope_base=self.rope_base,
             name="attn",
-        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x), decode=decode)
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        x = x + h
-        h = MLP(
-            dtype=self.dtype,
-            tp_axis=self.tp_axis,
-            tp_shards=self.tp_shards,
-            name="mlp",
-        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x))
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return x + h
+        )
 
 
 class TransformerLM(nn.Module):
@@ -559,7 +620,21 @@ class TransformerLM(nn.Module):
     # the whole q/k projections, every RMSNorm's epsilon, the rotary base.
     qk_norm: bool = False
     norm_eps: float = 1e-6
-    rope_base: float = 10000.0
+    rope_base: float | None = 10000.0  # None: no rotary
+    # A hybrid's layers, one kind a layer (``LAYER_TYPES``; None: every
+    # layer softmax attention), a linear-attention layer's four sizes
+    # (heads, key and value head widths, the causal convolution's taps;
+    # the published ``linear_*`` keys), where the norms sit ("pre" |
+    # "post_sublayer", see Block) and the dense feed-forward's width
+    # (None: d_model x 4 x 2/3).
+    layer_types: tuple[str, ...] | None = None
+    linear_num_heads: int | None = None
+    linear_key_dim: int | None = None
+    linear_value_dim: int | None = None
+    linear_conv_size: int = 4
+    linear_allow_neg_eigval: bool = True
+    norm_placement: str = "pre"
+    mlp_hidden: int | None = None
     max_decode_len: int = 2048
     kv_cache_dtype: str | None = None  # "int8": quantized decode cache
     num_kv_heads: int | None = None  # GQA: shrink the decode cache
@@ -600,6 +675,25 @@ class TransformerLM(nn.Module):
                 "paged_decode serves dense TransformerLMs; MoE blocks "
                 "keep the dense ragged cache"
             )
+        layer_types = self.layer_types or ("full_attention",) * self.num_layers
+        if len(layer_types) != self.num_layers:
+            raise ValueError(
+                f"layer_types names {len(layer_types)} layers, num_layers is {self.num_layers}"
+            )
+        hybrid = dict(
+            norm_placement=self.norm_placement,
+            mlp_hidden=self.mlp_hidden,
+            linear_num_heads=self.linear_num_heads,
+            linear_key_dim=self.linear_key_dim,
+            linear_value_dim=self.linear_value_dim,
+            linear_conv_size=self.linear_conv_size,
+            linear_allow_neg_eigval=self.linear_allow_neg_eigval,
+        )
+        if self.moe_every and (self.layer_types or self.norm_placement != "pre" or self.mlp_hidden):
+            raise NotImplementedError(
+                "layer_types, norm_placement and mlp_hidden shape dense blocks; "
+                "a routed block (moe_every) is pre-norm softmax attention"
+            )
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")(tokens)
         block_cls = nn.remat(Block, static_argnums=(2, 3)) if self.remat else Block
         moe_cls = nn.remat(MoEBlock, static_argnums=(2, 3)) if self.remat else MoEBlock
@@ -607,6 +701,7 @@ class TransformerLM(nn.Module):
             qk_norm=self.qk_norm, norm_eps=self.norm_eps, rope_base=self.rope_base
         )
         for i in range(self.num_layers):
+            _m_layer_kinds.inc(kind=layer_types[i])
             if self.moe_every and (i + 1) % self.moe_every == 0:
                 x = moe_cls(
                     self.num_heads,
@@ -648,6 +743,8 @@ class TransformerLM(nn.Module):
                 kv_page_size=self.kv_page_size,
                 kv_pool_blocks=self.kv_pool_blocks,
                 **layer_options,
+                layer_type=layer_types[i],
+                **hybrid,
                 name=f"block_{i}",
             )(x, train, decode)
         x = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")(x)

@@ -15,6 +15,10 @@ hand-written here with Pallas:
 - ``chunked_softmax_xent`` — LM-head loss computed per sequence chunk
   under ``jax.checkpoint``: the (batch, seq, vocab) fp32 logits are
   never materialized (peak chunk x vocab instead).
+- ``gated_delta_rule`` — the recurrence of a Gated-DeltaNet
+  linear-attention layer in chunks of 64 tokens, with a backward pass of
+  its own; the recurrence over chunk states is a Pallas kernel with the
+  state in VMEM (``gated_delta_fwd`` / ``gated_delta_bwd``).
 
 Every kernel ships with a pure-XLA reference twin used for (a) numeric
 tests, (b) non-TPU backends, (c) shapes the kernel doesn't support.
@@ -33,4 +37,5 @@ from hops_tpu.ops.attention import (  # noqa: F401
     quantize_kv,
     repeat_kv,
 )
+from hops_tpu.ops.gated_delta import gated_delta_rule  # noqa: F401
 from hops_tpu.ops.xent import chunked_softmax_xent  # noqa: F401

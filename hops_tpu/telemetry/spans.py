@@ -56,6 +56,14 @@ SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER, SCOPE_GRAD_EXCHANGE = TRAIN_SCOPES[4:]
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 SCOPE_MLP = TRAIN_SCOPES[1]
 
+#: Inside ``attn``, the parts of a linear-attention (Gated DeltaNet) mixer
+#: (``models/linear_attention.py``): the projections (q, k, v, the output
+#: gate and the two per-head gates), the three short causal convolutions
+#: with their activation, the scan (L2 norms, gates and the chunked gated
+#: delta rule, forward and backward) and the output (gated norm and
+#: ``W_o``). A softmax-attention block enters none of them.
+LINATTN_SCOPES = ("linattn_proj", "linattn_conv", "linattn_scan", "linattn_out")
+
 #: The training step's host vocabulary: :func:`span` names entered by
 #: ``Strategy.distribute_batch`` and by every ``Strategy.step`` callable,
 #: children of the launcher's ``experiment.run`` root span.
@@ -65,8 +73,12 @@ SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
 #: Trace-time counters of the training vocabulary: each says which form of
 #: an op a compiled step holds, and is added to while the step is traced.
 #: ``hops_tpu_train_per_shard_traces_total{op}`` (``parallel/mesh.py``),
-#: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``) and
-#: ``hops_tpu_train_moe_traces_total{impl}`` (``models/moe.py``) are named
+#: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``),
+#: ``hops_tpu_train_moe_traces_total{impl}`` (``models/moe.py``),
+#: ``hops_tpu_train_linattn_traces_total{impl}``
+#: (``models/linear_attention.py``) and
+#: ``hops_tpu_train_layer_kinds_total{kind}`` (``models/transformer.py``,
+#: one per layer traced) are named
 #: where they are counted; the flash kernels' sub-tiles
 #: (``ops/attention.py``: ``kernel`` = ``fwd`` | ``dq`` | ``dkv``, ``kind`` =
 #: ``interior`` | ``edge`` | ``skipped``, the counts of one batch-head per
