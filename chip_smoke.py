@@ -6,7 +6,8 @@ One process, one pass over the main path with a small dense LM
 weights random from a seed). It is go/no-go, not a yardstick: the
 benchmark's cells (``benchmark/run.py``) are what is measured.
 
-1. *kernels*: every ``pallas_call`` family in ``ops/attention.py`` is
+1. *kernels*: every ``pallas_call`` family in ``ops/attention.py`` and
+   the gated delta rule's five kernels (``ops/gated_delta.py``) are
    compiled by Mosaic (``interpret=False``) at the main path's shapes
    and compared with its reference twin run in fp32 at highest matmul
    precision;
@@ -173,6 +174,29 @@ def check_kernels() -> list[dict]:
                 A.paged_decode_attention_reference, qp,
                 A.dequantize_kv(kp8, kps), A.dequantize_kv(vp8, vps), vlp, pages),
         )
+    # -- gated delta rule: the five kernels, forward and all five gradients --
+    from hops_tpu.ops.gated_delta import gated_delta_rule
+
+    gh, gs, gk, gv = 10, 2048, 96, 192  # a head group of the hybrid cell's linear layers, 32 chunks
+    gq, gkey = (rand(1, gh, gs, gk, dtype=f32) for _ in range(2))
+    gq = gq / jnp.linalg.norm(gq, axis=-1, keepdims=True) / np.sqrt(gk)
+    gkey = gkey / jnp.linalg.norm(gkey, axis=-1, keepdims=True)
+    gval, gct = (rand(1, gh, gs, gv, dtype=f32) for _ in range(2))
+    log_alpha = -jnp.exp(jnp.asarray(rs.uniform(np.log(1e-3), np.log(1e-1), (1, gh, gs)), f32))
+    gbeta = jnp.asarray(rs.uniform(0, 2, (1, gh, gs)), f32)
+
+    def rule_and_grads(**route):
+        def loss(*args):
+            return jnp.sum(gated_delta_rule(*args, **route) * gct)
+        args = (gq, gkey, gval, log_alpha, gbeta)
+        return gated_delta_rule(*args, **route), jax.grad(loss, argnums=range(5))(*args)
+
+    family(
+        f"gated delta rule fwd+bwd seq {gs} float32",
+        lambda: rule_and_grads(interpret=INTERPRET),
+        # the XLA twin: batched matmuls at highest precision round a lax.scan, differentiated by jax.grad
+        lambda: rule_and_grads(custom_backward=False),
+    )
     return rows
 
 

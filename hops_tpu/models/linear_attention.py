@@ -39,7 +39,7 @@ L2_EPS = 1e-6
 
 _m_linattn_traces = REGISTRY.counter(
     "hops_tpu_train_linattn_traces_total",
-    "Linear-attention layers traced, by what runs the rule's recurrence over chunk states",
+    "Linear-attention layers traced, by what runs the gated delta rule",
     labels=("impl",),
 )
 

@@ -17,8 +17,9 @@ hand-written here with Pallas:
   never materialized (peak chunk x vocab instead).
 - ``gated_delta_rule`` — the recurrence of a Gated-DeltaNet
   linear-attention layer in chunks of 64 tokens, with a backward pass of
-  its own; the recurrence over chunk states is a Pallas kernel with the
-  state in VMEM (``gated_delta_fwd`` / ``gated_delta_bwd``).
+  its own; on a TPU five Pallas kernels (``gated_delta_local_fwd``,
+  ``gated_delta_fwd``, ``gated_delta_out_fwd``, ``gated_delta_bwd``,
+  ``gated_delta_local_bwd``) keep a chunk's matrices and the state in VMEM.
 
 Every kernel ships with a pure-XLA reference twin used for (a) numeric
 tests, (b) non-TPU backends, (c) shapes the kernel doesn't support.

@@ -16,19 +16,29 @@ overflows),
     U  = T (b V)      W = T (b e^c K)       V' = U - W S
     O  = (e^c Q) S + lower(Q K^T * G) V'    S' = e^{c_C} S + (e^{c_C - c} K)^T V'
 
-Everything but ``V'`` and ``S'`` is independent of the state, so it runs
-over all chunks at once as batched matmuls (:func:`_before`,
-:func:`_after`); what is sequential is one small recurrence over the
-``seq / C`` chunk states, ``X' = a X + D + M1^T (R - M2 X)``
-(:func:`_state_scan`). The backward pass has a recurrence of exactly that
-form for the states' cotangents, run over the chunks in reverse, so one
-function serves both. :func:`gated_delta_rule` is a ``jax.custom_vjp``:
-the forward keeps its inputs and the state entering each chunk (float32,
-``seq / C`` x d_k x d_v a head), the backward recomputes the chunk-local
-parts from them; ``jax.grad`` through a plain scan would keep every
-chunk's intermediates instead.
+Everything but ``V'`` and ``S'`` is independent of the state; what is
+sequential is one small recurrence over the ``seq / C`` chunk states,
+``X' = a X + D + M1^T (R - M2 X)``. The backward pass has a recurrence of
+exactly that form for the states' cotangents, run over the chunks in
+reverse. :func:`gated_delta_rule` is a ``jax.custom_vjp``: the forward
+keeps its inputs and the state entering each chunk (float32, ``seq / C`` x
+d_k x d_v a head), the backward recomputes the chunk-local parts from
+them; ``jax.grad`` through a plain scan would keep every chunk's
+intermediates instead.
 
-The state, every sum and the triangular solve are float32 whatever the
+Two routes, chosen by :func:`implementation`. On a TPU five Pallas kernels
+over the grid (head blocks, chunks) keep a chunk's C x C matrices and
+float32 copies in VMEM: ``gated_delta_local_fwd`` (``T``, ``W``, ``U``),
+``gated_delta_fwd`` (the recurrence), ``gated_delta_out_fwd`` (``O``),
+and for the backward ``gated_delta_bwd`` (the reverse recurrence) and
+``gated_delta_local_bwd`` (every input's cotangent, hand-written).
+Elsewhere, and for a state that is not float32, the chunk-local parts run
+over all chunks at once as batched matmuls (:func:`_before`,
+:func:`_after`) round a ``lax.scan`` (:func:`_state_scan`, which serves
+both directions) and ``jax.vjp`` differentiates them: the kernels' twin,
+and what they are tested against.
+
+The state, every sum and the inverse are float32 whatever the
 inputs' type; products with float32 operands run at highest precision (on
 a TPU the default rounds them to bfloat16, which is the state in 8 bits
 of mantissa).
@@ -37,20 +47,30 @@ of mantissa).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_LINATTN_KERNEL_CALLS
+
 F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 DEFAULT_CHUNK = 64
 #: what one group of heads may hold in float32 temporaries in its backward
-GROUP_BYTES = 1.5e9
-#: heads of one grid step of the Pallas recurrence (PERF.md §6, PR 29: 10
-#: and 5 read alike on a v5e, 30 do not fit VMEM)
+#: (PERF.md §6, PR 30: the cell's 30 heads x 8,192 tokens are 2.1 GB in one
+#: group and its step 3.7 GB of temporaries, against 5.2 in two groups and
+#: 4.9 in three, whose results `lax.map` stacks; PR 29's chip held 5.145)
+GROUP_BYTES = 2.5e9
+#: heads of one grid step of the kernels, worked on together (PERF.md §6,
+#: PR 30: 10 / 5 read 2.36 / 5.91 ms a call in the two chunk-local kernels,
+#: 5 / 5 2.58 / 5.91, 10 / 3 2.36 / 6.60; the chunk-local backward holds
+#: eleven operands and four results a head, and 10 of them do not fit VMEM)
 KERNEL_HEADS = 10
+LOCAL_BWD_HEADS = 5
 
 
 def _mm(spec: str, a, b):
@@ -107,65 +127,251 @@ def _state_scan(m2, r, m1_t, a, add=None, *, reverse=False, state_dtype=F32):
     return states.astype(F32), y
 
 
-def _scan_kernel(*refs, heads, has_add):
-    m2, r, m1_t, a = refs[:4]
-    states, y_out, x_scr = refs[-3:]
+# -- the TPU route: every part of the rule as a Pallas kernel -----------------
+#
+# Five kernels over one grid, (head blocks, chunks): a chunk's C x C
+# matrices (the decay, K K^T, Q K^T, A, T) and the float32 copies of q, k, v
+# live in VMEM from the loads in the model's type to the float32 stores of
+# what another kernel or the backward needs. The two gates travel as one
+# (..., 2, C) float32 array of rows, [log_alpha, beta]; a kernel turns a row
+# into a column with the identity mask and a reduction, so that c_i - c_j
+# is exactly 0 on the diagonal.
+#
+# A grid step works on all its heads at once, as (heads, rows, cols) values
+# and batched products: each product of a head waits for the one before it
+# (the inverse is seven in a row), and what hides that wait is the same
+# product of the other heads, issued beside it (PERF.md §6, PR 30: the local
+# forward 7.95 ms a call one head at a time, 2.56 five at a time).
 
+_NN, _NT, _TN = ((((2,), (1,)), ((0,), (0,))), (((2,), (2,)), ((0,), (0,))), (((1,), (1,)), ((0,), (0,))))
+#: rows of the diagonal blocks that :func:`_unit_lower_inverse` inverts by
+#: their nilpotent series
+_INVERSE_BLOCK = 16
+
+
+def _dot(a, b, dims=_NN):
+    """A product of (heads, rows, cols) operands accumulated in float32,
+    exact to float32 rounding whatever the operands' types. Both bfloat16:
+    one pass (their products are exact). One bfloat16, one float32: the
+    float32 operand is the exact sum of three bfloat16 terms, so three
+    passes give what the six of the highest precision on the upcast copies
+    give. Otherwise the highest precision."""
+    def one_pass(x, y):
+        return jax.lax.dot_general(x, y, dims, preferred_element_type=F32)
+
+    narrow = [t.dtype == jnp.bfloat16 for t in (a, b)]
+    if all(narrow):
+        return one_pass(a, b)
+    if any(narrow) and F32 in (a.dtype, b.dtype):
+        wide = b if narrow[0] else a
+        high = wide.astype(jnp.bfloat16)
+        rest = wide - high.astype(F32)
+        middle = rest.astype(jnp.bfloat16)
+        low = (rest - middle.astype(F32)).astype(jnp.bfloat16)
+        parts = [one_pass(a, t) if narrow[0] else one_pass(t, b) for t in (high, middle, low)]
+        return parts[0] + (parts[1] + parts[2])
+    return jax.lax.dot_general(a.astype(F32), b.astype(F32), dims, precision=_HIGHEST, preferred_element_type=F32)
+
+
+def _iotas(rows, cols):
+    """Row and column indices of a (rows, cols) matrix, with a leading
+    axis of 1 for the heads."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (1, rows, cols), 1),
+            jax.lax.broadcasted_iota(jnp.int32, (1, rows, cols), 2))
+
+
+def _column(row_vec):
+    """(heads, 1, C) -> (heads, C, 1), the same values."""
+    size = row_vec.shape[-1]
+    row, col = _iotas(size, size)
+    return jnp.sum(jnp.where(row == col, row_vec, 0.0), axis=2, keepdims=True)
+
+
+class _Gates(NamedTuple):
+    """A chunk's two gates in the forms the kernels use, (heads, ...)."""
+    c: jax.Array  # the running sum of log_alpha, a column (C, 1)
+    c_row: jax.Array  # the same values as a row (1, C)
+    last: jax.Array  # c's last entry (1, 1)
+    beta: jax.Array  # a column
+    beta_row: jax.Array
+    diff: jax.Array  # c_i - c_j (C, C), exactly 0 on the diagonal
+
+
+def _gate_forms(gates) -> _Gates:
+    """From the (heads, 2, C) rows [log_alpha, beta]."""
+    size = gates.shape[-1]
+    row, col = _iotas(size, size)
+    c = jnp.sum(jnp.where(col <= row, gates[:, 0:1, :], 0.0), axis=2, keepdims=True)
+    c_row = jnp.sum(jnp.where(row == col, c, 0.0), axis=1, keepdims=True)
+    beta_row = gates[:, 1:2, :]
+    return _Gates(c, c_row, _last_row(c), _column(beta_row), beta_row, c - c_row)
+
+
+def _last_row(column, value=None):
+    """The last entry of a (heads, C, 1) column as (heads, 1, 1); with
+    ``value`` (heads, 1, 1), a column that holds it there and 0 above."""
+    size = column.shape[1]
+    at_last = _iotas(size, 1)[0] == size - 1
+    if value is not None:
+        return jnp.where(at_last, value, 0.0)
+    return jnp.sum(jnp.where(at_last, column, 0.0), axis=1, keepdims=True)
+
+
+def _decay(diff, mask):
+    """``exp(diff)`` where ``mask``, 0 elsewhere (the exponent is masked:
+    on the other side of the diagonal it overflows)."""
+    return jnp.exp(jnp.where(mask, diff, -jnp.inf))
+
+
+def _apply_inverse(x, steps, target):
+    """``(I + x)^-1 target`` for ``x^steps = 0``, exactly: ``(I - x)(I +
+    x^2)(I + x^4)...`` applied to ``target`` from the left. A factor's
+    product and the next power share their left operand, so they are one
+    matmul on ``[power | target]``: 128 columns, the MXU's width."""
+    size = x.shape[-1]
+    factors = max(steps - 1, 0).bit_length()
+    power = x
+    for i in range(factors):
+        if i < factors - 1:
+            both = _dot(power, jnp.concatenate([power, target], axis=2))
+            next_power, moved = both[:, :, :size], both[:, :, size:]
+        else:
+            next_power, moved = None, _dot(power, target)
+        target = target - moved if i == 0 else target + moved
+        power = next_power
+    return target
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for strictly lower-triangular ``a`` (heads, C, C), by
+    products alone (Mosaic has no triangular solve). ``a`` is nilpotent, so
+    the series of :func:`_apply_inverse` is exact; taken over the whole
+    chunk the powers of ``a`` grow with beta <= 2 and cancel, so it runs
+    inside 16-row diagonal blocks ``d`` first, and then once more over the
+    blocks: with ``B = (I + d)^-1`` and ``N = B (a - d)``, ``(I + a)^-1 =
+    (I + N)^-1 B`` and ``N`` is nilpotent in C / 16 steps. Seven matmuls
+    at C = 64."""
+    size = a.shape[-1]
+    row, col = _iotas(size, size)
+    eye = jnp.broadcast_to((row == col).astype(F32), a.shape)
+    block = min(_INVERSE_BLOCK, size)
+    if size == block:
+        return _apply_inverse(a, size, eye)
+    shift = block.bit_length() - 1
+    diag = jnp.where(jnp.right_shift(row, shift) == jnp.right_shift(col, shift), a, 0.0)
+    within = _apply_inverse(diag, block, eye)
+    return _apply_inverse(_dot(within, a - diag), -(-size // block), within)
+
+
+def _local_fwd_kernel(k_ref, v_ref, gates_ref, w_ref, u_ref):
+    k = k_ref[...]
+    row, col = _iotas(k.shape[1], k.shape[1])
+    g = _gate_forms(gates_ref[...])
+    t = _unit_lower_inverse(g.beta * _dot(k, k, _NT) * _decay(g.diff, row > col))
+    # T (beta V) and T (beta e^c K) with the scalings on T's columns: k and v stay in their own type
+    u_ref[...] = _dot(t * g.beta_row, v_ref[...])
+    w_ref[...] = _dot(t * (g.beta_row * jnp.exp(g.c_row)), k)
+
+
+def _zero_before_the_first_chunk(x_scr):
     @pl.when(pl.program_id(1) == 0)
     def _():
         x_scr[...] = jnp.zeros_like(x_scr)
 
-    def dot(lhs, rhs):
-        return jax.lax.dot_general(lhs, rhs, (((1,), (0,)), ((), ())), precision=_HIGHEST,
-                                   preferred_element_type=F32)
 
-    for g in range(heads):
-        x = x_scr[g]
-        states[g] = x
-        y = r[g] - dot(m2[g], x)
-        y_out[g] = y
-        new = a[g] * x + dot(m1_t[g], y)
-        if has_add:
-            new = new + refs[4][g]
-        x_scr[g] = new
+def _state_fwd_kernel(w_ref, u_ref, k_ref, gates_ref, states_ref, y_ref, x_scr):
+    _zero_before_the_first_chunk(x_scr)
+    x = x_scr[...]
+    states_ref[...] = x
+    y = u_ref[...] - _dot(w_ref[...], x)
+    y_ref[...] = y
+    g = _gate_forms(gates_ref[...])
+    x_scr[...] = jnp.exp(g.last) * x + _dot(k_ref[...], jnp.exp(g.last - g.c) * y, _TN)
 
 
-def _state_scan_pallas(m2, r, m1_t, a, add=None, *, reverse=False, heads=KERNEL_HEADS, interpret=False):
-    """:func:`_state_scan` as one Pallas call (named ``gated_delta_fwd``,
-    or ``gated_delta_bwd`` when ``reverse``): grid (head blocks, chunks),
-    the chunks sequential with the state in VMEM."""
-    n, bh, c, dk = m2.shape
-    dv = r.shape[-1]
-    heads = next(h for h in range(min(heads, bh), 0, -1) if bh % h == 0)
-    a_row = jnp.broadcast_to(a[..., None, None], (n, bh, 1, dv))
+def _out_fwd_kernel(q_ref, k_ref, gates_ref, states_ref, y_ref, o_ref):
+    q = q_ref[...]
+    row, col = _iotas(q.shape[1], q.shape[1])
+    g = _gate_forms(gates_ref[...])
+    p = _dot(q, k_ref[...], _NT) * _decay(g.diff, row >= col)
+    o_ref[...] = (jnp.exp(g.c) * _dot(q, states_ref[...]) + _dot(p, y_ref[...])).astype(o_ref.dtype)
 
-    def at(i, j):
-        return (n - 1 - j if reverse else j, i, 0, 0)
 
-    def spec(rows, cols):
-        return pl.BlockSpec((None, heads, rows, cols), at)
+def _state_bwd_kernel(q_ref, k_ref, gates_ref, d_o_ref, w_ref, g_next_ref, d_u_ref, x_scr):
+    """The states' cotangents, last chunk first: ``G_n = (e^c Q)^T dO + a_n
+    G_{n+1} - W_n^T dU_n`` with ``dU_n = P_n^T dO_n + Kd_n G_{n+1}``."""
+    _zero_before_the_first_chunk(x_scr)
+    x = x_scr[...]
+    g_next_ref[...] = x
+    q, k, d_o = q_ref[...], k_ref[...], d_o_ref[...]
+    row, col = _iotas(q.shape[1], q.shape[1])
+    g = _gate_forms(gates_ref[...])
+    p_t = _dot(k, q, _NT) * _decay(-g.diff, col >= row)
+    d_u = _dot(p_t, d_o) + jnp.exp(g.last - g.c) * _dot(k, x)
+    d_u_ref[...] = d_u
+    x_scr[...] = (jnp.exp(g.last) * x + _dot(q, jnp.exp(g.c) * d_o.astype(F32), _TN)
+                  - _dot(w_ref[...], d_u, _TN))
 
-    operands = [m2, r, m1_t, a_row] + ([] if add is None else [add])
-    in_specs = [spec(c, dk), spec(c, dv), spec(dk, c), spec(1, dv)] + ([] if add is None else [spec(dk, dv)])
-    return pl.pallas_call(
-        functools.partial(_scan_kernel, heads=heads, has_add=add is not None),
-        out_shape=(jax.ShapeDtypeStruct((n, bh, dk, dv), F32), jax.ShapeDtypeStruct((n, bh, c, dv), F32)),
-        grid=(bh // heads, n),
-        in_specs=in_specs,
-        out_specs=(spec(dk, dv), spec(c, dv)),
-        scratch_shapes=[pltpu.VMEM((heads, dk, dv), F32)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="gated_delta_bwd" if reverse else "gated_delta_fwd",
-    )(*operands)
+
+def _local_bwd_kernel(q_ref, k_ref, v_ref, gates_ref, d_o_ref, states_ref, g_next_ref, d_u_ref, w_ref, u_ref,
+                      v_new_ref, d_q_ref, d_k_ref, d_v_ref, d_gates_ref):
+    """Every cotangent of a chunk's inputs from what the forward kept and
+    the reverse recurrence left in HBM. ``T`` is recomputed; its own
+    derivative is never needed: ``dA = -strict_lower(T^T dX X^T)`` with ``X
+    = [U, W]``."""
+    q, k, v, d_o = q_ref[...], k_ref[...], v_ref[...], d_o_ref[...]
+    s, g_next, d_u, w, u, v_new = (ref[...] for ref in (states_ref, g_next_ref, d_u_ref, w_ref, u_ref, v_new_ref))
+    size = q.shape[1]
+    row, col = _iotas(size, size)
+    g = _gate_forms(gates_ref[...])
+    beta, gamma, delta, a = g.beta, jnp.exp(g.c), jnp.exp(g.last - g.c), jnp.exp(g.last)
+    decay = _decay(g.diff, row >= col)
+    strict = jnp.where(row > col, decay, 0.0)
+    kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)
+    t = _unit_lower_inverse(beta * kk * strict)
+
+    # O = (e^c Q) S + P V',  S' = a S + (e^{c_C - c} K)^T V'
+    d_qd = _dot(d_o, s, _NT)
+    d_p = _dot(d_o, v_new, _NT) * decay  # dP * G, lower
+    d_kd = _dot(v_new, g_next, _NT)
+    d_q = gamma * d_qd + _dot(d_p, k)
+    d_k = _dot(d_p, q, _TN) + delta * d_kd
+    # W = T (beta e^c K),  U = T (beta V):  d rhs = T^T dX
+    d_ru = _dot(t, d_u, _TN)
+    d_rw = -_dot(t, _dot(d_u, s, _NT), _TN)
+    d_a_mat = -(_dot(d_ru, u, _NT) + _dot(d_rw, w, _NT)) * strict  # dA * G, strictly lower
+    d_kk = beta * d_a_mat
+    d_k = d_k + (beta * gamma) * d_rw + _dot(d_kk, k) + _dot(d_kk, k, _TN)
+    kf = k.astype(F32)
+    rw_k = jnp.sum(d_rw * kf, axis=2, keepdims=True)
+    d_beta = (jnp.sum(d_ru * v.astype(F32), axis=2, keepdims=True) + gamma * rw_k
+              + jnp.sum(d_a_mat * kk, axis=2, keepdims=True))
+    # c enters through G (rows less columns), e^c, e^{c_C - c} and a = e^{c_C}
+    d_decay = d_p * qk + d_kk * kk
+    d_delta = delta * jnp.sum(d_kd * kf, axis=2, keepdims=True)
+    d_c = (jnp.sum(d_decay, axis=2, keepdims=True) - _column(jnp.sum(d_decay, axis=1, keepdims=True))
+           + gamma * (jnp.sum(d_qd * q.astype(F32), axis=2, keepdims=True) + beta * rw_k) - d_delta)
+    d_last = jnp.sum(d_delta, axis=1, keepdims=True) + a * jnp.sum(
+        jnp.sum(s * g_next, axis=2, keepdims=True), axis=1, keepdims=True)
+    d_c = d_c + _last_row(d_c, d_last)
+    d_q_ref[...] = d_q.astype(d_q_ref.dtype)
+    d_k_ref[...] = d_k.astype(d_k_ref.dtype)
+    d_v_ref[...] = (beta * d_ru).astype(d_v_ref.dtype)
+    # log_alpha_j reaches every c_i with i >= j; both gates back to rows
+    d_log_alpha = jnp.sum(jnp.where(row >= col, d_c, 0.0), axis=1, keepdims=True)
+    d_beta = jnp.sum(jnp.where(row == col, d_beta, 0.0), axis=1, keepdims=True)
+    d_gates_ref[...] = jnp.where(_iotas(2, size)[0] == 0, d_log_alpha, d_beta)
 
 
 def default_head_groups(batch_heads: int, seq: int, d_k: int, d_v: int, chunk: int) -> int:
     """The fewest groups (a divisor of ``batch_heads``) whose backward pass
-    keeps under ``GROUP_BYTES``: a head's float32 temporaries there are
-    about seven arrays of chunk states and ten of values (the compiler's
-    count at 30 x 8,192 x (96, 192): 3.0 GB in one group)."""
-    per_head = 4.0 * seq * (7.0 * d_k * d_v / chunk + 10.0 * d_v)
+    keeps under ``GROUP_BYTES``: with every part in kernels a head's
+    temporaries there are about two float32 arrays of chunk states (the
+    kept states and their cotangents) and eight of values (W, U, V' and
+    dU, each padded to whole 128-lane tiles, and the inputs, ``dO`` and
+    the results in the model's type): the compiler's count for a described
+    v5e at 30 x 8,192 x (96, 192) is 2.14 GB in one group."""
+    per_head = 4.0 * seq * (2.0 * d_k * d_v / chunk + 8.0 * d_v)
     fitting = (g for g in range(1, batch_heads + 1)
                if batch_heads % g == 0 and per_head * batch_heads / g <= GROUP_BYTES)
     return next(fitting, batch_heads)
@@ -174,34 +380,152 @@ def default_head_groups(batch_heads: int, seq: int, d_k: int, d_v: int, chunk: i
 def _chunked(t, chunk):
     """(b, h, s, ...) -> (s / chunk, b * h, chunk, ...): chunk-major, so
     that the scan over chunk states slices its operands' leading axis."""
+    return jnp.swapaxes(_head_major(t, chunk), 0, 1)
+
+
+def _head_major(t, chunk):
+    """(b, h, s, ...) -> (b * h, s / chunk, chunk, ...): no data moves; the
+    kernels' index maps pick a (head block, chunk) from it."""
     b, h, s = t.shape[:3]
-    return jnp.swapaxes(t.reshape(b * h, s // chunk, chunk, *t.shape[3:]), 0, 1)
+    return t.reshape(b * h, s // chunk, chunk, *t.shape[3:])
 
 
 def implementation(interpret: bool | None = None, state_dtype=F32) -> str:
-    """``"pallas"`` or ``"xla_scan"``: what runs the recurrence over the
-    chunk states here (the label of ``hops_tpu_train_linattn_traces_total``).
-    The kernel on a TPU (PERF.md §6, PR 29: 5.2 ms a pass against the
-    scan's 6.2 at 30 heads x 8,192 tokens), ``lax.scan`` elsewhere and for
-    a state that is not float32; ``interpret=True`` forces the kernel
-    through the Pallas interpreter (tests)."""
+    """``"pallas"`` or ``"xla_scan"``: what runs the rule here (the label
+    of ``hops_tpu_train_linattn_traces_total``). The five kernels on a TPU
+    (PERF.md §6, PR 30), batched matmuls round a ``lax.scan`` elsewhere
+    and for a state that is not float32; ``interpret=True`` forces the
+    kernels through the Pallas interpreter (tests)."""
     if state_dtype != F32 or (interpret is None and jax.default_backend() != "tpu"):
         return "xla_scan"
     return "pallas"
 
 
-def _scan(*operands, reverse, route):
-    """``route`` is ``(implementation, state type, interpret)``."""
-    impl, state_dtype, interpret = route
-    if impl == "pallas":
-        return _state_scan_pallas(*operands, reverse=reverse, interpret=interpret)
-    return _state_scan(*operands, reverse=reverse, state_dtype=state_dtype)
+_m_kernel_calls = REGISTRY.counter(
+    COUNTER_TRAIN_LINATTN_KERNEL_CALLS,
+    "Mosaic calls of the gated delta rule traced, by kernel",
+    labels=("kernel",),
+)
+
+
+def _call(kernel_name, body, operands, outputs, *, heads, interpret, reverse=False, state=None):
+    """One ``pallas_call`` named ``kernel_name`` over the grid (head blocks,
+    chunks) of (b * h, n, rows, cols) ``operands``; ``outputs`` are (rows,
+    cols, type). With ``state`` = (d_k, d_v) the chunks run in order (from
+    the last when ``reverse``) over a float32 scratch of that shape a
+    head; without it both axes are parallel."""
+    bh, n = operands[0].shape[:2]
+    heads = next(h for h in range(min(heads, bh), 0, -1) if bh % h == 0)
+
+    def at(i, j):
+        return (i, n - 1 - j if reverse else j, 0, 0)
+
+    def spec(rows, cols):
+        return pl.BlockSpec((heads, None, rows, cols), at)
+
+    return pl.pallas_call(
+        body,
+        out_shape=tuple(jax.ShapeDtypeStruct((bh, n, rows, cols), dtype) for rows, cols, dtype in outputs),
+        grid=(bh // heads, n),
+        in_specs=[spec(*t.shape[2:]) for t in operands],
+        out_specs=tuple(spec(rows, cols) for rows, cols, _ in outputs),
+        scratch_shapes=[] if state is None else [pltpu.VMEM((heads, *state), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel" if state is None else "arbitrary")),
+        interpret=interpret,
+        name=kernel_name,
+    )(*operands)
+
+
+def _builder(kernel_name, static=("interpret",)):
+    """A call builder of the kernel ``kernel_name``: jitted, so that a
+    model's layers and a step's passes (forward, remat's forward, backward)
+    share one trace of the kernel body, and inlined, so that the enclosing
+    program still holds one ``pallas_call`` per use under that layer's own
+    scope (as ``ops/attention.py:_per_geometry``); every use counts once in
+    ``hops_tpu_train_linattn_kernel_calls_total``."""
+    def wrap(build):
+        jitted = jax.jit(functools.partial(build, kernel_name), static_argnames=static, inline=True)
+
+        @functools.wraps(build)
+        def counted(*operands, **options):
+            _m_kernel_calls.inc(kernel=kernel_name)
+            return jitted(*operands, **options)
+
+        return counted
+
+    return wrap
+
+
+@_builder("gated_delta_local_fwd")
+def _local_fwd_call(name, k, v, gates, interpret):
+    (c, dk), dv = k.shape[2:], v.shape[-1]
+    return _call(name, _local_fwd_kernel, (k, v, gates), ((c, dk, F32), (c, dv, F32)),
+                 heads=KERNEL_HEADS, interpret=interpret)
+
+
+@_builder("gated_delta_fwd")
+def _state_fwd_call(name, w, u, k, gates, interpret):
+    (c, dk), dv = w.shape[2:], u.shape[-1]
+    return _call(name, _state_fwd_kernel, (w, u, k, gates), ((dk, dv, F32), (c, dv, F32)),
+                 heads=KERNEL_HEADS, interpret=interpret, state=(dk, dv))
+
+
+@_builder("gated_delta_out_fwd", static=("out_dtype", "interpret"))
+def _out_fwd_call(name, q, k, gates, states, v_new, out_dtype, interpret):
+    return _call(name, _out_fwd_kernel, (q, k, gates, states, v_new), ((*v_new.shape[2:], out_dtype),),
+                 heads=KERNEL_HEADS, interpret=interpret)[0]
+
+
+@_builder("gated_delta_bwd")
+def _state_bwd_call(name, q, k, gates, d_o, w, interpret):
+    (c, dk), dv = w.shape[2:], d_o.shape[-1]
+    return _call(name, _state_bwd_kernel, (q, k, gates, d_o, w), ((dk, dv, F32), (c, dv, F32)),
+                 heads=KERNEL_HEADS, interpret=interpret, reverse=True, state=(dk, dv))
+
+
+@_builder("gated_delta_local_bwd")
+def _local_bwd_call(name, q, k, v, gates, d_o, states, g_next, d_u, w, u, v_new, interpret):
+    (c, dk), dv = q.shape[2:], v.shape[-1]
+    return _call(name, _local_bwd_kernel, (q, k, v, gates, d_o, states, g_next, d_u, w, u, v_new),
+                 ((c, dk, q.dtype), (c, dk, k.dtype), (c, dv, v.dtype), (2, c, F32)),
+                 heads=LOCAL_BWD_HEADS, interpret=interpret)
+
+
+def _gates(log_alpha, beta):
+    return jnp.stack([log_alpha.astype(F32), beta.astype(F32)], axis=-2)
+
+
+def _forward_pallas(q, k, v, log_alpha, beta, interpret):
+    """``o`` and what the backward reads again: the state entering each
+    chunk, ``W``, ``U`` and ``V'`` (float32; under a block's ``remat`` they
+    live from its recomputed forward to its backward)."""
+    gates = _gates(log_alpha, beta)
+    w, u = _local_fwd_call(k, v, gates, interpret=interpret)
+    states, v_new = _state_fwd_call(w, u, k, gates, interpret=interpret)
+    o = _out_fwd_call(q, k, gates, states, v_new, out_dtype=v.dtype, interpret=interpret)
+    return o, (states, w, u, v_new)
+
+
+def _backward_pallas(q, k, v, log_alpha, beta, kept, d_o, interpret):
+    gates = _gates(log_alpha, beta)
+    states, w, u, v_new = kept
+    g_next, d_u = _state_bwd_call(q, k, gates, d_o, w, interpret=interpret)
+    d_q, d_k, d_v, d_gates = _local_bwd_call(q, k, v, gates, d_o, states, g_next, d_u, w, u, v_new,
+                                             interpret=interpret)
+    return (d_q, d_k, d_v, d_gates[..., 0, :].astype(log_alpha.dtype), d_gates[..., 1, :].astype(beta.dtype))
 
 
 def _forward(q, k, v, log_alpha, beta, route):
-    """``(o, states)`` on whole chunks: (n, b * h, C, d) arrays."""
+    """``(o, kept)`` on whole chunks: (b * h, n, C, d) arrays for the
+    kernels, (n, b * h, C, d) for the scan; ``kept`` is what the route's
+    backward wants beside the inputs. ``route`` is
+    ``(implementation, state type, interpret)``."""
+    impl, state_dtype, interpret = route
+    if impl == "pallas":
+        return _forward_pallas(q, k, v, log_alpha, beta, interpret)
     w, u, kd_t, a, qd, p = _before(q, k, v, log_alpha, beta)
-    states, v_new = _scan(w, u, kd_t, a, reverse=False, route=route)
+    states, v_new = _state_scan(w, u, kd_t, a, state_dtype=state_dtype)
     return _after(qd, p, states, v_new), states
 
 
@@ -211,21 +535,25 @@ def _rule(q, k, v, log_alpha, beta, route):
 
 
 def _rule_fwd(q, k, v, log_alpha, beta, route):
-    o, states = _forward(q, k, v, log_alpha, beta, route)
-    return o.astype(v.dtype), (q, k, v, log_alpha, beta, states)
+    o, kept = _forward(q, k, v, log_alpha, beta, route)
+    return o.astype(v.dtype), (q, k, v, log_alpha, beta, kept)
 
 
 def _rule_bwd(route, saved, d_o):
-    *inputs, states = saved
+    *inputs, kept = saved
+    impl, state_dtype, interpret = route
+    if impl == "pallas":
+        return _backward_pallas(*inputs, kept, d_o, interpret)
+    states = kept
     (w, u, kd_t, a, qd, p), pull_before = jax.vjp(_before, *inputs)
     v_new = u - _mm("...cd,...dv->...cv", w, states)
     _, pull_after = jax.vjp(_after, qd, p, states, v_new)
     d_qd, d_p, d_states, d_v_new = pull_after(d_o.astype(F32))
     # the states' cotangents obey the forward's recurrence, last chunk first:
     # G_n = d_states_n + a_n G_{n+1} - W_n^T (d_v_new_n + Kd_n G_{n+1})
-    g_next, neg_d_u = _scan(
+    g_next, neg_d_u = _state_scan(
         jnp.swapaxes(kd_t, -1, -2), -d_v_new, jnp.swapaxes(w, -1, -2), a, d_states,
-        reverse=True, route=route)
+        reverse=True, state_dtype=state_dtype)
     d_u = -neg_d_u
     d_w = -_mm("...cv,...dv->...cd", d_u, states)
     d_kd_t = _mm("...dv,...cv->...dc", g_next, v_new)
@@ -266,12 +594,20 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, log_alpha: jax.Ar
             return _rule(*args, route)
         return _forward(*args, route)[0].astype(v.dtype)
 
-    args = tuple(_chunked(t, chunk) for t in (q, k, v, log_alpha, beta))
-    if head_groups > 1:  # (n, bh, ...) -> (groups, n, bh / groups, ...)
-        n, bh = args[0].shape[:2]
-        split = tuple(jnp.moveaxis(t.reshape(n, head_groups, bh // head_groups, *t.shape[2:]), 1, 0)
-                      for t in args)
-        o = jnp.moveaxis(jax.lax.map(rule, split), 0, 1).reshape(n, bh, chunk, -1)
+    # (heads, chunks, ...) for the kernels, whose index maps take any order; chunk-major for the scan
+    heads_axis = 0 if route[0] == "pallas" else 1
+    args = tuple((_head_major if heads_axis == 0 else _chunked)(t, chunk) for t in (q, k, v, log_alpha, beta))
+    if head_groups > 1:  # the heads' axis -> (groups, b * h / groups), the groups in front
+        bh = args[0].shape[heads_axis]
+
+        def split(t):
+            shape = t.shape[:heads_axis] + (head_groups, bh // head_groups) + t.shape[heads_axis + 1:]
+            return jnp.moveaxis(t.reshape(shape), heads_axis, 0)
+
+        o = jnp.moveaxis(jax.lax.map(rule, tuple(split(t) for t in args)), 0, heads_axis)
+        o = o.reshape(*o.shape[:heads_axis], bh, *o.shape[heads_axis + 2:])
     else:
         o = rule(args)
-    return jnp.swapaxes(o, 0, 1).reshape(*v.shape)[:, :, :s]
+    if heads_axis:
+        o = jnp.swapaxes(o, 0, 1)
+    return o.reshape(*v.shape)[:, :, :s]

@@ -84,6 +84,12 @@ SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
 #: ``interior`` | ``edge`` | ``skipped``, the counts of one batch-head per
 #: traced call) are named here.
 COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"
+#: One per Mosaic call of the gated delta rule traced (``ops/gated_delta.py``:
+#: ``kernel`` = ``gated_delta_local_fwd`` | ``gated_delta_fwd`` |
+#: ``gated_delta_out_fwd`` | ``gated_delta_local_bwd`` | ``gated_delta_bwd``,
+#: the ``pallas_call`` names): which parts of the rule a compiled step holds
+#: in kernels. A step on the XLA route counts none.
+COUNTER_TRAIN_LINATTN_KERNEL_CALLS = "hops_tpu_train_linattn_kernel_calls_total"
 
 
 def _sanitize(name: str) -> str:

@@ -147,3 +147,42 @@ def test_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, heads, d_he
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 3 and all(any(name in call for call in calls) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+
+
+def test_gated_delta_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+    """The rule's five kernels at the hybrid cell's shape (30 heads x 8,192
+    tokens x (96, 192), bf16, the default head groups), forward and
+    backward: Mosaic accepts the blocks, the transposed-operand products
+    and the VMEM they ask for under the default scoped limit (no
+    ``vmem_limit_bytes`` is set), and the compiler's temporaries stay
+    under what ``default_head_groups`` promises for a group. No float32
+    chunk x chunk array is a result of the program. Nothing runs."""
+    from hops_tpu.ops import gated_delta
+
+    heads, seq, d_k, d_v = 30, 8192, 96, 192
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        qk = jax.ShapeDtypeStruct((1, heads, seq, d_k), jnp.bfloat16, sharding=one_chip)
+        v = jax.ShapeDtypeStruct((1, heads, seq, d_v), jnp.bfloat16, sharding=one_chip)
+        gate = jax.ShapeDtypeStruct((1, heads, seq), jnp.float32, sharding=one_chip)
+
+        def both(q, k, v, log_alpha, beta):
+            def loss(*args):
+                o = gated_delta.gated_delta_rule(*args, interpret=False)
+                return (o.astype(jnp.float32) ** 2).sum()  # the output kernel stays live
+            return jax.value_and_grad(loss, argnums=range(5))(q, k, v, log_alpha, beta)
+
+        compiled = jax.jit(both).lower(qk, qk, v, gate, gate).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    for name in ("gated_delta_local_fwd", "gated_delta_fwd", "gated_delta_out_fwd", "gated_delta_bwd",
+                 "gated_delta_local_bwd"):
+        assert any(name in call for call in calls), name
+    assert len(calls) == 5  # the backward reads W, U and V' again, it does not recompute them
+    chunk = gated_delta.DEFAULT_CHUNK
+    assert f",{chunk},{chunk}]" not in text
+    groups = gated_delta.default_head_groups(heads, seq, d_k, d_v, chunk)
+    assert groups == 1 and compiled.memory_analysis().temp_size_in_bytes <= gated_delta.GROUP_BYTES

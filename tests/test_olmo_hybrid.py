@@ -209,6 +209,41 @@ def test_step_counts_its_layers_and_the_rules_route(tiny_step):
         assert any(line.startswith(name + "{") and label in line for line in exposed), (name, label)
 
 
+KERNELS = ("gated_delta_local_fwd", "gated_delta_fwd", "gated_delta_out_fwd", "gated_delta_local_bwd", "gated_delta_bwd")
+
+
+def _kernel_calls():
+    return {kernel: _count("hops_tpu_train_linattn_kernel_calls_total", kernel=kernel) for kernel in KERNELS}
+
+
+def test_step_counts_the_rules_kernels(tiny_step, monkeypatch):
+    """One count per Mosaic call traced. On the CPU's default route the
+    rule holds no kernel; with the kernels interpreted (steered here, the
+    program has no option for it) a traced L L L F step with ``remat`` and
+    a gradient holds, per linear layer, the three forward kernels twice
+    (the step's forward and the one the ``custom_vjp`` keeps its residuals
+    from) and the backward's two: the reverse recurrence and the local
+    backward."""
+    from hops_tpu.ops import gated_delta
+
+    _, state, batch = tiny_step
+    before = _kernel_calls()
+    jax.jit(make_lm_train_step(loss_chunk=16)).lower(state, batch)  # a step of its own: a cached trace counts nothing
+    assert _kernel_calls() == before  # the XLA route
+    whole_rule = gated_delta.gated_delta_rule
+    monkeypatch.setattr(gated_delta, "gated_delta_rule",
+                        lambda *args, **kwargs: whole_rule(*args, interpret=True, **kwargs))
+    _, metrics = jax.jit(make_lm_train_step(loss_chunk=16))(state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    traced = {kernel: count - before[kernel] for kernel, count in _kernel_calls().items()}
+    assert traced == {"gated_delta_local_fwd": 6, "gated_delta_fwd": 6, "gated_delta_out_fwd": 6,
+                      "gated_delta_local_bwd": 3, "gated_delta_bwd": 3}
+    exposed = render_prometheus(REGISTRY).splitlines()
+    for kernel in KERNELS:
+        assert any(line.startswith("hops_tpu_train_linattn_kernel_calls_total{") and f'kernel="{kernel}"' in line
+                   for line in exposed), kernel
+
+
 def _in_scope(name: str, scope: str) -> bool:
     return any(part.rsplit("(", 1)[-1].rstrip(")") == scope for part in name.split("/"))
 
