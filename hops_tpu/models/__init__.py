@@ -9,11 +9,13 @@ on the MXU and shared train-step factories.
 
 from hops_tpu.models import (  # noqa: F401
     common,
+    differential_attention,
     generation,
     linear_attention,
     mnist,
     moe,
     resnet,
+    state_space,
     transformer,
     widedeep,
 )

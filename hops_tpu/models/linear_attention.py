@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from hops_tpu.ops import gated_delta
+from hops_tpu.ops.causal_conv import causal_conv, dt_bias_init
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import LINATTN_SCOPES
@@ -47,22 +48,6 @@ _m_linattn_traces = REGISTRY.counter(
 def _decay_rate_init(key, shape, dtype=jnp.float32):
     """``A_log``: log of a rate uniform in (0, 16), as the published layer."""
     return jnp.log(jax.random.uniform(key, shape, dtype, minval=1e-4, maxval=16.0))
-
-
-def _dt_bias_init(key, shape, dtype=jnp.float32, dt_min=1e-3, dt_max=0.1):
-    """``dt_bias``: a step log-uniform in (1e-3, 0.1) through the inverse
-    softplus, as the published layer."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (math.log(dt_max) - math.log(dt_min))
-                 + math.log(dt_min))
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """Depth-wise causal convolution of ``x`` (b, s, channels) with
-    ``kernel`` (taps, channels): ``y_t = sum_j kernel[j] x_{t-taps+1+j}``."""
-    taps = kernel.shape[0]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(kernel[j] * padded[:, j: j + x.shape[1]] for j in range(taps))
 
 
 def _l2_normalise(x):
@@ -108,7 +93,7 @@ class GatedDeltaNet(nn.Module):
             if self.allow_neg_eigval:
                 beta = 2.0 * beta
             rate = jnp.exp(self.param("A_log", _decay_rate_init, (h,)))
-            log_alpha = -rate * jax.nn.softplus(a + self.param("dt_bias", _dt_bias_init, (h,)))
+            log_alpha = -rate * jax.nn.softplus(a + self.param("dt_bias", dt_bias_init, (h,)))
 
         with jax.named_scope(SCOPE_CONV):
             def conv(t, name):

@@ -46,8 +46,21 @@ _m_layer_kinds = REGISTRY.counter(
     labels=("kind",),
 )
 
-#: the kinds of layer a ``TransformerLM`` builds (``layer_types``)
-LAYER_TYPES = ("full_attention", "linear_attention")
+_m_shared_reads = REGISTRY.counter(
+    "hops_tpu_train_shared_reads_total",
+    "Layers traced that read a value an earlier layer wrote, by the value",
+    labels=("what",),
+)
+
+#: the kinds of layer a ``TransformerLM`` builds (``layer_types``):
+#: softmax attention over every key or behind ``window``, a Gated-DeltaNet
+#: layer, a Mamba layer, a gated memory unit (reads the ``y`` of the
+#: nearest Mamba layer before it) and cross attention (queries of its own
+#: against the K and V of the nearest ``full_attention`` layer before it)
+LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention", "mamba", "gated_memory",
+               "cross_attention")
+#: what a reading kind takes from which writing kind
+SHARED_VALUES = {"gated_memory": ("memory", "mamba"), "cross_attention": ("kv", "full_attention")}
 
 
 def rotary_embedding(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -78,6 +91,25 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (norm * scale).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """LayerNorm with scale and bias, computed in float32."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        norm = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + self.eps)
+        return (norm * scale + bias).astype(self.dtype)
+
+
+NORMS = {"rms": RMSNorm, "layer": LayerNorm}
 
 
 class Attention(nn.Module):
@@ -523,19 +555,48 @@ class Block(nn.Module):
     linear_value_dim: int | None = None
     linear_conv_size: int = 4
     linear_allow_neg_eigval: bool = True
+    # What a decoder-hybrid-decoder's layers differ in besides: the norm
+    # ("rms" | "layer": LayerNorm with bias), biases on the attention
+    # projections, the attention form ("softmax": ``Attention`` |
+    # "differential": ``differential_attention.DifferentialAttention``,
+    # with the layer's index in its lambda) and whether this layer hands a
+    # value on to later ones (``hands_on``:
+    # None | "memory", a Mamba layer's ``y`` | "kv", an attention layer's K
+    # and V): it then returns ``(x, value)``. A ``gated_memory`` or
+    # ``cross_attention`` layer takes that value as ``shared``.
+    norm_kind: str = "rms"
+    use_bias: bool = False
+    attention_form: str = "softmax"
+    layer_index: int = 0
+    hands_on: str | None = None
 
     @nn.compact
-    def __call__(self, x, train: bool = False, decode: bool = False):
+    def __call__(self, x, train: bool = False, decode: bool = False, shared=None):
         if self.layer_type not in LAYER_TYPES:
             raise ValueError(f"unknown layer_type {self.layer_type!r} (one of {LAYER_TYPES})")
         if self.norm_placement not in ("pre", "post_sublayer"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.norm_kind not in NORMS:
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r} (one of {tuple(NORMS)})")
         pre = self.norm_placement == "pre"
 
         def norm(t):
-            return RMSNorm(self.norm_eps, dtype=self.dtype)(t)
+            return NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype)(t)
 
-        if self.layer_type == "linear_attention":
+        reads = SHARED_VALUES.get(self.layer_type)
+        if reads and shared is None:
+            raise ValueError(
+                f"a {self.layer_type} layer reads the {reads[0]} of a {reads[1]} layer before it: none was handed on")
+        if self.layer_type in ("mamba", "gated_memory"):
+            from hops_tpu.models.state_space import GatedMemoryUnit, Mamba
+
+            if self.layer_type == "mamba":
+                mixer = Mamba(hands_on_memory=self.hands_on == "memory", dtype=self.dtype, name="attn")
+            else:
+                mixer = functools.partial(GatedMemoryUnit(dtype=self.dtype, name="attn"), memory=shared)
+        elif self.layer_type == "cross_attention":
+            mixer = functools.partial(self._attention(), kv=shared)
+        elif self.layer_type == "linear_attention":
             from hops_tpu.models.linear_attention import GatedDeltaNet
 
             mixer = GatedDeltaNet(
@@ -551,6 +612,8 @@ class Block(nn.Module):
         else:
             mixer = self._attention()
         h = mixer(norm(x) if pre else x, decode=decode)
+        if self.hands_on:
+            h, handed_on = h
         if not pre:
             h = norm(h)
         if self.dropout_rate:
@@ -567,9 +630,33 @@ class Block(nn.Module):
             h = norm(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return x + h
+        return (x + h, handed_on) if self.hands_on else x + h
 
     def _attention(self):
+        cross, hands_on_kv = self.layer_type == "cross_attention", self.hands_on == "kv"
+        if self.attention_form == "differential":
+            from hops_tpu.models.differential_attention import DifferentialAttention
+
+            if self.rope_base is not None or self.qk_norm or self.tp_shards > 1:
+                raise NotImplementedError(
+                    "differential attention is built without rotary, QK-norm or tensor parallelism")
+            return DifferentialAttention(
+                self.num_heads,
+                num_kv_heads=self.num_kv_heads,
+                layer_index=self.layer_index,
+                window=self.window,
+                use_bias=self.use_bias,
+                cross=cross,
+                hands_on_kv=hands_on_kv,
+                attention_impl=self.attention_impl,
+                norm_eps=self.norm_eps,
+                dtype=self.dtype,
+                name="attn",
+            )
+        if self.attention_form != "softmax" or cross or hands_on_kv or self.use_bias:
+            raise ValueError(
+                f"attention_form {self.attention_form!r}: biases, cross attention and handing on K/V "
+                "are built for the differential form only (attention_form is softmax | differential)")
         return Attention(
             self.num_heads,
             dtype=self.dtype,
@@ -635,10 +722,24 @@ class TransformerLM(nn.Module):
     linear_allow_neg_eigval: bool = True
     norm_placement: str = "pre"
     mlp_hidden: int | None = None
+    # A decoder-hybrid-decoder (SambaY, arXiv:2507.06607): ``layer_types``
+    # may also name "sliding_attention" (attention behind ``window``, which
+    # reaches those layers only once ``layer_types`` is given: every other
+    # attention layer then sees every key), "mamba", "gated_memory"
+    # and "cross_attention", which read what the nearest "mamba" /
+    # "full_attention" layer before them hands on; the norms' kind ("rms" |
+    # "layer": LayerNorm with bias, the final norm too), biases on the
+    # attention projections, the attention form ("softmax" |
+    # "differential") and whether the logits read the embedding matrix
+    # (no ``unembed`` parameter then).
+    norm_kind: str = "rms"
+    use_bias: bool = False
+    attention_form: str = "softmax"
+    tie_embeddings: bool = False
     max_decode_len: int = 2048
     kv_cache_dtype: str | None = None  # "int8": quantized decode cache
     num_kv_heads: int | None = None  # GQA: shrink the decode cache
-    window: int | None = None  # sliding-window causal attention
+    window: int | None = None  # sliding-window causal attention (see layer_types)
     ragged_decode: bool = False  # (b,) cache index: continuous batching
     # Paged KV cache (serving engine's memory core): per-layer block
     # pool + per-row page tables instead of (b, heads, capacity, d)
@@ -688,13 +789,32 @@ class TransformerLM(nn.Module):
             linear_value_dim=self.linear_value_dim,
             linear_conv_size=self.linear_conv_size,
             linear_allow_neg_eigval=self.linear_allow_neg_eigval,
+            norm_kind=self.norm_kind,
+            use_bias=self.use_bias,
+            attention_form=self.attention_form,
         )
-        if self.moe_every and (self.layer_types or self.norm_placement != "pre" or self.mlp_hidden):
+        if self.moe_every and (self.layer_types or self.norm_placement != "pre" or self.mlp_hidden
+                               or self.norm_kind != "rms" or self.use_bias or self.attention_form != "softmax"):
             raise NotImplementedError(
-                "layer_types, norm_placement and mlp_hidden shape dense blocks; "
-                "a routed block (moe_every) is pre-norm softmax attention"
+                "layer_types, norm_placement, mlp_hidden, norm_kind, use_bias and attention_form "
+                "shape dense blocks; a routed block (moe_every) is pre-norm softmax attention"
             )
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")(tokens)
+        # who hands what on: a reader takes the value of the nearest writer
+        # of its kind before it, and only such writers return one
+        source: dict[int, int] = {}
+        for i, kind in enumerate(layer_types):
+            if kind in SHARED_VALUES:
+                what, writer = SHARED_VALUES[kind]
+                before = [j for j in range(i) if layer_types[j] == writer]
+                if not before:
+                    raise ValueError(
+                        f"layer {i} ({kind}) reads the {what} of a {writer} layer before it: "
+                        f"layer_types has none ({layer_types})")
+                source[i] = before[-1]
+        hands_on = {j: SHARED_VALUES[layer_types[i]][0] for i, j in source.items()}
+        handed_on: dict[int, Any] = {}
+        embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
+        x = embed(tokens)
         block_cls = nn.remat(Block, static_argnums=(2, 3)) if self.remat else Block
         moe_cls = nn.remat(MoEBlock, static_argnums=(2, 3)) if self.remat else MoEBlock
         layer_options = dict(
@@ -724,6 +844,10 @@ class TransformerLM(nn.Module):
                     name=f"block_{i}",
                 )(x, train, decode)
                 continue
+            shared = ()
+            if i in source:
+                _m_shared_reads.inc(what=hands_on[source[i]])
+                shared = (handed_on[source[i]],)
             x = block_cls(
                 self.num_heads,
                 dtype=self.dtype,
@@ -737,22 +861,28 @@ class TransformerLM(nn.Module):
                 tp_shards=self.tp_shards,
                 kv_cache_dtype=self.kv_cache_dtype,
                 num_kv_heads=self.num_kv_heads,
-                window=self.window,
+                window=self.window if self.layer_types is None or layer_types[i] == "sliding_attention" else None,
                 ragged_decode=self.ragged_decode,
                 paged_decode=self.paged_decode,
                 kv_page_size=self.kv_page_size,
                 kv_pool_blocks=self.kv_pool_blocks,
                 **layer_options,
                 layer_type=layer_types[i],
+                layer_index=i,
+                hands_on=hands_on.get(i),
                 **hybrid,
                 name=f"block_{i}",
-            )(x, train, decode)
-        x = RMSNorm(self.norm_eps, dtype=self.dtype, name="final_norm")(x)
+            )(x, train, decode, *shared)
+            if i in hands_on:
+                x, handed_on[i] = x
+        x = NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype, name="final_norm")(x)
         if return_hidden:
             # The chunked-vocab loss (ops/xent.py) computes the loss
             # straight from hidden states + the unembed kernel without
             # ever materializing (batch, seq, vocab) fp32 logits.
             return x
+        if self.tie_embeddings:
+            return embed.attend(x).astype(jnp.float32)
         logits = nn.Dense(self.vocab_size, dtype=self.dtype, use_bias=False, name="unembed")(x)
         return logits.astype(jnp.float32)
 
@@ -807,8 +937,13 @@ def make_lm_train_step(
             if loss_chunk:
                 from hops_tpu.ops.xent import chunked_softmax_xent
 
+                # tied embeddings: the loss reads the embedding matrix as it
+                # lies, (vocab, d), and its dW joins the gather's gradient
+                tied = "unembed" not in params
                 loss = chunked_softmax_xent(
-                    out, params["unembed"]["kernel"], targets, chunk=loss_chunk
+                    out,
+                    params["embed"]["embedding"] if tied else params["unembed"]["kernel"],
+                    targets, chunk=loss_chunk, vocab_major=tied,
                 )
             else:
                 with jax.named_scope(SCOPE_LM_HEAD_LOSS):

@@ -20,6 +20,10 @@ hand-written here with Pallas:
   its own; on a TPU five Pallas kernels (``gated_delta_local_fwd``,
   ``gated_delta_fwd``, ``gated_delta_out_fwd``, ``gated_delta_bwd``,
   ``gated_delta_local_bwd``) keep a chunk's matrices and the state in VMEM.
+- ``selective_scan`` — the recurrence of a Mamba layer in chunks, with a
+  backward pass of its own that recomputes a chunk's states; on a TPU two
+  Pallas kernels (``selective_scan_fwd``, ``selective_scan_bwd``) keep the
+  state in VMEM. No array of per-token states is made.
 
 Every kernel ships with a pure-XLA reference twin used for (a) numeric
 tests, (b) non-TPU backends, (c) shapes the kernel doesn't support.

@@ -67,11 +67,15 @@ def chunked_softmax_xent(
     targets: jax.Array,
     *,
     chunk: int = 128,
+    vocab_major: bool = False,
 ) -> jax.Array:
     """Mean next-token cross-entropy from hidden states.
 
     ``hidden``: (batch, seq, d) — the final-norm output;
-    ``unembed``: (d, vocab) kernel; ``targets``: (batch, seq) int ids.
+    ``unembed``: (d, vocab) kernel, or with ``vocab_major`` the (vocab, d)
+    matrix of a tied embedding as it lies (the matmuls contract its
+    other dim; no transpose of it or of its gradient is made);
+    ``targets``: (batch, seq) int ids.
     Returns the scalar mean loss, identical (fp32 inputs) to computing
     full logits and feeding optax. ``chunk`` is a TOKEN count — the
     flattened ``batch*seq`` tokens are processed ``chunk`` at a time
@@ -96,7 +100,7 @@ def chunked_softmax_xent(
     loss and of its backward carries that name in the profiler trace.
     """
     sums = per_shard(
-        functools.partial(_loss_sum, chunk=chunk),
+        functools.partial(_loss_sum, chunk=chunk, vocab_major=vocab_major),
         op=SCOPE_LM_HEAD_LOSS, replicated=(1,),
     )(hidden, unembed, targets)
     return jnp.sum(sums) / targets.size
@@ -141,14 +145,14 @@ def _grouped(hidden: jax.Array, targets: jax.Array, chunk: int):
     return h.reshape(*shape, d), t.reshape(shape), valid.reshape(shape)
 
 
-def _chunk_logits(hc: jax.Array, w: jax.Array) -> jax.Array:
+def _chunk_logits(hc: jax.Array, w: jax.Array, vocab_major: bool = False) -> jax.Array:
     # (chunk, vocab) fp32 exists only inside one visit of a loop body.
     # bf16 inputs on the MXU, fp32 accumulation — the logits are BORN
     # fp32 here (the full-logits path rounds them through the model
     # dtype first, so bf16 models get slightly better loss numerics on
     # this path, exactness for fp32 models).
     return jax.lax.dot_general(
-        hc, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        hc, w, (((1,), (int(vocab_major),)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _chunk_loss(logits: jax.Array, tc: jax.Array, vc: jax.Array):
@@ -157,9 +161,9 @@ def _chunk_loss(logits: jax.Array, tc: jax.Array, vc: jax.Array):
     return jnp.sum((lse - tgt) * vc), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _loss_sum(
-    hidden: jax.Array, unembed: jax.Array, targets: jax.Array, chunk: int
+    hidden: jax.Array, unembed: jax.Array, targets: jax.Array, chunk: int, vocab_major: bool = False
 ) -> jax.Array:
     """Sum of the token losses of ``hidden``'s rows, shape ``(1,)`` (the
     batch-leading partial that ``per_shard`` stacks across shards).
@@ -173,7 +177,7 @@ def _loss_sum(
 
     def body(acc, args):
         hc, tc, vc = args
-        return acc + _chunk_loss(_chunk_logits(hc, w), tc, vc)[0], None
+        return acc + _chunk_loss(_chunk_logits(hc, w, vocab_major), tc, vc)[0], None
 
     total, _ = jax.lax.scan(
         body, jnp.float32(0),
@@ -181,7 +185,7 @@ def _loss_sum(
     return total[None]
 
 
-def _loss_sum_fwd(hidden, unembed, targets, chunk):
+def _loss_sum_fwd(hidden, unembed, targets, chunk, vocab_major):
     """The differentiated forward: one pass over the chunks that makes
     the loss sum AND d(loss sum)/d(hidden), d(loss sum)/d(unembed).
 
@@ -190,17 +194,18 @@ def _loss_sum_fwd(hidden, unembed, targets, chunk):
     the visit that makes its loss, rounded to ``hidden``'s dtype (what
     the MXU takes) into a ``(group rows, vocab)`` staging buffer. After
     a group's chunks, two matmuls over the whole group: dH, and dW added
-    into the fp32 ``(d, vocab)`` carry — once per ``_GROUP_ROWS`` rows.
+    into the fp32 ``(d, vocab)`` carry — once per ``_GROUP_ROWS`` rows
+    (``(vocab, d)`` with ``vocab_major``: the carry has ``unembed``'s layout).
     """
     _m_loss_traces.inc(**{"pass": "one_pass"})
     h, t, valid = _grouped(hidden, targets, chunk)
     _, per_group, _, d = h.shape
-    vocab = unembed.shape[1]
+    vocab = unembed.shape[0 if vocab_major else 1]
     w = unembed.astype(h.dtype)
 
     def visit(total, args):
         hc, tc, vc = args
-        logits = _chunk_logits(hc, w)
+        logits = _chunk_logits(hc, w, vocab_major)
         loss, lse = _chunk_loss(logits, tc, vc)
         p = jnp.exp(logits - lse[:, None])
         hit = jax.lax.broadcasted_iota(tc.dtype, p.shape, 1) == tc[:, None]
@@ -213,22 +218,22 @@ def _loss_sum_fwd(hidden, unembed, targets, chunk):
         total, dlogits = jax.lax.scan(visit, total, args)
         dlogits = dlogits.reshape(per_group * chunk, vocab)
         dh = jax.lax.dot_general(
-            dlogits, w, (((1,), (1,)), ((), ())),
+            dlogits, w, (((1,), (int(not vocab_major),)), ((), ())),
             preferred_element_type=jnp.float32).astype(hg.dtype)
         dw = dw + jax.lax.dot_general(
-            hg, dlogits, (((0,), (0,)), ((), ())),
+            *((dlogits, hg) if vocab_major else (hg, dlogits)), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return (total, dw), dh
 
     (total, dw), dh = jax.lax.scan(
-        group, (jnp.float32(0), jnp.zeros((d, vocab), jnp.float32)),
+        group, (jnp.float32(0), jnp.zeros(unembed.shape, jnp.float32)),
         (h, t, valid))
     n = hidden.shape[0] * hidden.shape[1]
     dh = dh.reshape(-1, d)[:n].reshape(hidden.shape)
     return total[None], (dh, dw.astype(unembed.dtype))
 
 
-def _loss_sum_bwd(chunk, grads, g):
+def _loss_sum_bwd(chunk, vocab_major, grads, g):
     # Scaled in fp32 and rounded once more: the cotangent (1 / tokens
     # under the mean) is not rounded to the hidden dtype first.
     return tuple((x * g[0]).astype(x.dtype) for x in grads) + (None,)

@@ -64,6 +64,18 @@ SCOPE_MLP = TRAIN_SCOPES[1]
 #: ``W_o``). A softmax-attention block enters none of them.
 LINATTN_SCOPES = ("linattn_proj", "linattn_conv", "linattn_scan", "linattn_out")
 
+#: Inside ``attn``, the parts of a state-space (Mamba) mixer
+#: (``models/state_space.py``): the projections (``W_in``, ``W_x``, the
+#: step's ``W_dt`` and its softplus), the short causal convolution with its
+#: activation, the selective scan (``ops/selective_scan.py``, forward and
+#: backward; kernel names ``selective_scan_fwd``, ``selective_scan_bwd``)
+#: and the gate (``y * silu(z)`` and ``W_out``). A gated memory unit enters
+#: the first and the last. ``diff_attn`` is entered by an attention layer
+#: of the differential form round its flash calls, the lambda combination
+#: and the norm over a head pair's values: the projections stay outside.
+SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
+SCOPE_DIFF_ATTN = "diff_attn"
+
 #: The training step's host vocabulary: :func:`span` names entered by
 #: ``Strategy.distribute_batch`` and by every ``Strategy.step`` callable,
 #: children of the launcher's ``experiment.run`` root span.
@@ -76,7 +88,11 @@ SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
 #: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``),
 #: ``hops_tpu_train_moe_traces_total{impl}`` (``models/moe.py``),
 #: ``hops_tpu_train_linattn_traces_total{impl}``
-#: (``models/linear_attention.py``) and
+#: (``models/linear_attention.py``),
+#: ``hops_tpu_train_ssm_traces_total{impl}`` (``models/state_space.py``),
+#: ``hops_tpu_train_shared_reads_total{what="memory"|"kv"}``
+#: (``models/transformer.py``, one per layer traced that reads a value
+#: an earlier layer wrote) and
 #: ``hops_tpu_train_layer_kinds_total{kind}`` (``models/transformer.py``,
 #: one per layer traced) are named
 #: where they are counted; the flash kernels' sub-tiles

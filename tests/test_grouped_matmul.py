@@ -186,3 +186,35 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     assert f",{chunk},{chunk}]" not in text
     groups = gated_delta.default_head_groups(heads, seq, d_k, d_v, chunk)
     assert groups == 1 and compiled.memory_analysis().temp_size_in_bytes <= gated_delta.GROUP_BYTES
+
+
+@pytest.mark.parametrize("chunk", [None, 64], ids=["default_chunk", "chunk_64"])
+def test_selective_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip, chunk):
+    """The two kernels of ``ops/selective_scan.py`` at one sequence of 8,192
+    tokens, 5,120 channels and 16 state values (this file holds the one
+    fixture that may load the TPU compiler): Mosaic accepts the SMEM blocks
+    of ``B`` and ``C``, the single-row stores of the lane partials and the
+    VMEM a chunk's recomputed states ask for; no array of the compiled
+    program holds per-token states. Nothing runs."""
+    from hops_tpu.ops import selective_scan as scan
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        def shaped(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        seq, d, n = 8192, 5120, 16
+        args = (shaped((1, seq, d), jnp.bfloat16), shaped((1, seq, d), jnp.float32), shaped((d, n), jnp.float32),
+                shaped((1, seq, n), jnp.bfloat16), shaped((1, seq, n), jnp.bfloat16), shaped((d,), jnp.float32))
+
+        def grads(*x):
+            return jax.grad(lambda *y: scan.selective_scan(*y, chunk=chunk, interpret=False).astype(jnp.float32).sum(),
+                            argnums=range(6))(*x)
+
+        text = jax.jit(grads).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2 and scan.KERNEL_FWD in calls[0] + calls[1] and scan.KERNEL_BWD in calls[0] + calls[1]
+    assert f"[1,{seq},{d},{n}]" not in text and f"[{seq},{d},{n}]" not in text and f"[1,{seq},{n},{d // 128},128]" not in text

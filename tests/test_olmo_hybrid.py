@@ -141,12 +141,12 @@ def test_layer_types_are_checked():
     with pytest.raises(ValueError, match="layer_types names 4 layers"):
         TransformerLM(**{**TINY, "num_layers": 3}).init(jax.random.PRNGKey(0), tokens)
     with pytest.raises(ValueError, match="unknown layer_type"):
-        TransformerLM(**{**TINY, "layer_types": ("mamba",) * 4}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "layer_types": ("hyena",) * 4}).init(jax.random.PRNGKey(0), tokens)
     with pytest.raises(ValueError, match="unknown norm_placement"):
         TransformerLM(**{**TINY, "norm_placement": "sandwich"}).init(jax.random.PRNGKey(0), tokens)
     with pytest.raises(NotImplementedError, match="a routed block"):
         TransformerLM(**{**TINY, "moe_every": 2}).init(jax.random.PRNGKey(0), tokens)
-    assert LAYER_TYPES == ("full_attention", "linear_attention")
+    assert LAYER_TYPES[:2] == ("full_attention", "linear_attention")
 
 
 # -- what must not move: the accepted configurations' shapes -------------------
