@@ -10,8 +10,11 @@ is TPU-first:
   present (context parallelism over the ICI ring);
 - all matmuls run in bfloat16 on the MXU with fp32 accumulation;
 - rotary position embeddings (no learned position table to shard);
-- optional ``nn.remat`` per block trades FLOPs for HBM
-  (the jax.checkpoint knob from the build brief).
+- optional ``nn.remat`` per block trades FLOPs for HBM (the
+  jax.checkpoint knob from the build brief): a block's input and the
+  values named in ``telemetry.spans.REMAT_KEEPS`` (kernel results and
+  ``d_model``-wide sublayer outputs that the backward reads) are held,
+  the rest of its forward runs again in the backward pass.
 
 Sharding contract (used by the launchers and __graft_entry__):
 embed/unembed and MLP kernels are Megatron-split on the ``model`` axis
@@ -39,6 +42,7 @@ from hops_tpu.ops.attention import (
 )
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import REMAT_KEEPS, keep
 
 _m_layer_kinds = REGISTRY.counter(
     "hops_tpu_train_layer_kinds_total",
@@ -614,6 +618,11 @@ class Block(nn.Module):
         h = mixer(norm(x) if pre else x, decode=decode)
         if self.hands_on:
             h, handed_on = h
+        # remat keeps a sublayer's result where the backward reads it, not the
+        # matmul that made it: the mixer's under either placement (a norm on
+        # it, or the second norm on the sum it enters), the feed-forward's
+        # under a norm on it only
+        h = keep(h, "mixer_out")
         if not pre:
             h = norm(h)
         if self.dropout_rate:
@@ -627,7 +636,7 @@ class Block(nn.Module):
             name="mlp",
         )(norm(x) if pre else x)
         if not pre:
-            h = norm(h)
+            h = norm(keep(h, "mlp_out"))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return (x + h, handed_on) if self.hands_on else x + h
@@ -815,8 +824,12 @@ class TransformerLM(nn.Module):
         handed_on: dict[int, Any] = {}
         embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
         x = embed(tokens)
-        block_cls = nn.remat(Block, static_argnums=(2, 3)) if self.remat else Block
-        moe_cls = nn.remat(MoEBlock, static_argnums=(2, 3)) if self.remat else MoEBlock
+        block_cls, moe_cls = Block, MoEBlock
+        if self.remat:
+            # a block's input and the values named in REMAT_KEEPS are held,
+            # the rest of its forward runs again in the backward pass
+            kept = jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)
+            block_cls, moe_cls = (nn.remat(cls, static_argnums=(2, 3), policy=kept) for cls in (Block, MoEBlock))
         layer_options = dict(
             qk_norm=self.qk_norm, norm_eps=self.norm_eps, rope_base=self.rope_base
         )

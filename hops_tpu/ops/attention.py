@@ -39,7 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_SUBTILES
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_SUBTILES, keep
 
 NEG_INF = float("-inf")
 _LANES = 128  # VPU lane width: per-row stats are broadcast across lanes
@@ -579,7 +579,10 @@ def _flash(q, k, v, band, sm_scale, interpret):
 def _flash_fwd(q, k, v, band, sm_scale, interpret):
     _count_subtiles("fwd", band)
     o, lse = _fwd_call(_flat(q), _flat(k), _flat(v), band, sm_scale, interpret)
-    return o.reshape(q.shape), (q, k, v, o.reshape(q.shape), lse)
+    # kept by a block's remat: the backward kernels then take q, k, v from the
+    # second forward and this call is not made again
+    o, lse = keep(o.reshape(q.shape), "flash_out"), keep(lse, "flash_lse")
+    return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(band, sm_scale, interpret, res, g):

@@ -107,6 +107,44 @@ COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"
 #: in kernels. A step on the XLA route counts none.
 COUNTER_TRAIN_LINATTN_KERNEL_CALLS = "hops_tpu_train_linattn_kernel_calls_total"
 
+#: What ``TransformerLM(remat=True)`` keeps of a block's forward besides the
+#: block's input: values that cost a kernel or a ``d_model``-wide matmul to
+#: make again and are one ``d_model``-wide row a token (or less) to hold.
+#: ``flash_out`` / ``flash_lse``: the flash forward's result and row
+#: statistics, the backward kernels' operands (``ops/attention.py``);
+#: ``mixer_out`` / ``mlp_out``: a sublayer's result
+#: (``models/transformer.py:Block``). JAX holds a named value only where the
+#: backward reads it: under a norm on each sublayer's output both, with the
+#: norms in front ``mixer_out`` alone (the second norm reads the sum it
+#: enters; ``Block`` does not name ``mlp_out`` there). Not kept, because they are several times the bytes per
+#: millisecond and the two cells that run ``remat`` have no room for them:
+#: the feed-forward's ``gate`` / ``up`` (FFN-wide), the linear-attention
+#: layers' projections, the gated delta rule's float32 ``states``, ``W``,
+#: ``U``, ``V'``, and the selective scan's ``y`` and chunk start states
+#: (504 MB for 3.5 ms at the Phi-4-flash cell's widths: with them XLA's own
+#: rematerialization ran two FFN-wide matmuls again to fit the chip, PERF.md
+#: section 6, PR 32).
+REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out")
+#: One per value traced under a ``REMAT_KEEPS`` name (``what``), whether or
+#: not a ``remat`` encloses it: outside one the name is the identity.
+COUNTER_TRAIN_REMAT_KEPT = "hops_tpu_train_remat_kept_total"
+
+
+_m_remat_kept = REGISTRY.counter(
+    COUNTER_TRAIN_REMAT_KEPT, "Values traced under a name that remat keeps", labels=("what",))
+
+
+def keep(x: Any, what: str) -> Any:
+    """``x`` under the name ``what`` (one of ``REMAT_KEEPS``): the value a
+    block's ``remat`` holds for the backward pass instead of computing it
+    again. Lowers to nothing."""
+    from jax.ad_checkpoint import checkpoint_name  # this module stays importable without jax
+
+    if what not in REMAT_KEEPS:
+        raise ValueError(f"{what!r} is not a name remat keeps (one of {REMAT_KEEPS})")
+    _m_remat_kept.inc(what=what)
+    return checkpoint_name(x, what)
+
 
 def _sanitize(name: str) -> str:
     return _NAME_RE.sub("_", name)

@@ -51,3 +51,26 @@ def workspace(tmp_path, monkeypatch):
 
     config.configure(workspace=str(tmp_path / "workspace"), project="testproj")
     yield tmp_path / "workspace"
+
+
+# Steering the kernels from a test (the program has no option for either):
+
+
+@pytest.fixture
+def flash_kernel_at_any_length(monkeypatch):
+    """Flash attention takes the Pallas kernel (interpreted on the CPU) at
+    any key length a 128-multiple divides, not only from 1,536 keys up."""
+    from hops_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_XLA_FASTER_BELOW", 0)
+
+
+@pytest.fixture
+def scan_kernels_interpreted(monkeypatch):
+    """The selective scan takes its two Pallas kernels through the
+    interpreter, not its XLA twin."""
+    from hops_tpu.ops import selective_scan
+
+    whole = selective_scan.selective_scan
+    monkeypatch.setattr(selective_scan, "selective_scan", lambda *a, **kw: whole(*a, **{**kw, "interpret": True}))
+

@@ -138,8 +138,9 @@ class TestResNetTPUForm:
 
     @pytest.mark.slow
     def test_remat_blocks_identical_values_and_grads(self):
-        """remat=True saves only block boundaries; values, grads, and
-        batch_stats updates must be numerically identical."""
+        """ResNet50's remat=True saves only block boundaries (TransformerLM's
+        keeps named values besides: tests/test_remat_keeps.py); values, grads,
+        and batch_stats updates must be numerically identical."""
         def build(remat):
             return ResNet50(num_classes=10, dtype=jnp.float32,
                             norm_dtype=jnp.float32, remat=remat)

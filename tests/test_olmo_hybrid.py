@@ -121,10 +121,19 @@ def test_each_departure_from_the_layers_is_seen(tiny):
     assert float(jnp.max(jnp.abs(model.apply({"params": taps}, inputs, return_hidden=True) - want))) > 1e-3
 
 
-def test_remat_changes_nothing(tiny):
+@pytest.mark.parametrize("impl", ["reference", "flash"])
+def test_remat_changes_nothing(tiny, impl, flash_kernel_at_any_length):
+    """Loss, hidden states and every block's gradient: what remat keeps by
+    name (``REMAT_KEEPS``: here both sublayers' results under the norms on
+    them and, with ``flash``, the kernel's result and row statistics) is
+    what its second forward would have made."""
     model, params, inputs, targets = tiny
+    if impl == "flash":  # 128 keys: the kernel, by the fixture
+        model = TransformerLM(**{**TINY, "attention_impl": "flash"})
+        tokens = jnp.asarray(np.random.RandomState(2).randint(0, VOCAB, (2, 129)), jnp.int32)
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
     plain = _program(model, params, inputs, targets)
-    again = _program(TransformerLM(**{**TINY, "remat": True}), params, inputs, targets)
+    again = _program(model.clone(remat=True), params, inputs, targets)
     np.testing.assert_allclose(again[0], plain[0], rtol=1e-6)
     np.testing.assert_allclose(again[1], plain[1], rtol=1e-5, atol=1e-6)
     assert _rel(again[2], plain[2]) < 1e-5

@@ -116,10 +116,21 @@ def test_every_parameters_gradient_follows_the_reference(tiny, both):
             assert _rel(g, w) < 10 * REL_TOL, (name, jax.tree_util.keystr(path))
 
 
-def test_remat_changes_nothing(tiny):
+@pytest.mark.parametrize("route", ["reference", "kernels"])
+def test_remat_changes_nothing(tiny, route, request):
+    """Loss, logits and every gradient: what remat keeps by name
+    (``REMAT_KEEPS``: the flash kernel's result and row statistics, every
+    mixer's result) is what its second forward would have made. ``kernels``
+    takes the flash kernel at 128 keys and the scan's two through the
+    interpreter; ``reference`` the XLA forms."""
     depth, model, params, inputs, targets = tiny
+    if route == "kernels":
+        request.getfixturevalue("flash_kernel_at_any_length"), request.getfixturevalue("scan_kernels_interpreted")
+        model = TransformerLM(**tiny_args(depth, attention_impl="flash"))
+        tokens = jnp.asarray(np.random.RandomState(2).randint(0, VOCAB, (1, 129)), jnp.int32)
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
     plain = _program(model, params, inputs, targets, _names(depth))
-    again = _program(TransformerLM(**tiny_args(depth, remat=True)), params, inputs, targets, _names(depth))
+    again = _program(model.clone(remat=True), params, inputs, targets, _names(depth))
     np.testing.assert_allclose(again[0], plain[0], rtol=1e-6)
     np.testing.assert_allclose(again[1], plain[1], rtol=1e-5, atol=1e-6)
     assert _rel(again[2], plain[2]) < 1e-5
@@ -334,9 +345,15 @@ def lowered_digest(toy):
 
 @pytest.mark.parametrize("toy", sorted(TOYS))
 def test_defaults_keep_the_parents_lowered_step(toy):
-    """``tests/data/transformer_lm_parent_lowered.json`` was written by the
-    parent commit (18a3e8f) with ``lowered_digest``: with the new fields at
-    their defaults the three accepted LM shapes lower to the same text."""
+    """``tests/data/transformer_lm_parent_lowered.json`` was written with
+    ``lowered_digest``: ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
+    PR 31's parent (no ``remat``: the fields added since, and the names
+    ``remat`` keeps values by, leave their lowered text as it was);
+    ``hybrid_shaped`` by PR 32 on top of ebb7672, because that toy has
+    ``remat=True`` and what ``remat`` keeps changed by design: per block the
+    second forward lost two ``dot_general`` (``mlp/down``, ``attn/out``) and
+    the first gained two ``reduce_precision`` on the kept results (227
+    ``dot_general`` where 18a3e8f wrote 235; the line count is 7,229 on both)."""
     digest, lines = lowered_digest(toy)
     assert lines == LOWERED[toy]["lines"]
     assert digest == LOWERED[toy]["sha256"]

@@ -1,0 +1,203 @@
+"""What ``TransformerLM(remat=True)`` holds for the backward pass and what
+its second forward still runs, read from traced programs (nothing here is
+executed): a block of each kind keeps its input and the values named in
+``telemetry.spans.REMAT_KEEPS`` that its backward reads, nothing else; the
+flash forward appears once a layer in a step's gradient, not twice; the
+selective scan's forward, which is not kept, twice.
+
+The kernels are steered in the tests (the program has no option for it):
+flash attention takes the Pallas kernel at 2,048 keys, as on the chip; the
+scan takes its kernels with ``interpret=True`` or, left alone, its XLA twin.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from flax import linen as nn
+from jax.extend.core import Literal
+
+from hops_tpu.models.transformer import Block, TransformerLM
+from hops_tpu.telemetry import REGISTRY
+from hops_tpu.telemetry.export import render_prometheus
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_REMAT_KEPT, REMAT_KEEPS, keep
+
+D_MODEL, HEADS, MLP_HIDDEN, SEQ = 64, 4, 192, 2048
+KEPT = jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)
+HYBRID = dict(norm_placement="post_sublayer", mlp_hidden=MLP_HIDDEN, qk_norm=True, rope_base=None,
+              linear_num_heads=HEADS, linear_key_dim=8, linear_value_dim=16)
+FLASH = dict(norm_kind="layer", norm_eps=1e-5, mlp_hidden=MLP_HIDDEN, rope_base=None, use_bias=True,
+             attention_form="differential", num_kv_heads=2)
+
+# kind of block -> (Block's fields, what it is handed, the names its remat keeps): ``mixer_out`` wherever a
+# norm reads the mixer's result or the sum it enters, ``mlp_out`` under a norm on the sublayer's output only
+BLOCKS = {
+    "post_norm_linear_attention": (dict(HYBRID, layer_type="linear_attention"), None, {"mixer_out", "mlp_out"}),
+    "post_norm_full_attention": (dict(HYBRID, layer_type="full_attention"), None,
+                                 {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
+    "pre_norm_mamba": (dict(FLASH, layer_type="mamba"), None, {"mixer_out"}),
+    "pre_norm_mamba_hands_on": (dict(FLASH, layer_type="mamba", hands_on="memory"), None, {"mixer_out"}),
+    "pre_norm_window_differential": (dict(FLASH, layer_type="sliding_attention", window=512, layer_index=1), None,
+                                     {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_full_differential": (dict(FLASH, layer_type="full_attention", hands_on="kv", layer_index=5), None,
+                                   {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_cross_differential": (dict(FLASH, layer_type="cross_attention", layer_index=7), "kv",
+                                    {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_gated_memory": (dict(FLASH, layer_type="gated_memory"), "memory", {"mixer_out"}),
+}
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the programs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _walk(inner)
+
+
+def _backward(fn, *args):
+    """The jaxpr of ``fn``'s forward and backward with the cotangent as an
+    argument, so that whatever a ``remat`` equation reads besides the
+    jaxpr's own arguments was made by the forward pass."""
+    def pullback(cotangent, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return vjp(jax.tree.map(lambda c, o: c.astype(o.dtype), cotangent, out))
+
+    return jax.make_jaxpr(pullback)(jax.eval_shape(fn, *args), *args).jaxpr
+
+
+def _kept(jaxpr):
+    """``[(name or None, aval)]`` of the forward values the ``remat``
+    equations of ``jaxpr`` read. JAX puts a ``reduce_precision`` to the
+    value's own type behind a kept value that the forward uses too."""
+    named, arguments, kept = {}, set(jaxpr.invars), []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            named[eqn.outvars[0]] = eqn.params["name"]
+        elif eqn.primitive.name == "reduce_precision" and eqn.invars[0] in named:
+            named[eqn.outvars[0]] = named[eqn.invars[0]]
+        elif eqn.primitive.name == "remat2":
+            kept += [(named.get(v), v.aval) for v in eqn.invars
+                     if not isinstance(v, Literal) and v not in arguments]
+    return kept
+
+
+def _mosaic_calls(jaxpr):
+    calls: dict[str, int] = {}
+    for eqn in _walk(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            calls[name] = calls.get(name, 0) + 1
+    return calls
+
+
+def _twin_forward_scans(jaxpr):
+    """Forward passes of the scan's XLA twin: a ``lax.scan`` over chunks,
+    first chunk first, round a ``lax.scan`` over a chunk's tokens (the
+    backward walks the chunks in reverse)."""
+    return sum(1 for eqn in _walk(jaxpr) if eqn.primitive.name == "scan" and not eqn.params["reverse"]
+               and any(inner.primitive.name == "scan" for inner in _walk(eqn.params["jaxpr"].jaxpr)))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("kind", sorted(BLOCKS))
+def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(kind, remat, scan_kernels_interpreted):
+    fields, handed, names = BLOCKS[kind]
+    cls = nn.remat(Block, static_argnums=(2, 3), policy=KEPT) if remat else Block
+    block = cls(HEADS, dtype=jnp.bfloat16, **fields)
+    x = jnp.zeros((1, SEQ, D_MODEL), jnp.bfloat16)
+    shared = {None: (), "memory": (jnp.zeros((1, SEQ, 2 * D_MODEL), jnp.bfloat16),),
+              "kv": ((jnp.zeros((1, 2, SEQ, D_MODEL // HEADS), jnp.bfloat16),) * 2,)}[handed]
+    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, True, False, *shared))
+    jaxpr = _backward(lambda p, x, *shared: block.apply(p, x, True, False, *shared), params, x, *shared)
+    kept = _kept(jaxpr)
+    if not remat:  # no remat equation: the names are the identity and every residual is held
+        assert kept == [] and not any(eqn.primitive.name == "remat2" for eqn in _walk(jaxpr))
+        return
+    # everything the backward is handed besides the block's arguments has a name, and each name once
+    assert sorted(name or "unnamed" for name, _ in kept) == sorted(names)
+    shapes = {name: aval for name, aval in kept}
+    for name in names & {"mixer_out", "mlp_out"}:  # one d_model-wide row a token
+        assert shapes[name].shape == (1, SEQ, D_MODEL) and shapes[name].dtype == jnp.bfloat16
+    # nothing FFN-wide, and no float32 array but the flash rows' statistics
+    assert all(aval.shape[-1] != MLP_HIDDEN for _, aval in kept)
+    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse"}
+    # the second forward (inside the remat equation) holds no flash forward; the backward's kernels are there
+    second, = [eqn.params["jaxpr"] for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
+    inside = _mosaic_calls(second)
+    assert "flash_fwd" not in inside
+    if "flash_out" in names:
+        assert inside["flash_bwd_dq"] == inside["flash_bwd_dkv"] == 1 and _mosaic_calls(jaxpr)["flash_fwd"] == 1
+    if fields["layer_type"] == "mamba":  # the scan's results are not kept: its forward runs again
+        assert inside["selective_scan_fwd"] == inside["selective_scan_bwd"] == 1
+        assert _mosaic_calls(jaxpr)["selective_scan_fwd"] == 2
+
+
+TINY_LM = dict(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, dtype=jnp.bfloat16)
+HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
+FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
+               "gated_memory", "cross_attention")
+STEPS = {
+    "hybrid": dict(TINY_LM, **HYBRID, num_layers=4, layer_types=HYBRID_KINDS),
+    "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
+}
+
+
+def _lm_backward(model):
+    tokens = jnp.zeros((1, SEQ), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens))
+    return _backward(lambda p: model.apply(p, tokens, train=True, return_hidden=True), params)
+
+
+@pytest.mark.parametrize("route", ["kernels", "xla_twin"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("toy", sorted(STEPS))
+def test_a_steps_gradient_runs_each_forward_kernel_once_a_layer(toy, remat, route, request):
+    """With ``remat`` the parent ran every forward kernel twice a layer (the
+    step's forward and the block's second one); the kept results leave one
+    flash forward. The selective scan's forward and the gated delta rule's
+    forward kernels are not kept and still run twice
+    (``tests/test_olmo_hybrid.py::test_step_counts_the_rules_kernels``)."""
+    if route == "kernels":
+        request.getfixturevalue("scan_kernels_interpreted")
+    jaxpr = _lm_backward(TransformerLM(**{**STEPS[toy], "remat": remat}))
+    kinds = STEPS[toy]["layer_types"]
+    attention = sum(kind.endswith("_attention") and kind != "linear_attention" for kind in kinds)
+    calls = _mosaic_calls(jaxpr)
+    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == calls["flash_bwd_dkv"] == attention
+    mamba = kinds.count("mamba")
+    if route == "kernels":
+        assert calls.get("selective_scan_fwd", 0) == (1 + remat) * mamba and calls.get("selective_scan_bwd", 0) == mamba
+    else:
+        assert "selective_scan_fwd" not in calls and _twin_forward_scans(jaxpr) == (1 + remat) * mamba
+    kept = [name for name, _ in _kept(jaxpr)]
+    if remat:
+        expected = {"flash_out": attention, "flash_lse": attention, "mixer_out": len(kinds),
+                    "mlp_out": len(kinds) * (toy == "hybrid")}
+        assert {name: kept.count(name) for name in REMAT_KEEPS} == expected
+
+
+def test_a_routed_blocks_remat_keeps_the_flash_results_too():
+    model = TransformerLM(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, num_layers=1, moe_every=1, num_experts=4,
+                          moe_top_k=2, moe_expert_hidden=48, dtype=jnp.bfloat16, remat=True)
+    jaxpr = _lm_backward(model)
+    assert sorted(name for name, _ in _kept(jaxpr) if name) == ["flash_lse", "flash_out"]  # MoEBlock names no sublayer
+    assert _mosaic_calls(jaxpr)["flash_fwd"] == 1
+
+
+def test_keep_counts_what_it_names_and_refuses_other_names():
+    counter = REGISTRY.counter(COUNTER_TRAIN_REMAT_KEPT, labels=("what",))
+    before = {what: counter.value(what=what) for what in REMAT_KEEPS}
+    jaxpr = jax.make_jaxpr(lambda x: keep(keep(x, "mlp_out") * 2.0, "flash_out"))(jnp.ones((4,)))
+    assert [eqn.params["name"] for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "name"] == ["mlp_out", "flash_out"]
+    after = {what: counter.value(what=what) - before[what] for what in REMAT_KEEPS}
+    assert after == {what: int(what in ("mlp_out", "flash_out")) for what in REMAT_KEEPS}
+    with pytest.raises(ValueError, match="not a name remat keeps"):
+        keep(jnp.ones((4,)), "gate")
+    exposed = render_prometheus(REGISTRY).splitlines()  # what /metrics shows
+    assert any(line.startswith(COUNTER_TRAIN_REMAT_KEPT + "{") and 'what="mlp_out"' in line for line in exposed)
+    # lowers to nothing: the lowered text of a named value is that of the value
+    lowered = [jax.jit(f).lower(jnp.ones((4,))).as_text() for f in (lambda x: keep(x, "mlp_out") * 2.0, lambda x: x * 2.0)]
+    assert lowered[0].replace("jit__lambda_", "") == lowered[1].replace("jit__lambda_", "")

@@ -1,8 +1,11 @@
 """Transformer LM: shapes, training, and sequence-parallel equivalence."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from hops_tpu.models import common
 from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
@@ -58,11 +61,25 @@ def test_ring_impl_matches_reference_on_mesh():
     )
 
 
-def test_remat_matches_plain():
-    tokens = _tokens(seq=32)
-    plain = TransformerLM(**TINY, attention_impl="reference")
-    remat = TransformerLM(**TINY, attention_impl="reference", remat=True)
-    variables = plain.init(jax.random.PRNGKey(0), tokens)
+@pytest.mark.parametrize("impl,seq", [("reference", 32), ("flash", 128)])
+def test_remat_matches_plain(impl, seq, flash_kernel_at_any_length):
+    """Logits, loss and every gradient: a value remat keeps by name
+    (``telemetry.spans.REMAT_KEEPS``) is the one its second forward would
+    have made; ``tests/test_remat_keeps.py`` reads which are kept."""
+    tokens = _tokens(seq=seq + 1)
+    plain = TransformerLM(**TINY, attention_impl=impl)
+    remat = TransformerLM(**TINY, attention_impl=impl, remat=True)
+    variables = plain.init(jax.random.PRNGKey(0), tokens[:, :-1])
     np.testing.assert_allclose(
-        plain.apply(variables, tokens), remat.apply(variables, tokens), atol=1e-5
+        plain.apply(variables, tokens[:, :-1]), remat.apply(variables, tokens[:, :-1]), atol=1e-5
     )
+
+    def loss(model, params):
+        logp = jax.nn.log_softmax(model.apply({"params": params}, tokens[:, :-1], train=True))
+        return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
+
+    want, want_grad = jax.value_and_grad(functools.partial(loss, plain))(variables["params"])
+    got, got_grad = jax.value_and_grad(functools.partial(loss, remat))(variables["params"])
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-7, err_msg=jax.tree_util.keystr(path))
