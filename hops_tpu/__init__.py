@@ -32,9 +32,12 @@ Distribution is SPMD over ``jax.sharding.Mesh`` with XLA collectives over
 ICI/DCN — no Spark, no NCCL, no JVM.
 """
 
+from hops_tpu import _startup  # first: the clock the rest of the import is timed on
+
 __version__ = "0.1.0"
 
-from hops_tpu.runtime import config, devices, fs, rundir  # noqa: F401
+with _startup.importing("hops_tpu"):
+    from hops_tpu.runtime import config, devices, fs, rundir  # noqa: F401
 
 __all__ = [
     "__version__",

@@ -36,7 +36,7 @@ from hops_tpu.parallel.strategy import (
 )
 from hops_tpu.runtime import rundir
 from hops_tpu.runtime.logging import attach_run_log, detach_run_log, get_logger, scalarize
-from hops_tpu.telemetry import tracing
+from hops_tpu.telemetry import spans, tracing
 from hops_tpu.telemetry.metrics import REGISTRY
 
 log = get_logger(__name__)
@@ -83,14 +83,21 @@ def _run_wrapper(
     strategy: Strategy | None,
 ) -> tuple[str, dict[str, Any]]:
     """Shared launcher mechanics for all experiment kinds."""
-    run = rundir.new_run(name=name, local_logdir=local_logdir)
-    chief = multihost.is_chief()
     # One trace per run, as a served request has one: the wrapper's
     # Strategy spans (input placement, step dispatch) are its children,
     # and the id in the registry record finds them at
     # GET /debug/traces/<id>. A no-op span (id None) with tracing off.
     root = tracing.start_trace("experiment.run", kind=kind, name=name)
     trace_id = root.trace_id or None
+    entered = time.time()
+    process = tracing.process_root()
+    if process is not None and spans.first_in_process("prelaunch"):
+        # what the process did before it came here, once: the interpreter,
+        # the imports, reaching the chip, the caller's own loading
+        tracing.record_span(spans.SPAN_STARTUP_PRELAUNCH, process,
+                            process.start, entered - process.start)
+    run = rundir.new_run(name=name, local_logdir=local_logdir)
+    chief = multihost.is_chief()
     if chief:
         registry.register(
             {"run_id": run.run_id, "name": name, "kind": kind,
@@ -107,6 +114,8 @@ def _run_wrapper(
             with contextlib.redirect_stdout(tee_out):
                 ctx = strategy.scope() if strategy is not None else contextlib.nullcontext()
                 with ctx, root:
+                    tracing.record_span(spans.SPAN_STARTUP_LAUNCH, tracing.current_span(),
+                                        entered, time.time() - entered)
                     result = fn(**kwargs) if kwargs else fn()
             metrics = _normalize_metrics(result, metric_key)
         except Exception as e:  # noqa: BLE001 — failures must land in the registry

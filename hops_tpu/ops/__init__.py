@@ -29,18 +29,21 @@ Every kernel ships with a pure-XLA reference twin used for (a) numeric
 tests, (b) non-TPU backends, (c) shapes the kernel doesn't support.
 """
 
-from hops_tpu.ops.attention import (  # noqa: F401
-    attention_reference,
-    decode_attention,
-    decode_attention_q8,
-    decode_attention_reference,
-    dequantize_kv,
-    flash_attention,
-    paged_decode_attention,
-    paged_decode_attention_reference,
-    paged_gather_kv,
-    quantize_kv,
-    repeat_kv,
-)
-from hops_tpu.ops.gated_delta import gated_delta_rule  # noqa: F401
-from hops_tpu.ops.xent import chunked_softmax_xent  # noqa: F401
+from hops_tpu import _startup
+
+with _startup.importing("hops_tpu.ops"):
+    from hops_tpu.ops.attention import (  # noqa: F401
+        attention_reference,
+        decode_attention,
+        decode_attention_q8,
+        decode_attention_reference,
+        dequantize_kv,
+        flash_attention,
+        paged_decode_attention,
+        paged_decode_attention_reference,
+        paged_gather_kv,
+        quantize_kv,
+        repeat_kv,
+    )
+    from hops_tpu.ops.gated_delta import gated_delta_rule  # noqa: F401
+    from hops_tpu.ops.xent import chunked_softmax_xent  # noqa: F401

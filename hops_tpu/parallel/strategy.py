@@ -24,17 +24,21 @@ from __future__ import annotations
 
 import contextlib
 import math
+import time
 from typing import Any, Callable, Iterator
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from hops_tpu import _startup
 from hops_tpu.parallel import mesh as mesh_lib
 from hops_tpu.telemetry import tracing
 from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import (
+    GAUGE_STARTUP_FIRST_STEP,
     SPAN_TRAIN_DISPATCH,
     SPAN_TRAIN_INPUT_PUT,
+    first_in_process,
     span,
 )
 
@@ -48,6 +52,10 @@ _IMPLICIT_MODE = "implicit"
 _m_input_bytes = REGISTRY.counter(
     "hops_tpu_train_input_bytes_total",
     "Bytes of batch data Strategy.distribute_batch placed on the mesh",
+)
+_m_first_step = REGISTRY.gauge(
+    GAUGE_STARTUP_FIRST_STEP,
+    "Seconds from the start of the process to the return of its first Strategy.step call",
 )
 
 
@@ -69,7 +77,10 @@ class _TracedStep:
         self._calls += 1
         with span(SPAN_TRAIN_DISPATCH, mode=self._mode):
             tracing.annotate(step=index)
-            return self._compiled(*args, **kwargs)
+            out = self._compiled(*args, **kwargs)
+        if index == 0 and first_in_process("first_step"):
+            _m_first_step.set(time.time() - _startup.process_start())
+        return out
 
     def __getattr__(self, name: str) -> Any:
         if name == "_compiled":  # not set yet (copy/pickle probe a bare instance)

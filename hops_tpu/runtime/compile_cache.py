@@ -10,6 +10,16 @@ this module sets no directory in code; otherwise the cache lives at the
 fixed ``<checkout>/.jax_cache``. The path is part of what makes an
 entry findable again, so there are no temp names, pids or timestamps
 anywhere in it.
+
+The same call starts listening to JAX's own monitoring events, and
+:func:`listen` does that alone (the tests, on the CPU): cache requests,
+hits and writes are counted for :func:`stats`, and every compile event
+(``trace``, ``lower``, ``backend``) feeds
+``hops_tpu_compile_seconds{phase}`` and
+``hops_tpu_compiles_total{phase, cache}`` and, when it is long enough to
+matter, becomes a ``hops_tpu_compile`` span in the trace ring with JAX's
+own start and duration (``telemetry/spans.py``, the start-up
+vocabulary).
 """
 
 from __future__ import annotations
@@ -19,6 +29,15 @@ import threading
 from pathlib import Path
 
 import jax
+
+from hops_tpu.telemetry import tracing
+from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import (
+    COMPILE_SPAN_MIN_S,
+    COUNTER_COMPILES,
+    HIST_COMPILE_SECONDS,
+    SPAN_COMPILE,
+)
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
@@ -32,12 +51,66 @@ _counts = dict.fromkeys(_EVENTS.values(), 0)  # guarded by: _lock
 _lock = threading.Lock()
 _listening = False  # guarded by: _lock
 
+#: JAX's compile events (``jax/_src/dispatch.py``), fired on the thread
+#: that compiles, as ``(event, start, end, fun_name=)`` on ``time.time()``.
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+PHASES = tuple(_PHASES.values())
+#: What the cache did for the backend compile under way on this thread:
+#: its hit event, or the write that follows a miss, fires inside the
+#: backend event's interval; neither means no cache took part.
+_cache_seen = threading.local()
+
+_m_compile_seconds = REGISTRY.histogram(
+    HIST_COMPILE_SECONDS, "Duration of JAX compile events by phase (trace, lower, backend)",
+    labels=("phase",))
+_m_compiles = REGISTRY.counter(
+    COUNTER_COMPILES,
+    "JAX compile events by phase and, for backend, what the persistent cache did (hit, miss, off)",
+    labels=("phase", "cache"))
+
 
 def _on_event(event: str, **_: object) -> None:
     name = _EVENTS.get(event)
     if name is not None:
         with _lock:
             _counts[name] += 1
+        if name != "requests":
+            _cache_seen.outcome = "hit" if name == "hits" else "miss"
+
+
+def _on_compile_span(event: str, start_time: float, end_time: float,
+                     fun_name: str = "", **_: object) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    duration = end_time - start_time
+    cache = _cache_seen.__dict__.pop("outcome", "off") if phase == "backend" else ""
+    _m_compile_seconds.observe(duration, phase=phase)
+    _m_compiles.inc(phase=phase, cache=cache)
+    if duration >= COMPILE_SPAN_MIN_S or cache == "miss":
+        parent = tracing.current_span() or tracing.process_root()
+        tracing.record_span(SPAN_COMPILE, parent, start_time, duration, phase=phase, fun_name=fun_name,
+                            **({"cache": cache} if cache else {}))
+
+
+def listen() -> None:
+    """Start listening to JAX's cache and compile events. Idempotent."""
+    global _listening
+    with _lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_time_span_listener(_on_compile_span)
+            _listening = True
+
+
+def compile_seconds() -> dict[str, float]:
+    """Seconds of compile events by phase since :func:`listen`, short
+    events included (the histogram's sums)."""
+    return {phase: _m_compile_seconds.labels(phase=phase).sum for phase in PHASES}
 
 
 def cache_dir() -> str:
@@ -49,14 +122,13 @@ def cache_dir() -> str:
 def enable() -> str | None:
     """Turn the persistent compile cache on and return its directory.
 
-    Idempotent, and never touches the backend. Also starts counting
-    JAX's own cache events so :func:`stats` can say whether a run
-    compiled or loaded. A process pinned to the CPU
+    Idempotent, and never touches the backend. Also starts listening to
+    JAX's own cache and compile events (:func:`listen`), so :func:`stats`
+    can say whether a run compiled or loaded. A process pinned to the CPU
     (``JAX_PLATFORMS=cpu``: the tests, ``--smoke``) caches nothing and
     gets ``None`` — XLA:CPU executables are not portable between the
     machines a checkout visits.
     """
-    global _listening
     if jax.config.jax_platforms == "cpu":
         return None
     if not os.environ.get(ENV_VAR):
@@ -72,10 +144,7 @@ def enable() -> str | None:
     # metadata in the key, an edit that moves traced lines costs one
     # compile; the names in a trace are always the running source's.
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    with _lock:
-        if not _listening:
-            jax.monitoring.register_event_listener(_on_event)
-            _listening = True
+    listen()
     return cache_dir()
 
 

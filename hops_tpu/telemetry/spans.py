@@ -21,9 +21,11 @@ import contextlib
 import functools
 import re
 import sys
+import threading
 import time
 from typing import Any, Callable, Iterator
 
+from hops_tpu import _startup
 from hops_tpu.telemetry.metrics import DEFAULT_BUCKETS, REGISTRY, Registry
 from hops_tpu.telemetry import tracing
 
@@ -81,6 +83,45 @@ SCOPE_DIFF_ATTN = "diff_attn"
 #: children of the launcher's ``experiment.run`` root span.
 SPAN_TRAIN_INPUT_PUT = "hops_tpu_train_input_put"
 SPAN_TRAIN_DISPATCH = "hops_tpu_train_dispatch"
+
+#: The start-up vocabulary: what a process spends between the kernel
+#: starting it and its first training step, on the span ring's clock.
+#: Readers outside the program (the benchmark's
+#: ``harness/startup_spans.py``) repeat these strings.
+#:
+#: ``hops_tpu_compile`` is one JAX compile event, recorded with JAX's own
+#: start and duration by the listener of ``runtime/compile_cache.py``:
+#: ``phase`` = ``trace`` (Python to jaxpr) | ``lower`` (jaxpr to MLIR) |
+#: ``backend`` (XLA compiling, or the persistent cache reading and
+#: loading an executable), ``fun_name``, and on ``backend`` ``cache`` =
+#: ``hit`` | ``miss`` | ``off``. A child of the calling context's active
+#: span (a step's dispatch span, the launcher's root) or, without one,
+#: of the process root. Every event feeds the histogram and the counter;
+#: only one of ``COMPILE_SPAN_MIN_S`` or longer, or a cache miss, becomes
+#: a span, so the eager operations of a set-up do not crowd a window's
+#: dispatch spans out of the ring.
+SPAN_PROCESS = tracing.PROCESS_ROOT
+SPAN_COMPILE = "hops_tpu_compile"
+HIST_COMPILE_SECONDS = "hops_tpu_compile_seconds"
+COUNTER_COMPILES = "hops_tpu_compiles_total"
+#: 0.5 ms: 340-860 spans a set-up, 0.14-0.37 s of events left out (PERF.md section 5).
+COMPILE_SPAN_MIN_S = 0.0005
+#: One import of a package that wraps its import block in
+#: ``_startup.importing`` (``package``); a nested import is a child of
+#: the import that caused it, the outermost of the process root.
+SPAN_STARTUP_IMPORT = "hops_tpu_startup_import"
+#: Process start to the first launcher entry of the process (recorded
+#: then, once): the interpreter, every import, reaching the chip, the
+#: caller's own loading. Under the process root.
+SPAN_STARTUP_PRELAUNCH = "hops_tpu_startup_prelaunch"
+#: Launcher entry to the call of the wrapper function, once a run: run
+#: directory, registry record, log handler, strategy scope. Under the
+#: run's ``experiment.run`` root.
+SPAN_STARTUP_LAUNCH = "hops_tpu_startup_launch"
+#: Process start to the return of the process's first ``Strategy.step``
+#: call (the step is dispatched, not finished): what a start costs an
+#: operator.
+GAUGE_STARTUP_FIRST_STEP = "hops_tpu_startup_first_step_seconds"
 
 #: Trace-time counters of the training vocabulary: each says which form of
 #: an op a compiled step holds, and is added to while the step is traced.
@@ -144,6 +185,37 @@ def keep(x: Any, what: str) -> Any:
         raise ValueError(f"{what!r} is not a name remat keeps (one of {REMAT_KEEPS})")
     _m_remat_kept.inc(what=what)
     return checkpoint_name(x, what)
+
+
+_happened: set[str] = set()  # guarded by: _happened_lock
+_happened_lock = threading.Lock()
+
+
+def first_in_process(what: str) -> bool:
+    """True for the first caller in this process to ask about ``what``
+    (``prelaunch``, ``first_step``), False ever after."""
+    with _happened_lock:
+        if what in _happened:
+            return False
+        _happened.add(what)
+        return True
+
+
+def _record_import(imp: _startup.importing) -> None:
+    root = tracing.process_root()
+    if root is None:
+        return
+    parent = root.context if imp.parent is None else tracing.TraceContext(
+        root.trace_id, imp.parent.span_id, root.sampled)
+    tracing.record_span(SPAN_STARTUP_IMPORT, parent, imp.start, imp.end - imp.start,
+                        span_id=imp.span_id, package=imp.package)
+
+
+# From here on an import goes to the ring as it ends; first, those that
+# ended while this module was not yet importable.
+_startup.sink = _record_import
+while _startup.pending:
+    _record_import(_startup.pending.pop(0))
 
 
 def _sanitize(name: str) -> str:

@@ -54,6 +54,7 @@ import threading
 import time
 from typing import Any, Iterator
 
+from hops_tpu import _startup
 from hops_tpu.telemetry.metrics import REGISTRY
 
 TRACEPARENT_HEADER = "traceparent"
@@ -328,7 +329,7 @@ def _env_float(name: str, default: float) -> float:
 
 
 #: Module-level fast path: every entry point checks this one bool first.
-_ENABLED = os.environ.get("HOPS_TPU_TRACING", "1") not in ("0", "false", "")
+_ENABLED = _startup.TRACING_AT_START
 
 #: The process-global tracer (ring + sampling decision).
 TRACER = Tracer(
@@ -366,6 +367,38 @@ def configure(
 
 def enabled() -> bool:
     return _ENABLED
+
+
+#: Name of the one root span a process has for what happens outside any
+#: request or run (:func:`process_root`).
+PROCESS_ROOT = "hops_tpu_process"
+
+_process_root: Span | None = None  # guarded by: _process_root_lock
+_process_root_lock = threading.Lock()
+
+
+def process_root() -> Span | None:
+    """The span that work outside any request or run hangs under: the
+    imports, compiles before a launcher is entered, the time before the
+    first launch. One per process, a trace of its own (``GET
+    /debug/traces/<id>``), started when the kernel started the process
+    and stored in the ring unfinished when first asked for (``duration``
+    None: the process is still running). It is a parent to hand to
+    :func:`record_span`, never the active context, so code outside a
+    request still finds no span to join. None when tracing is disabled."""
+    global _process_root
+    if not _ENABLED:
+        return None
+    with _process_root_lock:  # asked for a few hundred times a start, never in a step
+        if _process_root is None:
+            sampled = TRACER._sample()
+            root = Span(None, PROCESS_ROOT, new_trace_id(), None, sampled=sampled,
+                        attrs={"pid": os.getpid()}, recorded=False)
+            root.start = _startup.process_start()
+            if sampled:
+                TRACER._store(root)
+            _process_root = root
+        return _process_root
 
 
 # -- the instrumentation surface ----------------------------------------------

@@ -49,7 +49,8 @@ def _fake_jax(platforms):
             update=lambda key, value: updates.__setitem__(key, value),
         ),
         monitoring=types.SimpleNamespace(
-            register_event_listener=listeners.append),
+            register_event_listener=listeners.append,
+            register_event_time_span_listener=listeners.append),
     )
     return fake, updates, listeners
 

@@ -248,7 +248,8 @@ def test_step_spans_are_children_of_the_launchers_root(fresh_ring, n_dev, path):
     with mesh_lib.device_scope(devices):
         launcher = experiment.mirrored if path != "sharded" else experiment.launch
         launcher(train_fn, name=f"traced_{path}")
-    spans = fresh_ring.spans()
+    # the process's own trace holds what came before the launcher (telemetry/spans.py, the start-up vocabulary)
+    spans = [s for s in fresh_ring.spans() if s.trace_id != tracing.process_root().trace_id]
     root = [s for s in spans if s.name == "experiment.run"]
     assert len(root) == 1 and root[0].parent_id is None
     assert root[0].attrs["name"] == f"traced_{path}"
@@ -320,5 +321,9 @@ def test_distribute_batch_counts_the_bytes_it_placed(n_dev):
 
 
 def test_the_ring_holds_a_benchmark_window_of_the_fastest_cell():
-    # ResNet-50: ~230 steps of a 10 s window + ~65 traced + warm-up, two spans each, and the root
-    assert tracing.Tracer().ring_size == tracing.DEFAULT_RING_SIZE >= 2 * 2 * 300 + 1
+    # ResNet-50: ~230 steps of a 10 s window + ~65 traced + warm-up, two spans each, and the root;
+    # before them the set-up's own spans (the process root, three imports, prelaunch, launch, and a
+    # compile span per JAX event of 0.5 ms or longer: 337-856 a start over the cells, PERF.md section 5),
+    # which must not push the window's first dispatch span off the ring before the readers run
+    setup_spans = 1_500
+    assert tracing.Tracer().ring_size == tracing.DEFAULT_RING_SIZE >= 2 * 2 * 300 + 1 + setup_spans
