@@ -17,6 +17,7 @@ import optax
 from flax import linen as nn
 from flax.training import train_state
 
+from hops_tpu.parallel import mesh as mesh_lib
 from hops_tpu.telemetry.spans import SCOPE_OPTIMIZER
 
 
@@ -92,6 +93,10 @@ def make_train_step(
                     params, grad_comms, axis_name,
                     meta=getattr(state, "meta", None),
                 )
+            else:
+                # Strategy.step's default path on several devices keeps
+                # the large leaves split: their compute copy, gathered
+                params = mesh_lib.gathered(params)
             variables = {"params": params}
             if has_bn:
                 variables["batch_stats"] = state.batch_stats

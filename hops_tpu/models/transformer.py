@@ -931,6 +931,7 @@ def make_lm_train_step(
     import optax
 
     from hops_tpu.models.moe import max_load_over_mean, sum_sown_losses
+    from hops_tpu.parallel.mesh import gathered
     from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
 
     def train_step(state, batch):
@@ -939,6 +940,7 @@ def make_lm_train_step(
         step_rng = jax.random.fold_in(state.rng, state.step)
 
         def compute_loss(params):
+            params = gathered(params)
             out, mods = state.apply_fn(
                 {"params": params},
                 inputs,

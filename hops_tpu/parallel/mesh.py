@@ -17,6 +17,7 @@ intra-host ICI links and collectives ride ICI, not DCN.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import threading
 from typing import Any, Mapping, Sequence
@@ -25,8 +26,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from hops_tpu.parallel import sharding as shard_lib
 from hops_tpu.runtime import devices as rt_devices
 from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry.spans import SCOPE_GRAD_EXCHANGE
 
 # Sub-slice scoping: the trial driver partitions the slice into disjoint
 # device groups (1 chip, 2 chips, 2x2, ...) and enters a device_scope
@@ -223,6 +226,83 @@ def per_shard(fn: Any, *, op: str = "unnamed", replicated: Sequence[int] = ()) -
     return sharded
 
 
+#: Elements below which a train-state leaf stays whole on every device
+#: of the data axis. Splitting a leaf n ways saves (n - 1) / n of its
+#: update (28 B an element under Adam: ~26 ps an element at a v5e's 819
+#: GB/s) and turns its gradient's all-reduce into a reduce-scatter plus
+#: a gather of the compute copy: the same bytes over the links, one
+#: collective more. A collective costs some microseconds before its
+#: first byte, so under ~10^5 elements the extra one costs more than the
+#: update saves. Reckoned, not measured: every leaf of the LM cells is
+#: far above it or (norm scales: one dimension) never split.
+MIN_SPLIT_SIZE = 1 << 18
+
+
+def state_sharding(mesh: Mesh, axis: str | tuple[str, ...], leaf: Any) -> NamedSharding:
+    """How ``Strategy.step``'s default path keeps one leaf of a train
+    state between steps: split over the data axis along the dimension
+    ``sharding.split_dim`` picks, where it picks one (a leaf of 2 or more
+    dims and :data:`MIN_SPLIT_SIZE` elements with a dimension the axis
+    divides), else whole on every device."""
+    axes = axis if isinstance(axis, tuple) else (axis,)
+    shape = np.shape(leaf)
+    dim = shard_lib.split_dim(
+        shape, math.prod(mesh.shape[a] for a in axes), MIN_SPLIT_SIZE)
+    if dim is None:
+        return replicated(mesh)
+    return NamedSharding(mesh, P(*[None] * dim, axis, *[None] * (len(shape) - dim - 1)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _compute_copy(x: jax.Array, whole: NamedSharding, split: NamedSharding) -> jax.Array:
+    """``x`` whole on every device; its cotangent in the ``split`` layout."""
+    return jax.lax.with_sharding_constraint(x, whole)
+
+
+def _compute_copy_fwd(x, whole, split):
+    return _compute_copy(x, whole, split), None
+
+
+def _compute_copy_bwd(whole, split, _, g):
+    # A replicated constraint's own transpose would all-reduce the
+    # gradient to every device; only the owner of a part updates it.
+    with jax.named_scope(SCOPE_GRAD_EXCHANGE):
+        return (jax.lax.with_sharding_constraint(g, split),)
+
+
+_compute_copy.defvjp(_compute_copy_fwd, _compute_copy_bwd)
+
+
+def gathered(params: Any) -> Any:
+    """The compute copy of a parameter tree whose large leaves
+    ``Strategy.step``'s default path keeps split over the data axis
+    (:func:`state_sharding`): each such leaf pinned whole where the
+    forward reads it (XLA:TPU moves a narrowing cast ahead of the
+    gather, so a bf16 module gathers bf16) and its cotangent pinned to
+    the leaf's own split layout, so the partitioner sums a gradient to
+    its owner only: a reduce-scatter where the partial gradients are
+    made, in the dtype they are made in, instead of an all-reduce and a
+    slice. Without the pins the partitioner chooses by size, and at toy
+    sizes it gathers activations instead. Traced under the
+    ``grad_exchange`` scope. The identity outside a
+    :func:`gspmd_data_parallel` region and on a data axis of one
+    device."""
+    region = getattr(_gspmd, "region", None)
+    if region is None:
+        return params
+    mesh, axis = region
+    whole = replicated(mesh)
+
+    def pin(leaf):
+        split = state_sharding(mesh, axis, leaf)
+        if split.is_fully_replicated:
+            return leaf
+        with jax.named_scope(SCOPE_GRAD_EXCHANGE):
+            return _compute_copy(leaf, whole, split)
+
+    return jax.tree.map(pin, params)
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
@@ -266,6 +346,27 @@ def shard_batch(mesh: Mesh, batch: Any, axis: str | tuple[str, ...] = "data") ->
     return jax.tree.map(_place, batch)
 
 
+def same_values(tree: Any) -> Any:
+    """The identity, to ``jax.jit`` with ``out_shardings``: a program that
+    moves arrays between layouts on the devices they are on. Between a
+    whole copy and a split one ``jax.device_put`` goes through the host
+    when no device holds the wanted slice as a buffer of its own (7.8 GB
+    on a four-chip v5e host: 2.7 s to split, 12.8 s to gather, against
+    14 ms for the program; PERF.md section 6, PR 34)."""
+    return tree
+
+
 def replicate(mesh: Mesh, tree: Any) -> Any:
-    """Replicate a pytree (params/opt state) across the mesh."""
-    return jax.device_put(tree, replicated(mesh))
+    """Replicate a pytree (params/opt state) across the mesh. Leaves that
+    are split over the mesh's devices (a state ``Strategy.step`` returned)
+    are gathered by a program (:func:`same_values`), the rest placed."""
+    rep = replicated(mesh)
+    leaves, structure = jax.tree.flatten(tree)
+    devices = set(mesh.devices.flat)
+    split = [i for i, x in enumerate(leaves) if isinstance(x, jax.Array)
+             and not x.sharding.is_fully_replicated and x.sharding.device_set == devices]
+    if split:
+        whole = jax.jit(same_values, out_shardings=rep)([leaves[i] for i in split])
+        for i, x in zip(split, whole):
+            leaves[i] = x
+    return jax.device_put(jax.tree.unflatten(structure, leaves), rep)

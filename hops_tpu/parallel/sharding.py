@@ -9,7 +9,8 @@ flax param pytree, used by ``ShardedStrategy`` and the multichip dryrun.
 
 from __future__ import annotations
 
-from typing import Any
+import math
+from typing import Any, Collection, Sequence
 
 import jax
 import numpy as np
@@ -44,6 +45,25 @@ def infer_param_spec(
         return P(*spec)
 
     return jax.tree.map(spec_for, params)
+
+
+def split_dim(
+    shape: Sequence[int], parts: int, min_size: int, taken: Collection[int] = ()
+) -> int | None:
+    """The dimension along which a state leaf of ``shape`` is split
+    ``parts`` ways (ZeRO-style: ``ShardedStrategy``'s ``fsdp`` axis, the
+    data axis of ``Strategy.step``'s default path), or None where it
+    stays whole: a leaf of fewer than 2 dims or ``min_size`` elements,
+    or with no dimension outside ``taken`` (those another axis already
+    splits) that ``parts`` divides. The leading dimension where it
+    qualifies (a gather along it concatenates whole rows), else the
+    largest that does."""
+    if parts == 1 or len(shape) < 2 or math.prod(shape) < min_size:
+        return None
+    free = [d for d in range(len(shape)) if d not in taken and shape[d] % parts == 0]
+    if not free:
+        return None
+    return 0 if free[0] == 0 else max(free, key=lambda d: shape[d])
 
 
 def shard_params(mesh: Mesh, params: Any, axis: str = "model", min_size: int = 4096) -> Any:

@@ -5,9 +5,13 @@ Mirrors the ergonomics the reference exposed through
 inside ``experiment.mirrored`` wrapper functions (reference:
 mirroredstrategy_mnist_example.ipynb:125-131,
 multiworkermirroredstrategy_mnist_example.ipynb:137-141; SURVEY.md
-§2.9), but lowers to pjit-style sharded ``jax.jit`` over a Mesh: params
-replicated, batch sharded on the ``data`` axis, gradient AllReduce
-emitted by XLA over ICI — no NCCL, no TF_CONFIG, no cluster spec.
+§2.9), but lowers to pjit-style sharded ``jax.jit`` over a Mesh: batch
+sharded on the ``data`` axis, gradient collectives emitted by XLA over
+ICI — no NCCL, no TF_CONFIG, no cluster spec. On a data axis of one
+device the state is whole; on more, the step keeps its large leaves
+(float32 masters, optimizer moments) split over the axis between steps
+(:meth:`Strategy.step`), and :meth:`Strategy.replicate` gives whole
+copies back.
 
 Typical wrapper-function use::
 
@@ -25,7 +29,7 @@ from __future__ import annotations
 import contextlib
 import math
 import time
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterator, NamedTuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -36,6 +40,7 @@ from hops_tpu.telemetry import tracing
 from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import (
     GAUGE_STARTUP_FIRST_STEP,
+    GAUGE_TRAIN_STATE_BYTES,
     SPAN_TRAIN_DISPATCH,
     SPAN_TRAIN_INPUT_PUT,
     first_in_process,
@@ -57,6 +62,38 @@ _m_first_step = REGISTRY.gauge(
     GAUGE_STARTUP_FIRST_STEP,
     "Seconds from the start of the process to the return of its first Strategy.step call",
 )
+
+_m_state_bytes = REGISTRY.gauge(
+    GAUGE_TRAIN_STATE_BYTES,
+    "Bytes of the train state's leaves by how Strategy.step's default path lays them out "
+    "across the data axis: a device holds 1/n of 'split', all of 'whole'",
+    labels=("placement",),
+)
+
+
+def _set_state_bytes(leaves: list, shardings: list) -> None:
+    placed = {"split": 0, "whole": 0}
+    for leaf, sharding in zip(leaves, shardings):
+        nbytes = math.prod(jax.numpy.shape(leaf)) * jax.numpy.result_type(leaf).itemsize
+        placed["whole" if sharding.is_fully_replicated else "split"] += nbytes
+    for placement, nbytes in placed.items():
+        _m_state_bytes.set(nbytes, placement=placement)
+
+
+def _signature(state: Any) -> tuple[list, Any]:
+    """A state's leaves, and what the lazily derived steps memoise on:
+    its tree structure and its leaves' shapes."""
+    leaves, structure = jax.tree.flatten(state)
+    return leaves, (structure, tuple(jax.numpy.shape(x) for x in leaves))
+
+
+class _SplitStateProgram(NamedTuple):
+    """What ``Strategy._split_state_step`` compiles per state signature."""
+
+    step: Callable[..., Any]  # the jitted step, the state in ``layout`` in and out
+    lay_out: Callable[..., Any]  # a state in any layout -> the same values in ``layout``
+    layout: Any  # the state's tree of NamedShardings
+    shardings: list  # its leaves
 
 
 class _TracedStep:
@@ -128,6 +165,13 @@ class Strategy:
     # -- placement ------------------------------------------------------------
 
     def replicate(self, tree: Any) -> Any:
+        """Whole copies of every leaf on every device of the mesh: how a
+        fresh state enters :meth:`step`, and the way back from the split
+        layout a stepped state has on more than one device (split
+        leaves are gathered by a program, on the devices). On one host a
+        split state is fully addressable and ``np.asarray`` /
+        ``jax.device_get`` / pickling read it as it is; across hosts call
+        this first (a whole copy is addressable everywhere)."""
         return mesh_lib.replicate(self.mesh, tree)
 
     def distribute_batch(self, batch: Any) -> Any:
@@ -147,11 +191,34 @@ class Strategy:
         donate_state: bool = True,
         grad_comms: "Any | None" = None,
     ) -> Callable[..., Any]:
-        """Compile ``fn(state, batch) -> (state, aux)`` as one SPMD step:
-        state replicated, batch sharded.
+        """Compile ``fn(state, batch) -> (state, aux)`` as one SPMD step
+        over the batch sharded on the data axis.
 
-        Default path: XLA inserts the gradient collectives. With a
-        ``grad_comms.GradCommsConfig`` (argument here or on the
+        Default path: XLA inserts the gradient collectives. On a data
+        axis of one device the state is whole and the program is what it
+        always was. On more, the state's large leaves live split over
+        the axis between steps, one part a device (:meth:`state_layout`:
+        leaves of two or more dims and ``mesh.MIN_SPLIT_SIZE`` elements,
+        so the float32 masters and the optimizer's moments; norm scales,
+        ``step``, ``count``, ``rng``, BatchNorm statistics stay whole):
+        the step gathers the compute copy of a weight where the model
+        casts it, sums each gradient to its owner only (a reduce-scatter,
+        in the dtype it was all-reduced in), runs the optimizer on the
+        owned part and gathers nothing at its end (arXiv:2004.13336).
+        ``models.common.make_train_step`` and
+        ``models.transformer.make_lm_train_step`` pin that schedule
+        (``mesh.gathered``); another ``fn`` is partitioned as XLA sees
+        fit, correctly either way. The layout is derived from the first
+        state seen, per structure, and reported by the gauge
+        ``hops_tpu_train_state_bytes{placement="split"|"whole"}``. The
+        state may come in whole (:meth:`replicate`): it is laid out on
+        entry, once, and the same executable serves every later call;
+        what comes back is split. :meth:`replicate` gives whole copies
+        back (a multi-host checkpoint or export calls it first; on one
+        host the split arrays are fully addressable and read as they
+        are). The returned callable keeps ``.lower(state, batch)``.
+
+        With a ``grad_comms.GradCommsConfig`` (argument here or on the
         strategy), ``fn`` instead runs inside ``shard_map`` over the
         data axis and must do its own cross-replica reduction — build it
         with ``models.common.make_train_step(grad_comms=cfg)``, which
@@ -240,15 +307,92 @@ class Strategy:
                 with mesh_lib.gspmd_data_parallel(self.mesh, self.data_axis):
                     return fn(state, batch)
 
-            compiled = jax.jit(
-                partitioned,
-                in_shardings=(rep, data),
-                out_shardings=(rep, rep),
-                donate_argnums=donate,
-            )
+            if self.num_replicas_in_sync == 1:
+                compiled = jax.jit(
+                    partitioned,
+                    in_shardings=(rep, data),
+                    out_shardings=(rep, rep),
+                    donate_argnums=donate,
+                )
+            else:
+                compiled = self._split_state_step(partitioned, donate, rep, data)
             mode = _IMPLICIT_MODE
         stepped = self._step_cache[key] = _TracedStep(compiled, mode)
         return stepped
+
+    def state_layout(self, state: Any) -> Any:
+        """The shardings :meth:`step`'s default path keeps ``state`` in
+        between steps, leaf by leaf: split over the data axis along the
+        dimension ``sharding.split_dim`` picks where it picks one (the
+        large leaves: float32 masters and the optimizer's moments, which
+        have their shapes), whole on every device otherwise (norm
+        scales, biases, ``step``, ``count``, ``rng``, BatchNorm
+        statistics). On a data axis of one device everything is whole."""
+        return jax.tree.map(
+            lambda leaf: mesh_lib.state_sharding(self.mesh, self.data_axis, leaf), state)
+
+    def _split_state_step(
+        self,
+        fn: Callable[..., Any],
+        donate: tuple,
+        rep: NamedSharding,
+        data: NamedSharding,
+    ) -> Callable[..., Any]:
+        """The default path on a data axis of more than one device: one
+        ``jax.jit`` per state structure whose ``in_shardings`` and
+        ``out_shardings`` for the state are :meth:`state_layout` of the
+        first state seen. A state that arrives in another layout (whole
+        copies from :meth:`replicate`, a restored checkpoint) is laid
+        out on entry by a second, trivial program (each device slices
+        its own copy) and, where the step donates its state, deleted
+        there; the same step executable serves it and every later,
+        split, state."""
+        programs: dict[Any, _SplitStateProgram] = {}
+
+        def program_for(state) -> tuple[_SplitStateProgram, list]:
+            leaves, key = _signature(state)
+            program = programs.get(key)
+            if program is None:
+                layout = self.state_layout(state)
+                shardings = jax.tree.leaves(layout)
+                _set_state_bytes(leaves, shardings)
+                program = programs[key] = _SplitStateProgram(
+                    jax.jit(
+                        fn,
+                        in_shardings=(layout, data),
+                        out_shardings=(layout, rep),
+                        donate_argnums=donate,
+                    ),
+                    jax.jit(mesh_lib.same_values, out_shardings=layout),
+                    layout,
+                    shardings,
+                )
+            return program, leaves
+
+        def run(state, batch):
+            program, leaves = program_for(state)
+            if any(getattr(x, "sharding", None) != s for x, s in zip(leaves, program.shardings)):
+                state = program.lay_out(state)
+                if donate:
+                    # the caller gave the copies up; a part cannot reuse a
+                    # whole copy's buffer, so jit's own donation only warns
+                    for x in leaves:
+                        if isinstance(x, jax.Array):
+                            x.delete()
+            return program.step(state, batch)
+
+        def lower(state, batch):
+            # jit refuses an argument committed to another sharding than
+            # its in_shardings: lower for the state's shapes in the layout
+            program, _ = program_for(state)
+            abstract = jax.tree.map(
+                lambda x, s: jax.ShapeDtypeStruct(
+                    jax.numpy.shape(x), jax.numpy.result_type(x), sharding=s),
+                state, program.layout)
+            return program.step.lower(abstract, batch)
+
+        run.lower = lower
+        return run
 
     def _lazy_spec_step(
         self,
@@ -264,10 +408,7 @@ class Strategy:
         compiled: dict[Any, Callable[..., Any]] = {}
 
         def exe_for(state):
-            key = (
-                jax.tree.structure(state),
-                tuple(jax.numpy.shape(l) for l in jax.tree.leaves(state)),
-            )
+            _, key = _signature(state)
             exe = compiled.get(key)
             if exe is None:
                 specs = spec_fn(state)
@@ -303,16 +444,29 @@ class Strategy:
 
 class MirroredStrategy(Strategy):
     """Data parallelism over the chips of ONE host (reference:
-    single-host ``tf.distribute.MirroredStrategy``)."""
+    single-host ``tf.distribute.MirroredStrategy``). "Mirrored" is the
+    reference's name: on more than one chip :meth:`Strategy.step` keeps
+    the large leaves of the train state split over the chips between
+    steps, not mirrored, and every chip still computes with whole
+    weights. The host addresses all of a split array, so
+    ``jax.device_get`` and pickling read a stepped state as it is;
+    :meth:`Strategy.replicate` makes whole copies."""
 
     def __init__(self, data_axis: str = "data", grad_comms: Any | None = None):
         super().__init__(mesh_lib.local_mesh((data_axis,)), data_axis, grad_comms)
 
 
 class CollectiveAllReduceStrategy(Strategy):
-    """Data parallelism over the WHOLE slice; gradients AllReduce over
+    """Data parallelism over the WHOLE slice; gradients are summed over
     ICI/DCN (reference: ``MultiWorkerMirroredStrategy`` with NCCL —
-    SURVEY.md §2.9 row 2).
+    SURVEY.md §2.9 row 2). As on one host, the default path keeps the
+    large leaves of the train state split over the data axis between
+    steps (:meth:`Strategy.step`): reduce-scatter, the update on the
+    owned part, the compute copy gathered. Across hosts a split array
+    is not fully addressable: call :meth:`Strategy.replicate` on the
+    state before reading it on the host (export, ``np.asarray``); the
+    orbax-backed ``runtime.checkpoint`` saves and restores split arrays
+    as they are.
 
     ``update_sharding="cross_replica"`` switches the weight update to
     the ZeRO-1 reduce-scatter/sharded-update/all-gather schedule
@@ -375,15 +529,11 @@ class ShardedStrategy(Strategy):
         sp = shard_lib.infer_param_spec(
             leaf, "model", self.mesh.shape["model"], self.min_shard_size
         )
-        fsdp = self.mesh.shape["fsdp"]
         shape = jax.numpy.shape(leaf)
-        if fsdp == 1 or len(shape) < 2 or math.prod(shape) < self.min_shard_size:
-            return sp
         taken = {d for d, ax in enumerate(sp) if ax is not None}
-        free = [d for d in range(len(shape)) if d not in taken and shape[d] % fsdp == 0]
-        if not free:
+        dim = shard_lib.split_dim(shape, self.mesh.shape["fsdp"], self.min_shard_size, taken)
+        if dim is None:
             return sp
-        dim = max(free, key=lambda d: shape[d])
         parts = list(sp) + [None] * (len(shape) - len(sp))
         parts[dim] = "fsdp"
         return P(*parts)

@@ -122,6 +122,13 @@ SPAN_STARTUP_LAUNCH = "hops_tpu_startup_launch"
 #: call (the step is dispatched, not finished): what a start costs an
 #: operator.
 GAUGE_STARTUP_FIRST_STEP = "hops_tpu_startup_first_step_seconds"
+#: Bytes of a train state's leaves by how ``Strategy.step``'s default path
+#: lays them out across the data axis (``placement`` = ``split`` |
+#: ``whole``), set when a step derives the layout of a state
+#: (``parallel/strategy.py:Strategy.state_layout``): a device holds 1/n of
+#: ``split`` and all of ``whole``. On a data axis of one device nothing is
+#: derived and nothing is set.
+GAUGE_TRAIN_STATE_BYTES = "hops_tpu_train_state_bytes"
 
 #: Trace-time counters of the training vocabulary: each says which form of
 #: an op a compiled step holds, and is added to while the step is traced.

@@ -85,17 +85,22 @@ def test_shapes_that_are_not_whole_tiles_take_the_twin():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -218,3 +223,57 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip, chu
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 2 and scan.KERNEL_FWD in calls[0] + calls[1] and scan.KERNEL_BWD in calls[0] + calls[1]
     assert f"[1,{seq},{d},{n}]" not in text and f"[{seq},{d},{n}]" not in text and f"[1,{seq},{n},{d // 128},128]" not in text
+
+
+def test_four_chip_lm_step_compiles_to_gathers_of_weights_and_sums_to_the_owner(topo, monkeypatch):
+    """``Strategy.step``'s default path over the four chips of a described
+    v5e host, one Phi-3-mini block at the cell's widths and 2 x 4,096
+    tokens a chip (this file holds the one fixture that may load the TPU
+    compiler): the state enters and leaves in quarters, every gathered
+    array is a bf16 compute copy of a kernel, every kernel's gradient is
+    summed to its owner (XLA:TPU's ``all-reduce-scatter`` fusions) and no
+    all-reduce of a kernel's size is left, the unembed's float32 one
+    (``psum.7`` until PR 34) included. Nothing runs."""
+    import functools
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hops_tpu.models import common
+    from hops_tpu.models.transformer import TransformerLM, make_lm_train_step
+    from hops_tpu.parallel.strategy import Strategy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash kernels, not their interpreter
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        model = TransformerLM(vocab_size=32064, d_model=3072, num_heads=32, num_layers=1, window=2047,
+                              dtype=jnp.bfloat16, attention_impl="flash")
+        state = jax.eval_shape(functools.partial(
+            common.create_train_state, model, input_shape=(1, 8), input_dtype=jnp.int32), jax.random.PRNGKey(0))
+        mesh = Mesh(np.array(topo.devices), ("data",))
+        tokens = jax.ShapeDtypeStruct((8, 4097), jnp.int32, sharding=NamedSharding(mesh, P("data")))
+        compiled = Strategy(mesh).step(make_lm_train_step(loss_chunk=512)).lower(state, {"tokens": tokens}).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert 0.25 * state_bytes <= compiled.memory_analysis().argument_size_in_bytes <= 0.26 * state_bytes
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY "):]  # the program's own instructions; the fusions' bodies come before it
+    kernels = [tuple(x.shape) for x in jax.tree.leaves(state.params) if x.ndim >= 2]
+    assert len(kernels) == 7  # embedding, qkv, out, gate, up, down, unembed
+
+    def results(opcode, name=r"[\w.\-]+"):  # (dtype, dims) of ``entry``'s instructions of that name and opcode
+        found = re.findall(rf"^\s*%{name} = (\w+)\[([0-9,]*)\]\S* {opcode}", entry, re.M)
+        return [(dtype, tuple(int(d) for d in dims.split(","))) for dtype, dims in found]
+
+    # a gather is synchronous, or a start / done pair of fusions with a matmul between them
+    gathered = results(r"all-gather\(") + results(r"fusion\(", name=r"async-collective-done[\w.\-]*")
+    assert sorted(dims for _, dims in gathered) == sorted(kernels), gathered
+    assert {dtype for dtype, _ in gathered} == {"bf16"}
+    scatters = results(r"fusion\([^\n]*calls=%all-reduce-scatter")
+    assert len(scatters) == len(kernels) and ("f32", (768, 32064)) in scatters, scatters
+    for shape in re.findall(r"^\s*%[\w.\-]+ = (\(?[^=\n]*?) all-reduce\(", entry, re.M):
+        assert all(np.prod([int(d) for d in dims.split(",") if d]) <= 3072
+                   for dims in re.findall(r"\[([0-9,]*)\]", shape)), shape
