@@ -22,9 +22,13 @@ from hops_tpu.telemetry.spans import SCOPE_OPTIMIZER
 
 
 class TrainState(train_state.TrainState):
-    """flax TrainState + dropout RNG folded per step."""
+    """flax TrainState + dropout RNG folded per step + the selection biases
+    of a model's sigmoid routers (``models/moe.py``, the ``router_bias``
+    collection; None for every other model): state that the step moves by
+    rule and no optimizer touches, as ``BNTrainState.batch_stats`` is."""
 
     rng: jax.Array = None
+    router_bias: Any = None
 
 
 def create_train_state(
@@ -40,7 +44,8 @@ def create_train_state(
     variables = model.init({"params": params_rng, "dropout": dropout_rng}, dummy, train=False)
     tx = optimizer if optimizer is not None else optax.adam(learning_rate)
     return TrainState.create(
-        apply_fn=model.apply, params=variables["params"], tx=tx, rng=dropout_rng
+        apply_fn=model.apply, params=variables["params"], tx=tx, rng=dropout_rng,
+        router_bias=variables.get("router_bias"),
     )
 
 

@@ -1,4 +1,4 @@
-"""Gated DeltaNet: the linear-attention token mixer of a hybrid LM.
+"""Gated DeltaNet and Kimi Delta Attention: the linear-attention token mixers of a hybrid LM.
 
 One layer of Yang et al.'s Gated Delta Networks (arXiv:2412.06464) as
 flash-linear-attention publishes it and Olmo-Hybrid / Qwen3-Next
@@ -18,6 +18,18 @@ layer costs O(seq) whatever the context.
 zero before the sequence's start. ``Block`` enters this module under the
 name ``attn`` (the vocabulary's "token mixer"), and its parts enter
 ``telemetry.spans.LINATTN_SCOPES`` inside it.
+
+:class:`KimiDeltaAttention` (Kimi Linear, arXiv:2510.26692, as
+Ling-3.0-flash configures it: ``no_kda_lora``, ``kda_safe_gate``,
+``kda_lower_bound``, a head-wise output gate) is the same layer with a decay
+per key CHANNEL instead of one a head, kept above a lower bound:
+
+    q, k, v as above                beta = sigmoid(W_b x)
+    g = lower_bound * sigmoid(exp(A_log_h) * (W_a x + dt_bias))    (b, s, h, d_k), W_a full rank
+    o = kda_rule(q, k, v, g, beta)                                 (ops/kda.py)
+    out = W_o [ sigmoid(W_g x)_h * RMSNorm_{d_v}(o_h) ]            one gate a head
+
+It shares the convolution, the L2 norms and the scopes with the layer above.
 """
 
 from __future__ import annotations
@@ -29,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from hops_tpu.ops import gated_delta
+from hops_tpu.ops import gated_delta, kda
 from hops_tpu.ops.causal_conv import causal_conv, dt_bias_init
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
@@ -43,6 +55,24 @@ _m_linattn_traces = REGISTRY.counter(
     "Linear-attention layers traced, by what runs the gated delta rule",
     labels=("impl",),
 )
+
+
+_m_kda_traces = REGISTRY.counter(
+    "hops_tpu_train_kda_traces_total",
+    "Kimi-delta-attention layers traced, by what runs the rule",
+    labels=("impl",),
+)
+
+
+def refuse_decode(kind: str):
+    """What every mixer without a single-token form raises under ``decode=True``."""
+    raise NotImplementedError(
+        f"decoding a {kind} layer needs a per-request state of its own in "
+        "modelrepo/paged.py and LMEngine (a recurrent state beside the paged KV "
+        "cache, or a latent row in place of keys and values) and the mixer's "
+        "single-token form; the benchmark has no serving metric to judge it by, "
+        "so only the training path is built"
+    )
 
 
 def _decay_rate_init(key, shape, dtype=jnp.float32):
@@ -69,12 +99,7 @@ class GatedDeltaNet(nn.Module):
         from hops_tpu.models.transformer import RMSNorm
 
         if decode:
-            raise NotImplementedError(
-                "decoding a linear-attention layer needs two kinds of per-request "
-                "state in modelrepo/paged.py and LMEngine (a recurrent state beside "
-                "the paged KV cache); the benchmark has no serving metric to judge "
-                "it by, so only the training path is built"
-            )
+            refuse_decode("linear-attention")
         b, s, dm = x.shape
         h, dk, dv = self.num_heads, self.key_dim, self.value_dim
         _m_linattn_traces.inc(impl=gated_delta.implementation())
@@ -114,4 +139,79 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope(SCOPE_OUT):
             o = RMSNorm(self.norm_eps, dtype=jnp.float32, name="norm")(jnp.moveaxis(o, 1, 2))
             o = (o * nn.silu(gate.astype(jnp.float32)).reshape(o.shape)).astype(self.dtype)
+            return dense(dm, "out")(o.reshape(b, s, h * dv))
+
+
+def _kda_rate_init(key, shape, dtype=jnp.float32):
+    """``A_log`` of a Kimi-delta layer: log of a gate slope uniform in (1, 4)
+    a head. With ``W_a x + dt_bias`` of unit scale at initialisation the
+    log-decay ``lower_bound * sigmoid(slope * .)`` then covers most of
+    (lower_bound, 0) over a batch's tokens and channels: a gate that sits at
+    one end hides the rule from a comparison with a reference. Chosen here,
+    not published."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, minval=1.0, maxval=4.0))
+
+
+def _kda_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` of a Kimi-delta layer: uniform in (-1, 1) a channel (see
+    :func:`_kda_rate_init`)."""
+    return jax.random.uniform(key, shape, dtype, minval=-1.0, maxval=1.0)
+
+
+class KimiDeltaAttention(nn.Module):
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_size: int = 4
+    lower_bound: float = kda.LOWER_BOUND
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, decode: bool = False):
+        from hops_tpu.models.transformer import RMSNorm
+
+        if decode:
+            refuse_decode("Kimi-delta-attention")
+        if not kda.LOWER_BOUND <= self.lower_bound < 0:
+            raise ValueError(
+                f"lower_bound {self.lower_bound}: ops/kda.py forms its chunks for a log-decay in "
+                f"[{kda.LOWER_BOUND}, 0]")
+        b, s, dm = x.shape
+        h, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        _m_kda_traces.inc(impl=kda.implementation())
+
+        def dense(width, name, dtype=self.dtype):
+            return nn.Dense(width, dtype=dtype, use_bias=False, name=name)
+
+        with jax.named_scope(SCOPE_PROJ):
+            q, k, v = dense(h * dk, "q")(x), dense(h * dk, "k")(x), dense(h * dv, "v")(x)
+            # the gates in float32, as GatedDeltaNet's: a decay multiplies over
+            # thousands of tokens what a bf16 logit rounds off
+            x32 = x.astype(jnp.float32)
+            gate = jax.nn.sigmoid(dense(h, "gate", jnp.float32)(x32))
+            beta = jax.nn.sigmoid(dense(h, "b", jnp.float32)(x32))
+            a = dense(h * dk, "a", jnp.float32)(x32) + self.param("dt_bias", _kda_bias_init, (h * dk,))
+            slope = jnp.exp(self.param("A_log", _kda_rate_init, (h,)))
+            g = self.lower_bound * jax.nn.sigmoid(slope[:, None] * a.reshape(b, s, h, dk))
+
+        with jax.named_scope(SCOPE_CONV):
+            def conv(t, name):
+                kernel = self.param(name, nn.initializers.lecun_normal(), (self.conv_size, t.shape[-1]))
+                return nn.silu(causal_conv(t, kernel.astype(self.dtype)))
+
+            q, k, v = conv(q, "q_conv"), conv(k, "k_conv"), conv(v, "v_conv")
+
+        with jax.named_scope(SCOPE_SCAN):
+            def heads(t, d):  # (b, s, h * d) -> (b, h, s, d)
+                return jnp.moveaxis(t.reshape(b, s, h, d), 2, 1)
+
+            q = (_l2_normalise(heads(q, dk)) / math.sqrt(dk)).astype(self.dtype)
+            k = _l2_normalise(heads(k, dk)).astype(self.dtype)
+            o = per_shard(kda.kda_rule, op="kda")(
+                q, k, heads(v, dv), jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1))
+
+        with jax.named_scope(SCOPE_OUT):
+            o = RMSNorm(self.norm_eps, dtype=jnp.float32, name="norm")(jnp.moveaxis(o, 1, 2))
+            o = (o * gate[..., None]).astype(self.dtype)
             return dense(dm, "out")(o.reshape(b, s, h * dv))

@@ -28,6 +28,20 @@ carrying an ``expert`` axis, expert weights placed ``P("expert")`` (see
 :func:`expert_specs`) are partitioned by GSPMD.
 
 ``MoEBlock`` slots into ``TransformerLM`` as a drop-in MLP replacement.
+
+A second router, the one DeepSeek-V3 (arXiv:2412.19437) and the models
+after it publish (``scoring="sigmoid"``): sigmoid scores, a selection bias
+that enters the choice and not the weights and that the train step moves by
+rule, not by gradient (``router_bias`` collection, :func:`updated_router_bias`),
+a group-limited top-k (the experts in ``n_group`` consecutive groups, the
+``topk_group`` groups with the largest sum of their two best scores kept),
+weights renormalised over the chosen experts and scaled by ``routed_scale``,
+a shared expert every token passes (``shared_hidden``), and a sequence-wise
+balance loss (``losses/moe_seq_aux``). ``held_experts=(first, count)`` is ONE
+chip's share of such a layer under expert parallelism, without the exchange:
+the router spans all ``num_experts``, the parameters hold ``count`` experts
+from id ``first`` on, and only the rows routed to them are computed; what the
+absent experts would add is left out (the other chips' part of the sum).
 """
 
 from __future__ import annotations
@@ -42,7 +56,7 @@ from flax import linen as nn
 from hops_tpu.ops.grouped_matmul import grouped_matmul, implementation
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP
+from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_SHARED
 
 SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_COMBINE = MOE_SCOPES
 #: names of the expert-stacked weights, leading dim ``num_experts``
@@ -175,6 +189,18 @@ class MoEMLP(nn.Module):
     dtype: Any = jnp.bfloat16
     expert_axis: str | None = None
     expert_shards: int = 1
+    # The sigmoid router (module docstring). ``scoring``: "softmax" |
+    # "sigmoid"; the rest is read by the sigmoid router only, but for
+    # ``shared_hidden`` (the shared expert's width; None: none) and
+    # ``held_experts`` ((first, count): this chip's share; None: all).
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    selection_bias: bool = False
+    seq_aux: bool = False
+    shared_hidden: int | None = None
+    held_experts: tuple[int, int] | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -185,7 +211,15 @@ class MoEMLP(nn.Module):
                 f"{self.num_experts} experts not divisible by "
                 f"expert_shards={self.expert_shards}"
             )
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r} (softmax | sigmoid)")
+        if self.held_experts is not None and self.expert_axis is not None:
+            raise ValueError("held_experts is one chip's share without the exchange; expert_axis is the exchange")
         e_local = self.num_experts // self.expert_shards
+        if self.held_experts is not None:
+            first, e_local = self.held_experts
+            if not 0 <= first <= first + e_local <= self.num_experts:
+                raise ValueError(f"held_experts {self.held_experts} outside the {self.num_experts} experts")
 
         with jax.named_scope(SCOPE_ROUTER):
             # float32 end to end: the top-k is discontinuous in the logits
@@ -193,10 +227,13 @@ class MoEMLP(nn.Module):
                 self.num_experts, dtype=jnp.float32, use_bias=False,
                 precision=jax.lax.Precision.HIGHEST, name="router",
             )(x.astype(jnp.float32))
-            probs = jax.nn.softmax(router_logits, axis=-1)  # (b, s, E)
-            top_p, top_ids = jax.lax.top_k(probs, self.top_k)
-            if self.norm_topk_prob:
-                top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+            if self.scoring == "sigmoid":
+                probs, top_p, top_ids = self._sigmoid_choice(router_logits)
+            else:
+                probs = jax.nn.softmax(router_logits, axis=-1)  # (b, s, E)
+                top_p, top_ids = jax.lax.top_k(probs, self.top_k)
+                if self.norm_topk_prob:
+                    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
         # Plain (unboxed) params; under expert_axis they hold only this
         # shard's experts, otherwise parallelism comes from placing the
@@ -211,7 +248,7 @@ class MoEMLP(nn.Module):
         )
         _m_moe_traces.inc(impl=implementation(
             jax.ShapeDtypeStruct((b * s * self.top_k, dm), self.dtype), w_gate))
-        first = 0
+        first = 0 if self.held_experts is None else self.held_experts[0]
         if self.expert_axis is not None:
             first = jax.lax.axis_index(self.expert_axis) * e_local
         out, rows_per_expert = per_shard(
@@ -224,19 +261,67 @@ class MoEMLP(nn.Module):
             # the combine is a linear sum over experts, so psum over the
             # expert axis gives the whole layer.
             out = jax.lax.psum(out, self.expert_axis)
+        if self.shared_hidden:
+            from hops_tpu.models.transformer import MLP
+
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                out = out + MLP(hidden=self.shared_hidden, dtype=self.dtype, name="shared")(x.astype(self.dtype))
 
         with jax.named_scope(SCOPE_ROUTER):
-            # Load balancing (Switch): fraction of tokens that chose each
-            # expert times its mean probability. Router z-loss (ST-MoE,
-            # OLMoE): mean squared log-sum-exp of the logits.
-            density = rows_per_expert.astype(jnp.float32) / (b * s)
-            mean_prob = probs.reshape(-1, self.num_experts).mean(0)
-            self.sow("losses", "moe_aux", self.num_experts * jnp.sum(density * mean_prob))
-            self.sow("losses", "moe_router_z",
-                     jnp.mean(jnp.square(jax.nn.logsumexp(router_logits, axis=-1))))
+            if self.scoring == "sigmoid":
+                if self.seq_aux:
+                    self.sow("losses", "moe_seq_aux", self._sequence_balance(probs, top_ids))
+            else:
+                # Load balancing (Switch): fraction of tokens that chose each
+                # expert times its mean probability. Router z-loss (ST-MoE,
+                # OLMoE): mean squared log-sum-exp of the logits.
+                density = rows_per_expert.astype(jnp.float32) / (b * s)
+                mean_prob = probs.reshape(-1, self.num_experts).mean(0)
+                self.sow("losses", "moe_aux", self.num_experts * jnp.sum(density * mean_prob))
+                self.sow("losses", "moe_router_z",
+                         jnp.mean(jnp.square(jax.nn.logsumexp(router_logits, axis=-1))))
         self.sow("moe_stats", "rows_per_expert", rows_per_expert)
         self.sow("moe_stats", "expert_ids", top_ids)
+        if self.held_experts is not None:
+            # of the rows above, those that reached the experts held here
+            self.sow("moe_stats", "held_rows", jax.lax.dynamic_slice_in_dim(rows_per_expert, first, e_local).sum())
         return out
+
+    def _sigmoid_choice(self, router_logits):
+        """``(scores (b, s, E), weights (b, s, k), ids (b, s, k))`` of the
+        sigmoid router: the bias enters the choice only; a group's score is
+        the sum of its two largest biased scores; outside the kept groups an
+        expert cannot be chosen (DeepSeek-V3's code fills with 0 there,
+        which a negative biased score would lose to; here -inf)."""
+        scores = jax.nn.sigmoid(router_logits)
+        choice = scores
+        if self.selection_bias and (self.is_initializing() or self.has_variable("router_bias", "bias")):
+            # state the step moves by rule (updated_router_bias), never by a
+            # gradient; a caller that hands no ``router_bias`` collection routes without
+            bias = self.variable("router_bias", "bias", jnp.zeros, (self.num_experts,), jnp.float32)
+            choice = scores + jax.lax.stop_gradient(bias.value)
+        if self.n_group > 1:
+            grouped = choice.reshape(*choice.shape[:-1], self.n_group, self.num_experts // self.n_group)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+            kth = jax.lax.top_k(group_score, self.topk_group)[0][..., -1:]
+            choice = jnp.where((group_score >= kth)[..., None], grouped, -jnp.inf).reshape(choice.shape)
+        _, top_ids = jax.lax.top_k(choice, self.top_k)
+        top_p = jnp.take_along_axis(scores, top_ids, axis=-1)
+        if self.norm_topk_prob:
+            top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+        return scores, top_p * self.routed_scale, top_ids
+
+    def _sequence_balance(self, scores, top_ids):
+        """DeepSeek-V3's sequence-wise balance loss (its equations 17-20),
+        unweighted: per sequence ``sum_e f_e P_e`` with ``f_e`` the share of
+        the sequence's choices that fell on expert ``e`` times ``E`` and
+        ``P_e`` the mean over its tokens of the scores normalised over all
+        experts; the mean over the batch's sequences."""
+        b, s, k = top_ids.shape
+        chosen = jnp.sum(top_ids.reshape(b, s * k, 1) == jnp.arange(self.num_experts), axis=1)
+        share = chosen.astype(jnp.float32) * (self.num_experts / (k * s))
+        mean_score = (scores / scores.sum(-1, keepdims=True)).mean(1)
+        return jnp.mean(jnp.sum(share * mean_score, axis=-1))
 
 
 def sum_sown_losses(variables: Any, name: str | None = None) -> jax.Array | float:
@@ -264,6 +349,22 @@ def _sown(variables: Any, collection: str, name: str | None) -> list[tuple]:
         variables.get(collection, {}), is_leaf=lambda x: isinstance(x, tuple)
     )
     return [v for path, v in found if name is None or path[-1].key == name]
+
+
+def updated_router_bias(router_bias: Any, moe_stats: Any, rate: float) -> Any:
+    """The selection biases after a step (DeepSeek-V3, section 2.1.2): in
+    every routed layer ``bias_e + rate * sign(mean load - load_e)``, the load
+    being the rows the step's tokens sent to expert ``e``
+    (``moe_stats/rows_per_expert`` of the same module). No gradient is
+    involved. ``router_bias`` is the ``router_bias`` collection, ``moe_stats``
+    the collection a ``mutable=["moe_stats"]`` apply returned."""
+    def walk(bias, stats):
+        if "bias" in bias:
+            load = stats["rows_per_expert"][0].astype(jnp.float32)
+            return {"bias": bias["bias"] + rate * jnp.sign(jnp.mean(load) - load)}
+        return {name: walk(sub, stats[name]) for name, sub in bias.items()}
+
+    return walk(router_bias, moe_stats)
 
 
 def max_load_over_mean(variables: Any) -> jax.Array | float:

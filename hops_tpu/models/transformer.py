@@ -42,7 +42,7 @@ from hops_tpu.ops.attention import (
 )
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import REMAT_KEEPS, keep
+from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_EMBED, SCOPE_MLP, SCOPE_MTP, keep
 
 _m_layer_kinds = REGISTRY.counter(
     "hops_tpu_train_layer_kinds_total",
@@ -61,8 +61,12 @@ _m_shared_reads = REGISTRY.counter(
 #: layer, a Mamba layer, a gated memory unit (reads the ``y`` of the
 #: nearest Mamba layer before it) and cross attention (queries of its own
 #: against the K and V of the nearest ``full_attention`` layer before it)
+#: a Kimi-delta-attention layer (the delta rule with a decay per key channel)
+#: and latent attention (keys and values through a low-rank projection)
 LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention", "mamba", "gated_memory",
-               "cross_attention")
+               "cross_attention", "kimi_delta_attention", "latent_attention")
+#: the kinds of feed-forward a ``Block`` builds (``TransformerLM.ffn_types``)
+FFN_TYPES = ("dense", "moe")
 #: what a reading kind takes from which writing kind
 SHARED_VALUES = {"gated_memory": ("memory", "mamba"), "cross_attention": ("kv", "full_attention")}
 
@@ -487,6 +491,77 @@ class Attention(nn.Module):
         return self._project_out(o, b, s, dm)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) for
+    training, with the head-wise output gate and the per-head QK norm of
+    Ling-3.0-flash's configuration:
+
+        q = W_q x -> (heads, nope + rope)
+        [c | k_rope] = W_kva x  (kv_rank + rope)     c <- RMSNorm(c)
+        [k_nope | v] = W_kvb c -> (heads, nope + value)
+        k_h = [k_nope_h | k_rope]                     one k_rope for all heads
+        q_h, k_h <- RMSNorm(q_h), RMSNorm(k_h)        over a head's nope + rope channels, one learned scale each
+        rotate the rope part of q_h and k_h           interleaved pairs, base ``rope_base``
+        o_h = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
+        out = W_o [ sigmoid(W_g x)_h * o_h ]
+
+    The flash kernels take keys ``nope + rope`` wide beside values ``value``
+    wide. Its parts enter ``telemetry.spans.MLA_SCOPES``."""
+
+    num_heads: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
+    attention_impl: str = "flash"
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, decode: bool = False):
+        if decode:
+            from hops_tpu.models.linear_attention import refuse_decode
+
+            refuse_decode("latent-attention")
+        if self.attention_impl not in ("flash", "reference"):
+            raise ValueError(f"latent attention runs attention_impl flash | reference, not {self.attention_impl!r}")
+        b, s, dm = x.shape
+        h, nope, rope, dv = self.num_heads, self.nope_dim, self.rope_dim, self.value_dim
+        scope_proj, scope_attn, scope_out = MLA_SCOPES
+
+        def dense(width, name, dtype=self.dtype):
+            return nn.Dense(width, dtype=dtype, use_bias=False, name=name)
+
+        with jax.named_scope(scope_proj):
+            q = dense(h * (nope + rope), "q")(x).reshape(b, s, h, nope + rope)
+            latent = dense(self.kv_rank + rope, "kv_a")(x)
+            c = RMSNorm(self.norm_eps, dtype=self.dtype, name="kv_a_norm")(latent[..., : self.kv_rank])
+            kv = dense(h * (nope + dv), "kv_b")(c).reshape(b, s, h, nope + dv)
+            gate = jax.nn.sigmoid(dense(h, "gate", jnp.float32)(x.astype(jnp.float32)))
+
+        with jax.named_scope(scope_attn):
+            k_rope = jnp.broadcast_to(latent[:, :, None, self.kv_rank:], (b, s, h, rope))
+            k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+            q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+            k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+            q, k, v = (jnp.moveaxis(t, 2, 1) for t in (q, k, kv[..., nope:]))  # (b, h, s, d)
+            pos = jnp.arange(s)
+
+            def rotate(t):
+                return jnp.concatenate([t[..., :nope], rotary_embedding(t[..., nope:], pos, self.rope_base)], axis=-1)
+
+            q, k = rotate(q), rotate(k)
+            if self.attention_impl == "flash":
+                o = per_shard(functools.partial(flash_attention, causal=True), op="flash")(q, k, v)
+            else:
+                o = attention_reference(q, k, v, causal=True)
+
+        with jax.named_scope(scope_out):
+            o = (jnp.moveaxis(o, 1, 2) * gate[..., None].astype(o.dtype)).reshape(b, s, h * dv)
+            return dense(dm, "out")(o)
+
+
 class MLP(nn.Module):
     """SwiGLU: two fused up-projections + gated down-projection.
 
@@ -573,11 +648,22 @@ class Block(nn.Module):
     attention_form: str = "softmax"
     layer_index: int = 0
     hands_on: str | None = None
+    # What varies independently of the mixer: the feed-forward's kind
+    # ("dense" | "moe": ``moe.MoEMLP`` built from ``moe_options``, its
+    # keyword arguments as sorted pairs), and the sizes of a
+    # "latent_attention" mixer (``LatentAttention``'s, as pairs) and the
+    # lower bound of a "kimi_delta_attention" mixer's log-decay.
+    ffn_type: str = "dense"
+    moe_options: tuple[tuple[str, Any], ...] = ()
+    latent_options: tuple[tuple[str, Any], ...] = ()
+    linear_lower_bound: float = -5.0
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False, shared=None):
         if self.layer_type not in LAYER_TYPES:
             raise ValueError(f"unknown layer_type {self.layer_type!r} (one of {LAYER_TYPES})")
+        if self.ffn_type not in FFN_TYPES:
+            raise ValueError(f"unknown ffn_type {self.ffn_type!r} (one of {FFN_TYPES})")
         if self.norm_placement not in ("pre", "post_sublayer"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
         if self.norm_kind not in NORMS:
@@ -613,6 +699,23 @@ class Block(nn.Module):
                 dtype=self.dtype,
                 name="attn",
             )
+        elif self.layer_type == "kimi_delta_attention":
+            from hops_tpu.models.linear_attention import KimiDeltaAttention
+
+            mixer = KimiDeltaAttention(
+                self.linear_num_heads or self.num_heads,
+                key_dim=self.linear_key_dim,
+                value_dim=self.linear_value_dim,
+                conv_size=self.linear_conv_size,
+                lower_bound=self.linear_lower_bound,
+                norm_eps=self.norm_eps,
+                dtype=self.dtype,
+                name="attn",
+            )
+        elif self.layer_type == "latent_attention":
+            mixer = LatentAttention(
+                self.num_heads, **dict(self.latent_options), norm_eps=self.norm_eps,
+                attention_impl=self.attention_impl, dtype=self.dtype, name="attn")
         else:
             mixer = self._attention()
         h = mixer(norm(x) if pre else x, decode=decode)
@@ -628,13 +731,21 @@ class Block(nn.Module):
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         x = x + h
-        h = MLP(
-            dtype=self.dtype,
-            tp_axis=self.tp_axis,
-            tp_shards=self.tp_shards,
-            hidden=self.mlp_hidden,
-            name="mlp",
-        )(norm(x) if pre else x)
+        if self.ffn_type == "moe":
+            from hops_tpu.models.moe import MoEMLP
+
+            # the module keeps its name in the parameter tree ("moe"), its
+            # device ops carry the vocabulary's ``mlp`` scope (as MoEBlock)
+            with jax.named_scope(SCOPE_MLP):
+                h = MoEMLP(**dict(self.moe_options), dtype=self.dtype, name="moe")(norm(x) if pre else x)
+        else:
+            h = MLP(
+                dtype=self.dtype,
+                tp_axis=self.tp_axis,
+                tp_shards=self.tp_shards,
+                hidden=self.mlp_hidden,
+                name="mlp",
+            )(norm(x) if pre else x)
         if not pre:
             h = norm(keep(h, "mlp_out"))
         if self.dropout_rate:
@@ -688,6 +799,30 @@ class Block(nn.Module):
             rope_base=self.rope_base,
             name="attn",
         )
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437,
+    section 2.2): ``h'_i = M [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]``, one
+    block, a final norm of its own; the embedding and the head are the
+    model's. ``block`` is the constructor of its block (remat'd or not)."""
+
+    block: Any
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, hidden, next_embedded, train: bool = False):
+        def norm(name):
+            return RMSNorm(self.norm_eps, dtype=self.dtype, name=name)
+
+        # the module's way in is its embedding: the two norms and the projection
+        # carry the vocabulary's ``embed`` scope (inside ``mtp``), as the lookup does
+        with jax.named_scope(SCOPE_EMBED):
+            joined = jnp.concatenate([norm("hidden_norm")(hidden), norm("embed_norm")(next_embedded)], axis=-1)
+            x = nn.Dense(hidden.shape[-1], dtype=self.dtype, use_bias=False, name="proj")(joined)
+        x = self.block(name="block")(x, train, False)
+        return norm("final_norm")(x)
 
 
 class TransformerLM(nn.Module):
@@ -745,6 +880,35 @@ class TransformerLM(nn.Module):
     use_bias: bool = False
     attention_form: str = "softmax"
     tie_embeddings: bool = False
+    # A linear-attention / latent-attention hybrid with routed feed-forwards
+    # (Ling-3.0-flash): ``layer_types`` may also name "kimi_delta_attention"
+    # (sizes: the ``linear_*`` keys; ``linear_lower_bound`` of its log-decay)
+    # and "latent_attention" (``latent_*``: the key/value rank, a head's
+    # widths without and with position, and of its values); ``ffn_types``
+    # says per layer "dense" | "moe" (None: every layer dense), independently
+    # of the mixer; a "moe" layer is ``moe.MoEMLP`` with ``num_experts``,
+    # ``moe_top_k``, ``moe_expert_hidden``, ``moe_norm_topk_prob`` and the
+    # ``moe_*`` keys below (its sigmoid router; ``moe_held_experts``: this
+    # chip's (first, count) of the experts). ``mtp_layers`` = 1 adds a
+    # multi-token-prediction module (``MTPModule``: a block whose mixer is
+    # ``mtp_layer_type``, with a routed feed-forward if any layer has one),
+    # run when the caller hands ``mtp_tokens``.
+    ffn_types: tuple[str, ...] | None = None
+    linear_lower_bound: float = -5.0
+    latent_kv_rank: int | None = None
+    latent_nope_dim: int | None = None
+    latent_rope_dim: int | None = None
+    latent_value_dim: int | None = None
+    moe_scoring: str = "softmax"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scale: float = 1.0
+    moe_selection_bias: bool = False
+    moe_seq_aux: bool = False
+    moe_shared_hidden: int | None = None
+    moe_held_experts: tuple[int, int] | None = None
+    mtp_layers: int = 0
+    mtp_layer_type: str = "full_attention"
     max_decode_len: int = 2048
     kv_cache_dtype: str | None = None  # "int8": quantized decode cache
     num_kv_heads: int | None = None  # GQA: shrink the decode cache
@@ -771,6 +935,7 @@ class TransformerLM(nn.Module):
         train: bool = False,
         decode: bool = False,
         return_hidden: bool = False,
+        mtp_tokens=None,
     ):
         from hops_tpu.models.moe import MoEBlock
 
@@ -790,7 +955,32 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"layer_types names {len(layer_types)} layers, num_layers is {self.num_layers}"
             )
+        ffn_types = self.ffn_types or ("dense",) * self.num_layers
+        if len(ffn_types) != self.num_layers:
+            raise ValueError(f"ffn_types names {len(ffn_types)} layers, num_layers is {self.num_layers}")
+        if self.mtp_layers not in (0, 1):
+            raise NotImplementedError("one multi-token-prediction module is built (mtp_layers 0 | 1)")
+        if (self.moe_every or self.tp_shards > 1 or self.paged_decode) and "moe" in ffn_types:
+            raise NotImplementedError(
+                "ffn_types routes feed-forwards inside Block; moe_every, tensor parallelism and "
+                "paged_decode are built without it")
+        moe_options = tuple(sorted(dict(
+            num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,
+            norm_topk_prob=self.moe_norm_topk_prob, scoring=self.moe_scoring, n_group=self.moe_n_group,
+            topk_group=self.moe_topk_group, routed_scale=self.moe_routed_scale,
+            selection_bias=self.moe_selection_bias, seq_aux=self.moe_seq_aux,
+            shared_hidden=self.moe_shared_hidden,
+            held_experts=None if self.moe_held_experts is None else tuple(self.moe_held_experts),
+        ).items()))
+        latent_options = ()
+        if "latent_attention" in layer_types + (self.mtp_layer_type,) * self.mtp_layers:
+            latent_options = tuple(sorted(dict(
+                kv_rank=self.latent_kv_rank, nope_dim=self.latent_nope_dim, rope_dim=self.latent_rope_dim,
+                value_dim=self.latent_value_dim, rope_base=self.rope_base).items()))
         hybrid = dict(
+            moe_options=moe_options if "moe" in ffn_types else (),
+            latent_options=latent_options,
+            linear_lower_bound=self.linear_lower_bound,
             norm_placement=self.norm_placement,
             mlp_hidden=self.mlp_hidden,
             linear_num_heads=self.linear_num_heads,
@@ -833,6 +1023,35 @@ class TransformerLM(nn.Module):
         layer_options = dict(
             qk_norm=self.qk_norm, norm_eps=self.norm_eps, rope_base=self.rope_base
         )
+        def block(i, layer_type, ffn_type, **more):
+            """The constructor of layer ``i``'s block, but for its name."""
+            return functools.partial(
+                block_cls,
+                self.num_heads,
+                dtype=self.dtype,
+                attention_impl=self.attention_impl,
+                mesh=self.mesh,
+                seq_axis=self.seq_axis,
+                batch_axis=self.batch_axis,
+                dropout_rate=self.dropout_rate,
+                max_decode_len=self.max_decode_len,
+                tp_axis=self.tp_axis,
+                tp_shards=self.tp_shards,
+                kv_cache_dtype=self.kv_cache_dtype,
+                num_kv_heads=self.num_kv_heads,
+                window=self.window if self.layer_types is None or layer_type == "sliding_attention" else None,
+                ragged_decode=self.ragged_decode,
+                paged_decode=self.paged_decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_blocks=self.kv_pool_blocks,
+                **layer_options,
+                layer_type=layer_type,
+                ffn_type=ffn_type,
+                layer_index=i,
+                **more,
+                **hybrid,
+            )
+
         for i in range(self.num_layers):
             _m_layer_kinds.inc(kind=layer_types[i])
             if self.moe_every and (i + 1) % self.moe_every == 0:
@@ -861,49 +1080,39 @@ class TransformerLM(nn.Module):
             if i in source:
                 _m_shared_reads.inc(what=hands_on[source[i]])
                 shared = (handed_on[source[i]],)
-            x = block_cls(
-                self.num_heads,
-                dtype=self.dtype,
-                attention_impl=self.attention_impl,
-                mesh=self.mesh,
-                seq_axis=self.seq_axis,
-                batch_axis=self.batch_axis,
-                dropout_rate=self.dropout_rate,
-                max_decode_len=self.max_decode_len,
-                tp_axis=self.tp_axis,
-                tp_shards=self.tp_shards,
-                kv_cache_dtype=self.kv_cache_dtype,
-                num_kv_heads=self.num_kv_heads,
-                window=self.window if self.layer_types is None or layer_types[i] == "sliding_attention" else None,
-                ragged_decode=self.ragged_decode,
-                paged_decode=self.paged_decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_blocks=self.kv_pool_blocks,
-                **layer_options,
-                layer_type=layer_types[i],
-                layer_index=i,
-                hands_on=hands_on.get(i),
-                **hybrid,
-                name=f"block_{i}",
-            )(x, train, decode, *shared)
+            x = block(i, layer_types[i], ffn_types[i], hands_on=hands_on.get(i))(name=f"block_{i}")(
+                x, train, decode, *shared)
             if i in hands_on:
                 x, handed_on[i] = x
+        mtp_hidden = None
+        if self.mtp_layers and not decode and (mtp_tokens is not None or self.is_initializing()):
+            # the module predicts the token after the next from the last layer's
+            # output (before the final norm) and the next token's embedding
+            _m_layer_kinds.inc(kind=f"mtp_{self.mtp_layer_type}")
+            mtp_block = block(self.num_layers, self.mtp_layer_type, "moe" if "moe" in ffn_types else "dense")
+            mtp_hidden = MTPModule(mtp_block, self.norm_eps, dtype=self.dtype, name=SCOPE_MTP)(
+                x, embed(tokens if mtp_tokens is None else mtp_tokens), train)
         x = NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype, name="final_norm")(x)
         if return_hidden:
             # The chunked-vocab loss (ops/xent.py) computes the loss
             # straight from hidden states + the unembed kernel without
             # ever materializing (batch, seq, vocab) fp32 logits.
-            return x
+            return x if mtp_tokens is None else (x, mtp_hidden)
         if self.tie_embeddings:
-            return embed.attend(x).astype(jnp.float32)
-        logits = nn.Dense(self.vocab_size, dtype=self.dtype, use_bias=False, name="unembed")(x)
-        return logits.astype(jnp.float32)
+            head = lambda t: embed.attend(t).astype(jnp.float32)  # noqa: E731
+        else:
+            unembed = nn.Dense(self.vocab_size, dtype=self.dtype, use_bias=False, name="unembed")
+            head = lambda t: unembed(t).astype(jnp.float32)  # noqa: E731
+        return head(x) if mtp_tokens is None else (head(x), head(mtp_hidden))
 
 
 def make_lm_train_step(
     aux_loss_weight: float = 0.01,
     loss_chunk: int | None = None,
     router_z_loss_weight: float = 0.0,
+    mtp_loss_weight: float = 0.0,
+    seq_aux_loss_weight: float = 0.0,
+    router_bias_rate: float = 0.0,
 ):
     """Next-token-prediction step: ``(state, {"tokens"}) -> (state, metrics)``.
 
@@ -911,11 +1120,21 @@ def make_lm_train_step(
     so every launcher (launch/mirrored/collective_all_reduce) accepts it
     unchanged. MoE blocks' sown losses are folded in: load balancing at
     ``aux_loss_weight``, the router z-loss at ``router_z_loss_weight``
-    (OLMoE trains with 0.01 and 0.001). A model with MoE blocks also
+    (OLMoE trains with 0.01 and 0.001), a sigmoid router's sequence-wise
+    balance loss at ``seq_aux_loss_weight``. A model with MoE blocks also
     reports ``moe_aux_loss``, ``moe_router_z_loss`` (unweighted, summed
-    over layers) and ``moe_load_max_over_mean`` (busiest expert's rows
-    over the mean, the largest over layers) — device scalars like
-    ``loss``, no host sync.
+    over layers; ``moe_seq_aux_loss`` when it is weighted) and
+    ``moe_load_max_over_mean`` (busiest expert's rows over the mean, the
+    largest over layers) — device scalars like ``loss``, no host sync.
+
+    ``mtp_loss_weight`` > 0 trains a model's multi-token-prediction module:
+    the batch then holds ``seq + 2`` ids a row, positions ``[:-2]`` are
+    trained on ``[1:-1]`` and the module, given the hidden states and the
+    ids ``[1:-1]``, on ``[2:]``; ``loss`` stays the next-token loss,
+    ``mtp_loss`` is reported beside it and the total adds it at that
+    weight. ``router_bias_rate`` > 0 moves the selection biases a state
+    carries (``TrainState.router_bias``) after the step, by the step's own
+    expert loads and by no gradient (``moe.updated_router_bias``).
 
     ``loss_chunk``: compute the loss via the memory-efficient
     token-chunked LM-head path (``ops/xent.py``) — ``loss_chunk``
@@ -930,56 +1149,73 @@ def make_lm_train_step(
     """
     import optax
 
-    from hops_tpu.models.moe import max_load_over_mean, sum_sown_losses
+    from hops_tpu.models.moe import max_load_over_mean, sum_sown_losses, updated_router_bias
     from hops_tpu.parallel.mesh import gathered
     from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
 
     def train_step(state, batch):
         tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        if mtp_loss_weight:
+            inputs, targets, mtp_targets = tokens[:, :-2], tokens[:, 1:-1], tokens[:, 2:]
+        else:
+            inputs, targets = tokens[:, :-1], tokens[:, 1:]
         step_rng = jax.random.fold_in(state.rng, state.step)
+        router_bias = getattr(state, "router_bias", None)
 
         def compute_loss(params):
             params = gathered(params)
             out, mods = state.apply_fn(
-                {"params": params},
+                {"params": params, **({"router_bias": router_bias} if router_bias else {})},
                 inputs,
                 train=True,
                 return_hidden=bool(loss_chunk),
                 rngs={"dropout": step_rng},
                 mutable=["losses", "moe_stats"],
+                **({"mtp_tokens": targets} if mtp_loss_weight else {}),
             )
-            if loss_chunk:
-                from hops_tpu.ops.xent import chunked_softmax_xent
 
-                # tied embeddings: the loss reads the embedding matrix as it
-                # lies, (vocab, d), and its dW joins the gather's gradient
-                tied = "unembed" not in params
-                loss = chunked_softmax_xent(
-                    out,
-                    params["embed"]["embedding"] if tied else params["unembed"]["kernel"],
-                    targets, chunk=loss_chunk, vocab_major=tied,
-                )
-            else:
+            def token_loss(out, targets):
+                if loss_chunk:
+                    from hops_tpu.ops.xent import chunked_softmax_xent
+
+                    # tied embeddings: the loss reads the embedding matrix as it
+                    # lies, (vocab, d), and its dW joins the gather's gradient
+                    tied = "unembed" not in params
+                    return chunked_softmax_xent(
+                        out,
+                        params["embed"]["embedding"] if tied else params["unembed"]["kernel"],
+                        targets, chunk=loss_chunk, vocab_major=tied,
+                    )
                 with jax.named_scope(SCOPE_LM_HEAD_LOSS):
-                    loss = optax.softmax_cross_entropy_with_integer_labels(
-                        out, targets
-                    ).mean()
+                    return optax.softmax_cross_entropy_with_integer_labels(out, targets).mean()
+
+            if mtp_loss_weight:
+                out, mtp_out = out
+            loss = token_loss(out, targets)
             metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
             total = loss
+            if mtp_loss_weight:
+                metrics["mtp_loss"] = token_loss(mtp_out, mtp_targets)
+                total = total + mtp_loss_weight * metrics["mtp_loss"]
             if "losses" in mods:  # the model has MoE blocks
                 aux = sum_sown_losses(mods, "moe_aux")
                 router_z = sum_sown_losses(mods, "moe_router_z")
-                total = loss + aux_loss_weight * aux + router_z_loss_weight * router_z
-                metrics.update(
-                    moe_aux_loss=aux, moe_router_z_loss=router_z,
-                    moe_load_max_over_mean=max_load_over_mean(mods),
-                )
-            return total, metrics
+                total = total + aux_loss_weight * aux + router_z_loss_weight * router_z
+                metrics.update(moe_aux_loss=aux, moe_router_z_loss=router_z)
+                if seq_aux_loss_weight:
+                    metrics["moe_seq_aux_loss"] = sum_sown_losses(mods, "moe_seq_aux")
+                    total = total + seq_aux_loss_weight * metrics["moe_seq_aux_loss"]
+            if "moe_stats" in mods:
+                metrics["moe_load_max_over_mean"] = max_load_over_mean(mods)
+            return total, (metrics, mods.get("moe_stats") if router_bias and router_bias_rate else None)
 
-        (_, metrics), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)
+        (_, (metrics, stats)), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)
         with jax.named_scope(SCOPE_OPTIMIZER):
-            state = state.apply_gradients(grads=grads)
+            if stats is not None:
+                state = state.apply_gradients(
+                    grads=grads, router_bias=updated_router_bias(router_bias, stats, router_bias_rate))
+            else:
+                state = state.apply_gradients(grads=grads)
         return state, metrics
 
     return train_step

@@ -20,6 +20,11 @@ hand-written here with Pallas:
   its own; on a TPU five Pallas kernels (``gated_delta_local_fwd``,
   ``gated_delta_fwd``, ``gated_delta_out_fwd``, ``gated_delta_bwd``,
   ``gated_delta_local_bwd``) keep a chunk's matrices and the state in VMEM.
+- ``kda_rule`` — the delta rule with a decay per key channel (Kimi Delta
+  Attention) in chunks of 64 tokens, with a backward pass of its own; on a
+  TPU two Pallas kernels (``kda_fwd``, ``kda_bwd``: a chunk's matrices, the
+  decayed operands and the state in VMEM, the backward the forward's
+  ``jax.vjp`` traced into the kernel).
 - ``selective_scan`` — the recurrence of a Mamba layer in chunks, with a
   backward pass of its own that recomputes a chunk's states; on a TPU two
   Pallas kernels (``selective_scan_fwd``, ``selective_scan_bwd``) keep the
@@ -46,4 +51,5 @@ with _startup.importing("hops_tpu.ops"):
         repeat_kv,
     )
     from hops_tpu.ops.gated_delta import gated_delta_rule  # noqa: F401
+    from hops_tpu.ops.kda import kda_rule  # noqa: F401
     from hops_tpu.ops.xent import chunked_softmax_xent  # noqa: F401

@@ -505,8 +505,10 @@ def _flat(x):
     return x.reshape(b * h, s, d)
 
 
-def _band_specs(band: _Band, d: int):
-    """BlockSpecs of the two grid orders. Under ``(bh, query tile, key
+def _band_specs(band: _Band, d: int, d_v: int | None = None):
+    """BlockSpecs of the two grid orders, for queries and keys ``d`` wide and
+    values (and the output) ``d_v`` wide (None: ``d`` too; a latent-attention
+    layer's keys carry a rotary part the values lack). Under ``(bh, query tile, key
     step)`` (forward, dQ) the K/V block of step ``j`` is the ``j``-th key
     tile of the query tile's span; under ``(bh, key tile, query step)``
     (dK/dV) the Q/dO block is the ``j``-th query tile of the key tile's
@@ -524,14 +526,19 @@ def _band_specs(band: _Band, d: int):
     q_of_k = stepped(band.query_tiles, band.seq_q // band.block_q)
     own = lambda b, i, j: (b, i, 0)
     stats = pl.BlockSpec((1, 1, band.seq_q), lambda b, i, j: (b, 0, 0))
+    d_v = d if d_v is None else d_v
     q_major = {
         "q": pl.BlockSpec((1, band.block_q, d), own),
-        "kv": pl.BlockSpec((1, band.block_k, d), k_of_q),
+        "o": pl.BlockSpec((1, band.block_q, d_v), own),
+        "k": pl.BlockSpec((1, band.block_k, d), k_of_q),
+        "v": pl.BlockSpec((1, band.block_k, d_v), k_of_q),
         "stats": stats,
     }
     k_major = {
         "q": pl.BlockSpec((1, band.block_q, d), q_of_k),
-        "kv": pl.BlockSpec((1, band.block_k, d), own),
+        "o": pl.BlockSpec((1, band.block_q, d_v), q_of_k),
+        "k": pl.BlockSpec((1, band.block_k, d), own),
+        "v": pl.BlockSpec((1, band.block_k, d_v), own),
         "stats": stats,
     }
     return q_major, k_major
@@ -550,20 +557,21 @@ _per_geometry = functools.partial(
 @_per_geometry
 def _fwd_call(q, k, v, band, sm_scale, interpret):
     bh, seq_q, d = q.shape
-    spec, _ = _band_specs(band, d)
+    d_v = v.shape[-1]
+    spec, _ = _band_specs(band, d, d_v)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, band=band),
         grid=(bh, seq_q // band.block_q, band.key_steps()),
-        in_specs=[spec["q"], spec["kv"], spec["kv"]],
-        out_specs=[spec["q"], spec["stats"]],
+        in_specs=[spec["q"], spec["k"], spec["v"]],
+        out_specs=[spec["o"], spec["stats"]],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((band.block_q, _LANES), jnp.float32),
             pltpu.VMEM((band.block_q, _LANES), jnp.float32),
-            pltpu.VMEM((band.block_q, d), jnp.float32),
+            pltpu.VMEM((band.block_q, d_v), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -581,7 +589,7 @@ def _flash_fwd(q, k, v, band, sm_scale, interpret):
     o, lse = _fwd_call(_flat(q), _flat(k), _flat(v), band, sm_scale, interpret)
     # kept by a block's remat: the backward kernels then take q, k, v from the
     # second forward and this call is not made again
-    o, lse = keep(o.reshape(q.shape), "flash_out"), keep(lse, "flash_lse")
+    o, lse = keep(o.reshape(*q.shape[:-1], v.shape[-1]), "flash_out"), keep(lse, "flash_lse")
     return o, (q, k, v, o, lse)
 
 
@@ -596,14 +604,15 @@ def _bwd_calls(q, k, v, o, lse, g, band, sm_scale, interpret):
     shape = q.shape
     qf, kf, vf, of, gf = _flat(q), _flat(k), _flat(v), _flat(o), _flat(g)
     bh, seq_q, d = qf.shape
+    d_v = vf.shape[-1]
     delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), axis=-1)[:, None, :]
-    q_major, k_major = _band_specs(band, d)
+    q_major, k_major = _band_specs(band, d, d_v)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, band=band),
         grid=(bh, seq_q // band.block_q, band.key_steps()),
         in_specs=[
-            q_major["q"], q_major["kv"], q_major["kv"], q_major["q"],
+            q_major["q"], q_major["k"], q_major["v"], q_major["o"],
             q_major["stats"], q_major["stats"],
         ],
         out_specs=q_major["q"],
@@ -618,17 +627,17 @@ def _bwd_calls(q, k, v, o, lse, g, band, sm_scale, interpret):
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, band=band),
         grid=(bh, band.seq_k // band.block_k, band.query_steps()),
         in_specs=[
-            k_major["q"], k_major["kv"], k_major["kv"], k_major["q"],
+            k_major["q"], k_major["k"], k_major["v"], k_major["o"],
             k_major["stats"], k_major["stats"],
         ],
-        out_specs=[k_major["kv"], k_major["kv"]],
+        out_specs=[k_major["k"], k_major["v"]],
         out_shape=[
             jax.ShapeDtypeStruct((bh, band.seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, band.seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, band.seq_k, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((band.block_k, d), jnp.float32),
-            pltpu.VMEM((band.block_k, d), jnp.float32),
+            pltpu.VMEM((band.block_k, d_v), jnp.float32),
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -676,7 +685,8 @@ def flash_attention(
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Blocked flash attention over ``(batch, heads, seq, head_dim)``.
+    """Blocked flash attention over ``(batch, heads, seq, head_dim)``; ``v``
+    may be of another width than ``q`` and ``k`` (the output is ``v``'s).
 
     ``window`` (causal only): query p attends keys in
     ``[p - window + 1, p]`` — Mistral-style sliding-window attention.

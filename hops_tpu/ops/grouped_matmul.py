@@ -205,9 +205,16 @@ _kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
 
 def fit_tiling(m: int, k: int, n: int, tiling=DEFAULT_TILING):
     """``tiling`` cut to the problem (a dimension smaller than its tile is
-    one tile), or None when a dimension is not whole tiles or the tiles are
-    not whole (16, 128) register tiles: then the twin runs."""
-    tm, tk, tn = (min(t, d) for t, d in zip(tiling, (m, k, n)))
+    one tile; a contraction or column dimension its tile does not divide
+    takes the largest multiple of 128 below the tile that does: 2,560 under
+    a tile of 2,048 is two tiles of 1,280), or None when a dimension is not
+    whole tiles or the tiles are not whole (16, 128) register tiles: then
+    the twin runs."""
+    def lanes(tile, dim):
+        tile = min(tile, dim)
+        return next((t for t in range(tile - tile % 128, 0, -128) if dim % t == 0), tile) if dim % tile else tile
+
+    tm, tk, tn = min(tiling[0], m), lanes(tiling[1], k), lanes(tiling[2], n)
     if m % tm or k % tk or n % tn or tm % 16 or tk % 128 or tn % 128:
         return None
     return tm, tk, tn

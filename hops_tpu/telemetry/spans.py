@@ -49,6 +49,7 @@ TRAIN_SCOPES = (
     "lm_head_loss", "optimizer", "grad_exchange",
 )
 SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER, SCOPE_GRAD_EXCHANGE = TRAIN_SCOPES[4:]
+SCOPE_EMBED = TRAIN_SCOPES[2]
 
 #: Inside ``mlp``, the parts of a routed feed-forward (``models/moe.py``):
 #: the router (matmul, softmax, top-k, the two auxiliary losses), the
@@ -57,6 +58,9 @@ SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER, SCOPE_GRAD_EXCHANGE = TRAIN_SCOPES[4:]
 #: order, summed over a token's slots). A dense block enters none of them.
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 SCOPE_MLP = TRAIN_SCOPES[1]
+#: Inside ``mlp`` beside them, the shared expert of a routed feed-forward
+#: that has one (a dense SwiGLU every token passes).
+SCOPE_MOE_SHARED = "moe_shared"
 
 #: Inside ``attn``, the parts of a linear-attention (Gated DeltaNet) mixer
 #: (``models/linear_attention.py``): the projections (q, k, v, the output
@@ -65,6 +69,21 @@ SCOPE_MLP = TRAIN_SCOPES[1]
 #: delta rule, forward and backward) and the output (gated norm and
 #: ``W_o``). A softmax-attention block enters none of them.
 LINATTN_SCOPES = ("linattn_proj", "linattn_conv", "linattn_scan", "linattn_out")
+#: A Kimi-delta-attention mixer (``models/linear_attention.py``) enters the
+#: same four, with ``ops/kda.py``'s rule in ``linattn_scan``.
+
+#: Inside ``attn``, the parts of a latent-attention mixer
+#: (``models/transformer.py:LatentAttention``): the projections (``W_q``, the
+#: low-rank key/value projection down and up with its norm, the head-wise
+#: gate), the attention (per-head norms, the rotation of the rotary part, the
+#: flash kernels with keys wider than values, forward and backward) and the
+#: output (gate and ``W_o``). ``mtp`` is entered by the multi-token-prediction
+#: module of a ``TransformerLM`` that has one (its two input norms and its
+#: projection, which enter ``embed`` inside it; its block, whose parts enter
+#: their own scopes inside it; and its final norm; the second chunked loss
+#: runs under ``lm_head_loss`` like the first).
+MLA_SCOPES = ("mla_proj", "mla_attn", "mla_out")
+SCOPE_MTP = "mtp"
 
 #: Inside ``attn``, the parts of a state-space (Mamba) mixer
 #: (``models/state_space.py``): the projections (``W_in``, ``W_x``, the
@@ -154,6 +173,11 @@ COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"
 #: the ``pallas_call`` names): which parts of the rule a compiled step holds
 #: in kernels. A step on the XLA route counts none.
 COUNTER_TRAIN_LINATTN_KERNEL_CALLS = "hops_tpu_train_linattn_kernel_calls_total"
+#: One per Mosaic call of the Kimi delta rule traced (``ops/kda.py``: ``kernel``
+#: = ``kda_fwd`` | ``kda_bwd``, the ``pallas_call`` names); a step on the XLA
+#: route counts none. ``hops_tpu_train_kda_traces_total{impl}``
+#: (``models/linear_attention.py``) counts the layers traced by the route.
+COUNTER_TRAIN_KDA_KERNEL_CALLS = "hops_tpu_train_kda_kernel_calls_total"
 
 #: What ``TransformerLM(remat=True)`` keeps of a block's forward besides the
 #: block's input: values that cost a kernel or a ``d_model``-wide matmul to

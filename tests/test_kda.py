@@ -1,0 +1,130 @@
+"""The chunked Kimi delta rule (``hops_tpu/ops/kda.py``) against the
+token-by-token recurrence of ``benchmark/reference/ling_flash.py``, in
+float32 on the CPU: forward and all five gradients, the Pallas kernels in
+interpret mode against their XLA twin, and the rule with one decay for all
+channels against ``ops/gated_delta.py``.
+
+Tolerances: both sides are float32 and compute the same sums in another
+order, so they differ by rounding alone; 2e-5 relative (L2 over an array)
+leaves room for a whole chunk at the bound g = -5, where the decayed score
+matrices are formed round a block's middle row from factors of e^+-40.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference.ling_flash import kda_recurrence
+from hops_tpu.ops import kda
+from hops_tpu.ops.gated_delta import gated_delta_rule
+from hops_tpu.ops.kda import kda_rule
+from hops_tpu.telemetry import REGISTRY
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_KDA_KERNEL_CALLS
+
+B, H, DK, DV = 2, 3, 32, 16
+NAMES = ("q", "k", "v", "g", "beta")
+REL_TOL = 2e-5
+#: how the log-decay is drawn: over most of (-5, 0) a channel and token, a
+#: whole sequence AT the bound, and a decay that is all but absent
+DECAYS = ("spread", "bound", "none")
+
+
+def _inputs(seq, decay, seed=0):
+    rs = np.random.RandomState(seed)
+    q, k = rs.randn(B, H, seq, DK), rs.randn(B, H, seq, DK)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(DK)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = {"spread": -5.0 / (1.0 + np.exp(-2.5 * rs.randn(B, H, seq, DK))),
+         "bound": np.full((B, H, seq, DK), kda.LOWER_BOUND),
+         "none": np.full((B, H, seq, DK), -1e-6)}[decay]
+    beta = rs.uniform(0, 1, (B, H, seq))
+    return tuple(jnp.asarray(t, jnp.float32) for t in (q, k, rs.randn(B, H, seq, DV), g, beta))
+
+
+def _rel(got, want):
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _grads(fn, args, weights):
+    return jax.grad(lambda *a: jnp.sum(fn(*a) * weights), argnums=tuple(range(5)))(*args)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("seq", [128, 80])  # whole chunks, and a sequence that is padded
+def test_forward_follows_the_recurrence(seq, decay):
+    args = _inputs(seq, decay)
+    assert _rel(kda_rule(*args), kda_recurrence(*args)) < REL_TOL
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("seq", [128, 80])
+def test_all_five_gradients_follow_the_recurrence(seq, decay):
+    args = _inputs(seq, decay, seed=1)
+    weights = jnp.asarray(np.random.RandomState(2).randn(B, H, seq, DV), jnp.float32)
+    got, want = _grads(kda_rule, args, weights), _grads(kda_recurrence, args, weights)
+    # the gradient of a decay at the bound is the small difference of large terms: it is held to the keys' scale
+    scale = {name: jnp.linalg.norm(w) for name, w in zip(NAMES, want)}
+    scale["g"] = jnp.maximum(scale["g"], 0.1 * scale["k"])
+    for name, g, w in zip(NAMES, got, want):
+        assert float(jnp.linalg.norm(g - w) / scale[name]) < REL_TOL, name
+
+
+def test_custom_backward_is_the_forwards_own_gradient():
+    args = _inputs(128, "spread", seed=3)
+    weights = jnp.asarray(np.random.RandomState(4).randn(B, H, 128, DV), jnp.float32)
+    own = _grads(kda_rule, args, weights)
+    plain = _grads(lambda *a: kda_rule(*a, custom_backward=False), args, weights)
+    for name, a, b in zip(NAMES, own, plain):
+        assert _rel(a, b) < REL_TOL, name
+
+
+@pytest.mark.parametrize("decay", ["spread", "bound"])
+def test_one_decay_for_all_channels_is_the_gated_delta_rule(decay):
+    """``ops/gated_delta.py``'s rule is this one with ``g`` the same in every
+    channel: the two ops agree within float32 rounding, forward and backward."""
+    q, k, v, g, beta = _inputs(128, decay, seed=5)
+    log_alpha = g[..., 0]
+    uniform = jnp.broadcast_to(log_alpha[..., None], g.shape)
+    weights = jnp.asarray(np.random.RandomState(6).randn(B, H, 128, DV), jnp.float32)
+    assert _rel(kda_rule(q, k, v, uniform, beta), gated_delta_rule(q, k, v, log_alpha, beta)) < REL_TOL
+    d_kda = _grads(kda_rule, (q, k, v, uniform, beta), weights)
+    d_gdn = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a) * weights), argnums=tuple(range(5)))(
+        q, k, v, log_alpha, beta)
+    for name, a, b in zip(NAMES, d_kda, d_gdn):
+        a = a.sum(-1) if name == "g" else a  # the one number's gradient is the sum over its copies
+        # a decay's gradient at the bound is the small difference of large terms: held to the keys' scale
+        scale = jnp.maximum(jnp.linalg.norm(b), 0.1 * jnp.linalg.norm(d_gdn[1])) if name == "g" else jnp.linalg.norm(b)
+        assert float(jnp.linalg.norm(a - b) / scale) < REL_TOL, name
+
+
+@pytest.mark.parametrize("decay", ["spread", "bound"])
+def test_pallas_kernels_are_the_xla_scan(decay):
+    """Both kernels through the Pallas interpreter against their twin."""
+    args = _inputs(128, decay, seed=7)
+    weights = jnp.asarray(np.random.RandomState(8).randn(B, H, 128, DV), jnp.float32)
+    calls = REGISTRY.counter(COUNTER_TRAIN_KDA_KERNEL_CALLS, "", labels=("kernel",))
+    before = {name: calls.labels(kernel=name).value for name in ("kda_fwd", "kda_bwd")}
+    assert kda.implementation(interpret=True) == "pallas" and kda.implementation() == "xla_scan"
+    assert _rel(kda_rule(*args, interpret=True), kda_rule(*args)) < 1e-6
+    kernels = _grads(lambda *a: kda_rule(*a, interpret=True), args, weights)
+    for name, a, b in zip(NAMES, kernels, _grads(kda_rule, args, weights)):
+        assert float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-3)) < 1e-6, name
+    assert calls.labels(kernel="kda_fwd").value >= before["kda_fwd"] + 2  # the forward alone, and under grad
+    assert calls.labels(kernel="kda_bwd").value == before["kda_bwd"] + 1
+
+
+def test_bfloat16_inputs_keep_their_type_and_a_float32_state():
+    args = _inputs(128, "spread", seed=9)
+    q, k, v = (t.astype(jnp.bfloat16) for t in args[:3])
+    o = kda_rule(q, k, v, *args[3:])
+    assert o.dtype == jnp.bfloat16
+    exact = kda_recurrence(q, k, v, *args[3:])  # float32 arithmetic on the same rounded inputs
+    assert _rel(o.astype(jnp.float32), exact) < 4e-3  # one bf16 rounding of the output
+    grads = _grads(lambda *a: kda_rule(*a).astype(jnp.float32), (q, k, v, *args[3:]), jnp.ones(v.shape))
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+
+
+def test_a_chunk_that_is_not_whole_blocks_is_refused():
+    with pytest.raises(ValueError, match="whole blocks"):
+        kda_rule(*_inputs(48, "spread"), chunk=24)
