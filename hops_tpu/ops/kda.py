@@ -32,18 +32,53 @@ the bound on ``g`` is used.
 One function, :func:`_chunk`, is a chunk of the rule for a block of heads:
 ``(q, k, v, c, b, S) -> (O, S')`` on ``(heads, C, d)`` values, products
 through ``ops/gated_delta.py``'s ``_dot`` (exact to float32 rounding) and
-its inverse by products alone. Two routes run it, chosen by
-:func:`implementation`:
+its inverse by products alone. A second, :func:`_chunk_bwd`, is its
+pull-back written by hand: it recomputes ``A``, ``T``, ``U``, ``W``, ``V'``
+and ``P`` once (the decay factors once for both score matrices and both
+their cotangents) and takes, with ``d`` for a cotangent and ``T^T dU``,
+``T^T dW`` computed as one product,
+
+    dV' = P^T dO + (K e^{c_C - c}) dS'      dP = lower[dO V'^T]
+    dU  = dV'                               dW = -dV' S^T
+    d(b V) = T^T dU                         d(b K e^c) = T^T dW
+    dA  = -strict_lower[T^T dT T^T] = -strict_lower[(T^T dU) U^T + (T^T dW) W^T]
+    dS  = Diag(e^{c_C}) dS' + (Q e^c)^T dO - W^T dV'
+    d(Q e^c) = dO S^T                       d(K e^{c_C - c}) = V' dS'^T
+
+``T``'s own cotangent ``dT = dU (b V)^T + dW (b K e^c)^T`` is never formed:
+between two ``T^T`` it is the second form of ``dA``, one product 2 d wide
+in place of the fourteen a traced pull-back of the inverse's seven takes.
+``dA`` and ``dP`` go back to the operands block by block
+(:func:`_decayed_scores_bwd`) through the SAME factor pairs as forward, so
+every sum is again of ``e^{c_i - c_j}`` with ``i >= j`` and no exponent
+leaves +-40; a cotangent with respect to an exponent is the operand times
+its own cotangent, so with ``dR``, ``dC`` those of a score matrix's row
+and column operands before their decay
+
+    dQ   = dR_P + e^c . d(Q e^c)            d(b K) = dR_A + e^c . d(b K e^c)
+    dK_- = dC_A + dC_P + e^{c_C - c} . d(K e^{c_C - c})         K where its exponent is -c
+    dc   = Q . dQ + (b K) . d(b K) - K . dK_-                  rises with the rows, falls with the columns
+    dc_C += sum_i [(K e^{c_C - c}) . d(K e^{c_C - c})]_i + e^{c_C} . sum_v [dS' . S]
+    dK   = b d(b K) + dK_-       dV = b T^T dU       db = sum_v [T^T dU . V] + sum_d [d(b K) . K]
+
+(``.`` elementwise; the block's reference row ``r_I`` takes no cotangent:
+the two sides' cancel, since no product depends on it). ``v`` and ``dO``
+enter their products in the type they arrive in: ``U = (T b_row) V`` with
+``b`` on ``T``'s columns, ``P^T dO``, ``dO V'^T``, ``dO S^T``, ``(Q e^c)^T
+dO`` are three bfloat16 passes where they are bfloat16, the same float32
+result as six on the upcast copy (``_dot``'s contract). ``jax.vjp`` of
+:func:`_chunk` is the oracle ``tests/test_kda.py`` holds all six
+cotangents to; it runs in no program (``custom_backward=False`` aside).
+
+Two routes run the two functions, chosen by :func:`implementation`:
 
 - on a TPU two Pallas kernels over the grid (head blocks, chunks), the
   chunks in order with the state in VMEM: ``kda_fwd`` (``O`` and the state
   entering each chunk) and ``kda_bwd`` (the chunks from the last down, the
-  state's cotangent in VMEM; a chunk's cotangents are ``jax.vjp`` of
-  :func:`_chunk` traced into the kernel body, so the backward is the
-  forward's derivative by construction). The chunk's C x C matrices, the
-  decayed copies of q and k and every float32 temporary stay in VMEM;
-- elsewhere a ``lax.scan`` over the chunks of the same function and of its
-  ``jax.vjp``: the kernels' twin, and what they are tested against.
+  state's cotangent in VMEM). The chunk's C x C matrices, the decayed
+  copies of q and k and every float32 temporary stay in VMEM;
+- elsewhere a ``lax.scan`` over the chunks of each: the kernels' twin, and
+  what they are tested against.
 
 :func:`kda_rule` is a ``jax.custom_vjp``: the forward keeps its inputs and
 the state entering each chunk (float32, ``seq / C`` x d_k x d_v a head), the
@@ -53,6 +88,7 @@ backward recomputes the chunk from them.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +108,13 @@ LOWER_BOUND = -5.0
 SUB = 16
 _MAX_EXPONENT = -LOWER_BOUND * SUB / 2 + 5.0
 #: heads of one grid step of the two kernels, worked on together as batched
-#: products. At 32 heads x 8,192 tokens x (128, 128) on a v5e the forward /
-#: forward and backward read, ms a call: 2 / 1 heads 10.12 / 44.17, 4 / 2 8.36
-#: / 32.08, 8 / 4 8.18 / 28.43, 8 / 8 8.07 / 27.77, 16 / 8 8.12 / 27.74, 16 / 4
-#: 7.97 / 28.26 (my chip runs, PR 35): from 8 heads on the kernels are bound
-#: by their arithmetic (float32 products in six bfloat16 passes, five
-#: exponentials of a chunk's keys), not by the wait between products
+#: products. At 32 heads x 8,192 tokens x (128, 128), bfloat16, on a v5e the
+#: forward / backward kernel alone read, ms a call: 8 / 8 heads 5.22 / 9.24,
+#: 16 / 8 5.22 / 9.24, 16 / 16 5.21 / 9.25, 4 / 4 5.21 / 9.25 (my chip runs,
+#: PR 36; the traced pull-back PR 35 had read 18.28 beside them, and 2 / 1
+#: heads 44 ms for the pair): the kernels are bound by their arithmetic
+#: (float32 products in six bfloat16 passes, five exponentials of a chunk's
+#: keys), not by the wait between products
 FWD_HEADS = 8
 BWD_HEADS = 8
 _VMEM_LIMIT = 100 * 1024 * 1024
@@ -118,6 +155,41 @@ def _decayed_scores(rows, cols, factors, *, strict):
     return jnp.where(row > col if strict else row >= col, scores, 0.0)
 
 
+def _as_row(column):
+    """(heads, C, 1) -> (heads, 1, C), the same values (a mask and a sum:
+    no transpose of a one-lane array)."""
+    row, col = _iotas(column.shape[1], column.shape[1])
+    return jnp.sum(jnp.where(row == col, column, 0.0), axis=1, keepdims=True)
+
+
+class _Parts(NamedTuple):
+    """What both directions make of a chunk before ``O``, (heads, ...)."""
+    q: jax.Array  # float32
+    k: jax.Array
+    factors: list  # :func:`_decay_factors`
+    t: jax.Array  # T = (I + A)^-1
+    gamma: jax.Array  # e^c
+    u: jax.Array
+    w: jax.Array
+    v_new: jax.Array  # V' = U - W S
+    p: jax.Array
+
+
+def _chunk_parts(q, k, v, c, beta, state_t) -> _Parts:
+    """``v`` stays in its own type and ``beta`` rides ``T``'s columns (``T (b
+    V) = (T b_row) V``), so that a bfloat16 ``v`` meets a float32 ``T`` in
+    the three passes of ``_dot``; ``e^c`` differs by channel and has to
+    ride ``K``."""
+    q, k = q.astype(F32), k.astype(F32)
+    factors = _decay_factors(c)
+    t = _unit_lower_inverse(_decayed_scores(beta * k, k, factors, strict=True))
+    gamma = jnp.exp(c)
+    u = _dot(t * _as_row(beta), v)
+    w = _dot(t, (beta * gamma) * k)
+    p = _decayed_scores(q, k, factors, strict=False)
+    return _Parts(q, k, factors, t, gamma, u, w, u - _dot(w, state_t, _NT), p)
+
+
 def _chunk(q, k, v, c, beta, state_t):
     """One chunk of the rule for a block of heads. ``q``, ``k`` (heads, C,
     d_k) and ``v`` (heads, C, d_v) in any float type, ``c`` (heads, C, d_k)
@@ -125,21 +197,75 @@ def _chunk(q, k, v, c, beta, state_t):
     C, 1), float32; ``state_t`` (heads, d_v, d_k) is the state entering the
     chunk, TRANSPOSED: the decay then scales its lanes and every product
     with it is a plain one. Returns ``(O (heads, C, d_v) float32, the state
-    leaving the chunk)``."""
-    q, k, v = (t.astype(F32) for t in (q, k, v))
-    factors = _decay_factors(c)
-    t = _unit_lower_inverse(_decayed_scores(beta * k, k, factors, strict=True))
-    gamma = jnp.exp(c)
-    u = _dot(t, beta * v)
-    w = _dot(t, (beta * gamma) * k)
-    v_new = u - _dot(w, state_t, _NT)
-    p = _decayed_scores(q, k, factors, strict=False)
-    o = _dot(gamma * q, state_t, _NT) + _dot(p, v_new)
+    leaving the chunk)``. :func:`_chunk_bwd` is its pull-back, written by
+    hand: an edit here has a second function to keep in step."""
+    x = _chunk_parts(q, k, v, c, beta, state_t)
+    o = _dot(x.gamma * x.q, state_t, _NT) + _dot(x.p, x.v_new)
     last = c[:, -1:, :]
-    return o, jnp.exp(last) * state_t + _dot(v_new, jnp.exp(last - c) * k, _TN)
+    return o, jnp.exp(last) * state_t + _dot(x.v_new, jnp.exp(last - c) * x.k, _TN)
 
 
-# -- the XLA route: a scan over the chunks of `_chunk` and of its vjp ----------
+def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
+    """The pull-back of the chunk's two score matrices, ``A`` =
+    :func:`_decayed_scores` of ``(a_rows, cols)`` and ``P`` of ``(p_rows,
+    cols)``, given their MASKED cotangents: ``(d a_rows, d p_rows, d
+    cols)``, each with respect to the operand before its decay. Block by
+    block with the forward's own factor pairs, so what is summed is again
+    ``e^{c_i - c_j}`` with ``i >= j`` and no exponent leaves +-40; the two
+    matrices share the decayed columns, so a block's rows of both go
+    through one product each way."""
+    d_a_rows, d_p_rows, d_cols = [], [], 0.0
+    for i, (on_rows, on_cols) in enumerate(factors):
+        block = slice(i * SUB, (i + 1) * SUB)
+        d_scores = jnp.concatenate([d_a[:, block], d_p[:, block]], axis=1)
+        decayed_rows = jnp.concatenate([a_rows[:, block] * on_rows, p_rows[:, block] * on_rows], axis=1)
+        d_rows = _dot(d_scores, cols * on_cols)
+        d_a_rows.append(d_rows[:, :SUB] * on_rows)
+        d_p_rows.append(d_rows[:, SUB:] * on_rows)
+        d_cols = d_cols + _dot(d_scores, decayed_rows, _TN) * on_cols
+    return jnp.concatenate(d_a_rows, axis=1), jnp.concatenate(d_p_rows, axis=1), d_cols
+
+
+def _chunk_bwd(q, k, v, c, beta, state_t, d_o, d_state):
+    """The pull-back of :func:`_chunk` at its arguments, by formula:
+    ``(d_q, d_k, d_v, d_c, d_beta, d_state_t)`` for the cotangents ``d_o``
+    (heads, C, d_v; any float type) of ``O`` and ``d_state`` (heads, d_v,
+    d_k) of the state leaving the chunk. The module docstring has the
+    formulas; ``tests/test_kda.py`` holds every one of the six to
+    ``jax.vjp(_chunk)``. ``v`` and ``d_o`` enter their products in their own
+    type (three passes of ``_dot`` where they are bfloat16)."""
+    dtypes = q.dtype, k.dtype, v.dtype
+    size, d_v_width = q.shape[1], v.shape[2]
+    row, col = _iotas(size, size)
+    x = _chunk_parts(q, k, v, c, beta, state_t)
+    q, k, gamma = x.q, x.k, x.gamma
+    last = c[:, -1:, :]
+    to_end, whole = jnp.exp(last - c), jnp.exp(last)  # e^{c_C - c}, e^{c_C}
+    q_dec, k_end, k_beta = gamma * q, to_end * k, beta * k
+    # O = (Q e^c) S + P V',  S' = e^{c_C} S + (K e^{c_C - c})^T V'
+    d_v_new = _dot(x.p, d_o, _TN) + _dot(k_end, d_state, _NT)
+    d_p = jnp.where(row >= col, _dot(d_o, x.v_new, _NT), 0.0)
+    d_q_dec = _dot(d_o, state_t)
+    d_k_end = _dot(x.v_new, d_state)
+    # V' = U - W S,  U = (T b_row) V,  W = T (b e^c K):  [T^T dU | T^T dW] are the right-hand sides' cotangents
+    d_rhs = _dot(x.t, jnp.concatenate([d_v_new, -_dot(d_v_new, state_t)], axis=2), _TN)
+    d_bv = d_rhs[:, :, :d_v_width]
+    # dA = -strict_lower[T^T dT T^T] = -strict_lower[(T^T dU) U^T + (T^T dW) W^T], one product 2 d wide
+    d_a = -jnp.where(row > col, _dot(d_rhs, jnp.concatenate([x.u, x.w], axis=2), _NT), 0.0)
+    d_k_beta, d_q, d_k_cols = _decayed_scores_bwd(d_a, d_p, k_beta, q, k, x.factors)
+    d_q = d_q + gamma * d_q_dec
+    d_k_beta = d_k_beta + gamma * d_rhs[:, :, d_v_width:]  # b K is A's row operand and, under e^c, W's right-hand side
+    d_k_falling = d_k_cols + to_end * d_k_end  # K where its exponent is -c: the scores' columns and K e^{c_C - c}
+    # an exponent's cotangent is the operand times its own: c rises with Q and b K, falls with K; c_C is in e^{c_C} too
+    d_last = jnp.sum(k_end * d_k_end, axis=1, keepdims=True) + whole * jnp.sum(d_state * state_t, axis=1, keepdims=True)
+    d_c = q * d_q + k_beta * d_k_beta - k * d_k_falling + jnp.where(_iotas(size, 1)[0] == size - 1, d_last, 0.0)
+    d_beta = jnp.sum(d_bv * v.astype(F32), axis=2, keepdims=True) + jnp.sum(d_k_beta * k, axis=2, keepdims=True)
+    d_state_in = whole * d_state + _dot(d_o, q_dec, _TN) - _dot(d_v_new, x.w, _TN)
+    d_k = beta * d_k_beta + d_k_falling
+    return (d_q.astype(dtypes[0]), d_k.astype(dtypes[1]), (beta * d_bv).astype(dtypes[2]), d_c, d_beta, d_state_in)
+
+
+# -- the XLA route: a scan over the chunks of `_chunk` and of `_chunk_bwd` -----
 
 
 def _forward_scan(q, k, v, c, beta):
@@ -156,9 +282,7 @@ def _forward_scan(q, k, v, c, beta):
 
 def _backward_scan(q, k, v, c, beta, states, d_o):
     def step(d_state, chunk):
-        *inputs, d_o_n = chunk
-        _, pull = jax.vjp(_chunk, *inputs)
-        *d_inputs, d_state = pull((d_o_n.astype(F32), d_state))
+        *d_inputs, d_state = _chunk_bwd(*chunk, d_state)
         return d_state, tuple(d_inputs)
 
     _, d_inputs = jax.lax.scan(step, jnp.zeros_like(states[0]), (q, k, v, c, beta, states, d_o), reverse=True)
@@ -184,16 +308,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, c_ref, beta_ref, o_ref, states_ref, state_s
 
 def _bwd_kernel(q_ref, k_ref, v_ref, c_ref, beta_ref, states_ref, d_o_ref,
                 d_q_ref, d_k_ref, d_v_ref, d_c_ref, d_beta_ref, d_state_scr):
-    """The chunks from the last down: a chunk's cotangents are the vjp of
-    :func:`_chunk` at what the forward kept, given ``dO`` and the cotangent
-    of the state it left (the scratch)."""
+    """The chunks from the last down: a chunk's cotangents are
+    :func:`_chunk_bwd` at what the forward kept, given ``dO`` and the
+    cotangent of the state it left (the scratch)."""
     _zero_before_the_first_chunk(d_state_scr)
-    inputs = tuple(ref[...] for ref in (q_ref, k_ref, v_ref, c_ref, beta_ref, states_ref))
-    _, pull = jax.vjp(_chunk, *inputs)
-    d_q, d_k, d_v, d_c, d_beta, d_state = pull((d_o_ref[...].astype(F32), d_state_scr[...]))
-    d_q_ref[...], d_k_ref[...], d_v_ref[...] = d_q, d_k, d_v
-    d_c_ref[...], d_beta_ref[...] = d_c, d_beta
-    d_state_scr[...] = d_state
+    inputs = tuple(ref[...] for ref in (q_ref, k_ref, v_ref, c_ref, beta_ref, states_ref, d_o_ref, d_state_scr))
+    (d_q_ref[...], d_k_ref[...], d_v_ref[...], d_c_ref[...], d_beta_ref[...],
+     d_state_scr[...]) = _chunk_bwd(*inputs)
 
 
 _m_kernel_calls = REGISTRY.counter(
@@ -308,7 +429,9 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     ``v``'s type; differentiable in all five. A sequence that is not whole
     chunks is padded with tokens that leave the state as it is (``beta`` 0,
     ``g`` 0). ``custom_backward=False`` differentiates the scan with
-    ``jax.grad`` (tests); ``interpret`` as :func:`implementation` reads it."""
+    ``jax.grad`` (tests: the oracle of :func:`_chunk_bwd`; float32 values
+    only, a traced pull-back through a three-pass product rounds to
+    bfloat16); ``interpret`` as :func:`implementation` reads it."""
     if chunk % SUB:
         raise ValueError(f"chunk {chunk} is not whole blocks of {SUB} rows")
     s = q.shape[2]

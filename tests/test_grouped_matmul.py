@@ -225,6 +225,32 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip, chu
     assert f"[1,{seq},{d},{n}]" not in text and f"[{seq},{d},{n}]" not in text and f"[1,{seq},{n},{d // 128},128]" not in text
 
 
+def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+    """The Kimi delta rule's two kernels at the Ling cell's shape (32 heads x
+    8,192 tokens x (128, 128), bf16), forward and the hand-written backward
+    (this file holds the one fixture that may load the TPU compiler): Mosaic
+    accepts the 16-row blocks, their concatenations, the 32-deep transposed
+    products of ``_decayed_scores_bwd`` and the VMEM a block of heads asks
+    for under ``_VMEM_LIMIT``. Nothing runs."""
+    from hops_tpu.ops import kda
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        wide = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+        decay = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32, sharding=one_chip)
+        beta = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=one_chip)
+
+        def grads(*x):
+            return jax.grad(lambda *y: kda.kda_rule(*y, interpret=False).astype(jnp.float32).sum(), argnums=range(5))(*x)
+
+        text = jax.jit(grads).lower(wide, wide, wide, decay, beta).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2 and "kda_fwd" in calls[0] + calls[1] and "kda_bwd" in calls[0] + calls[1]
+
+
 def test_four_chip_lm_step_compiles_to_gathers_of_weights_and_sums_to_the_owner(topo, monkeypatch):
     """``Strategy.step``'s default path over the four chips of a described
     v5e host, one Phi-3-mini block at the cell's widths and 2 x 4,096

@@ -1,8 +1,10 @@
 """The chunked Kimi delta rule (``hops_tpu/ops/kda.py``) against the
 token-by-token recurrence of ``benchmark/reference/ling_flash.py``, in
 float32 on the CPU: forward and all five gradients, the Pallas kernels in
-interpret mode against their XLA twin, and the rule with one decay for all
-channels against ``ops/gated_delta.py``.
+interpret mode against their XLA twin, the rule with one decay for all
+channels against ``ops/gated_delta.py``, and the chunk's hand-written
+pull-back (``_chunk_bwd``, what both routes' backward runs) against its
+oracle ``jax.vjp(_chunk)`` on one chunk.
 
 Tolerances: both sides are float32 and compute the same sums in another
 order, so they differ by rounding alone; 2e-5 relative (L2 over an array)
@@ -70,7 +72,60 @@ def test_all_five_gradients_follow_the_recurrence(seq, decay):
         assert float(jnp.linalg.norm(g - w) / scale[name]) < REL_TOL, name
 
 
+def _one_chunk(decay, dtype, seed=11):
+    """``_chunk``'s own arguments for a block of ``H`` heads (the first
+    sequence of :func:`_inputs`, one chunk long) with a state entering the
+    chunk, and the two cotangents; ``q``, ``k``, ``v`` and ``dO`` in
+    ``dtype``."""
+    q, k, v, g, beta = (t[0] for t in _inputs(kda.DEFAULT_CHUNK, decay, seed))
+    rs = np.random.RandomState(seed + 1)
+    state, d_state = (jnp.asarray(0.5 * rs.randn(H, DV, DK), jnp.float32) for _ in range(2))
+    d_o = jnp.asarray(rs.randn(*v.shape), dtype)
+    q, k, v = (t.astype(dtype) for t in (q, k, v))
+    return (q, k, v, jnp.cumsum(g, axis=1), beta[..., None], state), (d_o, d_state)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("decay", DECAYS)
+def test_hand_written_chunk_backward_is_the_vjp_of_the_chunk(decay, dtype):
+    """All six cotangents of ``_chunk_bwd`` against ``jax.vjp(_chunk)``, with
+    a state entering the chunk and a cotangent for the one leaving it. In
+    bfloat16 ``v`` and ``dO`` take the three-pass products, and the oracle
+    is the vjp at float32 copies of the same rounded values (a traced vjp
+    through a three-pass product rounds ``T``'s cotangent to bfloat16): the
+    float32 cotangents still agree to float32 rounding, the three that
+    leave in bfloat16 to one rounding of the result."""
+    args, (d_o, d_state) = _one_chunk(decay, dtype)
+    _, pull = jax.vjp(kda._chunk, *(t.astype(jnp.float32) for t in args))
+    want = pull((d_o.astype(jnp.float32), d_state))
+    got = kda._chunk_bwd(*args, d_o, d_state)
+    names = ("q", "k", "v", "c", "beta", "state")
+    assert [g.dtype for g in got] == [dtype] * 3 + [jnp.float32] * 3
+    got = [g.astype(jnp.float32) for g in got]
+    scale = {name: jnp.linalg.norm(w) for name, w in zip(names, want)}
+    scale["c"] = jnp.maximum(scale["c"], 0.1 * scale["k"])  # at the bound: the small difference of large terms
+    for name, g, w in zip(names, got, want):
+        tol = 4e-3 if dtype == jnp.bfloat16 and name in "qkv" else REL_TOL
+        assert float(jnp.linalg.norm(g - w) / scale[name]) < tol, name
+
+
+def test_bfloat16_values_under_beta_on_the_inverses_columns_follow_the_float32_recurrence():
+    """``U = (T b_row) V`` with ``V`` left in bfloat16 (three passes) is ``T
+    (b V)`` to float32 rounding: the forward before its output is rounded,
+    against float32 arithmetic on the same rounded inputs."""
+    q, k, v, g, beta = _inputs(128, "spread", seed=12)
+    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+
+    def chunks(t):
+        return jnp.swapaxes(t.reshape(B * H, -1, kda.DEFAULT_CHUNK, *t.shape[3:]), 0, 1)
+
+    o, _ = kda._forward_scan(chunks(q), chunks(k), chunks(v), jnp.cumsum(chunks(g), axis=2), chunks(beta[..., None]))
+    assert o.dtype == jnp.float32
+    assert _rel(jnp.swapaxes(o, 0, 1).reshape(B, H, 128, DV), kda_recurrence(q, k, v, g, beta)) < REL_TOL
+
+
 def test_custom_backward_is_the_forwards_own_gradient():
+    """The hand-written route against ``jax.grad`` through the forward scan."""
     args = _inputs(128, "spread", seed=3)
     weights = jnp.asarray(np.random.RandomState(4).randn(B, H, 128, DV), jnp.float32)
     own = _grads(kda_rule, args, weights)
