@@ -12,14 +12,20 @@ top-8, no capacity):
 - dispatch: the ``tokens x top_k`` (token, slot) rows are ordered by
   expert with a stable sort and gathered — no capacity, no dropped
   token, no ``(tokens, experts, capacity)`` mask; shapes are static
-  (always ``tokens x top_k`` rows), so one compile whatever the routing;
+  (always ``tokens x top_k`` rows where every expert is held, chunks of
+  :func:`_held_bound` rows where a share is), so one compile whatever
+  the routing;
 - experts: SwiGLU, three grouped matmuls over the ragged groups
   (``ops/grouped_matmul.py``: a Pallas kernel named ``moe_gmm`` on the
   TPU, ``jax.lax.ragged_dot`` elsewhere; only the routed work is done);
 - combine: rows go back to token order and each token's ``top_k`` rows
-  are summed. Dispatch and combine are each other's transposes and both
-  run as gathers (by the sort order and by its inverse), forward and
-  backward: no scatter-add anywhere.
+  are summed. Dispatch and combine are each other's transposes. Where
+  every expert is held both run as gathers of all ``tokens x top_k``
+  rows (by the sort order and by its inverse), forward and backward: a
+  scatter-add of that many rows is what the rule "no scatter-add" keeps
+  out. Where a share is held the dispatch gathers a chunk's rows and the
+  combine adds them to their tokens as a 0/1 matrix product
+  (:func:`_add_rows`), forward and backward: still no scatter-add.
 
 Inside ``Strategy.step`` on a data mesh the routing runs per device
 shard (``parallel.mesh.per_shard``): each chip sorts its own tokens
@@ -42,6 +48,22 @@ chip's share of such a layer under expert parallelism, without the exchange:
 the router spans all ``num_experts``, the parameters hold ``count`` experts
 from id ``first`` on, and only the rows routed to them are computed; what the
 absent experts would add is left out (the other chips' part of the sum).
+
+A held share (``held_experts``, or ``expert_axis`` under an enclosing
+``shard_map``) moves only the rows its experts take (:func:`_held_share`).
+The router and the sort still see every routed row; the sort puts the held
+experts' rows first, and from there the layer works on ``bound`` sorted rows
+at a time: gather them, run the experts, add each row to its token. ``bound``
+is static, four times the rows an even routing sends the share
+(:func:`_held_bound`; 4,096 of 65,536 for 8 of 512 experts), and the loop
+over chunks is as long as the held rows need: one chunk unless the routing
+is more than four times off even, then as many as it takes, so no row is
+ever dropped and no capacity appears anywhere. ``moe_stats/held_rows`` counts
+the share's rows and ``moe_stats/held_overflow`` is 1 where a second chunk
+ran; ``hops_tpu_train_moe_traces_total{dispatch}`` says at trace time which
+of the two dispatches (``all`` | ``held``) a layer holds. It is also the send
+side of the exchange expert parallelism on chips needs: the rows a chip
+gathers for one peer's experts.
 """
 
 from __future__ import annotations
@@ -53,8 +75,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from hops_tpu.ops.grouped_matmul import grouped_matmul, implementation
-from hops_tpu.parallel.mesh import per_shard
+from hops_tpu.ops.grouped_matmul import DEFAULT_TILING, grouped_matmul, implementation
+from hops_tpu.parallel.mesh import per_shard, pvary
 from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_SHARED
 
@@ -64,8 +86,8 @@ EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 _m_moe_traces = REGISTRY.counter(
     "hops_tpu_train_moe_traces_total",
-    "Routed feed-forward layers traced, by the grouped matmul they hold",
-    labels=("impl",),
+    "Routed feed-forward layers traced, by the grouped matmul they hold and the rows they move (all | held)",
+    labels=("impl", "dispatch"),
 )
 
 
@@ -107,6 +129,136 @@ _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
+#: a held share works on this many times the rows an even routing would
+#: send its experts, a chunk of that size at a time (module docstring)
+_HELD_BOUND = 4
+
+
+def _held_bound(n_rows: int, n_local: int, num_experts: int) -> int:
+    """Rows a held share of ``n_local`` of ``num_experts`` experts works on
+    at a time: :data:`_HELD_BOUND` times the even share of ``n_rows``
+    routed rows, in whole row tiles of the grouped matmul, at most all."""
+    tile = DEFAULT_TILING[0]
+    share = -(-_HELD_BOUND * n_rows * n_local // num_experts)
+    return min(n_rows, -(-share // tile) * tile)
+
+
+def _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held=lambda rows: rows):
+    """The experts over sorted ``rows``, each output row times its
+    ``weight``; ``held`` zeroes what the grouped matmul leaves unspecified
+    (rows past the groups), on the way into and out of every call."""
+    with jax.named_scope(SCOPE_EXPERTS):
+        gate = held(grouped_matmul(rows, w_gate, sizes)).astype(jnp.float32)
+        up = held(grouped_matmul(rows, w_up, sizes)).astype(jnp.float32)
+        # p_e * W_down(h) == W_down(p_e * h): the weighting rides the
+        # activation's fusion at the experts' width, not the model's;
+        # float32 inside the fusion, one rounding on the way out
+        act = (nn.silu(gate) * up * weight[:, None]).astype(rows.dtype)
+        return held(grouped_matmul(act, w_down, sizes))
+
+
+def _add_rows(rows, token, n_tokens):
+    """``(n_tokens, width)`` float32: row ``t`` is the sum of the ``rows``
+    whose ``token`` is ``t``, as a 0/1 matrix product on the MXU (exact:
+    the ones are exact in any type and the sum is float32). The transpose
+    of the gather ``x[token]``, for a few thousand rows; a scatter-add of
+    as many rows is the slower of the two on the chip (PERF.md, PR 40)."""
+    onehot = (jnp.arange(n_tokens)[:, None] == token[None, :]).astype(rows.dtype)
+    return jax.lax.dot(onehot, rows, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _held_chunk(c, x, top_p, order, local_sizes, k, bound):
+    """Chunk ``c`` of the sorted rows, ``bound`` of them from row ``c *
+    bound`` on: ``(token, slot, held, sizes, rows, weight)``, the token and
+    the (token, slot) index of each row, a function that zeroes the rows
+    that reached no held expert, the part of every held expert's group
+    that lies in the chunk, and the chunk's rows of ``x`` and ``top_p``."""
+    with jax.named_scope(SCOPE_DISPATCH):
+        ends = jnp.cumsum(local_sizes)
+        at = c * bound + jnp.arange(bound)
+        here = at < ends[-1]
+
+        def held(rows):
+            return jnp.where(here.reshape(-1, *[1] * (rows.ndim - 1)), rows, 0)
+
+        def inside(edge):
+            return jnp.clip(edge - c * bound, 0, bound)
+
+        slot = order[jnp.minimum(at, order.shape[0] - 1)]
+        token = slot // k
+        return token, slot, held, inside(ends) - inside(ends - local_sizes), held(x[token]), held(top_p[slot])
+
+
+def _zeros_for(shape, *operands):
+    """Float32 zeros to start a loop's sum from. Under ``shard_map`` a
+    carry must enter the loop varying over the mesh axes it leaves
+    varying over: those any of the loop's ``operands`` varies over."""
+    axes = frozenset().union(*(jax.typeof(operand).vma for operand in operands))
+    return pvary(jnp.zeros(shape, jnp.float32), tuple(axes))
+
+
+def _held_chunks(local_sizes, bound):
+    """Chunks of ``bound`` sorted rows that hold a row of a held expert."""
+    return (jnp.sum(local_sizes) + bound - 1) // bound
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
+    """The expert pass of a chip that holds a share of the experts: ``x``
+    (tokens, d), ``top_p`` (tokens * k,), ``order`` the sort that puts the
+    held experts' rows first, ``local_sizes`` their groups. Works on
+    ``bound`` sorted rows at a time and stops after the last chunk with a
+    held row (one, unless the routing sends the share more than
+    :data:`_HELD_BOUND` times its even load): a gather of ``bound`` rows,
+    the experts, and :func:`_add_rows` into the float32 result. A loop of a
+    length the routing decides has no transpose of JAX's own, so the
+    backward pass is written out: the same loop, each chunk's experts
+    run again and pulled back (only the arguments are kept for it)."""
+    n_tokens = x.shape[0]
+
+    def body(c, out):
+        token, _, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
+        out_rows = _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held)
+        with jax.named_scope(SCOPE_COMBINE):
+            return out + _add_rows(out_rows, token, n_tokens)
+
+    operands = (x, top_p, w_gate, w_up, w_down, order, local_sizes)
+    out = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body, _zeros_for(x.shape, *operands))
+    return out.astype(x.dtype)
+
+
+def _held_share_fwd(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
+    return (_held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound),
+            (x, top_p, w_gate, w_up, w_down, order, local_sizes))
+
+
+def _held_share_bwd(k, bound, res, g):
+    x, top_p, w_gate, w_up, w_down, order, local_sizes = res
+    n_tokens = x.shape[0]
+
+    def body(c, grads):
+        token, slot, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
+        _, pull = jax.vjp(functools.partial(_swiglu_experts, sizes=sizes, held=held),
+                          rows, weight, w_gate, w_up, w_down)
+        with jax.named_scope(SCOPE_COMBINE):
+            d_out_rows = g[token]
+        d_rows, d_weight, *d_w = pull(d_out_rows)
+        with jax.named_scope(SCOPE_DISPATCH):
+            # a token's k weights are a row of k: the one 0/1 matrix serves both
+            d_weight = held(d_weight)[:, None] * (slot[:, None] % k == jnp.arange(k))
+            found = (_add_rows(held(d_rows), token, n_tokens),
+                     _add_rows(d_weight, token, n_tokens).reshape(top_p.shape), *d_w)
+        return tuple(total + part.astype(jnp.float32) for total, part in zip(grads, found))
+
+    primals = (x, top_p, w_gate, w_up, w_down)
+    grads = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body,
+                              tuple(_zeros_for(p.shape, *res, g) for p in primals))
+    return (*(grad.astype(p.dtype) for grad, p in zip(grads, primals)), None, None)
+
+
+_held_share.defvjp(_held_share_fwd, _held_share_bwd)
+
+
 def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, first=0):
     """The dropless expert pass over one shard's tokens.
 
@@ -114,40 +266,33 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
     weights and ids; ``w_*`` the stacks of the ``len(w_gate)`` experts
     from id ``first`` on (all of them unless the caller holds a slice).
     Returns ``(out (b, s, d), rows per expert (1, num_experts))``; the
-    leading 1 is the batch-leading partial ``per_shard`` stacks.
+    leading 1 is the batch-leading partial ``per_shard`` stacks. A caller
+    that holds a slice gets a third, ``(1,)``: 1 when the slice took more
+    rows than :func:`_held_bound` and :func:`_held_share` ran on.
     """
     b, s, d = x.shape
     k = top_ids.shape[-1]
     n_rows = b * s * k
     n_local = w_gate.shape[0]
+    share = n_local < num_experts
     flat_ids = top_ids.reshape(n_rows)
     with jax.named_scope(SCOPE_DISPATCH):
         # held experts first, in id order: their rows are the leading
         # sum(local sizes) rows whatever slice of the experts is held
         order = jnp.argsort((flat_ids - first) % num_experts, stable=True)
-        inverse = jnp.argsort(order)
+        inverse = None if share else jnp.argsort(order)  # a held share never undoes the sort
         sizes = jnp.sum(flat_ids[:, None] == jnp.arange(num_experts)[None, :], axis=0, dtype=jnp.int32)
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, first, n_local)
-
-        here = None if n_local == num_experts else jnp.arange(n_rows) < jnp.sum(local_sizes)
-
-        def held(rows):
-            """Rows of experts held elsewhere: the grouped matmul leaves
-            them unspecified, so they enter and leave every call as 0."""
-            if here is None:
-                return rows
-            return jnp.where(here.reshape(-1, *[1] * (rows.ndim - 1)), rows, 0)
-
-        rows = held(_to_sorted(x.reshape(b * s, d), order, inverse, k))
-        weight = held(_to_sorted(top_p.reshape(n_rows), order, inverse, 1))
-    with jax.named_scope(SCOPE_EXPERTS):
-        gate = held(grouped_matmul(rows, w_gate, local_sizes)).astype(jnp.float32)
-        up = held(grouped_matmul(rows, w_up, local_sizes)).astype(jnp.float32)
-        # p_e * W_down(h) == W_down(p_e * h): the weighting rides the
-        # activation's fusion at the experts' width, not the model's;
-        # float32 inside the fusion, one rounding on the way out
-        act = (nn.silu(gate) * up * weight[:, None]).astype(rows.dtype)
-        out_rows = held(grouped_matmul(act, w_down, local_sizes))
+    if share:
+        bound = _held_bound(n_rows, n_local, num_experts)
+        out = _held_share(x.reshape(b * s, d), top_p.reshape(n_rows), w_gate, w_up, w_down, order, local_sizes,
+                          k, bound)
+        overflow = (_held_chunks(local_sizes, bound) > 1).astype(jnp.int32)
+        return out.reshape(b, s, d), sizes[None], overflow[None]
+    with jax.named_scope(SCOPE_DISPATCH):
+        rows = _to_sorted(x.reshape(b * s, d), order, inverse, k)
+        weight = _to_sorted(top_p.reshape(n_rows), order, inverse, 1)
+    out_rows = _swiglu_experts(rows, weight, w_gate, w_up, w_down, local_sizes)
     with jax.named_scope(SCOPE_COMBINE):
         out = _from_sorted(out_rows, order, inverse, k)
     return out.reshape(b, s, d), sizes[None]
@@ -177,8 +322,9 @@ class MoEMLP(nn.Module):
       inside pipeline stages). Params hold only the local
       ``num_experts // expert_shards`` experts; routing still spans all
       ``num_experts`` (the router is replicated), each device computes
-      the rows of its local experts — a contiguous slice of the sorted
-      groups — and a ``psum`` over ``expert_axis`` combines.
+      the rows of its local experts — the leading rows of its own sort,
+      a chunk at a time (:func:`_held_share`) — and a ``psum`` over
+      ``expert_axis`` combines.
     """
 
     num_experts: int = 8
@@ -246,12 +392,16 @@ class MoEMLP(nn.Module):
                 ((e_local, dm, hidden), (e_local, dm, hidden), (e_local, hidden, dm)),
             )
         )
-        _m_moe_traces.inc(impl=implementation(
-            jax.ShapeDtypeStruct((b * s * self.top_k, dm), self.dtype), w_gate))
+        n_rows = b * s * self.top_k
+        held = e_local < self.num_experts
+        _m_moe_traces.inc(
+            impl=implementation(jax.ShapeDtypeStruct(
+                (_held_bound(n_rows, e_local, self.num_experts) if held else n_rows, dm), self.dtype), w_gate),
+            dispatch="held" if held else "all")
         first = 0 if self.held_experts is None else self.held_experts[0]
         if self.expert_axis is not None:
             first = jax.lax.axis_index(self.expert_axis) * e_local
-        out, rows_per_expert = per_shard(
+        out, rows_per_expert, *overflow = per_shard(
             functools.partial(_routed_experts, num_experts=self.num_experts, first=first),
             op="moe", replicated=(3, 4, 5),
         )(x.astype(self.dtype), top_p, top_ids, w_gate, w_up, w_down)
@@ -283,8 +433,11 @@ class MoEMLP(nn.Module):
         self.sow("moe_stats", "rows_per_expert", rows_per_expert)
         self.sow("moe_stats", "expert_ids", top_ids)
         if self.held_experts is not None:
-            # of the rows above, those that reached the experts held here
+            # of the rows above, those that reached the experts held here,
+            # and 1 if on some shard they were more than a chunk
             self.sow("moe_stats", "held_rows", jax.lax.dynamic_slice_in_dim(rows_per_expert, first, e_local).sum())
+            if overflow:
+                self.sow("moe_stats", "held_overflow", overflow[0].max())
         return out
 
     def _sigmoid_choice(self, router_logits):
@@ -375,6 +528,15 @@ def max_load_over_mean(variables: Any) -> jax.Array | float:
     if not rows:
         return 0.0
     return jnp.max(jnp.stack([jnp.max(r) / jnp.mean(r.astype(jnp.float32)) for r in rows]))
+
+
+def held_overflows(variables: Any) -> jax.Array | None:
+    """How many routed layers of a ``mutable=["moe_stats"]`` apply held a
+    share of the experts that took more rows than :func:`_held_bound`, so
+    that :func:`_held_share` ran on past its first chunk (None when no
+    layer holds a share)."""
+    flags = [f for v in _sown(variables, "moe_stats", "held_overflow") for f in v]
+    return jnp.sum(jnp.stack(flags)) if flags else None
 
 
 def expert_specs(params: Any, axis: str = "expert") -> Any:

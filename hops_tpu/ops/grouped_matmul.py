@@ -27,7 +27,9 @@ Off the TPU (``interpret=None``) the twin ``jax.lax.ragged_dot`` runs;
 ``interpret=True`` forces the kernels through the Pallas interpreter
 (tests). Rows past ``sum(group_sizes)`` belong to no group: what the
 result holds for them is unspecified and their gradient is not computed,
-so a caller that holds only some of the groups masks them.
+so a caller whose rows may outnumber its groups' masks them on the way
+in and out of every call: ``models/moe.py:_held_share``, whose chunk of
+sorted rows ends past the last row a held expert takes, is the one.
 """
 
 from __future__ import annotations
@@ -95,6 +97,14 @@ def _gmm_kernel(group_ids, tile_ids, offsets, n_items, lhs, rhs, out, acc, *, tm
             out[...] = jnp.where(mask, acc[...], out[...].astype(jnp.float32)).astype(out.dtype)
 
 
+# The two call builders are jitted so that a model's routed layers share one
+# trace of each kernel body a geometry, and inlined so that the enclosing
+# program still holds every ``pallas_call`` under its layer's own scope
+# (``ops/attention.py`` has the same builders since PR 28).
+_per_geometry = functools.partial(jax.jit, inline=True)
+
+
+@_per_geometry(static_argnames=("tiling", "transpose_rhs", "interpret"))
 def _gmm(lhs, rhs, group_sizes, *, tiling, transpose_rhs, interpret):
     """``lhs`` (m, k) by group with ``rhs`` (g, k, n), or (g, n, k) when
     ``transpose_rhs``; result (m, n) of ``lhs``'s type."""
@@ -151,6 +161,7 @@ def _tgmm_kernel(group_ids, tile_ids, offsets, n_items, lhs, grad, out, acc, *, 
             out[...] = acc[...].astype(out.dtype)
 
 
+@_per_geometry(static_argnames=("tiling", "interpret"))
 def _tgmm(lhs, grad, group_sizes, *, tiling, interpret):
     """``lhs[rows of g].T @ grad[rows of g]`` for every group: ``lhs``
     (m, k), ``grad`` (m, n), result (g, k, n) of ``lhs``'s type."""

@@ -153,7 +153,7 @@ GAUGE_TRAIN_STATE_BYTES = "hops_tpu_train_state_bytes"
 #: an op a compiled step holds, and is added to while the step is traced.
 #: ``hops_tpu_train_per_shard_traces_total{op}`` (``parallel/mesh.py``),
 #: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``),
-#: ``hops_tpu_train_moe_traces_total{impl}`` (``models/moe.py``),
+#: ``hops_tpu_train_moe_traces_total{impl, dispatch}`` (``models/moe.py``),
 #: ``hops_tpu_train_linattn_traces_total{impl}``
 #: (``models/linear_attention.py``),
 #: ``hops_tpu_train_ssm_traces_total{impl}`` (``models/state_space.py``),

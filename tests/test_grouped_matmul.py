@@ -4,6 +4,8 @@ gradients, float32, so 1e-5 of the largest value is rounding alone), the
 schedule's invariants, the fallback to the twin, and a compile of the
 three kernels for a described v5e at OLMoE's widths."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,6 +81,35 @@ def test_shapes_that_are_not_whole_tiles_take_the_twin():
     assert gm.implementation(*_operands(), tiling=TILING) == "ragged_dot"  # off the TPU, unless forced
     out = gm.grouped_matmul(lhs, rhs, jnp.asarray([16, 16, 16], jnp.int32), interpret=True)
     np.testing.assert_allclose(out, 64.0)
+
+
+def test_layers_of_one_geometry_share_one_trace_of_each_kernel_body(monkeypatch):
+    """Three routed layers' matmuls of one shape, forward and backward: the
+    body of ``moe_gmm`` is traced for the forward product and for d lhs (the
+    transposed geometry), the ragged contraction's once, not once a layer."""
+    traced = {"_gmm_kernel": 0, "_tgmm_kernel": 0}
+
+    def counting(name, body):
+        def kernel(*refs, **static):
+            traced[name] += 1
+            return body(*refs, **static)
+        return kernel
+
+    for name in traced:
+        monkeypatch.setattr(gm, name, counting(name, getattr(gm, name)))
+    m, k, n, layers = 112, 128, 384, 3  # a shape no other test of this process has traced
+    rs = np.random.RandomState(1)
+    lhs, stacks = jnp.asarray(rs.randn(m, k), jnp.float32), jnp.asarray(rs.randn(layers, 2, k, n), jnp.float32)
+    sizes = jnp.asarray([40, 72], jnp.int32)
+
+    def loss(interpret, lhs, stacks):
+        return sum(jnp.sum(gm.grouped_matmul(lhs, rhs, sizes, interpret=interpret) ** 2) for rhs in stacks)
+
+    got = jax.jit(jax.grad(functools.partial(loss, True), argnums=(0, 1)))(lhs, stacks)
+    want = jax.grad(functools.partial(loss, None), argnums=(0, 1))(lhs, stacks)
+    assert traced == {"_gmm_kernel": 2, "_tgmm_kernel": 1}
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=0)
 
 
 # -- the real widths, compiled for a chip that is described and not attached ---
@@ -249,6 +280,42 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 2 and "kda_fwd" in calls[0] + calls[1] and "kda_bwd" in calls[0] + calls[1]
+
+
+def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, monkeypatch):
+    """The routed layer of the Ling cell, forward and backward (8,192 tokens
+    x 2,560, top-8 of 512 experts, experts 0-7 held; this file holds the one
+    fixture that may load the TPU compiler): the grouped matmuls are the
+    ``moe_gmm`` kernels over a chunk of 4,096 rows, no array of the 65,536
+    routed rows at the model's or the experts' width is left, no scatter
+    stands in for the combine, and the temporaries are a third of the
+    1,075 MB the layer needed while both gathers moved every row (compile,
+    PR 40). Nothing runs."""
+    from hops_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not their twin
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        tokens, d, k, experts, held, hidden = 8192, 2560, 8, 512, 8, 768
+
+        def shape(dims, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+        def loss(x, top_p, w_gate, w_up, w_down, ids):
+            out = moe._routed_experts(x, top_p, ids, w_gate, w_up, w_down, num_experts=experts)[0]
+            return out.astype(jnp.float32).sum()
+
+        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+            shape((1, tokens, d)), shape((1, tokens, k), jnp.float32), shape((held, d, hidden)),
+            shape((held, d, hidden)), shape((held, hidden, d)), shape((1, tokens, k), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    assert moe._held_bound(tokens * k, held, experts) == 4096
+    text = compiled.as_text()
+    assert sum("tpu_custom_call" in line and "moe_gmm" in line for line in text.splitlines()) == 8  # 2 again + 6 back
+    assert f"[{tokens * k},{d}]" not in text and f"[{tokens * k},{hidden}]" not in text and " scatter(" not in text
+    assert f"[4096,{d}]" in text and compiled.memory_analysis().temp_size_in_bytes < 400e6
 
 
 def test_four_chip_lm_step_compiles_to_gathers_of_weights_and_sums_to_the_owner(topo, monkeypatch):

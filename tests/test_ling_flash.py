@@ -11,21 +11,23 @@ whole-sequence matmul against blocks of it): ~1e-5 relative on the
 gradients, checked at 2e-4.
 """
 
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import ling_flash as reference
-from hops_tpu.models import common
+from hops_tpu.models import common, moe
 from hops_tpu.models.linear_attention import GatedDeltaNet, KimiDeltaAttention
 from hops_tpu.models.moe import MoEMLP, sum_sown_losses, updated_router_bias
 from hops_tpu.models.transformer import (
     FFN_TYPES, LAYER_TYPES, MLP, LatentAttention, TransformerLM, make_lm_train_step)
 from hops_tpu.ops.attention import attention_reference, flash_attention
-from hops_tpu.ops.grouped_matmul import fit_tiling
+from hops_tpu.ops.grouped_matmul import fit_tiling, grouped_matmul
 from hops_tpu.ops.xent import chunked_softmax_xent
 from hops_tpu.parallel import mesh as mesh_lib
 from hops_tpu.parallel.strategy import Strategy
@@ -211,6 +213,116 @@ def test_the_shares_add_up_to_the_uncut_layer(x, routed):
     assert held_rows == 2 * SEQ * 4  # every routed row reached exactly one share
 
 
+# -- a held share moves only the rows its experts take ------------------------------------
+
+
+def _held_traces() -> float:
+    traces = REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch"))
+    return traces.value(impl="ragged_dot", dispatch="held")
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "interpret"])
+def test_a_held_share_is_its_part_of_the_layer_that_holds_every_expert(impl, monkeypatch):
+    """Experts 6 and 7 of 32 over 1,024 routed rows (bound 256, a chunk)
+    against all 32 with every other expert's matrices zero, on one routing:
+    the result and the gradients of ``x``, the weights and the three stacks."""
+    if impl == "interpret":
+        monkeypatch.setattr(moe, "grouped_matmul", lambda *a: grouped_matmul(*a, interpret=True))
+    first, count, experts, top_k, d = 6, 2, 32, 4, 128
+    keys = jax.random.split(jax.random.PRNGKey(7), 6)
+    x, seed = jax.random.normal(keys[0], (1, 256, d)), jax.random.normal(keys[1], (1, 256, d))
+    ids = jnp.argsort(jax.random.uniform(keys[2], (1, 256, experts)), axis=-1)[..., :top_k].astype(jnp.int32)
+    top_p = jax.random.uniform(keys[3], (1, 256, top_k), minval=0.1)
+    stacks = [0.1 * jax.random.normal(key, (count, d, d)) for key in jax.random.split(keys[4], 3)]
+    assert 0 < int(jnp.sum((ids >= first) & (ids < first + count))) < moe._held_bound(256 * top_k, count, experts) == 256
+
+    def run(first, x, top_p, *stacks):
+        out, rows, *overflow = moe._routed_experts(x, top_p, ids, *stacks, num_experts=experts, first=first)
+        return jnp.sum(out * seed), (out, rows, overflow)
+
+    def whole(x, top_p, *stacks):
+        return run(0, x, top_p, *(jnp.zeros((experts, d, d)).at[first: first + count].set(w) for w in stacks))
+
+    (_, (want, want_rows, none)), want_grads = jax.value_and_grad(whole, argnums=range(5), has_aux=True)(x, top_p, *stacks)
+    (_, (got, rows, overflow)), grads = jax.value_and_grad(
+        functools.partial(run, first), argnums=range(5), has_aux=True)(x, top_p, *stacks)
+    assert none == [] and int(overflow[0][0]) == 0
+    np.testing.assert_array_equal(rows, want_rows)
+    assert _rel(got, want) < 1e-5
+    for name, g, w in zip(("x", "top_p", "w_gate", "w_up", "w_down"), grads, want_grads):
+        assert _rel(g, w) < 1e-5, name
+    # the share's program moves a chunk's rows, never the 1,024
+    program = str(jax.make_jaxpr(functools.partial(run, first))(x, top_p, *stacks))
+    assert "f32[256,128]" in program and "f32[1024,128]" not in program
+    assert "f32[1024,128]" in str(jax.make_jaxpr(whole)(x, top_p, *stacks))
+
+
+@pytest.mark.parametrize("crowded", [False, True], ids=["spread", "crowded"])
+def test_a_share_past_its_bound_drops_no_row(x, crowded):
+    """Experts 8 and 9 of 32 (bound 256 of 1,024 routed rows). With a bias
+    that makes every token choose both, the share takes 512 rows, two chunks:
+    the layer is still the reference's restricted to the share, rows counted."""
+    layer = _moe(num_experts=32, held_experts=(8, 2))
+    params = layer.init(jax.random.PRNGKey(8), x)["params"]
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(9), (32,))
+    bias = {"bias": bias.at[8:10].add(10.0) if crowded else bias}
+
+    def program(params, x):
+        out, mods = layer.apply({"params": params, "router_bias": bias}, x, mutable=["moe_stats"])
+        return jnp.sum(jnp.square(out)), (out, mods["moe_stats"])
+
+    def ref(params, x):
+        with jax.default_matmul_precision("highest"):
+            y, _, _ = reference.moe_ffn(x, params, bias["bias"], top_k=4, n_group=4, topk_group=2, scale=2.5, held=(8, 2))
+        return jnp.sum(jnp.square(y)), y
+
+    (_, (out, stats)), grads = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(params, x)
+    (_, y), want = jax.value_and_grad(ref, argnums=(0, 1), has_aux=True)(params, x)
+    held_rows = int(stats["held_rows"][0])
+    assert held_rows == int(jnp.sum((stats["expert_ids"][0] == 8) | (stats["expert_ids"][0] == 9)))
+    assert (held_rows == 512, int(stats["held_overflow"][0])) == (crowded, int(crowded)) and (crowded or held_rows < 256)
+    assert _rel(out, y) < REL_TOL and _rel(grads, want) < REL_TOL
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+def test_a_share_under_the_expert_axis_is_a_held_share(x, shards):
+    """32 experts over 4 or 8 virtual devices under ``shard_map``: every
+    device takes the held-share path (at 8 a chunk is half the rows, at 4 it
+    is all of them) and the sum over the axis is the layer on one device."""
+    whole = MoEMLP(num_experts=32, top_k=4, expert_hidden=32, dtype=jnp.float32)
+    params = whole.init(jax.random.PRNGKey(10), x)["params"]
+    mesh = mesh_lib.make_mesh({"expert": shards}, devices=jax.devices()[:shards])
+    part = MoEMLP(num_experts=32, top_k=4, expert_hidden=32, dtype=jnp.float32, expert_axis="expert",
+                  expert_shards=shards)
+    before = _held_traces()
+
+    split = jax.shard_map(lambda params, x: part.apply({"params": params}, x), mesh=mesh,
+                          in_specs=(moe.expert_specs(params), P()), out_specs=P(), check_vma=False)
+
+    def run(layer, params, x):
+        return jnp.sum(jnp.square(layer(params, x)))
+
+    got, grads = jax.jit(jax.value_and_grad(functools.partial(run, split), argnums=(0, 1)))(params, x)
+    want, want_grads = jax.value_and_grad(functools.partial(run, lambda params, x: whole.apply({"params": params}, x)),
+                                          argnums=(0, 1))(params, x)
+    assert _held_traces() > before
+    assert float(got) == pytest.approx(float(want), rel=1e-5) and _rel(grads, want_grads) < 1e-5
+
+
+def test_with_every_expert_held_the_layer_is_traced_as_before():
+    """No loop, no branch, and the parent's three gathers (rows and weights
+    into sorted order, rows back), forward; the lowered step of an
+    OLMoE-shaped toy is pinned in ``tests/test_phi4_flash.py``."""
+    x, ids, top_p = jnp.zeros((1, 64, 32)), jnp.zeros((1, 64, 2), jnp.int32), jnp.zeros((1, 64, 2))
+    stacks = [jnp.zeros((8, 32, 32))] * 3
+    out = jax.eval_shape(lambda *a: moe._routed_experts(*a, num_experts=8), x, top_p, ids, *stacks)
+    assert len(out) == 2
+    program = str(jax.make_jaxpr(lambda *a: moe._routed_experts(*a, num_experts=8))(x, top_p, ids, *stacks))
+    assert not re.search(r"\b(cond|while)\b", program) and len(re.findall(r"\bgather\[", program)) == 3
+    held = str(jax.make_jaxpr(lambda *a: moe._routed_experts(*a, num_experts=32))(x, top_p, ids, *stacks))
+    assert re.search(r"\bwhile\b", held)
+
+
 def test_grouped_matmul_tiles_a_width_its_tile_does_not_divide():
     """2,560 (this model's width) under a tile of 2,048 is two tiles of 1,280;
     what fitted before fits as before."""
@@ -292,11 +404,16 @@ def test_tree_of_each_kind_of_block(tiny):
         "mtp": {"block": {"moe": {"bias": (EXPERTS,)}}}}
 
 
+@pytest.mark.parametrize("held", [4, 2], ids=["a_quarter_held", "an_eighth_held"])
 @pytest.mark.parametrize("impl", ["reference", "flash"])
-def test_remat_changes_nothing(tiny, impl, flash_kernel_at_any_length):
+def test_remat_changes_nothing(tiny, impl, held, flash_kernel_at_any_length):
+    """With 4 of 16 experts held a chunk is every routed row, with 2 half of them."""
     _, params, bias, tokens = tiny
-    plain = _program(TransformerLM(**{**TINY, "attention_impl": impl}), params, bias, tokens)
-    again = _program(TransformerLM(**{**TINY, "attention_impl": impl, "remat": True}), params, bias, tokens)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf[:held] if path[-1].key in moe.EXPERT_WEIGHTS else leaf, params)
+    options = {**TINY, "attention_impl": impl, "moe_held_experts": (4, held)}
+    plain = _program(TransformerLM(**options), params, bias, tokens)
+    again = _program(TransformerLM(**options, remat=True), params, bias, tokens)
     assert float(again["loss"]) == pytest.approx(float(plain["loss"]), rel=1e-6)
     assert _rel(again["grad"], plain["grad"]) < 1e-5
 
@@ -353,6 +470,7 @@ def test_step_trains_both_losses_and_moves_the_biases(tiny_step):
         losses.append((float(metrics["loss"]), float(metrics["mtp_loss"])))
     assert losses[-1][0] < losses[0][0] and losses[-1][1] < losses[0][1]
     assert float(metrics["moe_load_max_over_mean"]) > 1.0
+    assert int(metrics["moe_held_overflow"]) == 0  # of three layers that hold a share
     for path in (("block_1", "moe"), ("block_2", "moe"), ("mtp", "block", "moe")):
         bias = state.router_bias
         for key in path:
