@@ -105,7 +105,7 @@ def check_kernels() -> list[dict]:
         rows.append(row)
         print(f"[kernel] {json.dumps(row)}", flush=True)
 
-    # -- flash attention: forward + both backward kernels -------------------
+    # -- flash attention: forward + the backward kernel ----------------------
     b = 2
     q, k, v = (rand(b, NUM_HEADS, SEQ_LEN, D_HEAD) for _ in range(3))
     ct = rand(b, NUM_HEADS, SEQ_LEN, D_HEAD, dtype=f32)  # output cotangent
@@ -115,8 +115,8 @@ def check_kernels() -> list[dict]:
             return jnp.sum(attn(q, k, v, causal=True, window=window).astype(f32) * ct)
         return loss
 
-    for window, name in ((None, f"flash fwd+dq+dkv causal seq {SEQ_LEN}"),
-                         (512, f"flash fwd+dq+dkv causal seq {SEQ_LEN} window 512")):
+    for window, name in ((None, f"flash fwd+bwd causal seq {SEQ_LEN}"),
+                         (512, f"flash fwd+bwd causal seq {SEQ_LEN} window 512")):
         kernel = functools.partial(A.flash_attention, interpret=INTERPRET)
         family(
             name,

@@ -18,8 +18,18 @@ PERF §7). Causal and sliding-window calls work on the band only
 query tile can meet and starts at the tile's first one, and inside a
 grid tile the kernels walk sub-tiles of `_SUBTILE`, skip those outside
 the band and mask only those the diagonal or the window's edge crosses.
-The backward pass is two more kernels (dq and dk/dv) using the saved
-logsumexp, the standard flash-attention-2 split.
+The backward pass is one more kernel, `flash_bwd`, on the grid
+``(batch·heads, seq_k/block_k, query steps)``: it makes each sub-tile's
+probabilities again from the saved logsumexp, once, and adds the
+sub-tile's part to dV, dK and dQ. dK and dV of a key tile gather over the
+grid's inner axis; dQ gathers over the key tiles, so a batch-head's whole
+dQ stays in VMEM as a float32 ``(seq_q, d)`` sum until the head's last
+step (4 MiB with its output block at 4,096 x 96, 16 MiB at 8,192 x 192;
+beyond `_DQ_VMEM_BYTES`, 65,536 queries at 128, the query axis goes
+through the same kernel in slices). Until PR 41 the pass was
+flash-attention-2's split into a dQ and a dK/dV kernel, each of which
+made the scores, the exponentials, dP and dS of every sub-tile: 7.47 ->
+5.61 ms a layer at the Phi-3 cell's shape (my chip runs, PR 41).
 
 For cross-device sequence parallelism see
 ``hops_tpu.parallel.ringattention`` which rotates K/V chunks over the
@@ -119,7 +129,7 @@ def attention_reference(
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-# Edge, in queries and in keys, of the sub-tile at which the three
+# Edge, in queries and in keys, of the sub-tile at which the
 # training kernels decide "skip / compute unmasked / compute masked". A
 # grid tile (the block Pallas fetches per step) is walked in sub-tiles
 # of this edge inside the kernel body; a side of the grid tile that this
@@ -129,7 +139,8 @@ def attention_reference(
 # x 1,024 grid tile alone; 256 would give 1.125 / 1.062 and is slower:
 # every visit of a row of queries pays its row statistics and its
 # accumulator's read-modify-write whatever the number of keys, and below
-# 512 keys that cost outweighs the pairs saved. The three kernels at the
+# 512 keys that cost outweighs the pairs saved. The forward and, until
+# PR 41 made them one, the two backward kernels at the
 # Phi-3 cell's shape (64 batch-heads, 4,096 keys, d_head 96, window
 # 2,047), ms a call, sub-tile 1,024 / 512 / 256 inside a 1,024 grid
 # tile: forward 3.38 / 3.08 / 4.80, dQ 3.74 / 3.60 / 4.69, dK/dV 4.80 /
@@ -259,7 +270,7 @@ def _causal_mask(s, shift, window, keys_first=False):
 
 
 def _walk_subtiles(band, qi, kj, live, cell):
-    """The one loop of the three kernels: ``cell(a, b, shift)`` for every
+    """The one loop of both kernels: ``cell(a, b, shift)`` for every
     sub-tile ``(a, b)`` of grid tile ``(qi, kj)`` that the band touches.
     ``shift`` is None on an interior sub-tile (no iota, compare or select
     is traced for it) and `_causal_mask`'s argument on an edge one; a
@@ -271,8 +282,8 @@ def _walk_subtiles(band, qi, kj, live, cell):
     the cells 2 to 5 s longer and the kernels 1 to 3 % less (my chip
     runs, PR 28; PERF §6). ``cell``
     loads what it needs from its refs itself: operands loaded once per
-    row of sub-tiles and carried into the branches cost dQ 0.7 ms a call
-    at the Phi-3 cell's shape."""
+    row of sub-tiles and carried into the branches cost the dQ kernel of
+    PR 28 0.7 ms a call at the Phi-3 cell's shape."""
     n_a, n_b = band.block_q // band.sub_q, band.block_k // band.sub_k
 
     def visit(a, b):
@@ -387,84 +398,61 @@ def _fwd_kernel(
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels (flash-attention-2 split: dq, then dk/dv)
+# Backward kernel: grid (bh, nk, query steps), dQ resident per batch-head
 # ---------------------------------------------------------------------------
 
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _f32(x):
     return x.astype(jnp.float32)
 
 
-def _bwd_p_ds(q, kb, do, vb, lse, delta, shift, sm_scale, window, keys_first):
+def _bwd_p_ds(q, kb, do, vb, lse, delta, shift, sm_scale, window):
     """The probabilities of one sub-tile, made again from the saved
-    logsumexp, and the gradient of its scores: what dQ and dK/dV share.
-    Queries on the rows with ``lse`` and ``delta`` as columns (dQ), or
-    ``keys_first``: keys on the rows with the two statistics as the
-    lane-major rows they are stored as. dK/dV takes the second form: its
-    two products with ``p`` and ``ds`` are then plain matmuls and no
-    score-shaped matrix is transposed (5.30 -> 4.80 ms a call at the
-    Phi-3 cell's shape; the same turn makes dQ slower, 3.57 -> 3.72: my
-    chip runs, PR 28). Operands are float32 as the callers cast them:
-    feeding the inputs' dtype gave under 0.1 ms a call (PERF §6 PR 28)."""
-    s = jax.lax.dot_general(
-        *((kb, q) if keys_first else (q, kb)), _NT, preferred_element_type=jnp.float32
-    )
+    logsumexp, and the gradient of its scores, both with keys on the rows
+    and the two statistics as the lane-major rows they are stored as: the
+    products that give dV and dK are then plain matmuls and no
+    score-shaped matrix is transposed for them (5.30 -> 4.80 ms a call of
+    the dK/dV kernel at the Phi-3 cell's shape: my chip runs, PR 28).
+    Operands are float32 as the caller casts them: feeding the inputs'
+    dtype gave under 0.1 ms a call (PERF §6 PR 28)."""
+    s = jax.lax.dot_general(kb, q, _NT, preferred_element_type=jnp.float32)
     s = s * sm_scale
     if shift is None:
         # every pair visible: the query has keys, so its lse is finite
         p = jnp.exp(s - lse)
     else:
-        s = _causal_mask(s, shift, window, keys_first)
+        s = _causal_mask(s, shift, window, keys_first=True)
         lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
         p = jnp.where(lse == NEG_INF, 0.0, jnp.exp(s - lse_safe))
-    dp = jax.lax.dot_general(
-        *((vb, do) if keys_first else (do, vb)), _NT, preferred_element_type=jnp.float32
-    )
+    dp = jax.lax.dot_general(vb, do, _NT, preferred_element_type=jnp.float32)
     return p, p * (dp - delta) * sm_scale
 
 
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, sm_scale, band,
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr, *, sm_scale, band,
 ):
-    qi, step = pl.program_id(1), pl.program_id(2)
-    first, last = band.key_tiles(qi)
-    kj = first + step
-
-    @pl.when(step == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def cell(a, b, shift):
-        rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
-        at = pl.ds(qi * band.block_q + a * band.sub_q, band.sub_q)
-        kb = _f32(k_ref[0, cols, :])
-        _, ds = _bwd_p_ds(
-            _f32(q_ref[0, rows, :]), kb, _f32(do_ref[0, rows, :]), _f32(v_ref[0, cols, :]),
-            lse_ref[0, 0, at][:, None], delta_ref[0, 0, at][:, None],
-            shift, sm_scale, band.window, keys_first=False,
-        )
-        dq_scr[rows, :] += jax.lax.dot_general(
-            ds, kb, _NN, preferred_element_type=jnp.float32
-        )
-
-    _walk_subtiles(band, qi, kj, kj <= last, cell)
-
-    @pl.when(step == pl.num_programs(2) - 1)
-    def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, sm_scale, band,
-):
+    """dQ, dK and dV of one batch-head from one walk of the band: a
+    sub-tile's ``p`` and ``ds`` are made once and feed all three sums.
+    dK and dV gather over the query steps of a key tile as the grid
+    runs; dQ gathers over the key tiles, which are the grid's outer axis,
+    so its float32 sum stays in VMEM for the whole batch-head (`_bwd_call`
+    sizes it) and leaves with the head's last step. A row of dQ meets its
+    key tiles, and inside a tile its sub-tiles, in rising order."""
     kj, step = pl.program_id(1), pl.program_id(2)
     first, last = band.query_tiles(kj)
     qi = first + step
+    head_start = (kj == 0) & (step == 0)
+    head_end = (kj == pl.num_programs(1) - 1) & (step == pl.num_programs(2) - 1)
+
+    @pl.when(head_start)
+    def _init_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     @pl.when(step == 0)
     def _init():
@@ -473,18 +461,25 @@ def _bwd_dkv_kernel(
 
     def cell(a, b, shift):
         rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
-        at = pl.ds(qi * band.block_q + a * band.sub_q, band.sub_q)
+        at = pl.ds(pl.multiple_of(qi * band.block_q + a * band.sub_q, band.sub_q), band.sub_q)
         q, do = _f32(q_ref[0, rows, :]), _f32(do_ref[0, rows, :])
+        kb = _f32(k_ref[0, cols, :])
         p, ds = _bwd_p_ds(
-            q, _f32(k_ref[0, cols, :]), do, _f32(v_ref[0, cols, :]),
-            lse_ref[0, :, at], delta_ref[0, :, at],
-            shift, sm_scale, band.window, keys_first=True,
+            q, kb, do, _f32(v_ref[0, cols, :]), lse_ref[0, :, at], delta_ref[0, :, at],
+            shift, sm_scale, band.window,
         )
         dv_scr[cols, :] += jax.lax.dot_general(
             p, do, _NN, preferred_element_type=jnp.float32
         )
         dk_scr[cols, :] += jax.lax.dot_general(
             ds, q, _NN, preferred_element_type=jnp.float32
+        )
+        # the one product that contracts the keys of the keys-first `ds`;
+        # Mosaic turns the left operand itself, at the time of a `ds.T`
+        # written out (5.614 / 5.620 ms a call at the Phi-3 cell's shape:
+        # my chip runs, PR 41)
+        dq_scr[at, :] += jax.lax.dot_general(
+            ds, kb, _TN, preferred_element_type=jnp.float32
         )
 
     _walk_subtiles(band, qi, kj, qi <= last, cell)
@@ -493,6 +488,10 @@ def _bwd_dkv_kernel(
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(head_end)
+    def _finalize_head():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -509,10 +508,11 @@ def _band_specs(band: _Band, d: int, d_v: int | None = None):
     """BlockSpecs of the two grid orders, for queries and keys ``d`` wide and
     values (and the output) ``d_v`` wide (None: ``d`` too; a latent-attention
     layer's keys carry a rotary part the values lack). Under ``(bh, query tile, key
-    step)`` (forward, dQ) the K/V block of step ``j`` is the ``j``-th key
+    step)`` (forward) the K/V block of step ``j`` is the ``j``-th key
     tile of the query tile's span; under ``(bh, key tile, query step)``
-    (dK/dV) the Q/dO block is the ``j``-th query tile of the key tile's
-    span. A step past the span's end maps to the span's last tile again
+    (backward) the Q/dO block is the ``j``-th query tile of the key tile's
+    span, and ``dq`` is the batch-head's whole dQ, which no inner step
+    moves. A step past the span's end maps to the span's last tile again
     (Mosaic issues no copy for a block it already holds) and the kernel
     body runs nothing for it, so no grid step and no fetch is spent on a
     tile the band never touches."""
@@ -525,7 +525,8 @@ def _band_specs(band: _Band, d: int, d_v: int | None = None):
     k_of_q = stepped(band.key_tiles, band.seq_k // band.block_k)
     q_of_k = stepped(band.query_tiles, band.seq_q // band.block_q)
     own = lambda b, i, j: (b, i, 0)
-    stats = pl.BlockSpec((1, 1, band.seq_q), lambda b, i, j: (b, 0, 0))
+    whole = lambda b, i, j: (b, 0, 0)
+    stats = pl.BlockSpec((1, 1, band.seq_q), whole)
     d_v = d if d_v is None else d_v
     q_major = {
         "q": pl.BlockSpec((1, band.block_q, d), own),
@@ -539,6 +540,7 @@ def _band_specs(band: _Band, d: int, d_v: int | None = None):
         "o": pl.BlockSpec((1, band.block_q, d_v), q_of_k),
         "k": pl.BlockSpec((1, band.block_k, d), own),
         "v": pl.BlockSpec((1, band.block_k, d_v), own),
+        "dq": pl.BlockSpec((1, band.seq_q, d), whole),
         "stats": stats,
     }
     return q_major, k_major
@@ -587,64 +589,103 @@ def _flash(q, k, v, band, sm_scale, interpret):
 def _flash_fwd(q, k, v, band, sm_scale, interpret):
     _count_subtiles("fwd", band)
     o, lse = _fwd_call(_flat(q), _flat(k), _flat(v), band, sm_scale, interpret)
-    # kept by a block's remat: the backward kernels then take q, k, v from the
+    # kept by a block's remat: the backward kernel then takes q, k, v from the
     # second forward and this call is not made again
     o, lse = keep(o.reshape(*q.shape[:-1], v.shape[-1]), "flash_out"), keep(lse, "flash_lse")
     return o, (q, k, v, o, lse)
 
 
+# VMEM that one `flash_bwd` call may spend on the dQ it holds for a
+# batch-head: the float32 sum and the two buffers of its output block,
+# lanes padded to 128. The cells hold 4 MiB (4,096 x 96 or 128), 8 MiB
+# (8,192 x 128) and 16 MiB (8,192 x 192); 64 MiB is 65,536 queries at
+# `d` 128 in two-byte inputs, with which the call's limit is 96 of a
+# v5e's 128 MiB (it compiles for a described v5e: compile, PR 41; no
+# chip has run it). Longer query axes go through the kernel in slices
+# (`_flash_bwd`).
+_DQ_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _dq_vmem_bytes(rows: int, d: int, itemsize: int) -> int:
+    return rows * -(-d // _LANES) * _LANES * (4 + 2 * itemsize)
+
+
+def _query_slices(band: _Band, d: int, itemsize: int) -> int:
+    """Into how many equal slices of whole query tiles `_bwd_call` cuts
+    the query axis so that a slice's dQ fits `_DQ_VMEM_BYTES`: 1 at
+    every shape a cell runs."""
+    tiles = band.seq_q // band.block_q
+    for n in range(1, tiles + 1):
+        if tiles % n == 0 and _dq_vmem_bytes(band.seq_q // n, d, itemsize) <= _DQ_VMEM_BYTES:
+            return n
+    return tiles
+
+
 def _flash_bwd(band, sm_scale, interpret, res, g):
-    _count_subtiles("dq", band)
-    _count_subtiles("dkv", band)
-    return _bwd_calls(*res, g, band, sm_scale, interpret)
+    _count_subtiles("bwd", band)
+    q, k, v, o, lse = res
+    qf, kf, vf, of, gf = _flat(q), _flat(k), _flat(v), _flat(o), _flat(g)
+    delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), axis=-1)[:, None, :]
+    n = _query_slices(band, q.shape[-1], q.dtype.itemsize)
+    if n == 1:
+        dq, dk, dv = _bwd_call(qf, kf, vf, gf, lse, delta, band, sm_scale, interpret)
+    else:
+        # a slice of the queries is the same call further down the band;
+        # dK and dV add up over the slices in float32
+        rows = band.seq_q // n
+        parts = [
+            _bwd_call(
+                qf[:, at:at + rows], kf, vf, gf[:, at:at + rows],
+                lse[:, :, at:at + rows], delta[:, :, at:at + rows],
+                dataclasses.replace(band, seq_q=rows, q_offset=band.q_offset + at),
+                sm_scale, interpret,
+            )
+            for at in range(0, band.seq_q, rows)
+        ]
+        dq = jnp.concatenate([part[0] for part in parts], axis=1)
+        dk = sum(_f32(part[1]) for part in parts).astype(k.dtype)
+        dv = sum(_f32(part[2]) for part in parts).astype(v.dtype)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 @_per_geometry
-def _bwd_calls(q, k, v, o, lse, g, band, sm_scale, interpret):
-    shape = q.shape
-    qf, kf, vf, of, gf = _flat(q), _flat(k), _flat(v), _flat(o), _flat(g)
-    bh, seq_q, d = qf.shape
-    d_v = vf.shape[-1]
-    delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), axis=-1)[:, None, :]
-    q_major, k_major = _band_specs(band, d, d_v)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, band=band),
-        grid=(bh, seq_q // band.block_q, band.key_steps()),
-        in_specs=[
-            q_major["q"], q_major["k"], q_major["v"], q_major["o"],
-            q_major["stats"], q_major["stats"],
-        ],
-        out_specs=q_major["q"],
-        out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((band.block_q, d), jnp.float32)],
-        compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qf, kf, vf, gf, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, band=band),
+def _bwd_call(q, k, v, do, lse, delta, band, sm_scale, interpret):
+    bh, seq_q, d = q.shape
+    d_v = v.shape[-1]
+    _, spec = _band_specs(band, d, d_v)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, band=band),
         grid=(bh, band.seq_k // band.block_k, band.query_steps()),
-        in_specs=[
-            k_major["q"], k_major["k"], k_major["v"], k_major["o"],
-            k_major["stats"], k_major["stats"],
-        ],
-        out_specs=[k_major["k"], k_major["v"]],
+        in_specs=[spec["q"], spec["k"], spec["v"], spec["o"], spec["stats"], spec["stats"]],
+        out_specs=[spec["dq"], spec["k"], spec["v"]],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, band.seq_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, band.seq_k, d_v), v.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((seq_q, d), jnp.float32),
             pltpu.VMEM((band.block_k, d), jnp.float32),
             pltpu.VMEM((band.block_k, d_v), jnp.float32),
         ],
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GRID_SEMANTICS,
+            vmem_limit_bytes=_FLASH_COMPILER_PARAMS.vmem_limit_bytes
+            + _dq_vmem_bytes(seq_q, d, q.dtype.itemsize),
+        ),
+        # dQ, dK and dV take the buffers of q, k and v, which a training step
+        # reads here for the last time: a block of q is read only inside its
+        # own batch-head and dQ leaves after the head's last step, a tile of
+        # k or v only inside its own key tile's steps; a caller that reads
+        # them afterwards gets XLA's copy. All three results of one call are
+        # live at once, where dQ's could go before the dK/dV call came: not
+        # aliased, the Phi-3 step's temporaries read 4.621 GB against the
+        # pair's 4.521 and `peak_hbm_gb` 12.600 against 12.500; aliased
+        # 4.520 (compile and my chip runs, PR 41)
+        input_output_aliases={0: 0, 1: 1, 2: 2},
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qf, kf, vf, gf, lse, delta)
-
-    return dq.reshape(shape), dk.reshape(k.shape), dv.reshape(v.shape)
+        name="flash_bwd",
+    )(q, k, v, do, lse, delta)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -691,7 +732,7 @@ def flash_attention(
     ``window`` (causal only): query p attends keys in
     ``[p - window + 1, p]`` — Mistral-style sliding-window attention.
     Sub-tiles wholly below the window or past the diagonal are skipped
-    in all three kernels and their grid tiles are never fetched, so
+    in both kernels and their grid tiles are never fetched, so
     long-sequence compute is O(seq * window).
 
     Cross-length causal calls (chunked prefill: ``seq_q < seq_k``) run
@@ -720,10 +761,12 @@ def flash_attention(
     # inside the tile, not by the tile. The one row a cell runs is 1024
     # x 1024 at 4,096 keys, read again on the chip in PR 28 against 512
     # x 512 with the same 512 sub-tiles: forward 2.99 / 3.23 ms a call,
-    # dQ 3.57 / 3.81, dK/dV 4.43 / 4.87 at d_head 96 with a window of
-    # 2,047, dQ 1.71 / 1.96 and dK/dV 2.21 / 2.51 at d_head 128 without
-    # (my chip runs, PR 28); forward and dQ at 1024 x 2048 are within
-    # 0.1 ms of 1024 x 1024. The other rows were chosen on a removed stack and are not
+    # the backward's two kernels of that time dQ 3.57 / 3.81 and dK/dV
+    # 4.43 / 4.87 at d_head 96 with a window of 2,047, dQ 1.71 / 1.96
+    # and dK/dV 2.21 / 2.51 at d_head 128 without (my chip runs, PR 28);
+    # forward and dQ at 1024 x 2048 were within 0.1 ms of 1024 x 1024.
+    # The one backward kernel since PR 41 was read at these tiles only.
+    # The other rows were chosen on a removed stack and are not
     # measured on this one. A preferred size that doesn't divide the
     # sequence shrinks to the largest 128-multiple divisor rather than
     # silently punting to the O(seq²) reference.

@@ -163,7 +163,7 @@ GAUGE_TRAIN_STATE_BYTES = "hops_tpu_train_state_bytes"
 #: ``hops_tpu_train_layer_kinds_total{kind}`` (``models/transformer.py``,
 #: one per layer traced) are named
 #: where they are counted; the flash kernels' sub-tiles
-#: (``ops/attention.py``: ``kernel`` = ``fwd`` | ``dq`` | ``dkv``, ``kind`` =
+#: (``ops/attention.py``: ``kernel`` = ``fwd`` | ``bwd``, ``kind`` =
 #: ``interior`` | ``edge`` | ``skipped``, the counts of one batch-head per
 #: traced call) are named here.
 COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"

@@ -159,30 +159,43 @@ def test_kernels_compile_for_v5e_at_olmoe_widths(one_chip, k, n):
     assert len(calls) == 2 and all(gm.KERNEL_NAME in line.split(" = ")[0] for line in calls)  # dX and dW
 
 
-@pytest.mark.parametrize("heads, d_head, window", [(32, 96, 2047), (16, 128, None)], ids=["phi3", "olmoe"])
-def test_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, heads, d_head, window):
-    """The three flash kernels at 2 x 4,096 tokens a chip with the default
-    tiles and sub-tiles (this file holds the one fixture that may load the
-    TPU compiler): Mosaic accepts the sub-tile slices, the band-sized grid
-    axis with its clamped index maps, and the VMEM they ask for. Nothing
-    runs."""
+@pytest.mark.parametrize("batch_heads, seq, d_head, d_value, window, backward_calls", [
+    (64, 4096, 96, 96, 2047, 1), (32, 4096, 128, 128, None, 1), (32, 8192, 128, 128, None, 1),
+    (40, 8192, 128, 128, 512, 1), (32, 8192, 192, 128, None, 1), (2, 32768, 128, 128, None, 1),
+    (1, 65536, 128, 128, None, 1), (1, 131072, 128, 128, None, 2),
+], ids=["phi3", "olmoe", "hybrid_and_phi4_full", "phi4_window", "ling", "32k", "64k", "128k_in_two_slices"])
+def test_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, batch_heads, seq, d_head, d_value, window,
+                                                           backward_calls):
+    """`flash_fwd` and `flash_bwd` at the six LM cells' shapes with the
+    default tiles and sub-tiles, and at 32k, 64k and 128k keys (this file
+    holds the one fixture that may load the TPU compiler): Mosaic accepts
+    the sub-tile slices, the band-sized grid axis with its clamped index
+    maps, the product that contracts the keys of a keys-first `ds`, and the
+    VMEM a batch-head's resident dQ asks for, which follows from the shape
+    (4 MiB at 4,096 x 96, 16 MiB at 8,192 x 192, 64 MiB at 65,536 x 128), so
+    that a budget that does not fit fails here and not on the chip. Past
+    65,536 queries at 128 the query axis goes through the one kernel in
+    slices. Nothing runs."""
     from hops_tpu.ops.attention import flash_attention
 
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
     try:
-        x = jax.ShapeDtypeStruct((2, heads, 4096, d_head), jnp.bfloat16, sharding=one_chip)
+        qk = jax.ShapeDtypeStruct((1, batch_heads, seq, d_head), jnp.bfloat16, sharding=one_chip)
+        v = jax.ShapeDtypeStruct((1, batch_heads, seq, d_value), jnp.bfloat16, sharding=one_chip)
 
         def grads(q, k, v):
             return jax.grad(
                 lambda q, k, v: flash_attention(q, k, v, causal=True, window=window, interpret=False)
                 .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-        text = jax.jit(grads).lower(x, x, x).compile().as_text()
+        text = jax.jit(grads).lower(qk, qk, v).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
-    assert len(calls) == 3 and all(any(name in call for call in calls) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    assert sum("flash_fwd" in call for call in calls) == 1
+    assert sum("flash_bwd" in call for call in calls) == backward_calls == len(calls) - 1
+    assert not any("flash_bwd_d" in call for call in calls)
 
 
 def test_gated_delta_kernels_compile_for_v5e_at_the_cells_shape(one_chip):

@@ -262,7 +262,8 @@ def _flash_vs_reference(monkeypatch, q, k, v, *, sub, **kw):
 
     monkeypatch.setattr(A, "_SUBTILE", sub)
     blocks = {"block_q": kw.pop("block_q", 256), "block_k": kw.pop("block_k", 256)}
-    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)  # a cotangent that is not all ones
+    # a cotangent that is not all ones, of the output's shape (the values' width)
+    w = jax.random.normal(jax.random.PRNGKey(7), (*q.shape[:-1], v.shape[-1]), q.dtype)
 
     out = A.flash_attention(q, k, v, **kw, **blocks)
     ref = A.attention_reference(q, k, v, **kw)
@@ -296,6 +297,71 @@ def test_subtiled_flash_rectangular_kv(monkeypatch, q_offset, window, causal):
         monkeypatch, q, k, v, sub=128, block_q=256, block_k=512,
         causal=causal, window=window, q_offset=q_offset,
     )
+
+
+# name: (seq_q, seq_k, d, d_v, block_q, block_k, kwargs of the call)
+_FUSED_BACKWARD_CASES = {
+    "causal": (1024, 1024, 32, 32, 256, 256, dict(causal=True)),
+    "windowed": (1024, 1024, 32, 32, 256, 256, dict(causal=True, window=600)),
+    "non_causal": (768, 768, 32, 32, 256, 256, dict(causal=False)),
+    "fewer_queries_than_keys": (768, 1536, 32, 32, 256, 256, dict(causal=True)),
+    "fewer_queries_windowed": (768, 1536, 32, 32, 256, 256, dict(causal=True, window=700, q_offset=500)),
+    "values_narrower_than_keys": (768, 768, 48, 32, 256, 256, dict(causal=True)),  # Ling's 192 / 128
+    "values_narrower_windowed_rectangular": (768, 1024, 48, 32, 128, 256, dict(causal=True, window=520)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FUSED_BACKWARD_CASES))
+def test_one_backward_call_gathers_dq_over_key_tiles_and_dkv_over_query_tiles(monkeypatch, name):
+    """`flash_bwd` holds a batch-head's dQ while the grid walks the key
+    tiles and gathers a key tile's dK and dV over its query steps: cases
+    where a row of dQ meets three or more key tiles and a key tile three
+    or more query tiles in one call, against the reference's gradients."""
+    from hops_tpu.ops import attention as A
+
+    seq_q, seq_k, d, d_v, block_q, block_k, kw = _FUSED_BACKWARD_CASES[name]
+    band = _band(seq_q, seq_k, block_q, block_k, 128, kw.get("q_offset"), kw["causal"], kw.get("window"))
+    assert band.key_steps() >= 3 and band.query_steps() >= 3  # the most tiles a query tile, a key tile, meets
+    q, _, _ = _inputs(batch=1, heads=2, seq=seq_q, d=d)
+    _, k, _ = _inputs(batch=1, heads=2, seq=seq_k, d=d, seed=1)
+    _, _, v = _inputs(batch=1, heads=2, seq=seq_k, d=d_v, seed=2)
+    _flash_vs_reference(monkeypatch, q, k, v, sub=128, block_q=block_q, block_k=block_k, **kw)
+    grad = str(jax.make_jaxpr(jax.grad(lambda q: A.flash_attention(q, k, v, block_q=block_q, block_k=block_k, **kw).sum()))(q))
+    assert grad.count("name=flash_bwd") == 1 and "flash_bwd_d" not in grad
+    # dQ, dK, dV take q's, k's and v's buffers; the gradients above were right with q, k, v read afterwards
+    assert grad.count("input_output_aliases=((0, 0), (1, 1), (2, 2))") == 1
+
+
+@pytest.mark.parametrize("rows_that_fit, slices", [(1024, 1), (512, 2), (256, 4), (100, 4)])
+def test_a_dq_too_large_for_vmem_goes_through_the_one_kernel_in_query_slices(monkeypatch, rows_that_fit, slices):
+    """Whether a batch-head's dQ stays resident is decided from ``seq_q``
+    and ``d`` alone: past `_DQ_VMEM_BYTES` (forced small here; 65,536
+    queries at 128 on the chip) the query axis is cut into equal slices of
+    whole query tiles, each a call of the same kernel further down the
+    band, and dK and dV add up over them. One side of the limit and the
+    other give the reference's gradients."""
+    from hops_tpu.ops import attention as A
+
+    q, k, v = _inputs(batch=1, heads=2, seq=1024, d=32)
+    monkeypatch.setattr(A, "_DQ_VMEM_BYTES", A._dq_vmem_bytes(rows_that_fit, 32, 4))
+    kw = dict(causal=True, window=600, block_q=256, block_k=256)
+    assert A._query_slices(_band(1024, 1024, 256, 256, 128, None, True, 600), 32, 4) == slices
+    grad = jax.make_jaxpr(jax.grad(lambda q: A.flash_attention(q, k, v, **kw).sum()))(q)
+    assert str(grad).count("name=flash_bwd") == slices
+    _flash_vs_reference(monkeypatch, q, k, v, sub=128, **kw)
+
+
+def test_resident_dq_at_the_cells_shapes():
+    """The VMEM a call spends on its dQ (float32 sum + two buffers of the
+    bf16 output block, 128-lane rows): the sizes PERF §3 gives, one slice
+    at every cell's shape and up to 65,536 queries at 128."""
+    from hops_tpu.ops import attention as A
+
+    mib = lambda rows, d: A._dq_vmem_bytes(rows, d, 2) / 2**20
+    assert [mib(4096, 96), mib(4096, 128), mib(8192, 128), mib(8192, 192), mib(65536, 128)] == [4, 4, 8, 16, 64]
+    slices = lambda seq, d: A._query_slices(_band(seq, seq, 1024, 2048 if seq > 4096 else 1024, 512, None, True, None), d, 2)
+    assert [slices(4096, 96), slices(8192, 192), slices(32768, 128), slices(65536, 128), slices(131072, 128)] == [1, 1, 1, 1, 2]
+    assert slices(131072, 256) == 4 and slices(131072 * 8, 128) == 16
 
 
 @pytest.mark.parametrize("sub", [64, 128, 256])

@@ -129,7 +129,8 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     inside = _mosaic_calls(second)
     assert "flash_fwd" not in inside
     if "flash_out" in names:
-        assert inside["flash_bwd_dq"] == inside["flash_bwd_dkv"] == 1 and _mosaic_calls(jaxpr)["flash_fwd"] == 1
+        assert inside["flash_bwd"] == 1 and _mosaic_calls(jaxpr)["flash_fwd"] == 1
+        assert not {"flash_bwd_dq", "flash_bwd_dkv"} & set(_mosaic_calls(jaxpr))
     if fields["layer_type"] == "mamba":  # the scan's results are not kept: its forward runs again
         assert inside["selective_scan_fwd"] == inside["selective_scan_bwd"] == 1
         assert _mosaic_calls(jaxpr)["selective_scan_fwd"] == 2
@@ -166,7 +167,8 @@ def test_a_steps_gradient_runs_each_forward_kernel_once_a_layer(toy, remat, rout
     kinds = STEPS[toy]["layer_types"]
     attention = sum(kind.endswith("_attention") and kind != "linear_attention" for kind in kinds)
     calls = _mosaic_calls(jaxpr)
-    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == calls["flash_bwd_dkv"] == attention
+    assert calls["flash_fwd"] == calls["flash_bwd"] == attention  # one backward Mosaic call a layer, under remat too
+    assert not {"flash_bwd_dq", "flash_bwd_dkv"} & set(calls)
     mamba = kinds.count("mamba")
     if route == "kernels":
         assert calls.get("selective_scan_fwd", 0) == (1 + remat) * mamba and calls.get("selective_scan_bwd", 0) == mamba

@@ -128,7 +128,7 @@ def _pallas_names(jaxpr) -> list:
 
 @pytest.mark.parametrize("entry, args, names", [
     (_flash, (_SEQ, _SEQ, _SEQ), ["flash_fwd"]),
-    (_flash_grad, (_SEQ, _SEQ, _SEQ), ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+    (_flash_grad, (_SEQ, _SEQ, _SEQ), ["flash_fwd", "flash_bwd"]),
     (_dense_decode(False), (_Q1, _SEQ, _SEQ), ["dense_decode"]),
     (_dense_decode(True), (_Q1, _SEQ, _SEQ), ["dense_decode"]),
     (_paged_decode, (jax.ShapeDtypeStruct((2, 4, 1, 32), jnp.float32), _POOL, _POOL), ["paged_decode"]),
@@ -150,19 +150,23 @@ def test_lm_step_counts_the_flash_subtiles_it_compiles(monkeypatch):
 
     def read():
         return {(kernel, kind): counter.value(kernel=kernel, kind=kind)
-                for kernel in ("fwd", "dq", "dkv") for kind in kinds}
+                for kernel in ("fwd", "bwd", "dq", "dkv") for kind in kinds}
 
     lm = TransformerLM(vocab_size=64, d_model=32, num_heads=2, num_layers=2, window=200, dtype=jnp.float32)
     state = common.create_train_state(lm, jax.random.PRNGKey(0), (1, 8), input_dtype=jnp.int32)
     before = read()
     jax.jit(make_lm_train_step(loss_chunk=64)).lower(state, {"tokens": jnp.zeros((2, 257), jnp.int32)})
     added = {key: value - before[key] for key, value in read().items()}
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "bwd"):
         calls = added[kernel, "edge"] / kinds["edge"]
         assert calls >= 2 and calls == int(calls), added  # each of the two layers, whole calls
         assert all(added[kernel, kind] == calls * n for kind, n in kinds.items()), added
-    assert added["dq", "edge"] == added["dkv", "edge"] == 2 * kinds["edge"]
-    assert any(line.startswith(COUNTER_TRAIN_FLASH_SUBTILES + "{") and 'kernel="dkv"' in line
+    # going back every sub-tile is visited once: one backward kernel a layer, where
+    # until PR 41 a dQ and a dK/dV kernel each walked the band
+    assert added["bwd", "edge"] == 2 * kinds["edge"]
+    assert all(added["bwd", kind] == added["fwd", kind] for kind in kinds)
+    assert not any(added[kernel, kind] for kernel in ("dq", "dkv") for kind in kinds)
+    assert any(line.startswith(COUNTER_TRAIN_FLASH_SUBTILES + "{") and 'kernel="bwd"' in line
                for line in render_prometheus().splitlines())
 
 
