@@ -24,12 +24,14 @@ Ling-3.0-flash configures it: ``no_kda_lora``, ``kda_safe_gate``,
 ``kda_lower_bound``, a head-wise output gate) is the same layer with a decay
 per key CHANNEL instead of one a head, kept above a lower bound:
 
-    q, k, v as above                beta = sigmoid(W_b x)
+    q, k, v = silu(conv(W . x))     beta = sigmoid(W_b x)
     g = lower_bound * sigmoid(exp(A_log_h) * (W_a x + dt_bias))    (b, s, h, d_k), W_a full rank
-    o = kda_rule(q, k, v, g, beta)                                 (ops/kda.py)
+    o = kda_rule(q, k, v, g, beta)                                 (ops/kda.py, which takes the L2 norms itself)
     out = W_o [ sigmoid(W_g x)_h * RMSNorm_{d_v}(o_h) ]            one gate a head
 
-It shares the convolution, the L2 norms and the scopes with the layer above.
+It shares the convolution and the scopes with the layer above; the L2 norms
+of ``q`` and ``k`` (the same, with the same ``L2_EPS``) are the rule's own
+first step, on the tile its kernels hold.
 """
 
 from __future__ import annotations
@@ -203,15 +205,11 @@ class KimiDeltaAttention(nn.Module):
             q, k, v = conv(q, "q_conv"), conv(k, "k_conv"), conv(v, "v_conv")
 
         with jax.named_scope(SCOPE_SCAN):
-            def heads(t, d):  # (b, s, h * d) -> (b, h, s, d)
-                return jnp.moveaxis(t.reshape(b, s, h, d), 2, 1)
-
-            q = (_l2_normalise(heads(q, dk)) / math.sqrt(dk)).astype(self.dtype)
-            k = _l2_normalise(heads(k, dk)).astype(self.dtype)
+            # the rule takes the arrays as they are, heads behind the tokens, and normalises q and k itself
             o = per_shard(kda.kda_rule, op="kda")(
-                q, k, heads(v, dv), jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1))
+                q.reshape(b, s, h, dk), k.reshape(b, s, h, dk), v.reshape(b, s, h, dv), g, beta)
 
         with jax.named_scope(SCOPE_OUT):
-            o = RMSNorm(self.norm_eps, dtype=jnp.float32, name="norm")(jnp.moveaxis(o, 1, 2))
+            o = RMSNorm(self.norm_eps, dtype=jnp.float32, name="norm")(o)
             o = (o * gate[..., None]).astype(self.dtype)
             return dense(dm, "out")(o.reshape(b, s, h * dv))

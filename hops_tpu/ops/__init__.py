@@ -21,10 +21,13 @@ hand-written here with Pallas:
   ``gated_delta_fwd``, ``gated_delta_out_fwd``, ``gated_delta_bwd``,
   ``gated_delta_local_bwd``) keep a chunk's matrices and the state in VMEM.
 - ``kda_rule`` — the delta rule with a decay per key channel (Kimi Delta
-  Attention) in chunks of 64 tokens, with a backward pass of its own; on a
-  TPU two Pallas kernels (``kda_fwd``, ``kda_bwd``: a chunk's matrices, the
-  decayed operands and the state in VMEM, the backward the forward's
-  ``jax.vjp`` traced into the kernel).
+  Attention) in chunks of 64 tokens, on a layer's own arrays ((b, s, h, d),
+  ``q`` and ``k`` before their L2 norm, the log-decay and not its running
+  sum), with a backward pass written by hand; on a TPU two Pallas kernels
+  (``kda_fwd``, ``kda_bwd``: they read (chunk, heads x d) tiles of those
+  arrays and write the cotangents the same way, ``o`` head-major as the
+  gated norm after it is laid out; the norms, the running sum, a chunk's
+  matrices, the decayed operands and the state stay in VMEM).
 - ``selective_scan`` — the recurrence of a Mamba layer in chunks, with a
   backward pass of its own that recomputes a chunk's states; on a TPU two
   Pallas kernels (``selective_scan_fwd``, ``selective_scan_bwd``) keep the

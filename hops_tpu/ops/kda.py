@@ -5,10 +5,14 @@ Per head a state ``S`` (d_k x d_v), ``S_0 = 0``, and per token
     S_t = Diag(a_t) S_{t-1} + b_t k_t (v_t - (Diag(a_t) S_{t-1})^T k_t)^T      o_t = S_t^T q_t
 
 with ``a_t = exp(g_t)`` a VECTOR over the d_k key channels, ``g_t`` in
-``[LOWER_BOUND, 0]``, and ``b_t`` in [0, 1] (Kimi Linear, arXiv:2510.26692).
-With one ``g_t`` for all channels this is ``ops/gated_delta.py``'s rule
-letter for letter, and ``tests/test_kda.py`` holds the two ops to each
-other. That op pulls ``e^c`` (``c`` the running sum of ``g`` inside a chunk)
+``[LOWER_BOUND, 0]``, and ``b_t`` in [0, 1] (Kimi Linear, arXiv:2510.26692);
+``q_t`` and ``k_t`` are the layer's after their L2 norm, ``q_t = x / (|x|
+sqrt(d_k))`` and ``k_t = x / |x|`` with ``|x|^2 = sum x^2 + L2_EPS``, which
+is the published rule's first step and taken here, on the tile a kernel
+holds. With one ``g_t`` for all channels this is ``ops/gated_delta.py``'s
+rule letter for letter (that op is handed normalised operands), and
+``tests/test_kda.py`` holds the two ops to each other. That op pulls
+``e^c`` (``c`` the running sum of ``g`` inside a chunk)
 out of every product because ``c`` is one number a row; here the chunk's
 products are ``sum_d q_id k_jd e^{c_id - c_jd}`` and the decay has to ride
 the operands. With ``C`` tokens a chunk (the WY form),
@@ -30,8 +34,13 @@ the products that the mask keeps are at most 1 again. That is the one place
 the bound on ``g`` is used.
 
 One function, :func:`_chunk`, is a chunk of the rule for a block of heads:
-``(q, k, v, c, b, S) -> (O, S')`` on ``(heads, C, d)`` values, products
-through ``ops/gated_delta.py``'s ``_dot`` (exact to float32 rounding) and
+``(q, k, v, g, b, S) -> (O, S')`` on ``(heads, C, d)`` values, ``q`` and
+``k`` BEFORE their norm and ``g`` before its running sum: the chunk
+normalises in float32 (the result is never rounded to a narrower type on its
+way into the products) and forms ``c = L g`` with ``L`` the lower-triangular
+matrix of ones, a product like the others: ``L`` is exact in bfloat16, so
+three passes carry a float32 ``g`` exactly (:func:`_running_sum`). Products
+go through ``ops/gated_delta.py``'s ``_dot`` (exact to float32 rounding) and
 its inverse by products alone. A second, :func:`_chunk_bwd`, is its
 pull-back written by hand: it recomputes ``A``, ``T``, ``U``, ``W``, ``V'``
 and ``P`` once (the decay factors once for both score matrices and both
@@ -60,6 +69,8 @@ and column operands before their decay
     dc   = Q . dQ + (b K) . d(b K) - K . dK_-                  rises with the rows, falls with the columns
     dc_C += sum_i [(K e^{c_C - c}) . d(K e^{c_C - c})]_i + e^{c_C} . sum_v [dS' . S]
     dK   = b d(b K) + dK_-       dV = b T^T dU       db = sum_v [T^T dU . V] + sum_d [d(b K) . K]
+    dg   = L^T dc                                               the running sum from the last row up
+    dx   = r (dy - y sum_d [y . dy] / s^2)                      y = r x, r = s / |x|: the two norms (s = 1 / sqrt(d_k) for q, 1 for k)
 
 (``.`` elementwise; the block's reference row ``r_I`` takes no cotangent:
 the two sides' cancel, since no product depends on it). ``v`` and ``dO``
@@ -69,14 +80,29 @@ dO`` are three bfloat16 passes where they are bfloat16, the same float32
 result as six on the upcast copy (``_dot``'s contract). ``jax.vjp`` of
 :func:`_chunk` is the oracle ``tests/test_kda.py`` holds all six
 cotangents to; it runs in no program (``custom_backward=False`` aside).
+The running sum alone carries a pull-back of its own inside that oracle:
+the transpose JAX traces through a three-pass product rounds the cotangent
+to bfloat16.
 
 Two routes run the two functions, chosen by :func:`implementation`:
 
-- on a TPU two Pallas kernels over the grid (head blocks, chunks), the
-  chunks in order with the state in VMEM: ``kda_fwd`` (``O`` and the state
-  entering each chunk) and ``kda_bwd`` (the chunks from the last down, the
-  state's cotangent in VMEM). The chunk's C x C matrices, the decayed
-  copies of q and k and every float32 temporary stay in VMEM;
+- on a TPU two Pallas kernels over the grid (batch, head blocks, chunks),
+  the chunks in order with the state in VMEM: ``kda_fwd`` (``O`` and the
+  state entering each chunk) and ``kda_bwd`` (the chunks from the last down,
+  the state's cotangent in VMEM). They read and write the layer's own
+  arrays: ``q``, ``k``, ``v``, ``g`` and their cotangents are (b, s, h, d) as
+  the convolutions and the gate's projection hold them, seen as (b, s / C, C,
+  h * d) with no copy; a grid step takes the (C, heads * d) tile of a block
+  of heads and the body takes the heads apart at multiples of ``d`` lanes.
+  ``o`` and its cotangent are head-major, (b, h, s, d_v): XLA lays the gated
+  norm that reads ``o`` out head-major (a (tokens, h * d) array has no
+  (tokens, heads, d) view of the same bytes, tiled as the chip tiles it, so
+  an ``o`` written in tiles costs that norm three float32 copies: 33 ms a
+  step in the Ling cell, PERF §6 PR 42). The normalised and decayed copies
+  of q and k, the running sum, the chunk's C x C matrices and every float32
+  temporary stay in VMEM; ``o`` and the cotangents of q, k, v leave in their
+  operand's type. ``beta`` (1 MB) is the one array XLA moves, to a row a
+  chunk and head;
 - elsewhere a ``lax.scan`` over the chunks of each: the kernels' twin, and
   what they are tested against.
 
@@ -95,7 +121,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from hops_tpu.ops.gated_delta import _NT, _TN, _dot, _iotas, _unit_lower_inverse
+from hops_tpu.ops.gated_delta import _NT, _TN, _column, _dot, _iotas, _unit_lower_inverse
 from hops_tpu.telemetry.metrics import REGISTRY
 from hops_tpu.telemetry.spans import COUNTER_TRAIN_KDA_KERNEL_CALLS
 
@@ -108,15 +134,21 @@ LOWER_BOUND = -5.0
 SUB = 16
 _MAX_EXPONENT = -LOWER_BOUND * SUB / 2 + 5.0
 #: heads of one grid step of the two kernels, worked on together as batched
-#: products. At 32 heads x 8,192 tokens x (128, 128), bfloat16, on a v5e the
-#: forward / backward kernel alone read, ms a call: 8 / 8 heads 5.22 / 9.24,
-#: 16 / 8 5.22 / 9.24, 16 / 16 5.21 / 9.25, 4 / 4 5.21 / 9.25 (my chip runs,
-#: PR 36; the traced pull-back PR 35 had read 18.28 beside them, and 2 / 1
-#: heads 44 ms for the pair): the kernels are bound by their arithmetic
-#: (float32 products in six bfloat16 passes, five exponentials of a chunk's
-#: keys), not by the wait between products
-FWD_HEADS = 8
-BWD_HEADS = 8
+#: products: a step's tile of the layer's arrays is (64, heads x d). At 32
+#: heads x 8,192 tokens x (128, 128), bfloat16, on a v5e the op alone
+#: (forward / backward, ms; the 1 MB moves of beta included) read 4 / 4
+#: heads 5.58 / 9.54, 8 / 8 5.24 / 9.28, 16 / 16 5.09 / 9.16, 16 / 8 5.10 /
+#: 9.29, 32 / 8 5.15 / 9.31 (my chip runs, PR 42). Head-major operands had
+#: read the same at 4 to 16 heads (5.21-5.22 / 9.24-9.25: my chip runs, PR 36;
+#: the traced pull-back PR 35 had read 18.28 beside them, and 2 / 1 heads 44
+#: ms for the pair): the kernels are bound by their arithmetic (float32
+#: products in six bfloat16 passes, five exponentials of a chunk's keys), not
+#: by the wait between products; the wider tile's longer rows are what is left
+FWD_HEADS = 16
+BWD_HEADS = 16
+#: what the squared length of a query or key is raised by before its root
+#: (``models/linear_attention.py:L2_EPS``, the published layer's)
+L2_EPS = 1e-6
 _VMEM_LIMIT = 100 * 1024 * 1024
 
 
@@ -162,10 +194,53 @@ def _as_row(column):
     return jnp.sum(jnp.where(row == col, column, 0.0), axis=1, keepdims=True)
 
 
+def _normalise(x, scale=1.0):
+    """``(scale x / |x|, scale / |x|)`` over the last axis in float32: the
+    rule's first step, on the tile the kernel holds."""
+    x = x.astype(F32)
+    r = scale * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+    return r * x, r
+
+
+def _normalise_bwd(y, r, d_y, scale=1.0):
+    """The cotangent of ``x`` for ``y, r = _normalise(x, scale)``."""
+    return r * (d_y - y * (jnp.sum(y * d_y, axis=-1, keepdims=True) * scale ** -2))
+
+
+def _ones_below(like, *, transposed=False):
+    """The 0/1 matrix (heads, C, C) whose product with a (heads, C, d)
+    array ``like`` is its running sum over the rows (``transposed``: from
+    the last row up). bfloat16 holds it exactly, so ``_dot`` carries a
+    float32 operand through it in three exact passes."""
+    heads, size, _ = like.shape
+    row, col = _iotas(size, size)
+    ones = jnp.where(row <= col if transposed else row >= col, 1.0, 0.0).astype(jnp.bfloat16)
+    return jnp.broadcast_to(ones, (heads, size, size))
+
+
+@jax.custom_vjp
+def _running_sum(g):
+    """``c``: the running sum of ``g`` (heads, C, d_k) inside the chunk, as
+    a product with ones. Its pull-back is given (:func:`_running_sum_bwd`,
+    which :func:`_chunk_bwd` calls too): the transpose JAX would trace
+    through ``_dot``'s three passes rounds the cotangent to bfloat16."""
+    return _dot(_ones_below(g), g)
+
+
+def _running_sum_bwd(d_c):
+    return _dot(_ones_below(d_c, transposed=True), d_c)
+
+
+_running_sum.defvjp(lambda g: (_running_sum(g), None), lambda _, d_c: (_running_sum_bwd(d_c),))
+
+
 class _Parts(NamedTuple):
     """What both directions make of a chunk before ``O``, (heads, ...)."""
-    q: jax.Array  # float32
+    q: jax.Array  # float32, normalised
     k: jax.Array
+    r_q: jax.Array  # (heads, C, 1): what :func:`_normalise` scaled a row by
+    r_k: jax.Array
+    c: jax.Array  # the running sum of g
     factors: list  # :func:`_decay_factors`
     t: jax.Array  # T = (I + A)^-1
     gamma: jax.Array  # e^c
@@ -175,34 +250,37 @@ class _Parts(NamedTuple):
     p: jax.Array
 
 
-def _chunk_parts(q, k, v, c, beta, state_t) -> _Parts:
-    """``v`` stays in its own type and ``beta`` rides ``T``'s columns (``T (b
+def _chunk_parts(q, k, v, g, beta, state_t) -> _Parts:
+    """``q``, ``k`` are normalised here and stay float32 from then on;
+    ``v`` stays in its own type and ``beta`` rides ``T``'s columns (``T (b
     V) = (T b_row) V``), so that a bfloat16 ``v`` meets a float32 ``T`` in
     the three passes of ``_dot``; ``e^c`` differs by channel and has to
     ride ``K``."""
-    q, k = q.astype(F32), k.astype(F32)
+    q, r_q = _normalise(q, q.shape[-1] ** -0.5)
+    k, r_k = _normalise(k)
+    c = _running_sum(g)
     factors = _decay_factors(c)
     t = _unit_lower_inverse(_decayed_scores(beta * k, k, factors, strict=True))
     gamma = jnp.exp(c)
     u = _dot(t * _as_row(beta), v)
     w = _dot(t, (beta * gamma) * k)
     p = _decayed_scores(q, k, factors, strict=False)
-    return _Parts(q, k, factors, t, gamma, u, w, u - _dot(w, state_t, _NT), p)
+    return _Parts(q, k, r_q, r_k, c, factors, t, gamma, u, w, u - _dot(w, state_t, _NT), p)
 
 
-def _chunk(q, k, v, c, beta, state_t):
+def _chunk(q, k, v, g, beta, state_t):
     """One chunk of the rule for a block of heads. ``q``, ``k`` (heads, C,
-    d_k) and ``v`` (heads, C, d_v) in any float type, ``c`` (heads, C, d_k)
-    the running sum of the log-decay inside the chunk and ``beta`` (heads,
-    C, 1), float32; ``state_t`` (heads, d_v, d_k) is the state entering the
-    chunk, TRANSPOSED: the decay then scales its lanes and every product
-    with it is a plain one. Returns ``(O (heads, C, d_v) float32, the state
-    leaving the chunk)``. :func:`_chunk_bwd` is its pull-back, written by
-    hand: an edit here has a second function to keep in step."""
-    x = _chunk_parts(q, k, v, c, beta, state_t)
+    d_k) BEFORE their L2 norm and ``v`` (heads, C, d_v) in any float type,
+    ``g`` (heads, C, d_k) the log-decay and ``beta`` (heads, C, 1), float32;
+    ``state_t`` (heads, d_v, d_k) is the state entering the chunk,
+    TRANSPOSED: the decay then scales its lanes and every product with it
+    is a plain one. Returns ``(O (heads, C, d_v) float32, the state leaving
+    the chunk)``. :func:`_chunk_bwd` is its pull-back, written by hand: an
+    edit here has a second function to keep in step."""
+    x = _chunk_parts(q, k, v, g, beta, state_t)
     o = _dot(x.gamma * x.q, state_t, _NT) + _dot(x.p, x.v_new)
-    last = c[:, -1:, :]
-    return o, jnp.exp(last) * state_t + _dot(x.v_new, jnp.exp(last - c) * x.k, _TN)
+    last = x.c[:, -1:, :]
+    return o, jnp.exp(last) * state_t + _dot(x.v_new, jnp.exp(last - x.c) * x.k, _TN)
 
 
 def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
@@ -226,19 +304,19 @@ def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
     return jnp.concatenate(d_a_rows, axis=1), jnp.concatenate(d_p_rows, axis=1), d_cols
 
 
-def _chunk_bwd(q, k, v, c, beta, state_t, d_o, d_state):
+def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state):
     """The pull-back of :func:`_chunk` at its arguments, by formula:
-    ``(d_q, d_k, d_v, d_c, d_beta, d_state_t)`` for the cotangents ``d_o``
+    ``(d_q, d_k, d_v, d_g, d_beta, d_state_t)`` for the cotangents ``d_o``
     (heads, C, d_v; any float type) of ``O`` and ``d_state`` (heads, d_v,
     d_k) of the state leaving the chunk. The module docstring has the
     formulas; ``tests/test_kda.py`` holds every one of the six to
     ``jax.vjp(_chunk)``. ``v`` and ``d_o`` enter their products in their own
     type (three passes of ``_dot`` where they are bfloat16)."""
     dtypes = q.dtype, k.dtype, v.dtype
-    size, d_v_width = q.shape[1], v.shape[2]
+    size, d_k_width, d_v_width = *q.shape[1:], v.shape[2]
     row, col = _iotas(size, size)
-    x = _chunk_parts(q, k, v, c, beta, state_t)
-    q, k, gamma = x.q, x.k, x.gamma
+    x = _chunk_parts(q, k, v, g, beta, state_t)
+    q, k, c, gamma = x.q, x.k, x.c, x.gamma
     last = c[:, -1:, :]
     to_end, whole = jnp.exp(last - c), jnp.exp(last)  # e^{c_C - c}, e^{c_C}
     q_dec, k_end, k_beta = gamma * q, to_end * k, beta * k
@@ -261,14 +339,17 @@ def _chunk_bwd(q, k, v, c, beta, state_t, d_o, d_state):
     d_c = q * d_q + k_beta * d_k_beta - k * d_k_falling + jnp.where(_iotas(size, 1)[0] == size - 1, d_last, 0.0)
     d_beta = jnp.sum(d_bv * v.astype(F32), axis=2, keepdims=True) + jnp.sum(d_k_beta * k, axis=2, keepdims=True)
     d_state_in = whole * d_state + _dot(d_o, q_dec, _TN) - _dot(d_v_new, x.w, _TN)
-    d_k = beta * d_k_beta + d_k_falling
-    return (d_q.astype(dtypes[0]), d_k.astype(dtypes[1]), (beta * d_bv).astype(dtypes[2]), d_c, d_beta, d_state_in)
+    # back through the running sum and the two norms, to what the layer handed over
+    d_g = _running_sum_bwd(d_c)
+    d_q = _normalise_bwd(q, x.r_q, d_q, d_k_width ** -0.5)
+    d_k = _normalise_bwd(k, x.r_k, beta * d_k_beta + d_k_falling)
+    return (d_q.astype(dtypes[0]), d_k.astype(dtypes[1]), (beta * d_bv).astype(dtypes[2]), d_g, d_beta, d_state_in)
 
 
 # -- the XLA route: a scan over the chunks of `_chunk` and of `_chunk_bwd` -----
 
 
-def _forward_scan(q, k, v, c, beta):
+def _forward_scan(q, k, v, g, beta):
     """Chunk-major operands (n, b * h, C, ...); returns ``(o, the states
     entering each chunk)``."""
     def step(state_t, chunk):
@@ -276,45 +357,72 @@ def _forward_scan(q, k, v, c, beta):
         return new, (o, state_t)
 
     zero = jnp.zeros((q.shape[1], v.shape[-1], q.shape[-1]), F32)
-    _, (o, states) = jax.lax.scan(step, zero, (q, k, v, c, beta))
+    _, (o, states) = jax.lax.scan(step, zero, (q, k, v, g, beta))
     return o, states
 
 
-def _backward_scan(q, k, v, c, beta, states, d_o):
+def _backward_scan(q, k, v, g, beta, states, d_o):
     def step(d_state, chunk):
         *d_inputs, d_state = _chunk_bwd(*chunk, d_state)
         return d_state, tuple(d_inputs)
 
-    _, d_inputs = jax.lax.scan(step, jnp.zeros_like(states[0]), (q, k, v, c, beta, states, d_o), reverse=True)
+    _, d_inputs = jax.lax.scan(step, jnp.zeros_like(states[0]), (q, k, v, g, beta, states, d_o), reverse=True)
     return d_inputs
 
 
 # -- the TPU route: the same two loops as Pallas kernels -----------------------
+# q, k, v, g and their cotangents are the layer's own (b, s, h * d) arrays seen
+# by chunks, (b, n, C, h * d): a grid step takes the (C, heads * d) tile of a
+# block of heads and the body takes the heads apart at multiples of d lanes.
+# o and its cotangent, beta and its cotangent and the kept states are
+# head-major, (b, h, n, rows, cols): o (C, d_v) because that is how the compiled
+# gated norm reads it (see :func:`kda_rule`), beta as a ROW (1, C), since a
+# column of one lane would be laid out in HBM 128 lanes wide.
+
+
+def _heads_apart(ref, heads):
+    """The (C, heads * d) tile of ``ref`` as (heads, C, d)."""
+    d = ref.shape[1] // heads
+    return jnp.stack([ref[:, i * d: (i + 1) * d] for i in range(heads)])
+
+
+def _heads_together(ref, value):
+    """(heads, C, d) ``value`` into the (C, heads * d) tile of ``ref``, in
+    ``ref``'s type."""
+    d = value.shape[2]
+    for i in range(value.shape[0]):
+        ref[:, i * d: (i + 1) * d] = value[i].astype(ref.dtype)
 
 
 def _zero_before_the_first_chunk(scratch):
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _():
         scratch[...] = jnp.zeros_like(scratch)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, c_ref, beta_ref, o_ref, states_ref, state_scr):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref, state_scr):
     _zero_before_the_first_chunk(state_scr)
+    heads = beta_ref.shape[0]
     state_t = state_scr[...]
     states_ref[...] = state_t
-    o, state_scr[...] = _chunk(q_ref[...], k_ref[...], v_ref[...], c_ref[...], beta_ref[...], state_t)
+    o, state_scr[...] = _chunk(*(_heads_apart(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
+                               _column(beta_ref[...]), state_t)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, c_ref, beta_ref, states_ref, d_o_ref,
-                d_q_ref, d_k_ref, d_v_ref, d_c_ref, d_beta_ref, d_state_scr):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, d_o_ref,
+                d_q_ref, d_k_ref, d_v_ref, d_g_ref, d_beta_ref, d_state_scr):
     """The chunks from the last down: a chunk's cotangents are
     :func:`_chunk_bwd` at what the forward kept, given ``dO`` and the
     cotangent of the state it left (the scratch)."""
     _zero_before_the_first_chunk(d_state_scr)
-    inputs = tuple(ref[...] for ref in (q_ref, k_ref, v_ref, c_ref, beta_ref, states_ref, d_o_ref, d_state_scr))
-    (d_q_ref[...], d_k_ref[...], d_v_ref[...], d_c_ref[...], d_beta_ref[...],
-     d_state_scr[...]) = _chunk_bwd(*inputs)
+    heads = beta_ref.shape[0]
+    q, k, v, g = (_heads_apart(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref))
+    *d_tiles, d_beta, d_state_scr[...] = _chunk_bwd(
+        q, k, v, g, _column(beta_ref[...]), states_ref[...], d_o_ref[...], d_state_scr[...])
+    for ref, value in zip((d_q_ref, d_k_ref, d_v_ref, d_g_ref), d_tiles):
+        _heads_together(ref, value)
+    d_beta_ref[...] = _as_row(d_beta)
 
 
 _m_kernel_calls = REGISTRY.counter(
@@ -325,28 +433,32 @@ _m_kernel_calls = REGISTRY.counter(
 
 
 def _call(kernel_name, body, operands, outputs, *, heads, state, interpret, reverse=False):
-    """One ``pallas_call`` named ``kernel_name`` over the grid (head blocks,
-    chunks, in order or from the last) of (b * h, n, rows, cols)
-    ``operands``, with a float32 scratch of ``state`` a head; ``outputs``
-    are (rows, cols, type)."""
-    bh, n = operands[0].shape[:2]
-    heads = next(h for h in range(min(heads, bh), 0, -1) if bh % h == 0)
+    """One ``pallas_call`` named ``kernel_name`` over the grid (batch, head
+    blocks, chunks in order or from the last), with a float32 scratch of
+    ``state`` a head. ``operands`` and ``outputs`` (shape, type) are (b, n,
+    C, h * d), of which a step takes a block of heads' (C, heads * d) tile,
+    or head-major (b, h, n, rows, cols), of which it takes (heads, rows,
+    cols); the fifth operand is ``beta``."""
+    b, h, n = operands[4].shape[:3]
+    heads = next(i for i in range(min(heads, h), 0, -1) if h % i == 0)
 
-    def at(i, j):
-        return (i, n - 1 - j if reverse else j, 0, 0)
+    def chunk(j):
+        return n - 1 - j if reverse else j
 
-    def spec(rows, cols):
-        return pl.BlockSpec((heads, None, rows, cols), at)
+    def spec(shape):
+        if len(shape) == 4:
+            return pl.BlockSpec((None, None, shape[2], shape[3] // h * heads), lambda i, block, j: (i, chunk(j), 0, block))
+        return pl.BlockSpec((None, heads, None, *shape[3:]), lambda i, block, j: (i, block, chunk(j), 0, 0))
 
     return pl.pallas_call(
         body,
-        out_shape=tuple(jax.ShapeDtypeStruct((bh, n, rows, cols), dtype) for rows, cols, dtype in outputs),
-        grid=(bh // heads, n),
-        in_specs=[spec(*t.shape[2:]) for t in operands],
-        out_specs=tuple(spec(rows, cols) for rows, cols, _ in outputs),
+        out_shape=tuple(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in outputs),
+        grid=(b, h // heads, n),
+        in_specs=[spec(t.shape) for t in operands],
+        out_specs=tuple(spec(shape) for shape, _ in outputs),
         scratch_shapes=[pltpu.VMEM((heads, *state), F32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=kernel_name,
     )(*operands)
@@ -373,19 +485,22 @@ def _builder(kernel_name):
 
 
 @_builder("kda_fwd")
-def _forward_pallas(name, q, k, v, c, beta, interpret):
-    """Head-major operands (b * h, n, C, ...)."""
-    (size, dk), dv = q.shape[2:], v.shape[-1]
-    return _call(name, _fwd_kernel, (q, k, v, c, beta), ((size, dv, F32), (dv, dk, F32)),
-                 heads=FWD_HEADS, state=(dv, dk), interpret=interpret)
+def _forward_pallas(name, q, k, v, g, beta, interpret):
+    """``q``, ``k``, ``v``, ``g`` (b, n, C, h * d) and ``beta`` (b, h, n, 1,
+    C); ``o`` (b, h, n, C, d_v) in ``v``'s type, the states (b, h, n, d_v,
+    d_k)."""
+    b, h, n = beta.shape[:3]
+    state = v.shape[3] // h, q.shape[3] // h
+    return _call(name, _fwd_kernel, (q, k, v, g, beta),
+                 (((b, h, n, q.shape[2], state[0]), v.dtype), ((b, h, n, *state), F32)),
+                 heads=FWD_HEADS, state=state, interpret=interpret)
 
 
 @_builder("kda_bwd")
-def _backward_pallas(name, q, k, v, c, beta, states, d_o, interpret):
-    (size, dk), dv = q.shape[2:], v.shape[-1]
-    return _call(name, _bwd_kernel, (q, k, v, c, beta, states, d_o),
-                 ((size, dk, q.dtype), (size, dk, k.dtype), (size, dv, v.dtype), (size, dk, F32), (size, 1, F32)),
-                 heads=BWD_HEADS, state=(dv, dk), interpret=interpret, reverse=True)
+def _backward_pallas(name, q, k, v, g, beta, states, d_o, interpret):
+    return _call(name, _bwd_kernel, (q, k, v, g, beta, states, d_o),
+                 tuple((t.shape, t.dtype) for t in (q, k, v, g, beta)),
+                 heads=BWD_HEADS, state=states.shape[3:], interpret=interpret, reverse=True)
 
 
 def implementation(interpret: bool | None = None) -> str:
@@ -397,17 +512,17 @@ def implementation(interpret: bool | None = None) -> str:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule(q, k, v, c, beta, route):
-    return _rule_fwd(q, k, v, c, beta, route)[0]
+def _rule(q, k, v, g, beta, route):
+    return _rule_fwd(q, k, v, g, beta, route)[0]
 
 
-def _rule_fwd(q, k, v, c, beta, route):
+def _rule_fwd(q, k, v, g, beta, route):
     impl, interpret = route
     if impl == "pallas":
-        o, states = _forward_pallas(q, k, v, c, beta, interpret=interpret)
+        o, states = _forward_pallas(q, k, v, g, beta, interpret=interpret)
     else:
-        o, states = _forward_scan(q, k, v, c, beta)
-    return o.astype(v.dtype), (q, k, v, c, beta, states)
+        o, states = _forward_scan(q, k, v, g, beta)
+    return o.astype(v.dtype), (q, k, v, g, beta, states)
 
 
 def _rule_bwd(route, kept, d_o):
@@ -423,32 +538,43 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
              chunk: int = DEFAULT_CHUNK, custom_backward: bool = True,
              interpret: bool | None = None) -> jax.Array:
-    """``o`` (b, h, s, d_v) of the recurrence in the module docstring for
-    ``q``, ``k`` (b, h, s, d_k), ``v`` (b, h, s, d_v), the log-decay ``g``
-    (b, h, s, d_k) in ``[LOWER_BOUND, 0]`` and ``beta`` (b, h, s), in
-    ``v``'s type; differentiable in all five. A sequence that is not whole
-    chunks is padded with tokens that leave the state as it is (``beta`` 0,
-    ``g`` 0). ``custom_backward=False`` differentiates the scan with
-    ``jax.grad`` (tests: the oracle of :func:`_chunk_bwd`; float32 values
-    only, a traced pull-back through a three-pass product rounds to
-    bfloat16); ``interpret`` as :func:`implementation` reads it."""
+    """``o`` (b, s, h, d_v) of the recurrence in the module docstring, in
+    ``v``'s type (the kernel writes it head-major and the move to this shape
+    is left to XLA, which makes it a layout of the norm that follows), for
+    the arrays a layer holds: ``q``, ``k`` (b, s, h, d_k)
+    BEFORE their L2 norm (the rule normalises both and scales ``q`` by ``1 /
+    sqrt(d_k)``), ``v`` (b, s, h, d_v), the log-decay ``g`` (b, s, h, d_k)
+    in ``[LOWER_BOUND, 0]`` and ``beta`` (b, s, h); differentiable in all
+    five. A sequence that is not whole chunks is padded with tokens that
+    leave the state as it is (``beta`` 0, ``g`` 0). ``custom_backward=False``
+    differentiates the scan with ``jax.grad`` (tests: the oracle of
+    :func:`_chunk_bwd`; float32 values only, a traced pull-back through a
+    three-pass product rounds to bfloat16); ``interpret`` as
+    :func:`implementation` reads it."""
     if chunk % SUB:
         raise ValueError(f"chunk {chunk} is not whole blocks of {SUB} rows")
-    s = q.shape[2]
+    s = q.shape[1]
     pad = -s % chunk
     if pad:
-        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 3))
-                            for t in (q, k, v, g, beta))
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v, g, beta))
     route = (implementation(interpret) if custom_backward else "xla_scan", bool(interpret))
-    b, h, padded = q.shape[:3]
+    b, padded, h = beta.shape
+    n = padded // chunk
+    beta = beta.astype(F32)
+    if route[0] == "pallas":
+        def chunks(t):  # (b, s, h, d) -> (b, n, C, h * d): the same bytes
+            return t.reshape(b, n, chunk, -1)
 
-    def chunks(t):  # (b, h, s, ...) -> (b * h, n, C, ...), or chunk-major for the scan
-        t = t.reshape(b * h, padded // chunk, chunk, *t.shape[3:])
-        return t if route[0] == "pallas" else jnp.swapaxes(t, 0, 1)
+        beta = jnp.moveaxis(beta, 2, 1).reshape(b, h, n, 1, chunk)  # a row a chunk and head: 1 MB moved
+    else:
+        def chunks(t):  # (b, s, h, d) -> (n, b * h, C, d), chunk-major for the scan
+            return t.reshape(b, n, chunk, h, -1).transpose(1, 0, 3, 2, 4).reshape(n, b * h, chunk, -1)
 
-    g = chunks(g.astype(F32))
-    args = (chunks(q), chunks(k), chunks(v), jnp.cumsum(g, axis=2), chunks(beta.astype(F32)[..., None]))
+        beta = chunks(beta[..., None])
+    args = (chunks(q), chunks(k), chunks(v), chunks(g.astype(F32)), beta)
     o = _rule(*args, route) if custom_backward else _forward_scan(*args)[0].astype(v.dtype)
-    if route[0] != "pallas":
-        o = jnp.swapaxes(o, 0, 1)
-    return o.reshape(*v.shape)[:, :, :s]
+    if route[0] == "pallas":  # head-major from the kernel: the move is XLA's to place, and it makes it a layout
+        o = jnp.moveaxis(o.reshape(b, h, padded, -1), 1, 2)
+    else:
+        o = o.reshape(n, b, h, chunk, -1).transpose(1, 0, 3, 2, 4).reshape(b, padded, h, -1)
+    return o[:, :s]

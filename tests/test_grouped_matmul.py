@@ -5,6 +5,7 @@ schedule's invariants, the fallback to the twin, and a compile of the
 three kernels for a described v5e at OLMoE's widths."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -272,27 +273,51 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip, chu
 def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     """The Kimi delta rule's two kernels at the Ling cell's shape (32 heads x
     8,192 tokens x (128, 128), bf16), forward and the hand-written backward
-    (this file holds the one fixture that may load the TPU compiler): Mosaic
-    accepts the 16-row blocks, their concatenations, the 32-deep transposed
-    products of ``_decayed_scores_bwd`` and the VMEM a block of heads asks
-    for under ``_VMEM_LIMIT``. Nothing runs."""
+    (this file holds the one fixture that may load the TPU compiler), on the
+    arrays a layer holds: (b, s, h * d) from the convolutions, seen as (b, s,
+    h, d) on the way in and the cotangents seen as (b, s, h * d) again on the
+    way out; ``o`` is taken head-major, as the compiled gated norm takes it
+    (XLA turns the op's move to (b, s, h, d) into that norm's layout; read as
+    (b, s, h * d) it would cost one bfloat16 copy each way). Mosaic accepts the (64, 8 x 128) tiles and the heads taken apart
+    at multiples of 128 lanes, the 16-row blocks, their concatenations, the
+    32-deep transposed products of ``_decayed_scores_bwd`` and the VMEM a
+    block of heads asks for under ``_VMEM_LIMIT``; and XLA is left nothing to
+    do beside the two calls: no windowed sum, no transpose and no copy of a
+    32 x 8,192 x 128 array (the views are bitcasts: Mosaic's operands force
+    no layout copy), only the move of ``beta`` and of its cotangent, 1 MB
+    each. Nothing runs."""
     from hops_tpu.ops import kda
 
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
     try:
-        wide = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
-        decay = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32, sharding=one_chip)
-        beta = jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=one_chip)
+        b, s, h, d = 1, 8192, 32, 128
+        wide = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
+        decay = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32, sharding=one_chip)
+        beta = jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip)
+
+        def rule(q, k, v, g, beta):
+            o = kda.kda_rule(*(t.reshape(b, s, h, d) for t in (q, k, v, g)), beta, interpret=False)
+            return jnp.moveaxis(o, 2, 1)
 
         def grads(*x):
-            return jax.grad(lambda *y: kda.kda_rule(*y, interpret=False).astype(jnp.float32).sum(), argnums=range(5))(*x)
+            return jax.grad(lambda *y: rule(*y).astype(jnp.float32).sum(), argnums=range(5))(*x)
 
         text = jax.jit(grads).lower(wide, wide, wide, decay, beta).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 2 and "kda_fwd" in calls[0] + calls[1] and "kda_bwd" in calls[0] + calls[1]
+    assert "reduce-window" not in text
+    whole = re.compile(r"\[((\d+),)*\d+\]")  # a result's dims
+
+    def elements(line):
+        dims = whole.search(line.split(" = ", 1)[1])
+        return np.prod([int(n) for n in dims.group(0)[1:-1].split(",")]) if dims else 0
+
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r" (transpose|copy|copy-start)\(", line) and elements(line) >= h * s * d]
+    assert not moved, moved
 
 
 def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, monkeypatch):
