@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from hops_tpu.models.transformer import RMSNorm
+from hops_tpu.models.linear_attention import refuse_decode
 from hops_tpu.ops.attention import attention_reference, flash_attention
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.spans import SCOPE_DIFF_ATTN
@@ -55,11 +55,10 @@ class DifferentialAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, decode: bool = False, kv=None):
-        if decode:
-            raise NotImplementedError(
-                "decoding differential attention needs the differential form in the decode "
-                "kernels and, for cross layers, one K/V read by many layers in paged.BlockPool; "
-                "only the training path is built")
+        from hops_tpu.models.transformer import RMSNorm
+
+        if decode:  # its single-token form: the difference in the decode kernels, for cross layers one K/V read by many
+            refuse_decode("differential-attention")
         if self.attention_impl not in ("flash", "reference"):
             raise NotImplementedError(
                 f"differential attention runs on one device's flash or reference path, not {self.attention_impl!r}")
@@ -116,3 +115,23 @@ class DifferentialAttention(nn.Module):
             o = jnp.moveaxis(o.astype(self.dtype), 1, 2).reshape(b, s, dm)
         out = nn.Dense(dm, dtype=self.dtype, use_bias=self.use_bias, name="out")(o)
         return (out, (k, v)) if self.hands_on_kv else out
+
+
+def build_differential_attention(spec, shared) -> nn.Module:
+    """``transformer.build_attention``'s differential form, for the three attention kinds."""
+    options = dict(spec.mixer_options)
+    if options["rope_base"] is not None or options["qk_norm"] or shared.tp_shards > 1:
+        raise NotImplementedError("differential attention is built without rotary, QK-norm or tensor parallelism")
+    return DifferentialAttention(
+        shared.num_heads,
+        num_kv_heads=options["num_kv_heads"],
+        layer_index=options["layer_index"],
+        window=options["window"],
+        use_bias=options["use_bias"],
+        cross=spec.mixer == "cross_attention",
+        hands_on_kv=spec.hands_on == "kv",
+        attention_impl=shared.attention_impl,
+        norm_eps=spec.norm_eps,
+        dtype=shared.dtype,
+        name="attn",
+    )

@@ -144,6 +144,11 @@ class GatedDeltaNet(nn.Module):
             return dense(dm, "out")(o.reshape(b, s, h * dv))
 
 
+def build_gated_delta_net(spec, shared) -> nn.Module:
+    """``transformer.MIXERS["linear_attention"]``: the options are the module's sizes."""
+    return GatedDeltaNet(**dict(spec.mixer_options), norm_eps=spec.norm_eps, dtype=shared.dtype, name="attn")
+
+
 def _kda_rate_init(key, shape, dtype=jnp.float32):
     """``A_log`` of a Kimi-delta layer: log of a gate slope uniform in (1, 4)
     a head. With ``W_a x + dt_bias`` of unit scale at initialisation the
@@ -213,3 +218,8 @@ class KimiDeltaAttention(nn.Module):
             o = RMSNorm(self.norm_eps, dtype=jnp.float32, name="norm")(o)
             o = (o * gate[..., None]).astype(self.dtype)
             return dense(dm, "out")(o.reshape(b, s, h * dv))
+
+
+def build_kimi_delta_attention(spec, shared) -> nn.Module:
+    """``transformer.MIXERS["kimi_delta_attention"]``: the options are the module's sizes."""
+    return KimiDeltaAttention(**dict(spec.mixer_options), norm_eps=spec.norm_eps, dtype=shared.dtype, name="attn")

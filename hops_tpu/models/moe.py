@@ -33,7 +33,8 @@ through all experts, the expert weights enter whole. With a mesh
 carrying an ``expert`` axis, expert weights placed ``P("expert")`` (see
 :func:`expert_specs`) are partitioned by GSPMD.
 
-``MoEBlock`` slots into ``TransformerLM`` as a drop-in MLP replacement.
+``TransformerLM`` puts it in a ``Block`` wherever ``ffn_types`` (or ``moe_every``) says "moe"
+(:func:`build_routed_ffn`).
 
 A second router, the one DeepSeek-V3 (arXiv:2412.19437) and the models
 after it publish (``scoring="sigmoid"``): sigmoid scores, a selection bias
@@ -556,70 +557,16 @@ def expert_specs(params: Any, axis: str = "expert") -> Any:
     return walk(params)
 
 
-class MoEBlock(nn.Module):
-    """Transformer block with the MLP swapped for routed experts."""
+def build_routed_ffn(spec, shared):
+    """``transformer.FFNS["moe"]``: a layer's routed feed-forward, as a
+    function of the sublayer's input. The module keeps its name in the
+    parameter tree ("moe"); its device ops carry the vocabulary's ``mlp``
+    scope, as a dense layer's do by their module's name."""
+    experts = MoEMLP(**dict(spec.ffn_options), dtype=shared.dtype, expert_axis=shared.expert_axis,
+                     expert_shards=shared.expert_shards, name="moe")
 
-    num_heads: int
-    num_experts: int = 8
-    top_k: int = 2
-    expert_hidden: int | None = None
-    norm_topk_prob: bool = True
-    dtype: Any = jnp.bfloat16
-    attention_impl: str = "flash"
-    mesh: Any = None
-    seq_axis: str = "seq"
-    batch_axis: Any = None
-    dropout_rate: float = 0.0
-    max_decode_len: int = 2048
-    expert_axis: str | None = None
-    expert_shards: int = 1
-    kv_cache_dtype: str | None = None
-    num_kv_heads: int | None = None
-    window: int | None = None
-    ragged_decode: bool = False
-    qk_norm: bool = False
-    norm_eps: float = 1e-6
-    rope_base: float = 10000.0
-
-    @nn.compact
-    def __call__(self, x, train: bool = False, decode: bool = False):
-        from hops_tpu.models.transformer import Attention, RMSNorm
-
-        h = Attention(
-            self.num_heads,
-            dtype=self.dtype,
-            attention_impl=self.attention_impl,
-            mesh=self.mesh,
-            seq_axis=self.seq_axis,
-            batch_axis=self.batch_axis,
-            max_decode_len=self.max_decode_len,
-            kv_cache_dtype=self.kv_cache_dtype,
-            num_kv_heads=self.num_kv_heads,
-            window=self.window,
-            ragged_decode=self.ragged_decode,
-            qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps,
-            rope_base=self.rope_base,
-            name="attn",
-        )(RMSNorm(self.norm_eps, dtype=self.dtype)(x), decode=decode)
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        x = x + h
-        h = RMSNorm(self.norm_eps, dtype=self.dtype)(x)
-        # the routed experts ARE this block's feed-forward: the module
-        # keeps its name in the parameter tree ("moe"), its device ops
-        # carry the vocabulary's ``mlp`` scope like a dense block's
+    def routed(x):
         with jax.named_scope(SCOPE_MLP):
-            h = MoEMLP(
-                num_experts=self.num_experts,
-                top_k=self.top_k,
-                expert_hidden=self.expert_hidden,
-                norm_topk_prob=self.norm_topk_prob,
-                dtype=self.dtype,
-                expert_axis=self.expert_axis,
-                expert_shards=self.expert_shards,
-                name="moe",
-            )(h)
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return x + h
+            return experts(x)
+
+    return routed

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from hops_tpu.models.linear_attention import refuse_decode
 from hops_tpu.ops import selective_scan as scan_op
 from hops_tpu.ops.causal_conv import causal_conv, dt_bias_init
 from hops_tpu.parallel.mesh import per_shard
@@ -54,13 +55,6 @@ def _decay_init(key, shape, dtype=jnp.float32):
     return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
 
 
-def _refuse_decode(kind: str):
-    raise NotImplementedError(
-        f"decoding a {kind} layer needs a recurrent state and a convolution tail beside "
-        "the paged KV cache in modelrepo/paged.py and LMEngine; the benchmark has no "
-        "serving metric to judge it by, so only the training path is built")
-
-
 class Mamba(nn.Module):
     hands_on_memory: bool = False
     dtype: Any = jnp.bfloat16
@@ -68,7 +62,7 @@ class Mamba(nn.Module):
     @nn.compact
     def __call__(self, x, decode: bool = False):
         if decode:
-            _refuse_decode("state-space")
+            refuse_decode("state-space")
         dm = x.shape[-1]
         d_inner, n = EXPAND * dm, STATE_DIM
         rank = -(-dm // 16)
@@ -102,9 +96,19 @@ class GatedMemoryUnit(nn.Module):
     @nn.compact
     def __call__(self, x, memory, decode: bool = False):
         if decode:
-            _refuse_decode("gated-memory")
+            refuse_decode("gated-memory")
         with jax.named_scope(SCOPE_PROJ):
             gate = nn.Dense(memory.shape[-1], dtype=self.dtype, use_bias=False, name="in_proj")(x)
         with jax.named_scope(SCOPE_GATE):
             return nn.Dense(x.shape[-1], dtype=self.dtype, use_bias=False, name="out_proj")(
                 memory.astype(self.dtype) * nn.silu(gate))
+
+
+def build_mamba(spec, shared) -> nn.Module:
+    """``transformer.MIXERS["mamba"]``."""
+    return Mamba(hands_on_memory=spec.hands_on == "memory", dtype=shared.dtype, name="attn")
+
+
+def build_gated_memory(spec, shared) -> nn.Module:
+    """``transformer.MIXERS["gated_memory"]``."""
+    return GatedMemoryUnit(dtype=shared.dtype, name="attn")

@@ -24,6 +24,7 @@ by ``parallel.sharding.infer_param_spec``; activations shard
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -31,6 +32,10 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from hops_tpu.models.differential_attention import build_differential_attention
+from hops_tpu.models.linear_attention import build_gated_delta_net, build_kimi_delta_attention, refuse_decode
+from hops_tpu.models.moe import build_routed_ffn
+from hops_tpu.models.state_space import build_gated_memory, build_mamba
 from hops_tpu.ops.attention import (
     attention_reference,
     decode_attention,
@@ -42,7 +47,7 @@ from hops_tpu.ops.attention import (
 )
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_EMBED, SCOPE_MLP, SCOPE_MTP, keep
+from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_EMBED, SCOPE_MTP, keep
 
 _m_layer_kinds = REGISTRY.counter(
     "hops_tpu_train_layer_kinds_total",
@@ -56,19 +61,62 @@ _m_shared_reads = REGISTRY.counter(
     labels=("what",),
 )
 
-#: the kinds of layer a ``TransformerLM`` builds (``layer_types``):
-#: softmax attention over every key or behind ``window``, a Gated-DeltaNet
-#: layer, a Mamba layer, a gated memory unit (reads the ``y`` of the
-#: nearest Mamba layer before it) and cross attention (queries of its own
-#: against the K and V of the nearest ``full_attention`` layer before it)
-#: a Kimi-delta-attention layer (the delta rule with a decay per key channel)
-#: and latent attention (keys and values through a low-rank projection)
-LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention", "mamba", "gated_memory",
-               "cross_attention", "kimi_delta_attention", "latent_attention")
-#: the kinds of feed-forward a ``Block`` builds (``TransformerLM.ffn_types``)
-FFN_TYPES = ("dense", "moe")
-#: what a reading kind takes from which writing kind
+#: what a reading kind takes from which writing kind; the mixer takes it
+#: under that name (``GatedMemoryUnit(memory=)``, ``DifferentialAttention(kv=)``)
 SHARED_VALUES = {"gated_memory": ("memory", "mamba"), "cross_attention": ("kv", "full_attention")}
+
+
+def _pairs(**options) -> tuple[tuple[str, Any], ...]:
+    """Keyword arguments as sorted pairs: hashable, and equal when they say the same."""
+    return tuple(sorted(options.items()))
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of a ``TransformerLM`` is, where it differs from its
+    neighbours: the token mixer (a key of ``MIXERS``) and the feed-forward (a
+    key of ``FFNS``) with the keyword arguments their builders read, as sorted
+    pairs; the norms; the value it hands on to later layers (None | "memory",
+    a Mamba layer's ``y`` | "kv", an attention layer's K and V: its ``Block``
+    then returns ``(x, value)``). ``index`` is its place in the stack, for its
+    name. ``TransformerLM.layer_specs()`` resolves the model's fields to these."""
+
+    index: int = 0
+    mixer: str = "full_attention"
+    mixer_options: tuple[tuple[str, Any], ...] = ()
+    ffn: str = "dense"
+    ffn_options: tuple[tuple[str, Any], ...] = ()
+    norm_kind: str = "rms"  # "rms" | "layer": LayerNorm with bias
+    norm_placement: str = "pre"  # on each sublayer's input | "post_sublayer": on its output (Olmo 2)
+    norm_eps: float = 1e-6
+    hands_on: str | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class SharedSpec:
+    """What every layer of a model shares, and what ``clone()`` and
+    ``parallel/pipeline.py`` override: the fields of ``TransformerLM`` of the
+    same names (the attention path, the mesh, dropout, tensor parallelism,
+    the decode cache) and the expert axis of an enclosing ``shard_map``, which
+    only the pipeline sets."""
+
+    num_heads: int
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "flash"
+    mesh: Any = None
+    seq_axis: str = "seq"
+    batch_axis: Any = None
+    dropout_rate: float = 0.0
+    tp_axis: str | None = None
+    tp_shards: int = 1
+    expert_axis: str | None = None
+    expert_shards: int = 1
+    max_decode_len: int = 2048
+    kv_cache_dtype: str | None = None
+    ragged_decode: bool = False
+    paged_decode: bool = False
+    kv_page_size: int = 64
+    kv_pool_blocks: int | None = None
 
 
 def rotary_embedding(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
@@ -521,8 +569,6 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x, decode: bool = False):
         if decode:
-            from hops_tpu.models.linear_attention import refuse_decode
-
             refuse_decode("latent-attention")
         if self.attention_impl not in ("flash", "reference"):
             raise ValueError(f"latent attention runs attention_impl flash | reference, not {self.attention_impl!r}")
@@ -600,126 +646,95 @@ class MLP(nn.Module):
         return out
 
 
+def build_attention(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
+    """The mixer of a "full_attention", "sliding_attention" or
+    "cross_attention" layer, in the form its options name ("softmax":
+    ``Attention`` | "differential": ``DifferentialAttention``)."""
+    options = dict(spec.mixer_options)
+    form, use_bias = options.pop("form"), options.pop("use_bias")
+    if form == "differential":
+        return build_differential_attention(spec, shared)
+    if form != "softmax" or use_bias or spec.mixer == "cross_attention" or spec.hands_on == "kv":
+        raise ValueError(
+            f"attention_form {form!r}: biases, cross attention and handing on K/V "
+            "are built for the differential form only (attention_form is softmax | differential)")
+    return Attention(
+        shared.num_heads,
+        dtype=shared.dtype,
+        attention_impl=shared.attention_impl,
+        mesh=shared.mesh,
+        seq_axis=shared.seq_axis,
+        batch_axis=shared.batch_axis,
+        max_decode_len=shared.max_decode_len,
+        tp_axis=shared.tp_axis,
+        tp_shards=shared.tp_shards,
+        kv_cache_dtype=shared.kv_cache_dtype,
+        ragged_decode=shared.ragged_decode,
+        paged_decode=shared.paged_decode,
+        kv_page_size=shared.kv_page_size,
+        kv_pool_blocks=shared.kv_pool_blocks,
+        norm_eps=spec.norm_eps,
+        **options,  # num_kv_heads, window, qk_norm, rope_base
+        name="attn",
+    )
+
+
+def build_latent_attention(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
+    return LatentAttention(
+        shared.num_heads, **dict(spec.mixer_options), norm_eps=spec.norm_eps,
+        attention_impl=shared.attention_impl, dtype=shared.dtype, name="attn")
+
+
+def build_dense_ffn(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
+    return MLP(dtype=shared.dtype, tp_axis=shared.tp_axis, tp_shards=shared.tp_shards,
+               **dict(spec.ffn_options), name="mlp")
+
+
+#: ``LayerSpec.mixer`` -> ``(spec, shared) -> the token mixer``, a module
+#: named "attn" that takes ``(x, decode=)``, a reading kind also the value
+#: it reads (``SHARED_VALUES``). Each builder lives beside its module; a new
+#: kind of layer is its module, its builder, an entry here and its fields in
+#: ``TransformerLM.layer_specs()``.
+MIXERS = {
+    "full_attention": build_attention,  # softmax attention over every key
+    "linear_attention": build_gated_delta_net,
+    "sliding_attention": build_attention,  # behind ``window``
+    "mamba": build_mamba,
+    "gated_memory": build_gated_memory,  # reads the ``y`` of the nearest Mamba layer before it
+    "cross_attention": build_attention,  # queries of its own against the K and V of the nearest "full_attention" layer
+    "kimi_delta_attention": build_kimi_delta_attention,  # the delta rule with a decay per key channel
+    "latent_attention": build_latent_attention,  # keys and values through a low-rank projection
+}
+#: ``LayerSpec.ffn`` -> ``(spec, shared) -> the feed-forward``, called with
+#: the sublayer's input: ``MLP`` named "mlp", ``moe.MoEMLP`` named "moe".
+FFNS = {"dense": build_dense_ffn, "moe": build_routed_ffn}
+#: the kinds of layer a ``TransformerLM`` builds (``layer_types``, ``ffn_types``)
+LAYER_TYPES, FFN_TYPES = tuple(MIXERS), tuple(FFNS)
+
+
 class Block(nn.Module):
-    num_heads: int
-    dtype: Any = jnp.bfloat16
-    attention_impl: str = "flash"
-    mesh: Any = None
-    seq_axis: str = "seq"
-    batch_axis: Any = None
-    dropout_rate: float = 0.0
-    max_decode_len: int = 2048
-    tp_axis: str | None = None
-    tp_shards: int = 1
-    kv_cache_dtype: str | None = None
-    num_kv_heads: int | None = None
-    window: int | None = None
-    ragged_decode: bool = False
-    paged_decode: bool = False
-    kv_page_size: int = 64
-    kv_pool_blocks: int | None = None
-    qk_norm: bool = False
-    norm_eps: float = 1e-6
-    rope_base: float | None = 10000.0
-    # What a hybrid's layers differ in (TransformerLM.layer_types): the
-    # token mixer (softmax attention, or a Gated-DeltaNet layer whose
-    # ``linear_*`` sizes follow the published keys), where the norms sit
-    # ("pre": on each sublayer's input; "post_sublayer": on its output,
-    # the Olmo 2 placement) and the feed-forward's width.
-    layer_type: str = "full_attention"
-    norm_placement: str = "pre"
-    mlp_hidden: int | None = None
-    linear_num_heads: int | None = None
-    linear_key_dim: int | None = None
-    linear_value_dim: int | None = None
-    linear_conv_size: int = 4
-    linear_allow_neg_eigval: bool = True
-    # What a decoder-hybrid-decoder's layers differ in besides: the norm
-    # ("rms" | "layer": LayerNorm with bias), biases on the attention
-    # projections, the attention form ("softmax": ``Attention`` |
-    # "differential": ``differential_attention.DifferentialAttention``,
-    # with the layer's index in its lambda) and whether this layer hands a
-    # value on to later ones (``hands_on``:
-    # None | "memory", a Mamba layer's ``y`` | "kv", an attention layer's K
-    # and V): it then returns ``(x, value)``. A ``gated_memory`` or
-    # ``cross_attention`` layer takes that value as ``shared``.
-    norm_kind: str = "rms"
-    use_bias: bool = False
-    attention_form: str = "softmax"
-    layer_index: int = 0
-    hands_on: str | None = None
-    # What varies independently of the mixer: the feed-forward's kind
-    # ("dense" | "moe": ``moe.MoEMLP`` built from ``moe_options``, its
-    # keyword arguments as sorted pairs), and the sizes of a
-    # "latent_attention" mixer (``LatentAttention``'s, as pairs) and the
-    # lower bound of a "kimi_delta_attention" mixer's log-decay.
-    ffn_type: str = "dense"
-    moe_options: tuple[tuple[str, Any], ...] = ()
-    latent_options: tuple[tuple[str, Any], ...] = ()
-    linear_lower_bound: float = -5.0
+    """One layer: norm, mixer, residual, norm, feed-forward, residual (the
+    norms before their sublayers or after them). ``value`` is what an
+    earlier layer handed on, for a kind that reads one."""
+
+    spec: LayerSpec
+    shared: SharedSpec
 
     @nn.compact
-    def __call__(self, x, train: bool = False, decode: bool = False, shared=None):
-        if self.layer_type not in LAYER_TYPES:
-            raise ValueError(f"unknown layer_type {self.layer_type!r} (one of {LAYER_TYPES})")
-        if self.ffn_type not in FFN_TYPES:
-            raise ValueError(f"unknown ffn_type {self.ffn_type!r} (one of {FFN_TYPES})")
-        if self.norm_placement not in ("pre", "post_sublayer"):
-            raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
-        if self.norm_kind not in NORMS:
-            raise ValueError(f"unknown norm_kind {self.norm_kind!r} (one of {tuple(NORMS)})")
-        pre = self.norm_placement == "pre"
+    def __call__(self, x, train: bool = False, decode: bool = False, value=None):
+        spec, dropout_rate = self.spec, self.shared.dropout_rate
+        pre = spec.norm_placement == "pre"
 
         def norm(t):
-            return NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype)(t)
+            return NORMS[spec.norm_kind](spec.norm_eps, dtype=self.shared.dtype)(t)
 
-        reads = SHARED_VALUES.get(self.layer_type)
-        if reads and shared is None:
+        reads = SHARED_VALUES.get(spec.mixer)
+        if reads and value is None:
             raise ValueError(
-                f"a {self.layer_type} layer reads the {reads[0]} of a {reads[1]} layer before it: none was handed on")
-        if self.layer_type in ("mamba", "gated_memory"):
-            from hops_tpu.models.state_space import GatedMemoryUnit, Mamba
-
-            if self.layer_type == "mamba":
-                mixer = Mamba(hands_on_memory=self.hands_on == "memory", dtype=self.dtype, name="attn")
-            else:
-                mixer = functools.partial(GatedMemoryUnit(dtype=self.dtype, name="attn"), memory=shared)
-        elif self.layer_type == "cross_attention":
-            mixer = functools.partial(self._attention(), kv=shared)
-        elif self.layer_type == "linear_attention":
-            from hops_tpu.models.linear_attention import GatedDeltaNet
-
-            mixer = GatedDeltaNet(
-                self.linear_num_heads or self.num_heads,
-                key_dim=self.linear_key_dim,
-                value_dim=self.linear_value_dim,
-                conv_size=self.linear_conv_size,
-                allow_neg_eigval=self.linear_allow_neg_eigval,
-                norm_eps=self.norm_eps,
-                dtype=self.dtype,
-                name="attn",
-            )
-        elif self.layer_type == "kimi_delta_attention":
-            from hops_tpu.models.linear_attention import KimiDeltaAttention
-
-            mixer = KimiDeltaAttention(
-                self.linear_num_heads or self.num_heads,
-                key_dim=self.linear_key_dim,
-                value_dim=self.linear_value_dim,
-                conv_size=self.linear_conv_size,
-                lower_bound=self.linear_lower_bound,
-                norm_eps=self.norm_eps,
-                dtype=self.dtype,
-                name="attn",
-            )
-        elif self.layer_type == "latent_attention":
-            mixer = LatentAttention(
-                self.num_heads, **dict(self.latent_options), norm_eps=self.norm_eps,
-                attention_impl=self.attention_impl, dtype=self.dtype, name="attn")
-        else:
-            mixer = self._attention()
-        h = mixer(norm(x) if pre else x, decode=decode)
-        if self.hands_on:
+                f"a {spec.mixer} layer reads the {reads[0]} of a {reads[1]} layer before it: none was handed on")
+        mixer = MIXERS[spec.mixer](spec, self.shared)
+        h = mixer(norm(x) if pre else x, decode=decode, **({reads[0]: value} if reads else {}))
+        if spec.hands_on:
             h, handed_on = h
         # remat keeps a sublayer's result where the backward reads it, not the
         # matmul that made it: the mixer's under either placement (a norm on
@@ -728,77 +743,15 @@ class Block(nn.Module):
         h = keep(h, "mixer_out")
         if not pre:
             h = norm(h)
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
+        if dropout_rate:
+            h = nn.Dropout(dropout_rate, deterministic=not train)(h)
         x = x + h
-        if self.ffn_type == "moe":
-            from hops_tpu.models.moe import MoEMLP
-
-            # the module keeps its name in the parameter tree ("moe"), its
-            # device ops carry the vocabulary's ``mlp`` scope (as MoEBlock)
-            with jax.named_scope(SCOPE_MLP):
-                h = MoEMLP(**dict(self.moe_options), dtype=self.dtype, name="moe")(norm(x) if pre else x)
-        else:
-            h = MLP(
-                dtype=self.dtype,
-                tp_axis=self.tp_axis,
-                tp_shards=self.tp_shards,
-                hidden=self.mlp_hidden,
-                name="mlp",
-            )(norm(x) if pre else x)
+        h = FFNS[spec.ffn](spec, self.shared)(norm(x) if pre else x)
         if not pre:
             h = norm(keep(h, "mlp_out"))
-        if self.dropout_rate:
-            h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
-        return (x + h, handed_on) if self.hands_on else x + h
-
-    def _attention(self):
-        cross, hands_on_kv = self.layer_type == "cross_attention", self.hands_on == "kv"
-        if self.attention_form == "differential":
-            from hops_tpu.models.differential_attention import DifferentialAttention
-
-            if self.rope_base is not None or self.qk_norm or self.tp_shards > 1:
-                raise NotImplementedError(
-                    "differential attention is built without rotary, QK-norm or tensor parallelism")
-            return DifferentialAttention(
-                self.num_heads,
-                num_kv_heads=self.num_kv_heads,
-                layer_index=self.layer_index,
-                window=self.window,
-                use_bias=self.use_bias,
-                cross=cross,
-                hands_on_kv=hands_on_kv,
-                attention_impl=self.attention_impl,
-                norm_eps=self.norm_eps,
-                dtype=self.dtype,
-                name="attn",
-            )
-        if self.attention_form != "softmax" or cross or hands_on_kv or self.use_bias:
-            raise ValueError(
-                f"attention_form {self.attention_form!r}: biases, cross attention and handing on K/V "
-                "are built for the differential form only (attention_form is softmax | differential)")
-        return Attention(
-            self.num_heads,
-            dtype=self.dtype,
-            attention_impl=self.attention_impl,
-            mesh=self.mesh,
-            seq_axis=self.seq_axis,
-            batch_axis=self.batch_axis,
-            max_decode_len=self.max_decode_len,
-            tp_axis=self.tp_axis,
-            tp_shards=self.tp_shards,
-            kv_cache_dtype=self.kv_cache_dtype,
-            num_kv_heads=self.num_kv_heads,
-            window=self.window,
-            ragged_decode=self.ragged_decode,
-            paged_decode=self.paged_decode,
-            kv_page_size=self.kv_page_size,
-            kv_pool_blocks=self.kv_pool_blocks,
-            qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps,
-            rope_base=self.rope_base,
-            name="attn",
-        )
+        if dropout_rate:
+            h = nn.Dropout(dropout_rate, deterministic=not train)(h)
+        return (x + h, handed_on) if spec.hands_on else x + h
 
 
 class MTPModule(nn.Module):
@@ -826,7 +779,15 @@ class MTPModule(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    """GPT-style causal LM over token ids ``(batch, seq)`` → logits."""
+    """GPT-style causal LM over token ids ``(batch, seq)`` → logits.
+
+    The flat fields are the public surface (configuration files spell them,
+    serving re-configures with ``clone()``). Below it a layer has one
+    description: :meth:`layer_specs` resolves the fields to one ``LayerSpec``
+    a layer, once and without ``init``, with every check of how fields may
+    combine; :meth:`shared_spec` gathers what all layers share; ``Block``
+    builds mixer and feed-forward from the two through ``MIXERS`` and
+    ``FFNS``, and ``parallel/pipeline.py`` builds its stages from the same."""
 
     vocab_size: int = 32000
     d_model: int = 512
@@ -839,25 +800,29 @@ class TransformerLM(nn.Module):
     batch_axis: Any = None
     dropout_rate: float = 0.0
     remat: bool = False
-    moe_every: int = 0  # >0: every k-th block routes through experts
+    # ``LayerSpec.ffn``: every ``moe_every``-th layer routes through experts
+    # (short for the ``ffn_types`` with "moe" there; give one of the two).
+    # ``LayerSpec.ffn_options`` of a routed layer, ``moe.MoEMLP``'s arguments:
+    # the experts, the choices a token makes, one expert's SwiGLU width (None:
+    # d_model x 4) and whether the chosen experts' probabilities are
+    # renormalised (OLMoE publishes 1024 and False), with the ``moe_*`` below.
+    moe_every: int = 0
     num_experts: int = 8
     moe_top_k: int = 2
-    # One expert's SwiGLU width (None: d_model x 4, MoEMLP's default)
-    # and whether the chosen experts' probabilities are renormalised
-    # (OLMoE publishes 1024 and False).
     moe_expert_hidden: int | None = None
     moe_norm_topk_prob: bool = True
-    # Layer options that differ between published models: QK-norm over
-    # the whole q/k projections, every RMSNorm's epsilon, the rotary base.
+    # ``mixer_options`` of the attention kinds: QK-norm over the whole q/k
+    # projections and the rotary base (None: no rotary). ``norm_eps`` is every
+    # RMSNorm's epsilon (``LayerSpec.norm_eps``, the final norm, the mixers').
     qk_norm: bool = False
     norm_eps: float = 1e-6
-    rope_base: float | None = 10000.0  # None: no rotary
-    # A hybrid's layers, one kind a layer (``LAYER_TYPES``; None: every
-    # layer softmax attention), a linear-attention layer's four sizes
-    # (heads, key and value head widths, the causal convolution's taps;
-    # the published ``linear_*`` keys), where the norms sit ("pre" |
-    # "post_sublayer", see Block) and the dense feed-forward's width
-    # (None: d_model x 4 x 2/3).
+    rope_base: float | None = 10000.0
+    # ``LayerSpec.mixer``, one kind a layer (``LAYER_TYPES``; None: every
+    # layer softmax attention); ``mixer_options`` of "linear_attention" (heads,
+    # key and value head widths, the causal convolution's taps; the published
+    # ``linear_*`` keys); ``LayerSpec.norm_placement`` ("pre" |
+    # "post_sublayer") and the dense ``ffn_options`` (the feed-forward's
+    # width; None: d_model x 4 x 2/3).
     layer_types: tuple[str, ...] | None = None
     linear_num_heads: int | None = None
     linear_key_dim: int | None = None
@@ -871,28 +836,27 @@ class TransformerLM(nn.Module):
     # reaches those layers only once ``layer_types`` is given: every other
     # attention layer then sees every key), "mamba", "gated_memory"
     # and "cross_attention", which read what the nearest "mamba" /
-    # "full_attention" layer before them hands on; the norms' kind ("rms" |
-    # "layer": LayerNorm with bias, the final norm too), biases on the
-    # attention projections, the attention form ("softmax" |
-    # "differential") and whether the logits read the embedding matrix
-    # (no ``unembed`` parameter then).
+    # "full_attention" layer before them hands on (``LayerSpec.hands_on``);
+    # ``LayerSpec.norm_kind`` ("rms" | "layer": LayerNorm with bias, the final
+    # norm too); ``mixer_options`` of the attention kinds: biases on the
+    # projections and the form ("softmax" | "differential"). Not a layer's:
+    # whether the logits read the embedding matrix (no ``unembed`` then).
     norm_kind: str = "rms"
     use_bias: bool = False
     attention_form: str = "softmax"
     tie_embeddings: bool = False
     # A linear-attention / latent-attention hybrid with routed feed-forwards
     # (Ling-3.0-flash): ``layer_types`` may also name "kimi_delta_attention"
-    # (sizes: the ``linear_*`` keys; ``linear_lower_bound`` of its log-decay)
-    # and "latent_attention" (``latent_*``: the key/value rank, a head's
-    # widths without and with position, and of its values); ``ffn_types``
-    # says per layer "dense" | "moe" (None: every layer dense), independently
-    # of the mixer; a "moe" layer is ``moe.MoEMLP`` with ``num_experts``,
-    # ``moe_top_k``, ``moe_expert_hidden``, ``moe_norm_topk_prob`` and the
-    # ``moe_*`` keys below (its sigmoid router; ``moe_held_experts``: this
+    # (``mixer_options``: the ``linear_*`` keys; ``linear_lower_bound`` of its
+    # log-decay) and "latent_attention" (``latent_*``: the key/value rank, a
+    # head's widths without and with position, and of its values);
+    # ``LayerSpec.ffn`` per layer, "dense" | "moe" (None: ``moe_every``'s, or
+    # every layer dense), independently of the mixer; the rest of a routed
+    # layer's ``ffn_options`` (its sigmoid router; ``moe_held_experts``: this
     # chip's (first, count) of the experts). ``mtp_layers`` = 1 adds a
     # multi-token-prediction module (``MTPModule``: a block whose mixer is
-    # ``mtp_layer_type``, with a routed feed-forward if any layer has one),
-    # run when the caller hands ``mtp_tokens``.
+    # ``mtp_layer_type``, with a routed feed-forward if any layer has one; the
+    # last of ``layer_specs()``), run when the caller hands ``mtp_tokens``.
     ffn_types: tuple[str, ...] | None = None
     linear_lower_bound: float = -5.0
     latent_kv_rank: int | None = None
@@ -909,6 +873,9 @@ class TransformerLM(nn.Module):
     moe_held_experts: tuple[int, int] | None = None
     mtp_layers: int = 0
     mtp_layer_type: str = "full_attention"
+    # ``SharedSpec`` (with the fields of the same names above): the decode
+    # cache and tensor parallelism. ``num_kv_heads`` and ``window`` are
+    # ``mixer_options`` of the attention kinds.
     max_decode_len: int = 2048
     kv_cache_dtype: str | None = None  # "int8": quantized decode cache
     num_kv_heads: int | None = None  # GQA: shrink the decode cache
@@ -928,6 +895,96 @@ class TransformerLM(nn.Module):
     tp_axis: str | None = None
     tp_shards: int = 1
 
+    @nn.nowrap
+    def shared_spec(self) -> SharedSpec:
+        """What every layer shares: this model's fields of ``SharedSpec``'s names."""
+        return SharedSpec(**{f.name: getattr(self, f.name) for f in dataclasses.fields(SharedSpec)
+                             if f.name in self.__dataclass_fields__})
+
+    @nn.nowrap
+    def layer_specs(self) -> tuple[LayerSpec, ...]:
+        """The ``num_layers`` layers' descriptions and, with ``mtp_layers``,
+        the multi-token-prediction block's after them. Raises where the
+        fields name no model that is built; needs no ``init``."""
+        n = self.num_layers
+        layer_types = self.layer_types or ("full_attention",) * n
+        if len(layer_types) != n:
+            raise ValueError(f"layer_types names {len(layer_types)} layers, num_layers is {n}")
+        if self.moe_every and self.ffn_types:
+            raise NotImplementedError(
+                "moe_every is short for the ffn_types with 'moe' at every moe_every-th layer: give one of the two")
+        ffn_types = self.ffn_types or tuple(
+            "moe" if self.moe_every and (i + 1) % self.moe_every == 0 else "dense" for i in range(n))
+        if len(ffn_types) != n:
+            raise ValueError(f"ffn_types names {len(ffn_types)} layers, num_layers is {n}")
+        if self.mtp_layers not in (0, 1):
+            raise NotImplementedError("one multi-token-prediction module is built (mtp_layers 0 | 1)")
+        mixers = layer_types + (self.mtp_layer_type,) * self.mtp_layers
+        ffns = ffn_types + ("moe" if "moe" in ffn_types else "dense",) * self.mtp_layers
+        for what, kinds, given in (("layer_type", LAYER_TYPES, mixers), ("ffn_type", FFN_TYPES, ffns),
+                                   ("norm_kind", tuple(NORMS), (self.norm_kind,))):
+            for kind in given:
+                if kind not in kinds:
+                    raise ValueError(f"unknown {what} {kind!r} (one of {kinds})")
+        if self.norm_placement not in ("pre", "post_sublayer"):
+            raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.tp_shards > 1 and "moe" in ffns:
+            raise NotImplementedError(
+                "tensor parallelism composes with dense TransformerLMs; "
+                "shard MoE models over an expert axis instead "
+                "(parallel/pipeline.py expert_axis, models/moe.py)"
+            )
+        if self.paged_decode and "moe" in ffns:
+            raise NotImplementedError(
+                "paged_decode serves dense TransformerLMs; MoE blocks "
+                "keep the dense ragged cache"
+            )
+        # who hands what on: a reader takes the value of the nearest writer
+        # of its kind before it, and only such writers return one
+        hands_on: dict[int, str] = {}
+        for i, kind in enumerate(layer_types):
+            if kind in SHARED_VALUES:
+                what, writer = SHARED_VALUES[kind]
+                before = [j for j in range(i) if layer_types[j] == writer]
+                if not before:
+                    raise ValueError(
+                        f"layer {i} ({kind}) reads the {what} of a {writer} layer before it: "
+                        f"layer_types has none ({layer_types})")
+                hands_on[before[-1]] = what
+
+        linear = dict(num_heads=self.linear_num_heads or self.num_heads, key_dim=self.linear_key_dim,
+                      value_dim=self.linear_value_dim, conv_size=self.linear_conv_size)
+
+        def mixer_options(i, kind):
+            if kind == "linear_attention":
+                return _pairs(**linear, allow_neg_eigval=self.linear_allow_neg_eigval)
+            if kind == "kimi_delta_attention":
+                return _pairs(**linear, lower_bound=self.linear_lower_bound)
+            if kind == "latent_attention":
+                return _pairs(kv_rank=self.latent_kv_rank, nope_dim=self.latent_nope_dim,
+                              rope_dim=self.latent_rope_dim, value_dim=self.latent_value_dim,
+                              rope_base=self.rope_base)
+            if kind in ("mamba", "gated_memory"):
+                return ()
+            # the attention kinds; a differential map's lambda_0 follows the layer's index
+            return _pairs(
+                form=self.attention_form, use_bias=self.use_bias, num_kv_heads=self.num_kv_heads,
+                qk_norm=self.qk_norm, rope_base=self.rope_base,
+                window=self.window if self.layer_types is None or kind == "sliding_attention" else None,
+                **({"layer_index": i} if self.attention_form == "differential" else {}))
+
+        ffn_options = {"dense": _pairs(hidden=self.mlp_hidden), "moe": _pairs(
+            num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,
+            norm_topk_prob=self.moe_norm_topk_prob, scoring=self.moe_scoring, n_group=self.moe_n_group,
+            topk_group=self.moe_topk_group, routed_scale=self.moe_routed_scale,
+            selection_bias=self.moe_selection_bias, seq_aux=self.moe_seq_aux,
+            shared_hidden=self.moe_shared_hidden,
+            held_experts=None if self.moe_held_experts is None else tuple(self.moe_held_experts))}
+        return tuple(
+            LayerSpec(i, mixer, mixer_options(i, mixer), ffn, ffn_options[ffn], self.norm_kind,
+                      self.norm_placement, self.norm_eps, hands_on.get(i))
+            for i, (mixer, ffn) in enumerate(zip(mixers, ffns)))
+
     @nn.compact
     def __call__(
         self,
@@ -937,160 +994,33 @@ class TransformerLM(nn.Module):
         return_hidden: bool = False,
         mtp_tokens=None,
     ):
-        from hops_tpu.models.moe import MoEBlock
-
-        if self.tp_shards > 1 and self.moe_every:
-            raise NotImplementedError(
-                "tensor parallelism composes with dense TransformerLMs; "
-                "shard MoE models over an expert axis instead "
-                "(parallel/pipeline.py expert_axis, models/moe.py)"
-            )
-        if self.paged_decode and self.moe_every:
-            raise NotImplementedError(
-                "paged_decode serves dense TransformerLMs; MoE blocks "
-                "keep the dense ragged cache"
-            )
-        layer_types = self.layer_types or ("full_attention",) * self.num_layers
-        if len(layer_types) != self.num_layers:
-            raise ValueError(
-                f"layer_types names {len(layer_types)} layers, num_layers is {self.num_layers}"
-            )
-        ffn_types = self.ffn_types or ("dense",) * self.num_layers
-        if len(ffn_types) != self.num_layers:
-            raise ValueError(f"ffn_types names {len(ffn_types)} layers, num_layers is {self.num_layers}")
-        if self.mtp_layers not in (0, 1):
-            raise NotImplementedError("one multi-token-prediction module is built (mtp_layers 0 | 1)")
-        if (self.moe_every or self.tp_shards > 1 or self.paged_decode) and "moe" in ffn_types:
-            raise NotImplementedError(
-                "ffn_types routes feed-forwards inside Block; moe_every, tensor parallelism and "
-                "paged_decode are built without it")
-        moe_options = tuple(sorted(dict(
-            num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,
-            norm_topk_prob=self.moe_norm_topk_prob, scoring=self.moe_scoring, n_group=self.moe_n_group,
-            topk_group=self.moe_topk_group, routed_scale=self.moe_routed_scale,
-            selection_bias=self.moe_selection_bias, seq_aux=self.moe_seq_aux,
-            shared_hidden=self.moe_shared_hidden,
-            held_experts=None if self.moe_held_experts is None else tuple(self.moe_held_experts),
-        ).items()))
-        latent_options = ()
-        if "latent_attention" in layer_types + (self.mtp_layer_type,) * self.mtp_layers:
-            latent_options = tuple(sorted(dict(
-                kv_rank=self.latent_kv_rank, nope_dim=self.latent_nope_dim, rope_dim=self.latent_rope_dim,
-                value_dim=self.latent_value_dim, rope_base=self.rope_base).items()))
-        hybrid = dict(
-            moe_options=moe_options if "moe" in ffn_types else (),
-            latent_options=latent_options,
-            linear_lower_bound=self.linear_lower_bound,
-            norm_placement=self.norm_placement,
-            mlp_hidden=self.mlp_hidden,
-            linear_num_heads=self.linear_num_heads,
-            linear_key_dim=self.linear_key_dim,
-            linear_value_dim=self.linear_value_dim,
-            linear_conv_size=self.linear_conv_size,
-            linear_allow_neg_eigval=self.linear_allow_neg_eigval,
-            norm_kind=self.norm_kind,
-            use_bias=self.use_bias,
-            attention_form=self.attention_form,
-        )
-        if self.moe_every and (self.layer_types or self.norm_placement != "pre" or self.mlp_hidden
-                               or self.norm_kind != "rms" or self.use_bias or self.attention_form != "softmax"):
-            raise NotImplementedError(
-                "layer_types, norm_placement, mlp_hidden, norm_kind, use_bias and attention_form "
-                "shape dense blocks; a routed block (moe_every) is pre-norm softmax attention"
-            )
-        # who hands what on: a reader takes the value of the nearest writer
-        # of its kind before it, and only such writers return one
-        source: dict[int, int] = {}
-        for i, kind in enumerate(layer_types):
-            if kind in SHARED_VALUES:
-                what, writer = SHARED_VALUES[kind]
-                before = [j for j in range(i) if layer_types[j] == writer]
-                if not before:
-                    raise ValueError(
-                        f"layer {i} ({kind}) reads the {what} of a {writer} layer before it: "
-                        f"layer_types has none ({layer_types})")
-                source[i] = before[-1]
-        hands_on = {j: SHARED_VALUES[layer_types[i]][0] for i, j in source.items()}
-        handed_on: dict[int, Any] = {}
+        specs, shared = self.layer_specs(), self.shared_spec()
         embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
         x = embed(tokens)
-        block_cls, moe_cls = Block, MoEBlock
+        block_cls = Block
         if self.remat:
             # a block's input and the values named in REMAT_KEEPS are held,
             # the rest of its forward runs again in the backward pass
             kept = jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)
-            block_cls, moe_cls = (nn.remat(cls, static_argnums=(2, 3), policy=kept) for cls in (Block, MoEBlock))
-        layer_options = dict(
-            qk_norm=self.qk_norm, norm_eps=self.norm_eps, rope_base=self.rope_base
-        )
-        def block(i, layer_type, ffn_type, **more):
-            """The constructor of layer ``i``'s block, but for its name."""
-            return functools.partial(
-                block_cls,
-                self.num_heads,
-                dtype=self.dtype,
-                attention_impl=self.attention_impl,
-                mesh=self.mesh,
-                seq_axis=self.seq_axis,
-                batch_axis=self.batch_axis,
-                dropout_rate=self.dropout_rate,
-                max_decode_len=self.max_decode_len,
-                tp_axis=self.tp_axis,
-                tp_shards=self.tp_shards,
-                kv_cache_dtype=self.kv_cache_dtype,
-                num_kv_heads=self.num_kv_heads,
-                window=self.window if self.layer_types is None or layer_type == "sliding_attention" else None,
-                ragged_decode=self.ragged_decode,
-                paged_decode=self.paged_decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_blocks=self.kv_pool_blocks,
-                **layer_options,
-                layer_type=layer_type,
-                ffn_type=ffn_type,
-                layer_index=i,
-                **more,
-                **hybrid,
-            )
-
-        for i in range(self.num_layers):
-            _m_layer_kinds.inc(kind=layer_types[i])
-            if self.moe_every and (i + 1) % self.moe_every == 0:
-                x = moe_cls(
-                    self.num_heads,
-                    num_experts=self.num_experts,
-                    top_k=self.moe_top_k,
-                    expert_hidden=self.moe_expert_hidden,
-                    norm_topk_prob=self.moe_norm_topk_prob,
-                    dtype=self.dtype,
-                    attention_impl=self.attention_impl,
-                    mesh=self.mesh,
-                    seq_axis=self.seq_axis,
-                    batch_axis=self.batch_axis,
-                    dropout_rate=self.dropout_rate,
-                    max_decode_len=self.max_decode_len,
-                    kv_cache_dtype=self.kv_cache_dtype,
-                    num_kv_heads=self.num_kv_heads,
-                    window=self.window,
-                    ragged_decode=self.ragged_decode,
-                    **layer_options,
-                    name=f"block_{i}",
-                )(x, train, decode)
-                continue
-            shared = ()
-            if i in source:
-                _m_shared_reads.inc(what=hands_on[source[i]])
-                shared = (handed_on[source[i]],)
-            x = block(i, layer_types[i], ffn_types[i], hands_on=hands_on.get(i))(name=f"block_{i}")(
-                x, train, decode, *shared)
-            if i in hands_on:
-                x, handed_on[i] = x
+            block_cls = nn.remat(Block, static_argnums=(2, 3), policy=kept)
+        handed_on: dict[str, Any] = {}  # the newest value of each kind: a reader's nearest writer's
+        for spec in specs[: self.num_layers]:
+            _m_layer_kinds.inc(kind=spec.mixer)
+            value = ()
+            if spec.mixer in SHARED_VALUES:
+                what = SHARED_VALUES[spec.mixer][0]
+                _m_shared_reads.inc(what=what)
+                value = (handed_on[what],)
+            x = block_cls(spec, shared, name=f"block_{spec.index}")(x, train, decode, *value)
+            if spec.hands_on:
+                x, handed_on[spec.hands_on] = x
         mtp_hidden = None
         if self.mtp_layers and not decode and (mtp_tokens is not None or self.is_initializing()):
             # the module predicts the token after the next from the last layer's
             # output (before the final norm) and the next token's embedding
             _m_layer_kinds.inc(kind=f"mtp_{self.mtp_layer_type}")
-            mtp_block = block(self.num_layers, self.mtp_layer_type, "moe" if "moe" in ffn_types else "dense")
-            mtp_hidden = MTPModule(mtp_block, self.norm_eps, dtype=self.dtype, name=SCOPE_MTP)(
+            mtp_hidden = MTPModule(functools.partial(block_cls, specs[-1], shared), self.norm_eps,
+                                   dtype=self.dtype, name=SCOPE_MTP)(
                 x, embed(tokens if mtp_tokens is None else mtp_tokens), train)
         x = NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype, name="final_norm")(x)
         if return_hidden:

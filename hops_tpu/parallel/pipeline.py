@@ -19,6 +19,7 @@ exceeds per-chip HBM across many hosts.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable
 
 import jax
@@ -209,6 +210,33 @@ def pipeline_apply(
     )(stacked_params, ingest_params, emit_params, x)
 
 
+def _lm_stage_blocks(model: Any, **overrides: Any) -> tuple[Any, Any, int]:
+    """``(dense, routed, g)``: the one or two ``Block``s every stage of a
+    pipelined ``model`` scans over, built from ``model.layer_specs()`` with
+    the shared options in ``overrides`` replaced (and no dropout), and the
+    period ``g`` of its routed layers (``g - 1`` dense + 1 routed; 0: none,
+    ``routed`` is None). Stages are one program over stacked parameters, so
+    the layers must be alike: a model whose layers differ in anything but a
+    periodic dense / routed pattern, or hand values on, is refused."""
+    from hops_tpu.models.transformer import Block
+
+    specs = [dataclasses.replace(spec, index=0) for spec in model.layer_specs()[: model.num_layers]]
+    routed = [spec.ffn == "moe" for spec in specs]
+    g = routed.index(True) + 1 if any(routed) else 0
+    if g and routed != ([False] * (g - 1) + [True]) * (len(specs) // g):
+        raise NotImplementedError(
+            f"a pipelined model's routed layers come at a fixed period (moe_every); ffn_types "
+            f"{tuple(spec.ffn for spec in specs)} has none")
+    alike = {spec.ffn: spec for spec in specs}
+    if any(spec.hands_on or spec != alike[spec.ffn] for spec in specs):
+        raise NotImplementedError(
+            "a pipelined model's dense layers are one description and its routed layers one "
+            "(layer_specs(), but for the index), and no layer hands a value on: the stages scan "
+            f"stacked parameters; got mixers {tuple(spec.mixer for spec in specs)}")
+    shared = dataclasses.replace(model.shared_spec(), dropout_rate=0.0, **overrides)
+    return (*(Block(alike[ffn], shared) if ffn in alike else None for ffn in ("dense", "moe")), g)
+
+
 def pipelined_lm_apply(
     model: Any,
     params: Any,
@@ -231,8 +259,11 @@ def pipelined_lm_apply(
     ``(S, K, ...)`` — stage-sharded outside, ``lax.scan`` inside).
     Logits match ``model.apply`` exactly (tests/test_pipeline.py).
 
-    MoE models (``moe_every > 0``) pipeline too: layers chunk into
-    uniform (moe_every-1 dense + 1 MoE) groups. Routing is dropless and
+    The stages' blocks are built from ``model.layer_specs()``
+    (:func:`_lm_stage_blocks`, which says what the layers must have in
+    common). MoE models pipeline too where the routed layers come at a
+    period (``moe_every``, or the ``ffn_types`` it is short for): layers
+    chunk into uniform (g-1 dense + 1 MoE) groups. Routing is dropless and
     per token, so a microbatch's outputs are the whole batch's; the
     load-balancing loss is the mean over microbatches of a statistic of
     each microbatch (equal to the whole batch's only when every token
@@ -266,75 +297,47 @@ def pipelined_lm_apply(
     microbatches, summed over layers/stages) — feed it into the train
     loss exactly like ``make_lm_train_step`` does for the dense path.
     """
-    from hops_tpu.models.moe import EXPERT_WEIGHTS, MoEBlock, sum_sown_losses
-    from hops_tpu.models.transformer import Block, RMSNorm
+    from hops_tpu.models.moe import EXPERT_WEIGHTS, sum_sown_losses
+    from hops_tpu.models.transformer import NORMS
     from flax import linen as nn
 
-    if seq_axis and model.moe_every:
+    block, moe_block, g = _lm_stage_blocks(
+        model,
+        attention_impl="ring_local" if seq_axis else model.attention_impl,
+        mesh=mesh if seq_axis else None,
+        seq_axis=seq_axis or "seq",
+        batch_axis=batch_axis,
+        tp_axis=tp_axis,
+        tp_shards=mesh.shape[tp_axis] if tp_axis else 1,
+        expert_axis=expert_axis,
+        expert_shards=mesh.shape[expert_axis] if expert_axis else 1,
+    )
+    if seq_axis and g:
         raise NotImplementedError(
             "seq_axis inside pp is supported for dense LMs; MoE models "
             "compose pp with expert_axis instead"
         )
-    if expert_axis and not model.moe_every:
+    if expert_axis and not g:
         raise ValueError("expert_axis requires a MoE model (moe_every > 0)")
-    if tp_axis and model.moe_every:
+    if tp_axis and g:
         raise NotImplementedError(
             "tp_axis inside pp is supported for dense LMs; MoE models "
             "compose pp with expert_axis instead"
         )
 
     n_stages = mesh.shape[axis]
-    layer_options = dict(
-        qk_norm=model.qk_norm, norm_eps=model.norm_eps, rope_base=model.rope_base
-    )
-    block = Block(
-        model.num_heads,
-        dtype=model.dtype,
-        attention_impl="ring_local" if seq_axis else model.attention_impl,
-        mesh=mesh if seq_axis else None,
-        seq_axis=seq_axis or "seq",
-        batch_axis=batch_axis,
-        dropout_rate=0.0,
-        tp_axis=tp_axis,
-        tp_shards=mesh.shape[tp_axis] if tp_axis else 1,
-        num_kv_heads=model.num_kv_heads,
-        kv_cache_dtype=model.kv_cache_dtype,
-        window=model.window,
-        **layer_options,
-    )
     embed = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    norm = RMSNorm(model.norm_eps, dtype=model.dtype)
+    norm = NORMS[model.norm_kind](model.norm_eps, dtype=model.dtype)
     unembed = nn.Dense(model.vocab_size, dtype=model.dtype, use_bias=False)
 
-    if model.moe_every:
-        # MoE layers sit at positions g-1, 2g-1, ... (g = moe_every), so
-        # g consecutive layers form a uniform group tree of (g-1 dense +
+    if g:
+        # MoE layers sit at positions g-1, 2g-1, ..., so g consecutive
+        # layers form a uniform group tree of (g-1 dense +
         # 1 MoE) params: groups stack/scan exactly like layers do in the
         # dense path. Router/expert shapes repeat per MoE layer, so the
         # group trees all share structure. Load-balancing aux losses are
         # collected per group via mutable apply and accumulated through
         # the ring (stage_aux); return_aux exposes them to the caller.
-        g = model.moe_every
-        if model.num_layers % g:
-            raise ValueError(
-                f"{model.num_layers} layers not divisible by moe_every={g}")
-        moe_block = MoEBlock(
-            model.num_heads,
-            num_experts=model.num_experts,
-            top_k=model.moe_top_k,
-            expert_hidden=model.moe_expert_hidden,
-            norm_topk_prob=model.moe_norm_topk_prob,
-            dtype=model.dtype,
-            attention_impl=model.attention_impl,
-            mesh=None,
-            dropout_rate=0.0,
-            expert_axis=expert_axis,
-            expert_shards=mesh.shape[expert_axis] if expert_axis else 1,
-            num_kv_heads=model.num_kv_heads,
-            kv_cache_dtype=model.kv_cache_dtype,
-            window=model.window,
-            **layer_options,
-        )
         groups = []
         for start in range(0, model.num_layers, g):
             group = {"moe": params[f"block_{start + g - 1}"]}
@@ -462,10 +465,11 @@ def _scheduled_lm_loss_and_grads(
     import optax
     from flax import linen as nn
 
-    from hops_tpu.models.transformer import Block, RMSNorm
+    from hops_tpu.models.transformer import NORMS
 
     S, v, V, m = sched.n_stages, sched.v, sched.n_virtual, sched.num_microbatches
-    if model.moe_every:
+    block, _, routed_period = _lm_stage_blocks(model)
+    if routed_period:
         raise NotImplementedError(
             "explicit pipeline schedules support dense TransformerLMs; "
             "MoE pipelines use the autodiff ring (schedule=None)")
@@ -475,15 +479,8 @@ def _scheduled_lm_loss_and_grads(
             f"stages ({S} stages x {v} chunks)")
     K = model.num_layers // V
 
-    block = Block(
-        model.num_heads, dtype=model.dtype,
-        attention_impl=model.attention_impl, dropout_rate=0.0,
-        num_kv_heads=model.num_kv_heads,
-        kv_cache_dtype=model.kv_cache_dtype, window=model.window,
-        qk_norm=model.qk_norm, norm_eps=model.norm_eps, rope_base=model.rope_base,
-    )
     embed = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    norm = RMSNorm(model.norm_eps, dtype=model.dtype)
+    norm = NORMS[model.norm_kind](model.norm_eps, dtype=model.dtype)
     unembed = nn.Dense(model.vocab_size, dtype=model.dtype, use_bias=False)
 
     def stage_fn(stage_params, h):

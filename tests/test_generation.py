@@ -83,7 +83,7 @@ def test_generate_rejects_zero_new_tokens():
 
 def test_moe_blocks_inherit_max_decode_len():
     """MoE layers' KV caches must size to the model's max_decode_len, not
-    the MoEBlock default — otherwise decode past 2048 silently clamps."""
+    a default of their own — otherwise decode past 2048 silently clamps."""
     model = TransformerLM(**{**TINY, "moe_every": 1, "num_experts": 2, "moe_top_k": 1})
     tokens = jnp.zeros((1, 8), jnp.int32)
     variables = model.init(jax.random.PRNGKey(0), tokens, decode=True)
@@ -396,7 +396,7 @@ def test_windowed_moe_decode_matches_full_forward():
     """Advisor r3 (medium): window must apply in MoE layers too — the
     decode path and the full forward agree for a windowed MoE model,
     and the window genuinely changes MoE-layer attention."""
-    # moe_every=1: EVERY attention layer sits in a MoEBlock, so the
+    # moe_every=1: EVERY attention layer sits in a routed block, so the
     # windowed-vs-unwindowed comparison below cannot be satisfied by a
     # dense layer's (already correct) windowing.
     model = TransformerLM(**{

@@ -431,12 +431,14 @@ def test_without_mtp_tokens_the_model_is_its_own_next_token_model(tiny):
 def test_kinds_are_checked():
     assert {KDA, MLA} <= set(LAYER_TYPES) and FFN_TYPES == ("dense", "moe")
     tokens = jnp.zeros((1, 8), jnp.int32)
-    with pytest.raises(ValueError, match="ffn_types names 2 layers"):
-        TransformerLM(**{**TINY, "ffn_types": ("dense", "moe")}).init(jax.random.PRNGKey(0), tokens)
+    with pytest.raises(ValueError, match="ffn_types names 2 layers"):  # these three from layer_specs(), without init
+        TransformerLM(**{**TINY, "ffn_types": ("dense", "moe")}).layer_specs()
     with pytest.raises(ValueError, match="unknown ffn_type"):
-        TransformerLM(**{**TINY, "ffn_types": ("dense", "moe", "sparse")}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "ffn_types": ("dense", "moe", "sparse")}).layer_specs()
     with pytest.raises(NotImplementedError, match="one multi-token-prediction module"):
-        TransformerLM(**{**TINY, "mtp_layers": 2}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "mtp_layers": 2}).layer_specs()
+    with pytest.raises(NotImplementedError, match="moe_every is short for the ffn_types"):  # one thing said twice
+        TransformerLM(**{**TINY, "moe_every": 2}).layer_specs()
     with pytest.raises(ValueError, match="outside the 16 experts"):
         TransformerLM(**{**TINY, "moe_held_experts": (14, 4)}).init(jax.random.PRNGKey(0), tokens)
     with pytest.raises(ValueError, match="lower_bound"):

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from hops_tpu.models.moe import MoEBlock, MoEMLP, expert_specs
+from hops_tpu.models.moe import MoEMLP, expert_specs
 from hops_tpu.parallel import mesh as mesh_lib
 
 TINY = dict(num_experts=4, top_k=2, dtype=jnp.float32)
@@ -72,9 +72,16 @@ def test_expert_parallel_placement_and_step():
 
 
 def test_moe_block_in_transformer_shape():
+    from hops_tpu.models.transformer import Block, TransformerLM
+
     x = _x(b=2, s=32, d=32)
-    block = MoEBlock(num_heads=4, num_experts=4, dtype=jnp.float32, attention_impl="reference")
+    model = TransformerLM(num_heads=4, num_layers=1, moe_every=1, num_experts=4, dtype=jnp.float32,
+                          attention_impl="reference")
+    (spec,) = model.layer_specs()
+    assert spec.ffn == "moe" and dict(spec.ffn_options)["num_experts"] == 4
+    block = Block(spec, model.shared_spec())
     variables = block.init(jax.random.PRNGKey(0), x)
+    assert set(variables["params"]) == {"attn", "RMSNorm_0", "RMSNorm_1", "moe"}
     out = block.apply(variables, x)
     assert out.shape == x.shape
 

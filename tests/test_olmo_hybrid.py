@@ -146,15 +146,19 @@ def test_decoding_a_linear_layer_is_refused(tiny):
 
 
 def test_layer_types_are_checked():
-    tokens = jnp.zeros((1, 8), jnp.int32)
+    """The refusals come from ``layer_specs()``: no ``init`` is needed to see them."""
     with pytest.raises(ValueError, match="layer_types names 4 layers"):
-        TransformerLM(**{**TINY, "num_layers": 3}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "num_layers": 3}).layer_specs()
     with pytest.raises(ValueError, match="unknown layer_type"):
-        TransformerLM(**{**TINY, "layer_types": ("hyena",) * 4}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "layer_types": ("hyena",) * 4}).layer_specs()
     with pytest.raises(ValueError, match="unknown norm_placement"):
-        TransformerLM(**{**TINY, "norm_placement": "sandwich"}).init(jax.random.PRNGKey(0), tokens)
-    with pytest.raises(NotImplementedError, match="a routed block"):
-        TransformerLM(**{**TINY, "moe_every": 2}).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**{**TINY, "norm_placement": "sandwich"}).layer_specs()
+    # a routed feed-forward goes with any mixer and either norm placement: one block class
+    routed = TransformerLM(**{**TINY, "moe_every": 2})
+    assert [(spec.mixer, spec.ffn, spec.norm_placement) for spec in routed.layer_specs()] == [
+        (kind, ffn, "post_sublayer") for kind, ffn in zip(TINY["layer_types"], ("dense", "moe") * 2)]
+    params = jax.eval_shape(routed.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    assert "moe" in params["block_1"] and "q_conv" in params["block_1"]["attn"] and "mlp" in params["block_0"]
     assert LAYER_TYPES[:2] == ("full_attention", "linear_attention")
 
 

@@ -234,23 +234,28 @@ def test_no_float32_transpose_of_the_vocabulary_matrix_in_the_step():
 
 
 def test_readers_need_their_writers():
-    tokens = jnp.zeros((1, 8), jnp.int32)
     for kinds, match in ((("gated_memory", "mamba"), "reads the memory of a mamba layer before it"),
                          (("sliding_attention", "cross_attention"), "reads the kv of a full_attention layer")):
         with pytest.raises(ValueError, match=match):
-            TransformerLM(**tiny_args(2, layer_types=kinds)).init(jax.random.PRNGKey(0), tokens)
+            TransformerLM(**tiny_args(2, layer_types=kinds)).layer_specs()
+    model = TransformerLM(**tiny_args(2, layer_types=("mamba", "gated_memory")))
+    assert [spec.hands_on for spec in model.layer_specs()] == ["memory", None]
     with pytest.raises(ValueError, match="none was handed on"):  # a block used alone checks for itself
-        Block(HEADS, layer_type="gated_memory").init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 64)))
+        Block(model.layer_specs()[1], model.shared_spec()).init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 64)))
 
 
 def test_unknown_kinds_and_norms_are_refused_with_the_list():
     tokens = jnp.zeros((1, 8), jnp.int32)
     with pytest.raises(ValueError, match="unknown layer_type 'hyena'.*sliding_attention.*mamba.*cross_attention"):
-        TransformerLM(**tiny_args(2, layer_types=("hyena", "hyena"))).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**tiny_args(2, layer_types=("hyena", "hyena"))).layer_specs()
     with pytest.raises(ValueError, match="unknown norm_kind"):
-        TransformerLM(**tiny_args(8, norm_kind="batch")).init(jax.random.PRNGKey(0), tokens)
-    with pytest.raises(NotImplementedError, match="a routed block"):
-        TransformerLM(**tiny_args(8, moe_every=2)).init(jax.random.PRNGKey(0), tokens)
+        TransformerLM(**tiny_args(8, norm_kind="batch")).layer_specs()
+    # every second feed-forward routed: the decoder-hybrid-decoder's kinds, LayerNorm and biases stay
+    routed = TransformerLM(**tiny_args(8, moe_every=2))
+    assert [spec.ffn for spec in routed.layer_specs()] == ["dense", "moe"] * 4
+    params = jax.eval_shape(routed.init, jax.random.PRNGKey(0), tokens)["params"]
+    assert set(params["block_7"]) == {"LayerNorm_0", "LayerNorm_1", "attn", "moe"} and "mlp" in params["block_6"]
+    # the two below are the attention builders', raised where the block is built
     with pytest.raises(ValueError, match="built for the differential form only"):
         TransformerLM(**tiny_args(8, attention_form="softmax")).init(jax.random.PRNGKey(0), tokens)
     with pytest.raises(NotImplementedError, match="without rotary"):
@@ -328,6 +333,21 @@ TOYS = {
                           layer_types=("linear_attention",) * 3 + ("full_attention",), linear_num_heads=4,
                           linear_key_dim=8, linear_value_dim=16, norm_placement="post_sublayer", mlp_hidden=192,
                           qk_norm=True, rope_base=None, remat=True, dtype=jnp.float32),
+    "phi4_shaped": dict(vocab_size=256, d_model=64, num_heads=8, num_kv_heads=4, num_layers=8,
+                        layer_types=("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba",
+                                     "full_attention", "gated_memory", "cross_attention"),
+                        window=24, mlp_hidden=160, norm_kind="layer", norm_eps=1e-5, use_bias=True,
+                        attention_form="differential", tie_embeddings=True, rope_base=None, remat=True,
+                        dtype=jnp.float32),
+    "ling_shaped": dict(vocab_size=256, d_model=64, num_heads=4, num_layers=3,
+                        layer_types=("kimi_delta_attention", "latent_attention", "kimi_delta_attention"),
+                        ffn_types=("dense", "moe", "moe"), linear_num_heads=4, linear_key_dim=16,
+                        linear_value_dim=16, latent_kv_rank=32, latent_nope_dim=16, latent_rope_dim=8,
+                        latent_value_dim=16, rope_base=6e6, mlp_hidden=128, num_experts=16, moe_top_k=4,
+                        moe_expert_hidden=32, moe_scoring="sigmoid", moe_n_group=4, moe_topk_group=2,
+                        moe_routed_scale=2.5, moe_selection_bias=True, moe_seq_aux=True, moe_shared_hidden=32,
+                        moe_held_experts=(4, 4), mtp_layers=1, mtp_layer_type="latent_attention", remat=True,
+                        dtype=jnp.float32),
 }
 
 
@@ -346,7 +366,9 @@ def lowered_digest(toy):
 @pytest.mark.parametrize("toy", sorted(TOYS))
 def test_defaults_keep_the_parents_lowered_step(toy):
     """``tests/data/transformer_lm_parent_lowered.json`` was written with
-    ``lowered_digest``: ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
+    ``lowered_digest``: ``phi4_shaped`` and ``ling_shaped`` (with their trees) by
+    PR 43's parent (3d29bff), before ``Block`` was rebuilt round ``LayerSpec``;
+    ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
     PR 31's parent (no ``remat``: the fields added since, and the names
     ``remat`` keeps values by, leave their lowered text as it was);
     ``hybrid_shaped`` by PR 32 on top of ebb7672, because that toy has
@@ -359,7 +381,36 @@ def test_defaults_keep_the_parents_lowered_step(toy):
     assert digest == LOWERED[toy]["sha256"]
 
 
-@pytest.mark.parametrize("toy", ["phi3_shaped", "olmoe_shaped"])
+@pytest.mark.parametrize("n", [1, 2])
+def test_moe_every_is_the_ffn_types_it_abbreviates(n, monkeypatch):
+    """One way to place a routed feed-forward: ``moe_every=n`` and ``ffn_types`` with "moe" at every
+    n-th layer are the same layers, the same parameter tree and the same lowered step."""
+    short = {**TOYS["olmoe_shaped"], "num_layers": 4, "moe_every": n}
+    spelt = {**short, "moe_every": 0, "ffn_types": tuple("moe" if (i + 1) % n == 0 else "dense" for i in range(4))}
+    assert TransformerLM(**short).layer_specs() == TransformerLM(**spelt).layer_specs()
+    trees, digests = [], []
+    for name, args in (("short", short), ("spelt", spelt)):
+        monkeypatch.setitem(TOYS, name, args)
+        variables = jax.eval_shape(TransformerLM(**args).init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        trees.append(jax.tree.map(lambda x: (x.shape, x.dtype), variables["params"]))
+        digests.append(lowered_digest(name))
+    assert trees[0] == trees[1] and sum("moe" in block for block in trees[0].values()) == 4 // n
+    assert digests[0] == digests[1]
+
+
+def test_the_models_fields_are_the_parents():
+    """The flat fields are the public surface (configuration files spell them, serving clones with them):
+    ``tests/data/transformer_lm_parent_fields.json`` was written by PR 43's parent (3d29bff), name -> repr(default),
+    in order. A layer's description (``LayerSpec``, ``SharedSpec``) changes below it."""
+    import dataclasses
+
+    want = json.loads((DATA / "transformer_lm_parent_fields.json").read_text())
+    got = {f.name: repr(f.default) for f in dataclasses.fields(TransformerLM) if f.name not in ("parent", "name")}
+    assert list(got.items()) == list(want.items())
+    assert [f.name for f in dataclasses.fields(Block) if f.name not in ("parent", "name")] == ["spec", "shared"]
+
+
+@pytest.mark.parametrize("toy", ["phi3_shaped", "olmoe_shaped", "phi4_shaped", "ling_shaped"])
 def test_defaults_keep_the_parents_tree_and_first_loss(toy):
     model = TransformerLM(**TOYS[toy])
     state = common.create_train_state(model, jax.random.PRNGKey(0), (1, 8), input_dtype=jnp.int32)

@@ -126,6 +126,41 @@ def test_pipelined_transformer_lm_matches_dense(stage_mesh):
     np.testing.assert_allclose(pp, dense, atol=1e-4, rtol=1e-4)
 
 
+def test_pipelined_lm_builds_its_stages_from_the_models_layer_specs(stage_mesh):
+    """What the stages' blocks are comes from ``model.layer_specs()``: norms after the sublayers, the
+    feed-forward's own width, QK-norm and no rotary reach the ring (the hand-written constructor calls
+    dropped the first two in silence), and so does a routed pattern spelt as ``ffn_types``."""
+    from hops_tpu.models.transformer import TransformerLM
+    from hops_tpu.parallel.pipeline import pipelined_lm_apply
+
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (8, 16), 0, 64)
+    tiny = dict(vocab_size=64, d_model=32, num_heads=4, num_layers=8, dtype=jnp.float32, attention_impl="reference",
+                norm_placement="post_sublayer", mlp_hidden=48, qk_norm=True, rope_base=None, norm_kind="layer")
+    for more in ({}, dict(ffn_types=("dense", "moe") * 4, num_experts=2, moe_top_k=2)):
+        model = TransformerLM(**tiny, **more)
+        params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+        assert params["block_0"]["mlp"]["gate"]["kernel"].shape == (32, 48)
+        np.testing.assert_allclose(pipelined_lm_apply(model, params, tokens, stage_mesh),
+                                   model.apply({"params": params}, tokens), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("fields,match", [
+    (dict(layer_types=("linear_attention", "full_attention") * 2, linear_key_dim=8, linear_value_dim=8),
+     "dense layers are one description"),
+    (dict(layer_types=("mamba", "gated_memory") * 2), "no layer hands a value on"),
+    (dict(ffn_types=("moe", "dense", "dense", "moe"), num_experts=2), "come at a fixed period"),
+])
+def test_pipelined_lm_refuses_layers_it_cannot_stack(stage_mesh, fields, match):
+    from hops_tpu.models.transformer import TransformerLM
+    from hops_tpu.parallel.pipeline import make_pp_lm_train_step, pipelined_lm_apply
+
+    model = TransformerLM(vocab_size=64, d_model=32, num_heads=4, num_layers=4, dtype=jnp.float32, **fields)
+    with pytest.raises(NotImplementedError, match=match):
+        pipelined_lm_apply(model, {}, jnp.zeros((8, 16), jnp.int32), stage_mesh)
+    with pytest.raises(NotImplementedError, match=match):
+        make_pp_lm_train_step(model, stage_mesh, schedule="1f1b")
+
+
 @pytest.mark.slow
 def test_pipelined_lm_grads_match_dense(stage_mesh):
     from hops_tpu.models.transformer import TransformerLM
@@ -591,7 +626,7 @@ def test_pp_windowed_lm_matches_dense(stage_mesh):
 
 
 def test_pp_gqa_moe_lm_matches_dense(stage_mesh):
-    """Advisor r3 (low): the stage MoEBlock must carry num_kv_heads —
+    """Advisor r3 (low): the stage's routed block must carry num_kv_heads —
     a GQA MoE model previously failed with ScopeParamNotFoundError
     when pipelined."""
     from hops_tpu.models.transformer import TransformerLM

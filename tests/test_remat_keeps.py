@@ -28,21 +28,28 @@ HYBRID = dict(norm_placement="post_sublayer", mlp_hidden=MLP_HIDDEN, qk_norm=Tru
 FLASH = dict(norm_kind="layer", norm_eps=1e-5, mlp_hidden=MLP_HIDDEN, rope_base=None, use_bias=True,
              attention_form="differential", num_kv_heads=2)
 
-# kind of block -> (Block's fields, what it is handed, the names its remat keeps): ``mixer_out`` wherever a
-# norm reads the mixer's result or the sum it enters, ``mlp_out`` under a norm on the sublayer's output only
+TINY_LM = dict(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, dtype=jnp.bfloat16)
+HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
+FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
+               "gated_memory", "cross_attention")
+STEPS = {
+    "hybrid": dict(TINY_LM, **HYBRID, num_layers=4, layer_types=HYBRID_KINDS),
+    "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
+}
+
+# kind of block -> (the toy and the layer of it whose ``layer_specs()`` entry the block is built from, what it is
+# handed, the names its remat keeps): ``mixer_out`` wherever a norm reads the mixer's result or the sum it enters,
+# ``mlp_out`` under a norm on the sublayer's output only. Of the Mamba layers only the last before the gated memory
+# unit hands its ``y`` on, of the attention layers the full one, whose K and V the cross layer reads.
 BLOCKS = {
-    "post_norm_linear_attention": (dict(HYBRID, layer_type="linear_attention"), None, {"mixer_out", "mlp_out"}),
-    "post_norm_full_attention": (dict(HYBRID, layer_type="full_attention"), None,
-                                 {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
-    "pre_norm_mamba": (dict(FLASH, layer_type="mamba"), None, {"mixer_out"}),
-    "pre_norm_mamba_hands_on": (dict(FLASH, layer_type="mamba", hands_on="memory"), None, {"mixer_out"}),
-    "pre_norm_window_differential": (dict(FLASH, layer_type="sliding_attention", window=512, layer_index=1), None,
-                                     {"flash_out", "flash_lse", "mixer_out"}),
-    "pre_norm_full_differential": (dict(FLASH, layer_type="full_attention", hands_on="kv", layer_index=5), None,
-                                   {"flash_out", "flash_lse", "mixer_out"}),
-    "pre_norm_cross_differential": (dict(FLASH, layer_type="cross_attention", layer_index=7), "kv",
-                                    {"flash_out", "flash_lse", "mixer_out"}),
-    "pre_norm_gated_memory": (dict(FLASH, layer_type="gated_memory"), "memory", {"mixer_out"}),
+    "post_norm_linear_attention": ("hybrid", 0, None, {"mixer_out", "mlp_out"}),
+    "post_norm_full_attention": ("hybrid", 3, None, {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
+    "pre_norm_mamba": ("phi4_flash", 0, None, {"mixer_out"}),
+    "pre_norm_mamba_hands_on": ("phi4_flash", 4, None, {"mixer_out"}),
+    "pre_norm_window_differential": ("phi4_flash", 1, None, {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_full_differential": ("phi4_flash", 5, None, {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_cross_differential": ("phi4_flash", 7, "kv", {"flash_out", "flash_lse", "mixer_out"}),
+    "pre_norm_gated_memory": ("phi4_flash", 6, "memory", {"mixer_out"}),
 }
 
 
@@ -104,9 +111,12 @@ def _twin_forward_scans(jaxpr):
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("kind", sorted(BLOCKS))
 def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(kind, remat, scan_kernels_interpreted):
-    fields, handed, names = BLOCKS[kind]
+    toy, layer, handed, names = BLOCKS[kind]
+    model = TransformerLM(**STEPS[toy])
+    spec = model.layer_specs()[layer]
+    assert spec.hands_on == {"pre_norm_mamba_hands_on": "memory", "pre_norm_full_differential": "kv"}.get(kind)
     cls = nn.remat(Block, static_argnums=(2, 3), policy=KEPT) if remat else Block
-    block = cls(HEADS, dtype=jnp.bfloat16, **fields)
+    block = cls(spec, model.shared_spec())
     x = jnp.zeros((1, SEQ, D_MODEL), jnp.bfloat16)
     shared = {None: (), "memory": (jnp.zeros((1, SEQ, 2 * D_MODEL), jnp.bfloat16),),
               "kv": ((jnp.zeros((1, 2, SEQ, D_MODEL // HEADS), jnp.bfloat16),) * 2,)}[handed]
@@ -131,19 +141,9 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     if "flash_out" in names:
         assert inside["flash_bwd"] == 1 and _mosaic_calls(jaxpr)["flash_fwd"] == 1
         assert not {"flash_bwd_dq", "flash_bwd_dkv"} & set(_mosaic_calls(jaxpr))
-    if fields["layer_type"] == "mamba":  # the scan's results are not kept: its forward runs again
+    if spec.mixer == "mamba":  # the scan's results are not kept: its forward runs again
         assert inside["selective_scan_fwd"] == inside["selective_scan_bwd"] == 1
         assert _mosaic_calls(jaxpr)["selective_scan_fwd"] == 2
-
-
-TINY_LM = dict(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, dtype=jnp.bfloat16)
-HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
-FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
-               "gated_memory", "cross_attention")
-STEPS = {
-    "hybrid": dict(TINY_LM, **HYBRID, num_layers=4, layer_types=HYBRID_KINDS),
-    "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
-}
 
 
 def _lm_backward(model):
@@ -185,7 +185,8 @@ def test_a_routed_blocks_remat_keeps_the_flash_results_too():
     model = TransformerLM(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, num_layers=1, moe_every=1, num_experts=4,
                           moe_top_k=2, moe_expert_hidden=48, dtype=jnp.bfloat16, remat=True)
     jaxpr = _lm_backward(model)
-    assert sorted(name for name, _ in _kept(jaxpr) if name) == ["flash_lse", "flash_out"]  # MoEBlock names no sublayer
+    # one block class: a routed layer's mixer result is named as a dense layer's is
+    assert sorted(name for name, _ in _kept(jaxpr) if name) == ["flash_lse", "flash_out", "mixer_out"]
     assert _mosaic_calls(jaxpr)["flash_fwd"] == 1
 
 
