@@ -541,20 +541,26 @@ class Attention(nn.Module):
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) for
-    training, with the head-wise output gate and the per-head QK norm of
-    Ling-3.0-flash's configuration:
+    training, in two published forms that two options tell apart:
 
         q = W_q x -> (heads, nope + rope)
         [c | k_rope] = W_kva x  (kv_rank + rope)     c <- RMSNorm(c)
         [k_nope | v] = W_kvb c -> (heads, nope + value)
         k_h = [k_nope_h | k_rope]                     one k_rope for all heads
-        q_h, k_h <- RMSNorm(q_h), RMSNorm(k_h)        over a head's nope + rope channels, one learned scale each
+        q_h, k_h <- RMSNorm(q_h), RMSNorm(k_h)        ``qk_norm``: over a head's nope + rope channels, one learned scale each
         rotate the rope part of q_h and k_h           interleaved pairs, base ``rope_base``
         o_h = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
-        out = W_o [ sigmoid(W_g x)_h * o_h ]
+        out = W_o [ sigmoid(W_g x)_h * o_h ]          ``output_gate``: one scalar a head; without it W_o [ o_h ]
+
+    With both options (the defaults) it is Ling-3.0-flash's form
+    (``use_qk_norm``, ``gated_attention_proj_granularity_type: head_wise``;
+    arXiv:2510.26692). With neither it is DeepSeek-V3's (arXiv:2412.19437,
+    ``model_type: deepseek_v3`` with ``q_lora_rank: null``, as Kanana-2
+    publishes it): no ``gate``, ``q_norm`` or ``k_norm`` parameter exists and
+    none of their operations runs.
 
     The flash kernels take keys ``nope + rope`` wide beside values ``value``
-    wide. Its parts enter ``telemetry.spans.MLA_SCOPES``."""
+    wide. Its parts enter ``telemetry.spans.MLA_SCOPES`` in either form."""
 
     num_heads: int
     kv_rank: int
@@ -565,6 +571,8 @@ class LatentAttention(nn.Module):
     norm_eps: float = 1e-6
     attention_impl: str = "flash"
     dtype: Any = jnp.bfloat16
+    output_gate: bool = True
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, x, decode: bool = False):
@@ -584,13 +592,15 @@ class LatentAttention(nn.Module):
             latent = dense(self.kv_rank + rope, "kv_a")(x)
             c = RMSNorm(self.norm_eps, dtype=self.dtype, name="kv_a_norm")(latent[..., : self.kv_rank])
             kv = dense(h * (nope + dv), "kv_b")(c).reshape(b, s, h, nope + dv)
-            gate = jax.nn.sigmoid(dense(h, "gate", jnp.float32)(x.astype(jnp.float32)))
+            if self.output_gate:
+                gate = jax.nn.sigmoid(dense(h, "gate", jnp.float32)(x.astype(jnp.float32)))
 
         with jax.named_scope(scope_attn):
             k_rope = jnp.broadcast_to(latent[:, :, None, self.kv_rank:], (b, s, h, rope))
             k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
-            q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(q)
-            k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+            if self.qk_norm:
+                q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+                k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(k)
             q, k, v = (jnp.moveaxis(t, 2, 1) for t in (q, k, kv[..., nope:]))  # (b, h, s, d)
             pos = jnp.arange(s)
 
@@ -604,8 +614,10 @@ class LatentAttention(nn.Module):
                 o = attention_reference(q, k, v, causal=True)
 
         with jax.named_scope(scope_out):
-            o = (jnp.moveaxis(o, 1, 2) * gate[..., None].astype(o.dtype)).reshape(b, s, h * dv)
-            return dense(dm, "out")(o)
+            o = jnp.moveaxis(o, 1, 2)
+            if self.output_gate:
+                o = o * gate[..., None].astype(o.dtype)
+            return dense(dm, "out")(o.reshape(b, s, h * dv))
 
 
 class MLP(nn.Module):
@@ -683,6 +695,17 @@ def build_latent_attention(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
     return LatentAttention(
         shared.num_heads, **dict(spec.mixer_options), norm_eps=spec.norm_eps,
         attention_impl=shared.attention_impl, dtype=shared.dtype, name="attn")
+
+
+def counted_kind(spec: LayerSpec) -> str:
+    """The ``kind`` a layer adds to ``hops_tpu_train_layer_kinds_total``: its
+    mixer, and for latent attention the parts it is built without
+    (``latent_attention_no_output_gate_no_qk_norm`` is DeepSeek-V3's form;
+    Ling's, with both, stays ``latent_attention``)."""
+    if spec.mixer != "latent_attention":
+        return spec.mixer
+    options = dict(spec.mixer_options)
+    return spec.mixer + "".join(f"_no_{part}" for part in ("output_gate", "qk_norm") if not options[part])
 
 
 def build_dense_ffn(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
@@ -849,7 +872,9 @@ class TransformerLM(nn.Module):
     # (Ling-3.0-flash): ``layer_types`` may also name "kimi_delta_attention"
     # (``mixer_options``: the ``linear_*`` keys; ``linear_lower_bound`` of its
     # log-decay) and "latent_attention" (``latent_*``: the key/value rank, a
-    # head's widths without and with position, and of its values);
+    # head's widths without and with position, and of its values; its
+    # head-wise output gate and per-head QK norm, both on: Ling's form, both
+    # off: DeepSeek-V3's, as Kanana-2 publishes it);
     # ``LayerSpec.ffn`` per layer, "dense" | "moe" (None: ``moe_every``'s, or
     # every layer dense), independently of the mixer; the rest of a routed
     # layer's ``ffn_options`` (its sigmoid router; ``moe_held_experts``: this
@@ -863,6 +888,8 @@ class TransformerLM(nn.Module):
     latent_nope_dim: int | None = None
     latent_rope_dim: int | None = None
     latent_value_dim: int | None = None
+    latent_output_gate: bool = True
+    latent_qk_norm: bool = True
     moe_scoring: str = "softmax"
     moe_n_group: int = 1
     moe_topk_group: int = 1
@@ -963,7 +990,8 @@ class TransformerLM(nn.Module):
             if kind == "latent_attention":
                 return _pairs(kv_rank=self.latent_kv_rank, nope_dim=self.latent_nope_dim,
                               rope_dim=self.latent_rope_dim, value_dim=self.latent_value_dim,
-                              rope_base=self.rope_base)
+                              rope_base=self.rope_base, output_gate=self.latent_output_gate,
+                              qk_norm=self.latent_qk_norm)
             if kind in ("mamba", "gated_memory"):
                 return ()
             # the attention kinds; a differential map's lambda_0 follows the layer's index
@@ -1005,7 +1033,7 @@ class TransformerLM(nn.Module):
             block_cls = nn.remat(Block, static_argnums=(2, 3), policy=kept)
         handed_on: dict[str, Any] = {}  # the newest value of each kind: a reader's nearest writer's
         for spec in specs[: self.num_layers]:
-            _m_layer_kinds.inc(kind=spec.mixer)
+            _m_layer_kinds.inc(kind=counted_kind(spec))
             value = ()
             if spec.mixer in SHARED_VALUES:
                 what = SHARED_VALUES[spec.mixer][0]
@@ -1018,7 +1046,7 @@ class TransformerLM(nn.Module):
         if self.mtp_layers and not decode and (mtp_tokens is not None or self.is_initializing()):
             # the module predicts the token after the next from the last layer's
             # output (before the final norm) and the next token's embedding
-            _m_layer_kinds.inc(kind=f"mtp_{self.mtp_layer_type}")
+            _m_layer_kinds.inc(kind=f"mtp_{counted_kind(specs[-1])}")
             mtp_hidden = MTPModule(functools.partial(block_cls, specs[-1], shared), self.norm_eps,
                                    dtype=self.dtype, name=SCOPE_MTP)(
                 x, embed(tokens if mtp_tokens is None else mtp_tokens), train)

@@ -401,11 +401,15 @@ def test_moe_every_is_the_ffn_types_it_abbreviates(n, monkeypatch):
 def test_the_models_fields_are_the_parents():
     """The flat fields are the public surface (configuration files spell them, serving clones with them):
     ``tests/data/transformer_lm_parent_fields.json`` was written by PR 43's parent (3d29bff), name -> repr(default),
-    in order. A layer's description (``LayerSpec``, ``SharedSpec``) changes below it."""
+    in order. A layer's description (``LayerSpec``, ``SharedSpec``) changes below it. PR 44 added the latent
+    mixer's two options after ``latent_value_dim``, on by default (what the parent built)."""
     import dataclasses
 
     want = json.loads((DATA / "transformer_lm_parent_fields.json").read_text())
     got = {f.name: repr(f.default) for f in dataclasses.fields(TransformerLM) if f.name not in ("parent", "name")}
+    since = {"latent_output_gate": "True", "latent_qk_norm": "True"}
+    assert list(got)[list(got).index("latent_value_dim") + 1:][:2] == list(since)
+    assert {name: got.pop(name) for name in since} == since
     assert list(got.items()) == list(want.items())
     assert [f.name for f in dataclasses.fields(Block) if f.name not in ("parent", "name")] == ["spec", "shared"]
 
