@@ -25,7 +25,10 @@ top-8, no capacity):
   scatter-add of that many rows is what the rule "no scatter-add" keeps
   out. Where a share is held the dispatch gathers a chunk's rows and the
   combine adds them to their tokens as a 0/1 matrix product
-  (:func:`_add_rows`), forward and backward: still no scatter-add.
+  (:func:`_add_rows`), forward and backward: still no scatter-add. The
+  product walks the chunk a row tile at a time and stops after the last
+  tile that holds a row of the share, so it costs what the share takes
+  and not what the chunk could hold.
 
 Inside ``Strategy.step`` on a data mesh the routing runs per device
 shard (``parallel.mesh.per_shard``): each chip sorts its own tokens
@@ -59,12 +62,18 @@ is static, four times the rows an even routing sends the share
 (:func:`_held_bound`; 4,096 of 65,536 for 8 of 512 experts), and the loop
 over chunks is as long as the held rows need: one chunk unless the routing
 is more than four times off even, then as many as it takes, so no row is
-ever dropped and no capacity appears anywhere. ``moe_stats/held_rows`` counts
-the share's rows and ``moe_stats/held_overflow`` is 1 where a second chunk
-ran; ``hops_tpu_train_moe_traces_total{dispatch}`` says at trace time which
-of the two dispatches (``all`` | ``held``) a layer holds. It is also the send
-side of the exchange expert parallelism on chips needs: the rows a chip
-gathers for one peer's experts.
+ever dropped and no capacity appears anywhere. Inside a chunk the held rows
+lead and the combine multiplies row tiles of :data:`_ADD_TILE` only as far as
+they reach (a chunk is mostly rows of other experts: under an even routing
+three quarters of it). ``moe_stats/held_rows`` counts the share's rows,
+``moe_stats/held_overflow`` is 1 where a second chunk ran,
+``moe_stats/held_row_tiles`` counts the row tiles the forward combine
+multiplied and ``moe_stats/held_tile_share`` is that count over the tiles of
+the chunks that ran (1.0: every chunk full; the step reports the layers' mean
+as ``moe_held_tile_share``); ``hops_tpu_train_moe_traces_total{dispatch}``
+says at trace time which of the two dispatches (``all`` | ``held``) a layer
+holds. It is also the send side of the exchange expert parallelism on chips
+needs: the rows a chip gathers for one peer's experts.
 """
 
 from __future__ import annotations
@@ -147,7 +156,8 @@ def _held_bound(n_rows: int, n_local: int, num_experts: int) -> int:
 def _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held=lambda rows: rows):
     """The experts over sorted ``rows``, each output row times its
     ``weight``; ``held`` zeroes what the grouped matmul leaves unspecified
-    (rows past the groups), on the way into and out of every call."""
+    (rows past the groups) on the way out of the first two calls and into
+    the third; in the result those rows are the caller's to drop."""
     with jax.named_scope(SCOPE_EXPERTS):
         gate = held(grouped_matmul(rows, w_gate, sizes)).astype(jnp.float32)
         up = held(grouped_matmul(rows, w_up, sizes)).astype(jnp.float32)
@@ -155,25 +165,57 @@ def _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held=lambda rows:
         # activation's fusion at the experts' width, not the model's;
         # float32 inside the fusion, one rounding on the way out
         act = (nn.silu(gate) * up * weight[:, None]).astype(rows.dtype)
-        return held(grouped_matmul(act, w_down, sizes))
+        return grouped_matmul(act, w_down, sizes)
 
 
-def _add_rows(rows, token, n_tokens):
-    """``(n_tokens, width)`` float32: row ``t`` is the sum of the ``rows``
-    whose ``token`` is ``t``, as a 0/1 matrix product on the MXU (exact:
-    the ones are exact in any type and the sum is float32). The transpose
-    of the gather ``x[token]``, for a few thousand rows; a scatter-add of
-    as many rows is the slower of the two on the chip (PERF.md, PR 40)."""
-    onehot = (jnp.arange(n_tokens)[:, None] == token[None, :]).astype(rows.dtype)
-    return jax.lax.dot(onehot, rows, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+#: sorted rows of a chunk that :func:`_add_rows` multiplies at a time: whole row
+#: tiles of the grouped matmul, a divisor of both cells' chunks (PERF.md, PR 45)
+_ADD_TILE = 2048
+
+
+def _add_tiles(rows, bound):
+    """Row tiles :func:`_add_rows` multiplies for ``rows`` live rows of a
+    chunk of ``bound``: the tiles up to the one that holds the last of them."""
+    tile = min(bound, _ADD_TILE)
+    return (rows + tile - 1) // tile
+
+
+def _add_rows(totals, parts, token, live):
+    """Adds to every ``(n_tokens, width)`` float32 of ``totals`` the leading
+    ``live`` rows of its ``(bound, width)`` array of ``parts``, row ``r`` to
+    token ``token[r]``: a 0/1 matrix product on the MXU (exact: the ones are
+    exact in any type and the sum is float32) over one tile of rows at a
+    time, and only over the tiles that hold one of those rows; what the rows
+    past them hold is never read into a sum. The transpose of the gather
+    ``x[token]``; a scatter-add of as many rows is the slower of the two on
+    the chip (PERF.md, PR 40), and a product over the whole chunk is mostly
+    zeros times zeros-and-ones where the share is large (PR 45)."""
+    n_tokens, bound = totals[0].shape[0], token.shape[0]
+    tile = min(bound, _ADD_TILE)
+
+    def body(i, totals):
+        # a last tile that would pass the chunk's end starts early instead
+        # (as dynamic_slice would clamp it) and drops the rows it repeats
+        first = jnp.minimum(i * tile, bound - tile)
+        at = first + jnp.arange(tile)
+        keep = ((at >= i * tile) & (at < live))[:, None]
+        onehot = jnp.arange(n_tokens)[:, None] == jax.lax.dynamic_slice_in_dim(token, first, tile)[None, :]
+        return tuple(
+            total + jax.lax.dot(onehot.astype(rows.dtype),
+                                jnp.where(keep, jax.lax.dynamic_slice_in_dim(rows, first, tile), 0),
+                                precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+            for total, rows in zip(totals, parts))
+
+    return jax.lax.fori_loop(0, _add_tiles(live, bound), body, tuple(totals))
 
 
 def _held_chunk(c, x, top_p, order, local_sizes, k, bound):
     """Chunk ``c`` of the sorted rows, ``bound`` of them from row ``c *
-    bound`` on: ``(token, slot, held, sizes, rows, weight)``, the token and
-    the (token, slot) index of each row, a function that zeroes the rows
-    that reached no held expert, the part of every held expert's group
-    that lies in the chunk, and the chunk's rows of ``x`` and ``top_p``."""
+    bound`` on: ``(token, slot, live, held, sizes, rows, weight)``, the token
+    and the (token, slot) index of each row, how many of the chunk's rows
+    (its leading ones) reached a held expert, a function that zeroes the
+    others, the part of every held expert's group that lies in the chunk,
+    and the chunk's rows of ``x`` and ``top_p``."""
     with jax.named_scope(SCOPE_DISPATCH):
         ends = jnp.cumsum(local_sizes)
         at = c * bound + jnp.arange(bound)
@@ -187,7 +229,8 @@ def _held_chunk(c, x, top_p, order, local_sizes, k, bound):
 
         slot = order[jnp.minimum(at, order.shape[0] - 1)]
         token = slot // k
-        return token, slot, held, inside(ends) - inside(ends - local_sizes), held(x[token]), held(top_p[slot])
+        sizes = inside(ends) - inside(ends - local_sizes)
+        return token, slot, inside(ends[-1]), held, sizes, held(x[token]), held(top_p[slot])
 
 
 def _zeros_for(shape, *operands):
@@ -215,13 +258,11 @@ def _held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
     length the routing decides has no transpose of JAX's own, so the
     backward pass is written out: the same loop, each chunk's experts
     run again and pulled back (only the arguments are kept for it)."""
-    n_tokens = x.shape[0]
-
     def body(c, out):
-        token, _, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
+        token, _, live, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
         out_rows = _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held)
         with jax.named_scope(SCOPE_COMBINE):
-            return out + _add_rows(out_rows, token, n_tokens)
+            return _add_rows((out,), (out_rows,), token, live)[0]
 
     operands = (x, top_p, w_gate, w_up, w_down, order, local_sizes)
     out = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body, _zeros_for(x.shape, *operands))
@@ -235,26 +276,25 @@ def _held_share_fwd(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound
 
 def _held_share_bwd(k, bound, res, g):
     x, top_p, w_gate, w_up, w_down, order, local_sizes = res
-    n_tokens = x.shape[0]
 
     def body(c, grads):
-        token, slot, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
+        token, slot, live, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
         _, pull = jax.vjp(functools.partial(_swiglu_experts, sizes=sizes, held=held),
                           rows, weight, w_gate, w_up, w_down)
         with jax.named_scope(SCOPE_COMBINE):
-            d_out_rows = g[token]
+            d_out_rows = held(g[token])
         d_rows, d_weight, *d_w = pull(d_out_rows)
         with jax.named_scope(SCOPE_DISPATCH):
             # a token's k weights are a row of k: the one 0/1 matrix serves both
-            d_weight = held(d_weight)[:, None] * (slot[:, None] % k == jnp.arange(k))
-            found = (_add_rows(held(d_rows), token, n_tokens),
-                     _add_rows(d_weight, token, n_tokens).reshape(top_p.shape), *d_w)
-        return tuple(total + part.astype(jnp.float32) for total, part in zip(grads, found))
+            d_weight = d_weight[:, None] * (slot[:, None] % k == jnp.arange(k))
+            d_x, d_top_p = _add_rows(grads[:2], (d_rows, d_weight), token, live)
+        return (d_x, d_top_p, *(total + part.astype(jnp.float32) for total, part in zip(grads[2:], d_w)))
 
     primals = (x, top_p, w_gate, w_up, w_down)
+    shapes = (x.shape, (x.shape[0], k), w_gate.shape, w_up.shape, w_down.shape)  # a token's k weights a row
     grads = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body,
-                              tuple(_zeros_for(p.shape, *res, g) for p in primals))
-    return (*(grad.astype(p.dtype) for grad, p in zip(grads, primals)), None, None)
+                              tuple(_zeros_for(shape, *res, g) for shape in shapes))
+    return (*(grad.reshape(p.shape).astype(p.dtype) for grad, p in zip(grads, primals)), None, None)
 
 
 _held_share.defvjp(_held_share_fwd, _held_share_bwd)
@@ -268,8 +308,10 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
     from id ``first`` on (all of them unless the caller holds a slice).
     Returns ``(out (b, s, d), rows per expert (1, num_experts))``; the
     leading 1 is the batch-leading partial ``per_shard`` stacks. A caller
-    that holds a slice gets a third, ``(1,)``: 1 when the slice took more
-    rows than :func:`_held_bound` and :func:`_held_share` ran on.
+    that holds a slice gets a third, ``(1, 3)``: the chunks of
+    :func:`_held_bound` rows that :func:`_held_share` ran (more than one when
+    the slice took more rows than that), the row tiles its combine
+    multiplied in them and the row tiles they have (:func:`_add_tiles`).
     """
     b, s, d = x.shape
     k = top_ids.shape[-1]
@@ -288,8 +330,10 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
         bound = _held_bound(n_rows, n_local, num_experts)
         out = _held_share(x.reshape(b * s, d), top_p.reshape(n_rows), w_gate, w_up, w_down, order, local_sizes,
                           k, bound)
-        overflow = (_held_chunks(local_sizes, bound) > 1).astype(jnp.int32)
-        return out.reshape(b, s, d), sizes[None], overflow[None]
+        chunks, a_chunk = _held_chunks(local_sizes, bound), _add_tiles(bound, bound)
+        full, rest = jnp.divmod(jnp.sum(local_sizes), bound)
+        ran = jnp.stack([chunks, full * a_chunk + _add_tiles(rest, bound), chunks * a_chunk])
+        return out.reshape(b, s, d), sizes[None], ran[None]
     with jax.named_scope(SCOPE_DISPATCH):
         rows = _to_sorted(x.reshape(b * s, d), order, inverse, k)
         weight = _to_sorted(top_p.reshape(n_rows), order, inverse, 1)
@@ -402,7 +446,7 @@ class MoEMLP(nn.Module):
         first = 0 if self.held_experts is None else self.held_experts[0]
         if self.expert_axis is not None:
             first = jax.lax.axis_index(self.expert_axis) * e_local
-        out, rows_per_expert, *overflow = per_shard(
+        out, rows_per_expert, *chunks = per_shard(
             functools.partial(_routed_experts, num_experts=self.num_experts, first=first),
             op="moe", replicated=(3, 4, 5),
         )(x.astype(self.dtype), top_p, top_ids, w_gate, w_up, w_down)
@@ -435,10 +479,14 @@ class MoEMLP(nn.Module):
         self.sow("moe_stats", "expert_ids", top_ids)
         if self.held_experts is not None:
             # of the rows above, those that reached the experts held here,
-            # and 1 if on some shard they were more than a chunk
+            # 1 if on some shard they were more than a chunk, the row tiles
+            # the combine multiplied, and their share of the chunks' tiles
             self.sow("moe_stats", "held_rows", jax.lax.dynamic_slice_in_dim(rows_per_expert, first, e_local).sum())
-            if overflow:
-                self.sow("moe_stats", "held_overflow", overflow[0].max())
+            if chunks:
+                self.sow("moe_stats", "held_overflow", (chunks[0][:, 0] > 1).astype(jnp.int32).max())
+                _, tiles, of = chunks[0].sum(0)
+                self.sow("moe_stats", "held_row_tiles", tiles)
+                self.sow("moe_stats", "held_tile_share", tiles / jnp.maximum(of, 1))
         return out
 
     def _sigmoid_choice(self, router_logits):
@@ -538,6 +586,16 @@ def held_overflows(variables: Any) -> jax.Array | None:
     layer holds a share)."""
     flags = [f for v in _sown(variables, "moe_stats", "held_overflow") for f in v]
     return jnp.sum(jnp.stack(flags)) if flags else None
+
+
+def held_tile_share(variables: Any) -> jax.Array | None:
+    """Of the row tiles in the chunks :func:`_held_share` ran, the share its
+    combine multiplied (:func:`_add_rows` stops at a chunk's last live
+    tile; 1.0 is every tile of every chunk), the mean over the routed layers
+    of a ``mutable=["moe_stats"]`` apply that hold a share of the experts
+    (None when no layer does)."""
+    shares = [f for v in _sown(variables, "moe_stats", "held_tile_share") for f in v]
+    return jnp.mean(jnp.stack(shares)) if shares else None
 
 
 def expert_specs(params: Any, axis: str = "expert") -> Any:

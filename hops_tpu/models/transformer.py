@@ -1085,7 +1085,9 @@ def make_lm_train_step(
     ``moe_load_max_over_mean`` (busiest expert's rows over the mean, the
     largest over layers) and, where layers hold a share of the experts,
     ``moe_held_overflow`` (how many of them took more rows than their bound
-    in this step) — device scalars like ``loss``, no host sync.
+    in this step) and ``moe_held_tile_share`` (of the row tiles in the chunks
+    they ran, the share their combine multiplied: ``moe.held_tile_share``) —
+    device scalars like ``loss``, no host sync.
 
     ``mtp_loss_weight`` > 0 trains a model's multi-token-prediction module:
     the batch then holds ``seq + 2`` ids a row, positions ``[:-2]`` are
@@ -1109,7 +1111,8 @@ def make_lm_train_step(
     """
     import optax
 
-    from hops_tpu.models.moe import held_overflows, max_load_over_mean, sum_sown_losses, updated_router_bias
+    from hops_tpu.models.moe import (
+        held_overflows, held_tile_share, max_load_over_mean, sum_sown_losses, updated_router_bias)
     from hops_tpu.parallel.mesh import gathered
     from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
 
@@ -1169,6 +1172,7 @@ def make_lm_train_step(
                 metrics["moe_load_max_over_mean"] = max_load_over_mean(mods)
                 if (overflows := held_overflows(mods)) is not None:
                     metrics["moe_held_overflow"] = overflows
+                    metrics["moe_held_tile_share"] = held_tile_share(mods)
             return total, (metrics, mods.get("moe_stats") if router_bias and router_bias_rate else None)
 
         (_, (metrics, stats)), grads = jax.value_and_grad(compute_loss, has_aux=True)(state.params)

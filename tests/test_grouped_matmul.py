@@ -320,23 +320,32 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     assert not moved, moved
 
 
-def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, monkeypatch):
+# tokens, d_model, top_k, experts, held, expert width; the chunk; the layer's temporaries at the parent of PR 45
+HELD_LAYERS = {"ling": ((8192, 2560, 8, 512, 8, 768), 4096, 372_246_016),
+               "kanana": ((8192, 2048, 6, 128, 16, 768), 24576, 850_136_064)}
+
+
+@pytest.mark.parametrize("cell", HELD_LAYERS)
+def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, monkeypatch, cell):
     """The routed layer of the Ling cell, forward and backward (8,192 tokens
-    x 2,560, top-8 of 512 experts, experts 0-7 held; this file holds the one
+    x 2,560, top-8 of 512 experts, experts 0-7 held), and of the Kanana-2
+    cell (8,192 x 2,048, top-6 of 128, 16 held; this file holds the one
     fixture that may load the TPU compiler): the grouped matmuls are the
-    ``moe_gmm`` kernels over a chunk of 4,096 rows, no array of the 65,536
-    routed rows at the model's or the experts' width is left, no scatter
-    stands in for the combine, and the temporaries are a third of the
-    1,075 MB the layer needed while both gathers moved every row (compile,
-    PR 40). Nothing runs."""
+    ``moe_gmm`` kernels over a chunk of 4,096 / 24,576 rows, no array of all
+    the routed rows at the model's or the experts' width is left, no scatter
+    stands in for the combine, the 0/1 block that adds rows to tokens is as
+    wide as a row tile of :func:`moe._add_rows` and never as wide as the
+    chunk, and the temporaries are no more than they were while the product
+    spanned the chunk (compile, PR 45; Ling's a third of the 1,075 MB the
+    layer needed while both gathers moved every row: compile, PR 40).
+    Nothing runs."""
     from hops_tpu.models import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not their twin
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    (tokens, d, k, experts, held, hidden), bound, parent_temp = HELD_LAYERS[cell]
     try:
-        tokens, d, k, experts, held, hidden = 8192, 2560, 8, 512, 8, 768
-
         def shape(dims, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
@@ -349,11 +358,13 @@ def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, 
             shape((held, d, hidden)), shape((held, hidden, d)), shape((1, tokens, k), jnp.int32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
-    assert moe._held_bound(tokens * k, held, experts) == 4096
+    assert moe._held_bound(tokens * k, held, experts) == bound and bound % moe._ADD_TILE == 0
     text = compiled.as_text()
     assert sum("tpu_custom_call" in line and "moe_gmm" in line for line in text.splitlines()) == 8  # 2 again + 6 back
     assert f"[{tokens * k},{d}]" not in text and f"[{tokens * k},{hidden}]" not in text and " scatter(" not in text
-    assert f"[4096,{d}]" in text and compiled.memory_analysis().temp_size_in_bytes < 400e6
+    assert f"[{bound},{d}]" in text
+    assert f"pred[{tokens},{moe._ADD_TILE}]" in text and f"[{tokens},{bound}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
 
 
 def test_four_chip_lm_step_compiles_to_gathers_of_weights_and_sums_to_the_owner(topo, monkeypatch):

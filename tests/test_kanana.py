@@ -171,26 +171,70 @@ def test_routed_layer_follows_the_reference(x, routed):
     assert _rel(got, want) < REL_TOL
 
 
-def test_the_eight_shares_add_up_to_the_uncut_reference(x, routed):
+@pytest.mark.parametrize("load", ["spread", "idle_share", "three_chunks"])
+def test_the_eight_shares_add_up_to_the_uncut_reference(x, routed, load, monkeypatch):
     """16 experts over eight chips, two a chip: what the eight held shares add
     to the result, with the two shared experts (which every chip computes
-    alike) counted once, is the reference's layer with every expert."""
-    _, params, bias = routed
-    want, _ = _ref_layer(x, params, bias["bias"], held=(0, EXPERTS))
-    shared_once = MLP(hidden=64, dtype=jnp.float32).apply({"params": params["shared"]}, x)
-    total, held_rows = shared_once, 0
-    for first in range(0, EXPERTS, 2):
-        share = {**params, **{n: params[n][first: first + 2] for n in moe.EXPERT_WEIGHTS}}
-        out, stats = _moe(held_experts=(first, 2)).apply(
-            {"params": share, "router_bias": bias}, x, mutable=["moe_stats"])
+    alike) counted once, is the reference's layer with every expert; so are
+    their gradients the gradients of the layer that holds every expert (its
+    sort and inverse gather, no chunk and no 0/1 product), under one
+    cotangent. The same where a bias keeps every token off the first share
+    (it takes no row), and of 128 experts over sixteen chips where a bias
+    sends all 1,536 rows to the second share: three chunks of 512. The
+    combine's row tiles (cut to 64 rows for the test) are counted by hand."""
+    monkeypatch.setattr(moe, "_ADD_TILE", 64)
+    layer, params, bias = routed
+    experts, count = (128, 8) if load == "three_chunks" else (EXPERTS, 2)
+    if load == "three_chunks":
+        layer = _moe(num_experts=experts)
+        params = layer.init(jax.random.PRNGKey(4), x)["params"]
+        bias = {"bias": (0.3 * jax.random.normal(jax.random.PRNGKey(5), (experts,))).at[8:8 + TOP_K].add(10.0)}
+    if load == "idle_share":
+        bias = {"bias": bias["bias"].at[:2].add(-10.0)}
+    bound = moe._held_bound(2 * SEQ * TOP_K, count, experts)
+    assert bound == (512 if load == "three_chunks" else 768)
+    seed = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+
+    def run(layer, params, x):
+        out, mods = layer.apply({"params": params, "router_bias": bias}, x, mutable=["moe_stats"])
+        return jnp.sum(out * seed), (out, mods["moe_stats"])
+
+    want, _ = _ref_layer(x, params, bias["bias"], held=(0, experts))
+    (_, (uncut, _)), want_grads = jax.value_and_grad(functools.partial(run, layer), argnums=(0, 1), has_aux=True)(params, x)
+    assert _rel(uncut, want) < REL_TOL
+    shared = MLP(hidden=64, dtype=jnp.float32)
+    shared_once = shared.apply({"params": params["shared"]}, x)
+    d_shared = jax.grad(lambda x: jnp.sum(shared.apply({"params": params["shared"]}, x) * seed))(x)
+    total, held_rows, d_x, d_router, tile_shares = shared_once, 0, d_shared, 0.0, []
+    for first in range(0, experts, count):
+        share = {**params, **{n: params[n][first: first + count] for n in moe.EXPERT_WEIGHTS}}
+        (_, (out, stats)), (d_share, d_x_share) = jax.value_and_grad(
+            functools.partial(run, _moe(num_experts=experts, held_experts=(first, count))), argnums=(0, 1),
+            has_aux=True)(share, x)
         # the program's share is the reference's share, and the reference's share is its part of the uncut layer
-        ref_share, _ = _ref_layer(x, share, bias["bias"], held=(first, 2))
+        ref_share, _ = _ref_layer(x, share, bias["bias"], held=(first, count))
         assert _rel(out, ref_share) < REL_TOL
         total = total + (out - shared_once)
-        held_rows += int(stats["moe_stats"]["held_rows"][0])
-        assert int(stats["moe_stats"]["held_overflow"][0]) == 0
+        d_x, d_router = d_x + (d_x_share - d_shared), d_router + d_share["router"]["kernel"]
+        rows = int(stats["held_rows"][0])
+        held_rows += rows
+        chunks = -(-rows // bound)
+        assert int(stats["held_overflow"][0]) == int(chunks > 1)
+        tiles = rows // bound * (bound // 64) + -(-(rows % bound) // 64)
+        assert int(stats["held_row_tiles"][0]) == tiles
+        assert float(stats["held_tile_share"][0]) == pytest.approx(tiles / max(chunks * (bound // 64), 1))
+        tile_shares.append((rows, float(stats["held_tile_share"][0])))
+        for name in moe.EXPERT_WEIGHTS:  # a share's stacks are the uncut layer's rows of them
+            assert _rel(d_share[name], want_grads[0][name][first: first + count]) < 1e-5 if rows else not jnp.any(d_share[name])
     assert _rel(total, want) < 1e-5
+    assert _rel(d_x, want_grads[1]) < 1e-5 and _rel(d_router, want_grads[0]["router"]["kernel"]) < 1e-5
     assert held_rows == 2 * SEQ * TOP_K  # every routed row reached exactly one share
+    if load == "idle_share":
+        assert tile_shares[0] == (0, 0.0)
+    if load == "three_chunks":
+        assert tile_shares[1] == (3 * bound, 1.0) and all(share == (0, 0.0) for share in tile_shares[:1] + tile_shares[2:])
+    if load == "spread":  # a share ends inside a tile, so its last live tile is the last it multiplies
+        assert any(rows % 64 and share < 1.0 for rows, share in tile_shares)
 
 
 def test_a_chunk_of_this_share_is_half_the_rows():
@@ -384,6 +428,7 @@ def test_step_trains_under_the_warm_up_and_reports_no_overflow(tiny_step):
     # ... which re-route a few tokens; from the second step on the parameters move too
     assert losses[1] == pytest.approx(losses[0], rel=1e-3) and losses[-1] < losses[0] - 0.01
     assert int(metrics["moe_held_overflow"]) == 0 and float(metrics["moe_load_max_over_mean"]) > 1.0
+    assert 0.0 < float(metrics["moe_held_tile_share"]) <= 1.0  # of two layers that hold a share
     assert "moe_aux_loss" not in metrics and "moe_seq_aux_loss" not in metrics and "mtp_loss" not in metrics
     for name in PARTS[1:]:
         assert 0.0 < float(jnp.max(jnp.abs(state.router_bias[name]["moe"]["bias"]))) <= 5e-3 + 1e-9

@@ -221,51 +221,85 @@ def _held_traces() -> float:
     return traces.value(impl="ragged_dot", dispatch="held")
 
 
-@pytest.mark.parametrize("impl", ["ragged_dot", "interpret"])
-def test_a_held_share_is_its_part_of_the_layer_that_holds_every_expert(impl, monkeypatch):
-    """Experts 6 and 7 of 32 over 1,024 routed rows (bound 256, a chunk)
-    against all 32 with every other expert's matrices zero, on one routing:
-    the result and the gradients of ``x``, the weights and the three stacks."""
+def _routing_with(load, tokens, experts, top_k, first, count, key):
+    """``(1, tokens, top_k)`` expert ids: as they fall (``spread``), none on the
+    ``count`` held experts from ``first`` on, the first 100 tokens on one of
+    them, or every token on two of them."""
+    if load == "spread":
+        return jnp.argsort(jax.random.uniform(key, (1, tokens, experts)), axis=-1)[..., :top_k].astype(jnp.int32)
+    ids = jnp.argsort(jax.random.uniform(key, (1, tokens, experts - count)), axis=-1)[..., :top_k].astype(jnp.int32)
+    ids = jnp.where(ids >= first, ids + count, ids)  # the other experts
+    if load == "inside_a_tile":
+        ids = ids.at[0, :100, 1].set(first + 1)
+    if load == "three_chunks":
+        ids = ids.at[..., 0].set(first).at[..., 2].set(first + 1)
+    return ids
+
+
+@pytest.mark.parametrize("impl,load", [("ragged_dot", "spread"), ("interpret", "spread"), ("ragged_dot", "none"),
+                                       ("ragged_dot", "inside_a_tile"), ("ragged_dot", "three_chunks")])
+def test_a_held_share_is_its_part_of_the_layer_that_holds_every_expert(impl, load, monkeypatch):
+    """Experts 6 and 7 of 32 over 1,024 routed rows, or of 64 over 1,536
+    (bound 256, a chunk; a row tile of the combine cut to 64 for the test)
+    against all the experts with every other one's matrices zero, on one
+    routing: the result and the gradients of ``x``, the weights and the three
+    stacks, where the share takes rows as they fall, none at all, 100 (it
+    ends inside the second of a chunk's four tiles) and 768 (three chunks)."""
     if impl == "interpret":
         monkeypatch.setattr(moe, "grouped_matmul", lambda *a: grouped_matmul(*a, interpret=True))
-    first, count, experts, top_k, d = 6, 2, 32, 4, 128
+    monkeypatch.setattr(moe, "_ADD_TILE", 64)
+    first, count, top_k, d = 6, 2, 4, 128
+    tokens, experts = (256, 32) if load == "spread" else (384, 64)
     keys = jax.random.split(jax.random.PRNGKey(7), 6)
-    x, seed = jax.random.normal(keys[0], (1, 256, d)), jax.random.normal(keys[1], (1, 256, d))
-    ids = jnp.argsort(jax.random.uniform(keys[2], (1, 256, experts)), axis=-1)[..., :top_k].astype(jnp.int32)
-    top_p = jax.random.uniform(keys[3], (1, 256, top_k), minval=0.1)
+    x, seed = jax.random.normal(keys[0], (1, tokens, d)), jax.random.normal(keys[1], (1, tokens, d))
+    ids = _routing_with(load, tokens, experts, top_k, first, count, keys[2])
+    top_p = jax.random.uniform(keys[3], (1, tokens, top_k), minval=0.1)
     stacks = [0.1 * jax.random.normal(key, (count, d, d)) for key in jax.random.split(keys[4], 3)]
-    assert 0 < int(jnp.sum((ids >= first) & (ids < first + count))) < moe._held_bound(256 * top_k, count, experts) == 256
+    held_rows = int(jnp.sum((ids >= first) & (ids < first + count)))
+    assert moe._held_bound(tokens * top_k, count, experts) == 256
+    assert {"none": held_rows == 0, "inside_a_tile": held_rows == 100, "three_chunks": held_rows == 768}.get(
+        load, 0 < held_rows < 256)
 
     def run(first, x, top_p, *stacks):
-        out, rows, *overflow = moe._routed_experts(x, top_p, ids, *stacks, num_experts=experts, first=first)
-        return jnp.sum(out * seed), (out, rows, overflow)
+        out, rows, *ran = moe._routed_experts(x, top_p, ids, *stacks, num_experts=experts, first=first)
+        return jnp.sum(out * seed), (out, rows, ran)
 
     def whole(x, top_p, *stacks):
         return run(0, x, top_p, *(jnp.zeros((experts, d, d)).at[first: first + count].set(w) for w in stacks))
 
     (_, (want, want_rows, none)), want_grads = jax.value_and_grad(whole, argnums=range(5), has_aux=True)(x, top_p, *stacks)
-    (_, (got, rows, overflow)), grads = jax.value_and_grad(
+    (_, (got, rows, ran)), grads = jax.value_and_grad(
         functools.partial(run, first), argnums=range(5), has_aux=True)(x, top_p, *stacks)
-    assert none == [] and int(overflow[0][0]) == 0
+    # chunks run, row tiles the combine multiplied, row tiles those chunks have
+    by_hand = {"none": [0, 0, 0], "inside_a_tile": [1, 2, 4], "three_chunks": [3, 12, 12]}.get(
+        load, [1, -(-held_rows // 64), 4])
+    assert none == [] and ran[0][0].tolist() == by_hand
     np.testing.assert_array_equal(rows, want_rows)
-    assert _rel(got, want) < 1e-5
+    assert _rel(got, want) < 1e-5 if held_rows else float(jnp.max(jnp.abs(got))) == float(jnp.max(jnp.abs(want))) == 0.0
     for name, g, w in zip(("x", "top_p", "w_gate", "w_up", "w_down"), grads, want_grads):
-        assert _rel(g, w) < 1e-5, name
-    # the share's program moves a chunk's rows, never the 1,024
+        assert _rel(g, w) < 1e-5 if held_rows else float(jnp.max(jnp.abs(g))) == 0.0, name
+    # the share's program moves a chunk's rows, never all the routed ones
     program = str(jax.make_jaxpr(functools.partial(run, first))(x, top_p, *stacks))
-    assert "f32[256,128]" in program and "f32[1024,128]" not in program
-    assert "f32[1024,128]" in str(jax.make_jaxpr(whole)(x, top_p, *stacks))
+    assert "f32[256,128]" in program and f"f32[{tokens * top_k},128]" not in program
+    assert f"f32[{tokens * top_k},128]" in str(jax.make_jaxpr(whole)(x, top_p, *stacks))
 
 
-@pytest.mark.parametrize("crowded", [False, True], ids=["spread", "crowded"])
-def test_a_share_past_its_bound_drops_no_row(x, crowded):
-    """Experts 8 and 9 of 32 (bound 256 of 1,024 routed rows). With a bias
-    that makes every token choose both, the share takes 512 rows, two chunks:
-    the layer is still the reference's restricted to the share, rows counted."""
-    layer = _moe(num_experts=32, held_experts=(8, 2))
+@pytest.mark.parametrize("load", ["spread", "crowded", "idle", "three_chunks"])
+def test_a_share_past_its_bound_drops_no_row(x, load, monkeypatch):
+    """Experts 8 and 9 of 32 (bound 256 of 1,024 routed rows; a row tile of
+    the combine cut to 64 for the test). With a bias that makes every token
+    choose both, the share takes 512 rows, two chunks; with one that keeps
+    every token off them, none; of 64 experts over 384 tokens, 768 rows,
+    three chunks: the layer is still the reference's restricted to the
+    share, rows and the combine's row tiles counted."""
+    monkeypatch.setattr(moe, "_ADD_TILE", 64)
+    experts = 64 if load == "three_chunks" else 32
+    if load == "three_chunks":
+        x = jax.random.normal(jax.random.PRNGKey(11), (3, SEQ, 64))
+    layer = _moe(num_experts=experts, held_experts=(8, 2))
     params = layer.init(jax.random.PRNGKey(8), x)["params"]
-    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(9), (32,))
-    bias = {"bias": bias.at[8:10].add(10.0) if crowded else bias}
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(9), (experts,))
+    bias = {"bias": bias.at[8:10].add({"spread": 0.0, "idle": -10.0}.get(load, 10.0))}
 
     def program(params, x):
         out, mods = layer.apply({"params": params, "router_bias": bias}, x, mutable=["moe_stats"])
@@ -280,8 +314,16 @@ def test_a_share_past_its_bound_drops_no_row(x, crowded):
     (_, y), want = jax.value_and_grad(ref, argnums=(0, 1), has_aux=True)(params, x)
     held_rows = int(stats["held_rows"][0])
     assert held_rows == int(jnp.sum((stats["expert_ids"][0] == 8) | (stats["expert_ids"][0] == 9)))
-    assert (held_rows == 512, int(stats["held_overflow"][0])) == (crowded, int(crowded)) and (crowded or held_rows < 256)
+    assert moe._held_bound(x.shape[0] * SEQ * 4, 2, experts) == 256
+    chunks = {"crowded": 2, "idle": 0, "three_chunks": 3}.get(load, 1)
+    assert held_rows == 256 * chunks if load != "spread" else 0 < held_rows < 256
+    assert int(stats["held_overflow"][0]) == int(chunks > 1)
+    tiles = 4 * chunks if load != "spread" else -(-held_rows // 64)
+    assert int(stats["held_row_tiles"][0]) == tiles
+    assert float(stats["held_tile_share"][0]) == (tiles / (4 * chunks) if chunks else 0.0)  # 1.0 for full chunks
     assert _rel(out, y) < REL_TOL and _rel(grads, want) < REL_TOL
+    for name in moe.EXPERT_WEIGHTS:  # the three stacks alone, which the sum over all leaves would drown
+        assert _rel(grads[0][name], want[0][name]) < REL_TOL if chunks else not jnp.any(grads[0][name]), name
 
 
 @pytest.mark.parametrize("shards", [4, 8])
@@ -473,6 +515,7 @@ def test_step_trains_both_losses_and_moves_the_biases(tiny_step):
     assert losses[-1][0] < losses[0][0] and losses[-1][1] < losses[0][1]
     assert float(metrics["moe_load_max_over_mean"]) > 1.0
     assert int(metrics["moe_held_overflow"]) == 0  # of three layers that hold a share
+    assert float(metrics["moe_held_tile_share"]) == 1.0  # a toy's chunk is one row tile of the combine
     for path in (("block_1", "moe"), ("block_2", "moe"), ("mtp", "block", "moe")):
         bias = state.router_bias
         for key in path:

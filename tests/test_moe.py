@@ -3,8 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from hops_tpu.models import moe as moe_lib
 from hops_tpu.models.moe import MoEMLP, expert_specs
 from hops_tpu.parallel import mesh as mesh_lib
 
@@ -105,3 +107,51 @@ def test_moe_transformer_lm_trains():
     for _ in range(15):
         state, metrics = step(state, {"tokens": tokens})
     assert float(metrics["loss"]) < float(first["loss"])
+
+
+ADD_TILE, ADD_BOUND = 32, 4 * 32
+
+
+@pytest.mark.parametrize("live", [0, 1, ADD_TILE - 1, ADD_TILE, ADD_TILE + 1, ADD_BOUND])
+def test_add_rows_multiplies_the_live_row_tiles_and_no_other(live, monkeypatch):
+    """A chunk of four row tiles of which the leading ``live`` rows are held:
+    the sum into the tokens is ``segment_sum`` of the live rows in float32,
+    whatever the rows past them hold (NaN here: what a grouped matmul leaves
+    past its groups is unspecified), and the products that ran are those of
+    the tiles up to the last live row, one a part: a dead tile is skipped,
+    not multiplied by zeros."""
+    monkeypatch.setattr(moe_lib, "_ADD_TILE", ADD_TILE)
+    products, dot = [], jax.lax.dot
+
+    def counted(*args, **kwargs):
+        jax.debug.callback(lambda: products.append(1))
+        return dot(*args, **kwargs)
+
+    monkeypatch.setattr(jax.lax, "dot", counted)
+    n_tokens, width = 24, 16
+    keys = jax.random.split(jax.random.PRNGKey(live), 3)
+    token = jax.random.randint(keys[0], (ADD_BOUND,), 0, n_tokens)
+    held = (jnp.arange(ADD_BOUND) < live)[:, None]
+    rows = jnp.where(held, jax.random.normal(keys[1], (ADD_BOUND, width)), jnp.nan)
+    weights = jnp.where(held, jax.random.normal(keys[2], (ADD_BOUND, 3)), jnp.nan)
+    want = [jax.ops.segment_sum(part[:live], token[:live], n_tokens) for part in (rows, weights)]
+
+    got = moe_lib._add_rows([jnp.ones((n_tokens, width)), jnp.zeros((n_tokens, 3))], (rows, weights), token,
+                            jnp.int32(live))
+    jax.effects_barrier()
+    assert [g.dtype for g in got] == [jnp.float32] * 2
+    np.testing.assert_allclose(got[0] - 1.0, want[0], atol=1e-6)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-6)
+    assert len(products) == 2 * -(-live // ADD_TILE) == 2 * int(moe_lib._add_tiles(live, ADD_BOUND))
+
+
+def test_add_rows_counts_a_last_tile_that_passes_the_end_once():
+    """A chunk its tile does not divide (a share of 3 of 128 experts makes
+    one of 4,608 rows): the last tile starts early and skips the rows the
+    tile before it added."""
+    bound, n_tokens = moe_lib._ADD_TILE + 100, 8
+    token = jnp.arange(bound) % n_tokens
+    rows = jnp.ones((bound, 1))
+    (got,) = moe_lib._add_rows([jnp.zeros((n_tokens, 1))], (rows,), token, jnp.int32(bound))
+    np.testing.assert_array_equal(got[:, 0], jnp.bincount(token, length=n_tokens).astype(jnp.float32))
+    assert int(moe_lib._add_tiles(bound, bound)) == 2

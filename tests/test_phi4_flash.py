@@ -366,8 +366,16 @@ def lowered_digest(toy):
 @pytest.mark.parametrize("toy", sorted(TOYS))
 def test_defaults_keep_the_parents_lowered_step(toy):
     """``tests/data/transformer_lm_parent_lowered.json`` was written with
-    ``lowered_digest``: ``phi4_shaped`` and ``ling_shaped`` (with their trees) by
+    ``lowered_digest``: ``phi4_shaped`` (with its tree, and ``ling_shaped``'s) by
     PR 43's parent (3d29bff), before ``Block`` was rebuilt round ``LayerSpec``;
+    ``ling_shaped`` by PR 45 on top of 754fdf9, because that toy holds a share
+    of the experts (``moe_held_experts=(4, 4)``) and what a held share lowers to
+    changed by design: the 0/1 product that adds a chunk's rows to their tokens
+    is a loop over the chunk's live row tiles inside the loop over chunks,
+    forward and backward, and the layer sows two more statistics
+    (``held_row_tiles``, ``held_tile_share``; 10,076 lines where 754fdf9 wrote
+    9,721); the four toys without a held share read their older digests, which
+    is the proof that no other cell's step moved;
     ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
     PR 31's parent (no ``remat``: the fields added since, and the names
     ``remat`` keeps values by, leave their lowered text as it was);
