@@ -30,6 +30,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from hops_tpu.models.differential_attention import build_differential_attention
@@ -137,6 +138,47 @@ def rotary_embedding(x: jax.Array, positions: jax.Array, base: float = 10000.0) 
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotate_pairs(x, cos, sin, first):
+    """The pairs ``(first + 2i, first + 2i + 1)`` of ``x``'s last axis turned by
+    the angles whose ``cos`` / ``sin`` (``(seq, pairs)``) are given, the channels
+    before ``first`` as they are: `rotary_embedding`'s float32 arithmetic on
+    the same numbers, cast once. A pair's other member comes from a product
+    with a 0/1 matrix (exact: one term a sum), which XLA fuses with the
+    arithmetic and the write into ``x``'s own lanes; the strided slices and the
+    stack of `rotary_embedding` cost six passes over a 32-head ``q`` going
+    forward and ten going back, two of them in float32 (compile for a v5e,
+    PR 46: 3.2 of the 8.3 GB a layer moved under ``mla_attn``). Written into
+    ``x`` and not beside a copy of its first channels, the step's temporaries
+    are 0.21 GB smaller (4.762 against 4.969 GB: compile, PR 46). Going back
+    is the turn the other way."""
+    rope = x[..., first:]
+    d = rope.shape[-1]
+    # HIGHEST: a float32 ``x`` crosses the MXU whole (bf16 operands take one pass whatever the precision)
+    swapped = jnp.einsum("...d,de->...e", rope, jnp.asarray(np.eye(d)[np.arange(d) ^ 1], x.dtype),
+                         precision=jax.lax.Precision.HIGHEST)
+    by, across = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1) * jnp.tile(jnp.asarray([-1.0, 1.0]), d // 2)
+    turned = (rope.astype(jnp.float32) * by + swapped.astype(jnp.float32) * across).astype(x.dtype)
+    return turned if first == 0 else jax.lax.dynamic_update_slice_in_dim(x, turned, first, axis=-1)
+
+
+_rotate_pairs.defvjp(
+    lambda x, cos, sin, first: (_rotate_pairs(x, cos, sin, first), (cos, sin)),
+    lambda first, res, g: (_rotate_pairs(g, res[0], -res[1], first), None, None),
+)
+
+
+def rotate_from(x: jax.Array, positions: jax.Array, base: float, first: int = 0) -> jax.Array:
+    """RoPE over the interleaved pairs of ``x``'s channels from ``first`` on
+    (``(batch, heads, seq, d)``, ``positions`` ``(seq,)``): what
+    `rotary_embedding` gives for those channels, to the bit, beside the
+    untouched ones, in one pass."""
+    rope = x.shape[-1] - first
+    inv_freq = 1.0 / (base ** (jnp.arange(0, rope, 2, dtype=jnp.float32) / rope))
+    angles = positions[:, None].astype(jnp.float32) * inv_freq
+    return _rotate_pairs(x, jnp.cos(angles), jnp.sin(angles), first)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
@@ -166,6 +208,25 @@ class LayerNorm(nn.Module):
 
 
 NORMS = {"rms": RMSNorm, "layer": LayerNorm}
+
+
+class _TwoPartRMSNorm(nn.Module):
+    """`RMSNorm` over the channels of ``[a | b]`` without building it: ``b``
+    broadcasts against ``a`` on every axis but the last (a latent layer's one
+    rotary key against its heads' keys) and comes back as large as ``a``'s rows.
+    One ``scale`` as wide as both, so the parameter is `RMSNorm`'s."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, a, b):
+        width = a.shape[-1] + b.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (width,))
+        a32, b32 = a.astype(jnp.float32), b.astype(jnp.float32)
+        square = jnp.sum(a32 * a32, axis=-1, keepdims=True) + jnp.sum(b32 * b32, axis=-1, keepdims=True)
+        inv = jax.lax.rsqrt(square / width + self.eps)
+        return (a32 * inv * scale[: a.shape[-1]]).astype(self.dtype), (b32 * inv * scale[a.shape[-1]:]).astype(self.dtype)
 
 
 class Attention(nn.Module):
@@ -539,6 +600,11 @@ class Attention(nn.Module):
         return self._project_out(o, b, s, dm)
 
 
+def _latent_flash(q, k_nope, k_rope, v=None):
+    """A latent layer's flash call over the arrays `per_shard` splits by batch row."""
+    return flash_attention(q, (k_nope, k_rope), v, causal=True)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) for
     training, in two published forms that two options tell apart:
@@ -546,7 +612,7 @@ class LatentAttention(nn.Module):
         q = W_q x -> (heads, nope + rope)
         [c | k_rope] = W_kva x  (kv_rank + rope)     c <- RMSNorm(c)
         [k_nope | v] = W_kvb c -> (heads, nope + value)
-        k_h = [k_nope_h | k_rope]                     one k_rope for all heads
+        k_h = [k_nope_h | k_rope]                     one k_rope for all heads (never built: see below)
         q_h, k_h <- RMSNorm(q_h), RMSNorm(k_h)        ``qk_norm``: over a head's nope + rope channels, one learned scale each
         rotate the rope part of q_h and k_h           interleaved pairs, base ``rope_base``
         o_h = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
@@ -559,8 +625,17 @@ class LatentAttention(nn.Module):
     publishes it): no ``gate``, ``q_norm`` or ``k_norm`` parameter exists and
     none of their operations runs.
 
-    The flash kernels take keys ``nope + rope`` wide beside values ``value``
-    wide. Its parts enter ``telemetry.spans.MLA_SCOPES`` in either form."""
+    No 32-head copy of K is built (PR 46): the flash kernels take the keys in
+    two parts beside ``q``, which stays one array ``nope + rope`` wide. In
+    DeepSeek-V3's form the ONE rotary key is rotated as ``(b, 1, s, rope)`` and
+    handed over with ``W_kvb c`` as the projection wrote it, ``[k_nope | v]``
+    a head (``flash_attention(q, (kv, k_rope), None)``); in Ling's the QK norm
+    spans a head's nope + rope channels, so the rotary part differs by head by
+    a factor a position and goes as ``(b, h, s, rope)`` beside ``k_nope`` and
+    ``v`` (`_TwoPartRMSNorm` norms the two parts without putting them side by
+    side). The rotations are `rotate_from`'s: one pass over ``q``.
+    ``hops_tpu_train_flash_keys_total{keys}`` says which form a step traced.
+    Its parts enter ``telemetry.spans.MLA_SCOPES`` in either form."""
 
     num_heads: int
     kv_rank: int
@@ -596,20 +671,22 @@ class LatentAttention(nn.Module):
                 gate = jax.nn.sigmoid(dense(h, "gate", jnp.float32)(x.astype(jnp.float32)))
 
         with jax.named_scope(scope_attn):
-            k_rope = jnp.broadcast_to(latent[:, :, None, self.kv_rank:], (b, s, h, rope))
-            k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
-            if self.qk_norm:
-                q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(q)
-                k = RMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(k)
-            q, k, v = (jnp.moveaxis(t, 2, 1) for t in (q, k, kv[..., nope:]))  # (b, h, s, d)
             pos = jnp.arange(s)
 
-            def rotate(t):
-                return jnp.concatenate([t[..., :nope], rotary_embedding(t[..., nope:], pos, self.rope_base)], axis=-1)
+            def rotate(t, first=0):  # (b, heads, s, first + rope)
+                return rotate_from(t, pos, self.rope_base, first)
 
-            q, k = rotate(q), rotate(k)
+            k_rope = latent[:, None, :, self.kv_rank:]  # (b, 1, s, rope): ONE for all heads
+            kv = jnp.moveaxis(kv, 2, 1)  # (b, h, s, nope + value), as XLA lays the projection's result out
+            if self.qk_norm:
+                q = RMSNorm(self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+                k_nope, k_rope = _TwoPartRMSNorm(self.norm_eps, dtype=self.dtype, name="k_norm")(kv[..., :nope], k_rope)
+                k, v = (k_nope, rotate(k_rope)), kv[..., nope:]
+            else:
+                k, v = (kv, rotate(k_rope)), None  # the values ride behind the keys' lanes
+            q = rotate(jnp.moveaxis(q, 2, 1), nope)
             if self.attention_impl == "flash":
-                o = per_shard(functools.partial(flash_attention, causal=True), op="flash")(q, k, v)
+                o = per_shard(_latent_flash, op="flash")(q, *k, *(() if v is None else (v,)))
             else:
                 o = attention_reference(q, k, v, causal=True)
 

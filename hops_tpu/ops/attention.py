@@ -31,6 +31,32 @@ flash-attention-2's split into a dQ and a dK/dV kernel, each of which
 made the scores, the exponentials, dP and dS of every sub-tile: 7.47 ->
 5.61 ms a layer at the Phi-3 cell's shape (my chip runs, PR 41).
 
+Operand forms (`_Keys`, read off the operands while tracing; one algorithm,
+one band walk, one online softmax and one set of scratch sums for all):
+
+- ``flash_attention(q, k, v)``: keys in one array as wide as the queries,
+  values of their own width. Phi-3, OLMoE, the hybrid's full layer and
+  Phi-4-flash's differential calls; the kernels' bodies, block specs and
+  aliases are what they were before the other forms came (PR 46;
+  ``tests/data/flash_whole_key_jaxpr.json``).
+- ``flash_attention(q, (k_nope, k_rope), v)``: a latent-attention layer's
+  keys in two parts, ``k_nope`` ``(b, h, s, d_nope)`` and a rotary part
+  ``(b, 1, s, d_rope)`` that the heads of a batch row share (its block's
+  index ignores the head) or ``(b, h, s, d_rope)``; ``q`` stays one array
+  ``d_nope + d_rope`` wide. A sub-tile's two parts are put side by side in
+  VMEM and contracted once, so a score is the whole-key call's to the bit;
+  dK is one float32 sum whose lane ranges leave as ``dk_nope`` and a
+  per-head ``dk_rope`` (XLA adds a shared key's heads). Ling-3.0-flash (a
+  rotary part a head: its per-head QK norm scales it by head).
+- ``flash_attention(q, (kv, k_rope), None)``: the same with the values behind
+  ``k_nope``'s lanes in one array ``(b, h, s, d_nope + d_value)``, as the
+  layer's second projection writes it; ``[dk_nope | dv]`` leaves as one array
+  in that array's buffer. Kanana-2 (DeepSeek-V3's form, one shared rotary key).
+  Widths that are not whole 128-lane tiles are split by XLA into the form above.
+
+Below `_XLA_FASTER_BELOW` keys and in `attention_reference` the parts are
+put side by side by XLA (`whole_keys`): that path is the numeric ground truth.
+
 For cross-device sequence parallelism see
 ``hops_tpu.parallel.ringattention`` which rotates K/V chunks over the
 ICI ring and feeds each local chunk through this kernel's math.
@@ -49,7 +75,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_SUBTILES, keep
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_KEYS, COUNTER_TRAIN_FLASH_SUBTILES, keep
 
 NEG_INF = float("-inf")
 _LANES = 128  # VPU lane width: per-row stats are broadcast across lanes
@@ -107,6 +133,7 @@ def attention_reference(
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if window is not None and (not causal or window < 1):
         raise ValueError("window requires causal=True and window >= 1")
+    k, v = whole_keys(q, k, v)
     if q_offset is None:
         q_offset = k.shape[2] - q.shape[2] if causal else 0
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
@@ -151,6 +178,11 @@ _m_subtiles = REGISTRY.counter(
     COUNTER_TRAIN_FLASH_SUBTILES,
     "Sub-tiles of one batch-head in each traced flash kernel, by what the kernel does with them",
     labels=("kernel", "kind"),
+)
+_m_keys = REGISTRY.counter(
+    COUNTER_TRAIN_FLASH_KEYS,
+    "Traced calls of a flash kernel, by the form their keys came in",
+    labels=("keys",),
 )
 
 
@@ -356,9 +388,22 @@ def _online_softmax_update(sc, vb, m_scr, l_scr, acc_scr, p_scale=None):
     m_scr[...] = m_new
 
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale, band,
-):
+def _key_tile(k_refs, cols):
+    """Keys ``cols`` of a grid tile, whole rows: of the one array that holds them,
+    or of a per-head part and a rotary part put side by side in VMEM (the one
+    contraction over ``d_nope + d_rope`` channels that follows is then the
+    whole-key form's, to the bit)."""
+    parts = [ref[0, cols, :] for ref in k_refs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def _lanes(ref, start, width):
+    """Lanes ``[start, start + width)`` of a block, as a ref."""
+    return ref.at[:, :, pl.ds(start, width)]
+
+
+def _fwd_kernel(q_ref, *refs, sm_scale, band, keys):
+    k_refs, v_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = keys.split(refs)
     qi, step = pl.program_id(1), pl.program_id(2)
     first, last = band.key_tiles(qi)
     kj = first + step
@@ -372,7 +417,7 @@ def _fwd_kernel(
     def cell(a, b, shift):
         rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
         s = jax.lax.dot_general(
-            q_ref[0, rows, :], k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            q_ref[0, rows, :], _key_tile(k_refs, cols), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         s = s * sm_scale
@@ -433,17 +478,18 @@ def _bwd_p_ds(q, kb, do, vb, lse, delta, shift, sm_scale, window):
     return p, p * (dp - delta) * sm_scale
 
 
-def _bwd_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_scr, dk_scr, dv_scr, *, sm_scale, band,
-):
+def _bwd_kernel(q_ref, *refs, sm_scale, band, keys):
     """dQ, dK and dV of one batch-head from one walk of the band: a
     sub-tile's ``p`` and ``ds`` are made once and feed all three sums.
     dK and dV gather over the query steps of a key tile as the grid
     runs; dQ gathers over the key tiles, which are the grid's outer axis,
     so its float32 sum stays in VMEM for the whole batch-head (`_bwd_call`
     sizes it) and leaves with the head's last step. A row of dQ meets its
-    key tiles, and inside a tile its sub-tiles, in rising order."""
+    key tiles, and inside a tile its sub-tiles, in rising order. Keys in
+    two parts are one tile in VMEM (`_key_tile`) and dK one sum, whose lane
+    ranges leave as the parts' cotangents."""
+    k_refs, v_ref, (do_ref, lse_ref, delta_ref, dq_ref, *refs) = keys.split(refs)
+    dk_refs, dv_ref, (dq_scr, dk_scr, dv_scr) = keys.split(refs)
     kj, step = pl.program_id(1), pl.program_id(2)
     first, last = band.query_tiles(kj)
     qi = first + step
@@ -463,7 +509,7 @@ def _bwd_kernel(
         rows, cols = _sub(a, band.sub_q), _sub(b, band.sub_k)
         at = pl.ds(pl.multiple_of(qi * band.block_q + a * band.sub_q, band.sub_q), band.sub_q)
         q, do = _f32(q_ref[0, rows, :]), _f32(do_ref[0, rows, :])
-        kb = _f32(k_ref[0, cols, :])
+        kb = _f32(_key_tile(k_refs, cols))
         p, ds = _bwd_p_ds(
             q, kb, do, _f32(v_ref[0, cols, :]), lse_ref[0, :, at], delta_ref[0, :, at],
             shift, sm_scale, band.window,
@@ -486,7 +532,12 @@ def _bwd_kernel(
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        lane = 0
+        for dk_ref in dk_refs:
+            width = dk_ref.shape[-1]
+            part = dk_scr[...] if len(dk_refs) == 1 else dk_scr[:, lane:lane + width]
+            dk_ref[0] = part.astype(dk_ref.dtype)
+            lane += width
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
     @pl.when(head_end)
@@ -504,10 +555,72 @@ def _flat(x):
     return x.reshape(b * h, s, d)
 
 
-def _band_specs(band: _Band, d: int, d_v: int | None = None):
-    """BlockSpecs of the two grid orders, for queries and keys ``d`` wide and
-    values (and the output) ``d_v`` wide (None: ``d`` too; a latent-attention
-    layer's keys carry a rotary part the values lack). Under ``(bh, query tile, key
+@dataclasses.dataclass(frozen=True)
+class _Keys:
+    """How a flash call is handed its keys, read off its operands while
+    tracing: in one array ``d`` wide (``d_nope`` None), or in two parts, ``d_nope``
+    channels a head and a rotary part of the other ``d - d_nope`` that the
+    ``heads`` heads of a batch row share (1: every head has its own). ``fused``:
+    the per-head part's array carries the values behind its ``d_nope`` lanes
+    (a latent layer's ``W_kvb c``, as the projection wrote it) and no ``v`` comes."""
+
+    d_nope: int | None = None
+    heads: int = 1
+    fused: bool = False
+
+    @classmethod
+    def of(cls, q, k, v) -> _Keys:
+        if not isinstance(k, tuple):
+            return cls()
+        heads = math.prod(q.shape[:-2]) // math.prod(k[1].shape[:-2])
+        return cls(q.shape[-1] - k[1].shape[-1], heads, v is None)
+
+    @property
+    def label(self) -> str:
+        if self.d_nope is None:
+            return "whole"
+        return "two_part_shared" if self.heads > 1 else "two_part_per_head"
+
+    def specs(self, spec, arrays, share=True) -> list:
+        """A BlockSpec per array of `arrays` (the leaves of ``(k, v)``, the kernels'
+        order), the shared rotary part's by batch row."""
+        return [
+            spec["shared" if share and self.heads > 1 and i == 1 else "k"](x.shape[-1])
+            for i, x in enumerate(arrays)
+        ]
+
+    def split(self, refs):
+        """A kernel's refs from the keys on: (refs of the keys' parts, ref of
+        the values, the refs after them)."""
+        if self.d_nope is None:
+            return refs[:1], refs[1], refs[2:]
+        if not self.fused:
+            return refs[:2], refs[2], refs[3:]
+        kv, k_rope = refs[:2]
+        values = _lanes(kv, self.d_nope, kv.shape[-1] - self.d_nope)
+        return (_lanes(kv, 0, self.d_nope), k_rope), values, refs[2:]
+
+
+def whole_keys(q, k, v):
+    """``(k, v)`` as one array each: two-part keys side by side with the rotary
+    part on every head, the values out of a fused ``[k_nope | v]``."""
+    if not isinstance(k, tuple):
+        return k, v
+    k_nope, k_rope = k
+    if v is None:
+        d_nope = q.shape[-1] - k_rope.shape[-1]
+        k_nope, v = k_nope[..., :d_nope], k_nope[..., d_nope:]
+    k_rope = jnp.broadcast_to(k_rope, (*k_nope.shape[:-1], k_rope.shape[-1]))
+    return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def _band_specs(band: _Band, heads: int = 1):
+    """What makes a BlockSpec of a given width under each of the two grid
+    orders: for rows of queries (``q``: the queries, the output, dO), rows of
+    keys (``k``: keys, values and their cotangents; a latent-attention layer's
+    keys carry a rotary part the values lack) and rows of a rotary key that
+    the ``heads`` heads of a batch row share (``shared``: the block's first
+    index is the row's, whatever the head). Under ``(bh, query tile, key
     step)`` (forward) the K/V block of step ``j`` is the ``j``-th key
     tile of the query tile's span; under ``(bh, key tile, query step)``
     (backward) the Q/dO block is the ``j``-th query tile of the key tile's
@@ -522,25 +635,28 @@ def _band_specs(band: _Band, d: int, d_v: int | None = None):
             return b, jnp.clip(jnp.minimum(first + j, last), 0, n - 1), 0
         return index
 
+    def of_row(index):
+        return lambda b, i, j: (b // heads, *index(b, i, j)[1:])
+
+    def rows(block, index):
+        return lambda width: pl.BlockSpec((1, block, width), index)
+
     k_of_q = stepped(band.key_tiles, band.seq_k // band.block_k)
     q_of_k = stepped(band.query_tiles, band.seq_q // band.block_q)
     own = lambda b, i, j: (b, i, 0)
     whole = lambda b, i, j: (b, 0, 0)
     stats = pl.BlockSpec((1, 1, band.seq_q), whole)
-    d_v = d if d_v is None else d_v
     q_major = {
-        "q": pl.BlockSpec((1, band.block_q, d), own),
-        "o": pl.BlockSpec((1, band.block_q, d_v), own),
-        "k": pl.BlockSpec((1, band.block_k, d), k_of_q),
-        "v": pl.BlockSpec((1, band.block_k, d_v), k_of_q),
+        "q": rows(band.block_q, own),
+        "k": rows(band.block_k, k_of_q),
+        "shared": rows(band.block_k, of_row(k_of_q)),
         "stats": stats,
     }
     k_major = {
-        "q": pl.BlockSpec((1, band.block_q, d), q_of_k),
-        "o": pl.BlockSpec((1, band.block_q, d_v), q_of_k),
-        "k": pl.BlockSpec((1, band.block_k, d), own),
-        "v": pl.BlockSpec((1, band.block_k, d_v), own),
-        "dq": pl.BlockSpec((1, band.seq_q, d), whole),
+        "q": rows(band.block_q, q_of_k),
+        "k": rows(band.block_k, own),
+        "shared": rows(band.block_k, of_row(own)),
+        "dq": rows(band.seq_q, whole),
         "stats": stats,
     }
     return q_major, k_major
@@ -559,13 +675,15 @@ _per_geometry = functools.partial(
 @_per_geometry
 def _fwd_call(q, k, v, band, sm_scale, interpret):
     bh, seq_q, d = q.shape
-    d_v = v.shape[-1]
-    spec, _ = _band_specs(band, d, d_v)
+    keys = _Keys.of(q, k, v)
+    kv = jax.tree.leaves((k, v))
+    d_v = kv[0].shape[-1] - keys.d_nope if keys.fused else v.shape[-1]
+    spec, _ = _band_specs(band, keys.heads)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, band=band),
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, band=band, keys=keys),
         grid=(bh, seq_q // band.block_q, band.key_steps()),
-        in_specs=[spec["q"], spec["k"], spec["v"]],
-        out_specs=[spec["o"], spec["stats"]],
+        in_specs=[spec["q"](d), *keys.specs(spec, kv)],
+        out_specs=[spec["q"](d_v), spec["stats"]],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
@@ -578,7 +696,7 @@ def _fwd_call(q, k, v, band, sm_scale, interpret):
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(q, *kv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -588,10 +706,11 @@ def _flash(q, k, v, band, sm_scale, interpret):
 
 def _flash_fwd(q, k, v, band, sm_scale, interpret):
     _count_subtiles("fwd", band)
-    o, lse = _fwd_call(_flat(q), _flat(k), _flat(v), band, sm_scale, interpret)
+    _m_keys.inc(keys=_Keys.of(q, k, v).label)
+    o, lse = _fwd_call(*jax.tree.map(_flat, (q, k, v)), band, sm_scale, interpret)
     # kept by a block's remat: the backward kernel then takes q, k, v from the
     # second forward and this call is not made again
-    o, lse = keep(o.reshape(*q.shape[:-1], v.shape[-1]), "flash_out"), keep(lse, "flash_lse")
+    o, lse = keep(o.reshape(*q.shape[:-1], o.shape[-1]), "flash_out"), keep(lse, "flash_lse")
     return o, (q, k, v, o, lse)
 
 
@@ -624,11 +743,12 @@ def _query_slices(band: _Band, d: int, itemsize: int) -> int:
 def _flash_bwd(band, sm_scale, interpret, res, g):
     _count_subtiles("bwd", band)
     q, k, v, o, lse = res
-    qf, kf, vf, of, gf = _flat(q), _flat(k), _flat(v), _flat(o), _flat(g)
+    _m_keys.inc(keys=_Keys.of(q, k, v).label)
+    (qf, kf, vf), of, gf = jax.tree.map(_flat, (q, k, v)), _flat(o), _flat(g)
     delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), axis=-1)[:, None, :]
     n = _query_slices(band, q.shape[-1], q.dtype.itemsize)
     if n == 1:
-        dq, dk, dv = _bwd_call(qf, kf, vf, gf, lse, delta, band, sm_scale, interpret)
+        dq, dkv = _bwd_call(qf, kf, vf, gf, lse, delta, band, sm_scale, interpret)
     else:
         # a slice of the queries is the same call further down the band;
         # dK and dV add up over the slices in float32
@@ -643,26 +763,30 @@ def _flash_bwd(band, sm_scale, interpret, res, g):
             for at in range(0, band.seq_q, rows)
         ]
         dq = jnp.concatenate([part[0] for part in parts], axis=1)
-        dk = sum(_f32(part[1]) for part in parts).astype(k.dtype)
-        dv = sum(_f32(part[2]) for part in parts).astype(v.dtype)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+        dkv = jax.tree.map(lambda *x: sum(map(_f32, x)).astype(x[0].dtype), *(part[1] for part in parts))
+
+    def like(x, dx):
+        # a shared rotary key's cotangent leaves the kernel a head at a time
+        dx = dx.reshape(x.shape[0], -1, *x.shape[2:])
+        return dx if dx.shape == x.shape else _f32(dx).sum(axis=1, keepdims=True).astype(x.dtype)
+
+    return (dq.reshape(q.shape), *jax.tree.map(like, (k, v), dkv))
 
 
 @_per_geometry
 def _bwd_call(q, k, v, do, lse, delta, band, sm_scale, interpret):
     bh, seq_q, d = q.shape
-    d_v = v.shape[-1]
-    _, spec = _band_specs(band, d, d_v)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, sm_scale=sm_scale, band=band),
+    keys = _Keys.of(q, k, v)
+    kv = jax.tree.leaves((k, v))
+    d_v = do.shape[-1]
+    _, spec = _band_specs(band, keys.heads)
+    out_shape = [jax.ShapeDtypeStruct((bh, *x.shape[1:]), x.dtype) for x in kv]
+    dq, *dkv = pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, band=band, keys=keys),
         grid=(bh, band.seq_k // band.block_k, band.query_steps()),
-        in_specs=[spec["q"], spec["k"], spec["v"], spec["o"], spec["stats"], spec["stats"]],
-        out_specs=[spec["dq"], spec["k"], spec["v"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, band.seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, band.seq_k, d_v), v.dtype),
-        ],
+        in_specs=[spec["q"](d), *keys.specs(spec, kv), spec["q"](d_v), spec["stats"], spec["stats"]],
+        out_specs=[spec["dq"](d), *keys.specs(spec, kv, share=False)],
+        out_shape=[jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype), *out_shape],
         scratch_shapes=[
             pltpu.VMEM((seq_q, d), jnp.float32),
             pltpu.VMEM((band.block_k, d), jnp.float32),
@@ -681,11 +805,16 @@ def _bwd_call(q, k, v, do, lse, delta, band, sm_scale, interpret):
         # live at once, where dQ's could go before the dK/dV call came: not
         # aliased, the Phi-3 step's temporaries read 4.621 GB against the
         # pair's 4.521 and `peak_hbm_gb` 12.600 against 12.500; aliased
-        # 4.520 (compile and my chip runs, PR 41)
-        input_output_aliases={0: 0, 1: 1, 2: 2},
+        # 4.520 (compile and my chip runs, PR 41). A rotary key that heads
+        # share is read by every head and its cotangent is a head's own:
+        # that pair has no buffer in common
+        input_output_aliases={
+            i: i for i, (x, dx) in enumerate(zip((q, *kv), (q, *out_shape))) if x.shape == dx.shape
+        },
         interpret=interpret,
         name="flash_bwd",
-    )(q, k, v, do, lse, delta)
+    )(q, *kv, do, lse, delta)
+    return dq, jax.tree.unflatten(jax.tree.structure((k, v)), dkv)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -728,6 +857,9 @@ def flash_attention(
 ) -> jax.Array:
     """Blocked flash attention over ``(batch, heads, seq, head_dim)``; ``v``
     may be of another width than ``q`` and ``k`` (the output is ``v``'s).
+    ``k`` may come in two parts, ``(k_nope, k_rope)`` with the rotary part's
+    head axis 1 (shared) or ``heads``, and then ``v`` may be None: the values
+    ride behind ``k_nope``'s lanes (the module docstring has the forms).
 
     ``window`` (causal only): query p attends keys in
     ``[p - window + 1, p]`` — Mistral-style sliding-window attention.
@@ -751,7 +883,7 @@ def flash_attention(
         raise ValueError("window requires causal=True")
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    seq_q, seq_k = q.shape[2], k.shape[2]
+    seq_q, seq_k = q.shape[2], (k[0] if isinstance(k, tuple) else k).shape[2]
     if q_offset is None:
         q_offset = seq_k - seq_q if causal else 0
     forced = block_q is not None or block_k is not None
@@ -799,6 +931,12 @@ def flash_attention(
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if isinstance(k, tuple) and v is None:
+        d_nope = q.shape[-1] - k[1].shape[-1]
+        if d_nope % _LANES or k[0].shape[-1] % _LANES:
+            # Mosaic takes a block's lane range as a ref at whole 128-lane
+            # tiles only: other widths leave ``[k_nope | v]`` as two arrays
+            k, v = (k[0][..., :d_nope], k[1]), k[0][..., d_nope:]
     band = _Band(
         seq_q, seq_k, block_q, block_k, _sub_block(block_q), _sub_block(block_k),
         q_offset, causal, window,

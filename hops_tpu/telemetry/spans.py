@@ -167,6 +167,13 @@ GAUGE_TRAIN_STATE_BYTES = "hops_tpu_train_state_bytes"
 #: ``interior`` | ``edge`` | ``skipped``, the counts of one batch-head per
 #: traced call) are named here.
 COUNTER_TRAIN_FLASH_SUBTILES = "hops_tpu_train_flash_subtiles_total"
+#: One per traced call of a flash kernel, forward or backward, by the form its
+#: keys came in (``ops/attention.py:_Keys``): ``whole`` (one array as wide as
+#: the queries), ``two_part_shared`` (a part per head and ONE rotary part for
+#: the heads of a batch row: latent attention in DeepSeek-V3's form) or
+#: ``two_part_per_head`` (every head its own rotary part: Ling's, whose
+#: per-head QK norm scales it by head).
+COUNTER_TRAIN_FLASH_KEYS = "hops_tpu_train_flash_keys_total"
 #: One per Mosaic call of the gated delta rule traced (``ops/gated_delta.py``:
 #: ``kernel`` = ``gated_delta_local_fwd`` | ``gated_delta_fwd`` |
 #: ``gated_delta_out_fwd`` | ``gated_delta_local_bwd`` | ``gated_delta_bwd``,

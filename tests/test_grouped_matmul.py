@@ -199,6 +199,37 @@ def test_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, batch_heads
     assert not any("flash_bwd_d" in call for call in calls)
 
 
+@pytest.mark.parametrize("rope_heads, fused", [(1, True), (32, False)], ids=["kanana_shared_fused", "ling_per_head"])
+def test_two_part_flash_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, rope_heads, fused):
+    """`flash_fwd` and `flash_bwd` with a latent layer's keys in two parts at
+    32 heads x 8,192 keys, 128 + 64 | 128 (this file holds the one fixture that
+    may load the TPU compiler): Mosaic accepts the two parts put side by side
+    in VMEM, the lane ranges of a `[k_nope | v]` block read and written as refs,
+    a dK sum that leaves in two lane ranges, and the shared rotary key's block
+    index `bh // heads`. Kanana-2's form hands `[k_nope | v]` over as one array
+    and one rotary key a batch row; Ling's a rotary key a head. Nothing runs."""
+    from hops_tpu.ops.attention import flash_attention
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        def shape(heads, d):
+            return jax.ShapeDtypeStruct((1, heads, 8192, d), jnp.bfloat16, sharding=one_chip)
+
+        operands = (shape(32, 192), shape(32, 256 if fused else 128), shape(rope_heads, 64), *(() if fused else (shape(32, 128),)))
+
+        def grads(q, k_nope, k_rope, v=None):
+            return jax.grad(
+                lambda q, k_nope, k_rope, v: flash_attention(q, (k_nope, k_rope), v, causal=True, interpret=False)
+                .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k_nope, k_rope, v)
+
+        text = jax.jit(grads).lower(*operands).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2 and sum("flash_fwd" in call for call in calls) == sum("flash_bwd" in call for call in calls) == 1
+
+
 def test_gated_delta_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     """The rule's five kernels at the hybrid cell's shape (30 heads x 8,192
     tokens x (96, 192), bf16, the default head groups), forward and

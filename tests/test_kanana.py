@@ -93,6 +93,32 @@ def test_ungated_latent_attention_follows_the_reference(x, impl, flash_kernel_at
     assert _rel(got, jax.grad(lambda p: jnp.sum(jnp.square(ref(p, x))))(params)) < REL_TOL
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("first", [0, 16], ids=["every_channel", "behind_16_nope_channels"])
+def test_rotation_in_one_pass_is_rotary_embeddings(first, dtype):
+    """``rotate_from`` (a pair's other member by a 0/1 product, no strided slice)
+    gives ``rotary_embedding``'s numbers to the bit beside the untouched
+    channels; going back it turns the cotangent the other way in float32 and
+    casts once, where the autodiff of ``rotary_embedding`` rounds two terms and
+    adds them in ``dtype``: equal in float32, within an ulp in bfloat16."""
+    from hops_tpu.models.transformer import rotary_embedding, rotate_from
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    t, g = (jax.random.normal(k, (2, HEADS, SEQ, first + 8), jnp.float32).astype(dtype) for k in ks)
+    pos = jnp.arange(SEQ)
+
+    def plain(t):
+        return jnp.concatenate([t[..., :first], rotary_embedding(t[..., first:], pos, 1e6)], axis=-1)
+
+    want, back = jax.vjp(plain, t)
+    got, back_one_pass = jax.vjp(lambda t: rotate_from(t, pos, 1e6, first), t)
+    assert got.dtype == dtype and bool(jnp.all(got == want))
+    exact = jax.vjp(plain, t.astype(jnp.float32))[1](g.astype(jnp.float32))[0]
+    err = lambda d: float(jnp.max(jnp.abs(d.astype(jnp.float32) - exact)))
+    ulp = 2.0 ** -8 * float(jnp.max(jnp.abs(exact))) if dtype == jnp.bfloat16 else 1e-6
+    assert err(back_one_pass(g)[0]) <= ulp and err(back_one_pass(g)[0]) <= err(back(g)[0]) + 1e-6
+
+
 @pytest.mark.parametrize("options", [dict(output_gate=False), dict(qk_norm=False)], ids=lambda o: next(iter(o)))
 def test_each_option_takes_its_own_parameters_only(x, options):
     whole = set(LatentAttention(HEADS, **LATENT, dtype=jnp.float32).init(jax.random.PRNGKey(2), x)["params"])
@@ -451,6 +477,77 @@ def test_the_layer_kind_counter_names_the_form(tiny_step):
     assert held.value(impl="ragged_dot", dispatch="held") - before_held >= 2
     assert any(line.startswith("hops_tpu_train_layer_kinds_total{") and f'kind="{V3_FORM}"' in line
                for line in render_prometheus(REGISTRY).splitlines())  # what /metrics shows
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold (a
+    ``custom_vjp``'s, a ``remat``'s, a jitted builder's), kernel bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(inner)
+
+
+def _rotary_keys_broadcast_to_heads(program):
+    """The ``broadcast_in_dim`` equations under ``mla_attn`` that make ``HEADS``
+    rows of ``rope_dim`` channels out of one."""
+    rope, found = LATENT["rope_dim"], []
+    for eqn in _equations(program.jaxpr):
+        if eqn.primitive.name != "broadcast_in_dim" or "mla_attn" not in str(eqn.source_info.name_stack):
+            continue
+        given, made = eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape
+        if (len(made) == 4 and made[-1] == rope and HEADS in made[1:3]
+                and int(np.prod(made)) == HEADS * int(np.prod(given))):
+            found.append((given, made))
+    return found
+
+
+@pytest.mark.parametrize("form", ["deepseek_v3", "ling", "the_parents_way"])
+def test_the_one_rotary_key_is_not_copied_to_the_heads(x, form, flash_kernel_at_any_length):
+    """The mixer hands the kernels ONE rotary key a batch row (DeepSeek-V3's
+    form: rotated as ``(b, 1, s, rope)``) or scales it by head inside the QK
+    norm's own product (Ling's), and each head's ``k_nope`` where the projection
+    wrote it: forward and backward through the kernels, no equation under
+    ``mla_attn`` makes a key of ``HEADS`` x ``rope_dim`` out of one, as the
+    parent's ``broadcast_to`` did (the third case: what the search finds)."""
+    if form == "the_parents_way":
+        def parents(latent):
+            with jax.named_scope("mla_attn"):
+                return jnp.broadcast_to(latent[:, :, None, 32:], (*latent.shape[:2], HEADS, LATENT["rope_dim"]))
+        assert len(_rotary_keys_broadcast_to_heads(jax.make_jaxpr(parents)(jnp.zeros((2, SEQ, 40))))) == 1
+        return
+    options = dict(output_gate=False, qk_norm=False) if form == "deepseek_v3" else {}
+    mixer = LatentAttention(HEADS, **LATENT, **options, attention_impl="flash", dtype=jnp.float32)
+    params = mixer.init(jax.random.PRNGKey(2), x)["params"]
+    program = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(jnp.square(mixer.apply({"params": p}, x)))))(params)
+    assert sum(eqn.primitive.name == "pallas_call" for eqn in _equations(program.jaxpr)) == 2
+    assert _rotary_keys_broadcast_to_heads(program) == []
+
+
+@pytest.mark.parametrize("keys, args, calls", [
+    ("whole", dict(vocab_size=VOCAB, d_model=64, num_heads=HEADS, num_layers=2, dtype=jnp.float32), 2),
+    ("two_part_shared", {**TINY, "attention_impl": "flash"}, 3),
+    ("two_part_per_head", {**LING, "attention_impl": "flash"}, 2),  # the MTP module's joins with its loss weight
+])
+def test_the_flash_keys_counter_names_the_form_a_step_traces(keys, args, calls, flash_kernel_at_any_length):
+    """``hops_tpu_train_flash_keys_total{keys}``: one per traced flash call,
+    forward or backward, by the form its keys came in; a step counts its own
+    form only (Kanana-2's ``two_part_shared``, Ling's ``two_part_per_head``,
+    every other model's ``whole``)."""
+    from hops_tpu.telemetry.spans import COUNTER_TRAIN_FLASH_KEYS
+
+    counter = REGISTRY.counter(COUNTER_TRAIN_FLASH_KEYS, labels=("keys",))
+    forms = ("whole", "two_part_shared", "two_part_per_head")
+    model = TransformerLM(**args)
+    state = common.create_train_state(model, jax.random.PRNGKey(0), (1, 8), input_dtype=jnp.int32)
+    before = {form: counter.value(keys=form) for form in forms}
+    jax.jit(make_lm_train_step(loss_chunk=32)).lower(state, {"tokens": jnp.zeros((2, SEQ + 1), jnp.int32)})
+    added = {form: counter.value(keys=form) - before[form] for form in forms}
+    assert added[keys] >= 2 * calls and added[keys] % calls == 0, added
+    assert not any(n for form, n in added.items() if form != keys), added
+    assert any(line.startswith(COUNTER_TRAIN_FLASH_KEYS + "{") and f'keys="{keys}"' in line
+               for line in render_prometheus(REGISTRY).splitlines())
 
 
 @pytest.fixture(scope="module")

@@ -364,6 +364,165 @@ def test_resident_dq_at_the_cells_shapes():
     assert slices(131072, 256) == 4 and slices(131072 * 8, 128) == 16
 
 
+# -- keys in two parts (a latent-attention layer's k_nope and its rotary key) --
+
+
+# name: (d_nope, d_rope, d_value), the published widths and the toys'
+_TWO_PART_WIDTHS = {"published_128_64_128": (128, 64, 128), "toy_16_8_16": (16, 8, 16)}
+# name: (the rotary key has one head for all, the values ride behind k_nope in one array)
+_TWO_PART_FORMS = {"shared": (True, False), "shared_fused": (True, True), "per_head": (False, False),
+                   "per_head_fused": (False, True)}
+
+
+def _two_part_inputs(widths, shared, batch=2, heads=2, seq=512):
+    d_nope, d_rope, d_value = widths
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    normal = lambda k, h, d: jax.random.normal(k, (batch, h, seq, d), jnp.float32)
+    return (normal(ks[0], heads, d_nope + d_rope), normal(ks[1], heads, d_nope),
+            normal(ks[2], 1 if shared else heads, d_rope), normal(ks[3], heads, d_value),
+            normal(ks[4], heads, d_value))  # q, k_nope, k_rope, v, a cotangent that is not all ones
+
+
+def _two_part_call(fused, **kw):
+    from hops_tpu.ops import attention as A
+
+    def call(q, k_nope, k_rope, v):
+        if fused:  # [k_nope | v] in one array, as a latent layer's second projection writes it
+            return A.flash_attention(q, (jnp.concatenate([k_nope, v], axis=-1), k_rope), None, causal=True, **kw)
+        return A.flash_attention(q, (k_nope, k_rope), v, causal=True, **kw)
+
+    return call
+
+
+def _whole_key_reference(q, k_nope, k_rope, v):
+    from hops_tpu.ops import attention as A
+
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:-1], k_rope.shape[-1]))], axis=-1)
+    return A.attention_reference(q, k, v, causal=True)
+
+
+def _assert_two_part_follows_reference(call, inputs):
+    *operands, w = inputs
+    np.testing.assert_allclose(call(*operands), _whole_key_reference(*operands), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: (call(*a) * w).sum(), argnums=(0, 1, 2, 3))(*operands)
+    want = jax.grad(lambda *a: (_whole_key_reference(*a) * w).sum(), argnums=(0, 1, 2, 3))(*operands)
+    for name, a, b in zip(("dq", "dk_nope", "dk_rope", "dv"), got, want):
+        assert a.shape == b.shape, name  # a shared rotary key's cotangent is summed over the heads
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("widths", sorted(_TWO_PART_WIDTHS))
+@pytest.mark.parametrize("form", sorted(_TWO_PART_FORMS))
+def test_two_part_keys_follow_the_reference_on_the_whole_keys(monkeypatch, form, widths):
+    """Keys handed over as each head's ``k_nope`` and a rotary key (one for all
+    heads, or one a head), the values in an array of their own or behind
+    ``k_nope``'s lanes: output, dQ, dK_nope, dK_rope and dV against the reference
+    on ``[k_nope | k_rope]``, causal, sub-tile 128 inside 256-tiles at 512 keys
+    (interior, edge and skipped sub-tiles in one call)."""
+    from hops_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_SUBTILE", 128)
+    kinds = _band(512, 512, 256, 256, 128, None, True, None).subtile_kinds()
+    assert all(kinds.values())
+    shared, fused = _TWO_PART_FORMS[form]
+    _assert_two_part_follows_reference(
+        _two_part_call(fused, block_q=256, block_k=256), _two_part_inputs(_TWO_PART_WIDTHS[widths], shared))
+
+
+@pytest.mark.parametrize("form", sorted(_TWO_PART_FORMS))
+def test_two_part_keys_take_the_kernel_at_its_default_tiles(form, flash_kernel_at_any_length):
+    """No block forced: the route, the tiles and the band are the whole-key
+    call's (128 x 128 at 512 keys), and the jaxpr holds one kernel a pass.
+    dQ takes q's buffer and every cotangent as large as its operand that
+    operand's: a shared rotary key (operand 2) is read by every head and its
+    cotangent is a head's own; ``[k_nope | v]`` in one array gives its buffer to
+    ``[dk_nope | dv]`` at the published widths and is two arrays at the toys'
+    (a block's lane range is a ref at whole 128-lane tiles only)."""
+    shared, fused = _TWO_PART_FORMS[form]
+    call = _two_part_call(fused)
+    _assert_two_part_follows_reference(call, _two_part_inputs(_TWO_PART_WIDTHS["toy_16_8_16"], shared, batch=1))
+    for widths, takes_one_array in (("toy_16_8_16", False), ("published_128_64_128", fused)):
+        operands = _two_part_inputs(_TWO_PART_WIDTHS[widths], shared, batch=1)[:4]
+        grad = str(jax.make_jaxpr(jax.grad(lambda *a: call(*a).sum(), argnums=(0, 1, 2, 3)))(*operands))
+        assert grad.count("name=flash_fwd") == 1 and grad.count("name=flash_bwd") == 1
+        aliases = [(0, 0), (1, 1)] + ([] if shared else [(2, 2)]) + ([] if takes_one_array else [(3, 3)])
+        assert f"input_output_aliases={tuple(aliases)}" in grad, (widths, aliases)
+
+
+@pytest.mark.parametrize("form", sorted(_TWO_PART_FORMS))
+@pytest.mark.parametrize("slices", [2, 4])
+def test_two_part_keys_through_query_slices(monkeypatch, form, slices):
+    """Past `_DQ_VMEM_BYTES` (forced small) the query axis goes through the one
+    backward kernel in slices: both parts' cotangents add up over them."""
+    from hops_tpu.ops import attention as A
+
+    shared, fused = _TWO_PART_FORMS[form]
+    inputs = _two_part_inputs(_TWO_PART_WIDTHS["toy_16_8_16"], shared, batch=1, seq=1024)
+    monkeypatch.setattr(A, "_SUBTILE", 128)
+    monkeypatch.setattr(A, "_DQ_VMEM_BYTES", A._dq_vmem_bytes(1024 // slices, 24, 4))
+    call = _two_part_call(fused, block_q=256, block_k=256)
+    grad = jax.make_jaxpr(jax.grad(lambda q: call(q, *inputs[1:4]).sum()))(inputs[0])
+    assert str(grad).count("name=flash_bwd") == slices
+    _assert_two_part_follows_reference(call, inputs)
+
+
+def test_two_part_keys_below_the_kernels_length_take_the_xla_route():
+    """Under `_XLA_FASTER_BELOW` keys (and on the reference path) the two parts
+    are put side by side: the plain form is the ground truth."""
+    inputs = _two_part_inputs(_TWO_PART_WIDTHS["toy_16_8_16"], True, seq=128)
+    for fused in (False, True):
+        call = _two_part_call(fused)
+        assert "pallas_call" not in str(jax.make_jaxpr(call)(*inputs[:4]))
+        np.testing.assert_allclose(call(*inputs[:4]), _whole_key_reference(*inputs[:4]), atol=2e-5, rtol=2e-5)
+
+
+# name: (heads, keys, d, d_value, window, dtype): the cells' calls with keys in one array, and a toy
+_WHOLE_KEY_SHAPES = {
+    "phi3": (4, 4096, 96, 96, 2047, jnp.bfloat16),
+    "olmoe": (2, 4096, 128, 128, None, jnp.bfloat16),
+    "hybrid": (2, 8192, 128, 128, None, jnp.bfloat16),
+    "latent_whole": (2, 8192, 192, 128, None, jnp.bfloat16),
+    "toy": (2, 256, 24, 16, None, jnp.float32),
+}
+
+
+def whole_key_jaxpr_digest(name):
+    """One flash call with keys in one array, forward and backward: its jaxpr
+    (the two kernels' bodies, index maps, scratch and aliases) with what embeds
+    an address, a path or a line taken out."""
+    import hashlib
+    import re
+
+    from hops_tpu.ops import attention as A
+
+    heads, keys, d, d_value, window, dtype = _WHOLE_KEY_SHAPES[name]
+    q = jax.ShapeDtypeStruct((1, heads, keys, d), dtype)
+    v = jax.ShapeDtypeStruct((1, heads, keys, d_value), dtype)
+
+    def loss(q, k, v):
+        out = A.flash_attention(q, k, v, causal=True, window=window, interpret=False,
+                                block_q=None if keys > 1536 else 128)
+        return out.astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    text = re.sub(r"/[^ :]*hops_tpu/ops/attention\.py:\d+", "attention.py", text)
+    return {"sha256": hashlib.sha256(text.encode()).hexdigest(), "lines": len(text.splitlines())}
+
+
+@pytest.mark.parametrize("name", sorted(_WHOLE_KEY_SHAPES))
+def test_keys_in_one_array_trace_to_the_parents_kernels(name):
+    """``tests/data/flash_whole_key_jaxpr.json`` was written with
+    `whole_key_jaxpr_digest` by PR 46's parent (652ce61), before the kernels took
+    keys in two parts: a call with keys in one array traces to the same two
+    kernel bodies, block specs and aliases at the cells' shapes."""
+    import json
+    import pathlib
+
+    recorded = json.loads((pathlib.Path(__file__).parent / "data" / "flash_whole_key_jaxpr.json").read_text())
+    assert whole_key_jaxpr_digest(name) == recorded[name]
+
+
 @pytest.mark.parametrize("sub", [64, 128, 256])
 def test_fully_masked_rows_return_zeros(monkeypatch, sub):
     """A negative offset puts the first queries before every key: those

@@ -368,14 +368,17 @@ def test_defaults_keep_the_parents_lowered_step(toy):
     """``tests/data/transformer_lm_parent_lowered.json`` was written with
     ``lowered_digest``: ``phi4_shaped`` (with its tree, and ``ling_shaped``'s) by
     PR 43's parent (3d29bff), before ``Block`` was rebuilt round ``LayerSpec``;
-    ``ling_shaped`` by PR 45 on top of 754fdf9, because that toy holds a share
-    of the experts (``moe_held_experts=(4, 4)``) and what a held share lowers to
-    changed by design: the 0/1 product that adds a chunk's rows to their tokens
-    is a loop over the chunk's live row tiles inside the loop over chunks,
-    forward and backward, and the layer sows two more statistics
-    (``held_row_tiles``, ``held_tile_share``; 10,076 lines where 754fdf9 wrote
-    9,721); the four toys without a held share read their older digests, which
-    is the proof that no other cell's step moved;
+    ``ling_shaped`` by PR 46 on top of 652ce61, because that toy holds a latent
+    layer (and a latent MTP module) and what ``LatentAttention`` lowers to
+    changed by design: no 32-head copy of K is built (the QK norm runs over
+    ``k_nope`` and the one rotary key as two parts, the keys reach attention as
+    ``(k_nope, k_rope)`` and, at the toy's 48 keys, are put side by side by
+    ``attention_reference`` alone) and the rotary channels turn in
+    ``rotate_from`` (a 0/1 product for a pair's other member, no strided slice,
+    no stack; 10,031 lines where 652ce61 wrote 10,076, PR 45's text, whose held
+    share lowers to a loop over live row tiles); the four toys without a latent
+    layer read their older digests, which is the proof that no other cell's
+    step moved;
     ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
     PR 31's parent (no ``remat``: the fields added since, and the names
     ``remat`` keeps values by, leave their lowered text as it was);
