@@ -34,7 +34,12 @@ import numpy as np
 from flax import linen as nn
 
 from hops_tpu.models.differential_attention import build_differential_attention
-from hops_tpu.models.linear_attention import build_gated_delta_net, build_kimi_delta_attention, refuse_decode
+from hops_tpu.models.linear_attention import (
+    build_gated_delta_net,
+    build_kimi_delta_attention,
+    held_count,
+    refuse_decode,
+)
 from hops_tpu.models.moe import build_routed_ffn
 from hops_tpu.models.state_space import build_gated_memory, build_mamba
 from hops_tpu.ops.attention import (
@@ -48,7 +53,7 @@ from hops_tpu.ops.attention import (
 )
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_EMBED, SCOPE_MTP, keep
+from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_ATTN_GATE, SCOPE_EMBED, SCOPE_MTP, keep
 
 _m_layer_kinds = REGISTRY.counter(
     "hops_tpu_train_layer_kinds_total",
@@ -282,6 +287,18 @@ class Attention(nn.Module):
     # None: no rotary at all (a hybrid's full-attention layers, whose
     # linear-attention neighbours carry position).
     rope_base: float | None = 10000.0
+    # A head's width where it is not d_model / num_heads (Solar-Open2: 64
+    # heads of 128 at d_model 4,096).
+    head_dim: int | None = None
+    # Gated attention (arXiv:2505.06708, the elementwise form): ``out = W_o
+    # [sigmoid(W_g x) * o]``, one gate a channel from the layer's input.
+    output_gate: bool = False
+    # (first, count): build ``count`` of the ``num_heads`` query heads and the
+    # KV heads they read (query head j reads KV head j // group), and return
+    # their part of ``W_o``'s sum: a chip's share under head parallelism, with
+    # no collective (``tp_shards`` is the same share under an enclosing
+    # ``shard_map``, with the ``psum`` behind it). Training only.
+    held_heads: tuple[int, int] | None = None
 
     @nn.compact
     def __call__(self, x, decode: bool = False):
@@ -290,8 +307,23 @@ class Attention(nn.Module):
             raise ValueError(
                 f"{self.num_heads} heads not divisible by tp_shards={self.tp_shards}"
             )
+        if decode and (self.output_gate or self.held_heads is not None):
+            refuse_decode("gated or head-sharded softmax-attention")
         heads = self.num_heads // self.tp_shards
-        head_dim = dm // self.num_heads
+        head_dim = self.head_dim or dm // self.num_heads
+        held_kv = None
+        if self.held_heads is not None:
+            if self.tp_shards > 1 or self.qk_norm:
+                raise NotImplementedError(
+                    "held_heads builds a share of the heads: it composes neither with tp_shards "
+                    "(the same share under a shard_map) nor with qk_norm, which spans every head's channels")
+            heads = held_count(self.held_heads, self.num_heads, "full_attention")
+            group = self.num_heads // (self.num_kv_heads or self.num_heads)
+            first, last = self.held_heads[0], self.held_heads[0] + heads - 1
+            held_kv = last // group - first // group + 1
+            if heads % held_kv or (held_kv > 1 and (first % group or heads % group)):
+                raise ValueError(
+                    f"held_heads {self.held_heads}: whole groups of {group} query heads, or heads of one group")
         if self.num_kv_heads is None:
             qkv = nn.DenseGeneral(
                 (3, heads, head_dim), dtype=self.dtype, name="qkv", use_bias=False
@@ -308,7 +340,7 @@ class Attention(nn.Module):
                     f"{self.num_kv_heads} kv heads not divisible by "
                     f"tp_shards={self.tp_shards}"
                 )
-            kv_heads = self.num_kv_heads // self.tp_shards
+            kv_heads = held_kv or self.num_kv_heads // self.tp_shards
             q = jnp.moveaxis(
                 nn.DenseGeneral(
                     (heads, head_dim), dtype=self.dtype, name="q", use_bias=False
@@ -329,6 +361,11 @@ class Attention(nn.Module):
 
         if decode:
             return self._decode_attend(q, k, v, b, s, dm, head_dim)
+
+        gate = None
+        if self.output_gate:
+            with jax.named_scope(SCOPE_ATTN_GATE):
+                gate = jax.nn.sigmoid(nn.Dense(heads * head_dim, dtype=self.dtype, use_bias=False, name="gate")(x))
 
         pos = jnp.arange(s)
         if self.attention_impl == "ring_local":
@@ -382,7 +419,7 @@ class Attention(nn.Module):
         else:
             raise ValueError(f"unknown attention_impl {self.attention_impl!r}")
 
-        return self._project_out(o, b, s, dm)
+        return self._project_out(o, b, s, dm, gate)
 
     def _rotate(self, t, pos):
         return t if self.rope_base is None else rotary_embedding(t, pos, self.rope_base)
@@ -394,10 +431,14 @@ class Attention(nn.Module):
         flat = RMSNorm(self.norm_eps, dtype=self.dtype, name=name)(flat)
         return jnp.moveaxis(flat.reshape(b, s, h, d), 2, 1)
 
-    def _project_out(self, o, b, s, dm):
+    def _project_out(self, o, b, s, dm, gate=None):
         """(b, h_local, s, d) -> out projection; under tp the local
-        heads produce a partial sum combined by one psum."""
+        heads produce a partial sum combined by one psum. ``gate`` (b, s,
+        h_local * d) multiplies the heads' outputs first."""
         o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
+        if gate is not None:
+            with jax.named_scope(SCOPE_ATTN_GATE):
+                o = o * gate
         o = nn.DenseGeneral(dm, dtype=self.dtype, name="out", use_bias=False)(o)
         if self.tp_axis is not None:
             o = jax.lax.psum(o, self.tp_axis)
@@ -763,7 +804,7 @@ def build_attention(spec: LayerSpec, shared: SharedSpec) -> nn.Module:
         kv_page_size=shared.kv_page_size,
         kv_pool_blocks=shared.kv_pool_blocks,
         norm_eps=spec.norm_eps,
-        **options,  # num_kv_heads, window, qk_norm, rope_base
+        **options,  # num_kv_heads, window, qk_norm, rope_base, head_dim, output_gate, held_heads
         name="attn",
     )
 
@@ -960,7 +1001,7 @@ class TransformerLM(nn.Module):
     # ``mtp_layer_type``, with a routed feed-forward if any layer has one; the
     # last of ``layer_specs()``), run when the caller hands ``mtp_tokens``.
     ffn_types: tuple[str, ...] | None = None
-    linear_lower_bound: float = -5.0
+    linear_lower_bound: float | None = -5.0
     latent_kv_rank: int | None = None
     latent_nope_dim: int | None = None
     latent_rope_dim: int | None = None
@@ -977,6 +1018,23 @@ class TransformerLM(nn.Module):
     moe_held_experts: tuple[int, int] | None = None
     mtp_layers: int = 0
     mtp_layer_type: str = "full_attention"
+    # A Kimi-delta / gated-GQA hybrid as Solar-Open2 configures it:
+    # ``mixer_options`` of the softmax kinds (``head_dim``: a head's width
+    # where it is not d_model / num_heads; ``attention_output_gate``: ``W_o
+    # [sigmoid(W_g x) * o]``) and of "kimi_delta_attention" (the published
+    # gate: ``linear_lower_bound`` None for a log-decay ``-exp(A_log)
+    # softplus(.)`` without a bound, ``kda_gate_rank`` for the low-rank pairs
+    # in place of a full-rank ``W_a``, ``kda_allow_neg_eigval`` for beta in
+    # (0, 2), ``kda_output_gate`` "head_wise" | "channel_wise"); of both,
+    # ``held_heads`` = this chip's (first, count) of the heads of every mixer
+    # (of ``num_heads`` and of ``linear_num_heads``: the same range of both),
+    # whose part of ``W_o``'s sum each layer returns.
+    head_dim: int | None = None
+    attention_output_gate: bool = False
+    kda_gate_rank: int | None = None
+    kda_allow_neg_eigval: bool = False
+    kda_output_gate: str = "head_wise"
+    held_heads: tuple[int, int] | None = None
     # ``SharedSpec`` (with the fields of the same names above): the decode
     # cache and tensor parallelism. ``num_kv_heads`` and ``window`` are
     # ``mixer_options`` of the attention kinds.
@@ -1058,12 +1116,19 @@ class TransformerLM(nn.Module):
 
         linear = dict(num_heads=self.linear_num_heads or self.num_heads, key_dim=self.linear_key_dim,
                       value_dim=self.linear_value_dim, conv_size=self.linear_conv_size)
+        held_heads = None if self.held_heads is None else tuple(self.held_heads)
+        held_kinds = ("full_attention", "sliding_attention", "kimi_delta_attention")
+        if held_heads is not None and (self.attention_form != "softmax" or set(mixers) - set(held_kinds)):
+            raise NotImplementedError(
+                f"held_heads is built for softmax attention and Kimi-delta layers ({held_kinds}), not {set(mixers)}")
 
         def mixer_options(i, kind):
             if kind == "linear_attention":
                 return _pairs(**linear, allow_neg_eigval=self.linear_allow_neg_eigval)
             if kind == "kimi_delta_attention":
-                return _pairs(**linear, lower_bound=self.linear_lower_bound)
+                return _pairs(**linear, lower_bound=self.linear_lower_bound, gate_rank=self.kda_gate_rank,
+                              allow_neg_eigval=self.kda_allow_neg_eigval, output_gate=self.kda_output_gate,
+                              held_heads=held_heads)
             if kind == "latent_attention":
                 return _pairs(kv_rank=self.latent_kv_rank, nope_dim=self.latent_nope_dim,
                               rope_dim=self.latent_rope_dim, value_dim=self.latent_value_dim,
@@ -1076,7 +1141,8 @@ class TransformerLM(nn.Module):
                 form=self.attention_form, use_bias=self.use_bias, num_kv_heads=self.num_kv_heads,
                 qk_norm=self.qk_norm, rope_base=self.rope_base,
                 window=self.window if self.layer_types is None or kind == "sliding_attention" else None,
-                **({"layer_index": i} if self.attention_form == "differential" else {}))
+                **({"layer_index": i} if self.attention_form == "differential" else
+                   {"head_dim": self.head_dim, "output_gate": self.attention_output_gate, "held_heads": held_heads}))
 
         ffn_options = {"dense": _pairs(hidden=self.mlp_hidden), "moe": _pairs(
             num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,

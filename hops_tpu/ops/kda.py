@@ -4,8 +4,12 @@ Per head a state ``S`` (d_k x d_v), ``S_0 = 0``, and per token
 
     S_t = Diag(a_t) S_{t-1} + b_t k_t (v_t - (Diag(a_t) S_{t-1})^T k_t)^T      o_t = S_t^T q_t
 
-with ``a_t = exp(g_t)`` a VECTOR over the d_k key channels, ``g_t`` in
-``[LOWER_BOUND, 0]``, and ``b_t`` in [0, 1] (Kimi Linear, arXiv:2510.26692);
+with ``a_t = exp(g_t)`` a VECTOR over the d_k key channels and ``b_t`` in
+[0, 2] (Kimi Linear, arXiv:2510.26692), in two forms of the log-decay:
+``g_t`` in ``[LOWER_BOUND, 0]`` (``bounded``, the default: Ling-3.0-flash's
+safe gate, with ``b_t`` in [0, 1]) or any ``g_t <= 0`` (``bounded=False``:
+the published gate ``-exp(A_log) softplus(.)``, as Solar-Open2 configures
+it; below). What follows describes the bounded form first;
 ``q_t`` and ``k_t`` are the layer's after their L2 norm, ``q_t = x / (|x|
 sqrt(d_k))`` and ``k_t = x / |x|`` with ``|x|^2 = sum x^2 + L2_EPS``, which
 is the published rule's first step and taken here, on the tile a kernel
@@ -32,6 +36,28 @@ beside it (around the block's first row, e^-80 times a small ``q`` is a
 denormal and flushed); before the block the column factor only shrinks, and
 the products that the mask keeps are at most 1 again. That is the one place
 the bound on ``g`` is used.
+
+Without a bound (``bounded=False``) no one ``r_I`` serves a block: a decay
+of e^-40 a token is the model's, and 8 x 40 is past what float32's exponent
+holds. The two score matrices then take the secondary chunking of gated
+linear attention (arXiv:2312.06635; flash-linear-attention's KDA does the
+same): for the columns BEFORE a block the factors stand round the running
+sum at the row before the block, ``e^{c_i - r}`` on the rows and ``e^{r -
+c_j}`` on the columns, both at most 1 whatever ``g`` is, so an underflow is
+a true zero (:func:`_boundary_factors`); inside the block the exponent
+``c_id - c_jd`` of each (row, column, channel) is formed before the ``exp``,
+a column at a time (:func:`_block_scores`: 16 x 16 x d_k exponentials and
+multiply-adds a block, ~2 k a token and head beside the state products' 16
+k). No clamp, no floor on ``g``, no dropped term; nothing else in the chunk
+depends on the bound (``e^c``, ``e^{c_C - c}`` and ``e^{c_C}`` are at most
+1). One body serves both forms: ``bounded`` is a static argument of
+:func:`_chunk` and :func:`_chunk_bwd`, the bounded form traces to the
+kernels it traced to before the argument existed
+(``tests/data/kda_bounded_jaxpr.json``), and the unbounded form runs under
+kernel names of its own (``kda_unbounded_fwd``, ``kda_unbounded_bwd``). On a
+v5e at 8 heads x 8,192 tokens x (128, 128), bfloat16, the op alone reads
+1.56 ms forward / 4.36 forward + backward where the bounded form reads 1.40
+/ 3.69 (my chip run, PR 47).
 
 One function, :func:`_chunk`, is a chunk of the rule for a block of heads:
 ``(q, k, v, g, b, S) -> (O, S')`` on ``(heads, C, d)`` values, ``q`` and
@@ -60,7 +86,7 @@ in place of the fourteen a traced pull-back of the inverse's seven takes.
 ``dA`` and ``dP`` go back to the operands block by block
 (:func:`_decayed_scores_bwd`) through the SAME factor pairs as forward, so
 every sum is again of ``e^{c_i - c_j}`` with ``i >= j`` and no exponent
-leaves +-40; a cotangent with respect to an exponent is the operand times
+leaves +-40 (unbounded: none is above 0; :func:`_block_scores_bwd`); a cotangent with respect to an exponent is the operand times
 its own cotangent, so with ``dR``, ``dC`` those of a score matrix's row
 and column operands before their decay
 
@@ -89,7 +115,7 @@ Two routes run the two functions, chosen by :func:`implementation`:
 - on a TPU two Pallas kernels over the grid (batch, head blocks, chunks),
   the chunks in order with the state in VMEM: ``kda_fwd`` (``O`` and the
   state entering each chunk) and ``kda_bwd`` (the chunks from the last down,
-  the state's cotangent in VMEM). They read and write the layer's own
+  the state's cotangent in VMEM); ``kda_unbounded_*`` in the other form. They read and write the layer's own
   arrays: ``q``, ``k``, ``v``, ``g`` and their cotangents are (b, s, h, d) as
   the convolutions and the gate's projection hold them, seen as (b, s / C, C,
   h * d) with no copy; a grid step takes the (C, heads * d) tile of a block
@@ -152,7 +178,7 @@ L2_EPS = 1e-6
 _VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def _decay_factors(c):
+def _decay_factors(c, bounded=True):
     """For each block of ``SUB`` rows of a chunk, the two factors that carry
     ``e^{c_i - c_j}`` into a product: ``(rows e^{c_i - r}, columns e^{r -
     c_j})`` with ``r`` the running sum at the block's middle row. ``c`` is
@@ -161,7 +187,9 @@ def _decay_factors(c):
     e^40 inside the block and 1 after it, where the mask drops every product
     (``r - c_j`` grows without bound there). The ``minimum`` is never reached
     by a ``g`` inside the bound; it keeps one outside it from making an
-    infinity."""
+    infinity. Not ``bounded``: :func:`_boundary_factors`."""
+    if not bounded:
+        return _boundary_factors(c)
     size = c.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, size, 1), 1)
     factors = []
@@ -173,15 +201,61 @@ def _decay_factors(c):
     return factors
 
 
-def _decayed_scores(rows, cols, factors, *, strict):
+def _boundary_factors(c):
+    """The factors of a log-decay WITHOUT a lower bound (the secondary
+    chunking of gated linear attention, arXiv:2312.06635): for each block of
+    ``SUB`` rows ``(rows e^{c_i - r}, columns e^{r - c_j}, inside)`` with
+    ``r`` the running sum at the row BEFORE the block. Both factors are at
+    most 1 whatever ``g <= 0`` is (the rows lie after the boundary, the
+    columns that the column factor keeps before it: it is 0 from the block's
+    first column on), so an underflow is a true zero. ``inside`` covers the
+    block against itself, where no one ``r`` serves every pair: per column
+    ``j`` of the block the (heads, SUB, d_k) array ``e^{c_i - c_j}``, the
+    exponent formed before the ``exp`` (the ``minimum`` is reached only
+    above the diagonal, which the mask drops). The first block has no
+    columns before it: its two factors are None."""
+    size = c.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, size, 1), 1)
+    factors = []
+    for lo in range(0, size, SUB):
+        rows = c[:, lo: lo + SUB, :]
+        on_rows = on_cols = None
+        if lo:
+            boundary = c[:, lo - 1: lo, :]
+            on_rows = jnp.exp(rows - boundary)
+            on_cols = jnp.where(col < lo, jnp.exp(jnp.minimum(boundary - c, 0.0)), 0.0)
+        inside = [jnp.exp(jnp.minimum(rows - c[:, lo + j: lo + j + 1, :], 0.0)) for j in range(SUB)]
+        factors.append((on_rows, on_cols, inside))
+    return factors
+
+
+def _block_scores(rows, cols, lo, on_rows, on_cols, inside):
+    """Rows ``lo`` to ``lo + SUB`` of the unmasked decayed product, (heads,
+    SUB, C), from :func:`_boundary_factors`' factors: a product against the
+    columns before the block, and inside the block a column at a time
+    ``sum_d rows_id cols_jd e^{c_id - c_jd}``."""
+    size = cols.shape[1]
+    col = _iotas(SUB, size)[1]
+    scores = jnp.zeros((rows.shape[0], SUB, size), F32) if on_rows is None else _dot(rows * on_rows, cols * on_cols, _NT)
+    for j, decay in enumerate(inside):
+        column = jnp.sum(rows * (cols[:, lo + j: lo + j + 1, :] * decay), axis=2, keepdims=True)
+        scores = jnp.where(col == lo + j, column, scores)
+    return scores
+
+
+def _decayed_scores(rows, cols, factors, *, strict, bounded=True):
     """``lower[(rows e^c)(cols e^-c)^T]`` (heads, C, C) without ever forming
     ``e^-c``: block by block of ``SUB`` rows, each against the whole chunk's
     columns, then the mask (strictly below the diagonal when ``strict``).
     What the mask drops may be as large as e^80; what it keeps is a sum of
     ``rows_id cols_jd e^{c_id - c_jd}`` with the exponent at most 0."""
     size = rows.shape[1]
-    blocks = [_dot(rows[:, i * SUB: (i + 1) * SUB, :] * on_rows, cols * on_cols, _NT)
-              for i, (on_rows, on_cols) in enumerate(factors)]
+    if bounded:
+        blocks = [_dot(rows[:, i * SUB: (i + 1) * SUB, :] * on_rows, cols * on_cols, _NT)
+                  for i, (on_rows, on_cols) in enumerate(factors)]
+    else:
+        blocks = [_block_scores(rows[:, i * SUB: (i + 1) * SUB, :], cols, i * SUB, *block)
+                  for i, block in enumerate(factors)]
     scores = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
     row, col = _iotas(size, size)
     return jnp.where(row > col if strict else row >= col, scores, 0.0)
@@ -250,7 +324,7 @@ class _Parts(NamedTuple):
     p: jax.Array
 
 
-def _chunk_parts(q, k, v, g, beta, state_t) -> _Parts:
+def _chunk_parts(q, k, v, g, beta, state_t, bounded=True) -> _Parts:
     """``q``, ``k`` are normalised here and stay float32 from then on;
     ``v`` stays in its own type and ``beta`` rides ``T``'s columns (``T (b
     V) = (T b_row) V``), so that a bfloat16 ``v`` meets a float32 ``T`` in
@@ -259,16 +333,16 @@ def _chunk_parts(q, k, v, g, beta, state_t) -> _Parts:
     q, r_q = _normalise(q, q.shape[-1] ** -0.5)
     k, r_k = _normalise(k)
     c = _running_sum(g)
-    factors = _decay_factors(c)
-    t = _unit_lower_inverse(_decayed_scores(beta * k, k, factors, strict=True))
+    factors = _decay_factors(c, bounded)
+    t = _unit_lower_inverse(_decayed_scores(beta * k, k, factors, strict=True, bounded=bounded))
     gamma = jnp.exp(c)
     u = _dot(t * _as_row(beta), v)
     w = _dot(t, (beta * gamma) * k)
-    p = _decayed_scores(q, k, factors, strict=False)
+    p = _decayed_scores(q, k, factors, strict=False, bounded=bounded)
     return _Parts(q, k, r_q, r_k, c, factors, t, gamma, u, w, u - _dot(w, state_t, _NT), p)
 
 
-def _chunk(q, k, v, g, beta, state_t):
+def _chunk(q, k, v, g, beta, state_t, bounded=True):
     """One chunk of the rule for a block of heads. ``q``, ``k`` (heads, C,
     d_k) BEFORE their L2 norm and ``v`` (heads, C, d_v) in any float type,
     ``g`` (heads, C, d_k) the log-decay and ``beta`` (heads, C, 1), float32;
@@ -277,13 +351,39 @@ def _chunk(q, k, v, g, beta, state_t):
     is a plain one. Returns ``(O (heads, C, d_v) float32, the state leaving
     the chunk)``. :func:`_chunk_bwd` is its pull-back, written by hand: an
     edit here has a second function to keep in step."""
-    x = _chunk_parts(q, k, v, g, beta, state_t)
+    x = _chunk_parts(q, k, v, g, beta, state_t, bounded)
     o = _dot(x.gamma * x.q, state_t, _NT) + _dot(x.p, x.v_new)
     last = x.c[:, -1:, :]
     return o, jnp.exp(last) * state_t + _dot(x.v_new, jnp.exp(last - x.c) * x.k, _TN)
 
 
-def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
+def _block_scores_bwd(d_a, d_p, a_rows, p_rows, cols, lo, on_rows, on_cols, inside):
+    """The pull-back of two :func:`_block_scores` that share ``cols`` and the
+    factors, given their MASKED cotangents (heads, SUB, C): ``(d a_rows, d
+    p_rows, d cols`` through the product with the columns before the block
+    (heads, C, d_k)``, d cols`` of the block's own columns (heads, SUB,
+    d_k)``)``, each with respect to the operand before its decay."""
+    heads, size, width = cols.shape
+    col = _iotas(SUB, size)[1]
+    d_a_rows = d_p_rows = jnp.zeros((heads, SUB, width), F32)
+    d_cols = 0.0
+    if on_rows is not None:
+        d_scores = jnp.concatenate([d_a, d_p], axis=1)
+        d_rows = _dot(d_scores, cols * on_cols)
+        d_a_rows, d_p_rows = d_rows[:, :SUB] * on_rows, d_rows[:, SUB:] * on_rows
+        d_cols = _dot(d_scores, jnp.concatenate([a_rows * on_rows, p_rows * on_rows], axis=1), _TN) * on_cols
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, SUB, 1), 1)
+    d_own = jnp.zeros((heads, SUB, width), F32)  # of the block's own columns, the rows lo to lo + SUB of d cols
+    for j, decay in enumerate(inside):
+        d_a_j = jnp.sum(jnp.where(col == lo + j, d_a, 0.0), axis=2, keepdims=True)
+        d_p_j = jnp.sum(jnp.where(col == lo + j, d_p, 0.0), axis=2, keepdims=True)
+        decayed = cols[:, lo + j: lo + j + 1, :] * decay
+        d_a_rows, d_p_rows = d_a_rows + d_a_j * decayed, d_p_rows + d_p_j * decayed
+        d_own = jnp.where(row == j, jnp.sum((d_a_j * a_rows + d_p_j * p_rows) * decay, axis=1, keepdims=True), d_own)
+    return d_a_rows, d_p_rows, d_cols, d_own
+
+
+def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors, bounded=True):
     """The pull-back of the chunk's two score matrices, ``A`` =
     :func:`_decayed_scores` of ``(a_rows, cols)`` and ``P`` of ``(p_rows,
     cols)``, given their MASKED cotangents: ``(d a_rows, d p_rows, d
@@ -293,6 +393,12 @@ def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
     matrices share the decayed columns, so a block's rows of both go
     through one product each way."""
     d_a_rows, d_p_rows, d_cols = [], [], 0.0
+    if not bounded:
+        blocks = [slice(lo, lo + SUB) for lo in range(0, cols.shape[1], SUB)]
+        d_a_rows, d_p_rows, d_before, d_own = zip(*(
+            _block_scores_bwd(d_a[:, block], d_p[:, block], a_rows[:, block], p_rows[:, block], cols, block.start, *block_factors)
+            for block, block_factors in zip(blocks, factors)))
+        return jnp.concatenate(d_a_rows, axis=1), jnp.concatenate(d_p_rows, axis=1), sum(d_before) + jnp.concatenate(d_own, axis=1)
     for i, (on_rows, on_cols) in enumerate(factors):
         block = slice(i * SUB, (i + 1) * SUB)
         d_scores = jnp.concatenate([d_a[:, block], d_p[:, block]], axis=1)
@@ -304,7 +410,7 @@ def _decayed_scores_bwd(d_a, d_p, a_rows, p_rows, cols, factors):
     return jnp.concatenate(d_a_rows, axis=1), jnp.concatenate(d_p_rows, axis=1), d_cols
 
 
-def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state):
+def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state, bounded=True):
     """The pull-back of :func:`_chunk` at its arguments, by formula:
     ``(d_q, d_k, d_v, d_g, d_beta, d_state_t)`` for the cotangents ``d_o``
     (heads, C, d_v; any float type) of ``O`` and ``d_state`` (heads, d_v,
@@ -315,7 +421,7 @@ def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state):
     dtypes = q.dtype, k.dtype, v.dtype
     size, d_k_width, d_v_width = *q.shape[1:], v.shape[2]
     row, col = _iotas(size, size)
-    x = _chunk_parts(q, k, v, g, beta, state_t)
+    x = _chunk_parts(q, k, v, g, beta, state_t, bounded)
     q, k, c, gamma = x.q, x.k, x.c, x.gamma
     last = c[:, -1:, :]
     to_end, whole = jnp.exp(last - c), jnp.exp(last)  # e^{c_C - c}, e^{c_C}
@@ -330,7 +436,7 @@ def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state):
     d_bv = d_rhs[:, :, :d_v_width]
     # dA = -strict_lower[T^T dT T^T] = -strict_lower[(T^T dU) U^T + (T^T dW) W^T], one product 2 d wide
     d_a = -jnp.where(row > col, _dot(d_rhs, jnp.concatenate([x.u, x.w], axis=2), _NT), 0.0)
-    d_k_beta, d_q, d_k_cols = _decayed_scores_bwd(d_a, d_p, k_beta, q, k, x.factors)
+    d_k_beta, d_q, d_k_cols = _decayed_scores_bwd(d_a, d_p, k_beta, q, k, x.factors, bounded)
     d_q = d_q + gamma * d_q_dec
     d_k_beta = d_k_beta + gamma * d_rhs[:, :, d_v_width:]  # b K is A's row operand and, under e^c, W's right-hand side
     d_k_falling = d_k_cols + to_end * d_k_end  # K where its exponent is -c: the scores' columns and K e^{c_C - c}
@@ -349,11 +455,11 @@ def _chunk_bwd(q, k, v, g, beta, state_t, d_o, d_state):
 # -- the XLA route: a scan over the chunks of `_chunk` and of `_chunk_bwd` -----
 
 
-def _forward_scan(q, k, v, g, beta):
+def _forward_scan(q, k, v, g, beta, bounded=True):
     """Chunk-major operands (n, b * h, C, ...); returns ``(o, the states
     entering each chunk)``."""
     def step(state_t, chunk):
-        o, new = _chunk(*chunk, state_t)
+        o, new = _chunk(*chunk, state_t, bounded)
         return new, (o, state_t)
 
     zero = jnp.zeros((q.shape[1], v.shape[-1], q.shape[-1]), F32)
@@ -361,9 +467,9 @@ def _forward_scan(q, k, v, g, beta):
     return o, states
 
 
-def _backward_scan(q, k, v, g, beta, states, d_o):
+def _backward_scan(q, k, v, g, beta, states, d_o, bounded=True):
     def step(d_state, chunk):
-        *d_inputs, d_state = _chunk_bwd(*chunk, d_state)
+        *d_inputs, d_state = _chunk_bwd(*chunk, d_state, bounded)
         return d_state, tuple(d_inputs)
 
     _, d_inputs = jax.lax.scan(step, jnp.zeros_like(states[0]), (q, k, v, g, beta, states, d_o), reverse=True)
@@ -400,18 +506,18 @@ def _zero_before_the_first_chunk(scratch):
         scratch[...] = jnp.zeros_like(scratch)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref, state_scr):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref, state_scr, *, bounded=True):
     _zero_before_the_first_chunk(state_scr)
     heads = beta_ref.shape[0]
     state_t = state_scr[...]
     states_ref[...] = state_t
     o, state_scr[...] = _chunk(*(_heads_apart(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
-                               _column(beta_ref[...]), state_t)
+                               _column(beta_ref[...]), state_t, bounded)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, d_o_ref,
-                d_q_ref, d_k_ref, d_v_ref, d_g_ref, d_beta_ref, d_state_scr):
+                d_q_ref, d_k_ref, d_v_ref, d_g_ref, d_beta_ref, d_state_scr, *, bounded=True):
     """The chunks from the last down: a chunk's cotangents are
     :func:`_chunk_bwd` at what the forward kept, given ``dO`` and the
     cotangent of the state it left (the scratch)."""
@@ -419,7 +525,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, d_o_ref,
     heads = beta_ref.shape[0]
     q, k, v, g = (_heads_apart(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref))
     *d_tiles, d_beta, d_state_scr[...] = _chunk_bwd(
-        q, k, v, g, _column(beta_ref[...]), states_ref[...], d_o_ref[...], d_state_scr[...])
+        q, k, v, g, _column(beta_ref[...]), states_ref[...], d_o_ref[...], d_state_scr[...], bounded)
     for ref, value in zip((d_q_ref, d_k_ref, d_v_ref, d_g_ref), d_tiles):
         _heads_together(ref, value)
     d_beta_ref[...] = _as_row(d_beta)
@@ -484,23 +590,42 @@ def _builder(kernel_name):
     return wrap
 
 
-@_builder("kda_fwd")
-def _forward_pallas(name, q, k, v, g, beta, interpret):
+def _forward_call(name, body, q, k, v, g, beta, interpret):
     """``q``, ``k``, ``v``, ``g`` (b, n, C, h * d) and ``beta`` (b, h, n, 1,
     C); ``o`` (b, h, n, C, d_v) in ``v``'s type, the states (b, h, n, d_v,
     d_k)."""
     b, h, n = beta.shape[:3]
     state = v.shape[3] // h, q.shape[3] // h
-    return _call(name, _fwd_kernel, (q, k, v, g, beta),
+    return _call(name, body, (q, k, v, g, beta),
                  (((b, h, n, q.shape[2], state[0]), v.dtype), ((b, h, n, *state), F32)),
                  heads=FWD_HEADS, state=state, interpret=interpret)
 
 
-@_builder("kda_bwd")
-def _backward_pallas(name, q, k, v, g, beta, states, d_o, interpret):
-    return _call(name, _bwd_kernel, (q, k, v, g, beta, states, d_o),
+def _backward_call(name, body, q, k, v, g, beta, states, d_o, interpret):
+    return _call(name, body, (q, k, v, g, beta, states, d_o),
                  tuple((t.shape, t.dtype) for t in (q, k, v, g, beta)),
                  heads=BWD_HEADS, state=states.shape[3:], interpret=interpret, reverse=True)
+
+
+@_builder("kda_fwd")
+def _forward_pallas(name, q, k, v, g, beta, interpret):
+    return _forward_call(name, _fwd_kernel, q, k, v, g, beta, interpret)
+
+
+@_builder("kda_bwd")
+def _backward_pallas(name, q, k, v, g, beta, states, d_o, interpret):
+    return _backward_call(name, _bwd_kernel, q, k, v, g, beta, states, d_o, interpret)
+
+
+# the same two loops round the chunk of a log-decay without a lower bound, under names of their own
+@_builder("kda_unbounded_fwd")
+def _forward_pallas_unbounded(name, q, k, v, g, beta, interpret):
+    return _forward_call(name, functools.partial(_fwd_kernel, bounded=False), q, k, v, g, beta, interpret)
+
+
+@_builder("kda_unbounded_bwd")
+def _backward_pallas_unbounded(name, q, k, v, g, beta, states, d_o, interpret):
+    return _backward_call(name, functools.partial(_bwd_kernel, bounded=False), q, k, v, g, beta, states, d_o, interpret)
 
 
 def implementation(interpret: bool | None = None) -> str:
@@ -517,26 +642,26 @@ def _rule(q, k, v, g, beta, route):
 
 
 def _rule_fwd(q, k, v, g, beta, route):
-    impl, interpret = route
+    impl, interpret, bounded = route
     if impl == "pallas":
-        o, states = _forward_pallas(q, k, v, g, beta, interpret=interpret)
+        o, states = (_forward_pallas if bounded else _forward_pallas_unbounded)(q, k, v, g, beta, interpret=interpret)
     else:
-        o, states = _forward_scan(q, k, v, g, beta)
+        o, states = _forward_scan(q, k, v, g, beta, bounded)
     return o.astype(v.dtype), (q, k, v, g, beta, states)
 
 
 def _rule_bwd(route, kept, d_o):
-    impl, interpret = route
+    impl, interpret, bounded = route
     if impl == "pallas":
-        return _backward_pallas(*kept, d_o, interpret=interpret)
-    return _backward_scan(*kept, d_o)
+        return (_backward_pallas if bounded else _backward_pallas_unbounded)(*kept, d_o, interpret=interpret)
+    return _backward_scan(*kept, d_o, bounded)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
-             chunk: int = DEFAULT_CHUNK, custom_backward: bool = True,
+             bounded: bool = True, chunk: int = DEFAULT_CHUNK, custom_backward: bool = True,
              interpret: bool | None = None) -> jax.Array:
     """``o`` (b, s, h, d_v) of the recurrence in the module docstring, in
     ``v``'s type (the kernel writes it head-major and the move to this shape
@@ -544,8 +669,10 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     the arrays a layer holds: ``q``, ``k`` (b, s, h, d_k)
     BEFORE their L2 norm (the rule normalises both and scales ``q`` by ``1 /
     sqrt(d_k)``), ``v`` (b, s, h, d_v), the log-decay ``g`` (b, s, h, d_k)
-    in ``[LOWER_BOUND, 0]`` and ``beta`` (b, s, h); differentiable in all
-    five. A sequence that is not whole chunks is padded with tokens that
+    in ``[LOWER_BOUND, 0]`` (``bounded=False``: any ``g <= 0``, the published
+    gate's ``-exp(A_log) softplus(.)``; kernels ``kda_unbounded_fwd`` /
+    ``kda_unbounded_bwd``) and ``beta`` (b, s, h) in [0, 2]; differentiable
+    in all five. A sequence that is not whole chunks is padded with tokens that
     leave the state as it is (``beta`` 0, ``g`` 0). ``custom_backward=False``
     differentiates the scan with ``jax.grad`` (tests: the oracle of
     :func:`_chunk_bwd`; float32 values only, a traced pull-back through a
@@ -557,7 +684,7 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     pad = -s % chunk
     if pad:
         q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v, g, beta))
-    route = (implementation(interpret) if custom_backward else "xla_scan", bool(interpret))
+    route = (implementation(interpret) if custom_backward else "xla_scan", bool(interpret), bounded)
     b, padded, h = beta.shape
     n = padded // chunk
     beta = beta.astype(F32)
@@ -572,7 +699,7 @@ def kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
 
         beta = chunks(beta[..., None])
     args = (chunks(q), chunks(k), chunks(v), chunks(g.astype(F32)), beta)
-    o = _rule(*args, route) if custom_backward else _forward_scan(*args)[0].astype(v.dtype)
+    o = _rule(*args, route) if custom_backward else _forward_scan(*args, bounded)[0].astype(v.dtype)
     if route[0] == "pallas":  # head-major from the kernel: the move is XLA's to place, and it makes it a layout
         o = jnp.moveaxis(o.reshape(b, h, padded, -1), 1, 2)
     else:

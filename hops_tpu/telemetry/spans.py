@@ -70,7 +70,16 @@ SCOPE_MOE_SHARED = "moe_shared"
 #: ``W_o``). A softmax-attention block enters none of them.
 LINATTN_SCOPES = ("linattn_proj", "linattn_conv", "linattn_scan", "linattn_out")
 #: A Kimi-delta-attention mixer (``models/linear_attention.py``) enters the
-#: same four, with ``ops/kda.py``'s rule in ``linattn_scan``.
+#: same four, with ``ops/kda.py``'s rule in ``linattn_scan``. With the
+#: published low-rank gate it enters ``linattn_gate`` beside them: the two
+#: low-rank pairs (``f_b(f_a x)`` of the decay, ``g_b(g_a x)`` of the
+#: channel-wise output gate), ``dt_bias``, the softplus and the rate; a
+#: bounded full-rank layer computes its gate under ``linattn_proj``.
+SCOPE_LINATTN_GATE = "linattn_gate"
+#: Inside ``attn``, the output gate of a gated softmax-attention layer
+#: (``models/transformer.py:Attention(output_gate=True)``): the projection
+#: ``W_g x``, its sigmoid and the product with the heads' outputs.
+SCOPE_ATTN_GATE = "attn_gate"
 
 #: Inside ``attn``, the parts of a latent-attention mixer
 #: (``models/transformer.py:LatentAttention``): the projections (``W_q``, the
@@ -185,6 +194,17 @@ COUNTER_TRAIN_LINATTN_KERNEL_CALLS = "hops_tpu_train_linattn_kernel_calls_total"
 #: route counts none. ``hops_tpu_train_kda_traces_total{impl}``
 #: (``models/linear_attention.py``) counts the layers traced by the route.
 COUNTER_TRAIN_KDA_KERNEL_CALLS = "hops_tpu_train_kda_kernel_calls_total"
+#: One per Kimi-delta-attention layer traced, by the form of its decay gate
+#: (``models/linear_attention.py``): ``bound`` = the log-decay's lower bound
+#: (``-5.0``: Ling's safe gate, kernels ``kda_fwd`` / ``kda_bwd``) | ``none``
+#: (the published ``-exp(A_log) softplus(.)``, kernels ``kda_unbounded_fwd`` /
+#: ``kda_unbounded_bwd``), ``rank`` = ``full`` | the rank of ``f_a`` / ``f_b``.
+COUNTER_TRAIN_KDA_GATE = "hops_tpu_train_kda_gate_total"
+#: One per token mixer traced that builds a share of its heads (``held_heads``
+#: = (first, count): ``mixer`` = ``kimi_delta_attention`` | ``full_attention``,
+#: ``held`` = the heads built, ``of`` = the layer's own count), beside the
+#: ``moe_stats`` of a held share of experts.
+COUNTER_TRAIN_HELD_HEADS = "hops_tpu_train_held_heads_total"
 
 #: What ``TransformerLM(remat=True)`` keeps of a block's forward besides the
 #: block's input: values that cost a kernel or a ``d_model``-wide matmul to

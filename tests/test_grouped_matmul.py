@@ -301,9 +301,13 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_shape(one_chip, chu
     assert f"[1,{seq},{d},{n}]" not in text and f"[{seq},{d},{n}]" not in text and f"[1,{seq},{n},{d // 128},128]" not in text
 
 
-def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+@pytest.mark.parametrize("h, bounded", [(32, True), (8, False)], ids=["ling_bounded", "solar_unbounded"])
+def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip, h, bounded):
     """The Kimi delta rule's two kernels at the Ling cell's shape (32 heads x
-    8,192 tokens x (128, 128), bf16), forward and the hand-written backward
+    8,192 tokens x (128, 128), bf16) and, in the form of a log-decay without a
+    lower bound (``kda_unbounded_fwd`` / ``kda_unbounded_bwd``: a block's own
+    columns a column at a time, 64 lane reductions of (heads, 16, 128) a chunk
+    and matrix), at the Solar-Open2 cell's (8 held heads), forward and the hand-written backward
     (this file holds the one fixture that may load the TPU compiler), on the
     arrays a layer holds: (b, s, h * d) from the convolutions, seen as (b, s,
     h, d) on the way in and the cotangents seen as (b, s, h * d) again on the
@@ -322,13 +326,13 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
     try:
-        b, s, h, d = 1, 8192, 32, 128
+        b, s, d = 1, 8192, 128
         wide = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
         decay = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32, sharding=one_chip)
         beta = jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip)
 
         def rule(q, k, v, g, beta):
-            o = kda.kda_rule(*(t.reshape(b, s, h, d) for t in (q, k, v, g)), beta, interpret=False)
+            o = kda.kda_rule(*(t.reshape(b, s, h, d) for t in (q, k, v, g)), beta, bounded=bounded, interpret=False)
             return jnp.moveaxis(o, 2, 1)
 
         def grads(*x):
@@ -338,7 +342,8 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
-    assert len(calls) == 2 and "kda_fwd" in calls[0] + calls[1] and "kda_bwd" in calls[0] + calls[1]
+    names = ("kda_fwd", "kda_bwd") if bounded else ("kda_unbounded_fwd", "kda_unbounded_bwd")
+    assert len(calls) == 2 and all(name in calls[0] + calls[1] for name in names)
     assert "reduce-window" not in text
     whole = re.compile(r"\[((\d+),)*\d+\]")  # a result's dims
 

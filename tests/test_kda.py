@@ -307,3 +307,123 @@ def test_bfloat16_inputs_keep_their_type_and_a_float32_state():
 def test_a_chunk_that_is_not_whole_blocks_is_refused():
     with pytest.raises(ValueError, match="whole blocks"):
         kda_rule(*_inputs(48, "spread"), chunk=24)
+
+
+
+# -- a log-decay without a lower bound, beta up to 2 (the published gate) --------
+
+#: how the unbounded log-decay is drawn: magnitudes log-uniform from 1e-3 to 40
+#: (what ``-exp(A_log) softplus(.)`` covers), every token AT -40 (a chunk's
+#: running sum reaches -2,560), and channels that jump between -40 and -0.01
+UNBOUNDED_DECAYS = ("wide", "deep", "jumps")
+
+
+def _unbounded_inputs(seq, decay, seed=0):
+    """As :func:`_inputs` with ``g`` as low as -40 and ``beta`` in (0, 2)."""
+    q, k, v, _, _ = _inputs(seq, "none", seed)
+    rs = np.random.RandomState(seed + 100)
+    g = {"wide": -np.exp(rs.uniform(np.log(1e-3), np.log(40.0), (B, seq, H, DK))),
+         "deep": np.full((B, seq, H, DK), -40.0),
+         "jumps": np.where(rs.rand(B, seq, H, DK) < 0.1, -40.0, -0.01)}[decay]
+    return q, k, v, jnp.asarray(g, jnp.float32), jnp.asarray(rs.uniform(0, 2, (B, seq, H)), jnp.float32)
+
+
+def _unbounded(*args, **options):
+    return kda_rule(*args, bounded=False, **options)
+
+
+@pytest.mark.parametrize("decay", UNBOUNDED_DECAYS)
+@pytest.mark.parametrize("seq", [128, 80])
+def test_an_unbounded_log_decay_follows_the_recurrence(seq, decay):
+    """Forward and all five cotangents of the rule with ``bounded=False``
+    against the token-by-token recurrence, with log-decays down to -40 and
+    ``beta`` up to 2; the bounded form, which factors ``e^{c_i - c_j}`` round
+    a block's middle row, is far off on the same operands (its exponents are
+    cut at +-45), so these cases do tell the two apart."""
+    args = _unbounded_inputs(seq, decay, seed=21)
+    want = _recurrence(*args)
+    assert _rel(_unbounded(*args), want) < REL_TOL
+    assert _rel(kda_rule(*args), want) > 0.1
+    weights = jnp.asarray(np.random.RandomState(22).randn(B, seq, H, DV), jnp.float32)
+    got, want = _grads(_unbounded, args, weights), _grads(_recurrence, args, weights)
+    scale = {name: jnp.linalg.norm(w) for name, w in zip(NAMES, want)}
+    scale["g"] = jnp.maximum(scale["g"], 0.1 * scale["k"])  # every token at -40: the decay's gradient is e^-40 of the keys'
+    for name, g, w in zip(NAMES, got, want):
+        assert float(jnp.linalg.norm(g - w) / scale[name]) < REL_TOL, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("decay", UNBOUNDED_DECAYS)
+def test_hand_written_chunk_backward_is_the_vjp_of_the_unbounded_chunk(decay, dtype):
+    """`test_hand_written_chunk_backward_is_the_vjp_of_the_chunk` for the
+    chunk of an unbounded log-decay: the blocks' own columns go back a column
+    at a time through the same ``e^{c_i - c_j}`` as forward."""
+    args, (d_o, d_state) = _one_chunk("none", dtype)
+    g, beta = (jnp.moveaxis(t[0], 1, 0) for t in _unbounded_inputs(kda.DEFAULT_CHUNK, decay, seed=23)[3:])
+    args = (*args[:3], g, beta[..., None], args[5])
+    _, pull = jax.vjp(lambda *a: kda._chunk(*a, bounded=False), *(t.astype(jnp.float32) for t in args))
+    want = pull((d_o.astype(jnp.float32), d_state))
+    got = [t.astype(jnp.float32) for t in kda._chunk_bwd(*args, d_o, d_state, bounded=False)]
+    names = ("q", "k", "v", "g", "beta", "state")
+    scale = {name: jnp.linalg.norm(w) for name, w in zip(names, want)}
+    scale["g"] = jnp.maximum(scale["g"], 0.1 * scale["k"])
+    for name, g, w in zip(names, got, want):
+        tol = 4e-3 if dtype == jnp.bfloat16 and name in "qkv" else REL_TOL
+        assert float(jnp.linalg.norm(g - w) / scale[name]) < tol, name
+
+
+def test_unbounded_pallas_kernels_are_the_xla_scan():
+    """Both kernels of the unbounded form through the Pallas interpreter
+    against their twin, on a padded sequence, under names of their own."""
+    args = _unbounded_inputs(80, "wide", seed=24)
+    weights = jnp.asarray(np.random.RandomState(25).randn(B, 80, H, DV), jnp.float32)
+    calls = REGISTRY.counter(COUNTER_TRAIN_KDA_KERNEL_CALLS, "", labels=("kernel",))
+    names = ("kda_fwd", "kda_bwd", "kda_unbounded_fwd", "kda_unbounded_bwd")
+    before = {name: calls.labels(kernel=name).value for name in names}
+    assert _rel(_unbounded(*args, interpret=True), _unbounded(*args)) < 1e-6
+    kernels = _grads(lambda *a: _unbounded(*a, interpret=True), args, weights)
+    for name, a, b in zip(NAMES, kernels, _grads(_unbounded, args, weights)):
+        assert a.shape == b.shape and float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-3)) < 1e-6, name
+    after = {name: calls.labels(kernel=name).value - before[name] for name in names}
+    assert after == {"kda_fwd": 0, "kda_bwd": 0, "kda_unbounded_fwd": 2, "kda_unbounded_bwd": 1}
+
+
+# -- what must not move: the bounded form's kernels at the Ling cell's shapes ----
+
+#: name: (heads, tokens, d_k, d_v): a Kimi-delta layer of ``ling-3.0-flash-d7.train-8k``, and a toy
+_BOUNDED_SHAPES = {"ling": (32, 8192, 128, 128), "toy": (3, 128, 32, 16)}
+
+
+def bounded_jaxpr_digest(name):
+    """The rule in the bounded form on the kernels' route, forward and
+    backward: its jaxpr (both kernels' bodies, grids, block specs and
+    scratch) with what embeds an address, a path or a line taken out."""
+    import hashlib
+    import re
+
+    heads, seq, dk, dv = _BOUNDED_SHAPES[name]
+    q = jax.ShapeDtypeStruct((1, seq, heads, dk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, seq, heads, dv), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, seq, heads, dk), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, seq, heads), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return kda_rule(q, k, v, g, beta, interpret=False).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(q, q, v, g, beta))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    text = re.sub(r"/[^ :]*hops_tpu/ops/(\w+)\.py:\d+", r"\1.py", text)
+    return {"sha256": hashlib.sha256(text.encode()).hexdigest(), "lines": len(text.splitlines())}
+
+
+@pytest.mark.parametrize("name", sorted(_BOUNDED_SHAPES))
+def test_a_bounded_log_decay_traces_to_the_parents_kernels(name):
+    """``tests/data/kda_bounded_jaxpr.json`` was written with
+    `bounded_jaxpr_digest` by PR 47's parent (20ab083), before the rule took a
+    log-decay without a lower bound: a call in the bounded form (the default,
+    Ling's) traces to the same two kernel bodies, grids and block specs."""
+    import json
+    import pathlib
+
+    recorded = json.loads((pathlib.Path(__file__).parent / "data" / "kda_bounded_jaxpr.json").read_text())
+    assert bounded_jaxpr_digest(name) == recorded[name]
