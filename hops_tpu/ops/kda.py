@@ -134,7 +134,13 @@ Two routes run the two functions, chosen by :func:`implementation`:
 
 :func:`kda_rule` is a ``jax.custom_vjp``: the forward keeps its inputs and
 the state entering each chunk (float32, ``seq / C`` x d_k x d_v a head), the
-backward recomputes the chunk from them.
+backward recomputes the chunk from them. The forward rule names its two
+results (``kda_out``: ``o`` in ``v``'s type; ``kda_states``), so a block's
+``remat`` (``TransformerLM(remat=True)``, ``telemetry.spans.REMAT_KEEPS``)
+holds them as it holds the flash forward's: the block's second forward makes
+the operands again (projections, gate, convolutions: the backward reads
+them), not this rule, and a compiled step holds one forward call a layer
+(335 MB a layer kept at 32 heads x 8,192 tokens x (128, 128), for 5.07 ms).
 """
 
 from __future__ import annotations
@@ -149,7 +155,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from hops_tpu.ops.gated_delta import _NT, _TN, _column, _dot, _iotas, _unit_lower_inverse
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import COUNTER_TRAIN_KDA_KERNEL_CALLS
+from hops_tpu.telemetry.spans import COUNTER_TRAIN_KDA_KERNEL_CALLS, keep
 
 F32 = jnp.float32
 DEFAULT_CHUNK = 64
@@ -647,7 +653,9 @@ def _rule_fwd(q, k, v, g, beta, route):
         o, states = (_forward_pallas if bounded else _forward_pallas_unbounded)(q, k, v, g, beta, interpret=interpret)
     else:
         o, states = _forward_scan(q, k, v, g, beta, bounded)
-    return o.astype(v.dtype), (q, k, v, g, beta, states)
+    # what a block's remat holds of this layer, so that its second forward makes the operands and not this call again
+    o, states = keep(o.astype(v.dtype), "kda_out"), keep(states, "kda_states")
+    return o, (q, k, v, g, beta, states)
 
 
 def _rule_bwd(route, kept, d_o):

@@ -222,8 +222,15 @@ COUNTER_TRAIN_HELD_HEADS = "hops_tpu_train_held_heads_total"
 #: ``U``, ``V'``, and the selective scan's ``y`` and chunk start states
 #: (504 MB for 3.5 ms at the Phi-4-flash cell's widths: with them XLA's own
 #: rematerialization ran two FFN-wide matmuls again to fit the chip, PERF.md
-#: section 6, PR 32).
-REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out")
+#: section 6, PR 32). ``kda_out`` / ``kda_states``: the Kimi delta rule's
+#: result and the float32 state entering each chunk (``ops/kda.py:_rule_fwd``,
+#: and nowhere else: only a Kimi-delta layer makes them), the exception to the
+#: row-a-token rule that such a layer earns: its forward kernel runs at 7.7 %
+#: of its roof, so the 335 MB a layer of the Ling cell buy 5.07 ms (66 MB a
+#: ms) and the 84 MB of Solar-Open2's held heads 1.56 ms, where the selective
+#: scan's pair cost 144 MB a ms, and both cells have the room (PERF.md
+#: section 6, PR 48).
+REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out", "kda_out", "kda_states")
 #: One per value traced under a ``REMAT_KEEPS`` name (``what``), whether or
 #: not a ``remat`` encloses it: outside one the name is the identity.
 COUNTER_TRAIN_REMAT_KEPT = "hops_tpu_train_remat_kept_total"

@@ -65,12 +65,26 @@ def flash_kernel_at_any_length(monkeypatch):
     monkeypatch.setattr(attention, "_XLA_FASTER_BELOW", 0)
 
 
+def _interpret(monkeypatch, module, name):
+    whole = getattr(module, name)
+    monkeypatch.setattr(module, name, lambda *a, **kw: whole(*a, **{**kw, "interpret": True}))
+
+
 @pytest.fixture
 def scan_kernels_interpreted(monkeypatch):
     """The selective scan takes its two Pallas kernels through the
     interpreter, not its XLA twin."""
     from hops_tpu.ops import selective_scan
 
-    whole = selective_scan.selective_scan
-    monkeypatch.setattr(selective_scan, "selective_scan", lambda *a, **kw: whole(*a, **{**kw, "interpret": True}))
+    _interpret(monkeypatch, selective_scan, "selective_scan")
+
+
+@pytest.fixture
+def delta_kernels_interpreted(monkeypatch):
+    """The Kimi delta rule and the gated delta rule take their Pallas
+    kernels through the interpreter, not their XLA twins."""
+    from hops_tpu.ops import gated_delta, kda
+
+    _interpret(monkeypatch, kda, "kda_rule")
+    _interpret(monkeypatch, gated_delta, "gated_delta_rule")
 

@@ -388,6 +388,48 @@ def test_unbounded_pallas_kernels_are_the_xla_scan():
     assert after == {"kda_fwd": 0, "kda_bwd": 0, "kda_unbounded_fwd": 2, "kda_unbounded_bwd": 1}
 
 
+@pytest.mark.parametrize("route", ["kernels", "xla_scan"])
+@pytest.mark.parametrize("bounded", [True, False], ids=["bounded", "unbounded"])
+def test_a_checkpoint_that_keeps_the_rules_names_runs_its_forward_once(bounded, route):
+    """The forward rule names what it wrote (``kda_out``, ``kda_states``),
+    so a ``jax.checkpoint`` whose policy saves ``REMAT_KEEPS`` by name (a
+    block's ``remat``) holds the two and its second forward holds no call of
+    the rule; one whose policy lacks the two names (the parent's) runs the
+    forward again. ``hops_tpu_train_kda_kernel_calls_total`` counts TRACES:
+    JAX traces the forward rule twice under either policy."""
+    from test_remat_keeps import _kept, _mosaic_calls, _twin_forward_scans
+
+    from hops_tpu.telemetry.spans import REMAT_KEEPS
+
+    args = _inputs(128, "spread", seed=31)
+    forward, backward = ("kda_fwd", "kda_bwd") if bounded else ("kda_unbounded_fwd", "kda_unbounded_bwd")
+    counter = REGISTRY.counter(COUNTER_TRAIN_KDA_KERNEL_CALLS, "", labels=("kernel",))
+
+    for names, forwards in ((REMAT_KEEPS, 1), (REMAT_KEEPS[:4], 2)):
+        def rule(*a):  # squared, as the layer's norm reads ``o``: JAX holds a named value only where the backward reads it
+            return jnp.square(kda_rule(*a, bounded=bounded, interpret=True if route == "kernels" else None))
+
+        before = {name: counter.labels(kernel=name).value for name in (forward, backward)}
+        policy = jax.checkpoint_policies.save_only_these_names(*names)
+        jaxpr = jax.make_jaxpr(lambda d_o, *a: jax.vjp(jax.checkpoint(rule, policy=policy), *a)[1](d_o))(
+            jnp.ones((B, 128, H, DV)), *args).jaxpr
+        kept = {name: aval for name, aval in _kept(jaxpr)}
+        if route == "kernels":
+            calls = _mosaic_calls(jaxpr)
+            assert (calls[forward], calls[backward]) == (forwards, 1)
+            traced = {name: counter.labels(kernel=name).value - before[name] for name in before}
+            assert traced == {forward: 2, backward: 1}
+        else:
+            assert _twin_forward_scans(jaxpr, over_tokens=False) == forwards
+        if forwards == 1:  # the kernels' layout (b, h, n, rows, cols), the scan's (n, b * h, rows, cols)
+            chunks = (B, H, 2) if route == "kernels" else (2, B * H)
+            assert set(kept) == {"kda_out", "kda_states"}
+            assert (kept["kda_out"].shape, kept["kda_out"].dtype) == ((*chunks, 64, DV), jnp.float32)  # v's type
+            assert (kept["kda_states"].shape, kept["kda_states"].dtype) == ((*chunks, DV, DK), jnp.float32)
+        else:
+            assert kept == {}
+
+
 # -- what must not move: the bounded form's kernels at the Ling cell's shapes ----
 
 #: name: (heads, tokens, d_k, d_v): a Kimi-delta layer of ``ling-3.0-flash-d7.train-8k``, and a toy
@@ -397,7 +439,10 @@ _BOUNDED_SHAPES = {"ling": (32, 8192, 128, 128), "toy": (3, 128, 32, 16)}
 def bounded_jaxpr_digest(name):
     """The rule in the bounded form on the kernels' route, forward and
     backward: its jaxpr (both kernels' bodies, grids, block specs and
-    scratch) with what embeds an address, a path or a line taken out."""
+    scratch) with what embeds an address, a path or a line taken out, and
+    without the two names the forward rule gives its results since PR 48
+    (``kda_out``, ``kda_states``: a ``name`` equation each, which lowers to
+    nothing but renames every variable after it)."""
     import hashlib
     import re
 
@@ -410,7 +455,9 @@ def bounded_jaxpr_digest(name):
     def loss(q, k, v, g, beta):
         return kda_rule(q, k, v, g, beta, interpret=False).astype(jnp.float32).sum()
 
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(q, q, v, g, beta))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kda, "keep", lambda x, what: x)
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(q, q, v, g, beta))
     text = re.sub(r" at 0x[0-9a-f]+", "", text)
     text = re.sub(r"/[^ :]*hops_tpu/ops/(\w+)\.py:\d+", r"\1.py", text)
     return {"sha256": hashlib.sha256(text.encode()).hexdigest(), "lines": len(text.splitlines())}
@@ -421,7 +468,9 @@ def test_a_bounded_log_decay_traces_to_the_parents_kernels(name):
     """``tests/data/kda_bounded_jaxpr.json`` was written with
     `bounded_jaxpr_digest` by PR 47's parent (20ab083), before the rule took a
     log-decay without a lower bound: a call in the bounded form (the default,
-    Ling's) traces to the same two kernel bodies, grids and block specs."""
+    Ling's) traces to the same two kernel bodies, grids and block specs, and
+    to the same text round them once the names PR 48 gives the forward's
+    results are taken out."""
     import json
     import pathlib
 

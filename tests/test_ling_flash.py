@@ -539,6 +539,30 @@ def test_step_counts_its_layers_and_the_rules_route(tiny_step):
     assert traces.labels(impl="xla_scan").value - before_traces >= 2
 
 
+def test_a_remat_step_holds_one_forward_call_a_kimi_delta_layer(tiny_step, delta_kernels_interpreted):
+    """With the rule's kernels interpreted (steered here) a traced step
+    under ``remat`` holds ``kda_fwd`` and ``kda_bwd`` once a Kimi-delta
+    layer: ``remat`` keeps ``kda_out`` and ``kda_states`` (PR 48; the
+    parent's step held the forward twice). The two counters count traces,
+    and JAX traces the forward rule twice a layer."""
+    from test_remat_keeps import _mosaic_calls
+
+    model, step, tokens = tiny_step
+    layers = MIXERS.count(KDA)
+    state = jax.eval_shape(lambda: _state(model))
+    traced = REGISTRY.counter("hops_tpu_train_kda_kernel_calls_total", "", labels=("kernel",))
+    named = REGISTRY.counter("hops_tpu_train_remat_kept_total", "", labels=("what",))
+
+    def counts():
+        return [traced.value(kernel="kda_fwd"), traced.value(kernel="kda_bwd"),
+                named.value(what="kda_out"), named.value(what="kda_states")]
+
+    before = counts()
+    calls = _mosaic_calls(jax.make_jaxpr(step)(state, {"tokens": tokens}).jaxpr)
+    assert calls["kda_fwd"] == calls["kda_bwd"] == layers
+    assert [after - was for after, was in zip(counts(), before)] == [2 * layers, layers, 2 * layers, 2 * layers]
+
+
 @pytest.fixture(scope="module")
 def op_names(tiny_step):
     model, step, tokens = tiny_step

@@ -368,17 +368,17 @@ def test_defaults_keep_the_parents_lowered_step(toy):
     """``tests/data/transformer_lm_parent_lowered.json`` was written with
     ``lowered_digest``: ``phi4_shaped`` (with its tree, and ``ling_shaped``'s) by
     PR 43's parent (3d29bff), before ``Block`` was rebuilt round ``LayerSpec``;
-    ``ling_shaped`` by PR 46 on top of 652ce61, because that toy holds a latent
-    layer (and a latent MTP module) and what ``LatentAttention`` lowers to
-    changed by design: no 32-head copy of K is built (the QK norm runs over
-    ``k_nope`` and the one rotary key as two parts, the keys reach attention as
-    ``(k_nope, k_rope)`` and, at the toy's 48 keys, are put side by side by
-    ``attention_reference`` alone) and the rotary channels turn in
-    ``rotate_from`` (a 0/1 product for a pair's other member, no strided slice,
-    no stack; 10,031 lines where 652ce61 wrote 10,076, PR 45's text, whose held
-    share lowers to a loop over live row tiles); the four toys without a latent
-    layer read their older digests, which is the proof that no other cell's
-    step moved;
+    ``ling_shaped`` by PR 48 on top of 21d13a8, because that toy holds two
+    Kimi-delta layers under ``remat`` and what ``remat`` keeps of them changed
+    by design: the rule's ``o`` and chunk states are named (``kda_out``,
+    ``kda_states``: ``ops/kda.py:_rule_fwd``), so each layer's second forward
+    lost its scan over the chunks (14 ``stablehlo.while`` where 21d13a8 wrote
+    16, 293 ``dot_general`` for 341) and the first gained a
+    ``reduce_precision`` on each kept ``o`` (5 for 3); 9,400 lines where PR 46
+    wrote 10,031 (that PR moved what ``LatentAttention`` lowers to: the keys
+    in two parts, ``rotate_from``). The four toys without a Kimi-delta layer
+    read their older digests, which is the proof that no other cell's step
+    moved;
     ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
     PR 31's parent (no ``remat``: the fields added since, and the names
     ``remat`` keeps values by, leave their lowered text as it was);

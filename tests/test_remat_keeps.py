@@ -1,46 +1,62 @@
 """What ``TransformerLM(remat=True)`` holds for the backward pass and what
-its second forward still runs, read from traced programs (nothing here is
-executed): a block of each kind keeps its input and the values named in
+its second forward still runs, read from traced programs (one test here
+executes anything): a block of each kind keeps its input and the values named in
 ``telemetry.spans.REMAT_KEEPS`` that its backward reads, nothing else; the
-flash forward appears once a layer in a step's gradient, not twice; the
-selective scan's forward, which is not kept, twice.
+flash forward and the Kimi delta rule's appear once a layer in a step's
+gradient, not twice; the selective scan's forward and the gated delta
+rule's, which are not kept, twice.
 
 The kernels are steered in the tests (the program has no option for it):
 flash attention takes the Pallas kernel at 2,048 keys, as on the chip; the
-scan takes its kernels with ``interpret=True`` or, left alone, its XLA twin.
+scan and the two delta rules take their kernels with ``interpret=True`` or,
+left alone, their XLA twins.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from flax import linen as nn
 from jax.extend.core import Literal
 
+from hops_tpu.models import transformer
 from hops_tpu.models.transformer import Block, TransformerLM
 from hops_tpu.telemetry import REGISTRY
 from hops_tpu.telemetry.export import render_prometheus
 from hops_tpu.telemetry.spans import COUNTER_TRAIN_REMAT_KEPT, REMAT_KEEPS, keep
 
 D_MODEL, HEADS, MLP_HIDDEN, SEQ = 64, 4, 192, 2048
+KEY_DIM, VALUE_DIM, CHUNK = 16, 32, 64  # a Kimi-delta head, and the tokens of a chunk of its rule
 KEPT = jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)
 HYBRID = dict(norm_placement="post_sublayer", mlp_hidden=MLP_HIDDEN, qk_norm=True, rope_base=None,
               linear_num_heads=HEADS, linear_key_dim=8, linear_value_dim=16)
 FLASH = dict(norm_kind="layer", norm_eps=1e-5, mlp_hidden=MLP_HIDDEN, rope_base=None, use_bias=True,
              attention_form="differential", num_kv_heads=2)
+KIMI = dict(mlp_hidden=MLP_HIDDEN, rope_base=None, linear_num_heads=HEADS, linear_key_dim=KEY_DIM, linear_value_dim=VALUE_DIM)
+#: the published gate (Solar-Open2's): no lower bound on the log-decay, kernels ``kda_unbounded_*``
+UNBOUNDED = dict(linear_lower_bound=None, kda_gate_rank=8, kda_allow_neg_eigval=True, kda_output_gate="channel_wise")
 
 TINY_LM = dict(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, dtype=jnp.bfloat16)
 HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
 FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
                "gated_memory", "cross_attention")
+KIMI_KINDS = ("kimi_delta_attention", "full_attention", "kimi_delta_attention")
 STEPS = {
     "hybrid": dict(TINY_LM, **HYBRID, num_layers=4, layer_types=HYBRID_KINDS),
     "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
+    "kimi": dict(TINY_LM, **KIMI, num_layers=3, layer_types=KIMI_KINDS),
+    "kimi_unbounded": dict(TINY_LM, **KIMI, **UNBOUNDED, num_layers=3, layer_types=KIMI_KINDS),
 }
+#: the Kimi delta rule's kernels by toy (forward, backward)
+KDA_KERNELS = {"kimi": ("kda_fwd", "kda_bwd"), "kimi_unbounded": ("kda_unbounded_fwd", "kda_unbounded_bwd")}
 
 # kind of block -> (the toy and the layer of it whose ``layer_specs()`` entry the block is built from, what it is
 # handed, the names its remat keeps): ``mixer_out`` wherever a norm reads the mixer's result or the sum it enters,
 # ``mlp_out`` under a norm on the sublayer's output only. Of the Mamba layers only the last before the gated memory
-# unit hands its ``y`` on, of the attention layers the full one, whose K and V the cross layer reads.
+# unit hands its ``y`` on, of the attention layers the full one, whose K and V the cross layer reads. Of the linear
+# layers a Kimi-delta layer keeps its rule's result and chunk states; the gated delta rule and the scan keep nothing.
 BLOCKS = {
     "post_norm_linear_attention": ("hybrid", 0, None, {"mixer_out", "mlp_out"}),
     "post_norm_full_attention": ("hybrid", 3, None, {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
@@ -50,18 +66,23 @@ BLOCKS = {
     "pre_norm_full_differential": ("phi4_flash", 5, None, {"flash_out", "flash_lse", "mixer_out"}),
     "pre_norm_cross_differential": ("phi4_flash", 7, "kv", {"flash_out", "flash_lse", "mixer_out"}),
     "pre_norm_gated_memory": ("phi4_flash", 6, "memory", {"mixer_out"}),
+    "pre_norm_kimi_delta": ("kimi", 0, None, {"kda_out", "kda_states", "mixer_out"}),
+    "pre_norm_kimi_delta_unbounded": ("kimi_unbounded", 2, None, {"kda_out", "kda_states", "mixer_out"}),
 }
 
 
-def _walk(jaxpr):
-    """Every equation of ``jaxpr`` and of the programs its equations hold."""
+def _walk(jaxpr, kernels=True):
+    """Every equation of ``jaxpr`` and of the programs its equations hold
+    (``kernels=False``: but not of a Mosaic call's body)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if not kernels and eqn.primitive.name == "pallas_call":
+            continue
         for value in eqn.params.values():
             for inner in value if isinstance(value, (tuple, list)) else (value,):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _walk(inner)
+                    yield from _walk(inner, kernels)
 
 
 def _backward(fn, *args):
@@ -100,17 +121,20 @@ def _mosaic_calls(jaxpr):
     return calls
 
 
-def _twin_forward_scans(jaxpr):
-    """Forward passes of the scan's XLA twin: a ``lax.scan`` over chunks,
-    first chunk first, round a ``lax.scan`` over a chunk's tokens (the
-    backward walks the chunks in reverse)."""
-    return sum(1 for eqn in _walk(jaxpr) if eqn.primitive.name == "scan" and not eqn.params["reverse"]
-               and any(inner.primitive.name == "scan" for inner in _walk(eqn.params["jaxpr"].jaxpr)))
+def _twin_forward_scans(jaxpr, over_tokens=True):
+    """Forward passes of a rule's XLA twin: a ``lax.scan`` over chunks,
+    first chunk first (the backward walks the chunks in reverse), outside
+    any kernel. The selective scan's is round a ``lax.scan`` over a chunk's
+    tokens; a chunk of the Kimi delta rule (``over_tokens=False``) is
+    products alone."""
+    return sum(1 for eqn in _walk(jaxpr, kernels=False) if eqn.primitive.name == "scan" and not eqn.params["reverse"]
+               and over_tokens == any(inner.primitive.name == "scan" for inner in _walk(eqn.params["jaxpr"].jaxpr)))
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("kind", sorted(BLOCKS))
-def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(kind, remat, scan_kernels_interpreted):
+def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
+        kind, remat, scan_kernels_interpreted, delta_kernels_interpreted):
     toy, layer, handed, names = BLOCKS[kind]
     model = TransformerLM(**STEPS[toy])
     spec = model.layer_specs()[layer]
@@ -131,9 +155,9 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     shapes = {name: aval for name, aval in kept}
     for name in names & {"mixer_out", "mlp_out"}:  # one d_model-wide row a token
         assert shapes[name].shape == (1, SEQ, D_MODEL) and shapes[name].dtype == jnp.bfloat16
-    # nothing FFN-wide, and no float32 array but the flash rows' statistics
+    # nothing FFN-wide, and no float32 array but the flash rows' statistics and the Kimi delta rule's chunk states
     assert all(aval.shape[-1] != MLP_HIDDEN for _, aval in kept)
-    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse"}
+    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse", "kda_states"}
     # the second forward (inside the remat equation) holds no flash forward; the backward's kernels are there
     second, = [eqn.params["jaxpr"] for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
     inside = _mosaic_calls(second)
@@ -144,6 +168,17 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     if spec.mixer == "mamba":  # the scan's results are not kept: its forward runs again
         assert inside["selective_scan_fwd"] == inside["selective_scan_bwd"] == 1
         assert _mosaic_calls(jaxpr)["selective_scan_fwd"] == 2
+    if spec.mixer == "linear_attention":  # nor the gated delta rule's: its three forward kernels run again
+        assert inside["gated_delta_fwd"] == inside["gated_delta_bwd"] == 1
+        assert _mosaic_calls(jaxpr)["gated_delta_fwd"] == 2
+    if spec.mixer == "kimi_delta_attention":  # the Kimi delta rule's are: the second forward makes its operands only
+        forward, backward = KDA_KERNELS[toy]
+        assert forward not in inside and inside[backward] == 1
+        assert _mosaic_calls(jaxpr)[forward] == _mosaic_calls(jaxpr)[backward] == 1
+        # as the kernel writes them, head-major by chunk: o in the model's type, the state entering each chunk in float32
+        chunks = (1, HEADS, SEQ // CHUNK)
+        assert shapes["kda_out"].shape == (*chunks, CHUNK, VALUE_DIM) and shapes["kda_out"].dtype == jnp.bfloat16
+        assert shapes["kda_states"].shape == (*chunks, VALUE_DIM, KEY_DIM)
 
 
 def _lm_backward(model):
@@ -158,27 +193,93 @@ def _lm_backward(model):
 def test_a_steps_gradient_runs_each_forward_kernel_once_a_layer(toy, remat, route, request):
     """With ``remat`` the parent ran every forward kernel twice a layer (the
     step's forward and the block's second one); the kept results leave one
-    flash forward. The selective scan's forward and the gated delta rule's
-    forward kernels are not kept and still run twice
+    flash forward and one forward of the Kimi delta rule (PR 48). The
+    selective scan's forward and the gated delta rule's forward kernels are
+    not kept and still run twice
     (``tests/test_olmo_hybrid.py::test_step_counts_the_rules_kernels``)."""
     if route == "kernels":
         request.getfixturevalue("scan_kernels_interpreted")
+        request.getfixturevalue("delta_kernels_interpreted")
     jaxpr = _lm_backward(TransformerLM(**{**STEPS[toy], "remat": remat}))
     kinds = STEPS[toy]["layer_types"]
-    attention = sum(kind.endswith("_attention") and kind != "linear_attention" for kind in kinds)
+    attention = sum(kind.endswith("_attention") and kind not in ("linear_attention", "kimi_delta_attention") for kind in kinds)
     calls = _mosaic_calls(jaxpr)
     assert calls["flash_fwd"] == calls["flash_bwd"] == attention  # one backward Mosaic call a layer, under remat too
     assert not {"flash_bwd_dq", "flash_bwd_dkv"} & set(calls)
-    mamba = kinds.count("mamba")
+    mamba, gated, kimi = (kinds.count(kind) for kind in ("mamba", "linear_attention", "kimi_delta_attention"))
+    kda_forward, kda_backward = KDA_KERNELS.get(toy, KDA_KERNELS["kimi"])
     if route == "kernels":
         assert calls.get("selective_scan_fwd", 0) == (1 + remat) * mamba and calls.get("selective_scan_bwd", 0) == mamba
+        assert calls.get("gated_delta_fwd", 0) == (1 + remat) * gated and calls.get("gated_delta_bwd", 0) == gated
+        assert calls.get(kda_forward, 0) == calls.get(kda_backward, 0) == kimi  # once a layer, under remat too
     else:
-        assert "selective_scan_fwd" not in calls and _twin_forward_scans(jaxpr) == (1 + remat) * mamba
+        assert not {"selective_scan_fwd", "gated_delta_fwd", kda_forward} & set(calls)
+        assert _twin_forward_scans(jaxpr) == (1 + remat) * mamba
+        if kimi:  # (these toys hold no other scan of products: the gated delta rule's twin is one, and runs twice)
+            assert _twin_forward_scans(jaxpr, over_tokens=False) == kimi
     kept = [name for name, _ in _kept(jaxpr)]
     if remat:
         expected = {"flash_out": attention, "flash_lse": attention, "mixer_out": len(kinds),
-                    "mlp_out": len(kinds) * (toy == "hybrid")}
+                    "mlp_out": len(kinds) * (toy == "hybrid"), "kda_out": kimi, "kda_states": kimi}
         assert {name: kept.count(name) for name in REMAT_KEEPS} == expected
+
+
+@functools.cache
+def _loss_and_gradients(toy, route, dtype, layers, program):
+    """Loss and every gradient of ``layers`` layers of ``toy`` on 2 x 128
+    tokens under ``program``: ``plain`` (no ``remat``), ``parents`` (``remat``
+    with the four names PR 32 keeps: the rule's forward runs again) or
+    ``kept`` (this tree's ``remat``). ``route`` as the tests above steer it."""
+    from conftest import _interpret
+
+    from hops_tpu.ops import kda
+
+    model = TransformerLM(**{**STEPS[toy], "num_layers": layers, "layer_types": KIMI_KINDS[:layers], "dtype": dtype,
+                             "remat": program != "plain"})
+    tokens = jnp.asarray(np.random.RandomState(0).randint(0, 256, (2, 2 * CHUNK)), jnp.int32)
+    weights = jnp.asarray(np.random.RandomState(1).randn(2, 2 * CHUNK, D_MODEL), jnp.float32)
+    with pytest.MonkeyPatch.context() as patch:
+        if route == "kernels":
+            _interpret(patch, kda, "kda_rule")
+        if program == "parents":
+            patch.setattr(transformer, "REMAT_KEEPS", REMAT_KEEPS[:4])
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+        return jax.jit(jax.value_and_grad(
+            lambda p: jnp.sum(model.apply(p, tokens, train=True, return_hidden=True) * weights)))(params)
+
+
+@pytest.mark.parametrize("program", ["plain", "parents", "kept"])
+@pytest.mark.parametrize("toy, route, dtype, layers", [
+    *((toy, route, jnp.float32, 1) for toy in sorted(KDA_KERNELS) for route in ("kernels", "xla_twin")),
+    ("kimi", "xla_twin", jnp.bfloat16, 3)])
+def test_keeping_the_kimi_delta_rules_results_changes_no_value(toy, route, dtype, layers, program):
+    """Three programs of one model: without ``remat``, with the parent's
+    ``remat`` and with this tree's. In float32 (a Kimi-delta block) loss and
+    every gradient are equal to the bit: the backward reads the states the
+    first forward wrote where it read the same ones written again. In
+    bfloat16 (three layers) XLA's CPU fusions round a block's second forward
+    differently from its first, with or without the kept values, and the
+    three programs differ in the second digit of the gradient; what is kept
+    is no further from the program without ``remat`` than the parent's is."""
+    assert REMAT_KEEPS[4:] == ("kda_out", "kda_states")
+    plain = _loss_and_gradients(toy, route, dtype, layers, "plain")
+    if program == "plain":  # every parameter of the Kimi-delta block moves the loss
+        moves = jax.tree.map(lambda g: float(jnp.max(jnp.abs(g))) > 0, plain[1]["params"]["block_0"])
+        assert np.isfinite(float(plain[0])) and all(jax.tree.leaves(moves)), moves
+        return
+    got = _loss_and_gradients(toy, route, dtype, layers, program)
+    if dtype == jnp.float32:
+        jax.tree.map(np.testing.assert_array_equal, got, plain)
+        return
+
+    def distance(got):
+        return (sum(float(jnp.sum(jnp.square(g.astype(jnp.float32) - p.astype(jnp.float32))))
+                    for g, p in zip(jax.tree.leaves(got), jax.tree.leaves(plain)))
+                / sum(float(jnp.sum(jnp.square(p.astype(jnp.float32)))) for p in jax.tree.leaves(plain))) ** 0.5
+
+    assert distance(got) < 0.1
+    if program == "kept":  # (or than one rounding of a bfloat16)
+        assert distance(got) <= max(distance(_loss_and_gradients(toy, route, dtype, layers, "parents")), 2.0 ** -8)
 
 
 def test_a_routed_blocks_remat_keeps_the_flash_results_too():

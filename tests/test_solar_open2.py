@@ -416,6 +416,28 @@ def test_remat_changes_nothing(tiny):
     assert _rel(again["grad"], plain["grad"]) < 1e-5
 
 
+def test_a_remat_step_holds_one_forward_call_a_kimi_delta_layer(delta_kernels_interpreted):
+    """With the rule's kernels interpreted (steered here) a traced step
+    under ``remat`` holds ``kda_unbounded_fwd`` and ``kda_unbounded_bwd``
+    once a Kimi-delta layer and the bounded form's kernels nowhere:
+    ``remat`` keeps ``kda_out`` and ``kda_states`` (PR 48; the parent's
+    step held the forward twice), of the held heads alone."""
+    from test_remat_keeps import _kept, _mosaic_calls
+
+    model = TransformerLM(**{**TINY, "remat": True})
+    state = jax.eval_shape(functools.partial(common.create_train_state, model, input_shape=(1, 8), input_dtype=jnp.int32),
+                           jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, SEQ + 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(make_lm_train_step(loss_chunk=32, router_bias_rate=1e-3))(state, {"tokens": tokens}).jaxpr
+    calls = _mosaic_calls(jaxpr)
+    assert calls["kda_unbounded_fwd"] == calls["kda_unbounded_bwd"] == LAYERS.count(KDA)
+    assert not {"kda_fwd", "kda_bwd"} & set(calls)
+    held, chunks = TINY["held_heads"][1], -(-SEQ // 64)
+    kept = _kept(jaxpr)
+    assert sorted(aval.shape for name, aval in kept if name == "kda_states") == [(2, held, chunks, HEAD_DIM, HEAD_DIM)] * 3
+    assert sorted(aval.shape for name, aval in kept if name == "kda_out") == [(2, held, chunks, 64, HEAD_DIM)] * 3
+
+
 def test_fields_that_name_no_model_are_refused_in_words():
     for more, match in ((dict(layer_types=(GQA, KDA, KDA, "mamba")), "held_heads is built for"),
                         (dict(attention_form="differential"), "held_heads is built for")):
