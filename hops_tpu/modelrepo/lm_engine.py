@@ -50,7 +50,8 @@ from hops_tpu.models.generation import top_p_mask
 from hops_tpu.modelrepo.paged import BlockPool
 from hops_tpu.runtime import faultinject, flight, qos
 from hops_tpu.runtime.logging import get_logger
-from hops_tpu.telemetry.metrics import REGISTRY
+from hops_tpu.telemetry import spans, tracing
+from hops_tpu.telemetry.metrics import DEFAULT_BUCKETS, REGISTRY
 
 log = get_logger(__name__)
 
@@ -192,6 +193,17 @@ class _Request:
     # sampling makes the replayed stream identical); its TTFT was
     # already observed the first time around.
     ttft_observed: bool = False
+    # What the request waited for, read back through LMEngine.timing():
+    # the start of the iteration that first gave it a slot (monotonic; 0 =
+    # still queued), the ``seq`` of the iterations that admitted and
+    # finished it, how often it was preempted, and the instant the host got
+    # each of its tokens. All survive a preemption: the replayed stream
+    # re-emits tokens a streaming surface has already sent.
+    admitted_at: float = 0.0
+    first_iteration: int = 0
+    last_iteration: int = 0
+    preemptions: int = 0
+    token_t: list[float] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -229,6 +241,56 @@ class _SlotState:
     blocks: list[int] | None = None  # physical blocks, logical order
     shared_hit: bool = False  # admission reused prefix pages
     seq: int = 0  # admission order — preemption picks the newest
+
+
+#: Phase buckets reach down to 10 us: page and admission bookkeeping is
+#: two orders of magnitude under a dispatch.
+_PHASE_BUCKETS = (0.00001, 0.00005, 0.0001, 0.00025) + DEFAULT_BUCKETS
+
+
+class _Iteration:
+    """One ``step()``'s clock and counts (``telemetry/spans.py``: the
+    serving vocabulary). ``enter(phase)`` reads the clock once: that
+    reading ends the phase before and begins ``phase``, so the phases
+    tile the iteration and no interval is timed twice. A phase may be
+    entered again (a speculative engine's chunk, then its decode): its
+    seconds add up. ``now`` is the reading that began the current phase:
+    inside ``collect``, the instant the host got the tokens."""
+
+    __slots__ = ("seq", "wall", "t0", "now", "queued", "idle_s", "phase_s",
+                 "kinds", "rows_prefill", "rows_decode", "_phase", "_annotation")
+
+    def __init__(self, seq: int, queued: int, last_end: float | None):
+        self.seq, self.queued = seq, queued
+        self.wall = time.time()  # the span ring's clock
+        self.t0 = self.now = time.monotonic()
+        self.idle_s = self.t0 - last_end if last_end is not None else 0.0
+        self.phase_s = dict.fromkeys(spans.LM_PHASES, 0.0)
+        self.kinds: list[str] = []
+        self.rows_prefill = self.rows_decode = 0
+        self._phase: str | None = None
+        self._annotation: Any = None
+
+    def enter(self, phase: str | None) -> None:
+        """Begin ``phase`` (None: end the last one)."""
+        now = time.monotonic()
+        if self._phase is not None:
+            self.phase_s[self._phase] += now - self.now
+            self._annotation.__exit__(None, None, None)
+        self.now, self._phase = now, phase
+        if phase is not None:
+            # What span() does for its block: in a profiler session the
+            # phases lie on the host plane, on the device trace's clock.
+            self._annotation = jax.profiler.TraceAnnotation(
+                spans.LM_PHASE_ANNOTATION + phase)
+            self._annotation.__enter__()
+
+    def dispatched(self, kind: str, rows_prefill: int = 0, rows_decode: int = 0) -> None:
+        """A program of ``kind`` is about to run over these rows."""
+        if kind not in self.kinds:
+            self.kinds.append(kind)
+        self.rows_prefill += rows_prefill
+        self.rows_decode += rows_decode
 
 
 class LMEngine:
@@ -1450,6 +1512,36 @@ class LMEngine:
             "Engine dispatch waves that raised; their in-flight "
             "requests were failed and the scheduler continued",
         ).labels()
+        # The serving vocabulary (telemetry/spans.py): where an iteration
+        # went and what a request waited for. The clock reads behind them
+        # are taken whether or not tracing is on; the span is not.
+        phase_seconds = REGISTRY.histogram(
+            spans.HIST_LM_PHASE_SECONDS,
+            "Seconds of one engine iteration spent in each phase",
+            labels=("phase",), buckets=_PHASE_BUCKETS,
+        )
+        self._m_phase = {p: phase_seconds.labels(phase=p) for p in spans.LM_PHASES}
+        self._m_iterations = REGISTRY.counter(
+            spans.COUNTER_LM_ITERATIONS,
+            "Engine iterations that had live work, by what they dispatched",
+            labels=("kind",),
+        )
+        self._m_queue_wait = REGISTRY.histogram(
+            spans.HIST_LM_QUEUE_WAIT,
+            "Time from submit to the iteration that gave a request a slot",
+        ).labels()
+        self._m_inter_token = REGISTRY.histogram(
+            spans.HIST_LM_INTER_TOKEN,
+            "Gap between the instants the host got two consecutive "
+            "tokens of one request",
+            buckets=_PHASE_BUCKETS,
+        ).labels()
+        self._iter: _Iteration | None = None  # the step() in progress
+        self.iterations = 0  # seq of the last iteration that had live work
+        self._last_step_end: float | None = None
+        self._trace_root: tracing.Span | None = None
+        # Finished requests by ticket, for timing(); take_result drops them.
+        self._finished_reqs: dict[int, _Request] = {}
         # Host scheduling state shared by both layouts.
         self.preemptions = 0
         self.prefill_chunks = 0
@@ -1633,6 +1725,11 @@ class LMEngine:
         via :meth:`take_error` (serving turns it into a 5xx), and the
         scheduler keeps draining the queue on the next iteration.
         """
+        it = self._iter = _Iteration(
+            self.iterations + 1, len(self._queue), self._last_step_end)
+        before = (self.dispatches, self.tokens_emitted, self.preemptions)
+        error = None
+        it.enter("admit")
         try:
             faultinject.fire("lm_engine.dispatch")
             self._order_queue_for_prefix_waves()
@@ -1643,9 +1740,46 @@ class LMEngine:
             self._count_prefix_batched()
             return out
         except Exception as e:  # noqa: BLE001 — isolate to in-flight work
+            error = type(e).__name__
+            it.enter("collect")
             return self._fail_inflight(e)
         finally:
+            it.enter(None)
+            self._end_iteration(it, before, len(self._admitting), error)
             self._admitting.clear()
+
+    def _end_iteration(self, it: "_Iteration", before: tuple[int, int, int],
+                       admitted: int, error: str | None) -> None:
+        """Close ``it``: an iteration that had live work (it admitted,
+        dispatched, emitted or failed) takes the next ``seq``, feeds the phase
+        histogram and the iteration counter and, with tracing on, is one
+        ``hops_tpu_lm_iteration`` span under the engine's root."""
+        self._iter, self._last_step_end = None, it.now
+        dispatches = self.dispatches - before[0]
+        tokens = self.tokens_emitted - before[1]
+        if not (admitted or dispatches or tokens or error):
+            return
+        self.iterations = it.seq
+        kind = "+".join(it.kinds) or "none"
+        self._m_iterations.inc(kind=kind)
+        for phase, seconds in it.phase_s.items():
+            self._m_phase[phase].observe(seconds)
+        if not tracing.enabled():
+            return
+        if self._trace_root is None:
+            self._trace_root = tracing.detached_root(
+                spans.SPAN_LM_ENGINE, slots=self.slots,
+                cache_layout="paged" if self._paged else "dense")
+        tracing.record_span(
+            spans.SPAN_LM_ITERATION, self._trace_root, it.wall, it.now - it.t0,
+            seq=it.seq, kind=kind, dispatches=dispatches,
+            **{f"{p}_ms": round(s * 1e3, 4) for p, s in it.phase_s.items()},
+            rows_prefill=it.rows_prefill, rows_decode=it.rows_decode,
+            tokens=tokens, admitted=admitted,
+            preempted=self.preemptions - before[2], queued=it.queued,
+            idle_before_ms=round(it.idle_s * 1e3, 4),
+            **({"error": error} if error else {}),
+        )
 
     def _promote_next_admission(self) -> None:
         """Move the priority-admission winner to the queue head, so the
@@ -1727,12 +1861,14 @@ class LMEngine:
         """One iteration of the dense-cache engine (the seed layout:
         per-slot max-length cache reservations, monolithic bucketed
         prefill at admission)."""
+        it = self._iter
         finished = []
         wave: list[tuple[int, _Request]] = []
         for row in range(self.slots):
             if self._slot_state[row] is None and self._queue:
                 self._promote_next_admission()
                 req = self._queue.popleft()
+                self._admitted(req)
                 self._admitting.append(req)
                 if req.prefix is not None:
                     # Prefix-append admissions keep the per-request
@@ -1740,6 +1876,7 @@ class LMEngine:
                     done = self._admit(req, row)
                     if done is not None:
                         finished.append(done)
+                    it.enter("admit")
                 else:
                     wave.append((row, req))
         if wave:
@@ -1747,6 +1884,8 @@ class LMEngine:
         if not any(st is not None for st in self._slot_state):
             return finished
 
+        it.enter("build")
+        n_live = sum(st is not None for st in self._slot_state)
         tokens = jnp.asarray(
             [st.emitted[-1] if st else 0 for st in self._slot_state], jnp.int32
         )
@@ -1804,18 +1943,23 @@ class LMEngine:
                  for st in self._slot_state],
                 jnp.int32,
             )
+            vectors = sampling_vectors()
+            it.dispatched("spec_horizon", rows_decode=n_live)
+            it.enter("dispatch")
             toks, emits, accs, lives, self._cache, self._draft_cache = (
                 self._spec_horizon(
                     self.params, self.draft_params, self._cache,
                     self._draft_cache, tokens, active, rems, eos_ids,
-                    *sampling_vectors(),
+                    *vectors,
                     horizon=self.decode_horizon, sampled=sampled,
                     nucleus=nucleus,
                 )
             )
             self._mark_dispatch()
+            it.enter("wait")
             toks, emits = np.asarray(toks), np.asarray(emits)
             accs, lives = np.asarray(accs), np.asarray(lives)
+            it.enter("collect")
             for i in range(self.decode_horizon):
                 for row in range(self.slots):
                     if self._slot_state[row] is None or not lives[i, row]:
@@ -1828,12 +1972,15 @@ class LMEngine:
             return finished
 
         if self.spec_k:
+            vectors = sampling_vectors() if sampled else ()
+            it.dispatched("spec", rows_decode=n_live)
+            it.enter("dispatch")
             if sampled:
                 drafts, a_rows, bonus, self._cache, self._draft_cache = (
                     self._spec_step_sampled(
                         self.params, self.draft_params, self._cache,
                         self._draft_cache, tokens, active,
-                        *sampling_vectors(), nucleus=nucleus,
+                        *vectors, nucleus=nucleus,
                     )
                 )
             else:
@@ -1844,8 +1991,10 @@ class LMEngine:
                     )
                 )
             self._mark_dispatch()
+            it.enter("wait")
             drafts = np.asarray(drafts)
             a_rows, bonus = np.asarray(a_rows), np.asarray(bonus)
+            it.enter("collect")
             for row in range(self.slots):
                 if self._slot_state[row] is None:
                     continue
@@ -1874,31 +2023,41 @@ class LMEngine:
                  for st in self._slot_state],
                 jnp.int32,
             )
+            vectors = sampling_vectors()
+            it.dispatched("horizon", rows_decode=n_live)
+            it.enter("dispatch")
             toks, lives, self._cache = self._step_horizon(
                 self.params, self._cache, tokens, active, rems, eos_ids,
-                *sampling_vectors(),
+                *vectors,
                 horizon=self.decode_horizon, sampled=sampled,
                 nucleus=nucleus,
             )
             self._mark_dispatch()
+            it.enter("wait")
             toks, lives = np.asarray(toks), np.asarray(lives)
+            it.enter("collect")
             for i in range(self.decode_horizon):
                 for row in range(self.slots):
                     if self._slot_state[row] is not None and lives[i, row]:
                         account(row, int(toks[i, row]))
             return finished
 
+        vectors = sampling_vectors() if sampled else ()
+        it.dispatched("decode", rows_decode=n_live)
+        it.enter("dispatch")
         if sampled:
             nxt, self._cache = self._step_sampled(
                 self.params, self._cache, tokens, active,
-                *sampling_vectors(), nucleus=nucleus,
+                *vectors, nucleus=nucleus,
             )
         else:
             nxt, self._cache = self._step_greedy(
                 self.params, self._cache, tokens, active
             )
         self._mark_dispatch()
+        it.enter("wait")
         nxt = np.asarray(nxt)
+        it.enter("collect")
         for row in range(self.slots):
             if self._slot_state[row] is not None:
                 account(row, int(nxt[row]))
@@ -2008,6 +2167,7 @@ class LMEngine:
         self.admission_waves += 1
         tok0 = np.asarray(tok0)
         toks, lives = np.asarray(toks), np.asarray(lives)
+        now = time.monotonic()  # the whole wave's tokens reach the host at once
         for row, r in enumerate(wave):
             # live-going-in is a monotone true->false prefix per row, so
             # the real tokens are exactly the first sum(lives) scan
@@ -2019,7 +2179,7 @@ class LMEngine:
             # Offline waves never carry prefixes (run_offline falls
             # back to run() for those) — every admission is a miss.
             self._m_prefix_cache.inc(result="miss")
-            self._observe_ttft(r)
+            self._observe_ttft(r, now)
             self._results[r.ticket] = out
 
     def result(self, ticket: int) -> list[int] | None:
@@ -2029,9 +2189,30 @@ class LMEngine:
     def take_result(self, ticket: int) -> list[int] | None:
         """Like :meth:`result` but consuming — long-lived servers must
         use this or ``_results`` grows without bound. Also drops the
-        ticket's TTFT record."""
+        ticket's TTFT and timing records."""
         self.ttft_s.pop(ticket, None)
+        self._finished_reqs.pop(ticket, None)
         return self._results.pop(ticket, None)
+
+    def timing(self, ticket: int) -> dict[str, Any] | None:
+        """What a finished request waited for, in seconds from its
+        ``submit``: ``queue_wait_s`` (to the start of the iteration that
+        first gave it a slot), ``token_s`` (to the instant the host got
+        each token: ``token_s[0]`` is its ``ttft_s`` entry, tokens of one
+        horizon share an instant), ``first_iteration`` / ``last_iteration``
+        (the ``seq`` of the ``hops_tpu_lm_iteration`` spans that admitted
+        and finished it) and ``preemptions``. None until it finishes
+        through :meth:`step`, and after :meth:`take_result`."""
+        req = self._finished_reqs.get(ticket)
+        if req is None:
+            return None
+        return {
+            "queue_wait_s": req.admitted_at - req.submitted_at,
+            "token_s": [t - req.submitted_at for t in req.token_t],
+            "first_iteration": req.first_iteration,
+            "last_iteration": req.last_iteration,
+            "preemptions": req.preemptions,
+        }
 
     def error(self, ticket: int) -> BaseException | None:
         """The dispatch failure that killed this ticket, if any (set
@@ -2122,14 +2303,38 @@ class LMEngine:
         if self._paged:
             self._m_pool_util.set(self._pool.stats()["utilization"])
 
-    def _observe_ttft(self, req: "_Request") -> None:
+    def _observe_ttft(self, req: "_Request", now: float) -> None:
         """First-token latency, once per request — a preempted request
-        replays its stream but keeps its original TTFT."""
+        replays its stream but keeps its original TTFT. ``now`` is the
+        instant the host got the token."""
         if req.submitted_at and not req.ttft_observed:
-            dt = time.monotonic() - req.submitted_at
+            dt = now - req.submitted_at
             self._m_ttft.observe(dt)
             self.ttft_s[req.ticket] = dt
             req.ttft_observed = True
+
+    def _stamp_token(self, st: "_SlotState") -> None:
+        """Token ``len(st.emitted)`` of ``st``'s request reached the host
+        at the instant the iteration began collecting: the first token's
+        reading is its TTFT too, so the two cannot disagree. A replay
+        after a preemption passes over the tokens already stamped."""
+        times = st.req.token_t
+        if len(st.emitted) > len(times):
+            now = self._iter.now
+            if times:
+                self._m_inter_token.observe(now - times[-1])
+            else:
+                self._observe_ttft(st.req, now)
+            times.append(now)
+
+    def _admitted(self, req: "_Request") -> None:
+        """``req`` got a slot in this iteration (its first: a replay
+        after a preemption keeps the first admission's marks)."""
+        if not req.first_iteration:
+            it = self._iter
+            req.first_iteration, req.admitted_at = it.seq, it.t0
+            if req.submitted_at:
+                self._m_queue_wait.observe(it.t0 - req.submitted_at)
 
     def _account(self, row: int, tok: int, finished: list[int]) -> None:
         """The one emit-and-finish bookkeeping path, shared by the
@@ -2137,6 +2342,7 @@ class LMEngine:
         mirror the in-graph live-mask retirement exactly)."""
         st = self._slot_state[row]
         st.emitted.append(tok)
+        self._stamp_token(st)
         st.remaining -= 1
         st.n_sampled += 1
         self.tokens_emitted += 1
@@ -2289,6 +2495,7 @@ class LMEngine:
             self._pool.ref(blk)
         blocks = shared + new_blocks
         self._queue.popleft()
+        self._admitted(req)
         # Wave membership for the prefix-batching tally (slot failures
         # surface through _slot_state, so _fail_inflight skips these).
         self._admitting.append(req)
@@ -2390,6 +2597,7 @@ class LMEngine:
         # replays to an identical token stream (greedy is
         # deterministic; sampled keys fold (seed, token index) only).
         self._queue.appendleft(st.req)
+        st.req.preemptions += 1
         self.preemptions += 1
         self._m_preemptions.inc()
 
@@ -2399,8 +2607,8 @@ class LMEngine:
         self.tokens_emitted += 1
         self._m_tokens.inc()
         self._m_prefix_cache.inc(result="hit" if st.shared_hit else "miss")
-        self._observe_ttft(st.req)
         st.emitted = [tok]
+        self._stamp_token(st)
         st.remaining = st.req.max_new_tokens - 1
         st.n_sampled = 1
         if st.remaining == 0 or (st.eos_id is not None and tok == st.eos_id):
@@ -2415,6 +2623,7 @@ class LMEngine:
         Decode-only iterations use the horizon/speculative programs
         unchanged (they operate on the cache pytree, whatever its
         layout)."""
+        it = self._iter
         finished: list[int] = []
         for row in range(self.slots):
             if self._queue and self._slot_state[row] is None:
@@ -2426,6 +2635,7 @@ class LMEngine:
         ]
         if not live:
             return finished
+        it.enter("blocks")
         prefilling = [(r, st) for r, st in live if st.pending is not None]
         # Worst-case decode advance of this wave, for block coverage.
         horizon = 1 if prefilling else self.decode_horizon
@@ -2435,6 +2645,7 @@ class LMEngine:
                 continue  # preempted meanwhile, or still prefilling
             mirror = st.prompt_total + len(st.emitted) - 1
             self._ensure_blocks(r, st, min(mirror + adv, st.worst_len))
+        it.enter("build")
         # _ensure_blocks may have preempted: rebuild the worklists.
         live = [
             (r, st) for r, st in enumerate(self._slot_state) if st is not None
@@ -2479,13 +2690,20 @@ class LMEngine:
                     tokens[r, 0] = st.emitted[-1]
                     tl[r] = 1
                     ns[r] = st.n_sampled
+            operands = (jnp.asarray(tokens), jnp.asarray(base),
+                        jnp.asarray(tl), temps, topks, topps, seeds,
+                        jnp.asarray(ns))
+            it.enter("pages")
             self._sync_pages()
+            it.dispatched(
+                "mixed" if fused_decode and decoding else "chunk",
+                rows_prefill=len(prefilling),
+                rows_decode=len(decoding) if fused_decode else 0)
+            it.enter("dispatch")
             if self.spec_k:
                 toks, self._cache, self._draft_cache = self._spec_paged_chunk(
                     self.params, self.draft_params, self._cache,
-                    self._draft_cache, jnp.asarray(tokens),
-                    jnp.asarray(base), jnp.asarray(tl), temps, topks,
-                    topps, seeds, jnp.asarray(ns),
+                    self._draft_cache, *operands,
                     sampled=sampled, nucleus=nucleus,
                 )
                 # Inert decode rows were scratch-clamped in-graph; the
@@ -2493,13 +2711,13 @@ class LMEngine:
                 self._pages_dirty = True
             else:
                 toks, self._cache = self._paged_mixed(
-                    self.params, self._cache, jnp.asarray(tokens),
-                    jnp.asarray(base), jnp.asarray(tl), temps, topks,
-                    topps, seeds, jnp.asarray(ns),
+                    self.params, self._cache, *operands,
                     sampled=sampled, nucleus=nucleus,
                 )
             self._mark_dispatch()
+            it.enter("wait")
             toks = np.asarray(toks)
+            it.enter("collect")
             for r, st in prefilling:
                 n = int(tl[r])
                 self.prefill_chunks += 1
@@ -2526,6 +2744,7 @@ class LMEngine:
         # just emitted) must sit this dispatch out — letting it decode
         # here would advance its cache with tokens the host never
         # accounted.
+        it.enter("pages")
         self._sync_pages()
         dec_rows = {r for r, _ in decoding}
         is_decode = [r in dec_rows for r in range(self.slots)]
@@ -2544,6 +2763,7 @@ class LMEngine:
                 np.int32,
             ))
             self._idx_stale = False
+        it.enter("build")
         tokens = jnp.asarray(
             [st.emitted[-1] if dec else 0
              for st, dec in zip(self._slot_state, is_decode)],
@@ -2572,6 +2792,8 @@ class LMEngine:
                 jnp.int32,
             )
             if horizon > 1:
+                it.dispatched("spec_horizon", rows_decode=len(decoding))
+                it.enter("dispatch")
                 toks, emits, accs, lives, self._cache, self._draft_cache = (
                     self._spec_horizon(
                         self.params, self.draft_params, self._cache,
@@ -2581,8 +2803,10 @@ class LMEngine:
                     )
                 )
                 self._mark_dispatch()
+                it.enter("wait")
                 toks, emits = np.asarray(toks), np.asarray(emits)
                 accs, lives = np.asarray(accs), np.asarray(lives)
+                it.enter("collect")
                 for i in range(horizon):
                     for r in range(self.slots):
                         st = self._slot_state[r]
@@ -2594,6 +2818,8 @@ class LMEngine:
                             if emits[i, r, j] and self._slot_state[r] is st:
                                 self._account(r, int(toks[i, r, j]), finished)
                 return finished
+            it.dispatched("spec", rows_decode=len(decoding))
+            it.enter("dispatch")
             if sampled:
                 drafts, a_rows, bonus, self._cache, self._draft_cache = (
                     self._spec_step_sampled(
@@ -2617,8 +2843,10 @@ class LMEngine:
                 # the host.
                 self._pages_dirty = True
                 self._idx_stale = True
+            it.enter("wait")
             drafts = np.asarray(drafts)
             a_rows, bonus = np.asarray(a_rows), np.asarray(bonus)
+            it.enter("collect")
             for r, st in decoding:
                 if self._slot_state[r] is not st:
                     continue
@@ -2642,13 +2870,17 @@ class LMEngine:
                  for st, dec in zip(self._slot_state, is_decode)],
                 jnp.int32,
             )
+            it.dispatched("horizon", rows_decode=len(decoding))
+            it.enter("dispatch")
             toks, lives, self._cache = self._step_horizon(
                 self.params, self._cache, tokens, active, rems, eos_ids,
                 temps, topks, topps, seeds, ns,
                 horizon=horizon, sampled=sampled, nucleus=nucleus,
             )
             self._mark_dispatch()
+            it.enter("wait")
             toks, lives = np.asarray(toks), np.asarray(lives)
+            it.enter("collect")
             for i in range(horizon):
                 for r in range(self.slots):
                     st = self._slot_state[r]
@@ -2656,13 +2888,18 @@ class LMEngine:
                         self._account(r, int(toks[i, r]), finished)
             return finished
         # Single-step decode: the mixed program at chunk width 1.
+        operands = (tokens[:, None], base, active.astype(jnp.int32))
+        it.dispatched("decode", rows_decode=len(decoding))
+        it.enter("dispatch")
         toks, self._cache = self._paged_mixed(
-            self.params, self._cache, tokens[:, None], base,
-            active.astype(jnp.int32), temps, topks, topps, seeds, ns,
+            self.params, self._cache, *operands,
+            temps, topks, topps, seeds, ns,
             sampled=sampled, nucleus=nucleus,
         )
         self._mark_dispatch()
+        it.enter("wait")
         toks = np.asarray(toks)
+        it.enter("collect")
         for r, st in decoding:
             if self._slot_state[r] is st:
                 self._account(r, int(toks[r]), finished)
@@ -2674,6 +2911,8 @@ class LMEngine:
         caches on a speculative engine). Returns the ticket if the
         request finished at admission (budget of 1). Non-prefix
         requests go through :meth:`_admit_wave` (batched)."""
+        it = self._iter
+        it.enter("build")
         L = req.prompt.size
         base_cache, base_draft, base_len = req.prefix
         bucket = min(self._bucket(L), self._cap - base_len)
@@ -2685,6 +2924,8 @@ class LMEngine:
             sampled=req.temperature > 0,
             nucleus=req.temperature > 0 and 0.0 < req.top_p < 1.0,
         )
+        it.dispatched("append", rows_prefill=1)
+        it.enter("dispatch")
         if self.spec_k:
             first_tok, one_cache, one_draft = self._spec_append(
                 self.params, self.draft_params, base_cache, base_draft,
@@ -2704,7 +2945,7 @@ class LMEngine:
         self._cache = self._insert(
             self._cache, one_cache, jnp.int32(row), jnp.int32(base_len + L)
         )
-        return self._register(row, req, int(first_tok))
+        return self._register_first(row, req, first_tok)
 
     def _admit_wave(self, wave: list[tuple[int, "_Request"]]) -> list[int]:
         """Batched admission: ONE prefill dispatch + ONE cache merge for
@@ -2725,6 +2966,8 @@ class LMEngine:
             row, req = wave[0]
             done = self._admit_single(row, req)
             return [done] if done is not None else []
+        it = self._iter
+        it.enter("build")
         # The padded chunk must fit the SMALLER cache on speculative
         # engines (self._cap): the draft prefills the same bucket.
         bucket = max(
@@ -2754,6 +2997,8 @@ class LMEngine:
                 jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
                 jnp.asarray(seeds))
         admit_v, lens_v = jnp.asarray(admit), jnp.asarray(true_lens)
+        it.dispatched("prefill", rows_prefill=len(wave))
+        it.enter("dispatch")
         if self.spec_k:
             toks, t_rows, d_rows = self._spec_prefill_batch(
                 self.params, self.draft_params, *args,
@@ -2768,7 +3013,9 @@ class LMEngine:
             )
         self._cache = self._insert_batch(self._cache, t_rows, admit_v, lens_v)
         self.admission_waves += 1
+        it.enter("wait")
         toks = np.asarray(toks)
+        it.enter("collect")
         finished = []
         for row, req in wave:
             done = self._register(row, req, int(toks[row]))
@@ -2779,6 +3026,8 @@ class LMEngine:
     def _admit_single(self, row: int, req: "_Request") -> int | None:
         """b=1 admission for a one-request wave: two small dispatches,
         no transient full-slot cache (see :meth:`_admit_wave`)."""
+        it = self._iter
+        it.enter("build")
         L = req.prompt.size
         kwargs = dict(
             sampled=req.temperature > 0,
@@ -2786,12 +3035,14 @@ class LMEngine:
         )
         knobs = (jnp.float32(req.temperature), jnp.int32(req.top_k),
                  jnp.float32(req.top_p), jnp.int32(req.seed))
+        it.dispatched("prefill", rows_prefill=1)
         if self.spec_k:
             # The padded chunk must fit the SMALLER cache: the draft
             # prefills the same bucket.
             bucket = min(self._bucket(L), self._cap)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :L] = req.prompt
+            it.enter("dispatch")
             first_tok, one_cache, one_draft = self._spec_prefill(
                 self.params, self.draft_params, jnp.asarray(padded),
                 jnp.int32(L), *knobs, **kwargs,
@@ -2803,6 +3054,7 @@ class LMEngine:
             bucket = min(self._bucket(L), self.model.max_decode_len)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :L] = req.prompt
+            it.enter("dispatch")
             first_tok, one_cache = self._prefill(
                 self.params, jnp.asarray(padded), jnp.int32(L), *knobs,
                 **kwargs,
@@ -2810,7 +3062,15 @@ class LMEngine:
         self._cache = self._insert(
             self._cache, one_cache, jnp.int32(row), jnp.int32(L)
         )
-        return self._register(row, req, int(first_tok))
+        return self._register_first(row, req, first_tok)
+
+    def _register_first(self, row: int, req: "_Request", first_tok: Any) -> int | None:
+        """The tail of a one-request admission: wait for its first token,
+        then :meth:`_register` it."""
+        self._iter.enter("wait")
+        tok = int(first_tok)
+        self._iter.enter("collect")
+        return self._register(row, req, tok)
 
     def _register(self, row: int, req: "_Request", tok: int) -> int | None:
         """Shared admission bookkeeping: record the first emitted token
@@ -2820,7 +3080,6 @@ class LMEngine:
         self._m_prefix_cache.inc(
             result="hit" if req.prefix is not None else "miss"
         )
-        self._observe_ttft(req)
         st = _SlotState(
             ticket=req.ticket,
             emitted=[tok],
@@ -2830,7 +3089,9 @@ class LMEngine:
             top_k=req.top_k,
             top_p=req.top_p,
             seed=req.seed,
+            req=req,
         )
+        self._stamp_token(st)
         self._slot_state[row] = st
         if st.remaining == 0 or (req.eos_id is not None and tok == req.eos_id):
             return self._finish(row)
@@ -2839,6 +3100,8 @@ class LMEngine:
     def _finish(self, row: int) -> int:
         st = self._slot_state[row]
         self._results[st.ticket] = st.emitted
+        st.req.last_iteration = self._iter.seq
+        self._finished_reqs[st.ticket] = st.req
         self._slot_state[row] = None
         if self._paged and st.blocks is not None:
             # Blocks free the moment the last reader is gone; shared

@@ -43,7 +43,7 @@ from hops_tpu.runtime.resilience import (
     with_deadline,
 )
 from hops_tpu.telemetry import export as telemetry_export
-from hops_tpu.telemetry import tracing
+from hops_tpu.telemetry import spans, tracing
 from hops_tpu.telemetry import workload
 from hops_tpu.telemetry.metrics import RATIO_BUCKETS, REGISTRY
 from hops_tpu.telemetry.spans import span
@@ -250,6 +250,11 @@ class LMEnginePredictor:
         # Brownout degrade: under SLO burn (qos.DEGRADE+) decode
         # budgets clamp to this — shorter answers beat shed answers.
         self._brownout_max_new = int(cfg.get("brownout_max_new_tokens", 16))
+        self._m_lock_wait = REGISTRY.histogram(
+            spans.HIST_LM_LOCK_WAIT,
+            "Time a predict() call waited for the engine lock before it "
+            "could submit",
+        ).labels()
         self._cv = threading.Condition()
         self._stopping = False  # guarded by: self._cv
         self._thread = threading.Thread(target=self._loop, daemon=True)
@@ -318,11 +323,16 @@ class LMEnginePredictor:
             kw["priority"] = priority
         # The engine steps on ITS driver thread; attribute each
         # ticket's submit→finish window back to this request's trace
-        # retroactively (with per-ticket TTFT, the queue/prefill vs
-        # decode split) once the results are in.
+        # retroactively (with what it waited for: the engine lock, a
+        # slot, each token) once the results are in.
         trace_ctx = tracing.current_context()
         t_submit = time.time()
+        t_enter = time.monotonic()
         with self._cv:
+            # The driver thread holds this lock for a whole iteration, the
+            # device wait included, and takes it back at once.
+            lock_wait = time.monotonic() - t_enter
+            self._m_lock_wait.observe(lock_wait)
             if self._stopping:
                 raise RuntimeError("serving stopped")
             # All-or-nothing submission: a bad instance mid-batch must
@@ -359,6 +369,8 @@ class LMEnginePredictor:
             # tickets; surface it as this request's 5xx while other
             # callers keep streaming.
             ttfts = {t: self._engine.ttft_s.get(t) for t in tickets}
+            timings = ({t: self._engine.timing(t) for t in tickets}
+                       if trace_ctx is not None else {})
             errors = [self._engine.take_error(t) for t in tickets]
             results = [self._engine.take_result(t) for t in tickets]
             if trace_ctx is not None:
@@ -367,13 +379,23 @@ class LMEnginePredictor:
                     attrs: dict[str, Any] = {
                         "ticket": t,
                         "tokens": len(res) if res is not None else 0,
+                        "lock_wait_ms": round(lock_wait * 1e3, 3),
                     }
                     if ttfts.get(t) is not None:
                         attrs["ttft_ms"] = round(ttfts[t] * 1e3, 3)
+                    if timings[t] is not None:
+                        tm = timings[t]
+                        attrs.update(
+                            queue_wait_ms=round(tm["queue_wait_s"] * 1e3, 3),
+                            first_iteration=tm["first_iteration"],
+                            last_iteration=tm["last_iteration"],
+                            preemptions=tm["preemptions"],
+                            token_ms=[round(s * 1e3, 3) for s in tm["token_s"]],
+                        )
                     if err is not None:
                         attrs["error"] = type(err).__name__
                     tracing.record_span(
-                        "lm_engine.dispatch", trace_ctx, t_submit, dur,
+                        spans.SPAN_LM_REQUEST, trace_ctx, t_submit, dur,
                         **attrs)
             first = next((e for e in errors if e is not None), None)
             if first is not None:

@@ -252,6 +252,57 @@ def keep(x: Any, what: str) -> Any:
     return checkpoint_name(x, what)
 
 
+# The serving vocabulary stands below ``keep``, not beside the other
+# vocabularies above it: a training step's compile-cache key holds the
+# line ``keep`` calls ``checkpoint_name`` from.
+
+#: The serving engine's vocabulary (``modelrepo/lm_engine.py``,
+#: ``modelrepo/serving.py:LMEnginePredictor``). Readers outside the program
+#: (the benchmark's ``harness/engine_spans.py``) repeat these strings.
+#:
+#: ``hops_tpu_lm_iteration`` is one ``LMEngine.step()`` that had live work,
+#: recorded as it ends with its wall-clock start and duration under the
+#: engine's own root ``hops_tpu_lm_engine`` (``tracing.detached_root``: the
+#: driver thread has no request context and an iteration serves many
+#: requests). Attributes: ``seq`` (consecutive per engine), ``kind`` (what
+#: it dispatched, ``+``-joined: ``chunk`` = prompt chunks alone, ``mixed`` =
+#: chunks with decode rows fused in, ``decode``, ``horizon``, ``spec``,
+#: ``spec_horizon``; the dense layout's admissions ``prefill``, ``append``),
+#: ``dispatches``, one ``<phase>_ms`` per ``LM_PHASES`` member (they sum to
+#: the duration less the few clock reads between them), ``rows_prefill``,
+#: ``rows_decode``, ``tokens``, ``admitted``, ``preempted``, ``queued`` (the
+#: queue's length as the iteration began), ``idle_before_ms`` (since the
+#: previous ``step()`` returned), and ``error`` when the dispatch raised.
+SPAN_LM_ENGINE = "hops_tpu_lm_engine"
+SPAN_LM_ITERATION = "hops_tpu_lm_iteration"
+#: An iteration's phases, in the order a paged iteration meets them:
+#: ``admit`` (queue order, priority, slot and page-table bookkeeping),
+#: ``blocks`` (page growth with its reclaim and preemption), ``build`` (host
+#: lists to device operands), ``pages`` (page table and cache index pushed
+#: to the device), ``dispatch`` (the jitted call until it returns; on a first
+#: call its trace and compile), ``wait`` (the host blocked on the device for
+#: the tokens), ``collect`` (per-token accounting, finishes, prefix
+#: capture). Each is entered as a ``jax.profiler.TraceAnnotation``
+#: ``hops_tpu_lm_<phase>`` and observed into
+#: ``hops_tpu_lm_phase_seconds{phase}`` once an iteration.
+LM_PHASES = ("admit", "blocks", "build", "pages", "dispatch", "wait", "collect")
+LM_PHASE_ANNOTATION = "hops_tpu_lm_"  # + phase
+HIST_LM_PHASE_SECONDS = "hops_tpu_lm_phase_seconds"
+COUNTER_LM_ITERATIONS = "hops_tpu_lm_iterations_total"
+#: One per ticket of a ``predict`` call, under the request's trace, from the
+#: call's entry to its results: ``ticket``, ``tokens``, ``ttft_ms``, and what
+#: the request waited for: ``lock_wait_ms`` (entry of ``predict`` to holding
+#: the engine lock), ``queue_wait_ms`` (``submit`` to the start of the
+#: iteration that first gave it a slot), ``first_iteration`` /
+#: ``last_iteration`` (``seq`` of the iterations that admitted and finished
+#: it), ``preemptions``, ``token_ms`` (offset from ``submit`` of the instant
+#: the host got each token; ``token_ms[0]`` is ``ttft_ms``).
+SPAN_LM_REQUEST = "lm_engine.dispatch"
+HIST_LM_LOCK_WAIT = "hops_tpu_lm_lock_wait_seconds"
+HIST_LM_QUEUE_WAIT = "hops_tpu_lm_queue_wait_seconds"
+HIST_LM_INTER_TOKEN = "hops_tpu_lm_inter_token_seconds"
+
+
 _happened: set[str] = set()  # guarded by: _happened_lock
 _happened_lock = threading.Lock()
 

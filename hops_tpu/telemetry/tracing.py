@@ -377,27 +377,36 @@ _process_root: Span | None = None  # guarded by: _process_root_lock
 _process_root_lock = threading.Lock()
 
 
+def detached_root(name: str, start: float | None = None, **attrs: Any) -> Span | None:
+    """A root span for work that no request or run causes (a process's
+    start, an LM engine's iterations): a trace of its own (``GET
+    /debug/traces/<id>``), stored in the ring unfinished (``duration``
+    None: its owner is still running). It is a parent to hand to
+    :func:`record_span`, never the active context, so code outside a
+    request still finds no span to join. None when tracing is disabled."""
+    if not _ENABLED:
+        return None
+    sampled = TRACER._sample()
+    root = Span(None, name, new_trace_id(), None, sampled=sampled, attrs=attrs, recorded=False)
+    if start is not None:
+        root.start = start
+    if sampled:
+        TRACER._store(root)
+    return root
+
+
 def process_root() -> Span | None:
     """The span that work outside any request or run hangs under: the
     imports, compiles before a launcher is entered, the time before the
-    first launch. One per process, a trace of its own (``GET
-    /debug/traces/<id>``), started when the kernel started the process
-    and stored in the ring unfinished when first asked for (``duration``
-    None: the process is still running). It is a parent to hand to
-    :func:`record_span`, never the active context, so code outside a
-    request still finds no span to join. None when tracing is disabled."""
+    first launch. One per process, a :func:`detached_root` started when
+    the kernel started the process and made when first asked for. None
+    when tracing is disabled."""
     global _process_root
     if not _ENABLED:
         return None
     with _process_root_lock:  # asked for a few hundred times a start, never in a step
         if _process_root is None:
-            sampled = TRACER._sample()
-            root = Span(None, PROCESS_ROOT, new_trace_id(), None, sampled=sampled,
-                        attrs={"pid": os.getpid()}, recorded=False)
-            root.start = _startup.process_start()
-            if sampled:
-                TRACER._store(root)
-            _process_root = root
+            _process_root = detached_root(PROCESS_ROOT, _startup.process_start(), pid=os.getpid())
         return _process_root
 
 

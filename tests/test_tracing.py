@@ -735,6 +735,17 @@ class TestLMTraceE2E:
                 names["hops_tpu_serving_request"]["span_id"]
             assert dispatch["attrs"]["tokens"] == 5
             assert dispatch["attrs"]["ttft_ms"] > 0
+            # what the request waited for rides the same two paths
+            attrs = dispatch["attrs"]
+            assert len(attrs["token_ms"]) == 5
+            assert attrs["token_ms"][0] == attrs["ttft_ms"]
+            assert attrs["lock_wait_ms"] >= 0 and attrs["queue_wait_ms"] >= 0
+            assert attrs["first_iteration"] <= attrs["last_iteration"]
+            ring = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{cfg['port']}/debug/traces/{client.trace_id}",
+                timeout=10).read())
+            by_name = {r["name"]: r for r in ring["spans"]}
+            assert by_name["lm_engine.dispatch"]["attrs"]["token_ms"] == attrs["token_ms"]
         finally:
             serving.stop("traced-lm")
 
