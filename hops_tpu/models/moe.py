@@ -74,6 +74,13 @@ as ``moe_held_tile_share``); ``hops_tpu_train_moe_traces_total{dispatch}``
 says at trace time which of the two dispatches (``all`` | ``held``) a layer
 holds. It is also the send side of the exchange expert parallelism on chips
 needs: the rows a chip gathers for one peer's experts.
+
+Under ``TransformerLM(remat=True)`` a block holds what its router decided
+(``telemetry.spans.REMAT_KEEPS``: the float32 logits, the sigmoid router's
+chosen ids, the sort and the rows per expert), so its second forward makes
+the scores and the weights again from them and runs no router matmul, no
+``top_k`` and no sort; nothing of a token row's width is held. Outside a
+``remat`` the names are identities.
 """
 
 from __future__ import annotations
@@ -88,7 +95,7 @@ from flax import linen as nn
 from hops_tpu.ops.grouped_matmul import DEFAULT_TILING, grouped_matmul, implementation
 from hops_tpu.parallel.mesh import per_shard, pvary
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_SHARED
+from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_SHARED, keep
 
 SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_COMBINE = MOE_SCOPES
 #: names of the expert-stacked weights, leading dim ``num_experts``
@@ -322,9 +329,11 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
     with jax.named_scope(SCOPE_DISPATCH):
         # held experts first, in id order: their rows are the leading
         # sum(local sizes) rows whatever slice of the experts is held
-        order = jnp.argsort((flat_ids - first) % num_experts, stable=True)
-        inverse = None if share else jnp.argsort(order)  # a held share never undoes the sort
-        sizes = jnp.sum(flat_ids[:, None] == jnp.arange(num_experts)[None, :], axis=0, dtype=jnp.int32)
+        # (a block's remat keeps the sort and the counts: REMAT_KEEPS)
+        order = keep(jnp.argsort((flat_ids - first) % num_experts, stable=True), "moe_order")
+        inverse = None if share else keep(jnp.argsort(order), "moe_order")  # a held share never undoes the sort
+        sizes = keep(jnp.sum(flat_ids[:, None] == jnp.arange(num_experts)[None, :], axis=0, dtype=jnp.int32),
+                     "moe_sizes")
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, first, n_local)
     if share:
         bound = _held_bound(n_rows, n_local, num_experts)
@@ -413,15 +422,18 @@ class MoEMLP(nn.Module):
                 raise ValueError(f"held_experts {self.held_experts} outside the {self.num_experts} experts")
 
         with jax.named_scope(SCOPE_ROUTER):
-            # float32 end to end: the top-k is discontinuous in the logits
-            router_logits = nn.Dense(
+            # float32 end to end: the top-k is discontinuous in the logits.
+            # A block's remat keeps them (telemetry.spans.REMAT_KEEPS)
+            router_logits = keep(nn.Dense(
                 self.num_experts, dtype=jnp.float32, use_bias=False,
                 precision=jax.lax.Precision.HIGHEST, name="router",
-            )(x.astype(jnp.float32))
+            )(x.astype(jnp.float32)), "router_logits")
             if self.scoring == "sigmoid":
                 probs, top_p, top_ids = self._sigmoid_choice(router_logits)
             else:
                 probs = jax.nn.softmax(router_logits, axis=-1)  # (b, s, E)
+                # (the weights are this top_k's own values and its pull-back reads its
+                # own ids: no name reaches them, under remat this top_k runs again)
                 top_p, top_ids = jax.lax.top_k(probs, self.top_k)
                 if self.norm_topk_prob:
                     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
@@ -507,7 +519,8 @@ class MoEMLP(nn.Module):
             group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
             kth = jax.lax.top_k(group_score, self.topk_group)[0][..., -1:]
             choice = jnp.where((group_score >= kth)[..., None], grouped, -jnp.inf).reshape(choice.shape)
-        _, top_ids = jax.lax.top_k(choice, self.top_k)
+        # from the kept ids a block's second forward gathers the weights: none of the top_k runs again
+        top_ids = keep(jax.lax.top_k(choice, self.top_k)[1], "router_ids")
         top_p = jnp.take_along_axis(scores, top_ids, axis=-1)
         if self.norm_topk_prob:
             top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)

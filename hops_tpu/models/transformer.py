@@ -12,8 +12,9 @@ is TPU-first:
 - rotary position embeddings (no learned position table to shard);
 - optional ``nn.remat`` per block trades FLOPs for HBM (the
   jax.checkpoint knob from the build brief): a block's input and the
-  values named in ``telemetry.spans.REMAT_KEEPS`` (kernel results and
-  ``d_model``-wide sublayer outputs that the backward reads) are held,
+  values named in ``telemetry.spans.REMAT_KEEPS`` (kernel results,
+  ``d_model``-wide sublayer outputs that the backward reads and what a
+  routed layer's router decided) are held,
   the rest of its forward runs again in the backward pass.
 
 Sharding contract (used by the launchers and __graft_entry__):

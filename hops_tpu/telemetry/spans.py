@@ -229,8 +229,18 @@ COUNTER_TRAIN_HELD_HEADS = "hops_tpu_train_held_heads_total"
 #: of its roof, so the 335 MB a layer of the Ling cell buy 5.07 ms (66 MB a
 #: ms) and the 84 MB of Solar-Open2's held heads 1.56 ms, where the selective
 #: scan's pair cost 144 MB a ms, and both cells have the room (PERF.md
-#: section 6, PR 48).
-REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out", "kda_out", "kda_states")
+#: section 6, PR 48). ``router_logits`` / ``router_ids`` / ``moe_order`` /
+#: ``moe_sizes``: what a routed layer decides (``models/moe.py``, and nowhere
+#: else): the router's float32 logits ``(tokens, E)``, the chosen experts'
+#: ids, the stable sort of the ``tokens x top_k`` rows (its inverse too, under
+#: the same name, where every expert is held) and the rows per expert. With
+#: them the second forward runs no router matmul, no ``top_k`` and no sort;
+#: the weights are a gather of the scores at the kept ids. Integers are kept
+#: by name like any other value. 17.3 MB a layer of the Ling cell (512
+#: experts) buy 1.41 ms, 11.0 MB of Solar-Open2's (320) 0.97 ms: 12 MB a ms,
+#: the cheapest milliseconds ``remat`` had left (PERF.md section 6, PR 50).
+REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out", "kda_out", "kda_states",
+               "router_logits", "router_ids", "moe_order", "moe_sizes")
 #: One per value traced under a ``REMAT_KEEPS`` name (``what``), whether or
 #: not a ``remat`` encloses it: outside one the name is the identity.
 COUNTER_TRAIN_REMAT_KEPT = "hops_tpu_train_remat_kept_total"

@@ -53,6 +53,31 @@ def workspace(tmp_path, monkeypatch):
     yield tmp_path / "workspace"
 
 
+#: memory maps a worker may hold when a test file ends before its compiled
+#: programs are dropped: a third of what Linux allows a process (65,530)
+MAPS_BETWEEN_FILES = 20_000
+
+
+@pytest.fixture(autouse=True, scope="module")
+def compiled_programs_stay_under_the_map_limit():
+    """XLA:CPU maps memory for every executable the process holds and a
+    process may hold ``vm.max_map_count`` maps: past it a compile segfaults
+    or aborts and the worker is lost with the rest of its file (PR 50 saw
+    both in one run, in files that compile little). Files are dealt to the
+    workers as they come free, so two that leave 40,000 maps each
+    (``test_kda.py``, ``test_ling_flash.py``) can meet in one process."""
+    yield
+    try:
+        with open("/proc/self/maps") as maps:
+            held = sum(1 for _ in maps)
+    except OSError:
+        return
+    if held > MAPS_BETWEEN_FILES:
+        import jax
+
+        jax.clear_caches()
+
+
 # Steering the kernels from a test (the program has no option for either):
 
 

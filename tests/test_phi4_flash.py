@@ -22,7 +22,7 @@ import optax
 import pytest
 
 from benchmark.reference import phi4_flash as reference
-from hops_tpu.models import common
+from hops_tpu.models import common, moe
 from hops_tpu.models.differential_attention import DifferentialAttention
 from hops_tpu.models.state_space import GatedMemoryUnit, Mamba
 from hops_tpu.models.transformer import LAYER_TYPES, Block, TransformerLM, make_lm_train_step
@@ -351,34 +351,44 @@ TOYS = {
 }
 
 
-def lowered_digest(toy):
+def lowered_text(toy):
     """The step's lowered text with what embeds a path or a line taken out."""
-    import hashlib
-
     model = TransformerLM(**TOYS[toy])
     state = common.create_train_state(model, jax.random.PRNGKey(0), (1, 8), input_dtype=jnp.int32)
     step = make_lm_train_step(aux_loss_weight=0.01, loss_chunk=16, router_z_loss_weight=0.001)
     text = jax.jit(step).lower(state, {"tokens": jnp.zeros((2, 49), jnp.int32)}).as_text()
-    text = re.sub(r"loc\([^)]*\)|#loc\d*( = .*)?", "", text)
+    return re.sub(r"loc\([^)]*\)|#loc\d*( = .*)?", "", text)
+
+
+def lowered_digest(toy):
+    import hashlib
+
+    text = lowered_text(toy)
     return hashlib.sha256(text.encode()).hexdigest(), len(text.splitlines())
 
 
 @pytest.mark.parametrize("toy", sorted(TOYS))
-def test_defaults_keep_the_parents_lowered_step(toy):
+def test_defaults_keep_the_parents_lowered_step(toy, monkeypatch):
     """``tests/data/transformer_lm_parent_lowered.json`` was written with
     ``lowered_digest``: ``phi4_shaped`` (with its tree, and ``ling_shaped``'s) by
     PR 43's parent (3d29bff), before ``Block`` was rebuilt round ``LayerSpec``;
-    ``ling_shaped`` by PR 48 on top of 21d13a8, because that toy holds two
-    Kimi-delta layers under ``remat`` and what ``remat`` keeps of them changed
-    by design: the rule's ``o`` and chunk states are named (``kda_out``,
-    ``kda_states``: ``ops/kda.py:_rule_fwd``), so each layer's second forward
-    lost its scan over the chunks (14 ``stablehlo.while`` where 21d13a8 wrote
-    16, 293 ``dot_general`` for 341) and the first gained a
-    ``reduce_precision`` on each kept ``o`` (5 for 3); 9,400 lines where PR 46
-    wrote 10,031 (that PR moved what ``LatentAttention`` lowers to: the keys
-    in two parts, ``rotate_from``). The four toys without a Kimi-delta layer
-    read their older digests, which is the proof that no other cell's step
-    moved;
+    ``ling_shaped`` by PR 50 on top of ab23d0a, because that toy holds two
+    routed layers under ``remat`` and what ``remat`` keeps of them changed by
+    design: the router's logits, the chosen ids, the sort and the counts are
+    named (``router_logits``, ``router_ids``, ``moe_order``, ``moe_sizes``:
+    ``models/moe.py``), so each routed layer's second forward lost its router
+    matmul (291 ``dot_general`` where ab23d0a wrote 293), its three ``top_k``
+    (6 ``chlo.top_k`` for 12) and its sort (2 calls of ``@argsort`` for 4, one
+    ``stablehlo.sort`` for 2) and the first gained a ``reduce_precision`` on
+    each layer's kept logits (7 for 5); 9,292 lines where PR 48 wrote 9,400
+    (that PR named the Kimi delta rule's ``o`` and chunk states: 14
+    ``stablehlo.while`` where 21d13a8 wrote 16). The three toys that route
+    nothing read their older digests, which is the proof that no other cell's
+    step moved. ``olmoe_shaped`` routes without ``remat``: read with the
+    router's names left out (``moe.keep`` an identity) its text is the
+    parent's to the byte, and with them the same but for the numbers MLIR
+    gives private functions
+    (``test_the_routers_names_renumber_private_functions_and_nothing_else``);
     ``phi3_shaped`` and ``olmoe_shaped`` by commit 18a3e8f,
     PR 31's parent (no ``remat``: the fields added since, and the names
     ``remat`` keeps values by, leave their lowered text as it was);
@@ -387,9 +397,31 @@ def test_defaults_keep_the_parents_lowered_step(toy):
     second forward lost two ``dot_general`` (``mlp/down``, ``attn/out``) and
     the first gained two ``reduce_precision`` on the kept results (227
     ``dot_general`` where 18a3e8f wrote 235; the line count is 7,229 on both)."""
+    if not TOYS[toy].get("remat"):  # the names are identities there: read without the router's
+        monkeypatch.setattr(moe, "keep", lambda x, what: x)
     digest, lines = lowered_digest(toy)
     assert lines == LOWERED[toy]["lines"]
     assert digest == LOWERED[toy]["sha256"]
+
+
+def test_the_routers_names_renumber_private_functions_and_nothing_else(monkeypatch):
+    """Outside a ``remat`` a name lowers to nothing, but JAX lowers every
+    distinct equation as a private function of the primitive's name before
+    it inlines it, and MLIR numbers a name already taken by the count of such
+    clashes so far: the four distinct ``name`` equations of a routed layer
+    (``mixer_out`` is the module's first and clashes with none) move the
+    numbers of every private function made after them (``@argsort_77`` reads
+    ``@argsort_81``). OLMoE's step with the names is its step without them
+    once those numbers are taken off, line for line."""
+    named = lowered_text("olmoe_shaped")
+    monkeypatch.setattr(moe, "keep", lambda x, what: x)
+    unnamed = lowered_text("olmoe_shaped")
+    assert named != unnamed
+
+    def unnumbered(text):
+        return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
+
+    assert unnumbered(named).splitlines() == unnumbered(unnamed).splitlines()
 
 
 @pytest.mark.parametrize("n", [1, 2])

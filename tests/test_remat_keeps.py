@@ -4,7 +4,9 @@ executes anything): a block of each kind keeps its input and the values named in
 ``telemetry.spans.REMAT_KEEPS`` that its backward reads, nothing else; the
 flash forward and the Kimi delta rule's appear once a layer in a step's
 gradient, not twice; the selective scan's forward and the gated delta
-rule's, which are not kept, twice.
+rule's, which are not kept, twice; a routed layer's router matmul, its
+``top_k`` and its sort once (PR 50: the logits, the chosen ids, the order and
+the counts are kept).
 
 The kernels are steered in the tests (the program has no option for it):
 flash attention takes the Pallas kernel at 2,048 keys, as on the chip; the
@@ -39,6 +41,15 @@ KIMI = dict(mlp_hidden=MLP_HIDDEN, rope_base=None, linear_num_heads=HEADS, linea
 UNBOUNDED = dict(linear_lower_bound=None, kda_gate_rank=8, kda_allow_neg_eigval=True, kda_output_gate="channel_wise")
 
 TINY_LM = dict(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, dtype=jnp.bfloat16)
+EXPERTS = 16
+#: routed feed-forwards: Ling's and Solar-Open2's form (sigmoid scores, the choice limited to 2 of 4 groups, a shared
+#: expert, the balance loss, 4 of the 16 experts held), the same with every expert held, and OLMoE's (softmax, all held)
+ROUTED = {
+    "sigmoid_held": dict(moe_scoring="sigmoid", moe_n_group=4, moe_topk_group=2, moe_routed_scale=2.5, moe_selection_bias=True,
+                         moe_seq_aux=True, moe_shared_hidden=32, moe_held_experts=(4, 4)),
+    "sigmoid_all": dict(moe_scoring="sigmoid", moe_n_group=4, moe_topk_group=2, moe_routed_scale=2.5, moe_selection_bias=True),
+    "softmax_all": dict(moe_norm_topk_prob=False),
+}
 HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
 FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
                "gated_memory", "cross_attention")
@@ -48,6 +59,9 @@ STEPS = {
     "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
     "kimi": dict(TINY_LM, **KIMI, num_layers=3, layer_types=KIMI_KINDS),
     "kimi_unbounded": dict(TINY_LM, **KIMI, **UNBOUNDED, num_layers=3, layer_types=KIMI_KINDS),
+    **{f"routed_{form}": dict(TINY_LM, **options, num_layers=2, layer_types=("full_attention",) * 2, ffn_types=("moe",) * 2,
+                              rope_base=None, num_experts=EXPERTS, moe_top_k=4, moe_expert_hidden=32)
+       for form, options in ROUTED.items()},
 }
 #: the Kimi delta rule's kernels by toy (forward, backward)
 KDA_KERNELS = {"kimi": ("kda_fwd", "kda_bwd"), "kimi_unbounded": ("kda_unbounded_fwd", "kda_unbounded_bwd")}
@@ -57,6 +71,10 @@ KDA_KERNELS = {"kimi": ("kda_fwd", "kda_bwd"), "kimi_unbounded": ("kda_unbounded
 # ``mlp_out`` under a norm on the sublayer's output only. Of the Mamba layers only the last before the gated memory
 # unit hands its ``y`` on, of the attention layers the full one, whose K and V the cross layer reads. Of the linear
 # layers a Kimi-delta layer keeps its rule's result and chunk states; the gated delta rule and the scan keep nothing.
+# A routed block, and no other, keeps what its router decided: the logits, the ids where the weights are gathered by
+# them (the sigmoid router), the sort (and its inverse where every expert is held: one name, twice) and the counts.
+ROUTER_KEEPS = ("router_logits", "router_ids", "moe_order", "moe_sizes")
+PR48_KEEPS = tuple(name for name in REMAT_KEEPS if name not in ROUTER_KEEPS)  # the parent's six
 BLOCKS = {
     "post_norm_linear_attention": ("hybrid", 0, None, {"mixer_out", "mlp_out"}),
     "post_norm_full_attention": ("hybrid", 3, None, {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
@@ -68,6 +86,11 @@ BLOCKS = {
     "pre_norm_gated_memory": ("phi4_flash", 6, "memory", {"mixer_out"}),
     "pre_norm_kimi_delta": ("kimi", 0, None, {"kda_out", "kda_states", "mixer_out"}),
     "pre_norm_kimi_delta_unbounded": ("kimi_unbounded", 2, None, {"kda_out", "kda_states", "mixer_out"}),
+    "pre_norm_routed_sigmoid_held": ("routed_sigmoid_held", 1, None, ("flash_out", "flash_lse", "mixer_out", *ROUTER_KEEPS)),
+    "pre_norm_routed_sigmoid_all": ("routed_sigmoid_all", 1, None,
+                                    ("flash_out", "flash_lse", "mixer_out", *ROUTER_KEEPS, "moe_order")),
+    "pre_norm_routed_softmax_all": ("routed_softmax_all", 0, None,
+                                    ("flash_out", "flash_lse", "mixer_out", "router_logits", "moe_order", "moe_order", "moe_sizes")),
 }
 
 
@@ -99,13 +122,20 @@ def _backward(fn, *args):
 def _kept(jaxpr):
     """``[(name or None, aval)]`` of the forward values the ``remat``
     equations of ``jaxpr`` read. JAX puts a ``reduce_precision`` to the
-    value's own type behind a kept value that the forward uses too."""
+    value's own type behind a kept value that the forward uses too, and a
+    jitted function that saves an argument for its pull-back returns it."""
     named, arguments, kept = {}, set(jaxpr.invars), []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "name":
             named[eqn.outvars[0]] = eqn.params["name"]
         elif eqn.primitive.name == "reduce_precision" and eqn.invars[0] in named:
             named[eqn.outvars[0]] = named[eqn.invars[0]]
+        elif eqn.primitive.name == "jit":  # a call that hands an argument on (``take_along_axis`` its indices)
+            inner = eqn.params["jaxpr"].jaxpr
+            handed = dict(zip(inner.invars, eqn.invars))
+            for out, var in zip(eqn.outvars, inner.outvars):
+                if not isinstance(var, Literal) and handed.get(var) in named:
+                    named[out] = named[handed[var]]
         elif eqn.primitive.name == "remat2":
             kept += [(named.get(v), v.aval) for v in eqn.invars
                      if not isinstance(v, Literal) and v not in arguments]
@@ -152,12 +182,21 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
         return
     # everything the backward is handed besides the block's arguments has a name, and each name once
     assert sorted(name or "unnamed" for name, _ in kept) == sorted(names)
+    names = set(names)
     shapes = {name: aval for name, aval in kept}
     for name in names & {"mixer_out", "mlp_out"}:  # one d_model-wide row a token
         assert shapes[name].shape == (1, SEQ, D_MODEL) and shapes[name].dtype == jnp.bfloat16
-    # nothing FFN-wide, and no float32 array but the flash rows' statistics and the Kimi delta rule's chunk states
+    # nothing FFN-wide, and no float32 array but the flash rows' statistics, the Kimi delta rule's chunk states and
+    # the router's logits
     assert all(aval.shape[-1] != MLP_HIDDEN for _, aval in kept)
-    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse", "kda_states"}
+    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse", "kda_states", "router_logits"}
+    assert names.isdisjoint(ROUTER_KEEPS) or spec.ffn == "moe"
+    if spec.ffn == "moe":  # an expert a logit, an id a choice, an index a (token, slot) row, a count an expert: no token row
+        top_k = STEPS[toy]["moe_top_k"]
+        assert shapes["router_logits"].shape == (1, SEQ, EXPERTS)
+        assert shapes["moe_order"].shape == (SEQ * top_k,) and shapes["moe_sizes"].shape == (EXPERTS,)
+        assert "router_ids" not in names or shapes["router_ids"].shape == (1, SEQ, top_k)
+        assert all(shapes[name].dtype == jnp.int32 for name in names & {"router_ids", "moe_order", "moe_sizes"})
     # the second forward (inside the remat equation) holds no flash forward; the backward's kernels are there
     second, = [eqn.params["jaxpr"] for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
     inside = _mosaic_calls(second)
@@ -219,9 +258,94 @@ def test_a_steps_gradient_runs_each_forward_kernel_once_a_layer(toy, remat, rout
             assert _twin_forward_scans(jaxpr, over_tokens=False) == kimi
     kept = [name for name, _ in _kept(jaxpr)]
     if remat:
+        routed, form = STEPS[toy].get("ffn_types", ()).count("moe"), ROUTED.get(toy.removeprefix("routed_"), {})
         expected = {"flash_out": attention, "flash_lse": attention, "mixer_out": len(kinds),
-                    "mlp_out": len(kinds) * (toy == "hybrid"), "kda_out": kimi, "kda_states": kimi}
+                    "mlp_out": len(kinds) * (toy == "hybrid"), "kda_out": kimi, "kda_states": kimi,
+                    "router_logits": routed, "router_ids": routed * (form.get("moe_scoring") == "sigmoid"),
+                    "moe_order": routed * (1 if "moe_held_experts" in form else 2), "moe_sizes": routed}
         assert {name: kept.count(name) for name in REMAT_KEEPS} == expected
+
+
+def _routing(jaxpr):
+    """``(sorts, top_ks, router matmuls)`` in ``jaxpr`` and the programs its
+    equations hold: a router's matmul is the ``dot_general`` into a logit an
+    expert and token (its two pull-backs give a ``d_model``-wide result)."""
+    eqns = list(_walk(jaxpr, kernels=False))
+    return (sum(eqn.primitive.name == "sort" for eqn in eqns), sum(eqn.primitive.name == "top_k" for eqn in eqns),
+            sum(eqn.primitive.name == "dot_general" and eqn.outvars[0].aval.shape == (1, SEQ, EXPERTS) for eqn in eqns))
+
+
+@pytest.mark.parametrize("program", ["parents", "kept"])
+@pytest.mark.parametrize("form", sorted(ROUTED))
+def test_a_routed_layers_second_forward_routes_nothing_again(form, program, monkeypatch):
+    """A step's gradient over two routed layers under ``remat``. With the six
+    names the parent kept, each block's second forward ran the router's matmul
+    into 16 logits a token, every ``top_k`` of the choice (three where it is
+    limited to groups) and the sort of the (token, slot) rows (and its
+    inverse where every expert is held) again; with what the router decided
+    kept (PR 50) it runs none of them: the weights are a gather of the scores,
+    made again from the kept logits, at the kept ids. The softmax router's
+    ``top_k`` is the exception: its weights are that ``top_k``'s own values
+    and its pull-back reads its own ids, so no name reaches them; it runs
+    again, on probabilities made from the kept logits (no cell trains a
+    softmax router under ``remat``; giving it the sigmoid router's gather
+    would change OLMoE's step)."""
+    if program == "parents":
+        monkeypatch.setattr(transformer, "REMAT_KEEPS", PR48_KEEPS)
+    jaxpr = _lm_backward(TransformerLM(**{**STEPS[f"routed_{form}"], "remat": True}))
+    layers, once = 2, 1 if program == "kept" else 2
+    sorts = 1 if "moe_held_experts" in ROUTED[form] else 2  # a held share never undoes its sort
+    top_ks = 3 if "moe_n_group" in ROUTED[form] else 1
+    softmax = "moe_scoring" not in ROUTED[form]
+    assert _routing(jaxpr) == (layers * sorts * once, layers * top_ks * (2 if softmax else once), layers * once)
+    seconds = [eqn.params["jaxpr"] for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
+    assert len(seconds) == layers
+    for second in seconds:
+        assert _routing(second) == ((0, int(softmax), 0) if program == "kept" else (sorts, top_ks, 1))
+    if program == "parents":
+        assert not {name for name, _ in _kept(jaxpr)} & set(ROUTER_KEEPS)
+
+
+@functools.cache
+def _routed_loss_and_gradients(form, dtype, program):
+    """Loss and every gradient of the two routed layers of ``form`` on 2 x 128
+    tokens: ``plain`` (no ``remat``), ``parents`` (``remat`` with the six names
+    PR 48 keeps: the routing runs again) or ``kept`` (this tree's)."""
+    model = TransformerLM(**{**STEPS[f"routed_{form}"], "dtype": dtype, "remat": program != "plain"})
+    tokens = jnp.asarray(np.random.RandomState(0).randint(0, 256, (2, 128)), jnp.int32)
+    weights = jnp.asarray(np.random.RandomState(1).randn(2, 128, D_MODEL), jnp.float32)
+    with pytest.MonkeyPatch.context() as patch:
+        if program == "parents":
+            patch.setattr(transformer, "REMAT_KEEPS", PR48_KEEPS)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+        others = {name: value for name, value in variables.items() if name != "params"}  # the selection biases
+
+        def loss(params):
+            hidden, sown = model.apply({"params": params, **others}, tokens, train=True, return_hidden=True, mutable=["losses"])
+            return jnp.sum(hidden * weights) + sum(jnp.sum(leaf) for leaf in jax.tree.leaves(sown))
+
+        return jax.jit(jax.value_and_grad(loss))(variables["params"])
+
+
+@pytest.mark.parametrize("program", ["plain", "parents", "kept"])
+@pytest.mark.parametrize("form, dtype", [*((form, jnp.float32) for form in sorted(ROUTED)), ("sigmoid_held", jnp.bfloat16)])
+def test_keeping_what_the_router_decided_changes_no_value(form, dtype, program):
+    """Three programs of one model, as for the Kimi delta rule below. The
+    kept values are the first forward's own (float32 logits are not rounded
+    on the way, integers cannot be), so loss and every gradient (the sown
+    balance losses included) of this tree's ``remat`` equal the parent's to
+    the bit, in bfloat16 too. (The program without ``remat`` is a third
+    reading, a rounding away from both in float32: XLA's CPU fusions sum a
+    block's second forward in another order than its first.)"""
+    plain = _routed_loss_and_gradients(form, dtype, "plain")
+    if program == "plain":  # the router and every expert held move the loss
+        moves = jax.tree.map(lambda g: float(jnp.max(jnp.abs(g))) > 0, plain[1]["block_1"]["moe"])
+        assert np.isfinite(float(plain[0])) and all(jax.tree.leaves(moves)), moves
+        return
+    got = _routed_loss_and_gradients(form, dtype, program)
+    jax.tree.map(np.testing.assert_array_equal, got, _routed_loss_and_gradients(form, dtype, "parents"))
+    if dtype == jnp.float32:
+        jax.tree.map(functools.partial(np.testing.assert_allclose, rtol=1e-4, atol=1e-6 * float(jnp.abs(plain[0]))), got, plain)
 
 
 @functools.cache
@@ -261,7 +385,7 @@ def test_keeping_the_kimi_delta_rules_results_changes_no_value(toy, route, dtype
     differently from its first, with or without the kept values, and the
     three programs differ in the second digit of the gradient; what is kept
     is no further from the program without ``remat`` than the parent's is."""
-    assert REMAT_KEEPS[4:] == ("kda_out", "kda_states")
+    assert REMAT_KEEPS[4:6] == ("kda_out", "kda_states")
     plain = _loss_and_gradients(toy, route, dtype, layers, "plain")
     if program == "plain":  # every parameter of the Kimi-delta block moves the loss
         moves = jax.tree.map(lambda g: float(jnp.max(jnp.abs(g))) > 0, plain[1]["params"]["block_0"])
@@ -286,8 +410,10 @@ def test_a_routed_blocks_remat_keeps_the_flash_results_too():
     model = TransformerLM(vocab_size=256, d_model=D_MODEL, num_heads=HEADS, num_layers=1, moe_every=1, num_experts=4,
                           moe_top_k=2, moe_expert_hidden=48, dtype=jnp.bfloat16, remat=True)
     jaxpr = _lm_backward(model)
-    # one block class: a routed layer's mixer result is named as a dense layer's is
-    assert sorted(name for name, _ in _kept(jaxpr) if name) == ["flash_lse", "flash_out", "mixer_out"]
+    # one block class: a routed layer's mixer result is named as a dense layer's is (and what its softmax router
+    # decided, since PR 50: the sort and its inverse under one name)
+    assert sorted(name for name, _ in _kept(jaxpr) if name) == [
+        "flash_lse", "flash_out", "mixer_out", "moe_order", "moe_order", "moe_sizes", "router_logits"]
     assert _mosaic_calls(jaxpr)["flash_fwd"] == 1
 
 
