@@ -95,11 +95,14 @@ from flax import linen as nn
 from hops_tpu.ops.grouped_matmul import DEFAULT_TILING, grouped_matmul, implementation
 from hops_tpu.parallel.mesh import per_shard, pvary
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_SHARED, keep
+from hops_tpu.telemetry.spans import MOE_SCOPES, SCOPE_MLP, SCOPE_MOE_LATENT, SCOPE_MOE_SHARED, keep
 
 SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS, SCOPE_COMBINE = MOE_SCOPES
-#: names of the expert-stacked weights, leading dim ``num_experts``
+#: names of the expert-stacked weights, leading dim ``num_experts`` (a
+#: ``relu2`` expert has no ``w_gate``)
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+#: a feed-forward's form: gated SwiGLU | ``W_down relu(W_up x)^2`` (``MoEMLP``, ``transformer.MLP``)
+ACTIVATIONS = ("swiglu", "relu2")
 
 _m_moe_traces = REGISTRY.counter(
     "hops_tpu_train_moe_traces_total",
@@ -173,6 +176,20 @@ def _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held=lambda rows:
         # float32 inside the fusion, one rounding on the way out
         act = (nn.silu(gate) * up * weight[:, None]).astype(rows.dtype)
         return grouped_matmul(act, w_down, sizes)
+
+
+def _relu2_experts(rows, weight, w_up, w_down, sizes, held=lambda rows: rows):
+    """:func:`_swiglu_experts` for experts of the non-gated form ``W_down
+    relu(W_up x)^2``: two grouped matmuls forward, four backward."""
+    with jax.named_scope(SCOPE_EXPERTS):
+        up = held(grouped_matmul(rows, w_up, sizes)).astype(jnp.float32)
+        act = (jnp.square(nn.relu(up)) * weight[:, None]).astype(rows.dtype)
+        return grouped_matmul(act, w_down, sizes)
+
+
+def _experts(rows, weight, *stacks, sizes, held=lambda rows: rows):
+    """The experts in the form their stacks say: three are SwiGLU's (gate, up, down), two ``relu2``'s (up, down)."""
+    return (_swiglu_experts if len(stacks) == 3 else _relu2_experts)(rows, weight, *stacks, sizes, held)
 
 
 #: sorted rows of a chunk that :func:`_add_rows` multiplies at a time: whole row
@@ -253,10 +270,11 @@ def _held_chunks(local_sizes, bound):
     return (jnp.sum(local_sizes) + bound - 1) // bound
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_share(x, top_p, stacks, order, local_sizes, k, bound):
     """The expert pass of a chip that holds a share of the experts: ``x``
-    (tokens, d), ``top_p`` (tokens * k,), ``order`` the sort that puts the
+    (tokens, d), ``top_p`` (tokens * k,), ``stacks`` the held experts'
+    weights (:func:`_experts`), ``order`` the sort that puts the
     held experts' rows first, ``local_sizes`` their groups. Works on
     ``bound`` sorted rows at a time and stops after the last chunk with a
     held row (one, unless the routing sends the share more than
@@ -267,27 +285,25 @@ def _held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
     run again and pulled back (only the arguments are kept for it)."""
     def body(c, out):
         token, _, live, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
-        out_rows = _swiglu_experts(rows, weight, w_gate, w_up, w_down, sizes, held)
+        out_rows = _experts(rows, weight, *stacks, sizes=sizes, held=held)
         with jax.named_scope(SCOPE_COMBINE):
             return _add_rows((out,), (out_rows,), token, live)[0]
 
-    operands = (x, top_p, w_gate, w_up, w_down, order, local_sizes)
+    operands = (x, top_p, *stacks, order, local_sizes)
     out = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body, _zeros_for(x.shape, *operands))
     return out.astype(x.dtype)
 
 
-def _held_share_fwd(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound):
-    return (_held_share(x, top_p, w_gate, w_up, w_down, order, local_sizes, k, bound),
-            (x, top_p, w_gate, w_up, w_down, order, local_sizes))
+def _held_share_fwd(x, top_p, stacks, order, local_sizes, k, bound):
+    return (_held_share(x, top_p, stacks, order, local_sizes, k, bound), (x, top_p, stacks, order, local_sizes))
 
 
 def _held_share_bwd(k, bound, res, g):
-    x, top_p, w_gate, w_up, w_down, order, local_sizes = res
+    x, top_p, stacks, order, local_sizes = res
 
     def body(c, grads):
         token, slot, live, held, sizes, rows, weight = _held_chunk(c, x, top_p, order, local_sizes, k, bound)
-        _, pull = jax.vjp(functools.partial(_swiglu_experts, sizes=sizes, held=held),
-                          rows, weight, w_gate, w_up, w_down)
+        _, pull = jax.vjp(functools.partial(_experts, sizes=sizes, held=held), rows, weight, *stacks)
         with jax.named_scope(SCOPE_COMBINE):
             d_out_rows = held(g[token])
         d_rows, d_weight, *d_w = pull(d_out_rows)
@@ -297,21 +313,23 @@ def _held_share_bwd(k, bound, res, g):
             d_x, d_top_p = _add_rows(grads[:2], (d_rows, d_weight), token, live)
         return (d_x, d_top_p, *(total + part.astype(jnp.float32) for total, part in zip(grads[2:], d_w)))
 
-    primals = (x, top_p, w_gate, w_up, w_down)
-    shapes = (x.shape, (x.shape[0], k), w_gate.shape, w_up.shape, w_down.shape)  # a token's k weights a row
+    primals = (x, top_p, *stacks)
+    shapes = (x.shape, (x.shape[0], k), *(w.shape for w in stacks))  # a token's k weights a row
     grads = jax.lax.fori_loop(0, _held_chunks(local_sizes, bound), body,
-                              tuple(_zeros_for(shape, *res, g) for shape in shapes))
-    return (*(grad.reshape(p.shape).astype(p.dtype) for grad, p in zip(grads, primals)), None, None)
+                              tuple(_zeros_for(shape, x, top_p, *stacks, order, local_sizes, g) for shape in shapes))
+    d_x, d_top_p, *d_stacks = (grad.reshape(p.shape).astype(p.dtype) for grad, p in zip(grads, primals))
+    return d_x, d_top_p, tuple(d_stacks), None, None
 
 
 _held_share.defvjp(_held_share_fwd, _held_share_bwd)
 
 
-def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, first=0):
+def _routed_experts(x, top_p, top_ids, *stacks, num_experts, first=0):
     """The dropless expert pass over one shard's tokens.
 
     ``x`` (b, s, d); ``top_p``/``top_ids`` (b, s, k) the chosen experts'
-    weights and ids; ``w_*`` the stacks of the ``len(w_gate)`` experts
+    weights and ids; ``stacks`` the weights (``w_gate``, ``w_up``, ``w_down``;
+    ``relu2`` experts: ``w_up``, ``w_down``) of the ``len(stacks[0])`` experts
     from id ``first`` on (all of them unless the caller holds a slice).
     Returns ``(out (b, s, d), rows per expert (1, num_experts))``; the
     leading 1 is the batch-leading partial ``per_shard`` stacks. A caller
@@ -323,7 +341,7 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
     b, s, d = x.shape
     k = top_ids.shape[-1]
     n_rows = b * s * k
-    n_local = w_gate.shape[0]
+    n_local = stacks[0].shape[0]
     share = n_local < num_experts
     flat_ids = top_ids.reshape(n_rows)
     with jax.named_scope(SCOPE_DISPATCH):
@@ -337,8 +355,7 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, first, n_local)
     if share:
         bound = _held_bound(n_rows, n_local, num_experts)
-        out = _held_share(x.reshape(b * s, d), top_p.reshape(n_rows), w_gate, w_up, w_down, order, local_sizes,
-                          k, bound)
+        out = _held_share(x.reshape(b * s, d), top_p.reshape(n_rows), tuple(stacks), order, local_sizes, k, bound)
         chunks, a_chunk = _held_chunks(local_sizes, bound), _add_tiles(bound, bound)
         full, rest = jnp.divmod(jnp.sum(local_sizes), bound)
         ran = jnp.stack([chunks, full * a_chunk + _add_tiles(rest, bound), chunks * a_chunk])
@@ -346,14 +363,16 @@ def _routed_experts(x, top_p, top_ids, w_gate, w_up, w_down, *, num_experts, fir
     with jax.named_scope(SCOPE_DISPATCH):
         rows = _to_sorted(x.reshape(b * s, d), order, inverse, k)
         weight = _to_sorted(top_p.reshape(n_rows), order, inverse, 1)
-    out_rows = _swiglu_experts(rows, weight, w_gate, w_up, w_down, local_sizes)
+    out_rows = _experts(rows, weight, *stacks, sizes=local_sizes)
     with jax.named_scope(SCOPE_COMBINE):
         out = _from_sorted(out_rows, order, inverse, k)
     return out.reshape(b, s, d), sizes[None]
 
 
 class MoEMLP(nn.Module):
-    """Top-k routed SwiGLU expert FFN over ``(batch, seq, d_model)``.
+    """Top-k routed expert FFN over ``(batch, seq, d_model)``: SwiGLU experts
+    at the token's width, or ``relu2`` experts, or experts in a latent between
+    two shared projections (``activation``, ``latent_dim``).
 
     ``expert_hidden`` is one expert's width (default: ``d_model x
     hidden_mult`` rounded down to a multiple of 128); ``norm_topk_prob``
@@ -401,11 +420,21 @@ class MoEMLP(nn.Module):
     seq_aux: bool = False
     shared_hidden: int | None = None
     held_experts: tuple[int, int] | None = None
+    # LatentMoE (``nemotron_h``): ``latent_dim`` puts the routed experts in a
+    # latent of that width between two shared projections, ``out = W_up (sum
+    # of the chosen experts' E_i(W_down x))``; the router and the shared expert
+    # read the token whole. ``activation``: "swiglu" | "relu2" (an expert, and
+    # the shared one, is ``W_down relu(W_up .)^2``: no ``w_gate``).
+    latent_dim: int | None = None
+    activation: str = "swiglu"
 
     @nn.compact
     def __call__(self, x):
         b, s, dm = x.shape
-        hidden = self.expert_hidden or max(128, (dm * self.hidden_mult // 128) * 128)
+        width = self.latent_dim or dm  # what an expert reads and writes
+        hidden = self.expert_hidden or max(128, (width * self.hidden_mult // 128) * 128)
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {self.activation!r} (one of {ACTIVATIONS})")
         if self.num_experts % self.expert_shards:
             raise ValueError(
                 f"{self.num_experts} experts not divisible by "
@@ -442,37 +471,43 @@ class MoEMLP(nn.Module):
         # shard's experts, otherwise parallelism comes from placing the
         # full stack P("expert", None, None) — see expert_specs() below.
         init = nn.initializers.lecun_normal()
-        w_gate, w_up, w_down = (
-            self.param(name, init, shape).astype(self.dtype)
-            for name, shape in zip(
-                EXPERT_WEIGHTS,
-                ((e_local, dm, hidden), (e_local, dm, hidden), (e_local, hidden, dm)),
-            )
-        )
+        names = EXPERT_WEIGHTS[self.activation == "relu2":]
+        stacks = tuple(
+            self.param(name, init, (e_local, hidden, width) if name == "w_down" else (e_local, width, hidden))
+            .astype(self.dtype) for name in names)
         n_rows = b * s * self.top_k
         held = e_local < self.num_experts
         _m_moe_traces.inc(
             impl=implementation(jax.ShapeDtypeStruct(
-                (_held_bound(n_rows, e_local, self.num_experts) if held else n_rows, dm), self.dtype), w_gate),
+                (_held_bound(n_rows, e_local, self.num_experts) if held else n_rows, width), self.dtype), stacks[0]),
             dispatch="held" if held else "all")
         first = 0 if self.held_experts is None else self.held_experts[0]
         if self.expert_axis is not None:
             first = jax.lax.axis_index(self.expert_axis) * e_local
+        routed_in = x.astype(self.dtype)
+        if self.latent_dim:
+            with jax.named_scope(SCOPE_MOE_LATENT):
+                routed_in = nn.Dense(width, dtype=self.dtype, use_bias=False, name="latent_down")(routed_in)
         out, rows_per_expert, *chunks = per_shard(
             functools.partial(_routed_experts, num_experts=self.num_experts, first=first),
-            op="moe", replicated=(3, 4, 5),
-        )(x.astype(self.dtype), top_p, top_ids, w_gate, w_up, w_down)
+            op="moe", replicated=tuple(range(3, 3 + len(stacks))),
+        )(routed_in, top_p, top_ids, *stacks)
         rows_per_expert = rows_per_expert.sum(0)
         if self.expert_axis is not None:
             # Each shard contributed its local experts' weighted rows;
             # the combine is a linear sum over experts, so psum over the
             # expert axis gives the whole layer.
             out = jax.lax.psum(out, self.expert_axis)
+        if self.latent_dim:
+            # W_up is linear: of a share's partial sum it gives the share's part of the layer
+            with jax.named_scope(SCOPE_MOE_LATENT):
+                out = nn.Dense(dm, dtype=self.dtype, use_bias=False, name="latent_up")(out)
         if self.shared_hidden:
             from hops_tpu.models.transformer import MLP
 
             with jax.named_scope(SCOPE_MOE_SHARED):
-                out = out + MLP(hidden=self.shared_hidden, dtype=self.dtype, name="shared")(x.astype(self.dtype))
+                out = out + MLP(hidden=self.shared_hidden, activation=self.activation, dtype=self.dtype,
+                                name="shared")(x.astype(self.dtype))
 
         with jax.named_scope(SCOPE_ROUTER):
             if self.scoring == "sigmoid":

@@ -41,8 +41,8 @@ from hops_tpu.models.linear_attention import (
     held_count,
     refuse_decode,
 )
-from hops_tpu.models.moe import build_routed_ffn
-from hops_tpu.models.state_space import build_gated_memory, build_mamba
+from hops_tpu.models.moe import ACTIVATIONS, build_routed_ffn
+from hops_tpu.models.state_space import build_gated_memory, build_mamba, build_mamba2
 from hops_tpu.ops.attention import (
     attention_reference,
     decode_attention,
@@ -83,7 +83,8 @@ class LayerSpec:
     """What one layer of a ``TransformerLM`` is, where it differs from its
     neighbours: the token mixer (a key of ``MIXERS``) and the feed-forward (a
     key of ``FFNS``) with the keyword arguments their builders read, as sorted
-    pairs; the norms; the value it hands on to later layers (None | "memory",
+    pairs (either may be ``NO_SUBLAYER``: the layer is then the other alone,
+    one norm and one residual); the norms; the value it hands on to later layers (None | "memory",
     a Mamba layer's ``y`` | "kv", an attention layer's K and V: its ``Block``
     then returns ``(x, value)``). ``index`` is its place in the stack, for its
     name. ``TransformerLM.layer_specs()`` resolves the model's fields to these."""
@@ -740,7 +741,9 @@ class LatentAttention(nn.Module):
 
 
 class MLP(nn.Module):
-    """SwiGLU: two fused up-projections + gated down-projection.
+    """SwiGLU: two fused up-projections + gated down-projection; with
+    ``activation="relu2"`` the non-gated form ``W_down relu(W_up x)^2`` (no
+    ``gate`` parameter), as ``nemotron_h`` publishes it.
 
     ``tp_axis``/``tp_shards``: Megatron split under an enclosing
     shard_map — gate/up are column-sharded (each device holds
@@ -754,11 +757,14 @@ class MLP(nn.Module):
     tp_shards: int = 1
     # The published width where it is not d_model x hidden_mult x 2/3.
     hidden: int | None = None
+    activation: str = "swiglu"  # | "relu2"
 
     @nn.compact
     def __call__(self, x):
         dm = x.shape[-1]
         hidden = self.hidden
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {self.activation!r} (one of {ACTIVATIONS})")
         if hidden is None:
             hidden = int(dm * self.hidden_mult * 2 / 3)
             hidden = max(128, (hidden // 128) * 128)  # MXU-aligned
@@ -767,11 +773,14 @@ class MLP(nn.Module):
                 f"hidden {hidden} not divisible by tp_shards={self.tp_shards}"
             )
         hidden //= self.tp_shards
-        gate = nn.Dense(hidden, dtype=self.dtype, use_bias=False, name="gate")(x)
-        up = nn.Dense(hidden, dtype=self.dtype, use_bias=False, name="up")(x)
-        out = nn.Dense(dm, dtype=self.dtype, use_bias=False, name="down")(
-            nn.silu(gate) * up
-        )
+        if self.activation == "relu2":
+            up = nn.Dense(hidden, dtype=self.dtype, use_bias=False, name="up")(x)
+            act = jnp.square(nn.relu(up))
+        else:
+            gate = nn.Dense(hidden, dtype=self.dtype, use_bias=False, name="gate")(x)
+            up = nn.Dense(hidden, dtype=self.dtype, use_bias=False, name="up")(x)
+            act = nn.silu(gate) * up
+        out = nn.Dense(dm, dtype=self.dtype, use_bias=False, name="down")(act)
         if self.tp_axis is not None:
             out = jax.lax.psum(out, self.tp_axis)
         return out
@@ -846,18 +855,23 @@ MIXERS = {
     "cross_attention": build_attention,  # queries of its own against the K and V of the nearest "full_attention" layer
     "kimi_delta_attention": build_kimi_delta_attention,  # the delta rule with a decay per key channel
     "latent_attention": build_latent_attention,  # keys and values through a low-rank projection
+    "mamba2": build_mamba2,  # the state-space-dual layer: one scalar decay a head, B and C a group of heads
 }
 #: ``LayerSpec.ffn`` -> ``(spec, shared) -> the feed-forward``, called with
 #: the sublayer's input: ``MLP`` named "mlp", ``moe.MoEMLP`` named "moe".
 FFNS = {"dense": build_dense_ffn, "moe": build_routed_ffn}
+#: in place of a mixer or of a feed-forward: the layer has none (``nemotron_h``: one sublayer a layer)
+NO_SUBLAYER = "none"
 #: the kinds of layer a ``TransformerLM`` builds (``layer_types``, ``ffn_types``)
-LAYER_TYPES, FFN_TYPES = tuple(MIXERS), tuple(FFNS)
+LAYER_TYPES, FFN_TYPES = tuple(MIXERS) + (NO_SUBLAYER,), tuple(FFNS) + (NO_SUBLAYER,)
 
 
 class Block(nn.Module):
     """One layer: norm, mixer, residual, norm, feed-forward, residual (the
-    norms before their sublayers or after them). ``value`` is what an
-    earlier layer handed on, for a kind that reads one."""
+    norms before their sublayers or after them); where the mixer or the
+    feed-forward is ``NO_SUBLAYER``, the other alone with its norm and its
+    residual. ``value`` is what an earlier layer handed on, for a kind that
+    reads one."""
 
     spec: LayerSpec
     shared: SharedSpec
@@ -870,30 +884,34 @@ class Block(nn.Module):
         def norm(t):
             return NORMS[spec.norm_kind](spec.norm_eps, dtype=self.shared.dtype)(t)
 
-        reads = SHARED_VALUES.get(spec.mixer)
-        if reads and value is None:
-            raise ValueError(
-                f"a {spec.mixer} layer reads the {reads[0]} of a {reads[1]} layer before it: none was handed on")
-        mixer = MIXERS[spec.mixer](spec, self.shared)
-        h = mixer(norm(x) if pre else x, decode=decode, **({reads[0]: value} if reads else {}))
-        if spec.hands_on:
-            h, handed_on = h
-        # remat keeps a sublayer's result where the backward reads it, not the
-        # matmul that made it: the mixer's under either placement (a norm on
-        # it, or the second norm on the sum it enters), the feed-forward's
-        # under a norm on it only
-        h = keep(h, "mixer_out")
-        if not pre:
-            h = norm(h)
-        if dropout_rate:
-            h = nn.Dropout(dropout_rate, deterministic=not train)(h)
-        x = x + h
-        h = FFNS[spec.ffn](spec, self.shared)(norm(x) if pre else x)
-        if not pre:
-            h = norm(keep(h, "mlp_out"))
-        if dropout_rate:
-            h = nn.Dropout(dropout_rate, deterministic=not train)(h)
-        return (x + h, handed_on) if spec.hands_on else x + h
+        handed_on = None
+        if spec.mixer != NO_SUBLAYER:
+            reads = SHARED_VALUES.get(spec.mixer)
+            if reads and value is None:
+                raise ValueError(
+                    f"a {spec.mixer} layer reads the {reads[0]} of a {reads[1]} layer before it: none was handed on")
+            mixer = MIXERS[spec.mixer](spec, self.shared)
+            h = mixer(norm(x) if pre else x, decode=decode, **({reads[0]: value} if reads else {}))
+            if spec.hands_on:
+                h, handed_on = h
+            # remat keeps a sublayer's result where the backward reads it, not the
+            # matmul that made it: the mixer's under either placement (a norm on
+            # it, or the second norm on the sum it enters), the feed-forward's
+            # under a norm on it only
+            h = keep(h, "mixer_out")
+            if not pre:
+                h = norm(h)
+            if dropout_rate:
+                h = nn.Dropout(dropout_rate, deterministic=not train)(h)
+            x = x + h
+        if spec.ffn != NO_SUBLAYER:
+            h = FFNS[spec.ffn](spec, self.shared)(norm(x) if pre else x)
+            if not pre:
+                h = norm(keep(h, "mlp_out"))
+            if dropout_rate:
+                h = nn.Dropout(dropout_rate, deterministic=not train)(h)
+            x = x + h
+        return (x, handed_on) if spec.hands_on else x
 
 
 class MTPModule(nn.Module):
@@ -1036,6 +1054,25 @@ class TransformerLM(nn.Module):
     kda_allow_neg_eigval: bool = False
     kda_output_gate: str = "head_wise"
     held_heads: tuple[int, int] | None = None
+    # A Mamba-2 / latent-MoE hybrid as ``nemotron_h`` configures it: ``layer_types``
+    # may also name "mamba2" (``mixer_options``: the ``mamba_*`` keys, the heads, a
+    # head's channels and state, the groups that share ``B`` and ``C`` and the
+    # scan's chunk; ``mamba_held_heads`` = this chip's
+    # (first, count) of ``mamba_num_heads``, whole groups) and, like ``ffn_types``,
+    # ``NO_SUBLAYER`` ("none": the layer is its other sublayer alone, one norm and
+    # one residual; every ``nemotron_h`` layer is a mixer or a feed-forward).
+    # ``mlp_activation`` "relu2" makes every feed-forward (dense, routed experts,
+    # shared expert) the non-gated ``W_down relu(W_up x)^2``; ``moe_latent_dim``
+    # puts the routed experts between two shared projections of that width
+    # (``moe.MoEMLP.latent_dim``).
+    mamba_num_heads: int | None = None
+    mamba_head_dim: int | None = None
+    mamba_state_dim: int | None = None
+    mamba_n_groups: int = 1
+    mamba_chunk: int = 128
+    mamba_held_heads: tuple[int, int] | None = None
+    mlp_activation: str = "swiglu"
+    moe_latent_dim: int | None = None
     # ``SharedSpec`` (with the fields of the same names above): the decode
     # cache and tensor parallelism. ``num_kv_heads`` and ``window`` are
     # ``mixer_options`` of the attention kinds.
@@ -1118,10 +1155,13 @@ class TransformerLM(nn.Module):
         linear = dict(num_heads=self.linear_num_heads or self.num_heads, key_dim=self.linear_key_dim,
                       value_dim=self.linear_value_dim, conv_size=self.linear_conv_size)
         held_heads = None if self.held_heads is None else tuple(self.held_heads)
-        held_kinds = ("full_attention", "sliding_attention", "kimi_delta_attention")
+        held_kinds = ("full_attention", "sliding_attention", "kimi_delta_attention", "mamba2", NO_SUBLAYER)
         if held_heads is not None and (self.attention_form != "softmax" or set(mixers) - set(held_kinds)):
             raise NotImplementedError(
                 f"held_heads is built for softmax attention and Kimi-delta layers ({held_kinds}), not {set(mixers)}")
+        for i, (mixer, ffn) in enumerate(zip(mixers, ffns)):
+            if mixer == ffn == NO_SUBLAYER:
+                raise ValueError(f"layer {i} has neither a mixer nor a feed-forward")
 
         def mixer_options(i, kind):
             if kind == "linear_attention":
@@ -1135,8 +1175,12 @@ class TransformerLM(nn.Module):
                               rope_dim=self.latent_rope_dim, value_dim=self.latent_value_dim,
                               rope_base=self.rope_base, output_gate=self.latent_output_gate,
                               qk_norm=self.latent_qk_norm)
-            if kind in ("mamba", "gated_memory"):
+            if kind in ("mamba", "gated_memory", NO_SUBLAYER):
                 return ()
+            if kind == "mamba2":
+                return _pairs(num_heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
+                              state_dim=self.mamba_state_dim, n_groups=self.mamba_n_groups, chunk=self.mamba_chunk,
+                              held_heads=None if self.mamba_held_heads is None else tuple(self.mamba_held_heads))
             # the attention kinds; a differential map's lambda_0 follows the layer's index
             return _pairs(
                 form=self.attention_form, use_bias=self.use_bias, num_kv_heads=self.num_kv_heads,
@@ -1145,8 +1189,8 @@ class TransformerLM(nn.Module):
                 **({"layer_index": i} if self.attention_form == "differential" else
                    {"head_dim": self.head_dim, "output_gate": self.attention_output_gate, "held_heads": held_heads}))
 
-        ffn_options = {"dense": _pairs(hidden=self.mlp_hidden), "moe": _pairs(
-            num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,
+        ffn_options = {NO_SUBLAYER: (), "dense": _pairs(hidden=self.mlp_hidden, activation=self.mlp_activation), "moe": _pairs(
+            activation=self.mlp_activation, latent_dim=self.moe_latent_dim, num_experts=self.num_experts, top_k=self.moe_top_k, expert_hidden=self.moe_expert_hidden,
             norm_topk_prob=self.moe_norm_topk_prob, scoring=self.moe_scoring, n_group=self.moe_n_group,
             topk_group=self.moe_topk_group, routed_scale=self.moe_routed_scale,
             selection_bias=self.moe_selection_bias, seq_aux=self.moe_seq_aux,

@@ -240,7 +240,7 @@ COUNTER_TRAIN_HELD_HEADS = "hops_tpu_train_held_heads_total"
 #: experts) buy 1.41 ms, 11.0 MB of Solar-Open2's (320) 0.97 ms: 12 MB a ms,
 #: the cheapest milliseconds ``remat`` had left (PERF.md section 6, PR 50).
 REMAT_KEEPS = ("flash_out", "flash_lse", "mlp_out", "mixer_out", "kda_out", "kda_states",
-               "router_logits", "router_ids", "moe_order", "moe_sizes")
+               "router_logits", "router_ids", "moe_order", "moe_sizes", "ssd_out", "ssd_states")
 #: One per value traced under a ``REMAT_KEEPS`` name (``what``), whether or
 #: not a ``remat`` encloses it: outside one the name is the identity.
 COUNTER_TRAIN_REMAT_KEPT = "hops_tpu_train_remat_kept_total"
@@ -262,9 +262,34 @@ def keep(x: Any, what: str) -> Any:
     return checkpoint_name(x, what)
 
 
-# The serving vocabulary stands below ``keep``, not beside the other
-# vocabularies above it: a training step's compile-cache key holds the
-# line ``keep`` calls ``checkpoint_name`` from.
+# What follows stands below ``keep``, not beside the other vocabularies
+# above it: a training step's compile-cache key holds the line ``keep``
+# calls ``checkpoint_name`` from.
+
+#: Of ``REMAT_KEEPS``, ``ssd_out`` / ``ssd_states``: the state-space-dual
+#: scan's result and the float32 state entering each chunk
+#: (``ops/ssd.py:_scan_fwd``, and nowhere else: only a Mamba-2 layer makes
+#: them), kept by ``kda_out`` / ``kda_states``' rule: 16.8 + 33.5 MB a layer at
+#: 16 heads x 8,192 tokens x (64, 128), so that the forward kernel runs once
+#: a layer and step.
+#:
+#: A Mamba-2 mixer (``models/state_space.py:Mamba2``) enters ``SSM_SCOPES``
+#: like a Mamba layer: ``ssm_proj`` (``W_in``, the step's bias and softplus,
+#: the log-decay), ``ssm_conv`` (the convolution over ``[x | B | C]`` with its
+#: activation), ``ssm_scan`` (``ops/ssd.py``, forward and backward; kernel
+#: names ``ssd_fwd``, ``ssd_bwd``; the ``D x`` term) and ``ssm_gate`` (the
+#: gate, the group-wise norm and ``W_out``).
+#: ``hops_tpu_train_ssm_traces_total{impl}`` counts it as ``ssd_pallas`` |
+#: ``ssd_xla_scan``. One per Mosaic call of the scan traced (``kernel`` =
+#: ``ssd_fwd`` | ``ssd_bwd``, the ``pallas_call`` names):
+COUNTER_TRAIN_SSD_KERNEL_CALLS = "hops_tpu_train_ssd_kernel_calls_total"
+#: Inside ``mlp`` beside ``MOE_SCOPES``, the two shared projections of a
+#: latent mixture of experts (``models/moe.py:MoEMLP(latent_dim=)``): ``W_down``
+#: from the token's width to the experts' latent width before the dispatch and
+#: ``W_up`` back after the combine. A layer whose experts read the token whole
+#: never enters it.
+SCOPE_MOE_LATENT = "moe_latent"
+
 
 #: The serving engine's vocabulary (``modelrepo/lm_engine.py``,
 #: ``modelrepo/serving.py:LMEnginePredictor``). Readers outside the program

@@ -97,11 +97,12 @@ def _interpret(monkeypatch, module, name):
 
 @pytest.fixture
 def scan_kernels_interpreted(monkeypatch):
-    """The selective scan takes its two Pallas kernels through the
-    interpreter, not its XLA twin."""
-    from hops_tpu.ops import selective_scan
+    """The selective scan and the state-space-dual scan take their two
+    Pallas kernels each through the interpreter, not their XLA twins."""
+    from hops_tpu.ops import selective_scan, ssd
 
     _interpret(monkeypatch, selective_scan, "selective_scan")
+    _interpret(monkeypatch, ssd, "ssd_scan")
 
 
 @pytest.fixture

@@ -356,9 +356,57 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_shape(one_chip, h, bounded):
     assert not moved, moved
 
 
+def test_ssd_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
+    """The state-space-dual scan's two kernels at the Nemotron-3-Super cell's
+    shape (16 held heads of 64 in one group, a state of 128, 8,192 tokens in
+    chunks of 128, bf16), forward and the hand-written backward (this file
+    holds the one fixture that may load the TPU compiler), on the arrays a
+    layer holds: ``x`` (b, s, 16 x 64) from the convolution seen by chunks,
+    ``y`` and the cotangents the same way. Mosaic accepts the (128, 1,024)
+    tiles, two heads of 64 to a lane tile and never a slice off a lane
+    boundary, the sixteen (128, 128) decay matrices a step and the VMEM they
+    ask for under ``_VMEM_LIMIT``; XLA is left the move of the two gates to
+    rows a chunk and head (1 MB) and no transpose or copy of a token-major
+    array. Nothing runs."""
+    from hops_tpu.ops import ssd
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
+    try:
+        b, s, h, p, g, n = 1, 8192, 16, 64, 1, 128
+        wide = jax.ShapeDtypeStruct((b, s, h * p), jnp.bfloat16, sharding=one_chip)
+        state = jax.ShapeDtypeStruct((b, s, g * n), jnp.bfloat16, sharding=one_chip)
+        gate = jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip)
+
+        def scan(x, dt, a, b_m, c_m):
+            y = ssd.ssd_scan(x.reshape(b, s, h, p), dt, a, b_m.reshape(b, s, g, n), c_m.reshape(b, s, g, n),
+                             interpret=False)
+            return y.reshape(b, s, h * p)
+
+        def grads(*x):
+            return jax.grad(lambda *y: scan(*y).astype(jnp.float32).sum(), argnums=range(5))(*x)
+
+        text = jax.jit(grads).lower(wide, gate, gate, state, state).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    calls = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2 and all(name in calls[0] + calls[1] for name in ("ssd_fwd", "ssd_bwd"))
+    whole = re.compile(r"\[((\d+),)*\d+\]")  # a result's dims
+
+    def elements(line):
+        dims = whole.search(line.split(" = ", 1)[1])
+        return np.prod([int(n) for n in dims.group(0)[1:-1].split(",")]) if dims else 0
+
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r" (transpose|copy|copy-start)\(", line) and elements(line) >= h * s * p]
+    assert not moved, moved
+
+
 # tokens, d_model, top_k, experts, held, expert width; the chunk; the layer's temporaries at the parent of PR 45
+# (``nemotron``: what an expert reads is the latent, 1,024 wide; ``relu2`` experts, two stacks; the temporaries PR 51 read)
 HELD_LAYERS = {"ling": ((8192, 2560, 8, 512, 8, 768), 4096, 372_246_016),
-               "kanana": ((8192, 2048, 6, 128, 16, 768), 24576, 850_136_064)}
+               "kanana": ((8192, 2048, 6, 128, 16, 768), 24576, 850_136_064),
+               "nemotron": ((8192, 1024, 22, 512, 16, 2688), 22528, 1_185_556_992)}
 
 
 @pytest.mark.parametrize("cell", HELD_LAYERS)
@@ -373,7 +421,11 @@ def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, 
     wide as a row tile of :func:`moe._add_rows` and never as wide as the
     chunk, and the temporaries are no more than they were while the product
     spanned the chunk (compile, PR 45; Ling's a third of the 1,075 MB the
-    layer needed while both gathers moved every row: compile, PR 40).
+    layer needed while both gathers moved every row: compile, PR 40); of the
+    Nemotron-3-Super cell (8,192 x 1,024 in the latent, top-22 of 512, 16
+    held, ``relu2`` experts 2,688 wide: two stacks, 180,224 routed rows of
+    which a chunk of 22,528 is worked on, six ``moe_gmm`` calls in the
+    backward loop, two of them the forward's again).
     Nothing runs."""
     from hops_tpu.models import moe
 
@@ -385,18 +437,21 @@ def test_a_held_share_compiles_for_v5e_without_a_row_it_does_not_take(one_chip, 
         def shape(dims, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-        def loss(x, top_p, w_gate, w_up, w_down, ids):
-            out = moe._routed_experts(x, top_p, ids, w_gate, w_up, w_down, num_experts=experts)[0]
+        stacks = [shape((held, d, hidden))] * (1 if cell == "nemotron" else 2) + [shape((held, hidden, d))]
+
+        def loss(ids, x, top_p, *stacks):
+            out = moe._routed_experts(x, top_p, ids, *stacks, num_experts=experts)[0]
             return out.astype(jnp.float32).sum()
 
-        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
-            shape((1, tokens, d)), shape((1, tokens, k), jnp.float32), shape((held, d, hidden)),
-            shape((held, d, hidden)), shape((held, hidden, d)), shape((1, tokens, k), jnp.int32)).compile()
+        compiled = jax.jit(jax.grad(loss, argnums=range(1, 3 + len(stacks)))).lower(
+            shape((1, tokens, k), jnp.int32), shape((1, tokens, d)), shape((1, tokens, k), jnp.float32), *stacks).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
     assert moe._held_bound(tokens * k, held, experts) == bound and bound % moe._ADD_TILE == 0
     text = compiled.as_text()
-    assert sum("tpu_custom_call" in line and "moe_gmm" in line for line in text.splitlines()) == 8  # 2 again + 6 back
+    # SwiGLU experts: gate and up again + 6 back; relu2 experts: up again + 4 back (the gradient alone is compiled)
+    calls = sum("tpu_custom_call" in line and "moe_gmm" in line for line in text.splitlines())
+    assert calls == (5 if cell == "nemotron" else 8), calls
     assert f"[{tokens * k},{d}]" not in text and f"[{tokens * k},{hidden}]" not in text and " scatter(" not in text
     assert f"[{bound},{d}]" in text
     assert f"pred[{tokens},{moe._ADD_TILE}]" in text and f"[{tokens},{bound}]" not in text

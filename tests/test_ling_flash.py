@@ -237,26 +237,34 @@ def _routing_with(load, tokens, experts, top_k, first, count, key):
 
 
 @pytest.mark.parametrize("impl,load", [("ragged_dot", "spread"), ("interpret", "spread"), ("ragged_dot", "none"),
-                                       ("ragged_dot", "inside_a_tile"), ("ragged_dot", "three_chunks")])
+                                       ("ragged_dot", "inside_a_tile"), ("ragged_dot", "three_chunks"),
+                                       ("ragged_dot", "top22_of_512"), ("interpret", "top22_of_512")])
 def test_a_held_share_is_its_part_of_the_layer_that_holds_every_expert(impl, load, monkeypatch):
     """Experts 6 and 7 of 32 over 1,024 routed rows, or of 64 over 1,536
     (bound 256, a chunk; a row tile of the combine cut to 64 for the test)
     against all the experts with every other one's matrices zero, on one
     routing: the result and the gradients of ``x``, the weights and the three
     stacks, where the share takes rows as they fall, none at all, 100 (it
-    ends inside the second of a chunk's four tiles) and 768 (three chunks)."""
+    ends inside the second of a chunk's four tiles) and 768 (three chunks).
+    ``top22_of_512``: Nemotron-3-Super's routing (experts 0-15 of 512, 22
+    choices a token: 5,632 routed rows of which a chunk of 768 is worked on)
+    with its ``relu2`` experts, two stacks and not three: every row counted,
+    none dropped, one chunk as :func:`moe._held_bound` says."""
     if impl == "interpret":
         monkeypatch.setattr(moe, "grouped_matmul", lambda *a: grouped_matmul(*a, interpret=True))
     monkeypatch.setattr(moe, "_ADD_TILE", 64)
-    first, count, top_k, d = 6, 2, 4, 128
+    first, count, top_k, d, names = 6, 2, 4, 128, ("w_gate", "w_up", "w_down")
     tokens, experts = (256, 32) if load == "spread" else (384, 64)
+    if load == "top22_of_512":
+        first, count, top_k, tokens, experts, names = 0, 16, 22, 256, 512, ("w_up", "w_down")
+    bound = 768 if load == "top22_of_512" else 256
     keys = jax.random.split(jax.random.PRNGKey(7), 6)
     x, seed = jax.random.normal(keys[0], (1, tokens, d)), jax.random.normal(keys[1], (1, tokens, d))
-    ids = _routing_with(load, tokens, experts, top_k, first, count, keys[2])
+    ids = _routing_with("spread" if load == "top22_of_512" else load, tokens, experts, top_k, first, count, keys[2])
     top_p = jax.random.uniform(keys[3], (1, tokens, top_k), minval=0.1)
-    stacks = [0.1 * jax.random.normal(key, (count, d, d)) for key in jax.random.split(keys[4], 3)]
+    stacks = [0.1 * jax.random.normal(key, (count, d, d)) for key in jax.random.split(keys[4], len(names))]
     held_rows = int(jnp.sum((ids >= first) & (ids < first + count)))
-    assert moe._held_bound(tokens * top_k, count, experts) == 256
+    assert moe._held_bound(tokens * top_k, count, experts) == bound
     assert {"none": held_rows == 0, "inside_a_tile": held_rows == 100, "three_chunks": held_rows == 768}.get(
         load, 0 < held_rows < 256)
 
@@ -267,20 +275,22 @@ def test_a_held_share_is_its_part_of_the_layer_that_holds_every_expert(impl, loa
     def whole(x, top_p, *stacks):
         return run(0, x, top_p, *(jnp.zeros((experts, d, d)).at[first: first + count].set(w) for w in stacks))
 
-    (_, (want, want_rows, none)), want_grads = jax.value_and_grad(whole, argnums=range(5), has_aux=True)(x, top_p, *stacks)
+    argnums = range(2 + len(names))
+    (_, (want, want_rows, none)), want_grads = jax.value_and_grad(whole, argnums=argnums, has_aux=True)(x, top_p, *stacks)
     (_, (got, rows, ran)), grads = jax.value_and_grad(
-        functools.partial(run, first), argnums=range(5), has_aux=True)(x, top_p, *stacks)
+        functools.partial(run, first), argnums=argnums, has_aux=True)(x, top_p, *stacks)
     # chunks run, row tiles the combine multiplied, row tiles those chunks have
     by_hand = {"none": [0, 0, 0], "inside_a_tile": [1, 2, 4], "three_chunks": [3, 12, 12]}.get(
-        load, [1, -(-held_rows // 64), 4])
+        load, [1, -(-held_rows // 64), bound // 64])
     assert none == [] and ran[0][0].tolist() == by_hand
     np.testing.assert_array_equal(rows, want_rows)
+    assert int(rows.sum()) == tokens * top_k  # every routed row is some expert's: none dropped
     assert _rel(got, want) < 1e-5 if held_rows else float(jnp.max(jnp.abs(got))) == float(jnp.max(jnp.abs(want))) == 0.0
-    for name, g, w in zip(("x", "top_p", "w_gate", "w_up", "w_down"), grads, want_grads):
+    for name, g, w in zip(("x", "top_p", *names), grads, want_grads):
         assert _rel(g, w) < 1e-5 if held_rows else float(jnp.max(jnp.abs(g))) == 0.0, name
     # the share's program moves a chunk's rows, never all the routed ones
     program = str(jax.make_jaxpr(functools.partial(run, first))(x, top_p, *stacks))
-    assert "f32[256,128]" in program and f"f32[{tokens * top_k},128]" not in program
+    assert f"f32[{bound},128]" in program and f"f32[{tokens * top_k},128]" not in program
     assert f"f32[{tokens * top_k},128]" in str(jax.make_jaxpr(whole)(x, top_p, *stacks))
 
 
@@ -471,7 +481,7 @@ def test_without_mtp_tokens_the_model_is_its_own_next_token_model(tiny):
 
 
 def test_kinds_are_checked():
-    assert {KDA, MLA} <= set(LAYER_TYPES) and FFN_TYPES == ("dense", "moe")
+    assert {KDA, MLA} <= set(LAYER_TYPES) and FFN_TYPES == ("dense", "moe", "none")  # "none": one sublayer a layer (PR 51)
     tokens = jnp.zeros((1, 8), jnp.int32)
     with pytest.raises(ValueError, match="ffn_types names 2 layers"):  # these three from layer_specs(), without init
         TransformerLM(**{**TINY, "ffn_types": ("dense", "moe")}).layer_specs()

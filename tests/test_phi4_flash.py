@@ -446,7 +446,9 @@ def test_the_models_fields_are_the_parents():
     ``tests/data/transformer_lm_parent_fields.json`` was written by PR 43's parent (3d29bff), name -> repr(default),
     in order. A layer's description (``LayerSpec``, ``SharedSpec``) changes below it. PR 44 added the latent
     mixer's two options after ``latent_value_dim``, on by default (what the parent built); PR 47 the six fields of a
-    Kimi-delta / gated-GQA hybrid that holds a share of its heads after ``mtp_layer_type``, each off by default."""
+    Kimi-delta / gated-GQA hybrid that holds a share of its heads after ``mtp_layer_type``, each off by default;
+    PR 51 the eight of a Mamba-2 / latent-MoE hybrid after ``held_heads``, each off (or the published layer's
+    constant) by default."""
     import dataclasses
 
     want = json.loads((DATA / "transformer_lm_parent_fields.json").read_text())
@@ -457,6 +459,11 @@ def test_the_models_fields_are_the_parents():
     since = {"head_dim": "None", "attention_output_gate": "False", "kda_gate_rank": "None",
              "kda_allow_neg_eigval": "False", "kda_output_gate": "'head_wise'", "held_heads": "None"}
     assert list(got)[list(got).index("mtp_layer_type") + 1:][:6] == list(since)
+    assert {name: got.pop(name) for name in since} == since
+    since = {"mamba_num_heads": "None", "mamba_head_dim": "None", "mamba_state_dim": "None", "mamba_n_groups": "1",
+             "mamba_chunk": "128", "mamba_held_heads": "None", "mlp_activation": "'swiglu'",
+             "moe_latent_dim": "None"}
+    assert list(got)[list(got).index("mtp_layer_type") + 1:][:8] == list(since)
     assert {name: got.pop(name) for name in since} == since
     assert list(got.items()) == list(want.items())
     assert [f.name for f in dataclasses.fields(Block) if f.name not in ("parent", "name")] == ["spec", "shared"]

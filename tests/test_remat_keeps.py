@@ -54,11 +54,18 @@ HYBRID_KINDS = ("linear_attention",) * 3 + ("full_attention",)
 FLASH_KINDS = ("mamba", "sliding_attention", "mamba", "sliding_attention", "mamba", "full_attention",
                "gated_memory", "cross_attention")
 KIMI_KINDS = ("kimi_delta_attention", "full_attention", "kimi_delta_attention")
+#: ``nemotron_h``: ONE sublayer a layer (a Mamba-2 mixer, a latent mixture of ``relu2`` experts, attention), shares held
+NEMOTRON_KINDS, NEMOTRON_FFNS = ("mamba2", "none", "mamba2", "full_attention", "none"), ("none", "moe", "none", "none", "moe")
+NEMOTRON = dict(rope_base=None, mamba_num_heads=8, mamba_head_dim=16, mamba_state_dim=16, mamba_n_groups=4,
+                mamba_held_heads=(2, 4), mlp_activation="relu2", moe_latent_dim=32, num_experts=16, moe_top_k=4,
+                moe_expert_hidden=32, moe_scoring="sigmoid", moe_routed_scale=5.0, moe_selection_bias=True,
+                moe_shared_hidden=96, moe_held_experts=(4, 4))
 STEPS = {
     "hybrid": dict(TINY_LM, **HYBRID, num_layers=4, layer_types=HYBRID_KINDS),
     "phi4_flash": dict(TINY_LM, **FLASH, num_layers=8, layer_types=FLASH_KINDS, window=512, tie_embeddings=True),
     "kimi": dict(TINY_LM, **KIMI, num_layers=3, layer_types=KIMI_KINDS),
     "kimi_unbounded": dict(TINY_LM, **KIMI, **UNBOUNDED, num_layers=3, layer_types=KIMI_KINDS),
+    "nemotron": dict(TINY_LM, **NEMOTRON, num_layers=5, layer_types=NEMOTRON_KINDS, ffn_types=NEMOTRON_FFNS),
     **{f"routed_{form}": dict(TINY_LM, **options, num_layers=2, layer_types=("full_attention",) * 2, ffn_types=("moe",) * 2,
                               rope_base=None, num_experts=EXPERTS, moe_top_k=4, moe_expert_hidden=32)
        for form, options in ROUTED.items()},
@@ -73,8 +80,10 @@ KDA_KERNELS = {"kimi": ("kda_fwd", "kda_bwd"), "kimi_unbounded": ("kda_unbounded
 # layers a Kimi-delta layer keeps its rule's result and chunk states; the gated delta rule and the scan keep nothing.
 # A routed block, and no other, keeps what its router decided: the logits, the ids where the weights are gathered by
 # them (the sigmoid router), the sort (and its inverse where every expert is held: one name, twice) and the counts.
+# A ``nemotron_h`` layer is one sublayer: its result enters the residual and no norm reads it, so neither sublayer's
+# result is held (the next block's input is); a Mamba-2 layer keeps its scan's result and chunk states.
 ROUTER_KEEPS = ("router_logits", "router_ids", "moe_order", "moe_sizes")
-PR48_KEEPS = tuple(name for name in REMAT_KEEPS if name not in ROUTER_KEEPS)  # the parent's six
+PR48_KEEPS = tuple(name for name in REMAT_KEEPS if name not in ROUTER_KEEPS)  # PR 50's parent's six (and the scan's two since)
 BLOCKS = {
     "post_norm_linear_attention": ("hybrid", 0, None, {"mixer_out", "mlp_out"}),
     "post_norm_full_attention": ("hybrid", 3, None, {"flash_out", "flash_lse", "mixer_out", "mlp_out"}),
@@ -86,6 +95,8 @@ BLOCKS = {
     "pre_norm_gated_memory": ("phi4_flash", 6, "memory", {"mixer_out"}),
     "pre_norm_kimi_delta": ("kimi", 0, None, {"kda_out", "kda_states", "mixer_out"}),
     "pre_norm_kimi_delta_unbounded": ("kimi_unbounded", 2, None, {"kda_out", "kda_states", "mixer_out"}),
+    "pre_norm_mamba2_alone": ("nemotron", 0, None, {"ssd_out", "ssd_states"}),
+    "pre_norm_latent_moe_alone": ("nemotron", 1, None, ROUTER_KEEPS),
     "pre_norm_routed_sigmoid_held": ("routed_sigmoid_held", 1, None, ("flash_out", "flash_lse", "mixer_out", *ROUTER_KEEPS)),
     "pre_norm_routed_sigmoid_all": ("routed_sigmoid_all", 1, None,
                                     ("flash_out", "flash_lse", "mixer_out", *ROUTER_KEEPS, "moe_order")),
@@ -189,7 +200,8 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     # nothing FFN-wide, and no float32 array but the flash rows' statistics, the Kimi delta rule's chunk states and
     # the router's logits
     assert all(aval.shape[-1] != MLP_HIDDEN for _, aval in kept)
-    assert {name for name, aval in kept if aval.dtype == jnp.float32} == names & {"flash_lse", "kda_states", "router_logits"}
+    assert {name for name, aval in kept if aval.dtype == jnp.float32} == \
+        names & {"flash_lse", "kda_states", "router_logits", "ssd_states"}
     assert names.isdisjoint(ROUTER_KEEPS) or spec.ffn == "moe"
     if spec.ffn == "moe":  # an expert a logit, an id a choice, an index a (token, slot) row, a count an expert: no token row
         top_k = STEPS[toy]["moe_top_k"]
@@ -207,6 +219,13 @@ def test_a_blocks_remat_keeps_its_input_and_the_named_values_its_backward_reads(
     if spec.mixer == "mamba":  # the scan's results are not kept: its forward runs again
         assert inside["selective_scan_fwd"] == inside["selective_scan_bwd"] == 1
         assert _mosaic_calls(jaxpr)["selective_scan_fwd"] == 2
+    if spec.mixer == "mamba2":  # the state-space-dual scan's are: the second forward makes its operands only
+        assert "ssd_fwd" not in inside and inside["ssd_bwd"] == 1
+        assert _mosaic_calls(jaxpr)["ssd_fwd"] == _mosaic_calls(jaxpr)["ssd_bwd"] == 1
+        # token-major by chunk as the kernel writes them: y in the model's type; a group's states in float32
+        held, groups, chunks = 4, 2, SEQ // 128
+        assert shapes["ssd_out"].shape == (1, chunks, 128, held * 16) and shapes["ssd_out"].dtype == jnp.bfloat16
+        assert shapes["ssd_states"].shape == (1, groups, chunks, 16, held // groups * 16)
     if spec.mixer == "linear_attention":  # nor the gated delta rule's: its three forward kernels run again
         assert inside["gated_delta_fwd"] == inside["gated_delta_bwd"] == 1
         assert _mosaic_calls(jaxpr)["gated_delta_fwd"] == 2
@@ -245,24 +264,28 @@ def test_a_steps_gradient_runs_each_forward_kernel_once_a_layer(toy, remat, rout
     calls = _mosaic_calls(jaxpr)
     assert calls["flash_fwd"] == calls["flash_bwd"] == attention  # one backward Mosaic call a layer, under remat too
     assert not {"flash_bwd_dq", "flash_bwd_dkv"} & set(calls)
-    mamba, gated, kimi = (kinds.count(kind) for kind in ("mamba", "linear_attention", "kimi_delta_attention"))
+    mamba, gated, kimi, mamba2 = (kinds.count(kind) for kind in ("mamba", "linear_attention", "kimi_delta_attention", "mamba2"))
     kda_forward, kda_backward = KDA_KERNELS.get(toy, KDA_KERNELS["kimi"])
     if route == "kernels":
         assert calls.get("selective_scan_fwd", 0) == (1 + remat) * mamba and calls.get("selective_scan_bwd", 0) == mamba
         assert calls.get("gated_delta_fwd", 0) == (1 + remat) * gated and calls.get("gated_delta_bwd", 0) == gated
         assert calls.get(kda_forward, 0) == calls.get(kda_backward, 0) == kimi  # once a layer, under remat too
+        assert calls.get("ssd_fwd", 0) == calls.get("ssd_bwd", 0) == mamba2  # and the state-space-dual scan
     else:
-        assert not {"selective_scan_fwd", "gated_delta_fwd", kda_forward} & set(calls)
+        assert not {"selective_scan_fwd", "gated_delta_fwd", kda_forward, "ssd_fwd"} & set(calls)
         assert _twin_forward_scans(jaxpr) == (1 + remat) * mamba
-        if kimi:  # (these toys hold no other scan of products: the gated delta rule's twin is one, and runs twice)
-            assert _twin_forward_scans(jaxpr, over_tokens=False) == kimi
+        if kimi or mamba2:  # (these toys hold no other scan of products: the gated delta rule's twin is one, and runs twice)
+            assert _twin_forward_scans(jaxpr, over_tokens=False) == kimi + mamba2
     kept = [name for name, _ in _kept(jaxpr)]
     if remat:
-        routed, form = STEPS[toy].get("ffn_types", ()).count("moe"), ROUTED.get(toy.removeprefix("routed_"), {})
-        expected = {"flash_out": attention, "flash_lse": attention, "mixer_out": len(kinds),
+        ffns, form = STEPS[toy].get("ffn_types", ("dense",) * len(kinds)), STEPS[toy]
+        routed = ffns.count("moe")
+        both = sum("none" not in pair for pair in zip(kinds, ffns))  # a norm reads the mixer's result only before a feed-forward
+        expected = {"flash_out": attention, "flash_lse": attention, "mixer_out": both,
                     "mlp_out": len(kinds) * (toy == "hybrid"), "kda_out": kimi, "kda_states": kimi,
                     "router_logits": routed, "router_ids": routed * (form.get("moe_scoring") == "sigmoid"),
-                    "moe_order": routed * (1 if "moe_held_experts" in form else 2), "moe_sizes": routed}
+                    "moe_order": routed * (1 if "moe_held_experts" in form else 2), "moe_sizes": routed,
+                    "ssd_out": mamba2, "ssd_states": mamba2}
         assert {name: kept.count(name) for name in REMAT_KEEPS} == expected
 
 
