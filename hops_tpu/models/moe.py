@@ -72,8 +72,10 @@ multiplied and ``moe_stats/held_tile_share`` is that count over the tiles of
 the chunks that ran (1.0: every chunk full; the step reports the layers' mean
 as ``moe_held_tile_share``); ``hops_tpu_train_moe_traces_total{dispatch}``
 says at trace time which of the two dispatches (``all`` | ``held``) a layer
-holds. It is also the send side of the exchange expert parallelism on chips
-needs: the rows a chip gathers for one peer's experts.
+holds, and ``{weights}`` how it reads its chosen experts' weights (``mask``:
+the sigmoid router's :func:`_chosen`; ``top_k``: the softmax router's
+``top_k``'s own values). It is also the send side of the exchange expert
+parallelism on chips needs: the rows a chip gathers for one peer's experts.
 
 Under ``TransformerLM(remat=True)`` a block holds what its router decided
 (``telemetry.spans.REMAT_KEEPS``: the float32 logits, the sigmoid router's
@@ -106,8 +108,9 @@ ACTIVATIONS = ("swiglu", "relu2")
 
 _m_moe_traces = REGISTRY.counter(
     "hops_tpu_train_moe_traces_total",
-    "Routed feed-forward layers traced, by the grouped matmul they hold and the rows they move (all | held)",
-    labels=("impl", "dispatch"),
+    "Routed feed-forward layers traced, by the grouped matmul they hold, the rows they move (all | held) "
+    "and how they read the chosen experts' weights (mask | top_k)",
+    labels=("impl", "dispatch", "weights"),
 )
 
 
@@ -147,6 +150,38 @@ def _from_sorted_bwd(k, res, g):
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+
+
+def _is_choice(ids, num_experts):
+    """``(..., k, E)``: whether expert ``e`` is a token's ``j``-th choice."""
+    return ids[..., None] == jnp.arange(num_experts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _chosen(scores, ids, num_experts):
+    """``scores (..., E)`` at each token's chosen ``ids (..., k)``:
+    ``take_along_axis(scores, ids, -1)`` as a compare, a select and a sum over
+    the experts, and its pull-back as the same summed over the ``k`` choices
+    (only ``ids`` is kept for it). A token's ids are distinct, so each sum
+    has at most one term that is not zero and both equal the gather's bit for
+    bit; the ``tokens x k x E`` mask lives inside one fusion. On the chip a
+    gather of scalars costs ~10 ns each and its transpose is a scatter-add
+    into zeros of the table's size; the mask costs ~1 ps an element (PERF.md,
+    PR 52)."""
+    chosen = jnp.sum(jnp.where(_is_choice(ids, num_experts), scores[..., None, :], 0), axis=-1)
+    # (behind a barrier: XLA folds a sum of these sums, the renormalisation's, into one over k x E in another order)
+    return jax.lax.optimization_barrier(chosen)
+
+
+def _chosen_fwd(scores, ids, num_experts):
+    return _chosen(scores, ids, num_experts), ids
+
+
+def _chosen_bwd(num_experts, ids, g):
+    return jnp.sum(jnp.where(_is_choice(ids, num_experts), g[..., None], 0), axis=-2), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
 #: a held share works on this many times the rows an even routing would
@@ -480,7 +515,7 @@ class MoEMLP(nn.Module):
         _m_moe_traces.inc(
             impl=implementation(jax.ShapeDtypeStruct(
                 (_held_bound(n_rows, e_local, self.num_experts) if held else n_rows, width), self.dtype), stacks[0]),
-            dispatch="held" if held else "all")
+            dispatch="held" if held else "all", weights="mask" if self.scoring == "sigmoid" else "top_k")
         first = 0 if self.held_experts is None else self.held_experts[0]
         if self.expert_axis is not None:
             first = jax.lax.axis_index(self.expert_axis) * e_local
@@ -554,9 +589,9 @@ class MoEMLP(nn.Module):
             group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
             kth = jax.lax.top_k(group_score, self.topk_group)[0][..., -1:]
             choice = jnp.where((group_score >= kth)[..., None], grouped, -jnp.inf).reshape(choice.shape)
-        # from the kept ids a block's second forward gathers the weights: none of the top_k runs again
+        # from the kept ids a block's second forward reads the weights again: none of the top_k runs again
         top_ids = keep(jax.lax.top_k(choice, self.top_k)[1], "router_ids")
-        top_p = jnp.take_along_axis(scores, top_ids, axis=-1)
+        top_p = _chosen(scores, top_ids, self.num_experts)
         if self.norm_topk_prob:
             top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
         return scores, top_p * self.routed_scale, top_ids

@@ -162,7 +162,7 @@ GAUGE_TRAIN_STATE_BYTES = "hops_tpu_train_state_bytes"
 #: an op a compiled step holds, and is added to while the step is traced.
 #: ``hops_tpu_train_per_shard_traces_total{op}`` (``parallel/mesh.py``),
 #: ``hops_tpu_train_loss_traces_total{pass}`` (``ops/xent.py``),
-#: ``hops_tpu_train_moe_traces_total{impl, dispatch}`` (``models/moe.py``),
+#: ``hops_tpu_train_moe_traces_total{impl, dispatch, weights}`` (``models/moe.py``),
 #: ``hops_tpu_train_linattn_traces_total{impl}``
 #: (``models/linear_attention.py``),
 #: ``hops_tpu_train_ssm_traces_total{impl}`` (``models/state_space.py``),
@@ -235,7 +235,7 @@ COUNTER_TRAIN_HELD_HEADS = "hops_tpu_train_held_heads_total"
 #: ids, the stable sort of the ``tokens x top_k`` rows (its inverse too, under
 #: the same name, where every expert is held) and the rows per expert. With
 #: them the second forward runs no router matmul, no ``top_k`` and no sort;
-#: the weights are a gather of the scores at the kept ids. Integers are kept
+#: the weights are the scores at the kept ids (``moe._chosen``). Integers are kept
 #: by name like any other value. 17.3 MB a layer of the Ling cell (512
 #: experts) buy 1.41 ms, 11.0 MB of Solar-Open2's (320) 0.97 ms: 12 MB a ms,
 #: the cheapest milliseconds ``remat`` had left (PERF.md section 6, PR 50).

@@ -468,13 +468,13 @@ def test_the_layer_kind_counter_names_the_form(tiny_step):
     half = TransformerLM(**{**TINY, "latent_qk_norm": True}).layer_specs()[0]
     assert counted_kind(half) == f"{MLA}_no_output_gate"
     state = make_state()  # the init traces the layers too: before the counts are read
-    kinds, held = _kinds(), REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch"))
+    kinds, held = _kinds(), REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch", "weights"))
     before = {k: kinds.labels(kind=k).value for k in (V3_FORM, MLA)}
-    before_held = held.value(impl="ragged_dot", dispatch="held")
+    before_held = held.value(impl="ragged_dot", dispatch="held", weights="mask")
     jax.jit(step).lower(state, {"tokens": tokens})
     assert kinds.labels(kind=V3_FORM).value - before[V3_FORM] == 3
     assert kinds.labels(kind=MLA).value == before[MLA]
-    assert held.value(impl="ragged_dot", dispatch="held") - before_held >= 2
+    assert held.value(impl="ragged_dot", dispatch="held", weights="mask") - before_held >= 2
     assert any(line.startswith("hops_tpu_train_layer_kinds_total{") and f'kind="{V3_FORM}"' in line
                for line in render_prometheus(REGISTRY).splitlines())  # what /metrics shows
 

@@ -216,9 +216,9 @@ def test_the_shares_add_up_to_the_uncut_layer(x, routed):
 # -- a held share moves only the rows its experts take ------------------------------------
 
 
-def _held_traces() -> float:
-    traces = REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch"))
-    return traces.value(impl="ragged_dot", dispatch="held")
+def _held_traces(weights: str) -> float:
+    traces = REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch", "weights"))
+    return traces.value(impl="ragged_dot", dispatch="held", weights=weights)
 
 
 def _routing_with(load, tokens, experts, top_k, first, count, key):
@@ -346,7 +346,7 @@ def test_a_share_under_the_expert_axis_is_a_held_share(x, shards):
     mesh = mesh_lib.make_mesh({"expert": shards}, devices=jax.devices()[:shards])
     part = MoEMLP(num_experts=32, top_k=4, expert_hidden=32, dtype=jnp.float32, expert_axis="expert",
                   expert_shards=shards)
-    before = _held_traces()
+    before = _held_traces("top_k")  # (a softmax router: the weights are its top_k's own values)
 
     split = jax.shard_map(lambda params, x: part.apply({"params": params}, x), mesh=mesh,
                           in_specs=(moe.expert_specs(params), P()), out_specs=P(), check_vma=False)
@@ -357,7 +357,7 @@ def test_a_share_under_the_expert_axis_is_a_held_share(x, shards):
     got, grads = jax.jit(jax.value_and_grad(functools.partial(run, split), argnums=(0, 1)))(params, x)
     want, want_grads = jax.value_and_grad(functools.partial(run, lambda params, x: whole.apply({"params": params}, x)),
                                           argnums=(0, 1))(params, x)
-    assert _held_traces() > before
+    assert _held_traces("top_k") > before
     assert float(got) == pytest.approx(float(want), rel=1e-5) and _rel(grads, want_grads) < 1e-5
 
 

@@ -1,9 +1,12 @@
 """MoE layer: routing math, aux loss, and expert-parallel placement."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax import linen as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from hops_tpu.models import moe as moe_lib
@@ -155,3 +158,107 @@ def test_add_rows_counts_a_last_tile_that_passes_the_end_once():
     (got,) = moe_lib._add_rows([jnp.zeros((n_tokens, 1))], (rows,), token, jnp.int32(bound))
     np.testing.assert_array_equal(got[:, 0], jnp.bincount(token, length=n_tokens).astype(jnp.float32))
     assert int(moe_lib._add_tiles(bound, bound)) == 2
+
+
+# -- the sigmoid router reads its chosen scores with a compare-and-sum (PR 52) ------------
+
+
+class _Choice(MoEMLP):
+    """The sigmoid router of a layer alone: ``(scores, weights, ids)`` of its logits."""
+
+    @nn.compact
+    def __call__(self, logits):
+        return self._sigmoid_choice(logits)
+
+
+def _sigmoid_router(experts, top_k, grouped, cls=_Choice, **overrides):
+    """A sigmoid-scored layer whose weights are its chosen scores as they are
+    (no renormalisation, scale 1); ``grouped``: the choice limited to 4 of 8
+    groups, under a selection bias that is not zero."""
+    options = dict(num_experts=experts, top_k=top_k, expert_hidden=32, scoring="sigmoid", norm_topk_prob=False,
+                   dtype=jnp.float32, **(dict(n_group=8, topk_group=4, selection_bias=True) if grouped else {}))
+    bias = {"router_bias": {"bias": 0.3 * jax.random.normal(jax.random.PRNGKey(7), (experts,))}} if grouped else {}
+    return cls(**{**options, **overrides}), bias
+
+
+@pytest.mark.parametrize("experts, top_k, grouped", [(512, 22, False), (512, 8, False), (320, 8, False), (128, 6, False),
+                                                     (512, 8, True)])
+def test_chosen_is_take_along_axis_to_the_bit(experts, top_k, grouped):
+    """``moe._chosen`` against the gather it replaced, at the four cells'
+    router widths and under Ling's group-limited choice: value and gradient
+    equal bit for bit (a token's ids are distinct, so each sum has one term
+    that is not zero), through the router too (``_sigmoid_choice``: the bias
+    enters the choice, never the weights)."""
+    layer, bias = _sigmoid_router(experts, top_k, grouped)
+    keys = jax.random.split(jax.random.PRNGKey(experts + top_k), 2)
+    logits = 2.0 * jax.random.normal(keys[0], (2, 64, experts))
+    cotangent = jax.random.normal(keys[1], (2, 64, top_k))
+    scores, weights, ids = layer.apply(bias, logits)
+    assert ids.shape == (2, 64, top_k) and all(len(set(row)) == top_k for row in np.asarray(ids).reshape(-1, top_k))
+    if grouped:  # the bias moved the choice
+        assert not np.array_equal(np.sort(ids), np.sort(jax.lax.top_k(scores, top_k)[1]))
+    np.testing.assert_array_equal(scores, jax.nn.sigmoid(logits))
+    np.testing.assert_array_equal(weights, jnp.take_along_axis(scores, ids, axis=-1))
+
+    def read(how):
+        return jax.value_and_grad(lambda s: jnp.sum(how(s) * cotangent))(scores)
+
+    def renormalised(how):  # in one program: XLA would fold this sum and the read's into one over k x E but for the barrier
+        def weights(s):
+            return how(s) / (how(s).sum(-1, keepdims=True) + 1e-20)
+
+        return jax.jit(jax.value_and_grad(lambda s: jnp.sum(weights(s) * cotangent)))(scores)
+
+    for program in (read, renormalised):
+        jax.tree.map(np.testing.assert_array_equal, program(lambda s: moe_lib._chosen(s, ids, experts)),
+                     program(lambda s: jnp.take_along_axis(s, ids, axis=-1)))
+    got = jax.grad(lambda x: jnp.sum(layer.apply(bias, x)[1] * cotangent))(logits)
+    want = jax.grad(lambda x: jnp.sum(jnp.take_along_axis(jax.nn.sigmoid(x), ids, axis=-1) * cotangent))(logits)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_sigmoid_layers_step_gathers_and_scatters_no_score(remat):
+    """Value and gradient of a sigmoid layer that holds a share of its
+    experts, alone and under a block's ``remat`` policy: the lowered program
+    has no ``gather`` out of the ``(tokens, experts)`` score table and no
+    ``scatter`` into one (the old read's transpose), and the sums that took
+    their place carry the router's scope, the pull-back's too (a
+    ``custom_vjp``'s backward is traced under its call's name stack)."""
+    from hops_tpu.telemetry.spans import REMAT_KEEPS
+
+    experts, top_k, tokens = 64, 6, 48  # no other array of the layer is 64 wide or 48 x 6 long
+    layer, _ = _sigmoid_router(experts, top_k, False, MoEMLP, routed_scale=2.5, held_experts=(8, 8))
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, tokens, 32))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+
+    def loss(params, x):
+        apply = jax.checkpoint(layer.apply, policy=jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)) \
+            if remat else layer.apply
+        return jnp.sum(jnp.square(apply({"params": params}, x)))
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, x).as_text(debug_info=True)
+    moved = [line for line in text.splitlines()
+             if re.search(r"stablehlo\.(gather|scatter)", line) and re.search(rf"tensor<(1x)?{tokens}x{experts}xf32>", line)]
+    assert not moved, moved
+    # in their place the mask's sums, the router's only ones (nothing renormalises here), under its scope in both passes
+    sums = set(re.findall(r'"jit\(loss\)/([^"]*)/MoEMLP\._sigmoid_choice/reduce_sum"', text))
+    assert len(sums) == 2 + remat and all(name.endswith("moe_router") for name in sums)
+    assert sum("transpose(" in name for name in sums) == 1 + remat  # (the second forward runs inside the transposed remat)
+
+
+@pytest.mark.parametrize("scoring, weights", [("sigmoid", "mask"), ("softmax", "top_k")])
+def test_the_trace_counter_says_how_a_layer_reads_its_weights(scoring, weights):
+    from hops_tpu.telemetry.export import render_prometheus
+    from hops_tpu.telemetry.metrics import REGISTRY
+
+    counter = REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch", "weights"))
+    labels = dict(impl="ragged_dot", dispatch="all", weights=weights)
+    other = {**labels, "weights": "top_k" if weights == "mask" else "mask"}
+    before, before_other = counter.value(**labels), counter.value(**other)
+    layer = MoEMLP(num_experts=8, top_k=2, expert_hidden=32, scoring=scoring, dtype=jnp.float32)
+    x = _x()
+    jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert counter.value(**labels) == before + 1 and counter.value(**other) == before_other
+    assert any(line.startswith("hops_tpu_train_moe_traces_total{") and f'weights="{weights}"' in line
+               for line in render_prometheus(REGISTRY).splitlines())  # what /metrics shows

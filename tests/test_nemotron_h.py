@@ -324,14 +324,14 @@ def test_step_trains_and_names_its_parts(tiny):
 
     model, _, _, tokens = tiny
     counter = REGISTRY.counter("hops_tpu_train_ssm_traces_total", "", labels=("impl",))
-    dispatch = REGISTRY.counter("hops_tpu_train_moe_traces_total", "", labels=("impl", "dispatch"))
-    scans, held = counter.value(impl="ssd_xla_scan"), dispatch.value(impl="ragged_dot", dispatch="held")
+    dispatch = REGISTRY.counter("hops_tpu_train_moe_traces_total", "", labels=("impl", "dispatch", "weights"))
+    scans, held = counter.value(impl="ssd_xla_scan"), dispatch.value(impl="ragged_dot", dispatch="held", weights="mask")
     state = jax.jit(functools.partial(common.create_train_state, model.clone(remat=True), input_shape=(1, 8),
                                       input_dtype=jnp.int32, optimizer=optax.adam(1e-2)))(jax.random.PRNGKey(0))
     step = make_lm_train_step(loss_chunk=32, router_bias_rate=1e-3)
     text = jax.jit(step).lower(state, {"tokens": tokens}).as_text(debug_info=True)
     assert counter.value(impl="ssd_xla_scan") >= scans + 2
-    assert dispatch.value(impl="ragged_dot", dispatch="held") >= held + 2
+    assert dispatch.value(impl="ragged_dot", dispatch="held", weights="mask") >= held + 2
     names = set(re.findall(r'"(jit\(train_step\)[^"]*)"', text))
     for scope, outer in (*((s, "attn") for s in SSM_SCOPES), (SCOPE_MOE_LATENT, "mlp"), *((s, "mlp") for s in MOE_SCOPES)):
         for backward in (False, True):

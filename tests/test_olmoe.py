@@ -276,8 +276,8 @@ def test_old_moe_fields_build_the_parents_tree_round_the_experts():
 
 
 def _moe_traces(impl: str) -> float:
-    return REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch")).value(
-        impl=impl, dispatch="all")
+    return REGISTRY.counter("hops_tpu_train_moe_traces_total", labels=("impl", "dispatch", "weights")).value(
+        impl=impl, dispatch="all", weights="top_k")
 
 
 @pytest.fixture(scope="module")
@@ -295,7 +295,7 @@ def test_step_reports_the_routing_and_counts_its_trace(tiny_step):
     _, metrics = jax.jit(step)(state, batch)
     assert _moe_traces("ragged_dot") > before
     exposed = [line for line in render_prometheus(REGISTRY).splitlines()
-               if line.startswith("hops_tpu_train_moe_traces_total{") and 'dispatch="all"' in line]
+               if line.startswith("hops_tpu_train_moe_traces_total{") and 'dispatch="all",weights="top_k"' in line]
     assert len(exposed) == 1 and 'impl="ragged_dot"' in exposed[0]  # what /metrics shows: every expert is held
     assert set(metrics) == {"loss", "perplexity", "moe_aux_loss", "moe_router_z_loss", "moe_load_max_over_mean"}
     assert all(np.isfinite(float(v)) for v in metrics.values())

@@ -382,7 +382,12 @@ def test_defaults_keep_the_parents_lowered_step(toy, monkeypatch):
     ``stablehlo.sort`` for 2) and the first gained a ``reduce_precision`` on
     each layer's kept logits (7 for 5); 9,292 lines where PR 48 wrote 9,400
     (that PR named the Kimi delta rule's ``o`` and chunk states: 14
-    ``stablehlo.while`` where 21d13a8 wrote 16). The three toys that route
+    ``stablehlo.while`` where 21d13a8 wrote 16), and again by PR 52 on top of
+    c13bb9e, whose sigmoid router reads its chosen scores with a compare and
+    a sum and no gather (``moe._chosen``): in each of the two routed layers
+    both forwards lost a ``stablehlo.gather`` (36 where c13bb9e wrote 40) and
+    gained an ``optimization_barrier`` (7 for 3), the backward lost its
+    ``stablehlo.scatter`` (4 for 6); 9,317 lines. The three toys that route
     nothing read their older digests, which is the proof that no other cell's
     step moved. ``olmoe_shaped`` routes without ``remat``: read with the
     router's names left out (``moe.keep`` an identity) its text is the

@@ -306,12 +306,12 @@ def test_a_routed_layers_second_forward_routes_nothing_again(form, program, monk
     into 16 logits a token, every ``top_k`` of the choice (three where it is
     limited to groups) and the sort of the (token, slot) rows (and its
     inverse where every expert is held) again; with what the router decided
-    kept (PR 50) it runs none of them: the weights are a gather of the scores,
-    made again from the kept logits, at the kept ids. The softmax router's
+    kept (PR 50) it runs none of them: the weights are read out of the scores,
+    made again from the kept logits, at the kept ids (``moe._chosen``). The softmax router's
     ``top_k`` is the exception: its weights are that ``top_k``'s own values
     and its pull-back reads its own ids, so no name reaches them; it runs
     again, on probabilities made from the kept logits (no cell trains a
-    softmax router under ``remat``; giving it the sigmoid router's gather
+    softmax router under ``remat``; giving it the sigmoid router's read
     would change OLMoE's step)."""
     if program == "parents":
         monkeypatch.setattr(transformer, "REMAT_KEEPS", PR48_KEEPS)
