@@ -54,7 +54,17 @@ from hops_tpu.ops.attention import (
 )
 from hops_tpu.parallel.mesh import per_shard
 from hops_tpu.telemetry.metrics import REGISTRY
-from hops_tpu.telemetry.spans import MLA_SCOPES, REMAT_KEEPS, SCOPE_ATTN_GATE, SCOPE_EMBED, SCOPE_MTP, keep
+from hops_tpu.telemetry.spans import (
+    COUNTER_TRAIN_LOOP_TRACES,
+    MLA_SCOPES,
+    REMAT_KEEPS,
+    SCOPE_ATTN_GATE,
+    SCOPE_EMBED,
+    SCOPE_LOOP_EXIT,
+    SCOPE_LOOP_STEP,
+    SCOPE_MTP,
+    keep,
+)
 
 _m_layer_kinds = REGISTRY.counter(
     "hops_tpu_train_layer_kinds_total",
@@ -66,6 +76,12 @@ _m_shared_reads = REGISTRY.counter(
     "hops_tpu_train_shared_reads_total",
     "Layers traced that read a value an earlier layer wrote, by the value",
     labels=("what",),
+)
+
+_m_loop_traces = REGISTRY.counter(
+    COUNTER_TRAIN_LOOP_TRACES,
+    "Looped TransformerLMs traced (the stack is in the program once), by their loop steps",
+    labels=("steps",),
 )
 
 #: what a reading kind takes from which writing kind; the mixer takes it
@@ -95,7 +111,7 @@ class LayerSpec:
     ffn: str = "dense"
     ffn_options: tuple[tuple[str, Any], ...] = ()
     norm_kind: str = "rms"  # "rms" | "layer": LayerNorm with bias
-    norm_placement: str = "pre"  # on each sublayer's input | "post_sublayer": on its output (Olmo 2)
+    norm_placement: str = "pre"  # on each sublayer's input | "post_sublayer": on its output (Olmo 2) | "sandwich": both
     norm_eps: float = 1e-6
     hands_on: str | None = None
 
@@ -870,8 +886,10 @@ class Block(nn.Module):
     """One layer: norm, mixer, residual, norm, feed-forward, residual (the
     norms before their sublayers or after them); where the mixer or the
     feed-forward is ``NO_SUBLAYER``, the other alone with its norm and its
-    residual. ``value`` is what an earlier layer handed on, for a kind that
-    reads one."""
+    residual. Under "sandwich" a sublayer has both norms, ``x + N'(f(N(x)))``:
+    four norms with parameters of their own in a layer of two sublayers, named
+    in that order. ``value`` is what an earlier layer handed on, for a kind
+    that reads one."""
 
     spec: LayerSpec
     shared: SharedSpec
@@ -879,7 +897,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False, value=None):
         spec, dropout_rate = self.spec, self.shared.dropout_rate
-        pre = spec.norm_placement == "pre"
+        pre = spec.norm_placement in ("pre", "sandwich")  # a norm on a sublayer's input
+        post = spec.norm_placement in ("post_sublayer", "sandwich")  # and one on its output
 
         def norm(t):
             return NORMS[spec.norm_kind](spec.norm_eps, dtype=self.shared.dtype)(t)
@@ -899,14 +918,14 @@ class Block(nn.Module):
             # it, or the second norm on the sum it enters), the feed-forward's
             # under a norm on it only
             h = keep(h, "mixer_out")
-            if not pre:
+            if post:
                 h = norm(h)
             if dropout_rate:
                 h = nn.Dropout(dropout_rate, deterministic=not train)(h)
             x = x + h
         if spec.ffn != NO_SUBLAYER:
             h = FFNS[spec.ffn](spec, self.shared)(norm(x) if pre else x)
-            if not pre:
+            if post:
                 h = norm(keep(h, "mlp_out"))
             if dropout_rate:
                 h = nn.Dropout(dropout_rate, deterministic=not train)(h)
@@ -1073,6 +1092,18 @@ class TransformerLM(nn.Module):
     mamba_held_heads: tuple[int, int] | None = None
     mlp_activation: str = "swiglu"
     moe_latent_dim: int | None = None
+    # A looped LM (arXiv:2510.25741, as ``ouro`` configures it): with
+    # ``loop_steps`` T > 1 the ``num_layers`` layers and the final norm run T
+    # times on the SAME parameters, ``h_t = N_f(Stack(h_{t-1}))`` from the
+    # embedding on, as a ``scan`` over the loop steps (the stack is in the
+    # program once); ``norm_placement`` "sandwich" is its layers' (a norm on
+    # each sublayer's input and on its output). ``loop_exit_gate`` puts a
+    # ``Dense(1)`` with bias, ``exit_gate``, on each step's normed hidden state,
+    # float32 out. With ``return_hidden`` such a model returns ``(the T hidden
+    # states (T, batch, seq, d), the gate's logits (T, batch, seq) | None)``,
+    # without it the logits of step T. Training only.
+    loop_steps: int = 1
+    loop_exit_gate: bool = False
     # ``SharedSpec`` (with the fields of the same names above): the decode
     # cache and tensor parallelism. ``num_kv_heads`` and ``window`` are
     # ``mixer_options`` of the attention kinds.
@@ -1126,8 +1157,14 @@ class TransformerLM(nn.Module):
             for kind in given:
                 if kind not in kinds:
                     raise ValueError(f"unknown {what} {kind!r} (one of {kinds})")
-        if self.norm_placement not in ("pre", "post_sublayer"):
+        if self.norm_placement not in ("pre", "post_sublayer", "sandwich"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.loop_steps < 1 or (self.loop_exit_gate and self.loop_steps == 1):
+            raise ValueError(f"loop_steps {self.loop_steps} (at least 1); an exit gate needs a loop to leave (loop_steps > 1)")
+        if self.loop_steps > 1 and (self.mtp_layers or "moe" in ffns):
+            raise NotImplementedError(
+                "a looped stack is built of dense layers without a multi-token-prediction module: a routed layer's "
+                "sown losses and counts would need an entry a loop step, the module a rule for which step it reads")
         if self.tp_shards > 1 and "moe" in ffns:
             raise NotImplementedError(
                 "tensor parallelism composes with dense TransformerLMs; "
@@ -1211,6 +1248,11 @@ class TransformerLM(nn.Module):
         mtp_tokens=None,
     ):
         specs, shared = self.layer_specs(), self.shared_spec()
+        if decode and self.loop_steps > 1:
+            raise NotImplementedError(
+                "decode of a looped model: it needs a cache a loop step AND layer (loop_steps x the keys and "
+                "values, which neither Attention's cache nor modelrepo/paged.py's pool lays out) and a rule for "
+                "the step a token leaves at; a looped TransformerLM trains and runs whole sequences only")
         embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
         x = embed(tokens)
         block_cls = Block
@@ -1219,17 +1261,27 @@ class TransformerLM(nn.Module):
             # the rest of its forward runs again in the backward pass
             kept = jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS)
             block_cls = nn.remat(Block, static_argnums=(2, 3), policy=kept)
-        handed_on: dict[str, Any] = {}  # the newest value of each kind: a reader's nearest writer's
         for spec in specs[: self.num_layers]:
             _m_layer_kinds.inc(kind=counted_kind(spec))
-            value = ()
             if spec.mixer in SHARED_VALUES:
-                what = SHARED_VALUES[spec.mixer][0]
-                _m_shared_reads.inc(what=what)
-                value = (handed_on[what],)
-            x = block_cls(spec, shared, name=f"block_{spec.index}")(x, train, decode, *value)
-            if spec.hands_on:
-                x, handed_on[spec.hands_on] = x
+                _m_shared_reads.inc(what=SHARED_VALUES[spec.mixer][0])
+
+        def stack(mdl, x):
+            """The ``num_layers`` blocks of ``mdl``, in order, on ``x``."""
+            handed_on: dict[str, Any] = {}  # the newest value of each kind: a reader's nearest writer's
+            for spec in specs[: self.num_layers]:
+                value = (handed_on[SHARED_VALUES[spec.mixer][0]],) if spec.mixer in SHARED_VALUES else ()
+                x = block_cls(spec, shared, parent=mdl, name=f"block_{spec.index}")(x, train, decode, *value)
+                if spec.hands_on:
+                    x, handed_on[spec.hands_on] = x
+            return x
+
+        def final_norm(mdl, x):
+            return NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype, parent=mdl, name="final_norm")(x)
+
+        if self.loop_steps > 1:
+            return self._looped(x, stack, final_norm, embed, return_hidden)
+        x = stack(self, x)
         mtp_hidden = None
         if self.mtp_layers and not decode and (mtp_tokens is not None or self.is_initializing()):
             # the module predicts the token after the next from the last layer's
@@ -1238,18 +1290,102 @@ class TransformerLM(nn.Module):
             mtp_hidden = MTPModule(functools.partial(block_cls, specs[-1], shared), self.norm_eps,
                                    dtype=self.dtype, name=SCOPE_MTP)(
                 x, embed(tokens if mtp_tokens is None else mtp_tokens), train)
-        x = NORMS[self.norm_kind](self.norm_eps, dtype=self.dtype, name="final_norm")(x)
+        x = final_norm(self, x)
         if return_hidden:
             # The chunked-vocab loss (ops/xent.py) computes the loss
             # straight from hidden states + the unembed kernel without
             # ever materializing (batch, seq, vocab) fp32 logits.
             return x if mtp_tokens is None else (x, mtp_hidden)
-        if self.tie_embeddings:
-            head = lambda t: embed.attend(t).astype(jnp.float32)  # noqa: E731
-        else:
-            unembed = nn.Dense(self.vocab_size, dtype=self.dtype, use_bias=False, name="unembed")
-            head = lambda t: unembed(t).astype(jnp.float32)  # noqa: E731
+        head = self._head(embed)
         return head(x) if mtp_tokens is None else (head(x), head(mtp_hidden))
+
+    def _head(self, embed):
+        """Float32 logits of hidden states: the embedding matrix's where it is tied, else ``unembed``'s."""
+        if self.tie_embeddings:
+            return lambda t: embed.attend(t).astype(jnp.float32)
+        unembed = nn.Dense(self.vocab_size, dtype=self.dtype, use_bias=False, name="unembed")
+        return lambda t: unembed(t).astype(jnp.float32)
+
+    def _looped(self, x, stack, final_norm, embed, return_hidden):
+        """``loop_steps`` passes of ``final_norm(stack(.))`` from ``x`` on, each
+        reading what the one before wrote, as ONE ``scan`` whose body holds
+        the stack once: the parameters enter it whole (``variable_broadcast``),
+        the hidden state is the carry, and each step puts out its normed
+        hidden state and, with ``loop_exit_gate``, the gate's logit a token.
+        Per-block ``remat`` works inside the body as outside a loop: of every
+        step the backward holds each block's input and its kept values."""
+        _m_loop_traces.inc(steps=str(self.loop_steps))
+
+        def leave(mdl, x):
+            """A step's normed hidden state and, with ``loop_exit_gate``, its gate's logit a token."""
+            h = final_norm(mdl, x)
+            if not self.loop_exit_gate:
+                return h, None
+            with jax.named_scope(SCOPE_LOOP_EXIT):
+                return h, nn.Dense(1, dtype=jnp.float32, parent=mdl, name="exit_gate")(h)[..., 0]
+
+        if self.remat:
+            # of the norm and the gate the backward holds the stack's output alone, in the model's dtype: their
+            # float32 working values (three of them as large as it, and twice as wide) are made again
+            leave = nn.remat(leave)
+
+        def loop_step(mdl, h, _):
+            with jax.named_scope(SCOPE_LOOP_STEP):
+                h, gate = leave(mdl, stack(mdl, h))
+            return h, (h, gate)
+
+        x, (hidden, gates) = nn.scan(
+            loop_step, variable_broadcast="params", split_rngs={"params": False, "dropout": True},
+            length=self.loop_steps)(self, x, None)
+        if return_hidden:
+            return hidden, gates
+        return self._head(embed)(x)
+
+
+def exit_distribution(gate_logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(p, ln p)`` of the loop step a token leaves a looped model at, from
+    the exit gate's logits ``(T, ...)``: with ``lambda_t = sigmoid(logit_t)``,
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for ``t < T`` and ``p_T =
+    prod_{j<T} (1 - lambda_j)``, the rest (step ``T``'s own logit is not read).
+    Float32, formed as logarithms: ``ln p`` is exact where ``p`` underflows."""
+    logits = gate_logits[:-1].astype(jnp.float32)
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-logits), axis=0)  # ln prod_{j<=t} (1 - lambda_j)
+    before = jnp.concatenate([jnp.zeros_like(stayed[:1]), stayed[:-1]])
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(logits) + before, stayed[-1:]])
+    return jnp.exp(log_p), log_p
+
+
+def loop_exit_loss(hidden, gate_logits, unembed, targets, *, chunk: int, vocab_major: bool = False, beta: float = 0.0):
+    """A looped model's first-stage objective (arXiv:2510.25741) from its ``T``
+    hidden states ``(T, batch, seq, d)`` and exit-gate logits ``(T, batch,
+    seq)``: ``(total, metrics)`` with ``total = mean over tokens of [sum_t p_t
+    CE_t - beta H(p)]``, ``CE_t`` step ``t``'s next-token cross-entropy, ``p``
+    the token's `exit_distribution`, ``H`` its entropy. ONE chunked LM-head
+    pass over the ``T x batch x seq`` rows with ``p`` as the weights
+    (``ops/xent.py``): ``dW_head`` is formed once, the gate learns through the
+    weights' cotangent, and the pass's per-token losses give each step's own
+    mean loss. ``metrics``: ``loss`` (``sum_t p_t CE_t``, mean), ``loop_loss_steps``
+    (the ``T`` unweighted means), ``loop_exit_entropy``, ``loop_exit_mean_step``
+    (``sum_t t p_t``, mean; 1..T). The distribution and its entropy carry the
+    ``loop_exit`` scope, the pass ``lm_head_loss``."""
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    steps, b, s, _ = hidden.shape
+    with jax.named_scope(SCOPE_LOOP_EXIT):
+        p, log_p = exit_distribution(gate_logits)
+        entropy = -jnp.mean(jnp.sum(p * log_p, axis=0))
+        mean_step = jnp.mean(jnp.tensordot(jnp.arange(1.0, steps + 1), p, axes=1))
+
+    def rows(x):  # batch-leading: a batch sharded over chips keeps each row's T x seq rows on its chip
+        return jnp.moveaxis(x, 0, 1).reshape(b, steps * s, *x.shape[3:])
+
+    value, token_losses = chunked_softmax_xent(
+        rows(hidden), unembed, rows(jnp.broadcast_to(targets, (steps, b, s))), chunk=chunk,
+        vocab_major=vocab_major, weights=rows(p))
+    loss = steps * value  # the pass divides by its T x batch x seq rows
+    metrics = {"loss": loss, "loop_loss_steps": jnp.mean(token_losses.reshape(b, steps, s), axis=(0, 2)),
+               "loop_exit_entropy": entropy, "loop_exit_mean_step": mean_step}
+    return loss - beta * entropy, metrics
 
 
 def make_lm_train_step(
@@ -1259,6 +1395,7 @@ def make_lm_train_step(
     mtp_loss_weight: float = 0.0,
     seq_aux_loss_weight: float = 0.0,
     router_bias_rate: float = 0.0,
+    loop_exit_beta: float = 0.0,
 ):
     """Next-token-prediction step: ``(state, {"tokens"}) -> (state, metrics)``.
 
@@ -1286,6 +1423,14 @@ def make_lm_train_step(
     carries (``TrainState.router_bias``) after the step, by the step's own
     expert loads and by no gradient (``moe.updated_router_bias``).
 
+    A looped model (``TransformerLM(loop_steps=T)``) trains under
+    ``loss_chunk``. With an exit gate its objective is `loop_exit_loss`'s at
+    ``beta = loop_exit_beta``: every loop step's cross-entropy weighted by the
+    token's exit distribution, less ``beta`` times that distribution's
+    entropy; ``loss`` is the weighted cross-entropy, and ``loop_loss_steps``,
+    ``loop_exit_entropy`` and ``loop_exit_mean_step`` stand beside it. Without
+    a gate it trains on step ``T``'s hidden states alone.
+
     ``loss_chunk``: compute the loss via the memory-efficient
     token-chunked LM-head path (``ops/xent.py``) — ``loss_chunk``
     tokens' logits at a time, so the (batch, seq, vocab) fp32 logits
@@ -1303,6 +1448,9 @@ def make_lm_train_step(
         held_overflows, held_tile_share, max_load_over_mean, sum_sown_losses, updated_router_bias)
     from hops_tpu.parallel.mesh import gathered
     from hops_tpu.telemetry.spans import SCOPE_LM_HEAD_LOSS, SCOPE_OPTIMIZER
+
+    if loop_exit_beta and not loss_chunk:
+        raise ValueError("loop_exit_beta weighs a looped model's loss, which runs through the chunked head: give loss_chunk")
 
     def train_step(state, batch):
         tokens = batch["tokens"]
@@ -1325,26 +1473,33 @@ def make_lm_train_step(
                 **({"mtp_tokens": targets} if mtp_loss_weight else {}),
             )
 
+            # tied embeddings: the loss reads the embedding matrix as it
+            # lies, (vocab, d), and its dW joins the gather's gradient
+            tied = "unembed" not in params
+            head = params["embed"]["embedding"] if tied else params["unembed"]["kernel"]
+
             def token_loss(out, targets):
                 if loss_chunk:
                     from hops_tpu.ops.xent import chunked_softmax_xent
 
-                    # tied embeddings: the loss reads the embedding matrix as it
-                    # lies, (vocab, d), and its dW joins the gather's gradient
-                    tied = "unembed" not in params
-                    return chunked_softmax_xent(
-                        out,
-                        params["embed"]["embedding"] if tied else params["unembed"]["kernel"],
-                        targets, chunk=loss_chunk, vocab_major=tied,
-                    )
+                    return chunked_softmax_xent(out, head, targets, chunk=loss_chunk, vocab_major=tied)
                 with jax.named_scope(SCOPE_LM_HEAD_LOSS):
                     return optax.softmax_cross_entropy_with_integer_labels(out, targets).mean()
 
+            gate_logits = None
             if mtp_loss_weight:
                 out, mtp_out = out
-            loss = token_loss(out, targets)
-            metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
-            total = loss
+            elif isinstance(out, tuple):  # a looped model's T hidden states and its exit gate's logits
+                hidden, gate_logits = out
+                out = hidden[-1]
+            if gate_logits is not None:
+                total, metrics = loop_exit_loss(
+                    hidden, gate_logits, head, targets, chunk=loss_chunk, vocab_major=tied, beta=loop_exit_beta)
+                metrics["perplexity"] = jnp.exp(metrics["loss"])
+            else:
+                loss = token_loss(out, targets)
+                metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
+                total = loss
             if mtp_loss_weight:
                 metrics["mtp_loss"] = token_loss(mtp_out, mtp_targets)
                 total = total + mtp_loss_weight * metrics["mtp_loss"]

@@ -68,7 +68,8 @@ def chunked_softmax_xent(
     *,
     chunk: int = 128,
     vocab_major: bool = False,
-) -> jax.Array:
+    weights: jax.Array | None = None,
+) -> jax.Array | tuple[jax.Array, jax.Array]:
     """Mean next-token cross-entropy from hidden states.
 
     ``hidden``: (batch, seq, d) — the final-norm output;
@@ -96,9 +97,25 @@ def chunked_softmax_xent(
     loss), as for flash. Everywhere else (one device, a step already
     inside ``shard_map``) the one loop runs over all tokens.
 
+    ``weights``: (batch, seq) float32, one a token. The value is then
+    ``sum_i w_i CE_i / N`` over the ``N = batch x seq`` tokens and the call
+    returns ``(value, CE)`` with ``CE`` the tokens' own unweighted losses
+    (batch, seq) float32, a constant under differentiation. The same one pass
+    makes the gradients: a chunk's ``dlogits`` are scaled by its tokens'
+    weights, and the ``CE_i`` are the residual that is the weights' cotangent
+    (``d value / d w_i = CE_i / N``), so a weight may itself be learned (a
+    looped model's exit distribution: ``make_lm_train_step``). Without
+    ``weights`` nothing of this is traced.
+
     Traced under the ``lm_head_loss`` scope, so every device op of the
     loss and of its backward carries that name in the profiler trace.
     """
+    if weights is not None:
+        sums, token_losses = per_shard(
+            functools.partial(_weighted_loss_sum, chunk=chunk, vocab_major=vocab_major),
+            op=SCOPE_LM_HEAD_LOSS, replicated=(1,),
+        )(hidden, unembed, targets, weights.astype(jnp.float32))
+        return jnp.sum(sums) / targets.size, jax.lax.stop_gradient(token_losses)
     sums = per_shard(
         functools.partial(_loss_sum, chunk=chunk, vocab_major=vocab_major),
         op=SCOPE_LM_HEAD_LOSS, replicated=(1,),
@@ -123,10 +140,12 @@ _m_loss_traces = REGISTRY.counter(
 )
 
 
-def _grouped(hidden: jax.Array, targets: jax.Array, chunk: int):
+def _grouped(hidden: jax.Array, targets: jax.Array, chunk: int, weights: jax.Array | None = None):
     """Flatten to tokens, pad to whole groups, and shape for the two
     loops: ``h`` ``(groups, per_group, chunk, d)``, ``t`` and the fp32
-    ``valid`` mask ``(groups, per_group, chunk)``. A group is as many
+    ``valid`` mask ``(groups, per_group, chunk)``: what a token's loss
+    counts for, 1 a token and 0 in the padding, or the token's weight
+    where ``weights`` are given. A group is as many
     chunks as fill ``_GROUP_ROWS`` rows, spread evenly over the groups
     and never more than the tokens have."""
     b, s, d = hidden.shape
@@ -140,7 +159,10 @@ def _grouped(hidden: jax.Array, targets: jax.Array, chunk: int):
     if pad:
         h = jnp.concatenate([h, jnp.zeros((pad, d), h.dtype)])
         t = jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
-    valid = (jnp.arange(n + pad) < n).astype(jnp.float32)
+    if weights is None:
+        valid = (jnp.arange(n + pad) < n).astype(jnp.float32)
+    else:
+        valid = jnp.pad(weights.reshape(n), (0, pad))
     shape = (groups, per_group, chunk)
     return h.reshape(*shape, d), t.reshape(shape), valid.reshape(shape)
 
@@ -155,10 +177,17 @@ def _chunk_logits(hc: jax.Array, w: jax.Array, vocab_major: bool = False) -> jax
         hc, w, (((1,), (int(vocab_major),)), ((), ())), preferred_element_type=jnp.float32)
 
 
+def _of_tokens(ce: jax.Array, targets: jax.Array) -> jax.Array:
+    """The loops' per-row losses, in `_grouped`'s shape, without the padding and in ``targets``' shape."""
+    return ce.reshape(-1)[: targets.size].reshape(targets.shape)
+
+
 def _chunk_loss(logits: jax.Array, tc: jax.Array, vc: jax.Array):
+    """``(sum of the chunk's counted losses, the rows' log-sum-exp, the rows' own losses)``."""
     lse = jax.nn.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-    return jnp.sum((lse - tgt) * vc), lse
+    ce = lse - tgt
+    return jnp.sum(ce * vc), lse, ce
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -171,21 +200,32 @@ def _loss_sum(
     This body is the undifferentiated call (evaluation): the forward
     loop alone, no gradient made. Under differentiation JAX runs
     :func:`_loss_sum_fwd` in its place."""
+    return _forward_only(hidden, unembed, targets, chunk, vocab_major)[0]
+
+
+def _forward_only(hidden, unembed, targets, chunk, vocab_major, weights=None):
+    """``(the loss sum (1,), the tokens' own losses | None)``: the forward loop alone."""
     _m_loss_traces.inc(**{"pass": "forward_only"})
-    h, t, valid = _grouped(hidden, targets, chunk)
+    h, t, valid = _grouped(hidden, targets, chunk, weights)
     w = unembed.astype(h.dtype)
 
     def body(acc, args):
         hc, tc, vc = args
-        return acc + _chunk_loss(_chunk_logits(hc, w, vocab_major), tc, vc)[0], None
+        loss, _, ce = _chunk_loss(_chunk_logits(hc, w, vocab_major), tc, vc)
+        return acc + loss, None if weights is None else ce
 
-    total, _ = jax.lax.scan(
+    total, ce = jax.lax.scan(
         body, jnp.float32(0),
         jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), (h, t, valid)))
-    return total[None]
+    return total[None], None if weights is None else _of_tokens(ce, targets)
 
 
 def _loss_sum_fwd(hidden, unembed, targets, chunk, vocab_major):
+    total, grads, _ = _one_pass(hidden, unembed, targets, chunk, vocab_major)
+    return total, grads
+
+
+def _one_pass(hidden, unembed, targets, chunk, vocab_major, weights=None):
     """The differentiated forward: one pass over the chunks that makes
     the loss sum AND d(loss sum)/d(hidden), d(loss sum)/d(unembed).
 
@@ -196,9 +236,11 @@ def _loss_sum_fwd(hidden, unembed, targets, chunk, vocab_major):
     a group's chunks, two matmuls over the whole group: dH, and dW added
     into the fp32 ``(d, vocab)`` carry — once per ``_GROUP_ROWS`` rows
     (``(vocab, d)`` with ``vocab_major``: the carry has ``unembed``'s layout).
+    With ``weights`` a token's loss and its dlogits count for its weight, and
+    the tokens' own losses come back beside ``(total, (dH, dW))`` (else None).
     """
     _m_loss_traces.inc(**{"pass": "one_pass"})
-    h, t, valid = _grouped(hidden, targets, chunk)
+    h, t, valid = _grouped(hidden, targets, chunk, weights)
     _, per_group, _, d = h.shape
     vocab = unembed.shape[0 if vocab_major else 1]
     w = unembed.astype(h.dtype)
@@ -206,16 +248,21 @@ def _loss_sum_fwd(hidden, unembed, targets, chunk, vocab_major):
     def visit(total, args):
         hc, tc, vc = args
         logits = _chunk_logits(hc, w, vocab_major)
-        loss, lse = _chunk_loss(logits, tc, vc)
+        loss, lse, ce = _chunk_loss(logits, tc, vc)
         p = jnp.exp(logits - lse[:, None])
         hit = jax.lax.broadcasted_iota(tc.dtype, p.shape, 1) == tc[:, None]
         dlogits = jnp.where(hit, p - 1.0, p) * vc[:, None]
-        return total + loss, dlogits.astype(hc.dtype)
+        total = total + loss
+        dlogits = dlogits.astype(hc.dtype)
+        return total, dlogits if weights is None else (dlogits, ce)
 
     def group(carry, args):
         total, dw = carry
         hg = args[0].reshape(per_group * chunk, d)
         total, dlogits = jax.lax.scan(visit, total, args)
+        ce = None
+        if weights is not None:
+            dlogits, ce = dlogits
         dlogits = dlogits.reshape(per_group * chunk, vocab)
         dh = jax.lax.dot_general(
             dlogits, w, (((1,), (int(not vocab_major),)), ((), ())),
@@ -223,14 +270,18 @@ def _loss_sum_fwd(hidden, unembed, targets, chunk, vocab_major):
         dw = dw + jax.lax.dot_general(
             *((dlogits, hg) if vocab_major else (hg, dlogits)), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return (total, dw), dh
+        return (total, dw), dh if weights is None else (dh, ce)
 
     (total, dw), dh = jax.lax.scan(
         group, (jnp.float32(0), jnp.zeros(unembed.shape, jnp.float32)),
         (h, t, valid))
+    ce = None
+    if weights is not None:
+        dh, ce = dh
+        ce = _of_tokens(ce, targets)
     n = hidden.shape[0] * hidden.shape[1]
     dh = dh.reshape(-1, d)[:n].reshape(hidden.shape)
-    return total[None], (dh, dw.astype(unembed.dtype))
+    return total[None], (dh, dw.astype(unembed.dtype)), ce
 
 
 def _loss_sum_bwd(chunk, vocab_major, grads, g):
@@ -240,3 +291,24 @@ def _loss_sum_bwd(chunk, vocab_major, grads, g):
 
 
 _loss_sum.defvjp(_loss_sum_fwd, _loss_sum_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _weighted_loss_sum(hidden, unembed, targets, weights, chunk: int, vocab_major: bool = False):
+    """``(sum_i weights_i CE_i of ``hidden``'s rows, shape (1,); the rows' own
+    ``CE_i`` (batch, seq))``: `_loss_sum` with a weight a token, differentiable
+    in the weights too. The second output is a constant: its cotangent is dropped."""
+    return _forward_only(hidden, unembed, targets, chunk, vocab_major, weights)
+
+
+def _weighted_loss_sum_fwd(hidden, unembed, targets, weights, chunk, vocab_major):
+    total, grads, ce = _one_pass(hidden, unembed, targets, chunk, vocab_major, weights)
+    return (total, ce), (grads, ce)
+
+
+def _weighted_loss_sum_bwd(chunk, vocab_major, res, g):
+    grads, ce = res
+    return _loss_sum_bwd(chunk, vocab_major, grads, g[0]) + (ce * g[0][0],)
+
+
+_weighted_loss_sum.defvjp(_weighted_loss_sum_fwd, _weighted_loss_sum_bwd)

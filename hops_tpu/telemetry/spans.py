@@ -289,6 +289,19 @@ COUNTER_TRAIN_SSD_KERNEL_CALLS = "hops_tpu_train_ssd_kernel_calls_total"
 #: ``W_up`` back after the combine. A layer whose experts read the token whole
 #: never enters it.
 SCOPE_MOE_LATENT = "moe_latent"
+#: A looped model (``models/transformer.py:TransformerLM(loop_steps=T)``: the
+#: layer stack and the final norm run ``T`` times on the same parameters, a
+#: ``scan`` over the loop steps) enters ``loop_step`` round the scan's body, so
+#: a device trace reads ``.../while/body/loop_step/block_0/attn/...``, and
+#: ``loop_exit`` round what exits cost: the exit gate on each step's normed
+#: hidden state (inside the body) and, in ``make_lm_train_step``, the exit
+#: distribution the gates give and its entropy term (forward and backward).
+SCOPE_LOOP_STEP = "loop_step"
+SCOPE_LOOP_EXIT = "loop_exit"
+#: One per trace of a looped model (``steps`` = its ``loop_steps``). The
+#: scan's body is traced into the program ONCE whatever ``steps`` is;
+#: ``hops_tpu_train_layer_kinds_total`` counts its layers once a trace.
+COUNTER_TRAIN_LOOP_TRACES = "hops_tpu_train_loop_traces_total"
 
 
 #: The serving engine's vocabulary (``modelrepo/lm_engine.py``,

@@ -152,7 +152,7 @@ def test_layer_types_are_checked():
     with pytest.raises(ValueError, match="unknown layer_type"):
         TransformerLM(**{**TINY, "layer_types": ("hyena",) * 4}).layer_specs()
     with pytest.raises(ValueError, match="unknown norm_placement"):
-        TransformerLM(**{**TINY, "norm_placement": "sandwich"}).layer_specs()
+        TransformerLM(**{**TINY, "norm_placement": "around"}).layer_specs()  # "sandwich" names a placement since PR 54
     # a routed feed-forward goes with any mixer and either norm placement: one block class
     routed = TransformerLM(**{**TINY, "moe_every": 2})
     assert [(spec.mixer, spec.ffn, spec.norm_placement) for spec in routed.layer_specs()] == [

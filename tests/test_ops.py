@@ -1,6 +1,9 @@
 """Flash-attention kernel vs the XLA reference (interpreter on fake mesh)."""
 
 import functools
+import json
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -1301,6 +1304,95 @@ def test_chunked_xent_backward_does_not_recompute_the_logits():
     assert results.count(f"2048x{d}") == 1           # dH of a group
     assert results.count(f"{d}x{vocab}") == 1        # dW of a group
     assert len(results) == 3
+
+
+def _weighted_case(batch, seq, d, vocab, vocab_major, seed=0):
+    rs = np.random.RandomState(seed)
+    h = jnp.asarray(rs.randn(batch, seq, d), jnp.float32)
+    w = jnp.asarray(rs.randn(*((vocab, d) if vocab_major else (d, vocab))) * 0.3, jnp.float32)
+    t = jnp.asarray(rs.randint(0, vocab, (batch, seq)))
+    return h, w, t, jnp.asarray(rs.rand(batch, seq), jnp.float32)
+
+
+def _dense_weighted(h, w, weights, t, vocab_major):
+    """``sum_i w_i CE_i / N`` and the ``CE_i``, by the definition, on full logits."""
+    logits = h @ (w.T if vocab_major else w)
+    ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
+    return jnp.sum(weights * ce) / t.size, ce
+
+
+@pytest.mark.parametrize("vocab_major", [False, True], ids=["d_major", "vocab_major"])
+@pytest.mark.parametrize("batch, seq, chunk", [
+    (2, 64, 16),   # chunk divides the tokens
+    (3, 37, 16),   # 111 tokens: a ragged last chunk, padded with weight 0
+    (1, 24, 64),   # fewer tokens than a chunk
+], ids=["divides", "ragged_last_chunk", "under_one_chunk"])
+def test_weighted_chunked_xent_matches_the_dense_formula(batch, seq, chunk, vocab_major):
+    """PR 54: ``weights`` a token. The value, the tokens' own losses and all
+    THREE gradients (hidden, unembed, weights) against the formula on full
+    float32 logits; the undifferentiated call gives the same pair."""
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    h, w, t, weights = _weighted_case(batch, seq, 16, 67, vocab_major)
+
+    def chunked(h, w, weights):
+        return chunked_softmax_xent(h, w, t, chunk=chunk, vocab_major=vocab_major, weights=weights)
+
+    with jax.default_matmul_precision("highest"):
+        (value, ce), grads = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1, 2), has_aux=True))(h, w, weights)
+        (ref_value, ref_ce), ref_grads = jax.value_and_grad(
+            lambda h, w, weights: _dense_weighted(h, w, weights, t, vocab_major), argnums=(0, 1, 2), has_aux=True)(h, w, weights)
+        plain_value, plain_ce = jax.jit(chunked)(h, w, weights)
+    np.testing.assert_allclose(value, ref_value, rtol=1e-6)
+    np.testing.assert_allclose(ce, ref_ce, rtol=1e-5, atol=1e-6)
+    assert ce.shape == t.shape and ce.dtype == jnp.float32
+    np.testing.assert_allclose(plain_value, value, rtol=1e-6)
+    np.testing.assert_allclose(plain_ce, ce, rtol=1e-6)
+    for got, ref in zip(grads, ref_grads):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        np.testing.assert_allclose(got, ref, atol=1e-5 * float(jnp.abs(ref).max()), rtol=0)
+    np.testing.assert_allclose(grads[2], ce / t.size, rtol=1e-6)  # the residual IS the weights' cotangent
+
+
+def test_weighted_chunked_xent_with_unit_weights_is_the_unweighted_loss():
+    """Weights of one are the mean loss, to the gradient; the tokens' losses
+    beside it are a constant (a function of them alone has no gradient)."""
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    h, w, t, _ = _weighted_case(2, 40, 16, 67, False, seed=3)
+    ones = jnp.ones(t.shape, jnp.float32)
+    plain = jax.value_and_grad(lambda h, w: chunked_softmax_xent(h, w, t, chunk=16), argnums=(0, 1))(h, w)
+    weighted = jax.value_and_grad(lambda h, w: chunked_softmax_xent(h, w, t, chunk=16, weights=ones)[0], argnums=(0, 1))(h, w)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7), plain, weighted)
+    of_losses = jax.grad(lambda h: jnp.sum(chunked_softmax_xent(h, w, t, chunk=16, weights=ones)[1]))(h)
+    assert not np.any(np.asarray(of_losses))
+
+
+@pytest.mark.parametrize("case", ["d_major_forward_only", "d_major_one_pass", "vocab_major_forward_only",
+                                  "vocab_major_one_pass"])
+def test_unweighted_chunked_xent_lowers_to_the_parents_text(case):
+    """``tests/data/xent_unweighted_lowered.json`` was written by PR 54's
+    parent (6ac8d2a), before ``weights`` existed: 5,000 bf16 tokens of width
+    16 against a vocabulary of 67 in chunks of 512, the loss alone and with
+    both gradients, either layout of the matrix, locations stripped. Without
+    ``weights`` the program is the parent's to the byte."""
+    import hashlib
+
+    from hops_tpu.ops.xent import chunked_softmax_xent
+
+    vocab_major, grad = case.startswith("vocab_major"), case.endswith("one_pass")
+    h = jax.ShapeDtypeStruct((2, 2500, 16), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((67, 16) if vocab_major else (16, 67), jnp.float32)
+    t = jax.ShapeDtypeStruct((2, 2500), jnp.int32)
+
+    def loss(h, w, t):
+        return chunked_softmax_xent(h, w, t, chunk=512, vocab_major=vocab_major)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)) if grad else loss).lower(h, w, t).as_text()
+    text = re.sub(r"loc\([^)]*\)|#loc\d*( = .*)?", "", text)
+    want = json.loads((pathlib.Path(__file__).parent / "data" / "xent_unweighted_lowered.json").read_text())[case]
+    assert len(text.splitlines()) == want["lines"]
+    assert hashlib.sha256(text.encode()).hexdigest() == want["sha256"]
 
 
 def test_lm_train_step_loss_chunk_matches_dense_path():

@@ -453,7 +453,7 @@ def test_the_models_fields_are_the_parents():
     mixer's two options after ``latent_value_dim``, on by default (what the parent built); PR 47 the six fields of a
     Kimi-delta / gated-GQA hybrid that holds a share of its heads after ``mtp_layer_type``, each off by default;
     PR 51 the eight of a Mamba-2 / latent-MoE hybrid after ``held_heads``, each off (or the published layer's
-    constant) by default."""
+    constant) by default; PR 54 the two of a looped LM after ``moe_latent_dim``, ``loop_steps`` 1 and no exit gate."""
     import dataclasses
 
     want = json.loads((DATA / "transformer_lm_parent_fields.json").read_text())
@@ -469,6 +469,9 @@ def test_the_models_fields_are_the_parents():
              "mamba_chunk": "128", "mamba_held_heads": "None", "mlp_activation": "'swiglu'",
              "moe_latent_dim": "None"}
     assert list(got)[list(got).index("mtp_layer_type") + 1:][:8] == list(since)
+    assert {name: got.pop(name) for name in since} == since
+    since = {"loop_steps": "1", "loop_exit_gate": "False"}
+    assert list(got)[list(got).index("mtp_layer_type") + 1:][:2] == list(since)
     assert {name: got.pop(name) for name in since} == since
     assert list(got.items()) == list(want.items())
     assert [f.name for f in dataclasses.fields(Block) if f.name not in ("parent", "name")] == ["spec", "shared"]
